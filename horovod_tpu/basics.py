@@ -245,17 +245,6 @@ def init(comm=None) -> Topology:
         proc = _env_int("HVDTPU_RANK", 0)
         coordinator = os.environ.get("HVDTPU_COORDINATOR")
 
-        # Some site setups (PJRT plugin registration hooks) overwrite
-        # jax_platforms at interpreter start, clobbering the JAX_PLATFORMS
-        # the launcher exported for its workers.  Re-assert the env intent
-        # through the config API before any backend is instantiated.
-        env_platforms = os.environ.get("JAX_PLATFORMS")
-        if env_platforms and (jax.config.jax_platforms or "") != env_platforms:
-            try:
-                jax.config.update("jax_platforms", env_platforms)
-            except Exception:
-                pass  # backend already up; leave the platform alone
-
         owns_distributed = False
         if world > 1 and not _jax_distributed_active():
             if coordinator is None:

@@ -8,7 +8,7 @@ subprocess per point.  The design constraints, in order:
 
 * **Durability** — a ``campaign.json`` journal under the record dir is
   rewritten atomically (obs/pathspec.py's write-then-rename idiom)
-  after EVERY point, so a mid-campaign crash, watchdog kill (rc=86) or
+  after EVERY point, so a mid-campaign crash, an outer kill or an
   injected SIGABRT loses at most the in-flight point: the journal on
   disk is always a complete, parseable account of every finished point.
 * **Resume** — restarting with the same spec (matched by content hash)
@@ -28,8 +28,9 @@ subprocess per point.  The design constraints, in order:
   (what CI's resume gate seeds), advisory ``action=degrade`` forces the
   point down the degraded-record path without running it.
 
-No jax import anywhere in this module: the campaign driver must outlive
-backends that hang on import.
+No jax import anywhere in this module: the chip belongs to one process
+at a time, and that process is the point's ``bench.py`` child, never the
+campaign driver.
 """
 
 from __future__ import annotations
@@ -50,12 +51,6 @@ __all__ = ["load_spec", "expand_points", "run_campaign", "main",
 
 JOURNAL_SCHEMA = "hvdtpu-campaign-v1"
 JOURNAL_NAME = "campaign.json"
-
-# Grace the outer kill adds past the point's own --total-budget-secs:
-# bench.py bounds its own wall clock across retries; the outer timeout
-# must be strictly larger so the campaign never kills a point that
-# would have recovered (the hw_sweep.sh lesson, kept).
-OUTER_TIMEOUT_GRACE_SECS = 120
 
 # Axes that map to bench.py CLI flags and BAKE INTO the compiled
 # program — two points differing here cannot share an executable.
@@ -137,8 +132,8 @@ def _knob_token(v) -> str:
 
 
 def _explicit_points(spec: dict) -> List[dict]:
-    """An explicit point list (the retired hw_sweep.sh shape: named
-    heterogeneous configs, not a grid).  Each entry: {"name", "args",
+    """An explicit point list (named heterogeneous configs, not a
+    grid).  Each entry: {"name", "args",
     "env"?}.  Order is preserved — a hardware plan runs its headline
     number first."""
     points = []
@@ -183,8 +178,8 @@ def expand_points(spec: dict) -> List[dict]:
     1 + 3 points, not 6.  Unknown axes pass through as ``--axis-name
     value`` bench flags and count as compile-relevant (conservative:
     an unclassified knob must never be credited with executable
-    reuse).  A spec with an explicit ``points`` list (the retired
-    hw_sweep.sh shape) bypasses the grid entirely."""
+    reuse).  A spec with an explicit ``points`` list bypasses the grid
+    entirely."""
     if spec.get("points"):
         return _explicit_points(spec)
     axes = spec["axes"]
@@ -293,7 +288,7 @@ def _commit(record_dir: str, journal: dict) -> None:
 def _parse_result_line(stdout: str) -> Optional[dict]:
     """The last stdout line must be a strict JSON OBJECT (no bare
     scalars, no NaN/Infinity) — a traceback tail must not corrupt the
-    journal (the hw_sweep.sh validation rule, kept)."""
+    journal."""
     lines = [ln for ln in (stdout or "").splitlines() if ln.strip()]
     if not lines:
         return None
@@ -316,11 +311,6 @@ def subprocess_runner(point: dict, spec: dict, *, bench_cmd: List[str],
     the journal."""
     budget = int(point.get("budget_secs") or spec["point_budget_secs"])
     cmd = list(bench_cmd) + list(point["argv"])
-    # Size the child's own wall-clock budget inside the outer kill
-    # window — but only for the real bench (a test stub has no flag).
-    if ("--total-budget-secs" not in point["argv"] and bench_cmd
-            and os.path.basename(bench_cmd[-1]).startswith("bench")):
-        cmd += ["--total-budget-secs", str(budget)]
     env = dict(os.environ)
     env.update(point["env"])
     env["HVDTPU_BENCH_RECORD_DIR"] = record_dir
@@ -330,15 +320,14 @@ def subprocess_runner(point: dict, spec: dict, *, bench_cmd: List[str],
     try:
         proc = subprocess.run(
             cmd, env=env, capture_output=True, text=True,
-            timeout=budget + OUTER_TIMEOUT_GRACE_SECS,
+            timeout=budget,
         )
         rc, stdout, stderr = proc.returncode, proc.stdout, proc.stderr
     except subprocess.TimeoutExpired as exc:
         rc = 124
         stdout = (exc.stdout or b"").decode("utf-8", "replace") \
             if isinstance(exc.stdout, bytes) else (exc.stdout or "")
-        stderr = ("campaign outer timeout after "
-                  f"{budget + OUTER_TIMEOUT_GRACE_SECS}s")
+        stderr = f"campaign point budget spent after {budget}s"
     except OSError as exc:
         return {"rc": 127, "parsed": None, "tail": str(exc)}
     return {

@@ -1,4 +1,4 @@
-"""Elastic exception taxonomy — re-exported from the package-level leaf.
+"""Elastic exception hierarchy — re-exported from the package-level leaf.
 
 The classes live in :mod:`horovod_tpu.exceptions` so the runtime layer
 (engine, checkpoint) can raise them without importing the elastic

@@ -1,4 +1,4 @@
-"""Exception taxonomy for elastic (fault-tolerant) training.
+"""Exception hierarchy for elastic (fault-tolerant) training.
 
 Mirrors upstream Elastic Horovod's split (horovod/common/exceptions.py in
 the post-0.19 line):

@@ -40,23 +40,34 @@ __all__ = ["step_anatomy", "attach_anatomy", "top_ops_from_compiled",
            "roofline_verdict", "HBM_BANDWIDTH", "CPU_BW_ESTIMATE",
            "COMMS_BOUND_FRAC", "COMPUTE_BOUND_MFU"]
 
-# Peak HBM bandwidth, bytes/sec (public TPU spec sheets) — only used
-# for the ridge point of the roofline verdict, so order-of-magnitude
-# accuracy is enough.  Keys match obs/profile.py's PEAK_FLOPS table.
+# Peak HBM bandwidth, bytes/sec — only used for the ridge point of the
+# roofline verdict.  Same keys and same source pages as obs/profile.py's
+# PEAK_FLOPS table ("HBM bandwidth per chip").
 HBM_BANDWIDTH = {
     "TPU v2": 700e9,
     "TPU v3": 900e9,
     "TPU v4": 1228e9,
     "TPU v5 lite": 819e9,
-    "TPU v5e": 819e9,
-    "TPU v5p": 2765e9,
     "TPU v5": 2765e9,
     "TPU v6 lite": 1640e9,
-    "TPU v6e": 1640e9,
 }
 # A few DDR channels; estimate-flagged wherever it flows, like
-# profile.CPU_PEAK_ESTIMATE.
+# profile.CPU_PEAK_ESTIMATE, and like it reachable only for kind "cpu".
 CPU_BW_ESTIMATE = 5e10
+
+
+def _hbm_bandwidth(device_kind) -> tuple:
+    """``(bytes/sec, estimate_flag)`` under peak_flops' rule: a known
+    kind is authoritative, ``"cpu"`` is an estimate, anything else
+    raises."""
+    bw = HBM_BANDWIDTH.get(device_kind)
+    if bw is not None:
+        return bw, False
+    if device_kind == "cpu":
+        return CPU_BW_ESTIMATE, True
+    raise ValueError(
+        f"no HBM bandwidth known for device kind {device_kind!r}"
+    )
 
 # Verdict thresholds: a step spending over a third of itself waiting on
 # collectives is comms-bound whatever the MFU says; an MFU at or above
@@ -73,14 +84,12 @@ _BORING_OPS = {"parameter", "constant", "tuple", "get-tuple-element",
 
 
 def _bytes_from_compiled(compiled) -> Optional[float]:
-    """``bytes accessed`` from cost_analysis(), with the same
-    list-vs-dict shape tolerance as profile.flops_from_compiled."""
+    """``bytes accessed`` from cost_analysis() (None when the artifact
+    exposes no analysis, like profile.flops_from_compiled)."""
     try:
         ca = compiled.cost_analysis()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     try:
         v = float(ca.get("bytes accessed", 0.0))
     except (AttributeError, TypeError, ValueError):
@@ -118,17 +127,14 @@ def roofline_verdict(*, mfu: Optional[float],
                      collective_frac: float,
                      flops_per_step: Optional[float],
                      bytes_per_step: Optional[float],
-                     device_kind: Optional[str],
+                     device_kind: str,
                      dtype: str = "bf16") -> dict:
     """compute- / memory- / comms-bound, with the evidence beside the
     word.  Comms wins first (a stalled fabric caps everything else);
     then MFU or arithmetic intensity vs the ridge point decides between
     the MXUs and HBM."""
-    peak, peak_estimate = peak_flops(device_kind or "", dtype)
-    bw = HBM_BANDWIDTH.get(device_kind or "")
-    bw_estimate = bw is None
-    if bw is None:
-        bw = CPU_BW_ESTIMATE
+    peak, peak_estimate = peak_flops(device_kind, dtype)
+    bw, bw_estimate = _hbm_bandwidth(device_kind)
     ridge = peak / bw  # FLOPs/byte at which HBM stops being the limit
     intensity = None
     if flops_per_step and bytes_per_step:
@@ -191,7 +197,7 @@ def _engine_collective_ms(steps_observed: Optional[int]) -> tuple:
 def step_anatomy(step_ms: float, *,
                  mfu: Optional[float] = None,
                  flops_per_step: Optional[float] = None,
-                 device_kind: Optional[str] = None,
+                 device_kind: str,
                  dtype: str = "bf16",
                  compiled=None,
                  steps_observed: Optional[int] = None,
@@ -201,7 +207,7 @@ def step_anatomy(step_ms: float, *,
     verdict.  Returns None only when ``step_ms`` is unusable."""
     if not isinstance(step_ms, (int, float)) or not step_ms > 0:
         return None
-    peak, peak_estimate = peak_flops(device_kind or "", dtype)
+    peak, peak_estimate = peak_flops(device_kind, dtype)
     compute_ms = None
     compute_source = None
     if isinstance(mfu, (int, float)) and mfu >= 0:

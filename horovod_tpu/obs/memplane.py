@@ -7,10 +7,9 @@ and every FLOP (obs/profile.py) goes — this module is the missing
 
 * **Static accounting** — :func:`parse_memory_analysis` reads XLA's own
   post-compile memory breakdown (``compiled.memory_analysis()``:
-  argument / output / temp / alias bytes) version-tolerantly, the way
-  ``shard_map_compat`` tolerates interpreter drift: the attribute-object
-  form (jax 0.4.x), a dict form, a single-element-list form, and an
-  interpreter that exposes nothing at all (``source: unavailable`` —
+  argument / output / temp / alias bytes) shape-tolerantly: the
+  attribute-object form, a dict form, a single-element-list form, and
+  an executable that exposes nothing at all (``source: unavailable`` —
   never a crash).  :func:`register_program` publishes one breakdown per
   compiled program as ``mem.compiled.*{program=…}`` gauges; the compile
   sites (engine fused allreduce, the overlap train step per mode, the
@@ -81,7 +80,7 @@ __all__ = [
     "record_oom",
 ]
 
-# The owner taxonomy.  Free-form owners are accepted (a future subsystem
+# The owner classes.  Free-form owners are accepted (a future subsystem
 # can tag itself without touching this module) but the canonical five
 # are what the docs, the digest and the post-mortem verdict talk about.
 OWNERS = ("params", "optimizer_state", "grad_buckets", "kv_cache", "other")

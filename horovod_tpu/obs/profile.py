@@ -13,10 +13,10 @@ step:
   (:func:`analytic_step_flops` — the 6N + 12·L·s·d transformer rule and
   a per-model conv table), flagged ``source: analytic``.
 * **Device peak FLOP/s** — a small per-platform table
-  (:data:`PEAK_FLOPS`, public TPU spec sheets).  CPU and unknown chips
-  get a nominal order-of-magnitude entry marked **estimate-only**: a
+  (:data:`PEAK_FLOPS`, public TPU spec sheets).  The kind ``cpu``
+  gets a nominal order-of-magnitude entry marked **estimate-only**: a
   CPU MFU is a trajectory placeholder, never a perf claim, and every
-  consumer carries the flag.
+  consumer carries the flag.  Any other unknown kind is an error.
 * **Live gauges** — :class:`MFUProfiler` divides FLOPs by measured step
   time and publishes ``perf.mfu``, ``perf.model_tflops``,
   ``perf.step_ms`` (plus ``perf.mfu_estimate`` when the peak is a
@@ -25,7 +25,7 @@ step:
   the same number, computed once.
 
 No jax import at module scope: the launcher imports obs eagerly and
-must not pay (or hang on) a backend handshake for it.
+must not initialise a backend for it.
 """
 
 from __future__ import annotations
@@ -43,36 +43,44 @@ __all__ = [
 ]
 
 # Peak dense-matmul FLOP/s per chip (bf16 on MXU; fp32 runs at ~1/4 via
-# bf16x3 passes or worse).  Sources: public TPU spec sheets.  Shared
+# bf16x3 passes or worse), keyed by the string ``jax.Device.device_kind``
+# reports.  Source, one page per generation: Google Cloud TPU
+# documentation, "System architecture" (cloud.google.com/tpu/docs/v2,
+# /v3, /v4, /v5e, /v5p, /v6e), "Peak compute per chip (bf16)".  Shared
 # with bench.py — ONE table, so the bench headline and the live gauge
-# can never disagree about a chip's peak.
+# can never disagree about a chip's peak.  A kind that is not here is
+# an error, never a default.
 PEAK_FLOPS = {
     "TPU v2": 45e12,
     "TPU v3": 123e12,
     "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
-    "TPU v5": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
+    "TPU v5 lite": 197e12,   # v5e
+    "TPU v5": 459e12,        # v5p
+    "TPU v6 lite": 918e12,   # v6e
 }
 
 # Order-of-magnitude stand-in for a few AVX cores — good enough to keep
 # the MFU pipeline exercised end-to-end on the CPU dev path, useless as
-# a perf claim, hence estimate-flagged everywhere it flows.
+# a perf claim, hence estimate-flagged everywhere it flows.  Reachable
+# only for the device kind "cpu".
 CPU_PEAK_ESTIMATE = 1e11
 
 
 def peak_flops(device_kind: str, dtype: str = "bf16"
                ) -> Tuple[float, bool]:
     """``(peak FLOP/s, estimate_flag)`` for a device kind string
-    (``jax.Device.device_kind``).  Known TPUs are authoritative;
-    everything else (CPU dev mode, unknown chips) returns the nominal
-    CPU estimate with the flag raised."""
+    (``jax.Device.device_kind``).  Kinds in :data:`PEAK_FLOPS` are
+    authoritative; ``"cpu"`` returns the nominal CPU estimate with the
+    flag raised; any other kind raises — an accelerator this table does
+    not know must not be handed a CPU's peak."""
     peak = PEAK_FLOPS.get(device_kind)
     if peak is None:
-        return CPU_PEAK_ESTIMATE, True
+        if device_kind == "cpu":
+            return CPU_PEAK_ESTIMATE, True
+        raise ValueError(
+            f"no peak FLOP/s known for device kind {device_kind!r}; add "
+            "it to horovod_tpu.obs.profile.PEAK_FLOPS with its source"
+        )
     if dtype == "fp32":
         peak = peak / 4.0
     return peak, False
@@ -80,16 +88,13 @@ def peak_flops(device_kind: str, dtype: str = "bf16"
 
 def flops_from_compiled(compiled) -> Optional[float]:
     """Per-device FLOPs of one execution of a compiled executable, as
-    XLA counts them post-fusion (``cost_analysis()``).  Tolerates the
-    per-version shape drift (dict vs single-element list) and returns
-    None when the backend exposes no analysis — callers fall back to
+    XLA counts them post-fusion (``cost_analysis()``).  Returns None
+    when the backend exposes no analysis — callers fall back to
     :func:`analytic_step_flops`."""
     try:
         ca = compiled.cost_analysis()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     try:
         v = float(ca.get("flops", 0.0))
     except (AttributeError, TypeError, ValueError):

@@ -3,8 +3,7 @@
 The elastic KV heartbeat (elastic/context.py) proves a *process* is
 alive; it deliberately cannot see a deadlocked *training thread* — the
 beat thread keeps beating through one, and the hang is only surfaced by
-peers' collective timeouts, burning their retry budget (the ROADMAP open
-item, and what BENCH_r03–r05's never-diagnosed hangs cost).
+peers' collective timeouts, burning their retry budget.
 
 This module closes that gap with three pieces:
 

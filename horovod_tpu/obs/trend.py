@@ -1,9 +1,8 @@
 """Perf-trend observatory over the driver's benchmark trajectory.
 
-The repo's ``BENCH_r*.json`` / ``MULTICHIP_r*.json`` records are the
-only durable perf evidence this project has, and they span every schema
-era since r01 (bare parsed payloads without a ``device`` key, degraded
-CPU fallbacks, serve records, failed dark rounds).  This module is the
+``BENCH_r*.json`` / ``MULTICHIP_r*.json`` records span several schema
+eras (bare parsed payloads without a ``device`` key, degraded CPU dry
+runs, serve records, failed rounds).  This module is the
 ONE place that knows how to read them:
 
 * **classification** — ``classify()`` partitions a record into
@@ -17,7 +16,7 @@ ONE place that knows how to read them:
   (or unlucky) round no longer owns the regression threshold.
 * **degraded-streak verdict** — ``degraded_streak()`` names the dark
   trajectory out loud ("N consecutive records without a real
-  measurement; last real number is BENCH_r02.json ...") so it
+  measurement; last real number is BENCH_rNN.json ...") so it
   self-announces in every fresh record, the live digest and the
   ``--stats-summary`` table instead of needing a reviewer to notice.
 * **rendering** — ``render_markdown()`` emits the trajectory +
@@ -114,8 +113,8 @@ def classify(doc: dict) -> str:
 
     real = rc 0, a parsed measurement with a numeric value, and no
     ``degraded`` stamp anywhere; degraded = the explicit stamp bench.py
-    lands on CPU fallbacks and give-up records; failed = everything
-    else (the r03-r05 dark rounds: a nonzero rc and no measurement)."""
+    lands on CPU dry runs and failed runs; failed = everything else (a
+    nonzero rc and no measurement)."""
     parsed = parsed_payload(doc)
     if doc.get("degraded") or (isinstance(parsed, dict)
                                and parsed.get("degraded")):
@@ -294,7 +293,8 @@ def render_markdown(record_dir: Optional[str] = None) -> str:
     lines = ["<!-- generated by scripts/perf_report.py --write-docs; "
              "do not edit by hand -->", ""]
     if not records:
-        lines.append(f"_No BENCH records under {record_dir}._")
+        lines.append("_No BENCH records in the records directory: "
+                     "no device number is on record._")
         return "\n".join(lines) + "\n"
     streak = degraded_streak(records)
     lines += [f"**Trajectory verdict:** {streak['verdict']}", ""]

@@ -103,34 +103,17 @@ def axis_rank(axis_name: str = DP_AXIS):
 
 
 def axis_size(axis_name: str = DP_AXIS) -> int:
-    """Static width of the collective axis.  ``lax.axis_size`` across
-    the jax version drift: older releases lack it, where ``psum(1, axis)``
-    constant-folds to the same static width (the documented pre-axis_size
-    idiom)."""
-    size = getattr(lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static width of the collective axis."""
+    return lax.axis_size(axis_name)
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """shard_map across the jax version drift: newer jax exposes
-    ``jax.shard_map`` (replication check kwarg ``check_vma``), older
-    releases only ``jax.experimental.shard_map.shard_map``
-    (``check_rep``).  The ONE shim the data plane, the jit optimizer
-    path, and the bench all build their shard_maps through — without it
-    every one of those paths is dead on the older interpreter."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as sm  # noqa: PLC0415
-
-    return sm(
+    """``jax.shard_map`` without the replication check — the ONE
+    spelling the data plane, the jit optimizer path and the bench all
+    build their shard_maps through."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -148,13 +131,23 @@ def _allreduce_sum(x, axis_name, average):
 
 
 def _allreduce_fwd(x, axis_name, average):
-    return _allreduce_sum(x, axis_name, average), None
+    # The residual is an empty slice of x: it costs nothing and carries
+    # x's varying-axes type into the backward rule.
+    return _allreduce_sum(x, axis_name, average), jnp.ravel(x)[:0]
 
 
-def _allreduce_bwd(axis_name, average, _, g):
+def _allreduce_bwd(axis_name, average, like_x, g):
     # Reference rule: backward of allreduce is allreduce with the same op
     # (horovod/torch/mpi_ops.py:158-171).
-    return (_allreduce_sum(g, axis_name, average),)
+    ct = _allreduce_sum(g, axis_name, average)
+    # A psum's result is replicated over its axes, but the cotangent must
+    # have the primal input's type: under a replication-checked shard_map
+    # (check_vma=True) mark it varying over the axes x varied over.
+    names = axis_name if isinstance(axis_name, tuple) else (axis_name,)
+    varying = tuple(a for a in names if a in jax.typeof(like_x).vma)
+    if varying:
+        ct = lax.pcast(ct, varying, to="varying")
+    return (ct,)
 
 
 _allreduce_sum.defvjp(_allreduce_fwd, _allreduce_bwd)

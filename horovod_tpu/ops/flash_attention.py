@@ -61,7 +61,9 @@ def flash_attention(
 
     Differentiable; numerically matches
     :func:`horovod_tpu.parallel.local_attention` to fp32 tolerance.
-    ``interpret=None`` auto-selects the Pallas interpreter off-TPU.
+    ``interpret=None`` compiles the kernel on backend ``tpu`` and runs
+    the Pallas interpreter on backend ``cpu`` (the test mode); any other
+    backend raises.
 
     ``window=W`` (requires ``causal=True``) restricts each position to
     its last ``W`` keys (self included) — Mistral-style sliding-window
@@ -94,7 +96,7 @@ def flash_attention(
     bq = _pick_block(s, block_q)
     bk = _pick_block(s, block_k)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret_for_backend(jax.default_backend())
     # [B,S,H,D] -> [B*H, S, D]: one grid row per (batch, head).  GQA/MQA:
     # k/v fold to [B*HKV, S, D] and the kernels' index maps route each q
     # head to its kv group — no broadcast materialization.
@@ -104,6 +106,18 @@ def flash_attention(
     out = _flash(fold(q), fold(k), fold(v), causal, scale_, bq, bk,
                  h, hkv, window, bool(interpret))
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+
+
+def _interpret_for_backend(backend: str) -> bool:
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"flash_attention: backend {backend!r} is neither 'tpu' (compiled "
+        "kernel) nor 'cpu' (Pallas interpreter, the test mode); pass "
+        "interpret= explicitly to run it anywhere else"
+    )
 
 
 @functools.partial(jax.custom_vjp,

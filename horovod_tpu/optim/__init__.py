@@ -273,10 +273,6 @@ def distribute(
     replicated.  Pass explicit ``jax.sharding.PartitionSpec`` trees to
     override.
     """
-    # shard_map via the shared version shim: older jax only ships
-    # jax.experimental.shard_map (check_rep), newer jax.shard_map
-    # (check_vma) — the bare `from jax import shard_map` died on the
-    # older interpreter and took the whole CPU bench path with it.
     from jax.sharding import PartitionSpec as P  # noqa: PLC0415
 
     from ..ops.collectives import shard_map_compat  # noqa: PLC0415

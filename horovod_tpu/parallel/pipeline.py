@@ -222,14 +222,8 @@ def _vma_axes(refs, base):
 def _mark_varying(v, axes):
     """Mark a replicated value device-varying over ``axes`` so a scan
     carry's type matches the tick outputs under replication tracking
-    (check_vma=True) — a no-op without it."""
-    try:
-        return lax.pcast(v, axes, to="varying")
-    except (AttributeError, TypeError):  # older jax: pvary spelling
-        try:
-            return lax.pvary(v, axes)
-        except (AttributeError, TypeError):
-            return v  # very old jax: no vma tracking to satisfy
+    (check_vma=True)."""
+    return lax.pcast(v, axes, to="varying")
 
 
 class _Schedule:

@@ -767,6 +767,44 @@ def discover_nics(
                 pass
 
 
+def _local_tpu_chips() -> int:
+    """TPU chips attached to THIS host, counted from their device nodes
+    — never through JAX: the launcher must not open the chip its
+    workers need."""
+    import glob  # noqa: PLC0415
+
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def refuse_shared_tpu(slots: List[SlotInfo], env: Dict[str, str]) -> None:
+    """A TPU chip belongs to one process at a time, and slots get no
+    device binding: several slots on one TPU host would each initialise
+    the TPU backend and each claim every local chip.  The supported
+    layout there is ONE process per host driving all its chips through
+    ``hvd.mesh``; anything else is refused before a worker is spawned.
+    Only this host can be probed — remote hosts are the caller's word."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return
+    crowded = max(
+        (s.local_size for s in slots if is_local_host(s.hostname)),
+        default=0,
+    )
+    if crowded <= 1:
+        return
+    chips = _local_tpu_chips()
+    if not chips:
+        return
+    raise RuntimeError(
+        f"{crowded} slots on this host would each initialise the TPU "
+        f"backend and claim all {chips} local chip(s); a chip belongs to "
+        "one process at a time.  On a TPU host run ONE process (-np 1 per "
+        "host) and let it drive every local chip through hvd.mesh(); "
+        "for a multi-process CPU world set JAX_PLATFORMS=cpu."
+    )
+
+
 def build_slot_env(
     slot: SlotInfo,
     coordinator: str,
@@ -987,6 +1025,7 @@ def launch_job(
         base_env.update(env)
     if start_timeout is not None:
         base_env["HVDTPU_START_TIMEOUT"] = str(int(start_timeout))
+    refuse_shared_tpu(slots, base_env)
 
     if output_filename:
         os.makedirs(output_filename, exist_ok=True)
@@ -1325,6 +1364,7 @@ def launch_elastic_job(
     host_slots = _resolve_host_slots(hosts, hostfile,
                                      f"localhost:{capacity}")
     slots = allocate(host_slots, capacity)
+    refuse_shared_tpu(slots, {**os.environ, **(env or {})})
     host_of: Dict[int, str] = {s.rank: s.hostname for s in slots}
     host_order: List[str] = []
     for hs in host_slots:

@@ -159,6 +159,15 @@ DEFAULT_SPEC: Dict[str, Any] = {
 }
 
 
+def _device_report() -> dict:
+    import jax  # noqa: PLC0415
+
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
 def _epoch_scope(epoch: int) -> str:
     return f"serve_e{epoch}"
 
@@ -1146,6 +1155,10 @@ def _serve_epoch(ctx, engine, spec: dict, totals: Dict[str, Any],
                     reg.counter("serve.admitted_while_busy").value
                 ),
                 "frontends": frontends,
+                # The device THIS rank served on: the launcher side
+                # never initialises a backend, so a record's device
+                # (and the refusal of a non-TPU run) rests on this.
+                "device": _device_report(),
             }
             if sched.qos is not None:
                 # Per-tenant accounting across every epoch this rank
@@ -1262,6 +1275,9 @@ def serve_worker(spec: Optional[dict] = None):
     obs_progress.set_phase("compile")
     import jax  # noqa: PLC0415
 
+    from ..utils.compile_cache import enable_compile_cache  # noqa: PLC0415
+
+    enable_compile_cache()
     model = gpt(spec["size"], **spec.get("overrides", {}))
     dummy = jnp.zeros((1, min(8, model.cfg.max_len)), jnp.int32)
     params = model.init(jax.random.PRNGKey(spec["seed"]), dummy)

@@ -14,8 +14,7 @@ verdict table for a ``campaign.json`` journal
 ``docs/performance.md`` in place (between the ``perf-report`` markers),
 so the committed docs can never drift from the committed records.
 
-This replaces ``scripts/summarize_sweep.py`` (now a deprecation shim):
-campaign journals carry per-point status/provenance an ad-hoc sweep's
+Campaign journals carry per-point status/provenance an ad-hoc sweep's
 results file never had.
 
 Exit codes: 0 report rendered, 2 bad input.

@@ -718,7 +718,7 @@ def test_hvd005_function_scope_ok(tmp_path):
 def test_hvd009_resolves_through_shard_map_wrapper(tmp_path):
     findings = _lint_source(tmp_path, """
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def local_step(params, opt_state, xb):
             return params, opt_state
@@ -749,7 +749,7 @@ def test_hvd009_resolution_is_scope_first(tmp_path):
     # stateless apply stays quiet, the train step fires.
     findings = _lint_source(tmp_path, """
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def build_eval():
             step = shard_map(lambda p_, xb: p_, mesh=None,
@@ -773,7 +773,7 @@ def test_hvd009_name_does_not_resolve_to_same_named_method(tmp_path):
     # `init(self, params)` method and convict the lambda.
     findings = _lint_source(tmp_path, """
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         class Plan:
             def init(self, params):

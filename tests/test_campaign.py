@@ -320,7 +320,7 @@ def test_step_anatomy_components_tile_step_time():
     assert out["method"]["compute"] == "mfu x step"
     # A real compiled artifact yields an op table (dot/fusion at least).
     assert out.get("top_ops"), out
-    assert anatomy.step_anatomy(0.0, mfu=0.5) is None
+    assert anatomy.step_anatomy(0.0, mfu=0.5, device_kind="cpu") is None
 
 
 def test_anatomy_amortizes_engine_collective_wait():
@@ -332,7 +332,8 @@ def test_anatomy_amortizes_engine_collective_wait():
     for _ in range(4):
         hist.observe(5.0)  # 20 ms of cycle time over 4 steps
     try:
-        out = anatomy.step_anatomy(10.0, mfu=0.2, steps_observed=4)
+        out = anatomy.step_anatomy(10.0, mfu=0.2, steps_observed=4,
+                                   device_kind="cpu")
     finally:
         reset_registry()
     comp = out["components_ms"]

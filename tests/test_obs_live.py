@@ -817,7 +817,7 @@ def test_bench_regression_gate(tmp_path):
     dev = "TPU v5 lite"
     rec(1, {"metric": "m", "value": 100.0, "mfu": 0.2, "device": dev})
     rec(2, {"metric": "m", "value": 110.0, "mfu": 0.25, "device": dev})
-    rec(3, None, rc=86)
+    rec(3, None, rc=1)
 
     out = bench.attach_regression(
         {"metric": "m", "value": 99.0, "mfu": 0.22, "device": dev},
