@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import scopes
 from .transformer import TransformerConfig, raw_block_forward
 
 __all__ = [
@@ -497,12 +498,13 @@ def decode_step_paged(cfg: TransformerConfig, params, pool, tables,
             v_new = v_new.at[_i, w_page, w_off].set(
                 v_t[:, 0].astype(cfg.dtype), mode="drop"
             )
-            kc = jnp.take(k_new[_i], tables, axis=0,
-                          mode="fill", fill_value=0)
-            vc = jnp.take(v_new[_i], tables, axis=0,
-                          mode="fill", fill_value=0)
-            kc = kc.reshape(b, virt, kc.shape[-2], kc.shape[-1])
-            vc = vc.reshape(b, virt, vc.shape[-2], vc.shape[-1])
+            with jax.named_scope(scopes.KV_GATHER):
+                kc = jnp.take(k_new[_i], tables, axis=0,
+                              mode="fill", fill_value=0)
+                vc = jnp.take(v_new[_i], tables, axis=0,
+                              mode="fill", fill_value=0)
+                kc = kc.reshape(b, virt, kc.shape[-2], kc.shape[-1])
+                vc = vc.reshape(b, virt, vc.shape[-2], vc.shape[-1])
             att = _attend_cached(cfg, q[:, 0], kc, vc, pos)
             return att[:, None]
 
@@ -596,13 +598,14 @@ def assign_slot_paged(cfg: TransformerConfig, params, pool, tables,
     else:
         logits, one = _prefill_shard(cfg, params, rep, tokens[None],
                                      length[None], tp_axis)
-    pidx = jnp.arange(s)
-    row = jnp.take(tables, slot, axis=0)
-    pages = jnp.take(row, pidx // ps)
-    offs = pidx % ps
-    k = pool["k"].at[:, pages, offs].set(one["k"][:, 0], mode="drop")
-    v = pool["v"].at[:, pages, offs].set(one["v"][:, 0], mode="drop")
-    pos = pool["pos"].at[slot].set(length)
+    with jax.named_scope(scopes.KV_SCATTER):
+        pidx = jnp.arange(s)
+        row = jnp.take(tables, slot, axis=0)
+        pages = jnp.take(row, pidx // ps)
+        offs = pidx % ps
+        k = pool["k"].at[:, pages, offs].set(one["k"][:, 0], mode="drop")
+        v = pool["v"].at[:, pages, offs].set(one["v"][:, 0], mode="drop")
+        pos = pool["pos"].at[slot].set(length)
     last = jnp.take(logits[0], length - 1, axis=0)
     return {"k": k, "v": v, "pos": pos}, last
 

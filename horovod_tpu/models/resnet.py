@@ -20,7 +20,10 @@ from functools import partial
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
+
+from .. import scopes
 
 ModuleDef = Any
 
@@ -165,29 +168,36 @@ class ResNet(nn.Module):
         else:
             act = nn.relu
 
-        x = jnp.asarray(x, self.compute_dtype)
-        if self.s2d_stem:
-            x = space_to_depth(x, 2)
-            x = conv(
-                self.num_filters,
-                (4, 4),
-                (1, 1),
-                padding=[(1, 2), (1, 2)],
-                use_bias=False,
-                name="conv_init",
-            )(x)
-        else:
-            x = conv(
-                self.num_filters,
-                (7, 7),
-                (2, 2),
-                padding=[(3, 3), (3, 3)],
-                use_bias=False,
-                name="conv_init",
-            )(x)
-        x = norm(name="bn_init")(x)
-        x = act(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
+        # flax names the blocks (``stage<i>_block<j>``); what the model
+        # does outside any submodule (the input cast, the max-pool, the
+        # global mean) gets a scope here, so a device trace has no
+        # nameless part.  Scopes are metadata: the parameter tree is
+        # ``conv_init``, ``bn_init``, the blocks and ``head`` as before.
+        with jax.named_scope(scopes.STEM):
+            x = jnp.asarray(x, self.compute_dtype)
+            if self.s2d_stem:
+                x = space_to_depth(x, 2)
+                x = conv(
+                    self.num_filters,
+                    (4, 4),
+                    (1, 1),
+                    padding=[(1, 2), (1, 2)],
+                    use_bias=False,
+                    name="conv_init",
+                )(x)
+            else:
+                x = conv(
+                    self.num_filters,
+                    (7, 7),
+                    (2, 2),
+                    padding=[(3, 3), (3, 3)],
+                    use_bias=False,
+                    name="conv_init",
+                )(x)
+            x = norm(name="bn_init")(x)
+            x = act(x)
+            x = nn.max_pool(x, (3, 3), strides=(2, 2),
+                            padding=((1, 1), (1, 1)))
         for i, block_count in enumerate(self.stage_sizes):
             for j in range(block_count):
                 strides = (2, 2) if i > 0 and j == 0 else (1, 1)
@@ -199,12 +209,13 @@ class ResNet(nn.Module):
                     act=act,
                     name=f"stage{i+1}_block{j+1}",
                 )(x)
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(
-            self.num_classes, dtype=jnp.float32, param_dtype=jnp.float32,
-            name="head",
-        )(jnp.asarray(x, jnp.float32))
-        return x
+        with jax.named_scope(scopes.HEAD):
+            x = jnp.mean(x, axis=(1, 2))
+            x = nn.Dense(
+                self.num_classes, dtype=jnp.float32,
+                param_dtype=jnp.float32, name="head",
+            )(jnp.asarray(x, jnp.float32))
+            return x
 
 
 ResNet18 = partial(ResNet, stage_sizes=[2, 2, 2, 2], block=BasicBlock)

@@ -37,6 +37,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .. import scopes
 from ..parallel.moe import DEFAULT_GROUP_SIZE as MOE_DEFAULT_GROUP_SIZE
 
 
@@ -221,28 +222,35 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
     q_dim = nh * hd
     kv_dim = nkv * hd
 
-    h = ln1(x)
-    fused = qkv(h)
-    q = fused[..., :q_dim].reshape(b, s, nh, hd)
-    k = fused[..., q_dim:q_dim + kv_dim].reshape(b, s, nkv, hd)
-    v = fused[..., q_dim + kv_dim:].reshape(b, s, nkv, hd)
-    if rope_tabs is not None:
-        from ..ops.rope import apply_rope_tables  # noqa: PLC0415
+    # The two halves trace under scopes (``jax.named_scope``) of their own, so
+    # a device trace tells attention from MLP whatever XLA names the
+    # fusions.  A scope is metadata: it names no parameter, so the flax
+    # tree stays ``block<i>/{ln1,qkv,proj,ln2,fc1,fc2}``.
+    with jax.named_scope(scopes.ATTN):
+        h = ln1(x)
+        fused = qkv(h)
+        q = fused[..., :q_dim].reshape(b, s, nh, hd)
+        k = fused[..., q_dim:q_dim + kv_dim].reshape(b, s, nkv, hd)
+        v = fused[..., q_dim + kv_dim:].reshape(b, s, nkv, hd)
+        if rope_tabs is not None:
+            from ..ops.rope import apply_rope_tables  # noqa: PLC0415
 
-        q = apply_rope_tables(q, *rope_tabs)
-        k = apply_rope_tables(k, *rope_tabs)
-    if attend is None:
-        attend_cfg = cfg
-        if nh != cfg.num_heads or nkv != cfg.kv_heads:
-            # per-rank head shard: _attend sees the LOCAL head geometry
-            attend_cfg = replace(cfg, num_heads=nh, num_kv_heads=nkv,
-                                 emb_dim=q_dim)
-        att_4d = _attend(attend_cfg, q, k, v, positions)
-    else:
-        att_4d = attend(q, k, v)
-    att = act_store(att_4d.reshape(b, s, q_dim), cfg)
-    x = x + act_store(proj(att), cfg)
-    return x + act_store(mlp(ln2(x)), cfg)
+            q = apply_rope_tables(q, *rope_tabs)
+            k = apply_rope_tables(k, *rope_tabs)
+        if attend is None:
+            attend_cfg = cfg
+            if nh != cfg.num_heads or nkv != cfg.kv_heads:
+                # per-rank head shard: _attend sees the LOCAL head
+                # geometry
+                attend_cfg = replace(cfg, num_heads=nh, num_kv_heads=nkv,
+                                     emb_dim=q_dim)
+            att_4d = _attend(attend_cfg, q, k, v, positions)
+        else:
+            att_4d = attend(q, k, v)
+        att = act_store(att_4d.reshape(b, s, q_dim), cfg)
+        x = x + act_store(proj(att), cfg)
+    with jax.named_scope(scopes.MLP):
+        return x + act_store(mlp(ln2(x)), cfg)
 
 
 def raw_layer_norm(x, scale, bias, eps: float = 1e-6):
@@ -363,9 +371,10 @@ class GPT(nn.Module):
     @nn.compact
     def __call__(self, tokens, pos_offset=0, positions=None):
         cfg = self.cfg
-        tok = nn.Embed(
-            cfg.vocab_size, cfg.emb_dim, dtype=cfg.dtype, name="wte"
-        )(tokens)
+        with jax.named_scope(scopes.EMBED):
+            tok = nn.Embed(
+                cfg.vocab_size, cfg.emb_dim, dtype=cfg.dtype, name="wte"
+            )(tokens)
         s = tokens.shape[1]
         if s > cfg.max_len:
             raise ValueError(
@@ -393,9 +402,10 @@ class GPT(nn.Module):
             # out-of-range position (e.g. global S > max_len under SP,
             # which the local s<=max_len check can't see) poison the loss
             # LOUDLY instead of silently reusing the clamped last row.
-            pos = jnp.take(pos_table, positions, axis=0,
-                           mode="fill", fill_value=jnp.nan)
-            x = x + pos.astype(cfg.dtype)[None]
+            with jax.named_scope(scopes.EMBED):
+                pos = jnp.take(pos_table, positions, axis=0,
+                               mode="fill", fill_value=jnp.nan)
+                x = x + pos.astype(cfg.dtype)[None]
         rope_tabs = None
         if cfg.pos_embedding == "rope":
             from ..ops.rope import rope_tables  # noqa: PLC0415
@@ -411,11 +421,15 @@ class GPT(nn.Module):
             )
         for i in range(cfg.num_layers):
             x = block_cls(cfg, name=f"block{i}")(x, positions, rope_tabs)
-        x = nn.LayerNorm(dtype=jnp.float32, name="lnf")(x)
-        logits = nn.Dense(
-            cfg.vocab_size, dtype=cfg.dtype, use_bias=False, name="head"
-        )(x)
-        return logits.astype(jnp.float32)
+        # Final norm, LM head and the fp32 cast under one scope, like
+        # the raw-weights epilogue (tensor_parallel._gpt_head).
+        with jax.named_scope(scopes.HEAD):
+            x = nn.LayerNorm(dtype=jnp.float32, name="lnf")(x)
+            logits = nn.Dense(
+                cfg.vocab_size, dtype=cfg.dtype, use_bias=False,
+                name="head"
+            )(x)
+            return logits.astype(jnp.float32)
 
 
 # Named sizes (GPT-2 family geometry; head_dim 64, MXU-friendly widths).

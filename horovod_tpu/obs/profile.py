@@ -24,15 +24,59 @@ step:
   token), ``/metrics``, ``--stats-summary`` and every BENCH record see
   the same number, computed once.
 
+Two further measurement layers live here, both fed by names the
+program itself puts where the work happens:
+
+* **Compile log** — :func:`compile_log`: one record per
+  ``jax.monitoring`` compile event (program, phase ``trace`` / ``lower``
+  / ``backend``, seconds, ``time.perf_counter()`` at the end, and on
+  the backend record the persistent cache's ``hit`` or ``miss``).
+  ``utils/compile_cache.enable_compile_cache()`` registers the listener,
+  so every entry point has it before its first compile; the registry
+  carries ``compile.seconds{phase}``, ``compile.cache_hits`` and
+  ``compile.cache_misses``, and with ``HVDTPU_TRACE`` armed each backend
+  compile is a ``compile`` span on the ``compile`` lane of the span ring.
+* **Device trace** — :class:`DeviceTrace` (``with
+  hvd.obs.profile.device_trace(): ...``): the program's ONLY use of
+  ``jax.profiler``.  It records a bounded slice and reduces it
+  (:func:`reduce_trace`) to window and busy seconds per device, device
+  time by scope (the ``jax.named_scope`` names of horovod_tpu/scopes.py,
+  read from each operation's ``op_name``) and by Pallas kernel, and idle
+  time by the obs/trace.py span that covers each gap.  The spans are on
+  ``time.time()``; a marker annotation carrying that clock's stamp puts
+  them on the trace's timeline.  The serving rank arms one slice out of
+  every :data:`SLICE_PERIOD` busy steps when ``HVDTPU_TRACE`` is set
+  (:class:`SliceSchedule`) and emits each as a ``device_slice`` span.
+
 No jax import at module scope: the launcher imports obs eagerly and
 must not initialise a backend for it.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import collections
+import functools
+import glob
+import os
+import re
+import shutil
+import tempfile
+import threading
+import time
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+
+from ..scopes import SCOPES
 
 __all__ = [
+    "SCOPES",
+    "compile_log",
+    "compile_summary",
+    "install_compile_listener",
+    "DeviceTrace",
+    "device_trace",
+    "SliceSchedule",
+    "reduce_trace",
+    "read_xplane",
     "PEAK_FLOPS",
     "CPU_PEAK_ESTIMATE",
     "peak_flops",
@@ -235,3 +279,720 @@ class MFUProfiler:
             "estimate": bool(self.estimate),
         }
         return out
+
+
+# ---------------------------------------------------------------------------
+# Compile phases as a log
+# ---------------------------------------------------------------------------
+
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_VERDICTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+COMPILE_LOG_CAPACITY = 4096
+COMPILE_LANE = "compile"
+
+
+class _CompileLog:
+    """Bounded in-process log of ``jax.monitoring``'s compile events.
+
+    JAX reports the persistent cache's verdict as a bare event just
+    before the backend-compile duration of the same program, on the
+    same thread: the verdict is held per thread and lands on that
+    backend record, so a miss names its program.
+
+    The phases nest: while ``local_step`` is traced or lowered, every
+    jitted helper it calls (``jnp.add``, ``_where``, ...: thousands for
+    a 24-layer model) is traced inside it and reports a duration of its
+    own.  JAX also reports each phase's START (a scalar event); the
+    depth kept from those per thread lets only the outermost phase into
+    the log, whose seconds already hold the nested ones."""
+
+    def __init__(self):
+        self.records = collections.deque(maxlen=COMPILE_LOG_CAPACITY)
+        self.installed = False
+        self._pending = threading.local()
+
+    def on_event(self, event: str, **_kw) -> None:
+        verdict = _CACHE_VERDICTS.get(event)
+        if verdict is not None:
+            self._pending.verdict = verdict
+
+    def on_start(self, event: str, _value, **_kw) -> None:
+        if event in _COMPILE_PHASES:
+            self._pending.depth = getattr(self._pending, "depth", 0) + 1
+
+    def on_duration(self, event: str, seconds: float, **kw) -> None:
+        phase = _COMPILE_PHASES.get(event)
+        if phase is None:
+            return
+        depth = max(getattr(self._pending, "depth", 0) - 1, 0)
+        self._pending.depth = depth
+        if depth:
+            return
+        from . import trace as obs_trace  # noqa: PLC0415
+        from .registry import get_registry  # noqa: PLC0415
+
+        seconds = float(seconds)
+        # JAX says ``step`` when it traces and ``jit(step)`` when it
+        # lowers and compiles: one program, one name.
+        program = re.sub(r"^jit\((.*)\)$", r"\1",
+                         str(kw.get("fun_name", "")))
+        record = {"program": program, "phase": phase,
+                  "seconds": seconds, "t_end": time.perf_counter()}
+        reg = get_registry()
+        reg.counter("compile.seconds", phase=phase).inc(seconds)
+        if phase == "backend":
+            verdict = getattr(self._pending, "verdict", None)
+            self._pending.verdict = None
+            if verdict is not None:
+                record["cache"] = verdict
+                reg.counter("compile.cache_hits" if verdict == "hit"
+                            else "compile.cache_misses").inc()
+            if obs_trace.enabled():
+                t1 = time.time()
+                obs_trace.add_span(
+                    COMPILE_LANE, "compile", t1 - seconds, t1,
+                    program=record["program"], cache=verdict)
+        self.records.append(record)
+
+
+_COMPILE_LOG = _CompileLog()
+
+
+def install_compile_listener() -> None:
+    """Register the compile log with ``jax.monitoring``, once a
+    process.  Called by ``utils/compile_cache.enable_compile_cache()``;
+    the listeners run only when JAX traces, lowers or compiles."""
+    if _COMPILE_LOG.installed:
+        return
+    import jax.monitoring  # noqa: PLC0415
+
+    jax.monitoring.register_event_listener(_COMPILE_LOG.on_event)
+    jax.monitoring.register_scalar_listener(_COMPILE_LOG.on_start)
+    jax.monitoring.register_event_duration_secs_listener(
+        _COMPILE_LOG.on_duration)
+    _COMPILE_LOG.installed = True
+
+
+def compile_log() -> List[dict]:
+    """The compile events so far, oldest first: ``{"program", "phase",
+    "seconds", "t_end"}`` with ``phase`` one of ``trace``, ``lower``,
+    ``backend`` and ``t_end`` on ``time.perf_counter()``; a backend
+    record of a program that went through the persistent cache also
+    has ``"cache": "hit" | "miss"``.  The newest
+    :data:`COMPILE_LOG_CAPACITY` records are kept."""
+    return [dict(r) for r in list(_COMPILE_LOG.records)]
+
+
+def compile_summary() -> dict:
+    """The log in one line, for a drain summary: seconds by phase, the
+    cache's hits and misses, and the programs that missed."""
+    seconds = {phase: 0.0 for phase in _COMPILE_PHASES.values()}
+    hits, missed = 0, []
+    for r in compile_log():
+        seconds[r["phase"]] += r["seconds"]
+        if r.get("cache") == "hit":
+            hits += 1
+        elif r.get("cache") == "miss":
+            missed.append(r["program"])
+    return {"seconds": {k: round(v, 3) for k, v in seconds.items()},
+            "cache_hits": hits, "cache_misses": len(missed),
+            "missed": missed[-16:]}
+
+
+# ---------------------------------------------------------------------------
+# The device trace: one profiler hook, reduced in the program
+# ---------------------------------------------------------------------------
+
+# The reduction files an operation under the innermost program scope
+# (horovod_tpu/scopes.py, the one list every ``jax.named_scope`` of the
+# package names itself from) in its ``op_name``; an operation under
+# none of them goes under the innermost other name (a flax module's:
+# ``wte``, ``stage1_block1``), and one with no name at all under
+# ``unscoped``.
+UNSCOPED = "unscoped"
+UNCOVERED = "uncovered"
+CLOCK_MARKER = "hvdtpu_clock"
+SLICE_STEPS = 4
+SLICE_PERIOD = 128
+
+_DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+_HOST_PLANE = "/host:CPU"
+_OPS_LINE = "XLA Ops"
+_PALLAS_TARGET = 'custom_call_target="tpu_custom_call"'
+# Name-stack entries that are JAX's, not a scope: transforms, control
+# flow, the jitted function itself.
+_NOT_A_SCOPE = re.compile(
+    r"^(\w+\(.*\)|shard_map|while|body|cond|branch_\d+_fun|"
+    r"custom_vjp_call|custom_jvp_call|checkpoint|remat|pjit|jit)$")
+
+Interval = Tuple[float, float]
+
+
+@functools.lru_cache(maxsize=8192)  # events repeat a few thousand names
+def scope_of(op_name: str) -> str:
+    """``jit(step)/transpose(jvp(GPT))/block3/attn/qkv/dot_general:``
+    -> ``transpose(attn)``: the innermost program scope of an
+    operation's name stack (else the innermost other name), with the
+    transposed (backward) part kept apart from the forward one."""
+    parts = [p for p in op_name.rstrip(":").split("/") if p][:-1]
+    names = [p for p in parts if not _NOT_A_SCOPE.match(p)]
+    # under ``vmap`` JAX writes the scope inside the transform's name:
+    # ``vmap(sample)``
+    bare = [re.sub(r"^(?:\w+\()+([\w.]+)\)+$", r"\1", p) for p in parts]
+    scope = next((p for p in reversed(bare) if p in SCOPES),
+                 names[-1] if names else UNSCOPED)
+    if any(p.startswith("transpose(") for p in parts):
+        return f"transpose({scope})"
+    return scope
+
+
+def _union(intervals: Iterable[Interval]) -> List[Interval]:
+    merged: List[List[float]] = []
+    for a, b in sorted(intervals):
+        if b <= a:
+            continue
+        if merged and a <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], b)
+        else:
+            merged.append([a, b])
+    return [(a, b) for a, b in merged]
+
+
+def _total(intervals: Iterable[Interval]) -> float:
+    return sum(b - a for a, b in intervals)
+
+
+def _cover_segments(spans: Sequence[Tuple[str, float, float]]
+                    ) -> List[Tuple[float, float, str]]:
+    """The timeline cut at every span boundary, each piece labelled
+    with the SHORTEST span that covers it (the innermost, where spans
+    nest: ``decode_compute`` inside ``step``).  Pieces no span covers
+    are left out."""
+    edges = sorted({t for _, a, b in spans for t in (a, b)})
+    by_start = sorted(spans, key=lambda s: s[1])
+    out, active, nxt = [], [], 0
+    for lo, hi in zip(edges, edges[1:]):
+        while nxt < len(by_start) and by_start[nxt][1] <= lo:
+            active.append(by_start[nxt])
+            nxt += 1
+        active = [s for s in active if s[2] > lo]
+        if active:
+            name = min(active, key=lambda s: s[2] - s[1])[0]
+            out.append((lo, hi, name))
+    return out
+
+
+def _idle_by_span(busy: Sequence[Interval],
+                  segments: Sequence[Tuple[float, float, str]]
+                  ) -> Dict[str, float]:
+    """The gaps between busy intervals, split among the labelled
+    segments in one sweep; what no segment covers is ``uncovered``."""
+    out: Dict[str, float] = {}
+    j = 0
+    for (_, gap_lo), (gap_hi, _) in zip(busy, busy[1:]):
+        left = gap_hi - gap_lo
+        while j < len(segments) and segments[j][1] <= gap_lo:
+            j += 1
+        k = j
+        while k < len(segments) and segments[k][0] < gap_hi:
+            lo, hi, name = segments[k]
+            part = min(hi, gap_hi) - max(lo, gap_lo)
+            if part > 0:
+                out[name] = out.get(name, 0.0) + part
+                left -= part
+            k += 1
+        if left > 0:
+            out[UNCOVERED] = out.get(UNCOVERED, 0.0) + left
+    return out
+
+
+def reduce_trace(ops_by_device: Dict[int, Sequence[Sequence]],
+                 marker: Optional[Tuple[float, float]] = None,
+                 spans: Sequence[dict] = ()) -> dict:
+    """A device trace as plain data -> what a ``device_slice`` carries.
+
+    ``ops_by_device``: device index -> ``[name, start_ns, dur_ns,
+    op_name]`` per executed operation (:func:`read_xplane`).
+    ``marker``: ``(trace_ns, wall_s)`` of the clock marker — the same
+    instant on the trace's timeline and on ``time.time()``.  ``spans``:
+    obs/trace.py span documents (``name``, ``t0``, ``dur`` on
+    ``time.time()``); without a marker they cannot be placed and every
+    gap is ``uncovered``.
+
+    A device's window runs from its first operation's start to its last
+    one's end; busy is the UNION of its operations' intervals (two that
+    overlap count once), idle the rest, so busy + idle is the window.
+    ``by_scope`` and ``by_kernel`` are unions too, over the operations
+    of one scope (:func:`scope_of`) or one Pallas kernel (instruction
+    name without its number), summed over devices.  Seconds throughout.
+    """
+    segments: List[Tuple[float, float, str]] = []
+    if marker is not None and spans:
+        trace_ns, wall_s = marker
+        segments = _cover_segments([
+            (s["name"], trace_ns + (s["t0"] - wall_s) * 1e9,
+             trace_ns + (s["t0"] + s["dur"] - wall_s) * 1e9)
+            for s in spans if s["dur"] > 0])
+    per_device: Dict[str, dict] = {}
+    by_scope: Dict[str, float] = {}
+    by_kernel: Dict[str, float] = {}
+    idle_by_span: Dict[str, float] = {}
+    for index in sorted(ops_by_device):
+        ops = ops_by_device[index]
+        if not ops:
+            continue
+        busy = _union((o[1], o[1] + o[2]) for o in ops)
+        window = busy[-1][1] - busy[0][0]
+        per_device[str(index)] = {"window_s": window / 1e9,
+                                  "busy_s": _total(busy) / 1e9}
+        scopes: Dict[str, List[Interval]] = {}
+        kernels: Dict[str, List[Interval]] = {}
+        for name, start, dur, op_name in ops:
+            scopes.setdefault(scope_of(op_name or ""), []).append(
+                (start, start + dur))
+            if name.startswith("kernel:"):
+                kernels.setdefault(re.sub(r"\.\d+$", "", name[7:]),
+                                   []).append((start, start + dur))
+        for found, into in ((scopes, by_scope), (kernels, by_kernel)):
+            for key, intervals in found.items():
+                into[key] = into.get(key, 0.0) \
+                    + _total(_union(intervals)) / 1e9
+        for name, ns in _idle_by_span(busy, segments).items():
+            idle_by_span[name] = idle_by_span.get(name, 0.0) + ns / 1e9
+    n = max(len(per_device), 1)
+    window_s = sum(d["window_s"] for d in per_device.values()) / n
+    busy_s = sum(d["busy_s"] for d in per_device.values()) / n
+
+    def ranked(named: Dict[str, float], scale: float = 1.0) -> dict:
+        return {k: round(v * scale, 6) for k, v in
+                sorted(named.items(), key=lambda kv: -kv[1])}
+
+    return {"devices": len(per_device),
+            "ops": sum(len(ops) for ops in ops_by_device.values()),
+            "window_s": round(window_s, 6), "busy_s": round(busy_s, 6),
+            "idle_s": round(window_s - busy_s, 6),
+            "per_device": per_device,
+            "by_scope": ranked(by_scope), "by_kernel": ranked(by_kernel),
+            # per device, like window_s and busy_s: the three add up
+            "idle_by_span": ranked(idle_by_span, 1.0 / n)}
+
+
+# -- reading the profiler's file ----------------------------------------
+#
+# ``jax.profiler.ProfileData`` gives planes, lines and events, but not an
+# event's *metadata* stats, and the scope (XLA's ``op_name``) of a TPU
+# operation is one of those (``tf_op``).  The file is a protobuf whose
+# schema has been stable for years (tsl/profiler/protobuf/xplane.proto);
+# the few fields read here are decoded directly, and every other field
+# is skipped by its length.  The generated ``xplane_pb2`` is no way in:
+# it ships only inside tensorflow, which this package does not depend
+# on and which a rank that holds the chip must not import.
+
+def _varint(buf: bytes, i: int) -> Tuple[int, int]:
+    result = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        result |= (b & 0x7F) << shift
+        if b < 0x80:
+            return result, i
+        shift += 7
+
+
+def _fields(buf: bytes, lo: int, hi: int):
+    """``(field number, value)`` of the message in ``buf[lo:hi]``: an
+    int for a varint, ``(lo, hi)`` offsets for a length-delimited field,
+    raw bytes for a fixed-width one."""
+    i = lo
+    while i < hi:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        elif wire == 2:
+            n, i = _varint(buf, i)
+            value = (i, i + n)
+            i += n
+        elif wire in (1, 5):
+            n = 8 if wire == 1 else 4
+            value = buf[i:i + n]
+            i += n
+        else:
+            raise ValueError(f"xplane: wire type {wire} at byte {i}")
+        yield key >> 3, value
+
+
+def _text(buf: bytes, span: Tuple[int, int]) -> str:
+    return buf[span[0]:span[1]].decode("utf-8", "replace")
+
+
+def _map_entry(buf: bytes, span: Tuple[int, int]):
+    key, value = 0, None
+    for number, v in _fields(buf, *span):
+        if number == 1:
+            key = v
+        elif number == 2:
+            value = v
+    return key, value
+
+
+def _stat(buf: bytes, span: Tuple[int, int], stat_names: Dict[int, str]):
+    """One XStat -> ``(stat name, value)`` for an integer or a string
+    value; a string may be a reference into the plane's stat names."""
+    name, value = None, None
+    for number, v in _fields(buf, *span):
+        if number == 1:
+            name = stat_names.get(v)
+        elif number in (3, 4):
+            value = v
+        elif number == 5:
+            value = _text(buf, v)
+        elif number == 7:
+            value = stat_names.get(v)
+    return name, value
+
+
+def _plane(buf: bytes, span: Tuple[int, int]) -> dict:
+    plane = {"name": "", "lines": [], "event_md": [], "stat_names": {}}
+    for number, v in _fields(buf, *span):
+        if number == 2:
+            plane["name"] = _text(buf, v)
+        elif number == 3:
+            plane["lines"].append(v)
+        elif number == 4:
+            plane["event_md"].append(v)
+        elif number == 5:
+            key, md = _map_entry(buf, v)
+            for n, w in _fields(buf, *md):
+                if n == 2:
+                    plane["stat_names"][key] = _text(buf, w)
+    return plane
+
+
+def _event_metadata(buf: bytes, plane: dict, want_stat: str
+                    ) -> Dict[int, Tuple[str, Optional[str]]]:
+    """metadata id -> (event name, the ``want_stat`` stat or None).
+    Only that one stat is decoded: a TPU operation's metadata carries a
+    dozen others (its HLO text among them)."""
+    want = {k for k, n in plane["stat_names"].items() if n == want_stat}
+    out = {}
+    for entry in plane["event_md"]:
+        key, md = _map_entry(buf, entry)
+        name, stat = "", None
+        for number, v in _fields(buf, *md):
+            if number == 2:
+                name = _text(buf, v)
+            elif number == 5 and want and stat is None:
+                # XStat.metadata_id is the stat's first field
+                tag, i = _varint(buf, v[0])
+                if tag != 8 or _varint(buf, i)[0] in want:
+                    stat_name, value = _stat(buf, v, plane["stat_names"])
+                    if stat_name == want_stat:
+                        stat = value
+        out[key] = (name, stat)
+    return out
+
+
+def _line_events(buf: bytes, span: Tuple[int, int],
+                 only: Optional[str] = None,
+                 ids: Optional[set] = None) -> list:
+    """A line's events as ``(metadata id, start_ns, dur_ns, [stat
+    spans])``; with ``only``, nothing unless that is the line's name
+    (a device plane's other lines are never decoded); with ``ids``,
+    only the events of those metadata ids (the host plane holds the
+    runtime's own events by the thousand, the marker once)."""
+    name, t0_ns, events = "", 0, []
+    for number, v in _fields(buf, *span):
+        if number == 2:
+            name = _text(buf, v)
+        elif number == 3:
+            t0_ns = v
+        elif number == 4:
+            events.append(v)
+    if only is not None and name != only:
+        return []
+    out = []
+    for ev in events:
+        if ids is not None:
+            # XEvent.metadata_id is the event's first field
+            tag, i = _varint(buf, ev[0])
+            if tag == 8 and _varint(buf, i)[0] not in ids:
+                continue
+        md, offset_ps, dur_ps, stats = 0, 0, 0, []
+        for number, v in _fields(buf, *ev):
+            if number == 1:
+                md = v
+            elif number == 2:
+                offset_ps = v
+            elif number == 3:
+                dur_ps = v
+            elif number == 4:
+                stats.append(v)
+        out.append((md, t0_ns + offset_ps / 1e3, dur_ps / 1e3, stats))
+    return out
+
+
+def read_xplane(path: str) -> Tuple[Dict[int, List[list]],
+                                    Optional[Tuple[float, float]]]:
+    """An ``.xplane.pb`` -> ``(ops_by_device, marker)`` as
+    :func:`reduce_trace` takes them.  Operations are the events of each
+    TPU plane's ``XLA Ops`` line, named by the instruction's own name
+    (``fusion.21``; a Pallas kernel ``kernel:flash_fwd.2``) with the
+    ``op_name`` XLA kept for it; the marker is the
+    :data:`CLOCK_MARKER` annotation on the host plane."""
+    with open(path, "rb") as f:
+        buf = f.read()
+    ops: Dict[int, List[list]] = {}
+    marker = None
+    for number, span in _fields(buf, 0, len(buf)):
+        if number != 1:
+            continue
+        plane = _plane(buf, span)
+        device = _DEVICE_PLANE.match(plane["name"])
+        if device:
+            metadata = _event_metadata(buf, plane, "tf_op")
+            rows = ops.setdefault(int(device.group(1)), [])
+            for line in plane["lines"]:
+                for md, start, dur, _stats in _line_events(buf, line,
+                                                           _OPS_LINE):
+                    text, op_name = metadata.get(md, ("", None))
+                    rows.append([_instruction(text), start, dur,
+                                 op_name or ""])
+        elif plane["name"] == _HOST_PLANE and marker is None:
+            metadata = _event_metadata(buf, plane, "")
+            ids = {k for k, (n, _) in metadata.items()
+                   if n == CLOCK_MARKER}
+            for line in plane["lines"] if ids else ():
+                for md, start, _dur, stats in _line_events(buf, line,
+                                                           ids=ids):
+                    if md not in ids:
+                        continue
+                    for st in stats:
+                        stat_name, value = _stat(buf, st,
+                                                 plane["stat_names"])
+                        if stat_name == "wall_us":
+                            marker = (start, float(value) / 1e6)
+    return ops, marker
+
+
+def _instruction(text: str) -> str:
+    """``%fusion.21 = (...) fusion(...)`` -> ``fusion.21``; a Pallas
+    kernel (a custom call to ``tpu_custom_call``) -> ``kernel:<name>``."""
+    name = text.split(" = ", 1)[0].lstrip("%")
+    return "kernel:" + name if _PALLAS_TARGET in text else name
+
+
+# -- the hook -------------------------------------------------------------
+
+class _JaxProfiler:
+    """``jax.profiler`` behind two calls, so a test can hand
+    :class:`DeviceTrace` a recording instead."""
+
+    def __init__(self):
+        self._dir = None
+
+    def start(self) -> float:
+        import jax  # noqa: PLC0415
+
+        self._dir = tempfile.mkdtemp(prefix="hvdtpu_slice_")
+        jax.profiler.start_trace(self._dir)
+        wall = time.time()
+        with jax.profiler.TraceAnnotation(CLOCK_MARKER,
+                                          wall_us=int(wall * 1e6)):
+            pass
+        return wall
+
+    def stop(self):
+        import jax  # noqa: PLC0415
+
+        try:
+            jax.profiler.stop_trace()
+            found = sorted(glob.glob(os.path.join(
+                self._dir, "plugins", "profile", "*", "*.xplane.pb")))
+            return read_xplane(found[-1]) if found else ({}, None)
+        finally:
+            shutil.rmtree(self._dir, ignore_errors=True)
+            self._dir = None
+
+
+def _profiler_backend():
+    """The profiler to record with, or None where there is no device to
+    trace: on the CPU backend the trace has no device plane, and a rank
+    of the test suite must not pay for one."""
+    import jax  # noqa: PLC0415
+
+    if jax.default_backend() == "cpu":
+        return None
+    return _JaxProfiler()
+
+
+class DeviceTrace:
+    """One bounded slice of device trace, reduced in this process::
+
+        with hvd.obs.profile.device_trace(steps=8) as dt:
+            for batch in batches:
+                state, loss = step(state, batch)
+                if dt.step():       # True once `steps` were counted
+                    break
+        print(dt.result["by_scope"], dt.result["idle_by_span"])
+
+    Leaving the block (or the ``steps``-th ``step()``) stops the
+    profiler and sets ``result`` (:func:`reduce_trace` over the slice
+    and the span ring's spans that overlap it — those of ``lanes``,
+    where given — plus ``t0``/``t1`` on ``time.time()``).  The caller makes the device finish its work
+    (``block_until_ready``) before the end, or the tail is cut.  One
+    slice at a time per process: starting a second one drops the first.
+    On the CPU backend nothing is recorded and ``result`` stays None.
+    """
+
+    _active: Optional["DeviceTrace"] = None
+
+    def __init__(self, steps: int = SLICE_STEPS,
+                 lanes: Optional[Sequence[str]] = None):
+        self.steps = int(steps)
+        # Span lanes (trace ids) that may name an idle gap; None = all.
+        self.lanes = None if lanes is None else set(lanes)
+        self.result: Optional[dict] = None
+        self._left = 0
+        self._profiler = None
+        self._t0 = 0.0
+
+    @property
+    def recording(self) -> bool:
+        return self._profiler is not None
+
+    def start(self) -> bool:
+        """True if a slice is now being recorded."""
+        if DeviceTrace._active is not None:
+            DeviceTrace._active.stop(discard=True)
+        self._profiler = _profiler_backend()
+        if self._profiler is None:
+            return False
+        self._t0 = self._profiler.start()
+        self._left = self.steps
+        DeviceTrace._active = self
+        return True
+
+    def step(self) -> bool:
+        """Count one step; True when this was the slice's last (the
+        slice is then stopped and reduced)."""
+        if self._profiler is None:
+            return False
+        self._left -= 1
+        if self._left > 0:
+            return False
+        self.stop()
+        return True
+
+    def stop(self, discard: bool = False) -> Optional[dict]:
+        profiler, self._profiler = self._profiler, None
+        if profiler is None:
+            return self.result
+        DeviceTrace._active = None
+        t1 = time.time()
+        ops, marker = profiler.stop()
+        if discard:
+            return None
+        t2 = time.time()
+        from . import trace as obs_trace  # noqa: PLC0415
+
+        spans = []
+        if obs_trace.enabled():
+            spans = [s for s in obs_trace.get_buffer().snapshot()
+                     if s["t0"] + s["dur"] >= self._t0 and s["t0"] <= t1
+                     and s["name"] != "device_slice"
+                     and (self.lanes is None or s["trace"] in self.lanes)]
+        self.result = reduce_trace(ops, marker, spans)
+        # what the slice cost after its last step: stopping the
+        # profiler and reading its file, then the reduction
+        self.result.update(t0=self._t0, t1=t1, collect_s=round(t2 - t1, 6),
+                           reduce_s=round(time.time() - t2, 6))
+        return self.result
+
+    def __enter__(self) -> "DeviceTrace":
+        self.start()
+        return self
+
+    def __exit__(self, *_exc) -> None:
+        self.stop()
+
+
+device_trace = DeviceTrace
+
+
+class SliceSchedule:
+    """The serving rank's use of :class:`DeviceTrace`: of every
+    :data:`SLICE_PERIOD` busy steps the last :data:`SLICE_STEPS` are
+    recorded (so the first slice starts warm), each slice reduced in
+    the rank and emitted as ONE ``device_slice`` span on ``lane`` with
+    the reduction in its ``args``.  A slice also ends at the first idle
+    step: a bounded slice never waits for traffic.
+
+    A slice is not free: stopping the profiler and reading its file
+    hold the loop (``collect_s`` in the span), for a time that follows
+    the number of operations recorded (``ops``).  Decode steps are all
+    alike, so a slice is a few steps long, and the spans' own sample
+    rate (``HVDTPU_TRACE_SAMPLE_RATE``) thins the slices as it thins
+    the request lanes: at rate ``r`` one period in ``1/r`` has a slice.
+    Which period is a pure function of (epoch, period, rate), and busy
+    steps are counted from the schedule every rank of a group obeys, so
+    the ranks of a group stop a slice at the same step and pay for it
+    together, not one after the other inside each other's collectives.
+    """
+
+    def __init__(self, lane: str, rate: float = 1.0):
+        self.lane = lane
+        self.rate = rate
+        self.busy_steps = 0
+        self.last: Optional[dict] = None
+        self.failed = False
+        # Request lanes are left out: a request's queue_wait overlaps
+        # other requests' steps and would claim their gaps.
+        self._trace = DeviceTrace(SLICE_STEPS, lanes=(lane, COMPILE_LANE))
+
+    def tick(self, busy: bool, epoch: int, step: int) -> None:
+        """Once per loop iteration, after the step's spans are in the
+        ring.  A profiler that fails (a trace someone else started, a
+        full disk) costs the slices, never the serving loop."""
+        if self.failed:
+            return
+        try:
+            self._tick(busy, epoch, step)
+        except Exception:  # the loop must keep serving
+            from ..utils.logging import get_logger  # noqa: PLC0415
+
+            get_logger("obs.profile").exception(
+                "device slices disabled: the profiler failed")
+            self.failed = True
+
+    def _tick(self, busy: bool, epoch: int, step: int) -> None:
+        from . import trace as obs_trace  # noqa: PLC0415
+
+        if self._trace.recording:
+            if (self._trace.step() if busy else True):
+                self._emit(epoch, step)
+        if not busy:
+            return
+        self.busy_steps += 1
+        period, at = divmod(self.busy_steps, SLICE_PERIOD)
+        if at == SLICE_PERIOD - SLICE_STEPS and obs_trace.sampled(
+                f"device_slice/{epoch}/{period}", self.rate):
+            self._trace.start()
+
+    def _emit(self, epoch: int, step: int) -> None:
+        from . import trace as obs_trace  # noqa: PLC0415
+
+        result = self._trace.stop()
+        if result is None:
+            return
+        self.last = result
+        args = {k: v for k, v in result.items() if k not in ("t0", "t1")}
+        obs_trace.add_span(self.lane, "device_slice", result["t0"],
+                           result["t1"], epoch=epoch, step=step, **args)

@@ -55,7 +55,7 @@ TTFT_COMPONENTS = ("queue_wait", "schedule_broadcast", "admit_wait",
 TPOT_COMPONENTS = ("decode_compute", "scheduler", "stream_publish")
 
 # Step-lane trace ids: aggregate timing lanes, not requests.
-_STEP_TRACES = ("serve.steps", "engine", "overlap")
+_STEP_TRACES = ("serve.steps", "engine", "overlap", "compile")
 
 __all__ = ["load_docs", "merge", "report", "merge_glob", "main",
            "TTFT_COMPONENTS", "TPOT_COMPONENTS", "REPORT_SCHEMA"]

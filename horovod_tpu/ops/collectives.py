@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..basics import DP_AXIS
+from .. import scopes
 
 __all__ = [
     "ReduceOp",
@@ -237,7 +238,8 @@ def allreduce(
     if op == Adasum:
         from .adasum import adasum_allreduce  # noqa: PLC0415
 
-        return adasum_allreduce(tensor, axis_name=axis_name)
+        with jax.named_scope(scopes.ALLREDUCE):
+            return adasum_allreduce(tensor, axis_name=axis_name)
 
     def one(x):
         x = jnp.asarray(x)
@@ -255,7 +257,8 @@ def allreduce(
             y = y * postscale_factor
         return y
 
-    return jax.tree_util.tree_map(one, tensor)
+    with jax.named_scope(scopes.ALLREDUCE):
+        return jax.tree_util.tree_map(one, tensor)
 
 
 def allreduce_(tensor, op: ReduceOp = Average, **kwargs):
@@ -265,6 +268,7 @@ def allreduce_(tensor, op: ReduceOp = Average, **kwargs):
     return allreduce(tensor, op, **kwargs)
 
 
+@jax.named_scope(scopes.ALLREDUCE)  # the packing and unpacking too
 def grouped_allreduce(
     tensors: Sequence,
     op: ReduceOp = Average,

@@ -21,6 +21,11 @@ Design (the standard flash recurrence, TPU-shaped):
   drops into training.
 * Off-TPU (the CPU test mesh) the same kernel runs through the Pallas
   interpreter, so correctness tests don't need TPU hardware.
+* The three ``pallas_call`` sites are named ``flash_fwd``,
+  ``flash_bwd_dkdv`` and ``flash_bwd_dq``: XLA calls the compiled
+  instruction after the name (``flash_fwd.2``), so a device trace tells
+  forward, dk/dv and dq apart and a later Pallas kernel is not counted
+  as attention.
 """
 
 from __future__ import annotations
@@ -247,6 +252,7 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o, lse_wide[:, :, 0]
 
@@ -396,6 +402,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dkdv",
     )(q, k, v, o, do, lse_w)
     (dq,) = pl.pallas_call(
         kernel_dq,
@@ -415,6 +422,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, o, do, lse_w)
     return dq, dk, dv
 

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from .. import scopes
 from ..basics import DP_AXIS, global_topology, mesh as build_mesh
 from ..ops.collectives import (
     Adasum,
@@ -125,6 +126,10 @@ def DistributedGradientTransform(
         del params
         return optax.EmptyState()
 
+    # One scope over the reduction with its packing and unpacking: in a
+    # device trace the fused buffers' concatenates and slices are the
+    # reduction's cost, not the optimizer's.
+    @jax.named_scope(scopes.GRAD_ALLREDUCE)
     def update_fn(updates, state, params=None):
         del params
         from ..ops.sparse import (  # noqa: PLC0415
@@ -222,6 +227,14 @@ def DistributedGradientTransform(
     return optax.GradientTransformation(init_fn, update_fn)
 
 
+def _scoped(tx: optax.GradientTransformation, scope: str):
+    """``tx`` with its update traced under ``jax.named_scope(scope)``.
+    Metadata only: the state, its tree and the numbers are ``tx``'s."""
+    tx = optax.with_extra_args_support(tx)
+    return optax.GradientTransformationExtraArgs(
+        tx.init, jax.named_scope(scope)(tx.update))
+
+
 def DistributedOptimizer(
     optimizer: optax.GradientTransformation,
     *,
@@ -247,7 +260,7 @@ def DistributedOptimizer(
             compression=compression,
             gradient_predivide_factor=gradient_predivide_factor,
         ),
-        optimizer,
+        _scoped(optimizer, scopes.OPTIMIZER_UPDATE),
     )
     if backward_passes_per_step > 1:
         tx = optax.MultiSteps(tx, every_k_schedule=backward_passes_per_step)

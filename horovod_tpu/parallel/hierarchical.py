@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import scopes
 from ..basics import CROSS_AXIS, LOCAL_AXIS
 from ..ops.collectives import Average, ReduceOp, Sum, axis_size
 
@@ -161,7 +162,8 @@ def hierarchical_allreduce(
             out = out / (local_n * axis_size(cross_axis))
         return out
 
-    return jax.tree_util.tree_map(one, tensor)
+    with jax.named_scope(scopes.ALLREDUCE):
+        return jax.tree_util.tree_map(one, tensor)
 
 
 def hierarchical_adasum(

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import scopes
+
 __all__ = ["stack_tp_params", "unstack_tp_params", "tp_gpt_apply"]
 
 
@@ -211,11 +213,13 @@ def _gpt_embed(rep, cfg, tokens, pos_offset, positions):
                 "(zigzag_positions(axis_index, P, s_local))"
             )
         positions = pos_offset + jnp.arange(s)
-    x = jnp.take(rep["wte"]["embedding"], tokens, axis=0).astype(cfg.dtype)
-    if cfg.pos_embedding == "learned":
-        pos = jnp.take(rep["wpe"], positions, axis=0,
-                       mode="fill", fill_value=jnp.nan)
-        x = x + pos.astype(cfg.dtype)[None]
+    with jax.named_scope(scopes.EMBED):
+        x = jnp.take(rep["wte"]["embedding"], tokens,
+                     axis=0).astype(cfg.dtype)
+        if cfg.pos_embedding == "learned":
+            pos = jnp.take(rep["wpe"], positions, axis=0,
+                           mode="fill", fill_value=jnp.nan)
+            x = x + pos.astype(cfg.dtype)[None]
     rope_tabs = None
     if cfg.pos_embedding == "rope":
         from ..ops.rope import rope_tables  # noqa: PLC0415
@@ -224,6 +228,7 @@ def _gpt_embed(rep, cfg, tokens, pos_offset, positions):
     return x, positions, rope_tabs
 
 
+@jax.named_scope(scopes.HEAD)
 def _gpt_head(rep, cfg, x):
     """Shared replicated epilogue: final LN + LM head, fp32 logits."""
     from ..models.transformer import raw_layer_norm  # noqa: PLC0415

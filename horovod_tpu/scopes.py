@@ -1,0 +1,29 @@
+"""The names the program gives its own work in a device trace.
+
+Every ``jax.named_scope`` of the package takes its name from here, and
+the device-trace reduction (``obs/profile.py:scope_of``) files an
+operation under the innermost of :data:`SCOPES` in its ``op_name``: a
+scope that is not in this module would silently be filed under a flax
+module's name.  Metadata only: a scope is never a flax submodule, so
+parameter trees and checkpoints do not know these names.
+
+No imports: the models, the collectives and the launcher-side obs plane
+all read this module.
+"""
+
+GRAD_ALLREDUCE = "grad_allreduce"      # DistributedOptimizer: the reduction
+                                       # with its packing and unpacking
+ALLREDUCE = "allreduce"                # every traced allreduce, whatever XLA
+                                       # calls it (all-reduce.81, psum.220)
+OPTIMIZER_UPDATE = "optimizer_update"  # the wrapped optimizer's update
+ATTN = "attn"                          # a block's attention half
+MLP = "mlp"                            # a block's MLP half
+EMBED = "embed"                        # token and position embedding
+HEAD = "head"                          # final norm and output projection
+STEM = "stem"                          # ResNet's first conv and pool
+KV_GATHER = "kv_gather"                # paged decode: pages -> contiguous KV
+KV_SCATTER = "kv_scatter"              # paged prefill: KV -> pages
+SAMPLE = "sample"                      # the token pick
+
+SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLP, EMBED,
+          HEAD, STEM, KV_GATHER, KV_SCATTER, SAMPLE)

@@ -32,6 +32,8 @@ import zlib
 import jax
 import jax.numpy as jnp
 
+from .. import scopes
+
 __all__ = ["request_key", "token_key", "sample_token", "KEY_SHAPE"]
 
 # Raw key width: old-style jax PRNG keys are uint32[2]; the engine
@@ -49,11 +51,13 @@ def request_key(seed: int, rid: str):
     return jax.random.fold_in(jax.random.PRNGKey(int(seed)), rid_tag)
 
 
+@jax.named_scope(scopes.SAMPLE)
 def token_key(base, emission_index):
     """Key for the request's ``emission_index``-th generated token."""
     return jax.random.fold_in(base, emission_index)
 
 
+@jax.named_scope(scopes.SAMPLE)  # the token pick, by name in a device trace
 def sample_token(logits, temperature, top_k, key):
     """One token from one row of logits — greedy when ``temperature <=
     0``, else top-k-truncated temperature sampling via the Gumbel-max

@@ -57,6 +57,7 @@ from ..obs import goodput as obs_goodput
 from ..obs import memplane
 from ..obs import progress as obs_progress
 from ..obs import slo as obs_slo
+from ..obs import profile as obs_profile
 from ..obs import trace as obs_trace
 from ..testing.faults import maybe_fail
 from ..utils.logging import get_logger
@@ -484,6 +485,12 @@ def _serve_epoch(ctx, engine, spec: dict, totals: Dict[str, Any],
     scope = _epoch_scope(epoch)
     tracing = obs_trace.enabled()
     t_rate = obs_trace.sample_rate()
+    # The program's profiler hook (obs/profile.py), armed by the same
+    # switch and thinned by the same sample rate as the spans: a few
+    # decode steps of device trace out of every 128 busy ones, each
+    # slice a ``device_slice`` span on the step lane.
+    slices = (obs_profile.SliceSchedule("serve.steps", t_rate)
+              if tracing else None)
 
     # Epoch-start recovery broadcast: the group leader's replay of the
     # durable request record IS the schedule seed — every rank of the
@@ -907,7 +914,8 @@ def _serve_epoch(ctx, engine, spec: dict, totals: Dict[str, Any],
                 # validation plus same-step earlier prefills, and
                 # prefill is the engine.admit call whose argmax IS the
                 # first token (first-decode is folded into prefill on
-                # the greedy slot engine).
+                # the greedy slot engine): the span's end is the instant
+                # the first token was picked, on the server's clock.
                 # The ingest pump appends concurrently with this loop,
                 # so an arrival can land INSIDE (t_step0, t_sched]:
                 # schedule_broadcast must then start at the arrival,
@@ -1142,6 +1150,15 @@ def _serve_epoch(ctx, engine, spec: dict, totals: Dict[str, Any],
             # same step they start firing.
             slo_plane.publish(reg, t_step1)
         obs_progress.tick()
+        if tracing:
+            if busy:
+                # The loop's tail after the whole-step span (gauges, KV
+                # occupancy with its one small device read, goodput and
+                # SLO accounting): host time the device may be waiting
+                # on, so it has a name in a device slice too.
+                obs_trace.add_span("serve.steps", "bookkeeping", t_step1,
+                                   time.time(), epoch=epoch, step=step)
+            slices.tick(busy, epoch, step)
 
         if sdoc["stop"] and sched.idle():
             LOG.info("serving drained at epoch %d step %d", epoch, step)
@@ -1201,6 +1218,11 @@ def _serve_epoch(ctx, engine, spec: dict, totals: Dict[str, Any],
                 out["weight_version"] = swap.version
             if profiler is not None:
                 out["perf"] = profiler.summary()
+            # What this rank compiled, and whether the cache had it: a
+            # recompile in the middle of a serving day shows here first.
+            out["compile"] = obs_profile.compile_summary()
+            if slices is not None and slices.last is not None:
+                out["device_slice"] = slices.last
             # The rank's memory story rides the drain summary so a
             # `bench.py --serve` record embeds a WORKER-side breakdown
             # (census + per-program compiled bytes + the pool the KV

@@ -6,6 +6,10 @@ One rule, shared by every entry point that compiles for the chip
 directory is set in code; otherwise the cache is ``<checkout>/.jax_cache``
 (git-ignored).  The path is part of the cache key, so it is never a
 temporary name, a pid or a time.
+
+The same call arms the program's compile log
+(``horovod_tpu.obs.profile.compile_log()``): what was traced, lowered
+and compiled, for how long, and whether the cache had it.
 """
 
 from __future__ import annotations
@@ -40,6 +44,13 @@ def enable_compile_cache() -> Optional[str]:
     of an entry it wrote itself."""
     import jax  # noqa: PLC0415 — importers of utils must stay off jax
 
+    from ..obs.profile import install_compile_listener  # noqa: PLC0415
+
+    # Every entry point calls this before its first compile, so this is
+    # where the compile log (obs/profile.py: program, phase, seconds,
+    # cache hit or miss) starts listening — on the CPU too, where only
+    # the cache itself is left alone.
+    install_compile_listener()
     if jax.config.jax_platforms == "cpu":
         return None
     cache_dir, set_in_code = resolve_cache_dir()

@@ -1,74 +1,26 @@
 #!/usr/bin/env python
-"""Capture a jax.profiler trace of the bench step and summarize hot ops.
+"""Where the device's time goes in the bench step, by the program's names.
 
-Dev tool for the perf push (VERDICT r2 item 1). Writes the raw xplane to
---out (default /tmp/hvdtpu_trace) and prints a per-op-category time
-breakdown parsed from the xplane proto.
+Builds the exact step ``bench.py`` times, runs ``--iters`` steps of it
+under ``horovod_tpu.obs.profile.device_trace`` and prints the reduction
+as JSON: window and busy seconds, device time by scope (``attn``,
+``mlp``, ``grad_allreduce``, ``optimizer_update``, ...) and by Pallas
+kernel (``flash_fwd``, ``flash_bwd_dkdv``, ``flash_bwd_dq``), idle time
+by span.  Needs the chip: on the CPU backend nothing is recorded.
 """
 
 from __future__ import annotations
 
 import argparse
-import glob
-import gzip
 import json
 import os
 import sys
-from collections import defaultdict
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def summarize_xplane(logdir: str) -> None:
-    paths = glob.glob(
-        os.path.join(logdir, "**", "*.trace.json.gz"), recursive=True
-    )
-    if not paths:
-        print("no trace.json.gz found under", logdir)
-        return
-    path = max(paths, key=os.path.getmtime)
-    with gzip.open(path, "rt") as f:
-        trace = json.load(f)
-    events = trace.get("traceEvents", [])
-    pid_names = {}
-    tid_names = {}
-    for e in events:
-        if e.get("ph") == "M" and e.get("name") == "process_name":
-            pid_names[e["pid"]] = e["args"]["name"]
-        if e.get("ph") == "M" and e.get("name") == "thread_name":
-            tid_names[(e["pid"], e["tid"])] = e["args"]["name"]
-    # Find TPU device pids (XLA op lines)
-    by_name = defaultdict(float)
-    total = 0.0
-    for e in events:
-        if e.get("ph") != "X":
-            continue
-        pname = pid_names.get(e.get("pid"), "")
-        tname = tid_names.get((e.get("pid"), e.get("tid")), "")
-        # keep only the device XLA-op line
-        if "tpu" not in pname.lower() or "XLA Ops" not in tname:
-            continue
-        dur = e.get("dur", 0) / 1e3  # us -> ms
-        by_name[e["name"]] += dur
-        total += dur
-    print(f"== XLA op time by name (total {total:.2f} ms across trace) ==")
-    items = sorted(by_name.items(), key=lambda kv: -kv[1])
-    # group by fusion-category prefix
-    by_cat = defaultdict(float)
-    for name, dur in items:
-        cat = name.split(".")[0].rstrip("0123456789")
-        by_cat[cat] += dur
-    print("-- by category --")
-    for cat, dur in sorted(by_cat.items(), key=lambda kv: -kv[1])[:20]:
-        print(f"{dur:10.2f} ms  {100*dur/total:5.1f}%  {cat}")
-    print("-- top 30 ops --")
-    for name, dur in items[:30]:
-        print(f"{dur:10.2f} ms  {100*dur/total:5.1f}%  {name}")
-
-
 def main() -> int:
     parser = argparse.ArgumentParser()
-    parser.add_argument("--out", default="/tmp/hvdtpu_trace")
     parser.add_argument("--model", default="resnet50",
                         choices=["resnet50", "resnet101", "resnet18",
                                  "vgg16", "vgg19", "inception3",
@@ -83,14 +35,11 @@ def main() -> int:
     parser.add_argument("--flash-block-q", type=int, default=512)
     parser.add_argument("--flash-block-k", type=int, default=256)
     parser.add_argument("--iters", type=int, default=5)
-    parser.add_argument("--summarize-only", action="store_true")
     args = parser.parse_args()
 
-    if args.summarize_only:
-        summarize_xplane(args.out)
-        return 0
-
     import jax
+
+    from horovod_tpu.obs.profile import device_trace
 
     # the EXACT steps bench.py times
     from bench import build_gpt_step, build_step
@@ -113,13 +62,16 @@ def main() -> int:
     for _ in range(3):
         *carry, loss = step(*carry, *const)
     float(loss)
-    jax.profiler.start_trace(args.out)
-    for _ in range(args.iters):
-        *carry, loss = step(*carry, *const)
-    float(loss)
-    jax.profiler.stop_trace()
-    print("trace written to", args.out)
-    summarize_xplane(args.out)
+    with device_trace(steps=args.iters) as trace:
+        for _ in range(args.iters):
+            *carry, loss = step(*carry, *const)
+        float(loss)
+    if trace.result is None:
+        print("no device to trace on backend", jax.default_backend(),
+              file=sys.stderr)
+        return 3
+    trace.result["steps"] = args.iters
+    print(json.dumps(trace.result, indent=1))
     return 0
 
 
