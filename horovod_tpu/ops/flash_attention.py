@@ -14,11 +14,24 @@ Design (the standard flash recurrence, TPU-shaped):
   peak memory is O(block_q*d + block_k*d), independent of S.
 * fp32 accumulators regardless of input dtype (bf16 in, bf16 out, fp32
   softmax state — the MXU-native mixed precision).
+* Every kernel forms its score tile TRANSPOSED (``k @ q.T``: keys on
+  sublanes, queries on lanes), so a per-query statistic (running max
+  ``m``, running sum ``l``, the saved logsumexp, the backward's
+  ``delta``) is one lane of a ``(1, block_q)`` row: it is reduced over
+  sublanes with elementwise work, broadcast back the same way, and
+  crosses HBM as ``[Z, S]``, a 2 KB row per Q tile.  Nothing in a tile
+  loop goes through the cross-lane unit and no score-sized tile is
+  ever transposed.  On a TPU v5e at 128 x 1024 x 64, bf16, causal,
+  512 x 256 tiles, per call (chip runs of PR 26): forward 1.434 ms with
+  the statistics as lane-replicated ``(block_q, 128)`` columns reduced
+  and re-broadcast per tile, 0.750 with the columns reduced once per
+  row block, 0.633 as rows; dk/dv 1.210 -> 0.973, dq 0.860 -> 0.679.
 * Causal programs stop their K loop at the diagonal tile — the upper
   triangle is never computed, not just masked.
-* Backward is a blockwise recompute from the saved logsumexp (scan over
-  K tiles, O(S * block_k) live), wired via ``jax.custom_vjp`` so the op
-  drops into training.
+* Backward is a blockwise recompute from the saved logsumexp in two
+  Pallas kernels (dk/dv, dq), wired via ``jax.custom_vjp`` so the op
+  drops into training.  ``delta = rowsum(do * o)`` is computed once per
+  call, outside the kernels (0.025 ms there), not once per tile.
 * Off-TPU (the CPU test mesh) the same kernel runs through the Pallas
   interpreter, so correctness tests don't need TPU hardware.
 * The three ``pallas_call`` sites are named ``flash_fwd``,
@@ -40,6 +53,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(jnp.finfo(jnp.float32).min) / 2
+# dot_general numbers for a.T @ b: contract the rows (the keys) of both.
+_CONTRACT_ROWS = (((0,), (0,)), ((), ()))
 
 
 def _pick_block(seq: int, want: int) -> int:
@@ -161,18 +176,15 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
 
     K tiles live on the innermost grid dimension, so only (1, bk, d) of K
     and V are resident per step — VMEM peak is O(bq*d + bk*d), independent
-    of S (the long-context requirement).  The online-softmax state (acc,
-    m, l) persists across the sequential K dimension in VMEM scratch and
-    is flushed to the output block at the last K tile.  GQA/MQA: k/v have
-    Z_kv = batch*hkv rows; the index map routes each q head to its group.
+    of S (the long-context requirement).  The online-softmax state (acc
+    [d, bq], m and l [1, bq]: transposed like the tile) persists across
+    the sequential K dimension in VMEM scratch and is flushed to the
+    output block at the last K tile; lse leaves as one row per Q tile.
+    GQA/MQA: k/v have Z_kv = batch*hkv rows; the index map routes each q
+    head to its group.
     """
     z, s, d = q.shape
     nq, nk = s // bq, s // bk
-
-    # Mosaic requires the last two block dims to be (8k, 128k) or full —
-    # scalars-per-row state therefore rides a broadcast 128-lane dim, the
-    # same layout the public jax TPU flash kernel uses (MIN_BLOCK_SIZE).
-    LANES = 128
 
     def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref):
         i = pl.program_id(1)
@@ -195,37 +207,41 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
 
         @pl.when(needed)
         def _compute():
+            # the tile transposed: keys on sublanes, queries on lanes, so
+            # a query's statistic is one lane of a (1, bq) row.  A row
+            # whose keys are all masked so far sums placeholders (p = 1)
+            # that corr wipes at its first live tile.
             qb = q_ref[0].astype(jnp.float32) * scale  # [bq, d]
             kb = k_ref[0].astype(jnp.float32)          # [bk, d]
             vb = v_ref[0].astype(jnp.float32)
-            st = jnp.dot(qb, kb.T, preferred_element_type=jnp.float32)
+            st = jnp.dot(kb, qb.T, preferred_element_type=jnp.float32)
             if causal:
-                q_pos = i * bq + lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 0
-                )
                 k_pos = j * bk + lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 1
+                    jnp.int32, (bk, bq), 0
+                )
+                q_pos = i * bq + lax.broadcasted_iota(
+                    jnp.int32, (bk, bq), 1
                 )
                 st = jnp.where(k_pos > q_pos, NEG_INF, st)
                 if window is not None:
                     st = jnp.where(k_pos < q_pos - (window - 1),
                                    NEG_INF, st)
-            m_prev = m_ref[...]                       # [bq, LANES], lanes equal
-            m_new = jnp.maximum(m_prev, st.max(-1)[:, None])
-            p = jnp.exp(st - m_new[:, :1])
+            m_prev = m_ref[...]                        # [1, bq]
+            m_new = jnp.maximum(m_prev, st.max(0, keepdims=True))
+            p = jnp.exp(st - m_new)
             corr = jnp.exp(m_prev - m_new)
-            l_ref[...] = l_ref[...] * corr + p.sum(-1)[:, None]
-            acc_ref[...] = acc_ref[...] * corr[:, :1] + jnp.dot(
-                p, vb, preferred_element_type=jnp.float32
-            )
+            l_ref[...] = l_ref[...] * corr + p.sum(0, keepdims=True)
+            acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
+                vb, p, _CONTRACT_ROWS, preferred_element_type=jnp.float32,
+            )                                          # [d, bq]
             m_ref[...] = m_new
 
         @pl.when(j == nk - 1)
         def _flush():
-            o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
-            lse_ref[0] = m_ref[...] + jnp.log(l_ref[...])
+            o_ref[0] = (acc_ref[...] / l_ref[...]).T.astype(o_ref.dtype)
+            lse_ref[0, 0] = m_ref[...] + jnp.log(l_ref[...])
 
-    o, lse_wide = pl.pallas_call(
+    o, lse = pl.pallas_call(
         kernel,
         grid=(z, nq, nk),
         in_specs=[
@@ -237,16 +253,16 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda zi, qi, ki: (zi, qi, 0)),
-            pl.BlockSpec((1, bq, LANES), lambda zi, qi, ki: (zi, qi, 0)),
+            pl.BlockSpec((1, 1, 1, bq), lambda zi, qi, ki: (zi, qi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((z, s, d), q.dtype),
-            jax.ShapeDtypeStruct((z, s, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((z, nq, 1, bq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),       # acc
-            pltpu.VMEM((bq, LANES), jnp.float32),   # running max m
-            pltpu.VMEM((bq, LANES), jnp.float32),   # running sum l
+            pltpu.VMEM((d, bq), jnp.float32),   # acc
+            pltpu.VMEM((1, bq), jnp.float32),   # running max m
+            pltpu.VMEM((1, bq), jnp.float32),   # running sum l
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -254,7 +270,7 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
-    return o, lse_wide[:, :, 0]
+    return o, lse.reshape(z, s)
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
@@ -268,7 +284,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
     tile) pairs stream sequentially; dk/dv accumulate in VMEM scratch —
     under GQA the whole group's contribution folds into one kv row — and
     flush at the last pair.
-    Pass B (grid z, nq, nk): Q tile fixed, K tiles stream; dq accumulates.
+    Pass B (grid z, nq, nk): Q tile fixed, K tiles stream; dq accumulates
+    (as [d, bq], turned once at the flush).
     Both recompute P from the forward's saved logsumexp; ``delta`` =
     rowsum(do*o) is the standard softmax-backward correction.
     """
@@ -277,38 +294,43 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
     group = h // hkv
     nq, nk = s // bq, s // bk
     f32 = jnp.float32
-    LANES = 128
-    # lse rides the same broadcast 128-lane layout as the forward's
-    # softmax state (and the public jax TPU kernel's l/m blocks): Mosaic
-    # requires the last two block dims to be (8k, 128k) or full, which a
-    # narrow (1, bq) block over [Z, S] violates on hardware.  delta
-    # (rowsum(do*o)) needs no such array: it is recomputed per tile from
-    # the o tile, which is cheaper than streaming a (Z, S, 128) f32
-    # broadcast through HBM twice.
-    lse_w = jnp.broadcast_to(lse[:, :, None], (z, s, LANES))
+    # delta is computed once per call and shared by both kernels, which
+    # read it and lse as (1, bq) rows of a [Z, nq, 1, bq] view (a block
+    # equal to the last two dims is legal for any bq).  What the chip
+    # said of the alternatives (PR 26, per call at 128 x 1024 x 64):
+    # recomputing delta per tile from an o tile cost a multiply and a
+    # cross-lane sum per tile and one more 64 KB DMA per grid step;
+    # delta and lse as [Z, S, 128] lane-replicated arrays took that work
+    # out of the kernels and gave it back as 256 KB DMAs per dk/dv grid
+    # step and a second 64 MiB broadcast (kernels 2.069 -> 2.085 ms, the
+    # whole backward 2.687 -> 2.769); as rows 1.652 and 2.062.
+    delta = (do.astype(f32) * o.astype(f32)).sum(-1)
+    lse_r, delta_r = (x.reshape(z, nq, 1, bq) for x in (lse, delta))
 
-    def _recompute_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, i, j):
+    def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                        i, j):
         """The shared backward recurrence: rebuild this tile's softmax P
-        from the saved logsumexp and form dS = P * (dP - delta).  One
-        definition for both passes so the mask/scale math cannot drift."""
+        from the saved logsumexp and form dS = P * (dP - delta), both
+        transposed like the forward's tile (keys on sublanes, queries
+        on lanes).  One definition for both passes so the mask/scale
+        math cannot drift."""
         qb = q_ref[0].astype(f32)
         kb = k_ref[0].astype(f32)
         vb = v_ref[0].astype(f32)
         dob = do_ref[0].astype(f32)
-        delta_col = (dob * o_ref[0].astype(f32)).sum(-1)[:, None]
-        st = jnp.dot(qb, kb.T, preferred_element_type=f32) * scale
-        p = jnp.exp(st - lse_ref[0][:, :1])
+        st = jnp.dot(kb, qb.T, preferred_element_type=f32) * scale
+        p = jnp.exp(st - lse_ref[0, 0])
         if causal:
-            q_pos = i * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            k_pos = j * bk + lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+            q_pos = i * bq + lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
             p = jnp.where(k_pos > q_pos, 0.0, p)
             if window is not None:
                 p = jnp.where(k_pos < q_pos - (window - 1), 0.0, p)
-        dp = jnp.dot(dob, vb.T, preferred_element_type=f32)
-        ds = p * (dp - delta_col)
+        dp = jnp.dot(vb, dob.T, preferred_element_type=f32)
+        ds = p * (dp - delta_ref[0, 0])
         return qb, kb, dob, p, ds
 
-    def kernel_dkdv(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+    def kernel_dkdv(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc):
         j = pl.program_id(1)
         t = pl.program_id(2)          # (q head in group) * nq + (q tile)
@@ -330,10 +352,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
         @pl.when(needed)
         def _compute():
             qb, _, dob, p, ds = _recompute_p_ds(
-                q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, i, j
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, j
             )
-            dv_acc[...] += jnp.dot(p.T, dob, preferred_element_type=f32)
-            dk_acc[...] += jnp.dot(ds.T, qb,
+            dv_acc[...] += jnp.dot(p, dob, preferred_element_type=f32)
+            dk_acc[...] += jnp.dot(ds, qb,
                                    preferred_element_type=f32) * scale
 
         @pl.when(t == nq * group - 1)
@@ -341,7 +363,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
             dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
-    def kernel_dq(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+    def kernel_dq(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                   dq_ref, dq_acc):
         i = pl.program_id(1)
         j = pl.program_id(2)
@@ -359,17 +381,18 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
         @pl.when(needed)
         def _compute():
             _, kb, _, _, ds = _recompute_p_ds(
-                q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, i, j
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, j
             )
-            dq_acc[...] += jnp.dot(ds, kb,
-                                   preferred_element_type=f32) * scale
+            dq_acc[...] += lax.dot_general(
+                kb, ds, _CONTRACT_ROWS, preferred_element_type=f32,
+            ) * scale                                   # [d, bq]
 
         @pl.when(j == nk - 1)
         def _flush():
-            dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+            dq_ref[0] = dq_acc[...].T.astype(dq_ref.dtype)
 
     qkv_spec = lambda tile, which: pl.BlockSpec((1, tile, d), which)
-    lane_spec = lambda which: pl.BlockSpec((1, bq, LANES), which)
+    stat_spec = lambda which: pl.BlockSpec((1, 1, 1, bq), which)
 
     def _qrow(zi, ti):
         """Pass-A q row for kv row ``zi`` and inner step ``ti``."""
@@ -383,8 +406,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
             qkv_spec(bk, lambda zi, ji, ti: (zi, ji, 0)),   # k
             qkv_spec(bk, lambda zi, ji, ti: (zi, ji, 0)),   # v
             qkv_spec(bq, lambda zi, ji, ti: (_qrow(zi, ti), ti % nq, 0)),
-            qkv_spec(bq, lambda zi, ji, ti: (_qrow(zi, ti), ti % nq, 0)),
-            lane_spec(lambda zi, ji, ti: (_qrow(zi, ti), ti % nq, 0)),
+            stat_spec(lambda zi, ji, ti: (_qrow(zi, ti), ti % nq, 0, 0)),
+            stat_spec(lambda zi, ji, ti: (_qrow(zi, ti), ti % nq, 0, 0)),
         ],
         out_specs=[
             qkv_spec(bk, lambda zi, ji, ti: (zi, ji, 0)),
@@ -403,7 +426,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
         ),
         interpret=interpret,
         name="flash_bwd_dkdv",
-    )(q, k, v, o, do, lse_w)
+    )(q, k, v, do, lse_r, delta_r)
     (dq,) = pl.pallas_call(
         kernel_dq,
         grid=(z, nq, nk),
@@ -412,18 +435,18 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
             qkv_spec(bk, lambda zi, ii, ji: (_kv_row(zi, h, hkv), ji, 0)),
             qkv_spec(bk, lambda zi, ii, ji: (_kv_row(zi, h, hkv), ji, 0)),
             qkv_spec(bq, lambda zi, ii, ji: (zi, ii, 0)),
-            qkv_spec(bq, lambda zi, ii, ji: (zi, ii, 0)),
-            lane_spec(lambda zi, ii, ji: (zi, ii, 0)),
+            stat_spec(lambda zi, ii, ji: (zi, ii, 0, 0)),
+            stat_spec(lambda zi, ii, ji: (zi, ii, 0, 0)),
         ],
         out_specs=[qkv_spec(bq, lambda zi, ii, ji: (zi, ii, 0))],
         out_shape=[jax.ShapeDtypeStruct((z, s, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((bq, d), f32)],
+        scratch_shapes=[pltpu.VMEM((d, bq), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="flash_bwd_dq",
-    )(q, k, v, o, do, lse_w)
+    )(q, k, v, do, lse_r, delta_r)
     return dq, dk, dv
 
 

@@ -464,3 +464,100 @@ class TestSlidingWindow:
                   attention_impl="reference", attention_window=8)
         with pytest.raises(ValueError, match="flash-only"):
             ref.apply(params, toks)
+
+
+# (id, causal, window, q heads, kv heads, block_q, block_k) at S=256, d=64:
+# at least 2 Q tiles and 4 K tiles everywhere.
+_ROW_STAT_CASES = [
+    ("noncausal", False, None, 2, 2, 128, 64),
+    ("causal", True, None, 2, 2, 128, 64),
+    ("window96", True, 96, 2, 2, 128, 64),
+    ("mqa_4_on_1", True, None, 4, 1, 128, 64),
+    ("mqa_4_on_1_noncausal", False, None, 4, 1, 128, 64),
+    ("window24_under_a_k_tile", True, 24, 2, 2, 128, 64),
+    ("causal_4x8_tiles", True, None, 2, 2, 64, 32),
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "causal,window,h,hkv,bq,bk", [c[1:] for c in _ROW_STAT_CASES],
+    ids=[c[0] for c in _ROW_STAT_CASES],
+)
+def test_row_statistics_across_tiles(causal, window, h, hkv, bq, bk, dtype):
+    """The per-query statistics (running max and sum, the saved
+    logsumexp, the backward's delta) over several K tiles: scores GROW
+    along the key axis, so the running max changes in every tile and
+    every earlier partial sum is rescaled each time.  Output, saved lse
+    and dq/dk/dv against float32 references on the same inputs."""
+    from horovod_tpu.ops.flash_attention import _flash_fwd_kernel
+
+    b, s, d = 1, 256, 64
+    rng = np.random.RandomState(5)
+    f32 = jnp.float32
+    # q positive, k a ramp along the sequence: q.k rises by about 3 per
+    # 64 keys after the 1/8 scale
+    q = jnp.asarray(0.5 + 0.3 * np.abs(rng.randn(b, s, h, d)), dtype)
+    ramp = (np.arange(s) / s)[None, :, None, None]
+    k = jnp.asarray(2.0 * ramp + 0.1 * rng.randn(b, s, hkv, d), dtype)
+    v = jnp.asarray(rng.randn(b, s, hkv, d), dtype)
+    wgt = jnp.asarray(rng.randn(b, s, h, d), f32)
+    qf, kf, vf = (x.astype(f32) for x in (q, k, v))
+    rep = lambda t: jnp.repeat(t, h // hkv, axis=2)
+    scale = d ** -0.5
+
+    def scores(q, k):
+        st = jnp.einsum("bqhd,bkhd->bhqk", q, rep(k)) * scale
+        q_pos = jnp.arange(s)[:, None]
+        k_pos = jnp.arange(s)[None, :]
+        if causal:
+            st = jnp.where(k_pos > q_pos, -jnp.inf, st)
+        if window is not None:
+            st = jnp.where(k_pos < q_pos - (window - 1), -jnp.inf, st)
+        return st
+
+    def reference(q, k, v):
+        if window is None:
+            return local_attention(q, rep(k), rep(v), causal=causal)
+        p = jax.nn.softmax(scores(q, k), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, rep(v))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               block_q=bq, block_k=bk)
+
+    loss = lambda f: lambda *a: (f(*a).astype(f32) * wgt).sum()
+    out = flash(q, k, v)
+    want = reference(qf, kf, vf)
+    got_g = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want_g = jax.grad(loss(reference), argnums=(0, 1, 2))(qf, kf, vf)
+
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, s, d)
+    _, lse = _flash_fwd_kernel(fold(q), fold(k), fold(v), causal, scale,
+                               bq, bk, h, hkv, window, True)
+    want_lse = jax.nn.logsumexp(scores(qf, kf), axis=-1).reshape(-1, s)
+    # the running max moved in every K tile of the last row
+    last_row = np.asarray(scores(qf, kf))[0, 0, -1]
+    tile_max = last_row.reshape(-1, bk).max(-1)
+    live = np.isfinite(tile_max)
+    assert live.sum() >= (1 if window else 4)
+    assert np.all(np.diff(tile_max[live]) > 0)
+
+    # float32: rounding of sums only (seen: 4e-7 out, 3e-6 gradients);
+    # bfloat16: the outputs, and the o that the backward's delta reads,
+    # are rounded to 8 bits of mantissa (seen: 0.004 out, 0.017 dq)
+    tol, grad_tol = (5e-6, 2e-5) if dtype == jnp.float32 else (1e-2, 3e-2)
+    assert out.dtype == dtype and lse.dtype == f32
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               atol=2e-5, rtol=2e-5)
+
+    def close(name, a, r, tol):
+        a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
+        assert a.shape == r.shape, name
+        err = np.abs(a - r).max() / np.abs(r).max()
+        assert err <= tol, f"{name}: {err:.3g} of the largest entry"
+
+    close("out", out, want, tol)
+    for name, a, r in zip(("dq", "dk", "dv"), got_g, want_g):
+        close(name, a, r, grad_tol)
