@@ -63,8 +63,13 @@ def logits(config, params, images):
         return x @ p["head"]["kernel"] + p["head"]["bias"]
 
 
-def loss(config, params, batch):
+def logprob(config, params, batch):
+    """Log-probability of each image's label: float32 [n]."""
     lg = logits(config, params, batch["images"])
     logp = jax.nn.log_softmax(lg, axis=-1)
-    return -jnp.take_along_axis(
-        logp, batch["labels"][:, None], axis=-1).mean()
+    return jnp.take_along_axis(
+        logp, batch["labels"][:, None], axis=-1)[:, 0]
+
+
+def loss(config, params, batch):
+    return -logprob(config, params, batch).mean()

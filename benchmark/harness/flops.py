@@ -6,6 +6,8 @@ operations never count), so a utilisation derived from them cannot be
 raised by recomputing.
 The program's ``cost_analysis()`` is not used: a Pallas custom call is
 opaque to it and recomputed operations count there.
+Which count is a family's is stated by the family, not here
+(``benchmark/models/<family>.py:train_flops_per_item``).
 """
 
 from __future__ import annotations
@@ -70,15 +72,6 @@ def resnet_forward_flops_per_image(cfg: dict) -> float:
 
 def resnet_train_flops_per_image(cfg: dict) -> float:
     return 3.0 * resnet_forward_flops_per_image(cfg)
-
-
-def train_flops_per_item(cfg: dict, traffic: dict) -> float:
-    """Dispatch on the configuration's ``family``."""
-    if cfg["family"] == "gpt2":
-        return gpt_train_flops_per_token(cfg, traffic["seq_len"])
-    if cfg["family"] == "resnet":
-        return resnet_train_flops_per_image(cfg)
-    raise ValueError(f"no FLOP count for family {cfg['family']!r}")
 
 
 # ------------------------------------------------- flash attention kernel

@@ -9,7 +9,8 @@ edits nothing that is here::
                                          chips, traffic parameters, why
     benchmark/configs/<config>.json      the configuration as it is run
     benchmark/configs/<config>.reference.py   its plain reference
-    benchmark/models/<family>.py         how a training step is built
+    benchmark/models/<family>.py         how a training step is built,
+                                         and the model FLOPs of one item
     benchmark/runners/<runner>.py        run(cell, ...) -> observations
     benchmark/metrics/<metric>.py        read(run) -> number or None;
                                          unit, layer and what it moves
@@ -87,9 +88,24 @@ def load_runner(name: str, root: str = ROOT):
                                     name + ".py"))
 
 
+# What a family's file has to define (benchmark/models/common.py says
+# what each returns).  Checked here, before anything is built: a file
+# that lacks one would otherwise fail after the measured window.
+BUILDER_FUNCTIONS = ("build", "train_flops_per_item")
+
+
 def load_model_builder(family: str, root: str = ROOT):
-    return load_module(os.path.join(root, "benchmark", "models",
-                                    family + ".py"))
+    path = os.path.join(root, "benchmark", "models", family + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"benchmark: no builder for family {family!r} "
+                         f"({path} is missing)")
+    module = load_module(path)
+    for name in BUILDER_FUNCTIONS:
+        if not callable(getattr(module, name, None)):
+            raise SystemExit(
+                f"benchmark: {path} defines no {name}(): every family "
+                f"states its own {', '.join(BUILDER_FUNCTIONS)}")
+    return module
 
 
 def load_reference(config_name: str, root: str = ROOT):
@@ -115,6 +131,19 @@ def available_metrics(root: str = ROOT) -> List[str]:
     directory = os.path.join(root, "benchmark", "metrics")
     return sorted(f[:-3] for f in os.listdir(directory)
                   if f.endswith(".py") and not f.startswith("_"))
+
+
+def reader_scopes(root: str = ROOT) -> List[str]:
+    """The ``SCOPE`` of every reader that states one: the scopes the
+    ``breakdown``'s ``device_scopes`` files device time under.  A later
+    PR's reader of a new scope joins them by being there."""
+    scopes = []
+    for name in available_metrics(root):
+        reader = load_module(os.path.join(root, "benchmark", "metrics",
+                                          name + ".py"))
+        if getattr(reader, "SCOPE", None):
+            scopes.append(reader.SCOPE)
+    return scopes
 
 
 def read_metrics(entries: List[dict], run: Dict[str, Any],

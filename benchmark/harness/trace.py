@@ -4,7 +4,8 @@ A trace is first turned into plain data::
 
     {"planes": [{"name": "/device:TPU:0",
                  "lines": [{"name": "XLA Ops",
-                            "events": [[name, start_ns, dur_ns], ...]}]}]}
+                            "events": [[name, start_ns, dur_ns, scope],
+                                       ...]}]}]}
 
 (`load_xplane`), so that everything below is arithmetic on lists and can
 be checked against a small recording kept with the tests
@@ -19,7 +20,11 @@ What is read:
   text (``%fusion.21 = (f32[1024,50257]...) fusion(...)``); `load_xplane`
   keeps the instruction's own name (``fusion.21``) and marks a Pallas
   kernel, which XLA sees as a custom call to ``tpu_custom_call``, as
-  ``tpu_custom_call:<name>`` (``tpu_custom_call:block0.3``);
+  ``tpu_custom_call:<name>`` (``tpu_custom_call:block0.3``).  The fourth
+  element is the operation's scope as XLA kept it (its ``op_name``:
+  ``jit(step)/transpose(jvp(GPT))/block3/attn/qkv/dot_general:``), empty
+  for an operation the compiler made itself and in recordings saved
+  before the loader kept it;
 * the *host plane* is ``/host:CPU``; the benchmark's own
   ``jax.profiler.TraceAnnotation`` spans (``dispatch``, ``wait_loss``)
   are events of that name on one of its thread lines.
@@ -27,12 +32,16 @@ What is read:
 
 from __future__ import annotations
 
+import functools
 import glob
 import gzip
 import json
 import os
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+
+from benchmark.harness import xplane
+from benchmark.harness.stats import median
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
@@ -41,6 +50,13 @@ PALLAS = re.compile(r"^tpu_custom_call:")
 HOST_PLANE = "/host:CPU"
 COLLECTIVE = re.compile(
     r"^(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)")
+UNSCOPED = "unscoped"
+# Entries of an operation's scope that are JAX's and not a name someone
+# gave: a transform around a function (``jit(step)``, ``jvp(GPT)``,
+# ``transpose(jvp(GPT))``), control flow, the partitioner.
+NOT_A_NAME = re.compile(
+    r"^(\w+\(.*\)|shard_map|while|body|cond|branch_\d+_fun|"
+    r"custom_vjp_call|custom_jvp_call|checkpoint|remat|pjit|jit)$")
 
 Interval = Tuple[float, float]
 
@@ -57,29 +73,28 @@ def find_xplane(trace_dir: str) -> str:
 
 
 def load_xplane(path: str, host_names: Iterable[str] = ()) -> dict:
-    """``ProfileData`` -> the plain structure above.  Of device planes
-    only the ``XLA Ops`` line is kept, of the host plane only the events
-    named in ``host_names`` (it holds every thread of the process)."""
-    from jax.profiler import ProfileData  # noqa: PLC0415
-
+    """The profiler's file -> the plain structure above.  Of device
+    planes only the ``XLA Ops`` line is kept, of the host plane only the
+    events named in ``host_names`` (it holds every thread of the
+    process)."""
     want = set(host_names)
+
+    def on_device(plane):
+        return bool(DEVICE_PLANE.match(plane))
+
     planes = []
-    for plane in ProfileData.from_file(path).planes:
-        on_device = bool(DEVICE_PLANE.match(plane.name))
-        if not on_device and plane.name != HOST_PLANE:
-            continue
-        lines = []
-        for line in plane.lines:
-            if on_device and line.name != OPS_LINE:
-                continue
-            events = [[op_name(e.name) if on_device else e.name,
-                       float(e.start_ns), float(e.duration_ns)]
-                      for e in line.events
-                      if on_device or e.name in want]
-            if events:
-                lines.append({"name": line.name, "events": events})
+    for plane in xplane.planes(
+            path,
+            want_plane=lambda p: on_device(p) or p == HOST_PLANE,
+            want_line=lambda p, line: line == OPS_LINE or not on_device(p),
+            want_event=lambda p, name: on_device(p) or name in want):
+        named = op_name if on_device(plane["name"]) else str
+        lines = [{"name": line["name"],
+                  "events": [[named(name), start, dur, scope]
+                             for name, start, dur, scope in line["events"]]}
+                 for line in plane["lines"] if line["events"]]
         if lines:
-            planes.append({"name": plane.name, "lines": lines})
+            planes.append({"name": plane["name"], "lines": lines})
     return {"planes": planes}
 
 
@@ -127,8 +142,15 @@ def save_recording(trace: dict, path: str) -> None:
 
 
 def load_recording(path: str) -> dict:
+    """A saved trace; events saved as ``[name, start, dur]``, before the
+    loader kept the scope, get an empty one."""
     with gzip.open(path, "rt") as f:
-        return json.load(f)
+        trace = json.load(f)
+    for plane in trace["planes"]:
+        for line in plane["lines"]:
+            line["events"] = [e if len(e) > 3 else [*e, ""]
+                              for e in line["events"]]
+    return trace
 
 
 # ------------------------------------------------------------ selection
@@ -220,8 +242,62 @@ def time_by_name(events: Iterable[list], key=lambda name: name
                  ) -> Dict[str, float]:
     """Summed duration (ns) by operation name, or by ``key(name)``."""
     out: Dict[str, float] = {}
-    for name, _start, dur in events:
+    for name, _start, dur, *_scope in events:
         out[key(name)] = out.get(key(name), 0.0) + dur
+    return out
+
+
+def scope_of(event: list) -> str:
+    """An event's scope; empty where it was recorded without one."""
+    return event[3] if len(event) > 3 else ""
+
+
+@functools.lru_cache(maxsize=None)  # a step has a few thousand scopes
+def scope_names(scope: str) -> Tuple[str, ...]:
+    """The names in an operation's scope, outermost first, without JAX's
+    own entries and without the primitive it ends in:
+    ``jit(step)/transpose(jvp(GPT))/block3/attn/qkv/dot_general:`` ->
+    ``("block3", "attn", "qkv")``.  The backward pass of a scope carries
+    the same names (under ``transpose(...)``), so forward and backward
+    are read together."""
+    parts = [p for p in scope.rstrip(":").split("/") if p][:-1]
+    return tuple(p for p in parts if not NOT_A_NAME.match(p))
+
+
+def under(events: Iterable[list], scope: str) -> List[list]:
+    """The events traced under ``jax.named_scope(scope)``, at any depth,
+    forward or backward."""
+    return [e for e in events if scope in scope_names(scope_of(e))]
+
+
+def scope_ms(run, scope: str) -> Optional[float]:
+    """What a ``<scope>_ms`` reader returns: summed device time of the
+    operations under ``scope`` per step on one device, from the traced
+    window; median over the cell's devices.  None where nothing ran
+    under it (a program without the scope, a recording without scopes)."""
+    traced = run.get("trace")
+    if not traced or not traced["ops"]:
+        return None
+    value = median([
+        sum(e[2] for e in under(ops, scope)) / traced["steps"] / 1e6
+        for ops in traced["ops"].values()])
+    return value if value > 0 else None
+
+
+def time_by_scope(events: Iterable[list], known: Iterable[str] = ()
+                  ) -> Dict[str, float]:
+    """Summed duration (ns) by scope: an operation goes under the
+    outermost of the ``known`` scopes in its names (the scopes some
+    reader reads), else under its innermost name (a flax module's:
+    ``wte``, ``conv1``), else under ``unscoped`` (the compiler's own
+    operations, and whatever the program traced under no name)."""
+    known = set(known)
+    out: Dict[str, float] = {}
+    for event in events:
+        names = scope_names(scope_of(event))
+        key = next((n for n in names if n in known),
+                   names[-1] if names else UNSCOPED)
+        out[key] = out.get(key, 0.0) + event[2]
     return out
 
 
@@ -247,7 +323,7 @@ def idle_gaps(merged_busy: Sequence[Interval], spans: Sequence[list]
     that overlap it, and what none covers is ``between_steps``."""
     out: Dict[str, float] = {}
     by_name: Dict[str, List[Interval]] = {}
-    for name, start, dur in spans:
+    for name, start, dur, *_scope in spans:
         by_name.setdefault(name, []).append((start, start + dur))
     unions = {n: union(v) for n, v in by_name.items()}
     gaps = [(merged_busy[i][1], merged_busy[i + 1][0])
@@ -265,19 +341,23 @@ def idle_gaps(merged_busy: Sequence[Interval], spans: Sequence[list]
     return out
 
 
-def top(named: Dict[str, float], n: int = 10, scale: float = 1e-9
-        ) -> List[list]:
-    """The ``n`` largest entries as ``[[name, seconds], ...]``."""
-    items = sorted(named.items(), key=lambda kv: -kv[1])[:n]
-    return [[k, v * scale] for k, v in items]
+def top(named: Dict[str, float], n: int = 10, scale: float = 1e-9,
+        last: str = None) -> List[list]:
+    """The ``n`` largest entries as ``[[name, seconds], ...]``; the one
+    named ``last`` closes the list whatever its size."""
+    items = sorted(named.items(), key=lambda kv: -kv[1])
+    closing = [kv for kv in items if kv[0] == last]
+    items = [kv for kv in items if kv[0] != last][:n - len(closing)]
+    return [[k, v * scale] for k, v in items + closing]
 
 
-def device_time(ops_by_device: Dict[int, List[list]], spans: Sequence[list]
-                ) -> Optional[dict]:
+def device_time(ops_by_device: Dict[int, List[list]], spans: Sequence[list],
+                scopes: Iterable[str] = ()) -> Optional[dict]:
     """What the last line's ``device`` and ``breakdown`` carry: busy and
     window seconds averaged over the devices, the operations that took
     most time (first device, operations of one kind summed under their
-    stem) and the idle gaps by what the host did."""
+    stem), the same time by scope (``time_by_scope`` with the scopes the
+    readers read) and the idle gaps by what the host did."""
     if not ops_by_device:
         return None
     busy_ns, window_ns = [], []
@@ -291,6 +371,8 @@ def device_time(ops_by_device: Dict[int, List[list]], spans: Sequence[list]
         "device": {"busy_s": sum(busy_ns) / len(busy_ns) / 1e9,
                    "window_s": sum(window_ns) / len(window_ns) / 1e9},
         "breakdown": {"device_ops": top(time_by_name(first, stem)),
+                      "device_scopes": top(time_by_scope(first, scopes),
+                                           last=UNSCOPED),
                       "idle_gaps": top(idle_gaps(merged, spans))},
         "idle_share_worst": max(
             1.0 - b / w for b, w in zip(busy_ns, window_ns) if w > 0),

@@ -1,5 +1,7 @@
 """90th percentile of the gaps between consecutive ready stamps, over
-every step of the window."""
+every step of the window: the stamps the loop takes itself
+(``train_throughput`` says which), so every gap is one step waited for
+inside the loop and none is the drain after it."""
 
 from benchmark.harness.stats import percentile
 

@@ -8,8 +8,26 @@ its state on the device from the seed.
 
 from __future__ import annotations
 
-from benchmark.models.common import (FRESH, Built, make_on_device, replicated,
-                                     seed_key, sharded)
+from benchmark.harness import flops
+from benchmark.models.common import (FRESH, OPTIMIZER_SCOPE, Built,
+                                     make_on_device, replicated, seed_key,
+                                     sharded)
+
+
+def train_flops_per_item(config: dict, ran: dict) -> float:
+    """Model FLOPs one token of a training step requires, at the sizes
+    the program was built with (``ran`` over the configuration file)."""
+    return flops.gpt_train_flops_per_token({**config, **ran}, ran["seq_len"])
+
+
+def fault_probes(config: dict, ran: dict) -> dict:
+    """The last block made an identity: with its two output projections
+    zero it adds nothing to the residual stream."""
+    from benchmark.harness.correct import zeroed
+
+    last = f"block{({**config, **ran})['n_layer'] - 1}"
+    return {"identity_block": lambda v: zeroed(
+        v, [("params", last, "proj"), ("params", last, "fc2")])}
 
 
 def build(config: dict, params: dict, seed: int,
@@ -73,13 +91,26 @@ def build(config: dict, params: dict, seed: int,
         # out_specs P() presents the loss as replicated, so it has to be
         # the global mean.
         loss = jax.lax.pmean(loss, hvd.DP_AXIS)
-        return optax.apply_updates(p, updates), opt_state, loss
+        # XLA names a fusion after its root, and the optimizer's fusions
+        # are rooted at this add: under the scope that
+        # ``DistributedOptimizer`` gives the update itself, so that
+        # ``optimizer_ms`` finds both.  Metadata only.
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            p = optax.apply_updates(p, updates)
+        return p, opt_state, loss
 
     step = jax.jit(
         jax.shard_map(local_step, mesh=mesh,
                       in_specs=(P(), P(), P(hvd.DP_AXIS)),
                       out_specs=(P(), P(), P()), check_vma=False),
         donate_argnums=(0, 1))
+
+    def program_loss(p, b):
+        """``loss_fn`` again, keeping each token's term."""
+        logits = model.apply(p, b["tokens"][:, :-1])
+        nll = optax.softmax_cross_entropy_with_integer_labels(
+            logits, b["tokens"][:, 1:])
+        return nll.mean(), -nll
 
     def sample(n):
         """``n`` fresh sequences, not the batch the window trained on."""
@@ -90,7 +121,7 @@ def build(config: dict, params: dict, seed: int,
     return Built(
         step=step, state=state, carry_len=2,
         items_per_step=batch * seq, chips=chips, mesh=mesh,
-        program_loss=jax.jit(lambda p, b: loss_fn(p, b["tokens"])),
+        program_loss=jax.jit(program_loss),
         sample=sample, variables=lambda state: state[0],
         ran=ran | {"seq_len": seq, "global_batch": batch,
                    "attention": cfg.attention_impl},

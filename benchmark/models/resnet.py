@@ -8,8 +8,16 @@ from __future__ import annotations
 
 import importlib
 
-from benchmark.models.common import (FRESH, Built, make_on_device, replicated,
-                                     seed_key, sharded)
+from benchmark.harness import flops
+from benchmark.models.common import (FRESH, OPTIMIZER_SCOPE, Built,
+                                     make_on_device, replicated, seed_key,
+                                     sharded)
+
+
+def train_flops_per_item(config: dict, ran: dict) -> float:
+    """Model FLOPs one image of a training step requires, at the sizes
+    the program was built with (``ran`` over the configuration file)."""
+    return flops.resnet_train_flops_per_image({**config, **ran})
 
 
 def build(config: dict, params: dict, seed: int,
@@ -84,7 +92,9 @@ def build(config: dict, params: dict, seed: int,
             loss_fn, has_aux=True)(p, batch_stats, images, labels)
         updates, opt_state = tx.update(grads, opt_state, p)
         loss = jax.lax.pmean(loss, hvd.DP_AXIS)
-        return optax.apply_updates(p, updates), new_stats, opt_state, loss
+        with jax.named_scope(OPTIMIZER_SCOPE):  # see models/gpt2.py
+            p = optax.apply_updates(p, updates)
+        return p, new_stats, opt_state, loss
 
     step = jax.jit(
         jax.shard_map(local_step, mesh=mesh,
@@ -103,8 +113,12 @@ def build(config: dict, params: dict, seed: int,
                     k_labels, (n,), 0, config["num_classes"], jnp.int32)}
 
     def program_loss(variables, b):
-        return loss_fn(variables["params"], variables["batch_stats"],
-                       b["images"], b["labels"])[0]
+        """``loss_fn`` again, keeping each image's term."""
+        logits, _ = model.apply(variables, b["images"], train=True,
+                                mutable=["batch_stats"])
+        nll = optax.softmax_cross_entropy_with_integer_labels(
+            logits, b["labels"])
+        return nll.mean(), -nll
 
     return Built(
         step=step, state=state, carry_len=3,

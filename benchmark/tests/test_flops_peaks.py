@@ -5,7 +5,7 @@ import os
 
 import pytest
 
-from benchmark.harness import flops
+from benchmark.harness import flops, registry
 from benchmark.harness.peaks import peaks
 from helpers import ROOT
 
@@ -25,7 +25,12 @@ def test_gpt2_medium_flops_per_token_by_hand():
     assert forward == pytest.approx(757_286_912, rel=1e-12)
     assert flops.gpt_forward_flops_per_token(cfg, 1024) == forward
     assert flops.gpt_train_flops_per_token(cfg, 1024) == 3 * forward
-    assert flops.train_flops_per_item(cfg, {"seq_len": 1024}) == 3 * forward
+    # the family's own statement, which the runner asks
+    family = registry.load_model_builder(cfg["family"], ROOT)
+    assert family.train_flops_per_item(cfg, {"seq_len": 1024}) == 3 * forward
+    # sizes the program was built with go over the file's
+    assert family.train_flops_per_item(
+        cfg, {"seq_len": 1024, "n_layer": 12}) < 3 * forward
 
 
 def test_resnet50_forward_flops_by_hand():
@@ -48,6 +53,8 @@ def test_resnet50_forward_flops_by_hand():
     assert macs == pytest.approx(4.09e9, rel=0.01)
     assert flops.resnet_forward_flops_per_image(cfg) == 2 * macs
     assert flops.resnet_train_flops_per_image(cfg) == 6 * macs
+    family = registry.load_model_builder(cfg["family"], ROOT)
+    assert family.train_flops_per_item(cfg, {"image_size": 224}) == 6 * macs
 
 
 def test_flash_lower_bound_and_its_side():

@@ -1,6 +1,7 @@
-"""The four readers that read what the program names itself (PR 24):
-the flash kernels by their ``pallas_call`` names, on a recording made
-after the kernels were named, and the program's compile log."""
+"""The readers that read what the program names itself: the flash
+kernels by their ``pallas_call`` names, on a recording made after the
+kernels were named, the program's compile log (PR 24), and the device
+time under a scope, on a slice of the profiler's own file (PR 27)."""
 
 import json
 import os
@@ -9,7 +10,7 @@ import pytest
 
 from benchmark.harness import registry
 from benchmark.harness import trace as tr
-from helpers import ROOT
+from helpers import ROOT, xplane_slice
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 NAMED = "gpt2m_train_s1024.named_kernels.two_steps"
@@ -109,11 +110,91 @@ def test_compile_readers_find_nothing_without_a_log(monkeypatch, name):
     assert _reader(name).read({"requests": []}) is None
 
 
+SCOPE_READERS = {"attn_ms": 74.0, "mlp_ms": 36.0, "head_ms": 24.0,
+                 "optimizer_ms": 7.0}      # ms in the slice, 1000 x real
+
+
+def _slice_run(tmp_path, steps=1):
+    trace = tr.load_xplane(xplane_slice(tmp_path))
+    # the slice's times are in microseconds; a thousand times longer and
+    # they read as the milliseconds of a real step
+    ops = {d: [[n, a * 1e3, b * 1e3, s] for n, a, b, s in events]
+           for d, events in tr.device_ops(trace).items()}
+    return {"trace": {"ops": ops, "steps": steps}}
+
+
+@pytest.mark.parametrize("name", sorted(SCOPE_READERS))
+def test_scope_readers_sum_forward_and_backward(tmp_path, name):
+    run = _slice_run(tmp_path)
+    assert _reader(name).read(run) == pytest.approx(SCOPE_READERS[name])
+    two_steps = _slice_run(tmp_path, steps=2)
+    assert _reader(name).read(two_steps) == pytest.approx(
+        SCOPE_READERS[name] / 2)
+    # a second device that ran nothing under the scope: the median of
+    # the two, as the other per-device readers take it
+    run["trace"]["ops"][1] = [["copy.1", 0.0, 5e6, ""]]
+    assert _reader(name).read(run) == pytest.approx(SCOPE_READERS[name] / 2)
+    # nothing to read: a recording without scopes, an untraced run
+    assert _reader(name).read(_traced_run(NAMED)) is None
+    assert _reader(name).read({"trace": None}) is None
+
+
+def test_the_older_readers_read_the_same_events_from_the_new_loader(
+        tmp_path):
+    run = _slice_run(tmp_path)
+    assert _reader("flash_fwd_ms").read(run) == pytest.approx(20.0)
+    assert _reader("flash_bwd_ms").read(run) == pytest.approx(30.0)
+    assert _reader("flash_ms").read(run) == pytest.approx(50.0)
+    assert _reader("allreduce_ms").read(run) == pytest.approx(9.0)
+    assert _reader("allreduce_exposed_ms").read(run) == pytest.approx(9.0)
+    # the kernels are inside attn_ms, which is the point of the split
+    assert _reader("attn_ms").read(run) - _reader("flash_ms").read(run) \
+        == pytest.approx(24.0)
+
+
+def test_collective_readers_on_the_four_chip_recording():
+    """``allreduce_ms`` and ``allreduce_exposed_ms`` on a recording
+    saved without scopes give the numbers its expect file always had."""
+    name = "gpt2m_train_dp4.one_step_device0"
+    with open(os.path.join(DATA, name + ".expect.json")) as f:
+        expect = json.load(f)
+    run = _traced_run(name, steps=1)
+    assert _reader("allreduce_ms").read(run) == pytest.approx(
+        expect["collective_ns"] / 1e6)
+    assert _reader("allreduce_exposed_ms").read(run) == pytest.approx(
+        expect["collective_exposed_ns"] / 1e6)
+    assert _reader("flash_ms").read(run) == pytest.approx(
+        expect["kernel_ns"]["^tpu_custom_call:"] / 1e6)
+
+
+def test_every_reader_of_a_scope_names_it_for_the_breakdown():
+    assert sorted(registry.reader_scopes(ROOT)) == [
+        "attn", "head", "mlp", "optimizer_update"]
+
+
+def test_the_scope_entries_are_appended_and_name_their_cells():
+    bench = registry.benchmark_json(ROOT)
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[-4:] == ["attn_ms", "mlp_ms", "head_ms", "optimizer_ms"]
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    gpt = {"gpt2m_train_s1024", "gpt2m_train_dp4"}
+    for name in ("attn_ms", "mlp_ms", "head_ms"):
+        assert by_name[name]["layer"] == "Models"
+    assert by_name["optimizer_ms"]["layer"] == "Step builder"
+    for name in names[-4:]:
+        # not ResNet's cell: XLA fuses its SGD update into the backward
+        # convolutions' fusions, and 7 us a step are left under the scope
+        assert set(by_name[name]["workloads"]) == gpt
+        assert by_name[name]["moves"] == "train_throughput"
+        assert by_name[name]["source"] == "device_trace"
+        assert by_name[name]["unit"] == "ms"
+
+
 def test_the_four_entries_are_appended_and_name_their_cells():
     bench = registry.benchmark_json(ROOT)
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-4:] == ["flash_fwd_ms", "flash_bwd_ms",
-                          "compile_trace_lower_s", "compile_cache_misses"]
+    assert names[-8:-4] == ["flash_fwd_ms", "flash_bwd_ms",
+                            "compile_trace_lower_s", "compile_cache_misses"]
     gpt = {"gpt2m_train_s1024", "gpt2m_train_dp4"}
     cells = {w["name"] for w in bench["workloads"]}
     by_name = {m["name"]: m for m in bench["per_layer"]}

@@ -133,3 +133,79 @@ def test_stems_group_operations_of_one_kind():
     assert tr.stem("tpu_custom_call:block23.5") == "tpu_custom_call:block"
     assert tr.stem("all-reduce:psum.220") == "all-reduce:psum"
     assert tr.stem("copy-done") == "copy-done"
+
+
+# ---- the loader's own decoding of the profiler's file, with scopes ----
+
+from helpers import BWD, FWD, xplane_slice  # noqa: E402
+
+
+@pytest.fixture
+def xplane_file(tmp_path):
+    return xplane_slice(tmp_path)
+
+
+def test_the_loader_keeps_name_time_and_scope(xplane_file):
+    trace = tr.load_xplane(xplane_file, host_names=("dispatch", "wait_loss"))
+    assert [p["name"] for p in trace["planes"]] == ["/device:TPU:0",
+                                                    "/host:CPU"]
+    ops = tr.device_ops(trace)[0]
+    assert [e[0] for e in ops] == [
+        "copy-start.17", "fusion", "convolution_add_fusion.3",
+        "tpu_custom_call:flash_fwd.2", "convolution_add_fusion.1",
+        "fusion.399", "multiply_reduce_fusion.9", "fusion.403",
+        "tpu_custom_call:flash_bwd_dq.2", "fusion.225", "all-reduce.81",
+        "fusion.297", "fusion.298", "fusion.6"]
+    assert ops[0] == ["copy-start.17", 1000.0, 2000.0, ""]     # no scope
+    assert ops[3][1:] == [16000.0, 20000.0,
+                          FWD + "block0/attn/flash_fwd/pallas_call:"]
+    assert ops[7][3] == BWD + "block0/mlp/fc2/dot_general:"   # by reference
+    assert tr.host_spans(trace, ("dispatch", "wait_loss")) == [
+        ["dispatch", 1900.0, 500.0, ""], ["wait_loss", 2900.0, 150000.0, ""]]
+    busy, window, _ = tr.busy(ops)
+    assert (busy, window) == (155500.0, 155500.0)
+
+
+def test_scopes_read_forward_and_backward_together(xplane_file):
+    ops = tr.device_ops(tr.load_xplane(xplane_file))[0]
+    assert tr.scope_names(ops[2][3]) == ("block0", "attn", "qkv")
+    assert tr.scope_names(ops[9][3]) == ("block0", "attn", "proj")
+    assert tr.scope_names(ops[1][3]) == ("embed", "wte")
+    assert tr.scope_names(ops[13][3]) == () == tr.scope_names("")
+    assert [e[0] for e in tr.under(ops, "attn")] == [
+        "convolution_add_fusion.3", "tpu_custom_call:flash_fwd.2",
+        "tpu_custom_call:flash_bwd_dq.2", "fusion.225"]
+    assert sum(e[2] for e in tr.under(ops, "allreduce")) == 9000.0
+    assert tr.under(ops, "att") == []          # a name, not a prefix
+    known = ["attn", "mlp", "head", "optimizer_update"]
+    assert tr.time_by_scope(ops, known) == {
+        "attn": 74000.0, "mlp": 36000.0, "head": 24000.0,
+        "optimizer_update": 7000.0, "allreduce": 9000.0, "wte": 3000.0,
+        "unscoped": 2500.0}
+    # without a reader's scope an operation goes under its innermost name
+    assert tr.time_by_scope(ops)["qkv"] == 10000.0
+    out = tr.device_time({0: ops}, [], known)
+    listed = out["breakdown"]["device_scopes"]
+    assert [name for name, _ in listed] == [
+        "attn", "mlp", "head", "allreduce", "optimizer_update", "wte",
+        "unscoped"]
+    assert [s for _, s in listed] == pytest.approx(
+        [74e-6, 36e-6, 24e-6, 9e-6, 7e-6, 3e-6, 2.5e-6])
+    assert out["breakdown"]["device_ops"][0] == ["fusion", pytest.approx(
+        56.5e-6)]
+    # unscoped closes the list however large, and is never cut from it
+    many = {f"s{i}": float(i + 1) for i in range(12)} | {"unscoped": 99.0}
+    listed = tr.top(many, last="unscoped", scale=1.0)
+    assert len(listed) == 10 and listed[-1] == ["unscoped", 99.0]
+    assert listed[0] == ["s11", 12.0]
+
+
+def test_recordings_saved_without_scopes_still_load():
+    for name in os.listdir(DATA):
+        if not name.endswith(".json.gz"):
+            continue
+        ops = tr.device_ops(tr.load_recording(os.path.join(DATA, name)))
+        first = next(iter(ops.values()))
+        assert all(len(e) == 4 and e[3] == "" for e in first)
+        assert tr.under(first, "attn") == []
+        assert set(tr.time_by_scope(first)) == {"unscoped"}

@@ -4,8 +4,9 @@
 
     JAX_PLATFORMS=cpu python3 benchmark/tools/compile_check.py <cell> ...
 
-Prints each cell's per-device memory as the TPU compiler counts it and
-whether the Pallas kernel and an all-reduce are in the compiled text.
+Prints each cell's per-device memory as the TPU compiler counts it,
+whether the Pallas kernel, an all-reduce and the optimizer's scope are in
+the compiled text, and the family's model FLOPs per item.
 Nothing runs, so this says nothing about results or times.
 """
 
@@ -36,9 +37,9 @@ def check(cell_name: str) -> dict:
                                         topology_name="v5e:2x2")
     mesh = Mesh(np.asarray(topo.devices[:cell["chips"]], dtype=object),
                 (hvd.DP_AXIS,))
-    builder = registry.load_model_builder(cell["config_values"]["family"])
-    built = builder.build(cell["config_values"], cell["params"], 0,
-                          described_mesh=mesh)
+    config = cell["config_values"]
+    builder = registry.load_model_builder(config["family"])
+    built = builder.build(config, cell["params"], 0, described_mesh=mesh)
     compiled = built.step.lower(*built.state).compile()
     mem = compiled.memory_analysis()
     text = compiled.as_text()
@@ -54,6 +55,9 @@ def check(cell_name: str) -> dict:
                       + mem.temp_size_in_bytes) / gib,
         "tpu_custom_call": "tpu_custom_call" in text,
         "all_reduce": "all-reduce" in text,
+        "optimizer_scope": "optimizer_update/add" in text,
+        "model_flops_per_item": builder.train_flops_per_item(
+            config, built.ran),
     }
 
 

@@ -1,26 +1,31 @@
 #!/usr/bin/env python3
 """Look at a raw ``.xplane.pb`` by hand before writing code against it:
-planes, lines, and the first events of each line with their stats.
+planes, lines, and the first events of each line with their scope, as
+the benchmark's own decoder (benchmark/harness/xplane.py) reads them.
 
     python3 benchmark/tools/inspect_trace.py <file.xplane.pb> [events]
 """
 
+import os
 import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
 
 
 def main(path: str, show: int = 4) -> None:
-    from jax.profiler import ProfileData
+    from benchmark.harness import xplane
 
-    for plane in ProfileData.from_file(path).planes:
-        lines = list(plane.lines)
-        print(f"PLANE {plane.name!r}: {len(lines)} lines")
-        for line in lines:
-            events = list(line.events)
-            print(f"  LINE {line.name!r}: {len(events)} events")
-            for e in events[:show]:
-                stats = {k: v for k, v in list(e.stats)[:8]}
-                print(f"    {e.name!r} start_ns={e.start_ns} "
-                      f"dur_ns={e.duration_ns} {stats}")
+    everything = xplane.planes(path, lambda plane: True,
+                               lambda plane, line: True,
+                               lambda plane, event: True)
+    for plane in everything:
+        print(f"PLANE {plane['name']!r}: {len(plane['lines'])} lines")
+        for line in plane["lines"]:
+            print(f"  LINE {line['name']!r}: {len(line['events'])} events")
+            for name, start, dur, scope in line["events"][:show]:
+                print(f"    {name[:100]!r} start_ns={start} dur_ns={dur} "
+                      f"scope={scope!r}")
 
 
 if __name__ == "__main__":

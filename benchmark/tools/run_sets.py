@@ -32,6 +32,23 @@ def spread(values):
     return (q3 - q1) / statistics.median(values)
 
 
+def reference_numbers(line) -> str:
+    """What the run's reference checks compared, its checks' seconds and
+    any stall it noted: a tolerance is set from these over many seeds."""
+    checks, notes = line.get("checks", {}), line.get("notes", {})
+    picked = [(name, key) for name, key in (
+        ("matches_reference", "abs_diff"),
+        ("logprob_matches_reference", "abs_diff_max"),
+        ("gradient_matches_reference", "diff_norm_over_reference_norm"))
+        if name in checks]
+    out = [f"{key}={checks[name][key]:.4g}" for name, key in picked]
+    out += [f"{key}={notes[key]:.4g}" for key in ("checks_s", "drain_ms")
+            if key in notes]
+    if notes.get("stalls"):
+        out.append("stalls=" + json.dumps(notes["stalls"]))
+    return " ".join(out)
+
+
 def one_run(command, workload, seed, seconds, trace, extra=()):
     t0 = time.time()
     proc = subprocess.run(
@@ -83,7 +100,8 @@ def main(argv=None) -> int:
             print(f"# set {k} run {i} rc={line['rc']} "
                   f"correct={line.get('correct')} "
                   + " ".join(f"{n}={m['value']:.6g}" for n, m in
-                             line.get("metrics", {}).items()), flush=True)
+                             line.get("metrics", {}).items())
+                  + " " + reference_numbers(line), flush=True)
         sets.append(rows)
     for i in range(args.trace_runs):
         extra = ["--dump-trace", os.path.join(
