@@ -52,7 +52,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from .. import scopes
-from .transformer import TransformerConfig, raw_block_forward
+from .transformer import (TransformerConfig, raw_block_forward,
+                          require_gpt2_block)
 
 __all__ = [
     "init_cache",
@@ -77,6 +78,7 @@ def _params(params):
 def init_cache(cfg: TransformerConfig, batch: int, max_len=None):
     """Empty slot pool: per-layer K/V at the cache dtype + per-slot
     write positions ``pos [batch]``."""
+    require_gpt2_block(cfg, "models.decode.init_cache")
     if cfg.moe_experts > 0:
         raise ValueError("decode cache supports dense blocks only")
     s = max_len or cfg.max_len
@@ -181,6 +183,7 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens_t,
     touching their cache.  Frozen rows still produce (meaningless)
     logits; callers ignore them.
     """
+    require_gpt2_block(cfg, "models.decode.decode_step")
     p = _params(params)
     b = tokens_t.shape[0]
     pos = _slot_pos(cache, b)
@@ -272,6 +275,7 @@ def prefill(cfg: TransformerConfig, params, tokens, max_len=None,
     table to run off) trip the full forward's max_len guard here; use
     the scanned path for that corner.
     """
+    require_gpt2_block(cfg, "models.decode.prefill")
     from ..parallel.tensor_parallel import (  # noqa: PLC0415
         _gpt_embed, _gpt_head,
     )
@@ -361,6 +365,7 @@ def assign_slot(cfg: TransformerConfig, params, cache, slot, tokens,
     trace-time dynamic, so one compiled assign per prompt-length bucket
     serves every admission.
     """
+    require_gpt2_block(cfg, "models.decode.assign_slot")
     s = tokens.shape[0]
     s_cache = cache["k"].shape[2]
     if s > s_cache:
@@ -398,6 +403,7 @@ def init_paged_pool(cfg: TransformerConfig, num_pages: int,
     ``kv_heads`` overrides the per-pool head count for width-sharded
     pools (each device of the width axis holds only ITS heads' pages).
     """
+    require_gpt2_block(cfg, "models.decode.init_paged_pool")
     if cfg.moe_experts > 0:
         raise ValueError("decode cache supports dense blocks only")
     hkv = kv_heads if kv_heads is not None else cfg.kv_heads
@@ -436,6 +442,7 @@ def decode_step_paged(cfg: TransformerConfig, params, pool, tables,
     through the two row-parallel psums over ``tp_axis`` — call inside
     ``shard_map`` (serve/engine.py does).
     """
+    require_gpt2_block(cfg, "models.decode.decode_step_paged")
     if tp_axis is None:
         p = _params(params)
         rep = p
@@ -581,6 +588,7 @@ def assign_slot_paged(cfg: TransformerConfig, params, pool, tables,
     the pool both hold only this shard's heads (see
     :func:`decode_step_paged`).
     """
+    require_gpt2_block(cfg, "models.decode.assign_slot_paged")
     s = tokens.shape[0]
     ps = pool["k"].shape[2]
     mp = tables.shape[1]
@@ -625,6 +633,7 @@ def generate(cfg: TransformerConfig, params, prompt, steps: int,
     as pad — and the decode loop exits as soon as every row is done, so
     a batch of short completions stops paying for its longest member.
     """
+    require_gpt2_block(cfg, "models.decode.generate")
     if temperature > 0 and key is None:
         raise ValueError("temperature > 0 requires a PRNG key")
 

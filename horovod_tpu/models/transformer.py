@@ -95,6 +95,34 @@ class TransformerConfig:
     # contract (tests/test_fp8.py pins how far it may drift) — so opt-in,
     # mirroring models/resnet.py act_store_dtype.
     act_store_dtype: Optional[Any] = None
+    # ---- what a block is made of.  Every default is GPT-2's, so the
+    # named GPT sizes build the tree and the mathematics they always did.
+    # One mixer per layer: "attention" or "mamba" (a Mamba-2 state-space
+    # mixer, ops/ssd.py).  None = attention in every layer.
+    layer_types: Optional[tuple] = None
+    norm: str = "layernorm"            # layernorm | rmsnorm
+    norm_eps: float = 1e-6
+    use_bias: bool = True              # biases of the dense projections
+    mlp: str = "gelu"                  # gelu | silu_gated: W_out(silu(g)*u)
+    # softmax(attention_scale * q k^T); None = head_dim ** -0.5
+    attention_scale: Optional[float] = None
+    embedding_multiplier: float = 1.0  # x = multiplier * wte[tokens]
+    residual_multiplier: float = 1.0   # x = x + multiplier * branch(x)
+    logits_scaling: float = 1.0        # logits = head(x) / scaling
+    tie_embeddings: bool = False       # the head is wte, transposed
+    # Which of a rematerialized block's values are kept for the backward
+    # pass (a name in jax.checkpoint_policies).
+    remat_policy: str = "dots_with_no_batch_dims_saveable"
+    # The Mamba-2 mixer: ssm_heads heads of ssm_head_dim (their product is
+    # the inner width), a state of ssm_state per head channel, B and C
+    # shared by the heads of a group, a causal depthwise conv of ssm_conv
+    # taps, the scan in chunks of ssm_chunk tokens.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
 
     def __post_init__(self):
         if self.num_kv_heads is not None:
@@ -103,11 +131,31 @@ class TransformerConfig:
                     f"num_heads={self.num_heads} must be a positive "
                     f"multiple of num_kv_heads={self.num_kv_heads}"
                 )
-        if self.pos_embedding not in ("learned", "rope"):
+        if self.pos_embedding not in ("learned", "rope", "none"):
             raise ValueError(
-                f"pos_embedding must be 'learned' or 'rope', got "
+                f"pos_embedding must be 'learned', 'rope' or 'none', got "
                 f"{self.pos_embedding!r}"
             )
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(
+                f"norm must be 'layernorm' or 'rmsnorm', got {self.norm!r}")
+        if self.mlp not in ("gelu", "silu_gated"):
+            raise ValueError(
+                f"mlp must be 'gelu' or 'silu_gated', got {self.mlp!r}")
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            unknown = set(self.layer_types) - {"attention", "mamba"}
+            if unknown or len(self.layer_types) != self.num_layers:
+                raise ValueError(
+                    f"layer_types must name 'attention' or 'mamba' for each "
+                    f"of num_layers={self.num_layers} layers, got "
+                    f"{self.layer_types!r}")
+            if "mamba" in self.layer_types and (
+                    self.ssm_heads <= 0
+                    or self.ssm_heads % self.ssm_groups):
+                raise ValueError(
+                    f"a 'mamba' layer needs ssm_heads={self.ssm_heads} to be "
+                    f"a positive multiple of ssm_groups={self.ssm_groups}")
 
     @property
     def head_dim(self) -> int:
@@ -117,6 +165,40 @@ class TransformerConfig:
     def kv_heads(self) -> int:
         return (self.num_kv_heads if self.num_kv_heads is not None
                 else self.num_heads)
+
+    def layer_type(self, i: int) -> str:
+        return self.layer_types[i] if self.layer_types else "attention"
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+
+def require_gpt2_block(cfg: TransformerConfig, who: str) -> None:
+    """Refuse, before anything is traced, a configuration that ``who``
+    cannot run: the decode and serving paths, the tensor-parallel and
+    the pipeline schedules build on :func:`block_math` with GPT-2's five
+    callables from raw weights (LayerNorm, biased dense projections, a
+    gelu MLP, attention in every layer) and would run that wiring under
+    another model's name."""
+    gpt2 = TransformerConfig()
+    if cfg.layer_types and set(cfg.layer_types) != {"attention"}:
+        raise ValueError(
+            f"{who} runs attention layers only: layer_types="
+            f"{cfg.layer_types!r} holds a layer it has no state for")
+    for setting in ("norm", "norm_eps", "mlp", "use_bias",
+                    "tie_embeddings", "embedding_multiplier",
+                    "residual_multiplier", "logits_scaling",
+                    "attention_scale"):
+        if getattr(cfg, setting) != getattr(gpt2, setting):
+            raise ValueError(
+                f"{who} implements GPT-2's block only "
+                f"({setting}={getattr(gpt2, setting)!r}); this "
+                f"configuration says {setting}={getattr(cfg, setting)!r}")
+    if cfg.pos_embedding == "none":
+        raise ValueError(
+            f"{who} implements learned and rotary positions; this "
+            f"configuration says pos_embedding='none'")
 
 
 def _attend(cfg: TransformerConfig, q, k, v, positions):
@@ -129,7 +211,7 @@ def _attend(cfg: TransformerConfig, q, k, v, positions):
         return flash_attention(
             q, k, v, causal=True,
             block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
-            window=cfg.attention_window,
+            window=cfg.attention_window, scale=cfg.attention_scale,
         )
     if cfg.attention_window is not None:
         raise ValueError(
@@ -145,6 +227,11 @@ def _attend(cfg: TransformerConfig, q, k, v, positions):
         rep = cfg.num_heads // cfg.kv_heads
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
+    if cfg.attention_scale is not None and cfg.attention_impl != "reference":
+        raise ValueError(
+            "attention_scale is for the flash and reference schedules; "
+            f"attention_impl={cfg.attention_impl!r} does not take it"
+        )
     if cfg.attention_impl == "ring":
         from ..parallel.ring_attention import ring_attention  # noqa: PLC0415
 
@@ -175,7 +262,8 @@ def _attend(cfg: TransformerConfig, q, k, v, positions):
     # local_attention masks from scalar offsets: valid because every
     # non-zigzag layout is contiguous per shard (zigzag never routes here)
     return local_attention(
-        q, k, v, causal=True, q_offset=positions[0], kv_offset=positions[0]
+        q, k, v, causal=True, scale=cfg.attention_scale,
+        q_offset=positions[0], kv_offset=positions[0]
     )
 
 
@@ -189,24 +277,67 @@ def act_store(y, cfg: TransformerConfig):
     return jnp.asarray(jnp.asarray(y, cfg.act_store_dtype), cfg.dtype)
 
 
+def mamba_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
+                conv_bias, dt_bias, a_log, d_skip, norm_scale, out_proj):
+    """The Mamba-2 mixer on the normed stream ``h`` [b, s, emb]: one
+    projection to the gate ``z``, the conv's input ``xBC`` and ``dt``;
+    a causal depthwise conv and silu over ``xBC``; the state-space scan
+    (``ops/ssd.py``); the gate, THEN the RMS norm over all inner
+    channels (one group); the output projection.  ``in_proj`` and
+    ``out_proj`` are callables like ``block_math``'s, the rest raw
+    arrays.  ``dt``, ``A`` and everything the scan carries are float32.
+    Returns the residual delta."""
+    from ..ops.ssd import ssd_scan  # noqa: PLC0415
+
+    b, s, _ = h.shape
+    inner, heads = cfg.ssm_inner, cfg.ssm_heads
+    bc = cfg.ssm_groups * cfg.ssm_state
+    fused = in_proj(h)
+    z = fused[..., :inner]
+    xbc = fused[..., inner:2 * inner + 2 * bc]
+    dt = fused[..., 2 * inner + 2 * bc:]
+    # output t reads inputs t-(taps-1) .. t, zeros before the sequence
+    taps = conv_kernel.shape[0]
+    padded = jnp.pad(xbc.astype(jnp.float32),
+                     ((0, 0), (taps - 1, 0), (0, 0)))
+    conv = sum(padded[:, k:k + s] * conv_kernel[k]
+               for k in range(taps)) + conv_bias
+    xbc = jax.nn.silu(conv).astype(fused.dtype)
+    x = xbc[..., :inner].reshape(b, s, heads, cfg.ssm_head_dim)
+    B = xbc[..., inner:inner + bc].reshape(b, s, cfg.ssm_groups,
+                                           cfg.ssm_state)
+    C = xbc[..., inner + bc:].reshape(b, s, cfg.ssm_groups, cfg.ssm_state)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+    y = ssd_scan(x, dt, -jnp.exp(a_log.astype(jnp.float32)), B, C, d_skip,
+                 cfg.ssm_chunk)
+    gated = y.reshape(b, s, inner).astype(jnp.float32) \
+        * jax.nn.silu(z.astype(jnp.float32))
+    normed = gated * jax.lax.rsqrt(
+        jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + cfg.norm_eps)
+    return out_proj(normed * norm_scale)
+
+
 def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
-               ln1, qkv, proj, ln2, mlp,
+               ln1, ln2, mlp, qkv=None, proj=None, ssm=None,
                num_heads: Optional[int] = None,
                num_kv_heads: Optional[int] = None,
                attend=None):
-    """THE pre-LN transformer block wiring — the single source of truth.
+    """THE pre-norm block wiring — the single source of truth.
 
-    ``LN → qkv → split-heads → rope → attend → proj(+res) → LN →
-    mlp(+res)``, shared by the flax :class:`Block`, the raw-weights
-    pipeline-parallel block (:func:`raw_block_forward`), and the
-    Megatron tensor-parallel block (``parallel/tensor_parallel.py``) so
-    a change to the block (a bias flag, a norm variant, the head
-    split) is made exactly once.
+    ``norm → mixer → (+res) → norm → feed-forward → (+res)``, each
+    residual added through ``cfg.residual_multiplier``.  The mixer is
+    attention (``qkv → split-heads → rope → attend → proj``) or, where
+    the caller hands in ``ssm``, whatever that callable makes of the
+    normed stream (the Mamba-2 mixer, :func:`mamba_mixer`).  Shared by
+    the flax :class:`Block`, the raw-weights pipeline-parallel block
+    (:func:`raw_block_forward`), and the Megatron tensor-parallel block
+    (``parallel/tensor_parallel.py``) so a change to the block (a bias
+    flag, a norm variant, the head split) is made exactly once.
 
-    Callers supply the five parameterized layer applications as
-    callables (flax modules, raw-weight closures, or psum-rejoined
-    tensor-parallel closures); ``proj`` and ``mlp`` return the residual
-    DELTA (this function adds it to the stream).  ``num_heads`` /
+    Callers supply the parameterized layer applications as callables
+    (flax modules, raw-weight closures, or psum-rejoined tensor-parallel
+    closures); ``proj``, ``ssm`` and ``mlp`` return the residual DELTA
+    (this function adds it to the stream).  ``num_heads`` /
     ``num_kv_heads`` override the config's head counts for callers
     operating on a per-rank head shard (TP).  ``attend`` overrides the
     attention schedule itself: a callable ``(q, k, v) -> att`` over the
@@ -222,35 +353,44 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
     q_dim = nh * hd
     kv_dim = nkv * hd
 
+    def add(x, delta):
+        if cfg.residual_multiplier == 1.0:
+            return x + delta
+        return x + cfg.residual_multiplier * delta
+
     # The two halves trace under scopes (``jax.named_scope``) of their own, so
-    # a device trace tells attention from MLP whatever XLA names the
+    # a device trace tells the mixer from the MLP whatever XLA names the
     # fusions.  A scope is metadata: it names no parameter, so the flax
     # tree stays ``block<i>/{ln1,qkv,proj,ln2,fc1,fc2}``.
-    with jax.named_scope(scopes.ATTN):
-        h = ln1(x)
-        fused = qkv(h)
-        q = fused[..., :q_dim].reshape(b, s, nh, hd)
-        k = fused[..., q_dim:q_dim + kv_dim].reshape(b, s, nkv, hd)
-        v = fused[..., q_dim + kv_dim:].reshape(b, s, nkv, hd)
-        if rope_tabs is not None:
-            from ..ops.rope import apply_rope_tables  # noqa: PLC0415
+    if ssm is not None:
+        with jax.named_scope(scopes.SSM):
+            x = add(x, act_store(ssm(ln1(x)), cfg))
+    else:
+        with jax.named_scope(scopes.ATTN):
+            h = ln1(x)
+            fused = qkv(h)
+            q = fused[..., :q_dim].reshape(b, s, nh, hd)
+            k = fused[..., q_dim:q_dim + kv_dim].reshape(b, s, nkv, hd)
+            v = fused[..., q_dim + kv_dim:].reshape(b, s, nkv, hd)
+            if rope_tabs is not None:
+                from ..ops.rope import apply_rope_tables  # noqa: PLC0415
 
-            q = apply_rope_tables(q, *rope_tabs)
-            k = apply_rope_tables(k, *rope_tabs)
-        if attend is None:
-            attend_cfg = cfg
-            if nh != cfg.num_heads or nkv != cfg.kv_heads:
-                # per-rank head shard: _attend sees the LOCAL head
-                # geometry
-                attend_cfg = replace(cfg, num_heads=nh, num_kv_heads=nkv,
-                                     emb_dim=q_dim)
-            att_4d = _attend(attend_cfg, q, k, v, positions)
-        else:
-            att_4d = attend(q, k, v)
-        att = act_store(att_4d.reshape(b, s, q_dim), cfg)
-        x = x + act_store(proj(att), cfg)
+                q = apply_rope_tables(q, *rope_tabs)
+                k = apply_rope_tables(k, *rope_tabs)
+            if attend is None:
+                attend_cfg = cfg
+                if nh != cfg.num_heads or nkv != cfg.kv_heads:
+                    # per-rank head shard: _attend sees the LOCAL head
+                    # geometry
+                    attend_cfg = replace(cfg, num_heads=nh,
+                                         num_kv_heads=nkv, emb_dim=q_dim)
+                att_4d = _attend(attend_cfg, q, k, v, positions)
+            else:
+                att_4d = attend(q, k, v)
+            att = act_store(att_4d.reshape(b, s, q_dim), cfg)
+            x = add(x, act_store(proj(att), cfg))
     with jax.named_scope(scopes.MLP):
-        return x + act_store(mlp(ln2(x)), cfg)
+        return add(x, act_store(mlp(ln2(x)), cfg))
 
 
 def raw_layer_norm(x, scale, bias, eps: float = 1e-6):
@@ -280,6 +420,7 @@ def raw_block_forward(cfg: TransformerConfig, p, x, positions, rope_tabs,
     (``parallel/pipeline.py``) and, with an ``attend`` override, the
     KV-cache decode path (models/decode.py); numerically equivalent to
     the flax :class:`Block` (pinned by tests/test_pipeline.py)."""
+    require_gpt2_block(cfg, "raw_block_forward")
     dt = cfg.dtype
 
     def mlp(h):
@@ -297,19 +438,50 @@ def raw_block_forward(cfg: TransformerConfig, p, x, positions, rope_tabs,
     )
 
 
+def _norm(cfg: TransformerConfig, name: str):
+    """The configuration's norm as a flax module, float32 math."""
+    if cfg.norm == "rmsnorm":
+        return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
+    return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """``softplus(dt_bias)`` log-uniform in [1e-3, 1e-1] (the Mamba-2
+    paper's initialisation)."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, jnp.log(1e-3),
+                                    jnp.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))  # softplus's inverse
+
+
+def _conv_init(key, shape, dtype=jnp.float32):
+    """Uniform in +-1/sqrt(taps): what the Mamba-2 reference code's
+    depthwise ``Conv1d`` starts from (PyTorch's default).  With normal
+    0.02 the conv's output, and with it B, C and the state's share of
+    ``y``, would start two orders of magnitude under the skip ``D x``."""
+    bound = shape[0] ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
 class Block(nn.Module):
-    """Pre-LN transformer block: LN → attn → +res, LN → MLP → +res.
+    """Pre-norm block: norm → mixer → +res, norm → MLP → +res.
 
     The wiring lives in :func:`block_math`; this module only declares
-    the flax parameters and hands their applications in as callables.
+    the flax parameters (the attention mixer's or the Mamba-2 mixer's,
+    by ``layer_type``) and hands their applications in as callables.
     """
 
     cfg: TransformerConfig
+    layer_type: str = "attention"
 
     @nn.compact
     def __call__(self, x, positions, rope_tabs=None):
         cfg = self.cfg
         kv_dim = cfg.kv_heads * cfg.head_dim
+        width = cfg.mlp_ratio * cfg.emb_dim
+
+        def dense(features, name):
+            return nn.Dense(features, dtype=cfg.dtype,
+                            use_bias=cfg.use_bias, name=name)
 
         def mlp(h):
             if cfg.moe_experts > 0:
@@ -318,8 +490,7 @@ class Block(nn.Module):
                 )
 
                 moe_p = moe_flax_params(
-                    self, cfg.emb_dim, cfg.mlp_ratio * cfg.emb_dim,
-                    cfg.moe_experts,
+                    self, cfg.emb_dim, width, cfg.moe_experts,
                 )
                 y, aux = moe_mlp(
                     h, moe_p, top_k=cfg.moe_top_k,
@@ -331,19 +502,49 @@ class Block(nn.Module):
                 # y inherits ln2's fp32; keep the residual stream in the
                 # compute dtype like the dense-MLP path does
                 return y.astype(cfg.dtype)
-            m = nn.Dense(cfg.mlp_ratio * cfg.emb_dim, dtype=cfg.dtype,
-                         name="fc1")(h)
-            return nn.Dense(cfg.emb_dim, dtype=cfg.dtype,
-                            name="fc2")(act_store(nn.gelu(m), cfg))
+            if cfg.mlp == "silu_gated":
+                gate_up = dense(2 * width, "fc1")(h)
+                m = jax.nn.silu(gate_up[..., :width]) * gate_up[..., width:]
+            else:
+                m = nn.gelu(dense(width, "fc1")(h))
+            return dense(cfg.emb_dim, "fc2")(act_store(m, cfg))
 
+        mixer = {}
+        if self.layer_type == "mamba":
+            inner, heads = cfg.ssm_inner, cfg.ssm_heads
+            conv_dim = inner + 2 * cfg.ssm_groups * cfg.ssm_state
+            zeros, ones = nn.initializers.zeros, nn.initializers.ones
+
+            def ssm(h):
+                return mamba_mixer(
+                    cfg, h,
+                    in_proj=nn.Dense(inner + conv_dim + heads,
+                                     dtype=cfg.dtype, use_bias=False,
+                                     name="in_proj"),
+                    conv_kernel=self.param(
+                        "conv_kernel", _conv_init,
+                        (cfg.ssm_conv, conv_dim), jnp.float32),
+                    conv_bias=self.param("conv_bias", zeros, (conv_dim,),
+                                         jnp.float32),
+                    dt_bias=self.param("dt_bias", _dt_bias_init, (heads,),
+                                       jnp.float32),
+                    a_log=self.param(
+                        "A_log", lambda *_: jnp.log(
+                            jnp.arange(1, heads + 1, dtype=jnp.float32))),
+                    d_skip=self.param("D", ones, (heads,), jnp.float32),
+                    norm_scale=self.param("ssm_norm", ones, (inner,),
+                                          jnp.float32),
+                    out_proj=nn.Dense(cfg.emb_dim, dtype=cfg.dtype,
+                                      use_bias=False, name="out_proj"),
+                )
+
+            mixer["ssm"] = ssm
+        else:
+            mixer["qkv"] = dense(cfg.emb_dim + 2 * kv_dim, "qkv")
+            mixer["proj"] = dense(cfg.emb_dim, "proj")
         return block_math(
             cfg, x, positions, rope_tabs,
-            ln1=nn.LayerNorm(dtype=jnp.float32, name="ln1"),
-            qkv=nn.Dense(cfg.emb_dim + 2 * kv_dim, dtype=cfg.dtype,
-                         name="qkv"),
-            proj=nn.Dense(cfg.emb_dim, dtype=cfg.dtype, name="proj"),
-            ln2=nn.LayerNorm(dtype=jnp.float32, name="ln2"),
-            mlp=mlp,
+            ln1=_norm(cfg, "ln1"), ln2=_norm(cfg, "ln2"), mlp=mlp, **mixer,
         )
 
 
@@ -371,10 +572,12 @@ class GPT(nn.Module):
     @nn.compact
     def __call__(self, tokens, pos_offset=0, positions=None):
         cfg = self.cfg
+        wte = nn.Embed(cfg.vocab_size, cfg.emb_dim, dtype=cfg.dtype,
+                       name="wte")
         with jax.named_scope(scopes.EMBED):
-            tok = nn.Embed(
-                cfg.vocab_size, cfg.emb_dim, dtype=cfg.dtype, name="wte"
-            )(tokens)
+            tok = wte(tokens)
+            if cfg.embedding_multiplier != 1.0:
+                tok = tok * cfg.embedding_multiplier
         s = tokens.shape[1]
         if s > cfg.max_len:
             raise ValueError(
@@ -417,19 +620,29 @@ class GPT(nn.Module):
         if cfg.remat:
             block_cls = nn.remat(
                 Block,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+                policy=getattr(jax.checkpoint_policies, cfg.remat_policy),
             )
         for i in range(cfg.num_layers):
-            x = block_cls(cfg, name=f"block{i}")(x, positions, rope_tabs)
+            x = block_cls(cfg, cfg.layer_type(i), name=f"block{i}")(
+                x, positions, rope_tabs)
         # Final norm, LM head and the fp32 cast under one scope, like
         # the raw-weights epilogue (tensor_parallel._gpt_head).
         with jax.named_scope(scopes.HEAD):
-            x = nn.LayerNorm(dtype=jnp.float32, name="lnf")(x)
-            logits = nn.Dense(
-                cfg.vocab_size, dtype=cfg.dtype, use_bias=False,
-                name="head"
-            )(x)
-            return logits.astype(jnp.float32)
+            x = _norm(cfg, "lnf")(x)
+            if cfg.tie_embeddings:
+                # one matrix, two uses: it receives both gradients
+                logits = jnp.einsum(
+                    "bsd,vd->bsv", x.astype(cfg.dtype),
+                    wte.embedding.astype(cfg.dtype),
+                    preferred_element_type=jnp.float32)
+            else:
+                logits = nn.Dense(
+                    cfg.vocab_size, dtype=cfg.dtype, use_bias=False,
+                    name="head"
+                )(x).astype(jnp.float32)
+            if cfg.logits_scaling != 1.0:
+                logits = logits / cfg.logits_scaling
+            return logits
 
 
 # Named sizes (GPT-2 family geometry; head_dim 64, MXU-friendly widths).
@@ -439,6 +652,26 @@ GPT_CONFIGS = {
     "small": TransformerConfig(num_layers=12, num_heads=12, emb_dim=768),
     "medium": TransformerConfig(num_layers=24, num_heads=16, emb_dim=1024),
     "large": TransformerConfig(num_layers=36, num_heads=20, emb_dim=1280),
+    # https://huggingface.co/ibm-granite/granite-4.0-h-micro config.json
+    # (model_type granitemoehybrid, no routed experts): nine Mamba-2
+    # layers to one grouped-query attention layer, no positional
+    # embedding, a silu-gated feed-forward of 8192 in every layer.
+    # Training path only (require_gpt2_block says who refuses it).
+    "granite-4.0-h-micro": TransformerConfig(
+        vocab_size=100352, num_layers=40, emb_dim=2048, max_len=131072,
+        layer_types=tuple("attention" if i % 10 == 5 else "mamba"
+                          for i in range(40)),
+        num_heads=32, num_kv_heads=8, attention_scale=0.015625,
+        pos_embedding="none", mlp_ratio=4, mlp="silu_gated",
+        norm="rmsnorm", norm_eps=1e-5, use_bias=False,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=8.0, tie_embeddings=True,
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=1,
+        ssm_conv=4, ssm_chunk=256,
+        # a Mamba block's matmul outputs are 0.47 GB at 8192 tokens:
+        # keep only each block's input
+        remat_policy="nothing_saveable",
+    ),
 }
 
 

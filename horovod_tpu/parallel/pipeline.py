@@ -53,6 +53,9 @@ def stack_pp_params(params, cfg, pp: int):
     tensor_parallel.stack_tp_params for why that distinction is
     load-bearing under autodiff).
     """
+    from ..models.transformer import require_gpt2_block  # noqa: PLC0415
+
+    require_gpt2_block(cfg, "parallel.pipeline.stack_pp_params")
     if cfg.num_layers % pp:
         raise ValueError(
             f"pp={pp} must divide num_layers={cfg.num_layers}"
@@ -89,6 +92,9 @@ def stack_pp_params_circular(params, cfg, pp: int, circles: int):
     stream can wrap through every device ``circles`` times
     (:func:`pp_gpt_loss_circular`).  ``replicated`` as in
     :func:`stack_pp_params`."""
+    from ..models.transformer import require_gpt2_block  # noqa: PLC0415
+
+    require_gpt2_block(cfg, "parallel.pipeline.stack_pp_params_circular")
     if circles < 1:
         raise ValueError(f"circles={circles} must be >= 1")
     if cfg.num_layers % (pp * circles):
@@ -236,8 +242,12 @@ class _Schedule:
                  pp_axis, microbatches, pos_offset, positions, remat,
                  contiguous=True, local=None, layer_fn=None,
                  extra_axes=()):
+        from ..models.transformer import (  # noqa: PLC0415
+            require_gpt2_block,
+        )
         from .tensor_parallel import _gpt_embed  # noqa: PLC0415
 
+        require_gpt2_block(cfg, "parallel.pipeline")
         self.pp_axis = pp_axis
         self.pp = lax.axis_size(pp_axis)
         self.stage = lax.axis_index(pp_axis)
@@ -574,8 +584,10 @@ def stack_tp_pp_params(params, cfg, pp: int, tp: int):
       ...]``: ``in_specs=P(pp_axis)``.
     * ``replicated`` — embeddings, final LN, head: ``in_specs=P()``.
     """
+    from ..models.transformer import require_gpt2_block  # noqa: PLC0415
     from .tensor_parallel import stack_tp_params  # noqa: PLC0415
 
+    require_gpt2_block(cfg, "parallel.pipeline.stack_tp_pp_params")
     if cfg.num_layers % pp:
         raise ValueError(
             f"pp={pp} must divide num_layers={cfg.num_layers}"

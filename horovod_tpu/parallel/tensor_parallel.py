@@ -77,6 +77,9 @@ def stack_tp_params(params, cfg, tp: int):
     Requires ``num_heads % tp == 0`` and ``kv_heads % tp == 0`` (whole
     heads per rank) and ``mlp_ratio * emb_dim % tp == 0``.
     """
+    from ..models.transformer import require_gpt2_block  # noqa: PLC0415
+
+    require_gpt2_block(cfg, "parallel.tensor_parallel.stack_tp_params")
     if cfg.num_heads % tp or cfg.kv_heads % tp:
         raise ValueError(
             f"tp={tp} must divide num_heads={cfg.num_heads} and "
@@ -249,9 +252,10 @@ def _tp_block(cfg, p, rep, x, positions, rope_tabs, tp_axis, tp,
     (models/decode.py) supplies one that appends to its per-shard KV
     pages and attends its own heads."""
     from ..models.transformer import (  # noqa: PLC0415
-        block_math, raw_dense, raw_layer_norm,
+        block_math, raw_dense, raw_layer_norm, require_gpt2_block,
     )
 
+    require_gpt2_block(cfg, "parallel.tensor_parallel")
     dt = cfg.dtype
 
     def row(kernel, bias):  # row-parallel: psum rejoin, then the bias
@@ -292,8 +296,10 @@ def tp_gpt_apply(sharded_params, replicated_params, cfg, tokens,
     to the unsharded model's.  Use ``check_vma=True`` (replication
     tracking) when differentiating — see ``stack_tp_params``.
     """
+    from ..models.transformer import require_gpt2_block  # noqa: PLC0415
     from ..ops.collectives import axis_size  # noqa: PLC0415
 
+    require_gpt2_block(cfg, "parallel.tensor_parallel.tp_gpt_apply")
     tp = axis_size(tp_axis)
     p = jax.tree_util.tree_map(lambda a: a[0], sharded_params)
     rep = replicated_params

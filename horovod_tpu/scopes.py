@@ -17,6 +17,9 @@ ALLREDUCE = "allreduce"                # every traced allreduce, whatever XLA
                                        # calls it (all-reduce.81, psum.220)
 OPTIMIZER_UPDATE = "optimizer_update"  # the wrapped optimizer's update
 ATTN = "attn"                          # a block's attention half
+SSM = "ssm"                            # a block's Mamba-2 mixer half: in_proj,
+                                       # conv, scan, gated norm, out_proj
+SSD_SCAN = "ssd_scan"                  # the state-space scan alone, inside ssm
 MLP = "mlp"                            # a block's MLP half
 EMBED = "embed"                        # token and position embedding
 HEAD = "head"                          # final norm and output projection
@@ -25,5 +28,5 @@ KV_GATHER = "kv_gather"                # paged decode: pages -> contiguous KV
 KV_SCATTER = "kv_scatter"              # paged prefill: KV -> pages
 SAMPLE = "sample"                      # the token pick
 
-SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLP, EMBED,
-          HEAD, STEM, KV_GATHER, KV_SCATTER, SAMPLE)
+SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, SSM, SSD_SCAN,
+          MLP, EMBED, HEAD, STEM, KV_GATHER, KV_SCATTER, SAMPLE)

@@ -108,6 +108,9 @@ class SlotEngine:
                  sample_seed: int = 0):
         if kv_mode not in ("contiguous", "paged"):
             raise ValueError(f"unknown kv_mode {kv_mode!r}")
+        from ..models.transformer import require_gpt2_block  # noqa: PLC0415
+
+        require_gpt2_block(cfg, "serve.engine.SlotEngine")
         self.cfg = cfg
         self.params = params
         self.num_slots = num_slots

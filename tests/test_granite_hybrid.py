@@ -1,0 +1,278 @@
+"""The hybrid Mamba-2 / grouped-query-attention model (the configuration
+``granite-4.0-h-micro``): the chunked state-space scan against the
+token-by-token recurrence, the model against its plain reference
+(tests/granite_reference.py), and the paths that cannot run such a
+configuration refusing it.  All on the CPU at small sizes with seeded
+weights: hidden 64, 4 Mamba heads of 16, state 16, chunk 8, a 4-layer
+pattern with one attention layer, 2 K/V heads under 4 query heads.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import granite_reference as ref  # noqa: E402
+
+from horovod_tpu.models.transformer import GPT_CONFIGS, gpt  # noqa: E402
+from horovod_tpu.ops.ssd import ssd_scan  # noqa: E402
+
+SMALL = dict(
+    num_layers=4, layer_types=("mamba", "attention", "mamba", "mamba"),
+    vocab_size=256, emb_dim=64, num_heads=4, num_kv_heads=2, ssm_heads=4,
+    ssm_head_dim=16, ssm_state=16, ssm_chunk=8, attention_scale=0.125,
+    attention_impl="reference", dtype=jnp.float32)
+CONFIG = dict(
+    layer_types=SMALL["layer_types"], mamba_n_heads=4, mamba_d_head=16,
+    mamba_n_groups=1, mamba_d_state=16, mamba_d_conv=4,
+    num_attention_heads=4, num_key_value_heads=2,
+    attention_multiplier=0.125, rms_norm_eps=1e-5, residual_multiplier=0.22,
+    embedding_multiplier=12.0, logits_scaling=8.0)
+TOKENS = jax.random.randint(jax.random.PRNGKey(0), (2, 33), 0, 256)
+
+
+def scan_inputs(b=2, s=48, h=4, p=16, g=2, n=16):
+    k = jax.random.split(jax.random.PRNGKey(0), 6)
+    return (jax.random.normal(k[0], (b, s, h, p)),
+            0.5 * jax.nn.softplus(jax.random.normal(k[1], (b, s, h))),
+            -jnp.exp(0.5 * jax.random.normal(k[2], (h,))),
+            jax.random.normal(k[3], (b, s, g, n)),
+            jax.random.normal(k[4], (b, s, g, n)),
+            jax.random.normal(k[5], (h,)))
+
+
+# chunk lengths 8 and 16 over 48 tokens (6 and 3 chunks a sequence), and
+# the whole sequence as one chunk (no state is carried)
+@pytest.mark.parametrize("chunk", [8, 16, 48])
+def test_ssd_scan_matches_token_recurrence(chunk):
+    args = scan_inputs()
+    weight = jax.random.normal(jax.random.PRNGKey(7), args[0].shape)
+    with jax.default_matmul_precision("highest"):
+        got = ssd_scan(*args, chunk)
+        want = ref.ssd_recurrence(*args)
+        got_grads = jax.grad(
+            lambda *a: (ssd_scan(*a, chunk) * weight).sum(),
+            argnums=range(6))(*args)
+        want_grads = jax.grad(
+            lambda *a: (ref.ssd_recurrence(*a) * weight).sum(),
+            argnums=range(6))(*args)
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-5)
+    for name, a, b in zip("x dt A B C D".split(), got_grads, want_grads):
+        np.testing.assert_allclose(
+            a, b, atol=1e-5 * float(jnp.abs(b).max()), rtol=1e-4,
+            err_msg=f"gradient of {name}")
+
+
+def test_ssd_scan_refuses_a_sequence_the_chunk_does_not_divide():
+    with pytest.raises(ValueError, match="multiple of chunk"):
+        ssd_scan(*scan_inputs(s=44), 8)
+
+
+def test_ssd_scan_has_no_token_loop_and_no_seq_by_seq_tensor():
+    """The program's scan has no loop at all (the recurrence over the 6
+    chunk states is one triangular product), and no array has two
+    sequence-long axes."""
+    args = scan_inputs()
+    jaxpr = jax.make_jaxpr(lambda *a: ssd_scan(*a, 8))(*args)
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    for eqn in walk(jaxpr.jaxpr):
+        assert eqn.primitive.name not in ("scan", "while"), eqn
+        for var in eqn.outvars:
+            assert sum(d >= 48 for d in var.aval.shape) <= 1, var.aval
+
+
+def seeded(model):
+    """Seeded weights with every leaf moved off its initial value, so
+    that D = 1, the norms' ones and the conv's zero bias hide nothing."""
+    params = model.init(jax.random.PRNGKey(1), TOKENS[:, :-1])
+    leaves, treedef = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(2), len(leaves))
+    return jax.tree.unflatten(treedef, [
+        leaf + 0.1 * jax.random.normal(key, leaf.shape)
+        for leaf, key in zip(leaves, keys)])
+
+
+def program_loss(model, params):
+    logits = model.apply(params, TOKENS[:, :-1])
+    return optax.softmax_cross_entropy_with_integer_labels(
+        logits, TOKENS[:, 1:]).mean()
+
+
+def apart(model, params, depart=None):
+    """(largest logit difference, norm of the gradients' difference over
+    the reference's norm) of the program against the plain reference."""
+    with jax.default_matmul_precision("highest"):
+        got = model.apply(params, TOKENS[:, :-1])
+        want = ref.logits(CONFIG, params, TOKENS[:, :-1], depart)
+        got_grads = jax.grad(lambda p: program_loss(model, p))(params)
+        want_grads = jax.grad(
+            lambda p: ref.loss(CONFIG, p, TOKENS, depart))(params)
+
+    def norm(tree):
+        return jnp.sqrt(sum(jnp.sum(a * a) for a in jax.tree.leaves(tree)))
+
+    return (float(jnp.abs(got - want).max()),
+            float(norm(jax.tree.map(jnp.subtract, got_grads, want_grads))
+                  / norm(want_grads)))
+
+
+@pytest.mark.parametrize("attention", ["reference", "flash"])
+def test_model_matches_plain_reference(attention):
+    model = gpt("granite-4.0-h-micro",
+                **{**SMALL, "attention_impl": attention})
+    logits_apart, grads_apart = apart(model, seeded(model))
+    assert logits_apart < 1e-4 and grads_apart < 1e-4
+
+
+# One case per thing the model adds: the program built with a multiplier
+# of 1, or the reference departing from one equation, and the comparison
+# that passes above fails.
+@pytest.mark.parametrize("program,depart", [
+    ({"embedding_multiplier": 1.0}, None),
+    ({"residual_multiplier": 1.0}, None),
+    ({"logits_scaling": 1.0}, None),
+    ({"attention_scale": None}, None),
+    ({}, "norm_then_gate"),
+    ({}, "wrong_kv_heads"),
+    ({}, "conv_shift"),
+])
+def test_comparison_fails_on_a_seeded_departure(program, depart):
+    model = gpt("granite-4.0-h-micro", **{**SMALL, **program})
+    logits_apart, grads_apart = apart(model, seeded(model), depart)
+    assert logits_apart > 1e-2 and grads_apart > 1e-2
+
+
+def test_tied_head_is_one_matrix_with_both_gradients():
+    model = gpt("granite-4.0-h-micro", **SMALL)
+    params = seeded(model)
+    assert "head" not in params["params"]
+    assert params["params"]["wte"]["embedding"].shape == (256, 64)
+    grads = jax.grad(lambda p: program_loss(model, p))(params)
+    table = grads["params"]["wte"]["embedding"]
+    seen = np.unique(np.asarray(TOKENS[:, :-1]))
+    unseen = np.setdiff1d(np.arange(256), seen)
+    # a row no token looked up still gets the head's gradient, and the
+    # lookup's comes on top for the rows that were
+    assert float(jnp.abs(table[unseen]).min()) > 0
+    head_only = jax.grad(lambda p: program_loss(model, {"params": {
+        **p["params"], "wte": jax.lax.stop_gradient(p["params"]["wte"])}})
+    )(params)
+    assert float(jnp.abs(head_only["params"]["wte"]["embedding"]).max()) == 0
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(lambda p: ref.loss(CONFIG, p, TOKENS))(params)
+    np.testing.assert_allclose(
+        table, want["params"]["wte"]["embedding"], atol=1e-5, rtol=1e-3)
+
+
+def test_named_configuration_holds_the_published_values():
+    cfg = GPT_CONFIGS["granite-4.0-h-micro"]
+    assert cfg.num_layers == 40 and len(cfg.layer_types) == 40
+    assert [i for i, k in enumerate(cfg.layer_types)
+            if k == "attention"] == [5, 15, 25, 35]
+    assert (cfg.emb_dim, cfg.num_heads, cfg.kv_heads, cfg.head_dim) == (
+        2048, 32, 8, 64)
+    assert (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups,
+            cfg.ssm_conv, cfg.ssm_chunk) == (64, 64, 128, 1, 4, 256)
+    assert cfg.mlp_ratio * cfg.emb_dim == 8192 and cfg.mlp == "silu_gated"
+    assert (cfg.vocab_size, cfg.max_len) == (100352, 131072)
+    assert (cfg.attention_scale, cfg.embedding_multiplier,
+            cfg.residual_multiplier, cfg.logits_scaling) == (
+                0.015625, 12.0, 0.22, 8.0)
+    assert cfg.pos_embedding == "none" and cfg.tie_embeddings
+    assert cfg.norm == "rmsnorm" and cfg.norm_eps == 1e-5
+    assert not cfg.use_bias
+    # the issue's table: one period and an eighth of the vocabulary
+    shapes = jax.eval_shape(
+        gpt("granite-4.0-h-micro", num_layers=10,
+            layer_types=cfg.layer_types[:10], vocab_size=12544,
+            attention_impl="reference").init,
+        jax.random.PRNGKey(0), jnp.zeros((1, 256), jnp.int32))
+    assert sum(int(np.prod(a.shape))
+               for a in jax.tree.leaves(shapes)) == 772_160_448
+
+
+def test_gpt_nano_tree_and_loss_are_the_parents():
+    """``gpt("nano")`` builds the parameter tree and computes the loss it
+    did before the block took its mixer per layer: the numbers are the
+    parent commit's (488ae9f), read there with this very code."""
+    model = gpt("nano", attention_impl="reference")
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 33), 0, 1024)
+    params = model.init(jax.random.PRNGKey(1), tokens[:, :-1])
+    assert list(params["params"]) == [
+        "wte", "wpe", "block0", "block1", "block2", "lnf", "head"]
+    for i in range(3):
+        assert list(params["params"][f"block{i}"]) == [
+            "ln1", "qkv", "proj", "ln2", "fc1", "fc2"]
+        assert set(params["params"][f"block{i}"]["qkv"]) == {"kernel", "bias"}
+    total = sum(float(jnp.abs(a).sum()) for a in jax.tree.leaves(params))
+    assert total == pytest.approx(55945.50500488281, rel=1e-6)
+    loss = optax.softmax_cross_entropy_with_integer_labels(
+        model.apply(params, tokens[:, :-1]), tokens[:, 1:]).mean()
+    assert float(loss) == pytest.approx(7.709083557128906, abs=1e-5)
+
+
+def _refusals():
+    from horovod_tpu.models import decode
+    from horovod_tpu.parallel import pipeline, tensor_parallel
+    from horovod_tpu.models.transformer import raw_block_forward
+    from horovod_tpu.serve.engine import SlotEngine
+
+    x = jnp.zeros((1, 8, 64))
+    return {
+        "generate": lambda c, p, t: decode.generate(c, p, t, 4),
+        "prefill": lambda c, p, t: decode.prefill(c, p, t),
+        "decode_step": lambda c, p, t: decode.decode_step(
+            c, p, None, t[:, 0]),
+        "decode_step_paged": lambda c, p, t: decode.decode_step_paged(
+            c, p, None, None, t[:, 0], None),
+        "init_cache": lambda c, p, t: decode.init_cache(c, 1),
+        "init_paged_pool": lambda c, p, t: decode.init_paged_pool(c, 4, 8, 2),
+        "slot_engine": lambda c, p, t: SlotEngine(c, p, 2),
+        "stack_tp_params": lambda c, p, t: tensor_parallel.stack_tp_params(
+            {}, c, 2),
+        "tp_gpt_apply": lambda c, p, t: tensor_parallel.tp_gpt_apply(
+            {}, {}, c, t, "tp"),
+        "stack_pp_params": lambda c, p, t: pipeline.stack_pp_params({}, c, 2),
+        "stack_pp_params_circular":
+            lambda c, p, t: pipeline.stack_pp_params_circular({}, c, 2, 1),
+        "stack_tp_pp_params":
+            lambda c, p, t: pipeline.stack_tp_pp_params({}, c, 2, 2),
+        "pp_gpt_apply": lambda c, p, t: pipeline.pp_gpt_apply(
+            {}, {}, c, t, "pp", microbatches=1),
+        "raw_block_forward": lambda c, p, t: raw_block_forward(
+            c, {}, x, jnp.arange(8), None),
+    }
+
+
+# Every path that builds on block_math with GPT-2's five callables, and
+# every setting it does not implement: refused by name before anything
+# is traced (the parameters handed in are empty).
+@pytest.mark.parametrize("setting,override", [
+    ("layer_types", {}),
+    ("norm", {"layer_types": None, "norm": "rmsnorm"}),
+    ("mlp", {"layer_types": None, "mlp": "silu_gated"}),
+])
+@pytest.mark.parametrize("path", sorted(_refusals()))
+def test_paths_refuse_a_configuration_they_cannot_run(path, setting,
+                                                      override):
+    from dataclasses import replace
+
+    gpt2 = gpt("nano").cfg
+    if setting == "layer_types":
+        cfg = gpt("granite-4.0-h-micro", **SMALL).cfg
+    else:
+        cfg = replace(gpt2, **override)
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    with pytest.raises(ValueError, match=setting):
+        _refusals()[path](cfg, {}, tokens)
