@@ -28,17 +28,30 @@ Design (the standard flash recurrence, TPU-shaped):
   row block, 0.633 as rows; dk/dv 1.210 -> 0.973, dq 0.860 -> 0.679.
 * Causal programs stop their K loop at the diagonal tile — the upper
   triangle is never computed, not just masked.
-* Backward is a blockwise recompute from the saved logsumexp in two
-  Pallas kernels (dk/dv, dq), wired via ``jax.custom_vjp`` so the op
-  drops into training.  ``delta = rowsum(do * o)`` is computed once per
-  call, outside the kernels (0.025 ms there), not once per tile.
+* Backward is a blockwise recompute from the saved logsumexp, wired via
+  ``jax.custom_vjp`` so the op drops into training.  ``delta =
+  rowsum(do * o)`` is computed once per call, outside the kernels
+  (0.025 ms there), not once per tile.  ONE kernel forms each tile's
+  ``p`` and ``ds`` once and takes dq, dk and dv from them, dq's
+  accumulator and a whole kv row's dk and dv accumulators resident in
+  VMEM, wherever those fit the VMEM the call states (a function of the
+  shape alone: ``_fused_bwd_vmem_bytes`` against
+  ``_FUSED_BWD_VMEM_LIMIT``; 8192 keys at head size 64 or 128).
+  A longer sequence takes the two passes it replaced (dk/dv, then dq,
+  each recomputing ``p`` and ``ds``), whose VMEM does not grow with S.
+  Per call at 128 x 1024 x 64 (chip runs of PR 29): two passes 0.973 +
+  0.679 ms, one kernel 1.025; at 32 query over 8 K/V heads x 8192 x 64,
+  10.72 + 7.51 against 12.00.
 * Off-TPU (the CPU test mesh) the same kernel runs through the Pallas
   interpreter, so correctness tests don't need TPU hardware.
-* The three ``pallas_call`` sites are named ``flash_fwd``,
-  ``flash_bwd_dkdv`` and ``flash_bwd_dq``: XLA calls the compiled
-  instruction after the name (``flash_fwd.2``), so a device trace tells
-  forward, dk/dv and dq apart and a later Pallas kernel is not counted
-  as attention.
+* The ``pallas_call`` sites are named ``flash_fwd``, ``flash_bwd_dkdv``
+  and ``flash_bwd_dq``: XLA calls the compiled instruction after the
+  name (``flash_fwd.2``), so a device trace tells the kernels apart and
+  a later Pallas kernel is not counted as attention.  The one-kernel
+  backward keeps the name ``flash_bwd_dkdv`` and covers dq, dk and dv
+  under it (the benchmark's reader knows the two backward names and no
+  third); ``flash_bwd_dq`` occurs only where the two passes ran, so a
+  trace says which path every call took.
 """
 
 from __future__ import annotations
@@ -170,6 +183,30 @@ def _kv_row(zi, h: int, hkv: int):
     return (zi // h) * hkv + (zi % h) // (h // hkv)
 
 
+# The VMEM the one-kernel backward states (``vmem_limit_bytes``): twice
+# a v5e's default scoped limit, a quarter of its VMEM.  A backward whose
+# resident dk/dv accumulators and tiles fit it runs as one kernel; a
+# longer sequence takes the two passes, whose VMEM does not grow with S.
+_FUSED_BWD_VMEM_LIMIT = 32 * 2 ** 20
+
+
+def _fused_bwd_vmem_bytes(s: int, d: int, bq: int, bk: int,
+                          itemsize: int) -> int:
+    """VMEM the one-kernel backward holds for a kv row of ``s`` keys,
+    every buffer's minor dimension padded to the 128 lanes its tiles
+    occupy: the two float32 accumulators, the dk and dv output blocks
+    (two buffers each), the streamed tiles (two buffers each), dq's
+    accumulator and six score-sized float32 temporaries.  The TPU
+    compiler asked 32.63 MiB for 16384 x 64 in bfloat16 inside a
+    differentiated ``flash_attention`` (sandbox compile for a v5e,
+    PR 29), where this counts 36.1; the unpadded count, 19.6, was wrong
+    there."""
+    lanes = -(-d // 128) * 128
+    resident = 2 * s * lanes * 4 + 2 * 2 * s * lanes * itemsize
+    tiles = 2 * (3 * bq + 2 * bk) * lanes * itemsize
+    return resident + tiles + d * bq * 4 + 6 * bk * bq * 4
+
+
 def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
                       interpret):
     """Returns (o [Z,S,D], lse [Z,S]) with Z = batch*heads.
@@ -275,26 +312,41 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
                       h, hkv, window, interpret):
-    """Fused Pallas flash backward: two passes, both tiled, both skipping
-    fully-masked causal blocks (the scan fallback below computes the whole
-    upper triangle and streams O(S*bk) score tiles through HBM — on a
-    causal LM that is ~2x wasted FLOPs and the dominant HBM stream).
+    """Pallas flash backward, tiled, skipping fully-masked causal blocks
+    (the scan fallback below computes the whole upper triangle and
+    streams O(S*bk) score tiles through HBM — on a causal LM that is ~2x
+    wasted FLOPs and the dominant HBM stream).  P is recomputed from the
+    forward's saved logsumexp; ``delta`` = rowsum(do*o) is the standard
+    softmax-backward correction.
 
-    Pass A (grid z_kv, nk, nq*group): K tile fixed, (q-head-in-group, Q
-    tile) pairs stream sequentially; dk/dv accumulate in VMEM scratch —
-    under GQA the whole group's contribution folds into one kv row — and
-    flush at the last pair.
-    Pass B (grid z, nq, nk): Q tile fixed, K tiles stream; dq accumulates
-    (as [d, bq], turned once at the flush).
-    Both recompute P from the forward's saved logsumexp; ``delta`` =
-    rowsum(do*o) is the standard softmax-backward correction.
+    One kernel (grid z, nq, nk; call site ``flash_bwd_dkdv``, which here
+    covers dq, dk AND dv) wherever ``_fused_bwd_vmem_bytes`` of the shape
+    fits ``_FUSED_BWD_VMEM_LIMIT``: Q tile fixed, K tiles stream, each
+    needed tile's P and dS formed once.  dq accumulates as [d, bq],
+    turned once at the flush; dk and dv accumulate in two [S, d] float32
+    scratch buffers that live for a whole kv row — under GQA the group's
+    query heads are consecutive z and fold into them — zeroed at the
+    row's first grid step and written, cast once, to the (1, S, d)
+    output blocks at its last.  No partial sum goes through HBM.  The
+    other orientation (K tile fixed, dq resident as [group, S, d]) read
+    1.093 ms a call where this one reads 1.025, and 13.08 against 12.00
+    at 8192 keys with grouped heads (chip runs of PR 29).
+
+    Two passes above that limit, each recomputing P and dS, VMEM
+    independent of S:
+    Pass A (grid z_kv, nk, nq*group; ``flash_bwd_dkdv``): K tile fixed,
+    (q-head-in-group, Q tile) pairs stream sequentially; dk/dv
+    accumulate in VMEM scratch — under GQA the whole group's
+    contribution folds into one kv row — and flush at the last pair.
+    Pass B (grid z, nq, nk; ``flash_bwd_dq``): Q tile fixed, K tiles
+    stream; dq accumulates (as [d, bq], turned once at the flush).
     """
     z, s, d = q.shape
     z_kv = k.shape[0]
     group = h // hkv
     nq, nk = s // bq, s // bk
     f32 = jnp.float32
-    # delta is computed once per call and shared by both kernels, which
+    # delta is computed once per call and shared by all kernels, which
     # read it and lse as (1, bq) rows of a [Z, nq, 1, bq] view (a block
     # equal to the last two dims is legal for any bq).  What the chip
     # said of the alternatives (PR 26, per call at 128 x 1024 x 64):
@@ -307,13 +359,25 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
     delta = (do.astype(f32) * o.astype(f32)).sum(-1)
     lse_r, delta_r = (x.reshape(z, nq, 1, bq) for x in (lse, delta))
 
+    def _tile_needed(i, j):
+        """Does Q tile ``i`` see K tile ``j``?  Causal: not if the K tile
+        is entirely above the diagonal; with a window, not if it is
+        entirely below the band either.  The forward's predicate, one
+        definition for all three backward kernels."""
+        needed = (j * bk <= (i + 1) * bq - 1) if causal else (j >= 0)
+        if window is not None:
+            needed = jnp.logical_and(
+                needed, (j + 1) * bk - 1 >= i * bq - (window - 1)
+            )
+        return needed
+
     def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         i, j):
         """The shared backward recurrence: rebuild this tile's softmax P
         from the saved logsumexp and form dS = P * (dP - delta), both
         transposed like the forward's tile (keys on sublanes, queries
-        on lanes).  One definition for both passes so the mask/scale
-        math cannot drift."""
+        on lanes).  One definition for all three kernels so the
+        mask/scale math cannot drift."""
         qb = q_ref[0].astype(f32)
         kb = k_ref[0].astype(f32)
         vb = v_ref[0].astype(f32)
@@ -341,15 +405,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
             dk_acc[...] = jnp.zeros_like(dk_acc)
             dv_acc[...] = jnp.zeros_like(dv_acc)
 
-        # Q tiles entirely above the diagonal see only masked scores;
-        # with a window, Q tiles entirely past the band do too.
-        needed = ((i + 1) * bq - 1 >= j * bk) if causal else (i >= 0)
-        if window is not None:
-            needed = jnp.logical_and(
-                needed, (j + 1) * bk - 1 >= i * bq - (window - 1)
-            )
-
-        @pl.when(needed)
+        @pl.when(_tile_needed(i, j))
         def _compute():
             qb, _, dob, p, ds = _recompute_p_ds(
                 q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, j
@@ -372,13 +428,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
         def _init():
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
-        needed = (j * bk <= (i + 1) * bq - 1) if causal else (j >= 0)
-        if window is not None:
-            needed = jnp.logical_and(
-                needed, (j + 1) * bk - 1 >= i * bq - (window - 1)
-            )
-
-        @pl.when(needed)
+        @pl.when(_tile_needed(i, j))
         def _compute():
             _, kb, _, _, ds = _recompute_p_ds(
                 q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, j
@@ -391,8 +441,90 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
         def _flush():
             dq_ref[0] = dq_acc[...].T.astype(dq_ref.dtype)
 
+    def kernel_fused(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
+        zi = pl.program_id(0)
+        i = pl.program_id(1)
+        j = pl.program_id(2)
+        # a kv row's query heads are consecutive zi: its accumulators
+        # live from the first head's first tile to the last head's last
+        first_tile = jnp.logical_and(i == 0, j == 0)
+        last_tile = jnp.logical_and(i == nq - 1, j == nk - 1)
+
+        @pl.when(jnp.logical_and(zi % group == 0, first_tile))
+        def _init_row():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
+
+        @pl.when(j == 0)
+        def _init():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+
+        @pl.when(_tile_needed(i, j))
+        def _compute():
+            qb, kb, dob, p, ds = _recompute_p_ds(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, j
+            )
+            rows = pl.ds(pl.multiple_of(j * bk, bk), bk)
+            dv_acc[rows, :] += jnp.dot(p, dob, preferred_element_type=f32)
+            dk_acc[rows, :] += jnp.dot(ds, qb,
+                                       preferred_element_type=f32) * scale
+            dq_acc[...] += lax.dot_general(
+                kb, ds, _CONTRACT_ROWS, preferred_element_type=f32,
+            ) * scale                                   # [d, bq]
+
+        @pl.when(j == nk - 1)
+        def _flush():
+            dq_ref[0] = dq_acc[...].T.astype(dq_ref.dtype)
+
+        @pl.when(jnp.logical_and(zi % group == group - 1, last_tile))
+        def _flush_row():
+            dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
     qkv_spec = lambda tile, which: pl.BlockSpec((1, tile, d), which)
     stat_spec = lambda which: pl.BlockSpec((1, 1, 1, bq), which)
+
+    if (_fused_bwd_vmem_bytes(s, d, bq, bk, q.dtype.itemsize)
+            <= _FUSED_BWD_VMEM_LIMIT):
+        q_tile = lambda zi, ii, ji: (zi, ii, 0)
+        q_stat = lambda zi, ii, ji: (zi, ii, 0, 0)
+        kv_tile = lambda zi, ii, ji: (_kv_row(zi, h, hkv), ji, 0)
+        kv_whole = lambda zi, ii, ji: (_kv_row(zi, h, hkv), 0, 0)
+        return pl.pallas_call(
+            kernel_fused,
+            grid=(z, nq, nk),
+            in_specs=[
+                qkv_spec(bq, q_tile),
+                qkv_spec(bk, kv_tile),
+                qkv_spec(bk, kv_tile),
+                qkv_spec(bq, q_tile),       # do
+                stat_spec(q_stat),          # lse
+                stat_spec(q_stat),          # delta
+            ],
+            out_specs=[
+                qkv_spec(bq, q_tile),
+                qkv_spec(s, kv_whole),
+                qkv_spec(s, kv_whole),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((z, s, d), q.dtype),
+                jax.ShapeDtypeStruct((z_kv, s, d), k.dtype),
+                jax.ShapeDtypeStruct((z_kv, s, d), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((d, bq), f32),
+                pltpu.VMEM((s, d), f32),
+                pltpu.VMEM((s, d), f32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                # dk and dv accumulate across all three axes
+                dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=_FUSED_BWD_VMEM_LIMIT,
+            ),
+            interpret=interpret,
+            name="flash_bwd_dkdv",
+        )(q, k, v, do, lse_r, delta_r)
 
     def _qrow(zi, ti):
         """Pass-A q row for kv row ``zi`` and inner step ``ti``."""

@@ -561,3 +561,136 @@ def test_row_statistics_across_tiles(causal, window, h, hkv, bq, bk, dtype):
     close("out", out, want, tol)
     for name, a, r in zip(("dq", "dk", "dv"), got_g, want_g):
         close(name, a, r, grad_tol)
+
+
+def _grouped_blockwise(q, k, v, o, lse, do, causal, scale, bk, window, h,
+                       hkv):
+    """`_flash_bwd_blockwise` knows no grouped heads: give every query
+    head its own copy of its kv row and fold dk and dv back."""
+    from horovod_tpu.ops.flash_attention import _flash_bwd_blockwise
+
+    z, s, d = q.shape
+    b, group = z // h, h // hkv
+    f32 = jnp.float32
+    rep = lambda t: jnp.repeat(
+        t.astype(f32).reshape(b, hkv, 1, s, d), group, 2
+    ).reshape(z, s, d)
+    dq, dk, dv = _flash_bwd_blockwise(q.astype(f32), rep(k), rep(v), o, lse,
+                                      do, causal, scale, bk, window=window)
+    fold = lambda t: t.reshape(b, hkv, group, s, d).sum(2).reshape(-1, s, d)
+    return dq, fold(dk), fold(dv)
+
+
+# (id, causal, window, q heads, kv heads, S, block_q, block_k, scale)
+_BWD_PATH_CASES = [
+    ("noncausal", False, None, 2, 2, 64, 16, 16, None),
+    ("causal", True, None, 2, 2, 64, 16, 16, None),
+    ("window24_of_64", True, 24, 2, 2, 64, 16, 16, None),
+    ("gqa_4_to_a_kv_head", True, None, 8, 2, 64, 16, 16, None),
+    ("gqa_noncausal", False, None, 8, 2, 64, 16, 16, None),
+    ("mqa_4_on_1", True, None, 4, 1, 64, 16, 16, None),
+    ("stated_scale", True, None, 4, 1, 64, 16, 16, 0.015625),
+    ("nq_3_nk_6", True, None, 2, 2, 48, 16, 8, None),
+    ("gqa_window_nq_2_nk_8", True, 20, 4, 2, 64, 32, 8, 0.3),
+    ("one_tile", True, None, 2, 1, 32, 32, 32, None),
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "causal,window,h,hkv,s,bq,bk,scale", [c[1:] for c in _BWD_PATH_CASES],
+    ids=[c[0] for c in _BWD_PATH_CASES],
+)
+def test_one_kernel_backward_matches_two_passes_and_oracle(
+        monkeypatch, causal, window, h, hkv, s, bq, bk, scale, dtype):
+    """The backward as one kernel (dq, dk and dv from one p and ds a
+    tile, a kv row's accumulators resident) against the two passes it
+    replaced and against the blockwise scan.  One kernel and two passes
+    add the same float32 terms in the same order (a dk row block gets
+    its terms by query head, then Q tile, in both), so they agree to the
+    bit; the scan sums in another order."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    b, d = 2, 16
+    rng = np.random.RandomState(11)
+    mk = lambda heads: jnp.asarray(rng.randn(b * heads, s, d) * 0.7, dtype)
+    q, do, k, v = mk(h), mk(h), mk(hkv), mk(hkv)
+    scale = d ** -0.5 if scale is None else scale
+    o, lse = fa._flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv,
+                                  window, True)
+    args = (q, k, v, o, lse, do, causal, scale, bq, bk, h, hkv, window, True)
+
+    def kernels():
+        return list(_pallas_calls(
+            jax.make_jaxpr(lambda: fa._flash_bwd_pallas(*args))().jaxpr))
+
+    one = fa._flash_bwd_pallas(*args)
+    assert kernels() == ["flash_bwd_dkdv"]
+    monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", 0)
+    two = fa._flash_bwd_pallas(*args)
+    assert kernels() == ["flash_bwd_dkdv", "flash_bwd_dq"]
+    ref = _grouped_blockwise(q, k, v, o, lse, do, causal, scale, bk, window,
+                             h, hkv)
+    tol = 2e-6 if dtype == jnp.float32 else 1e-2
+    for name, a, t, r in zip(("dq", "dk", "dv"), one, two, ref):
+        assert a.dtype == dtype and a.shape == r.shape, name
+        np.testing.assert_array_equal(
+            np.asarray(a, np.float32), np.asarray(t, np.float32),
+            err_msg=f"{name}: one kernel against two passes")
+        for which, got in (("one kernel", a), ("two passes", t)):
+            got, want = np.asarray(got, np.float32), np.asarray(r)
+            err = np.abs(got - want).max() / np.abs(want).max()
+            assert err <= tol, (
+                f"{name}, {which}: {err:.3g} of the largest entry")
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+# (id, q shape [B,S,H,D], kv heads, dtype, scale, the backward's kernels)
+_ONE_KERNEL = ["flash_bwd_dkdv"]
+_TWO_PASSES = ["flash_bwd_dkdv", "flash_bwd_dq"]
+_GATE_CASES = [
+    ("gpt2m_train_8x1024x16x64", (8, 1024, 16, 64), 16, jnp.bfloat16, None,
+     _ONE_KERNEL),
+    ("granite4hm_1x8192x32on8x64", (1, 8192, 32, 64), 8, jnp.bfloat16,
+     0.015625, _ONE_KERNEL),
+    ("longest_fused_8192x128", (1, 8192, 4, 128), 2, jnp.bfloat16, None,
+     _ONE_KERNEL),
+    ("over_the_budget_16384x64", (1, 16384, 4, 64), 4, jnp.bfloat16, None,
+     _TWO_PASSES),
+    ("over_the_budget_131072x128", (1, 131072, 4, 128), 2, jnp.bfloat16,
+     None, _TWO_PASSES),
+    ("float32_8192x64_fits", (1, 8192, 2, 64), 2, jnp.float32, None,
+     _ONE_KERNEL),
+    ("float32_16384x64_does_not", (1, 16384, 2, 64), 2, jnp.float32, None,
+     _TWO_PASSES),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,kv_heads,dtype,scale,backward", [c[1:] for c in _GATE_CASES],
+    ids=[c[0] for c in _GATE_CASES],
+)
+def test_backward_path_follows_the_shape(shape, kv_heads, dtype, scale,
+                                         backward):
+    """Which backward runs is read from the ``pallas_call`` names in the
+    differentiated jaxpr, as a device trace would read it: one kernel
+    (under the name ``flash_bwd_dkdv``) at both benchmark shapes and up
+    to the VMEM the call states, ``flash_bwd_dq`` beside it only above."""
+    b, s, _, d = shape
+    q = jax.ShapeDtypeStruct(shape, dtype)
+    kv = jax.ShapeDtypeStruct((b, s, kv_heads, d), dtype)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=scale,
+                               interpret=True).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv)
+    assert list(_pallas_calls(jaxpr.jaxpr)) == ["flash_fwd"] + backward

@@ -127,7 +127,7 @@ def test_gpt_step_carries_each_scope(gpt_locations, path):
     assert _has(gpt_locations, *path), path
     # and the backward pass carries the same names, transposed
     if path[0].startswith("block"):
-        back = "/".join(path).replace("flash_fwd", "flash_bwd_dq")
+        back = "/".join(path).replace("flash_fwd", "flash_bwd_dkdv")
         assert any(loc.startswith("transpose(") and back in loc
                    for loc in gpt_locations), back
 
@@ -159,10 +159,11 @@ def _pallas_names(jaxpr, out):
     return out
 
 
-def test_step_holds_three_named_pallas_calls(gpt_step):
+def test_step_holds_its_named_pallas_calls(gpt_step):
+    """Forward, and the one backward kernel under the name it kept."""
     step, args = gpt_step
     names = _pallas_names(jax.make_jaxpr(step)(*args).jaxpr, [])
-    assert sorted(names) == ["flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]
+    assert sorted(names) == ["flash_bwd_dkdv", "flash_fwd"]
 
 
 def _paths(tree):
@@ -210,9 +211,18 @@ def test_resnet50_parameter_tree_is_letter_for_letter_the_same():
     assert _paths(shapes) == sorted(want)
 
 
-def test_named_flash_kernels_are_bitwise_the_unnamed_ones(monkeypatch):
+@pytest.mark.parametrize("vmem_limit, names", [
+    (None, ["flash_bwd_dkdv", "flash_fwd"]),           # one backward kernel
+    (0, ["flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]),      # two passes
+], ids=["one_kernel", "two_passes"])
+def test_named_flash_kernels_are_bitwise_the_unnamed_ones(
+        monkeypatch, vmem_limit, names):
     """A kernel's name is metadata: forward, dq, dk and dv in interpret
-    mode are bit for bit what the unnamed ``pallas_call`` gives."""
+    mode are bit for bit what the unnamed ``pallas_call`` gives, on both
+    backward paths."""
+    if vmem_limit is not None:
+        monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", vmem_limit)
+        jax.clear_caches()
     rng = np.random.RandomState(3)
     q, k, v = (jnp.asarray(rng.randn(2, 64, 4, 16), jnp.float32) * 0.3
                for _ in range(3))
@@ -238,7 +248,7 @@ def test_named_flash_kernels_are_bitwise_the_unnamed_ones(monkeypatch):
     monkeypatch.setattr(fa.pl, "pallas_call", unnamed)
     jax.clear_caches()
     plain = run()
-    assert sorted(seen) == ["flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]
+    assert sorted(seen) == names
     for a, b in zip(named, plain):
         assert a.tobytes() == b.tobytes()
 

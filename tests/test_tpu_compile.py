@@ -111,6 +111,54 @@ def test_granite_attention_compiles_for_v5e(one_chip, direction):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# (id, q shape [B,S,H,D], kv heads, dtype, scale, window, one kernel?)
+_BACKWARD_PATHS = [
+    ("gpt2m_train_128x1024x64", (8, 1024, 16, 64), 16, jnp.bfloat16, None,
+     None, True),
+    ("granite4hm_32on8x8192x64", (1, 8192, 32, 64), 8, jnp.bfloat16,
+     0.015625, None, True),
+    # the longest rows the shape gate admits: what it counts must cover
+    # what the compiler asks for inside the limit the call states
+    ("longest_fp32_8192x64", (1, 8192, 8, 64), 8, jnp.float32, None, None,
+     True),
+    ("longest_fp32_8192x128", (1, 8192, 4, 128), 2, jnp.float32, None,
+     None, True),
+    ("longest_fp32_4096x256_window", (1, 4096, 2, 256), 2, jnp.float32,
+     None, 1024, True),
+    # as one kernel this one asks for 32.63 MiB
+    ("over_the_budget_16384x64", (1, 16384, 8, 64), 8, jnp.bfloat16, None,
+     None, False),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,kv_heads,dtype,scale,window,one_kernel",
+    [c[1:] for c in _BACKWARD_PATHS], ids=[c[0] for c in _BACKWARD_PATHS],
+)
+def test_flash_backward_path_compiles_for_v5e(one_chip, shape, kv_heads,
+                                              dtype, scale, window,
+                                              one_kernel):
+    """The backward as ONE kernel (under the name ``flash_bwd_dkdv``, no
+    ``flash_bwd_dq`` beside it) at both benchmark shapes and at the
+    longest rows the shape gate admits, compiled inside the
+    ``vmem_limit_bytes`` the call states (the TPU compiler refuses a
+    kernel that needs more); the two passes above the budget."""
+    b, s, _, d = shape
+    q = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, kv_heads, d), dtype, sharding=one_chip)
+
+    def backward(q, k, v):
+        return jax.grad(
+            lambda *a: flash_attention(
+                *a, causal=True, scale=scale, window=window,
+                interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(backward).lower(q, kv, kv).compile().as_text()
+    assert "flash_bwd_dkdv" in text
+    assert ("flash_bwd_dq" not in text) == one_kernel
+
+
 @pytest.mark.parametrize("direction", ["forward", "backward"])
 def test_ssd_scan_compiles_for_v5e_without_a_loop(one_chip, direction):
     """The matmul form through XLA: no ``while`` in the compiled program
