@@ -14,7 +14,8 @@
                                       CPU devices (never a chip result)
 
 Every phase goes through the entry points a user calls (``hvd.init``,
-``bench.build_step`` / ``build_gpt_step``, ``ServeJob`` + ``ServeClient``)
+``horovod_tpu.testing.steps.build_step`` / ``build_gpt_step``, ``ServeJob``
++ ``ServeClient``)
 and checks its own output.  A chip belongs to one process at a time, so
 this parent never imports JAX: it runs the phases as children, one after
 another, and builds its last line from what they reported.  That line is
@@ -150,12 +151,13 @@ def _compile(step, state):
 
 def phase_resnet50(rehearse: bool) -> dict:
     """Trainer, conv: hvd.init -> broadcast_parameters ->
-    DistributedOptimizer -> the shard_map+jit step bench.py builds."""
+    DistributedOptimizer -> the shard_map+jit step ``testing/steps.py``
+    builds."""
     device = _start_backend(rehearse)
-    import bench
+    from horovod_tpu.testing.steps import build_step
 
     model, batch, image = _sizes(rehearse)["resnet"]
-    step, state, static = bench.build_step(model, "bf16", batch, image)
+    step, state, static = build_step(model, "bf16", batch, image)
     compiled, compile_secs = _compile(step, state)
     _, losses, secs = _run_steps(compiled, state, static["carry_len"],
                                  TRAIN_STEPS)
@@ -170,13 +172,13 @@ def phase_gpt_small(rehearse: bool) -> dict:
     interpreted — proved from the compiled text) against the same step
     built with attention="reference" on the same seed."""
     device = _start_backend(rehearse)
-    import bench
+    from horovod_tpu.testing.steps import build_gpt_step
 
     size, batch, seq = _sizes(rehearse)["gpt"]
     out = {"device": device, "model": f"gpt-{size}", "batch": batch,
            "seq_len": seq}
     for attention in ("flash", "reference"):
-        step, state, static = bench.build_gpt_step(
+        step, state, static = build_gpt_step(
             size, "bf16", batch, seq, attention=attention)
         compiled, compile_secs = _compile(step, state)
         if attention == "flash":
@@ -324,15 +326,15 @@ def phase_dp4(rehearse: bool) -> dict:
     import optax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    import bench
     import horovod_tpu as hvd
     from horovod_tpu.models.transformer import gpt
     from horovod_tpu.parallel import hierarchical_allreduce
+    from horovod_tpu.testing.steps import build_gpt_step
 
     sizes = _sizes(rehearse)
     size, _, seq = sizes["gpt"]
     per_chip = sizes["gpt_dp_batch"]
-    step, state, static = bench.build_gpt_step(
+    step, state, static = build_gpt_step(
         size, "bf16", per_chip, seq, attention="flash")
     global_batch = static["global_batch"]
     tokens = state[2]
@@ -514,10 +516,9 @@ def main() -> int:
         return _run_phase_here(args.phase, args.rehearse_cpu,
                                args.phase_timeout)
 
-    if not os.path.exists(os.path.join(HERE, "bench.py")) or \
-            not os.path.isdir(os.path.join(HERE, "horovod_tpu")):
-        print("chip_smoke: bench.py and horovod_tpu/ must sit beside this "
-              "script", file=sys.stderr)
+    if not os.path.isdir(os.path.join(HERE, "horovod_tpu")):
+        print("chip_smoke: horovod_tpu/ must sit beside this script",
+              file=sys.stderr)
         return 2
     platforms = os.environ.get("JAX_PLATFORMS", "")
     if not args.rehearse_cpu and platforms and \

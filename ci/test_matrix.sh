@@ -411,153 +411,16 @@ PYTHONPATH="$PWD${PYTHONPATH:+:$PYTHONPATH}" \
     python "$LIVE_TMP/scrape.py" "$LIVE_TMP"
 rm -rf "$LIVE_TMP"
 
-# Goodput gate (ISSUE 17): the per-rank goodput ledger, the tenant SLO
-# burn-rate plane, and the bench regression sentinel.  hvdtpu-lint
-# stays clean over the new surface, the decision-table suites run
-# (tiling invariant, two-window burn alerting, trajectory partition),
-# the sentinel audits a seeded BENCH trajectory (the CPU dry run must be
-# labelled degraded and excluded from the baselines, the chip round
-# stays real, exit 0), and a seeded regressing
-# candidate must FAIL it — a sentinel that cannot fail is decorative.
+# Goodput gate (ISSUE 17): the per-rank goodput ledger and the tenant
+# SLO burn-rate plane.  hvdtpu-lint stays clean over the surface and the
+# decision-table suites run (tiling invariant, two-window burn alerting).
 echo "== goodput gate: lint + decision-table suites =="
 python -m horovod_tpu.analysis horovod_tpu/obs/goodput.py \
-    horovod_tpu/obs/slo.py scripts/perf_gate.py \
+    horovod_tpu/obs/slo.py \
     --baseline horovod_tpu/analysis/baseline.json
 JAX_PLATFORMS=cpu \
     timeout 300 python -m pytest tests/test_goodput.py \
-    tests/test_slo.py tests/test_perf_gate.py -x -q
-echo "== goodput gate: sentinel audits a seeded BENCH trajectory =="
-GP_TMP=$(mktemp -d)
-mkdir "$GP_TMP/records"
-python - "$GP_TMP/records" <<'EOF'
-import json, sys
-
-d = sys.argv[1]
-metric = "resnet50_bf16_images_per_sec_per_chip"
-json.dump({"n": 1, "rc": 0, "parsed": {
-    "metric": metric, "value": 2000.0, "device": "TPU v5 lite"}},
-    open(f"{d}/BENCH_r01.json", "w"))
-json.dump({"n": 2, "rc": 0, "degraded": True,
-           "failure_phase": "cpu-dry-run",
-           "parsed": {"metric": metric, "value": 9.0, "device": "cpu",
-                      "degraded": True},
-           "provenance": {"platform": "cpu", "device_kind": "cpu",
-                          "jax_platforms": "cpu"}},
-          open(f"{d}/BENCH_r02.json", "w"))
-EOF
-python scripts/perf_gate.py --records-dir "$GP_TMP/records" \
-    | tee "$GP_TMP/audit.txt"
-python - "$GP_TMP" <<'EOF'
-import sys
-
-lines = open(f"{sys.argv[1]}/audit.txt").read().splitlines()
-
-def bucket(rec):
-    for line in lines:
-        if rec in line:
-            return line.split()[0]
-    return None
-
-assert bucket("BENCH_r01.json") == "real"
-assert bucket("BENCH_r02.json") == "degraded"
-assert any(l.startswith("# baselines") for l in lines), "no baselines"
-print("goodput gate: trajectory partition OK")
-EOF
-echo "== goodput gate: seeded regression must fail the sentinel =="
-cat > "$GP_TMP/cand.json" <<'EOF'
-{"metric": "resnet50_bf16_images_per_sec_per_chip", "value": 1000.0,
- "device": "TPU v5 lite",
- "provenance": {"platform": "tpu", "device_kind": "TPU v5 lite",
-                "jax_platforms": ""}}
-EOF
-if python scripts/perf_gate.py --records-dir "$GP_TMP/records" \
-        --candidate "$GP_TMP/cand.json" > "$GP_TMP/verdict.txt"; then
-    echo "goodput gate FAILED: seeded regression passed the sentinel" >&2
-    exit 1
-fi
-grep -q "REGRESSION" "$GP_TMP/verdict.txt" || {
-    echo "goodput gate FAILED: sentinel failed without a REGRESSION verdict" >&2
-    exit 1
-}
-rm -rf "$GP_TMP"
-
-# Campaign gate (ISSUE 19): the resumable-campaign plane end to end.
-# Lint stays clean over the new surface, the unit suite runs, then the
-# acceptance chaos shape through the REAL front door (bench.py
-# --campaign on a 2-point CPU spec): a seeded SIGABRT between point 1's
-# journal commit and point 2's launch kills the first session; the
-# journal on disk must still be schema-valid with point 1 committed and
-# point 2 pending; the rerun (no fault) must resume and run ONLY point
-# 2.  Every landed record must carry the step-time anatomy (components
-# tiling the step within 5%) and the trend stamp, and perf_report.py
-# must name the committed trajectory's degraded streak with r02 as the
-# last real number.
-echo "== campaign gate: lint + unit suite =="
-python -m horovod_tpu.analysis horovod_tpu/bench/campaign.py \
-    horovod_tpu/obs/trend.py horovod_tpu/obs/anatomy.py \
-    scripts/perf_report.py \
-    --baseline horovod_tpu/analysis/baseline.json
-JAX_PLATFORMS=cpu \
-    timeout 300 python -m pytest tests/test_campaign.py -x -q
-echo "== campaign gate: seeded abort between points, then resume =="
-CP_TMP=$(mktemp -d)
-cat > "$CP_TMP/spec.json" <<'EOF'
-{"name": "ci_campaign",
- "base_args": ["--cpu", "--model", "resnet18", "--batch-size", "4",
-               "--image-size", "64", "--iters", "2", "--warmup", "1"],
- "points": [{"name": "p1", "args": []},
-            {"name": "p2", "args": ["--batch-size", "8"]}],
- "retry_degraded": 0,
- "point_budget_secs": 600}
-EOF
-if JAX_PLATFORMS=cpu HVDTPU_RECORD_DIR="$CP_TMP/records" \
-   HVDTPU_FAULT_SPEC="campaign_point:step=2:action=abort" \
-       timeout 900 python bench.py --campaign "$CP_TMP/spec.json"; then
-    echo "campaign gate FAILED: aborted campaign reported success" >&2
-    exit 1
-fi
-python - "$CP_TMP/records" <<'EOF'
-import json, sys
-j = json.load(open(f"{sys.argv[1]}/campaign.json"))
-assert j["schema"] == "hvdtpu-campaign-v1", j["schema"]
-assert j["points"]["p1"]["status"] == "degraded", j["points"]["p1"]
-assert j["points"]["p2"]["status"] == "pending", j["points"]["p2"]
-print("campaign gate: journal survived the abort intact")
-EOF
-JAX_PLATFORMS=cpu HVDTPU_RECORD_DIR="$CP_TMP/records" \
-    timeout 900 python bench.py --campaign "$CP_TMP/spec.json"
-python - "$CP_TMP/records" <<'EOF'
-import glob, json, sys
-d = sys.argv[1]
-j = json.load(open(f"{d}/campaign.json"))
-# Resume ran ONLY the in-flight point: p1's single pre-abort attempt
-# stands (retry_degraded=0), p2 completed exactly once.
-assert j["points"]["p1"]["attempts"] == 1, j["points"]["p1"]
-assert j["points"]["p2"]["attempts"] == 1, j["points"]["p2"]
-assert j["points"]["p2"]["status"] == "degraded", j["points"]["p2"]
-records = sorted(glob.glob(f"{d}/BENCH_*.json"))
-assert len(records) == 2, records
-for path in records:
-    parsed = json.load(open(path)).get("parsed") or {}
-    anatomy = parsed.get("anatomy") or {}
-    tile = anatomy.get("tile_pct")
-    assert tile is not None and abs(tile - 100.0) <= 5.0, (path, tile)
-    assert parsed.get("trend", {}).get("verdict"), path
-print("campaign gate: resume completed only point 2; every record "
-      "carries anatomy + trend provenance")
-EOF
-echo "== campaign gate: perf_report names the degraded streak =="
-python scripts/perf_report.py --records-dir "$CP_TMP/records" \
-    --campaign "$CP_TMP/records/campaign.json" > "$CP_TMP/report.txt"
-python - "$CP_TMP/report.txt" <<'EOF'
-import sys
-text = open(sys.argv[1]).read()
-assert "2 consecutive records without a real measurement" in text, text
-assert "ci_campaign" in text, text
-print("campaign gate OK")
-EOF
-rm -rf "$CP_TMP"
-
+    tests/test_slo.py -x -q
 # Post-mortem gate (ISSUE 4): a 2-proc job crashed with action=abort on
 # rank 1 must leave per-rank flight-recorder dumps and a launcher-written
 # postmortem.json that is schema-valid and blames the injected rank; the
@@ -908,11 +771,9 @@ rm -rf "$MS_TMP"
 # 4-device CPU mesh must (a) schedule per-bucket collectives INSIDE the
 # backward — inspector-verified >=2 gradient collectives before the
 # last backward compute op, while the off-mode module reads as one
-# monolithic end-of-backward psum — (b) produce training bitwise-equal
-# to off for both bucket and bucket+zero1, and (c) land a BENCH record
-# (degraded allowed on CPU) with the overlap stats embedded.
+# monolithic end-of-backward psum — and (b) produce training
+# bitwise-equal to off for both bucket and bucket+zero1.
 echo "== overlap gate: in-backward bucketed collectives =="
-OV_TMP=$(mktemp -d)
 JAX_PLATFORMS=cpu \
 XLA_FLAGS="--xla_force_host_platform_device_count=4" \
 PYTHONPATH="$PWD${PYTHONPATH:+:$PYTHONPATH}" \
@@ -978,33 +839,6 @@ for mode in ("bucket", "bucket+zero1"):
 print(f"overlap gate OK: bucket={rep.as_dict()} off={rep_off.as_dict()}, "
       f"bucket/bucket+zero1 bitwise == off over 4 steps")
 EOF
-# (c) a BENCH record lands with the overlap stats embedded
-JAX_PLATFORMS=cpu \
-XLA_FLAGS="--xla_force_host_platform_device_count=4" \
-PYTHONPATH="$PWD${PYTHONPATH:+:$PYTHONPATH}" \
-HVDTPU_BENCH_RECORD_DIR="$OV_TMP" \
-    timeout 540 python bench.py --cpu --model resnet18 --image-size 64 \
-    --batch-size 2 --iters 2 --warmup 1 --overlap bucket \
-    --grad-bucket-mb 4 > "$OV_TMP/bench.out"
-python - "$OV_TMP" <<'EOF'
-import glob, json, sys
-
-recs = sorted(glob.glob(f"{sys.argv[1]}/BENCH_*.json"))
-assert recs, "overlap bench landed no BENCH record"
-doc = json.load(open(recs[-1]))
-parsed = doc.get("parsed") or {}
-gauges = parsed.get("engine_gauges") or {}
-assert parsed.get("overlap_mode") == "bucket", parsed
-assert gauges.get("overlap_mode") == "bucket", gauges
-assert gauges.get("overlap.buckets", 0) >= 2, gauges
-bb = gauges.get("overlap_bucket_bytes")
-assert bb and len(bb) == int(gauges["overlap.buckets"]), gauges
-assert parsed.get("donation", {}).get("ok") is True, parsed
-print(f"overlap bench record OK: {len(bb)} buckets, "
-      f"donation {parsed['donation']['donated']}/"
-      f"{parsed['donation']['expected']}")
-EOF
-rm -rf "$OV_TMP"
 
 # HLO schedule-diff gate (ISSUE 12): every rank must COMPILE the same
 # collective sequence for the engine fused-allreduce, the overlap
@@ -1029,8 +863,7 @@ PYTHONPATH="$PWD${PYTHONPATH:+:$PYTHONPATH}" \
 # (a request admitted after step 0 completes), the serve gauges must
 # appear in a mid-run /metrics scrape, a deterministically killed
 # serving rank must respawn and replay its in-flight requests (zero
-# dropped, tokens bitwise-equal to single-stream generate), and
-# `bench.py --serve` must land a BENCH record with latency percentiles.
+# dropped, tokens bitwise-equal to single-stream generate).
 echo "== serve gate: unit suite + lint over the subsystem =="
 # slow-marked multi-proc acceptances are excluded from tier-1's budget
 # (-m 'not slow') and run HERE by node id — the gate is their home.
@@ -1132,42 +965,6 @@ assert sorted(results) == [0, 1], results
 print(f"serve gate OK: 8/8 requests exact through the chaos run, "
       f"{len(serve_series)} serve series scraped, trace {ejob.trace}")
 EOF
-echo "== serve gate: bench --serve lands a latency-percentile record =="
-SV_TMP=$(mktemp -d)
-JAX_PLATFORMS=cpu \
-PYTHONPATH="$PWD${PYTHONPATH:+:$PYTHONPATH}" \
-HVDTPU_BENCH_RECORD_DIR="$SV_TMP" \
-    timeout 300 python bench.py --serve --cpu \
-    --serve-requests 6 --serve-rate 6 > "$SV_TMP/bench.out"
-python - "$SV_TMP" <<'EOF'
-import glob, json, sys
-
-recs = sorted(glob.glob(f"{sys.argv[1]}/BENCH_*.json"))
-assert recs, "bench --serve landed no BENCH record"
-doc = json.load(open(recs[-1]))
-parsed = doc.get("parsed") or {}
-serve = parsed.get("serve") or {}
-assert parsed.get("metric") == "serve_nano_tokens_per_sec", parsed
-for h in ("ttft_ms", "tpot_ms"):
-    for q in ("p50", "p90", "p99"):
-        assert isinstance(serve.get(h, {}).get(q), (int, float)), (h, q)
-assert serve.get("requests") == 6, serve
-assert doc.get("degraded") is True  # CPU numbers are placeholders
-# Paged KV waste gate (ISSUE 15): on the bench's mixed-length workload
-# the paged pool's busy-step waste must stay within the partial-last-
-# page bound — against a PR-14 contiguous baseline of ~0.85 recomputed
-# on the same traffic (embedded alongside it in the record).
-kv = serve.get("kv") or {}
-assert kv.get("mode") == "paged", kv
-assert kv.get("waste_ratio_mean") is not None \
-    and kv["waste_ratio_mean"] <= 0.15, kv
-assert kv.get("contiguous_equiv_waste_mean", 0) > 0.3, kv
-print(f"serve bench record OK: {parsed['value']} tok/s, "
-      f"ttft p50 {serve['ttft_ms']['p50']}ms, "
-      f"kv waste {kv['waste_ratio_mean']} "
-      f"(contiguous-equivalent {kv['contiguous_equiv_waste_mean']})")
-EOF
-rm -rf "$SV_TMP"
 
 # Paged KV + width-sharded fleet gate (ISSUE 15): unit suite for the
 # allocator/paged-decode/width/sampling planes, the slow-marked fleet

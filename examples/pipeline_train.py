@@ -18,9 +18,8 @@ No reference equivalent — Horovod 0.19.1 is data-parallel only
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
         python examples/pipeline_train.py --smoke --cpu   # 4-dev CPU mesh
 
-(``--cpu`` sets the platform in-process, like ``bench.py --cpu`` and
-tests/conftest.py — more robust than ``JAX_PLATFORMS=cpu`` in the shell
-when a TPU plugin is installed but its backend is unreachable.)
+(``--cpu`` sets the platform in-process, like tests/conftest.py — more
+robust than ``JAX_PLATFORMS=cpu`` in the shell when a TPU plugin is installed but its backend is unreachable.)
 """
 
 from __future__ import annotations

@@ -6,7 +6,8 @@ counts, fp16/bf16 allreduce flag) on the TPU stack.
     python examples/synthetic_benchmark.py --model resnet50 --batch-size 64
     python -m horovod_tpu.run -np 2 python examples/synthetic_benchmark.py
 
-(bench.py at the repo root is the driver-facing single-line version.)
+(How a user writes the step, and a rough img/s.  The measurement is
+``benchmark/run.py``: ``PERF.md`` has its cells and their numbers.)
 """
 
 from __future__ import annotations

@@ -55,7 +55,7 @@ __all__ = [
 
 # The exhaustive wall-clock partition.  `productive_step` is the only
 # class that counts toward goodput.fraction; everything else is the
-# overhead classes the roadmap's hardware campaign needs itemized.
+# overhead classes a hardware run needs itemized.
 CLASSES: Tuple[str, ...] = (
     "init",
     "compile",

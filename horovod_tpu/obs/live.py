@@ -189,11 +189,6 @@ class LiveAggregator:
         # Last serving-world size the digest printed: the autoscale
         # token shows transitions ("world 4→6") across rounds.
         self._serve_world_prev: Optional[int] = None
-        # Perf-trend token, computed once per process: the committed
-        # BENCH trajectory can't change mid-job, and digest() runs on
-        # every round — don't re-read the record dir each time.
-        # False = not yet computed (None is a valid "no token" result).
-        self._trend_token: object = False
 
     # ------------------------------------------------------------ ingest
 
@@ -321,33 +316,7 @@ class LiveAggregator:
         mem = self._mem_part(views)
         if mem:
             parts.append(mem)
-        trend = self._trend_part()
-        if trend:
-            parts.append(trend)
         return "live[" + time.strftime("%H:%M:%S") + "] " + " | ".join(parts)
-
-    def _trend_part(self) -> Optional[str]:
-        """One digest token for the perf-trend verdict (obs/trend.py):
-        only speaks when the committed BENCH trajectory is dark, so an
-        operator babysitting a hardware window learns "the last N
-        records were all degraded" before burning the window on another
-        one.  Quiet on healthy or empty trajectories."""
-        if self._trend_token is False:
-            token = None
-            try:
-                from . import trend as obs_trend  # noqa: PLC0415
-
-                stamp = obs_trend.trend_stamp()
-                if stamp is not None and stamp["degraded_streak"]:
-                    token = (
-                        f"trend {stamp['degraded_streak']} records dark"
-                        + (f", last real {stamp['last_real_record']}"
-                           if stamp["last_real_record"] else "")
-                    )
-            except Exception:
-                token = None
-            self._trend_token = token
-        return self._trend_token  # type: ignore[return-value]
 
     @staticmethod
     def _tuner_part(views) -> Optional[str]:

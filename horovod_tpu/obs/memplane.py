@@ -27,7 +27,7 @@ and every FLOP (obs/profile.py) goes — this module is the missing
   hbm_peak_bytes,hbm_limit_bytes,headroom_bytes,live_bytes}`` +
   ``mem.owner_bytes{owner=…}`` gauges; :func:`install_census` arms it
   as a registry collector so every snapshot (the live stream, the exit
-  dump, a BENCH record) refreshes the numbers for free.  The census is
+  dump) refreshes the numbers for free.  The census is
   host-triggered: it sees the arrays alive *between* dispatches, not
   XLA's transient peak (docs/observability.md states this honestly).
 * **OOM black box** — :func:`maybe_record_oom` (hooked into
@@ -192,7 +192,7 @@ def register_program(name: str, compiled=None, *, stats: Optional[dict] = None,
 
 def program_report() -> Dict[str, dict]:
     """``{program name -> breakdown}`` of everything registered so far
-    (what BENCH records embed)."""
+    (what ``memory_record`` embeds)."""
     with _lock:
         return {k: dict(v) for k, v in _programs.items()}
 
@@ -470,9 +470,9 @@ def dominant_owner(doc: Optional[dict] = None) -> Tuple[Optional[str], float]:
 
 def memory_record() -> dict:
     """The record-embeddable view: one fresh census + every registered
-    per-program breakdown.  Safe anywhere (a degraded BENCH record may
-    write before jax ever initialized — the census then reports
-    ``source: unavailable`` and the programs dict is empty)."""
+    per-program breakdown.  Safe anywhere (before jax ever
+    initialized the census reports ``source: unavailable`` and the
+    programs dict is empty)."""
     try:
         c = census(publish=False)
     except Exception:

@@ -1,9 +1,7 @@
 """Live MFU profiler: model-FLOPs accounting over measured step time.
 
-The ROADMAP's item-5 campaign has machinery but no *measurement layer*:
-MFU existed only as a line bench.py computed inline at the end of a
-run.  This module is that layer, shared by every surface that times a
-step:
+The measurement layer shared by every surface of the program that times
+a step:
 
 * **Model FLOPs per step** — preferred source: XLA's own post-fusion
   cost analysis of the compiled artifact (:func:`flops_from_compiled`,
@@ -21,7 +19,7 @@ step:
   time and publishes ``perf.mfu``, ``perf.model_tflops``,
   ``perf.step_ms`` (plus ``perf.mfu_estimate`` when the peak is a
   guess) into the metrics registry — so the digest (``mfu 0.31``
-  token), ``/metrics``, ``--stats-summary`` and every BENCH record see
+  token), ``/metrics``, ``--stats-summary`` see
   the same number, computed once.
 
 Two further measurement layers live here, both fed by names the
@@ -90,9 +88,8 @@ __all__ = [
 # bf16x3 passes or worse), keyed by the string ``jax.Device.device_kind``
 # reports.  Source, one page per generation: Google Cloud TPU
 # documentation, "System architecture" (cloud.google.com/tpu/docs/v2,
-# /v3, /v4, /v5e, /v5p, /v6e), "Peak compute per chip (bf16)".  Shared
-# with bench.py — ONE table, so the bench headline and the live gauge
-# can never disagree about a chip's peak.  A kind that is not here is
+# /v3, /v4, /v5e, /v5p, /v6e), "Peak compute per chip (bf16)".  ONE
+# table, so no two surfaces can disagree about a chip's peak.  A kind that is not here is
 # an error, never a default.
 PEAK_FLOPS = {
     "TPU v2": 45e12,
@@ -200,8 +197,8 @@ _CONV_FWD_FLOPS_224 = {
 def analytic_step_flops(model_name: str, batch_size: int,
                         seq_len: Optional[int] = None,
                         image_size: int = 224) -> Optional[float]:
-    """Analytic per-step training FLOPs keyed off the bench model
-    builders (``bench.py --model`` names).  None for a model the tables
+    """Analytic per-step training FLOPs keyed off the model names of
+    ``testing/steps.py``'s builders.  None for a model the tables
     don't know — the caller then reports no MFU rather than a wrong
     one."""
     if model_name.startswith("gpt-"):

@@ -723,30 +723,6 @@ def mem_section(dumps: Dict[str, dict]) -> Optional[str]:
     return "\n".join(rows)
 
 
-def trend_section(dumps: Dict[str, dict]) -> Optional[str]:
-    """Perf-trend verdict (obs/trend.py) over the checkout's committed
-    BENCH records.  Unlike the other sections this reads the record
-    directory, not the dumps: the trajectory is a property of the repo,
-    and a dark streak ("N records without a real measurement") must
-    reach the operator at end-of-job even when the job itself produced
-    no perf gauges.  None on a fresh checkout (no records) so dev runs
-    stay quiet."""
-    del dumps  # same call shape as the other sections
-    from . import trend as obs_trend  # noqa: PLC0415
-
-    stamp = obs_trend.trend_stamp()
-    if stamp is None:
-        return None
-    lines = [
-        f"records {stamp['records']} "
-        f"(real {stamp['real']}, degraded {stamp['degraded']}, "
-        f"failed {stamp['failed']})",
-    ]
-    if stamp["verdict"]:
-        lines.append(stamp["verdict"])
-    return "\n".join(lines)
-
-
 def _rank_sort_key(label: str):
     """Rank-label ordering shared by the summary table's columns and
     the ckpt section's rows: numeric ranks first (numerically, with

@@ -2091,7 +2091,3 @@ def _print_stats_summary(args, env: Dict[str, str]) -> None:
     if mem is not None:
         print("\n== device memory (memory plane) ==")
         print(mem)
-    trend = obs_summary.trend_section(dumps)
-    if trend is not None:
-        print("\n== perf trend (BENCH trajectory) ==")
-        print(trend)

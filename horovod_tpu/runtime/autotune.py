@@ -56,7 +56,7 @@ CYCLE_BOUNDS_MS = (1.0, 50.0)
 # baked into the compiled XLA program, so every move costs a full
 # recompile (minutes on TPU) where a fusion_mb move costs one
 # negotiation cycle.  The candidate chain below is the offline sweep
-# (bench.py --grad-bucket-mb) a deployment walks once per model shape;
+# (HVDTPU_GRAD_BUCKET_MB) a deployment walks once per model shape;
 # too small → per-collective launch latency dominates, too large → the
 # last bucket's wire time has no backward compute left to hide behind
 # (docs/performance.md "overlap").
@@ -77,9 +77,8 @@ def grad_bucket_candidates() -> List[float]:
 
 def resolve_grad_bucket_bytes(cli_mb: Optional[float] = None) -> int:
     """The ONE resolution path for the bucket-size knob (CLI flag over
-    HVDTPU_GRAD_BUCKET_MB over the 16 MB default), shared by bench.py
-    and optim/overlap.py so the two can never disagree about what a
-    given run used."""
+    HVDTPU_GRAD_BUCKET_MB over the 16 MB default) that
+    optim/overlap.py resolves its bucket size through."""
     mb = (
         float(cli_mb)
         if cli_mb is not None

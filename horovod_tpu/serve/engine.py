@@ -475,10 +475,9 @@ class SlotEngine:
 
     def step_flops(self) -> Optional[float]:
         """Model FLOPs of one ``decode_step`` over the full slot pool,
-        from XLA's cost analysis of the compiled artifact (the same
-        accountant bench.py trusts — post-fusion, per-device; a width-
-        sharded program reports its SHARD's flops, which is the point:
-        width divides per-device work).  AOT lowered once and cached;
+        from XLA's cost analysis of the compiled artifact (post-fusion,
+        per-device; a width-sharded program reports its SHARD's flops,
+        which is the point: width divides per-device work).  AOT lowered once and cached;
         None when the backend exposes no cost model."""
         if self._step_flops_known:
             return self._step_flops

@@ -187,7 +187,7 @@ class ServeClient:
 
     Talks the signed KV protocol (the secret travels via
     ``HVDTPU_SECRET`` or the constructor), so any process holding the
-    per-job secret can drive a serving job — the CI gates, bench.py's
+    per-job secret can drive a serving job — the CI gates, the benchmark's
     open-loop generator, and operator tooling all use this class.
     Routing is client-side and coordination-free: one read of the
     ``serve/frontdoor`` doc pins ``F``, then every submission routes by

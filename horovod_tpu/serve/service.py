@@ -403,7 +403,7 @@ def _publish_out(kv, rid: str, *, tokens, done: bool, epoch: int,
     if t_done is not None:
         # Leader-clock completion stamp: lets a measuring client
         # compute throughput from server-side stamps instead of its
-        # own polling cadence (bench.py --serve; poll-granularity
+        # own polling cadence (poll-granularity
         # error was larger than the effects being measured).
         doc["t_done"] = float(t_done)
     if error is not None:
@@ -1223,8 +1223,8 @@ def _serve_epoch(ctx, engine, spec: dict, totals: Dict[str, Any],
             out["compile"] = obs_profile.compile_summary()
             if slices is not None and slices.last is not None:
                 out["device_slice"] = slices.last
-            # The rank's memory story rides the drain summary so a
-            # `bench.py --serve` record embeds a WORKER-side breakdown
+            # The rank's memory story rides the drain summary so its
+            # reader gets a WORKER-side breakdown
             # (census + per-program compiled bytes + the pool the KV
             # slots pin), not just the launcher's empty view.
             mem = memplane.memory_record()

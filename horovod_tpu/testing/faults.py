@@ -29,9 +29,7 @@ Grammar::
   AFTER the reduction so a corruption lands on one rank's copy of the
   *agreed* result, the silent-data-corruption shape the divergence
   sentinel exists to catch — corrupting before the reduce would spread
-  identically to every rank and diverge nothing), ``campaign_point``
-  (bench/campaign.py, between one sweep point's journal commit and the
-  next point's launch).
+  identically to every rank and diverge nothing).
 * ``rank`` — only fire on this rank (resolved from the ``rank=`` call
   argument, else ``HVDTPU_RANK``, else ``HVDTPU_ELASTIC_RANK``).  Absent
   means any rank.
@@ -92,14 +90,7 @@ Grammar::
   and tensor, finite-in/finite-out, the canonical SDC bit flip);
   ``nan_inject`` instructs the same site to overwrite that element
   with NaN (the nonfinite-provenance chaos input).  Both are applied
-  by the site via :func:`corrupt_grad`.  ``degrade`` instructs the
-  campaign driver (bench/campaign.py, point ``campaign_point`` — fired
-  between the previous point's journal commit and the next point's
-  launch, with the 1-based point index as the step) to force that
-  point down the degraded-record path without running it — the
-  deterministic mid-sweep failure the resume/retry machinery is
-  chaos-tested against; the generic ``abort`` at the same point is
-  the "campaign dies between points" input the CI resume gate seeds.
+  by the site via :func:`corrupt_grad`.
   ``worker_exit``/``task_fn`` points default to ``exit``.
 * ``code`` — exit code for ``action=exit`` (default 43, distinguishable
   from real crashes in launcher traces).
@@ -133,7 +124,6 @@ _ADVISORY_POINTS = {
     "frontend_exit": ("frontend_beat",),
     "flip_bits": ("grad_ready",),
     "nan_inject": ("grad_ready",),
-    "degrade": ("campaign_point",),
 }
 
 
@@ -218,7 +208,7 @@ def parse_spec(raw: str) -> List[FaultSpec]:
                                  "corrupt_write", "drop_replica",
                                  "trace_drop", "swap_abort",
                                  "scale_fail", "oom", "frontend_exit",
-                                 "flip_bits", "nan_inject", "degrade"):
+                                 "flip_bits", "nan_inject"):
                     raise ValueError(f"unknown fault action {value!r}")
                 spec.action = value
             elif key == "name":
@@ -397,8 +387,7 @@ def maybe_fail(
         )
         if spec.action in ("corrupt_write", "drop_replica", "trace_drop",
                            "swap_abort", "scale_fail", "oom",
-                           "frontend_exit", "flip_bits", "nan_inject",
-                           "degrade"):
+                           "frontend_exit", "flip_bits", "nan_inject"):
             # Advisory actions: the call site owns the I/O, so the
             # registry can only instruct it — corrupt the payload it is
             # about to write, or skip the push entirely.

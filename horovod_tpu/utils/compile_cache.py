@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule, shared by every entry point that compiles for the chip
-(``bench.py``, ``chip_smoke.py``, the serve worker): if
+(``benchmark/run.py``, ``chip_smoke.py``, the serve worker): if
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and no
 directory is set in code; otherwise the cache is ``<checkout>/.jax_cache``
 (git-ignored).  The path is part of the cache key, so it is never a

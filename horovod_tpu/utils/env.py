@@ -50,14 +50,12 @@ AUTOTUNE_GP_NOISE = "HVDTPU_AUTOTUNE_GAUSSIAN_PROCESS_NOISE"
 AUTOTUNE_DRIFT_THRESHOLD = "HVDTPU_AUTOTUNE_DRIFT_THRESHOLD"
 AUTOTUNE_DRIFT_SAMPLES = "HVDTPU_AUTOTUNE_DRIFT_SAMPLES"
 # Backward-overlap gradient plane (optim/overlap.py): gradient-bucket
-# size cap in MB for the jit path's in-backward bucketed collectives,
-# and the default overlap mode bench.py/--overlap resolves through.
+# size cap in MB for the jit path's in-backward bucketed collectives.
 # Unlike fusion_mb, the bucket size is baked into the compiled program
 # (moving it forces an XLA recompile), so it is swept offline
 # (autotune.grad_bucket_candidates) rather than tuned live.
 GRAD_BUCKET_MB = "HVDTPU_GRAD_BUCKET_MB"
 DEFAULT_GRAD_BUCKET_MB = 16.0
-OVERLAP = "HVDTPU_OVERLAP"
 # Steady-state schedule replay (GSPMD-style static schedule, recreated
 # dynamically): after REPLAY_CYCLES consecutive cycles whose executed
 # schedule is bitwise-identical on every rank, the engine stops

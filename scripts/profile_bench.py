@@ -1,7 +1,8 @@
 #!/usr/bin/env python
 """Where the device's time goes in the bench step, by the program's names.
 
-Builds the exact step ``bench.py`` times, runs ``--iters`` steps of it
+Builds the step of ``horovod_tpu/testing/steps.py`` (the one
+``chip_smoke.py`` smokes), runs ``--iters`` steps of it
 under ``horovod_tpu.obs.profile.device_trace`` and prints the reduction
 as JSON: window and busy seconds, device time by scope (``attn``,
 ``mlp``, ``grad_allreduce``, ``optimizer_update``, ...) and by Pallas
@@ -32,10 +33,6 @@ def main() -> int:
                         help="default: 128 resnet, 8 gpt")
     parser.add_argument("--seq-len", type=int, default=1024)
     parser.add_argument("--remat", action="store_true")
-    # keep in lockstep with bench.py: the profile must be of the tiles
-    # the benchmark actually runs (512x256, the measured v5e winner)
-    parser.add_argument("--flash-block-q", type=int, default=512)
-    parser.add_argument("--flash-block-k", type=int, default=256)
     parser.add_argument("--iters", type=int, default=5)
     args = parser.parse_args()
 
@@ -43,8 +40,7 @@ def main() -> int:
 
     from horovod_tpu.obs.profile import device_trace
 
-    # the EXACT steps bench.py times
-    from bench import build_gpt_step, build_step
+    from horovod_tpu.testing.steps import build_gpt_step, build_step
 
     is_gpt = args.model.startswith("gpt-")
     if args.batch_size is None:
@@ -53,8 +49,6 @@ def main() -> int:
         step, state, _ = build_gpt_step(
             args.model[len("gpt-"):], args.dtype, args.batch_size,
             args.seq_len, remat=args.remat,
-            flash_block_q=args.flash_block_q,
-            flash_block_k=args.flash_block_k,
         )
         carry, const = list(state[:-1]), list(state[-1:])
     else:

@@ -1,5 +1,6 @@
 #!/usr/bin/env python
-"""Sweep per-compile XLA:TPU compiler options for the bench step.
+"""Sweep per-compile XLA:TPU compiler options for the ResNet-50 step
+of ``horovod_tpu/testing/steps.py``.
 
 Dev tool for the perf push: jit ``compiler_options`` set the TPU
 compiler's options per compile, one variant after another in one
@@ -18,7 +19,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def run_variant(opts, iters=20, warmup=5, batch=128):
-    from bench import build_step
+    from horovod_tpu.testing.steps import build_step
 
     step, state, _ = build_step("resnet50", "bf16", batch)
     compiled = step.lower(*state).compile(compiler_options=opts or None)

@@ -13,7 +13,6 @@ and the existing fault registry.
 from __future__ import annotations
 
 import dataclasses
-import json
 import os
 import time
 
@@ -571,54 +570,6 @@ class TestAutotuneLog:
         tagged = tmp_path / "autotune.e2.csv"
         assert tagged.exists()
         assert len(tagged.read_text().strip().splitlines()) == 2
-
-
-# ------------------------------------------------------ degraded bench record
-
-
-class TestDegradedBenchRecord:
-    def test_write_and_schema(self, tmp_path):
-        import bench
-
-        path = bench.write_degraded_record(
-            "backend UNAVAILABLE", rc=1, phase="compile",
-            record_dir=str(tmp_path),
-        )
-        doc = json.loads(open(path).read())
-        assert doc["degraded"] is True
-        assert doc["failure_phase"] == "compile"
-        assert doc["parsed"] is None
-        assert isinstance(doc["n"], int) and doc["rc"] == 1
-        assert "UNAVAILABLE" in doc["tail"]
-
-    def test_numbering_continues_from_existing(self, tmp_path):
-        import bench
-
-        (tmp_path / "BENCH_r07.json").write_text(json.dumps({"n": 7}))
-        path = bench.write_degraded_record(
-            "x", rc=1, phase="init", record_dir=str(tmp_path)
-        )
-        assert path.endswith("BENCH_r08.json")
-
-    def test_attach_regression_skips_degraded(self, tmp_path):
-        import bench
-
-        good = {
-            "n": 1, "rc": 0,
-            "parsed": {"metric": "m", "device": "TPU v5 lite",
-                       "value": 100.0, "mfu": 0.3},
-        }
-        degraded = {
-            "n": 2, "rc": 86, "degraded": True, "failure_phase": "init",
-            "parsed": None,
-        }
-        (tmp_path / "BENCH_r01.json").write_text(json.dumps(good))
-        (tmp_path / "BENCH_r02.json").write_text(json.dumps(degraded))
-        out = {"metric": "m", "device": "TPU v5 lite", "value": 90.0}
-        bench.attach_regression(out, record_dir=str(tmp_path))
-        assert out["baseline_record"]["file"] == "BENCH_r01.json"
-        assert out["baseline_record"]["degraded_records_skipped"] == 1
-        assert out["deltas"]["value"]["pct"] == pytest.approx(-10.0)
 
 
 # ------------------------------------------------------- 2-proc integration

@@ -1,7 +1,7 @@
 """fp8 activation-storage numerics contract (VERDICT r3 weak #2).
 
-``bench.py --dtype fp8`` (bf16 compute, e4m3 activation storage between
-ResNet blocks) changes the loss contract, so the opt-in path needs a
+``dtype="fp8"`` of the step builders (bf16 compute, e4m3 activation
+storage between ResNet blocks) changes the loss contract, so the opt-in path needs a
 convergence-sanity assertion, reference-style: on a fixed seed, a short
 training run under fp8 must track the bf16 run's loss within a stated
 tolerance — and must actually train (loss decreases).
@@ -23,9 +23,9 @@ pytestmark = pytest.mark.full
 
 
 def _short_train(dtype: str, steps: int = 6) -> list:
-    import bench
+    from horovod_tpu.testing.steps import build_step
 
-    step, state, static = bench.build_step(
+    step, state, static = build_step(
         "resnet18", dtype, batch_size=2, image_size=32
     )
     carry, const = state[:3], state[3:]
@@ -57,9 +57,9 @@ def test_fp8_tracks_bf16_loss():
 
 
 def _short_gpt_train(dtype: str, steps: int = 6) -> list:
-    import bench
+    from horovod_tpu.testing.steps import build_gpt_step
 
-    step, state, static = bench.build_gpt_step(
+    step, state, static = build_gpt_step(
         "nano", dtype, batch_size=2, seq_len=64, attention="reference"
     )
     *carry, const = state

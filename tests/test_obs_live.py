@@ -3,9 +3,8 @@ delta encoding round-trips, aggregator merge across elastic
 incarnations, Prometheus exposition validity on the KV server's
 /metrics branch, deterministic straggler attribution on both collective
 paths (controller cycles, elastic KV waits) under the ``action=delay``
-fault, the KV wait backoff, the bench regression gate, and the 2-proc
-chaos acceptance: an injected delay straggler is named live and at job
-end, and attribution resets across incarnations."""
+fault, the KV wait backoff, and the 2-proc chaos acceptance: an
+injected delay straggler is named live and at job end, and attribution resets across incarnations."""
 
 import json
 import re
@@ -758,7 +757,7 @@ def test_summary_straggler_section_absent_when_clean():
 
 
 # ---------------------------------------------------------------------------
-# satellites: delay fault grammar, wait backoff, bench gate, CLI
+# satellites: delay fault grammar, wait backoff, CLI
 # ---------------------------------------------------------------------------
 
 
@@ -805,48 +804,6 @@ def test_kv_wait_exponential_backoff(monkeypatch):
     assert clock.sleeps[:6] == [0.05, 0.1, 0.2, 0.4, 0.8, 1.0]
     assert max(clock.sleeps) <= 1.0
     assert len(clock.sleeps) < 20  # fixed 0.1s polling would need 100
-
-
-def test_bench_regression_gate(tmp_path):
-    import bench
-
-    def rec(n, parsed, rc=0):
-        (tmp_path / f"BENCH_r0{n}.json").write_text(
-            json.dumps({"n": n, "rc": rc, "parsed": parsed}))
-
-    dev = "TPU v5 lite"
-    rec(1, {"metric": "m", "value": 100.0, "mfu": 0.2, "device": dev})
-    rec(2, {"metric": "m", "value": 110.0, "mfu": 0.25, "device": dev})
-    rec(3, None, rc=1)
-
-    out = bench.attach_regression(
-        {"metric": "m", "value": 99.0, "mfu": 0.22, "device": dev},
-        record_dir=str(tmp_path))
-    # r19: the baseline is the EWMA over the real trajectory
-    # (0.5*110 + 0.5*100 = 105), not the single newest record, and the
-    # provenance names every record the fold consumed.
-    assert out["baseline_record"] == {
-        "file": "BENCH_r02.json",
-        "baseline_records": ["BENCH_r01.json", "BENCH_r02.json"],
-        "ewma": {"k": 5, "alpha": 0.5, "count": 2},
-        "stale_records_skipped": 1,
-        "degraded_records_skipped": 0, "stale": True}
-    assert out["deltas"]["value"]["pct"] == -5.71
-    assert out["regression"] is True
-
-    ok = bench.attach_regression(
-        {"metric": "m", "value": 112.0, "device": dev},
-        record_dir=str(tmp_path))
-    assert ok["regression"] is False and "mfu" not in ok["deltas"]
-    # device mismatch (CPU dev run vs TPU record) is never compared
-    cpu = bench.attach_regression(
-        {"metric": "m", "value": 5.0, "device": "cpu"},
-        record_dir=str(tmp_path))
-    assert cpu["regression"] is None
-    assert cpu["baseline_record"]["file"] is None
-    # an unreadable record dir must never sink the measurement
-    assert "regression" in bench.attach_regression(
-        {"metric": "m", "value": 1.0}, record_dir=None)
 
 
 def test_cli_live_knobs_map_to_env():

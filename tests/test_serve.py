@@ -336,9 +336,14 @@ def test_sharded_decode_attention_matches_replicated():
     want_neg = _attend_cached(cfg, q_neg, k_neg, v, pos)
     got_neg = fn(q_neg, k_neg, v, pos)
     assert np.abs(np.asarray(got_neg)).max() > 0.0
+    # These scores are near -2.6e4, where float32 numbers lie 2e-3
+    # apart, so the two schedules' exponentials differ in their fourth
+    # digit: observed 1.54e-5 absolute on outputs up to 3.3.  The limit
+    # is ten times that; an underflowed merge would be off by the
+    # outputs' own size.
     np.testing.assert_allclose(np.asarray(got_neg),
                                np.asarray(want_neg),
-                               atol=1e-5, rtol=1e-5)
+                               atol=2e-4, rtol=0)
 
 
 def test_ulysses_prefill_attention_matches_local():

@@ -1,5 +1,6 @@
-"""Chip-compiler tests: the main path's Pallas kernel, compiled by the
-TPU's own compiler for a described (not attached) v5e at real widths.
+"""Chip-compiler tests: the main path's Pallas kernels, and the gradient
+plane's bucketed step, compiled by the TPU's own compiler for a described
+(not attached) v5e at real widths.
 
 The ONE file with such tests.  The topology is described inside a
 module-scoped fixture — never at import, in a ``skipif`` or a
@@ -34,16 +35,34 @@ def no_compile_cache():
 
 
 @pytest.fixture(scope="module")
-def one_chip(no_compile_cache):
+def topo(no_compile_cache):
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The 2x2 as one flat data-parallel mesh, as ``hvd.mesh("flat")``
+    makes it on the chip."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    import horovod_tpu as hvd
+
+    return Mesh(np.asarray(topo.devices, dtype=object).reshape(4),
+                (hvd.DP_AXIS,))
 
 
 # (id, q shape [B,S,H,D], kv heads, dtype, causal, window)
@@ -187,3 +206,104 @@ def test_ssd_scan_compiles_for_v5e_without_a_loop(one_chip, direction):
                        ).lower(*args).compile()
     assert " while(" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+# The gradient plane's "proof of overlap" (optim/overlap.py), read from
+# the artifact that matters.  XLA:CPU merges the buckets' all-reduces, so
+# its text proves nothing either way; this is the TPU compiler's, for the
+# described 2x2, at its default options.
+@pytest.mark.parametrize("width,bucket_bytes", [
+    (None, 8 * 1024),         # tests/test_overlap.py's MLP: 5 buckets
+    (1024, 4 * 1024 * 1024),  # four 4 MiB weights: 8 buckets, 16 MiB
+], ids=["tiny_mlp", "4MiB_buckets"])
+def test_tpu_compiler_combines_the_bucket_allreduces(four_chips, width,
+                                                     bucket_bytes):
+    """What holds today (a finding for ROADMAP A2, PERF.md section 7):
+    the ``bucket`` plan asks for one psum per bucket inside the backward,
+    and the TPU compiler's all-reduce combiner folds them into ONE
+    all-reduce whose operands are the buckets, scheduled after the last
+    backward fusion — the same schedule as ``off``.  Nothing overlaps.
+    A PR that makes the buckets survive changes these assertions."""
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+    from horovod_tpu.ops.collectives import shard_map_compat
+    from horovod_tpu.optim import overlap
+
+    sizes = [width] * 5 if width else [32, 64, 37, 41, 10]
+
+    def init_params():
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
+        return [{"w": jax.random.normal(k, (a, b)) * 0.1,
+                 "b": jnp.zeros(b)}
+                for k, a, b in zip(keys, sizes, sizes[1:])]
+
+    def loss_fn(params, x, y):
+        h = x
+        for i, layer in enumerate(params):
+            h = h @ layer["w"] + layer["b"]
+            if i < 3:
+                h = jax.nn.relu(h)
+        return jnp.mean((h - y) ** 2)
+
+    def on_mesh(shape, spec):
+        return jax.ShapeDtypeStruct(
+            shape.shape, shape.dtype,
+            sharding=NamedSharding(four_chips, spec))
+
+    params = jax.eval_shape(init_params)
+    x = on_mesh(jax.ShapeDtypeStruct((16, sizes[0]), jnp.float32),
+                P(hvd.DP_AXIS))
+    y = on_mesh(jax.ShapeDtypeStruct((16, sizes[-1]), jnp.float32),
+                P(hvd.DP_AXIS))
+    texts, plans = {}, {}
+    for mode in ("off", "bucket"):
+        plan = overlap.OverlapPlan(
+            params, optax.sgd(0.05, momentum=0.9), mode=mode,
+            mesh=four_chips, bucket_mb=bucket_bytes / 2 ** 20)
+        spec = plan.state_spec()
+        step = jax.jit(
+            shard_map_compat(
+                plan.local_step(loss_fn), mesh=four_chips,
+                in_specs=(spec, P(hvd.DP_AXIS), P(hvd.DP_AXIS)),
+                out_specs=(spec, P())),
+            donate_argnums=(0,))
+        state = jax.tree_util.tree_map(
+            lambda sp, sub: jax.tree_util.tree_map(
+                lambda leaf: on_mesh(leaf, sp), sub),
+            spec, jax.eval_shape(plan.init, params),
+            is_leaf=lambda v: isinstance(v, P))
+        texts[mode] = step.lower(state, x, y).compile().as_text()
+        plans[mode] = plan
+
+    n_buckets = len(plans["bucket"].layout.buckets)
+    assert n_buckets >= 3  # the plan did ask for separate collectives
+
+    def gradient_allreduces(text):
+        """(shape, opcode) of the entry computation's reduce-class
+        collectives, the scalar loss's left out, in schedule order."""
+        found = []
+        for line in overlap._entry_lines(text):
+            for op in ("all-reduce-start", "all-reduce", "reduce-scatter"):
+                if f" {op}(" in line:
+                    shape = line.split(" = ", 1)[1].split(f" {op}(")[0]
+                    if not shape.startswith("f32[]"):
+                        found.append((shape, op))
+        return found
+
+    for mode in ("off", "bucket"):
+        reduces = gradient_allreduces(texts[mode])
+        assert [op for _, op in reduces] == ["all-reduce"], (mode, reduces)
+    # one operand per bucket: combined, not dropped
+    combined = gradient_allreduces(texts["bucket"])[0][0]
+    assert combined.count("f32[") == n_buckets, combined
+
+    if n_buckets <= 5:
+        # inspect_schedule reads the same thing where it can: its
+        # pattern stops at the "/*index=5*/" the compiler writes into a
+        # tuple shape of more than five elements (PERF.md section 7).
+        for mode in ("off", "bucket"):
+            rep = overlap.inspect_schedule(texts[mode])
+            assert rep.gradient_collectives == 1, (mode, rep.as_dict())
+            assert rep.in_backward == 0 and rep.monolithic, rep.as_dict()
