@@ -36,36 +36,69 @@ CONFIG = dict(
 TOKENS = jax.random.randint(jax.random.PRNGKey(0), (2, 33), 0, 256)
 
 
-def scan_inputs(b=2, s=48, h=4, p=16, g=2, n=16):
+def scan_inputs(b=2, s=48, h=4, p=16, g=2, n=16, dtype=jnp.float32):
     k = jax.random.split(jax.random.PRNGKey(0), 6)
-    return (jax.random.normal(k[0], (b, s, h, p)),
+    return (jax.random.normal(k[0], (b, s, h, p)).astype(dtype),
             0.5 * jax.nn.softplus(jax.random.normal(k[1], (b, s, h))),
             -jnp.exp(0.5 * jax.random.normal(k[2], (h,))),
-            jax.random.normal(k[3], (b, s, g, n)),
-            jax.random.normal(k[4], (b, s, g, n)),
+            jax.random.normal(k[3], (b, s, g, n)).astype(dtype),
+            jax.random.normal(k[4], (b, s, g, n)).astype(dtype),
             jax.random.normal(k[5], (h,)))
 
 
-# chunk lengths 8 and 16 over 48 tokens (6 and 3 chunks a sequence), and
-# the whole sequence as one chunk (no state is carried)
-@pytest.mark.parametrize("chunk", [8, 16, 48])
-def test_ssd_scan_matches_token_recurrence(chunk):
-    args = scan_inputs()
+def scan_and_recurrence(args, chunk):
+    """(y, the six gradients) of the kernels (through the Pallas
+    interpreter) and of the token-by-token recurrence in float32."""
     weight = jax.random.normal(jax.random.PRNGKey(7), args[0].shape)
+    wide = tuple(a.astype(jnp.float32) for a in args)
     with jax.default_matmul_precision("highest"):
         got = ssd_scan(*args, chunk)
-        want = ref.ssd_recurrence(*args)
+        want = ref.ssd_recurrence(*wide)
         got_grads = jax.grad(
-            lambda *a: (ssd_scan(*a, chunk) * weight).sum(),
-            argnums=range(6))(*args)
+            lambda *a: (ssd_scan(*a, chunk).astype(jnp.float32)
+                        * weight).sum(), argnums=range(6))(*args)
         want_grads = jax.grad(
             lambda *a: (ref.ssd_recurrence(*a) * weight).sum(),
-            argnums=range(6))(*args)
-    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-5)
+            argnums=range(6))(*wide)
+    return got, want, got_grads, want_grads
+
+
+# Two sequences, two groups of two heads.  Chunk lengths 8 and 16 over 48
+# tokens (6 and 3 chunks a sequence), the whole sequence as one chunk (no
+# state is carried), and the published chunk, 256, which the production
+# tiles divide, over 512 tokens; there the log-decays reach -150 and
+# float32 leaves their exp four digits.
+@pytest.mark.parametrize("chunk,seq,tol", [
+    (8, 48, 1e-4), (16, 48, 1e-4), (48, 48, 1e-4), (256, 512, 1e-3)])
+def test_ssd_scan_matches_token_recurrence(chunk, seq, tol):
+    got, want, got_grads, want_grads = scan_and_recurrence(
+        scan_inputs(s=seq), chunk)
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol / 10)
     for name, a, b in zip("x dt A B C D".split(), got_grads, want_grads):
         np.testing.assert_allclose(
-            a, b, atol=1e-5 * float(jnp.abs(b).max()), rtol=1e-4,
+            a, b, atol=tol / 10 * float(jnp.abs(b).max()), rtol=tol,
             err_msg=f"gradient of {name}")
+
+
+# bfloat16 x, B and C, as the benchmark's cell runs the scan (dt, A and D
+# stay float32 there too).  The cell reads its whole gradient 2.7 % from
+# the float32 reference's (PERF.md section 6) and is held to 8.2 %; the
+# scan alone, against the recurrence on the same rounded inputs, has to
+# stay under the 2.7 %, output and each gradient by its norm.
+@pytest.mark.parametrize("chunk,seq", [(16, 48), (256, 512)])
+def test_ssd_scan_in_bfloat16_stays_within_the_cells_reading(chunk, seq):
+    got, want, got_grads, want_grads = scan_and_recurrence(
+        scan_inputs(s=seq, dtype=jnp.bfloat16), chunk)
+
+    def apart(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+    assert got.dtype == jnp.bfloat16
+    assert apart(got, want) < 0.027
+    for name, a, b in zip("x dt A B C D".split(), got_grads, want_grads):
+        assert a.dtype == b.dtype or name in "xBC", name
+        assert apart(a, b) < 0.027, (name, apart(a, b))
 
 
 def test_ssd_scan_refuses_a_sequence_the_chunk_does_not_divide():
@@ -73,23 +106,56 @@ def test_ssd_scan_refuses_a_sequence_the_chunk_does_not_divide():
         ssd_scan(*scan_inputs(s=44), 8)
 
 
-def test_ssd_scan_has_no_token_loop_and_no_seq_by_seq_tensor():
-    """The program's scan has no loop at all (the recurrence over the 6
-    chunk states is one triangular product), and no array has two
-    sequence-long axes."""
-    args = scan_inputs()
-    jaxpr = jax.make_jaxpr(lambda *a: ssd_scan(*a, 8))(*args)
+def test_ssd_scan_has_no_token_loop_and_no_chunk_by_chunk_tensor():
+    """Outside the two kernels the program has no loop, and no array with
+    two chunk-long axes leaves a kernel or is made beside one: nothing
+    there is as large as the ``chunk x chunk`` tensors of all chunks and
+    heads (batch x heads x seq x chunk elements), forward or backward."""
+    b, s, h, chunk = 2, 192, 8, 48
+    args = scan_inputs(b=b, s=s, h=h)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: ssd_scan(*a, chunk).sum(), argnums=range(6)))(*args)
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             yield eqn
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from walk(sub)
+            if eqn.primitive.name != "pallas_call":
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from walk(sub)
 
+    kernels = []
     for eqn in walk(jaxpr.jaxpr):
         assert eqn.primitive.name not in ("scan", "while"), eqn
+        if eqn.primitive.name == "pallas_call":
+            kernels.append(eqn.params["name"])
         for var in eqn.outvars:
-            assert sum(d >= 48 for d in var.aval.shape) <= 1, var.aval
+            shape = var.aval.shape
+            assert np.prod(shape) < b * h * s * chunk, var.aval
+            assert tuple(shape[-2:]) != (chunk, chunk), var.aval
+    assert sorted(kernels) == ["ssd_bwd", "ssd_fwd"]
+
+
+def test_ssd_kernels_are_traced_once_for_layers_of_one_shape():
+    """The calls sit behind an inner ``jax.jit``: two layers of one shape
+    share one traced forward, so a program lowers each kernel once."""
+    args = scan_inputs()
+
+    def two_layers(*a):
+        return ssd_scan(ssd_scan(*a, 8), *a[1:], 8)
+
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if (eqn.primitive.name, eqn.params.get("name")) == (
+                    "jit", "_forward"):
+                found.append(eqn.params["jaxpr"])
+            elif eqn.primitive.name != "pallas_call":
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+    walk(jax.make_jaxpr(two_layers)(*args).jaxpr)
+    assert len(found) == 2 and found[0] is found[1]
 
 
 def seeded(model):

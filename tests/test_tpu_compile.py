@@ -178,22 +178,44 @@ def test_flash_backward_path_compiles_for_v5e(one_chip, shape, kv_heads,
     assert ("flash_bwd_dq" not in text) == one_kernel
 
 
-@pytest.mark.parametrize("direction", ["forward", "backward"])
-def test_ssd_scan_compiles_for_v5e_without_a_loop(one_chip, direction):
-    """The matmul form through XLA: no ``while`` in the compiled program
-    (a device trace files one under no scope and its body a second
-    time), and the ``chunk x chunk`` tensors of all 32 chunks and 64
-    heads (0.5 GiB each in float32) fused away far enough that the
-    backward pass's temporaries stay under 1 GiB (0.30 GiB when this
-    was written, 0.25 forward)."""
-    from horovod_tpu.ops.ssd import ssd_scan
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """``ssd_scan`` takes its interpret mode from the default backend,
+    which is the CPU here, through ``flash_attention._interpret_for_
+    backend`` (it has no switch of its own): ask for the compiled
+    kernels the way ``benchmark/tools/compile_check.py`` does."""
+    from horovod_tpu.ops import flash_attention as fa
 
+    monkeypatch.setattr(fa, "_interpret_for_backend", lambda backend: False)
+
+
+def _cell_scan_args(one_chip, heads=64):
     def shaped(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    args = (shaped(1, 8192, 64, 64), shaped(1, 8192, 64, dtype=jnp.float32),
-            shaped(64, dtype=jnp.float32), shaped(1, 8192, 1, 128),
-            shaped(1, 8192, 1, 128), shaped(64, dtype=jnp.float32))
+    return (shaped(1, 8192, heads, 64),
+            shaped(1, 8192, heads, dtype=jnp.float32),
+            shaped(heads, dtype=jnp.float32), shaped(1, 8192, 1, 128),
+            shaped(1, 8192, 1, 128), shaped(heads, dtype=jnp.float32))
+
+
+@pytest.mark.parametrize("direction,kernels,temporaries_mib", [
+    ("forward", ["ssd_fwd"], 64), ("backward", ["ssd_fwd", "ssd_bwd"], 320)])
+def test_ssd_scan_compiles_for_v5e_without_a_loop(
+        one_chip, compiled_kernels, direction, kernels, temporaries_mib):
+    """The two Pallas kernels at the cell's shapes, inside the VMEM their
+    calls state: no ``while`` in the compiled program (a device trace
+    files one under no scope and its body a second time), and nothing of
+    ``[chunks, heads, chunk, chunk]`` in HBM (0.5 GiB each in float32:
+    the XLA form's backward held 0.30 GiB of temporaries, under a limit
+    of 1 GiB).  What the design keeps: forward the 18 MiB of ``a`` and
+    ``dt`` laid out for the kernel (0 MiB of temporaries when this was
+    written: they are fused into their producers); backward the 64 MiB
+    of chunk-start states, the layouts, and in this test, whose ``x`` and
+    ``dy`` arrive as ``[seq, heads, 64]`` in tiles of 128 lanes, three
+    64 MiB copies that fold heads into lanes (289 MiB in all; in the
+    model ``x`` is a slice of a ``[seq, 4352]`` matrix and needs none)."""
+    from horovod_tpu.ops.ssd import ssd_scan
 
     def scan(*a):
         return ssd_scan(*a, 256)
@@ -203,9 +225,30 @@ def test_ssd_scan_compiles_for_v5e_without_a_loop(one_chip, direction):
                         argnums=tuple(range(6)))(*a)
 
     compiled = jax.jit(scan if direction == "forward" else backward
-                       ).lower(*args).compile()
-    assert " while(" not in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+                       ).lower(*_cell_scan_args(one_chip)).compile()
+    text = compiled.as_text()
+    assert " while(" not in text
+    for name in ("ssd_fwd", "ssd_bwd"):
+        assert (f"/{name}/" in text) == (name in kernels), name
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < temporaries_mib * 2 ** 20)
+
+
+def test_ssd_scan_refuses_a_shape_over_its_vmem(one_chip, compiled_kernels):
+    """1024 heads of 64 x 128 float32 state are 32 MiB of scratch alone, and
+    the call states 16:
+    refused before anything is traced, with the numbers."""
+    from horovod_tpu.ops.ssd import ssd_scan
+
+    with pytest.raises(ValueError, match=r"heads=1024 x head_dim=64 x "
+                       r"state=128 at chunk=256 needs \d+ bytes of VMEM "
+                       r"\(40\.2 MiB\), over the 16777216 \(16 MiB\)"):
+        jax.jit(lambda *a: ssd_scan(*a, 256)).lower(
+            *_cell_scan_args(one_chip, heads=1024))
+    with pytest.raises(ValueError, match="chunk=64 is not a multiple of "
+                       "128"):
+        jax.jit(lambda *a: ssd_scan(*a, 64)).lower(
+            *_cell_scan_args(one_chip))
 
 
 # The gradient plane's "proof of overlap" (optim/overlap.py), read from
