@@ -123,6 +123,41 @@ class TransformerConfig:
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # Latent attention (layer type "mla"): queries through a rank
+    # q_lora_rank, keys and values through one latent of kv_lora_rank,
+    # each with a norm of its own (the configuration's); a head's query and key are
+    # qk_nope_head_dim latent-made channels beside qk_rope_head_dim
+    # rotary ones, the rotary key one vector shared by all heads; values
+    # are v_head_dim wide.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Routed experts that drop nothing (parallel/moe.py, the dropless
+    # core; not the GShard path of moe_experts above): >0 puts, in every
+    # layer after the first dense_layers_first, routed_experts gated
+    # experts of width routed_width behind a sigmoid router that picks
+    # routed_top_k a token and scales their normalised weights by
+    # routed_scaling, beside shared_experts experts every token takes.
+    # This chip holds routed_held of them (None = all) from
+    # routed_first_held on; the router scores all.  The selection bias
+    # (collection "moe_state") and the last step's rows per held expert
+    # and load of every routed expert (collection "moe_stats") are state,
+    # not parameters; the training step moves the bias against the load
+    # (parallel/moe.py:rebalanced).
+    routed_experts: int = 0
+    routed_held: Optional[int] = None
+    routed_first_held: int = 0
+    routed_top_k: int = 0
+    routed_width: int = 0
+    routed_scaling: float = 1.0
+    shared_experts: int = 0
+    dense_layers_first: int = 0
+    # Multi-token-prediction modules after the last block (0 or 1): the
+    # model then also returns logits for the token after next wherever
+    # it is handed next_tokens.
+    mtp_modules: int = 0
 
     def __post_init__(self):
         if self.num_kv_heads is not None:
@@ -144,11 +179,11 @@ class TransformerConfig:
                 f"mlp must be 'gelu' or 'silu_gated', got {self.mlp!r}")
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
-            unknown = set(self.layer_types) - {"attention", "mamba"}
+            unknown = set(self.layer_types) - {"attention", "mamba", "mla"}
             if unknown or len(self.layer_types) != self.num_layers:
                 raise ValueError(
-                    f"layer_types must name 'attention' or 'mamba' for each "
-                    f"of num_layers={self.num_layers} layers, got "
+                    f"layer_types must name 'attention', 'mamba' or 'mla' "
+                    f"for each of num_layers={self.num_layers} layers, got "
                     f"{self.layer_types!r}")
             if "mamba" in self.layer_types and (
                     self.ssm_heads <= 0
@@ -156,6 +191,40 @@ class TransformerConfig:
                 raise ValueError(
                     f"a 'mamba' layer needs ssm_heads={self.ssm_heads} to be "
                     f"a positive multiple of ssm_groups={self.ssm_groups}")
+            if "mla" in self.layer_types:
+                sizes = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                         "qk_rope_head_dim", "v_head_dim")
+                if min(getattr(self, k) for k in sizes) <= 0:
+                    raise ValueError(
+                        f"an 'mla' layer needs positive {', '.join(sizes)}")
+                if self.pos_embedding != "rope":
+                    raise ValueError(
+                        "an 'mla' layer rotates its rotary channels: "
+                        "pos_embedding must be 'rope'")
+        if self.routed_experts > 0:
+            if self.moe_experts > 0:
+                raise ValueError(
+                    "routed_experts (dropless) and moe_experts (GShard "
+                    "capacity) are two expert layers: set one")
+            if self.mlp != "silu_gated":
+                raise ValueError(
+                    "the routed experts are silu-gated: mlp must be "
+                    "'silu_gated'")
+            held = self.held_experts
+            if not (0 < self.routed_top_k <= self.routed_experts
+                    and self.routed_width > 0 and held > 0
+                    and 0 <= self.routed_first_held
+                    and self.routed_first_held + held
+                    <= self.routed_experts):
+                raise ValueError(
+                    f"routed_experts={self.routed_experts} needs "
+                    f"0 < routed_top_k <= routed_experts, a routed_width "
+                    f"and held experts {self.routed_first_held}.."
+                    f"{self.routed_first_held + held - 1} among them")
+        if self.mtp_modules not in (0, 1):
+            raise ValueError(
+                f"mtp_modules={self.mtp_modules}: one prediction module is "
+                f"implemented, or none")
 
     @property
     def head_dim(self) -> int:
@@ -168,6 +237,23 @@ class TransformerConfig:
 
     def layer_type(self, i: int) -> str:
         return self.layer_types[i] if self.layer_types else "attention"
+
+    def ffn_type(self, i: int) -> str:
+        """``"routed"`` where layer ``i``'s feed-forward is the dropless
+        expert layer, else ``"dense"``."""
+        return ("routed" if self.routed_experts > 0
+                and i >= self.dense_layers_first else "dense")
+
+    @property
+    def held_experts(self) -> int:
+        return (self.routed_held if self.routed_held is not None
+                else self.routed_experts)
+
+    @property
+    def rope_dim(self) -> int:
+        """The channels RoPE rotates: a whole head, or an MLA head's
+        rotary part."""
+        return self.qk_rope_head_dim or self.head_dim
 
     @property
     def ssm_inner(self) -> int:
@@ -186,10 +272,10 @@ def require_gpt2_block(cfg: TransformerConfig, who: str) -> None:
         raise ValueError(
             f"{who} runs attention layers only: layer_types="
             f"{cfg.layer_types!r} holds a layer it has no state for")
-    for setting in ("norm", "norm_eps", "mlp", "use_bias",
-                    "tie_embeddings", "embedding_multiplier",
-                    "residual_multiplier", "logits_scaling",
-                    "attention_scale"):
+    for setting in ("routed_experts", "mtp_modules", "norm", "norm_eps",
+                    "mlp", "use_bias", "tie_embeddings",
+                    "embedding_multiplier", "residual_multiplier",
+                    "logits_scaling", "attention_scale"):
         if getattr(cfg, setting) != getattr(gpt2, setting):
             raise ValueError(
                 f"{who} implements GPT-2's block only "
@@ -317,8 +403,44 @@ def mamba_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
     return out_proj(normed * norm_scale)
 
 
+def mla_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *, q_a,
+              q_a_norm, q_b, kv_a, kv_a_norm, kv_b, proj):
+    """Latent attention on the normed stream ``h`` [b, s, emb]: queries
+    ``q_b(norm(q_a(h)))``, heads of ``[nope ; rope]``; ``kv_a(h)`` gives
+    the latent and ONE rotary key for all heads; ``kv_b(norm(latent))``
+    gives each head's ``[k_nope ; v]``.  RoPE (``ops/rope.py``, split
+    halves) turns the rotary parts only; a head's key is its own
+    ``k_nope`` beside the shared rotary key.  Scores over the whole
+    ``nope + rope`` channels, causal, through the configured schedule.
+    The seven layers are callables like ``block_math``'s.  Returns the
+    residual delta."""
+    from ..ops.rope import apply_rope_tables  # noqa: PLC0415
+
+    b, s, _ = h.shape
+    nh, latent = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+    if nope + rope != vd and cfg.attention_impl == "flash":
+        raise ValueError(
+            f"the flash kernels take one head size: qk_nope_head_dim + "
+            f"qk_rope_head_dim = {nope + rope}, v_head_dim = {vd}")
+    with jax.named_scope(scopes.MLA_PROJ):
+        q = q_b(q_a_norm(q_a(h))).reshape(b, s, nh, nope + rope)
+        kv = kv_a(h)
+        k_v = kv_b(kv_a_norm(kv[..., :latent])).reshape(b, s, nh, nope + vd)
+        q_rope = apply_rope_tables(q[..., nope:], *rope_tabs)
+        k_rope = apply_rope_tables(kv[..., None, latent:], *rope_tabs)
+        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_v[..., :nope], jnp.broadcast_to(k_rope, (b, s, nh, rope))],
+            axis=-1)
+        v = k_v[..., nope:]
+    att = _attend(cfg, q, k, v, positions)
+    return proj(act_store(att.reshape(b, s, nh * vd), cfg))
+
+
 def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
-               ln1, ln2, mlp, qkv=None, proj=None, ssm=None,
+               ln1, ln2, mlp, qkv=None, proj=None, ssm=None, mla=None,
                num_heads: Optional[int] = None,
                num_kv_heads: Optional[int] = None,
                attend=None):
@@ -327,8 +449,10 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
     ``norm → mixer → (+res) → norm → feed-forward → (+res)``, each
     residual added through ``cfg.residual_multiplier``.  The mixer is
     attention (``qkv → split-heads → rope → attend → proj``) or, where
-    the caller hands in ``ssm``, whatever that callable makes of the
-    normed stream (the Mamba-2 mixer, :func:`mamba_mixer`).  Shared by
+    the caller hands in ``ssm`` or ``mla``, whatever that callable makes
+    of the normed stream (the Mamba-2 mixer, :func:`mamba_mixer`, under
+    the scope ``ssm``; latent attention, :func:`mla_mixer`, under
+    ``attn``).  Shared by
     the flax :class:`Block`, the raw-weights pipeline-parallel block
     (:func:`raw_block_forward`), and the Megatron tensor-parallel block
     (``parallel/tensor_parallel.py``) so a change to the block (a bias
@@ -365,6 +489,9 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
     if ssm is not None:
         with jax.named_scope(scopes.SSM):
             x = add(x, act_store(ssm(ln1(x)), cfg))
+    elif mla is not None:
+        with jax.named_scope(scopes.ATTN):
+            x = add(x, act_store(mla(ln1(x)), cfg))
     else:
         with jax.named_scope(scopes.ATTN):
             h = ln1(x)
@@ -466,12 +593,15 @@ class Block(nn.Module):
     """Pre-norm block: norm → mixer → +res, norm → MLP → +res.
 
     The wiring lives in :func:`block_math`; this module only declares
-    the flax parameters (the attention mixer's or the Mamba-2 mixer's,
-    by ``layer_type``) and hands their applications in as callables.
+    the flax parameters (the attention mixer's, the Mamba-2 mixer's or
+    latent attention's, by ``layer_type``; a dense feed-forward's or the
+    routed experts', by ``ffn``) and hands their applications in as
+    callables.
     """
 
     cfg: TransformerConfig
     layer_type: str = "attention"
+    ffn: str = "dense"
 
     @nn.compact
     def __call__(self, x, positions, rope_tabs=None):
@@ -483,7 +613,58 @@ class Block(nn.Module):
             return nn.Dense(features, dtype=cfg.dtype,
                             use_bias=cfg.use_bias, name=name)
 
+        def feed_forward(h, wide, fc1, fc2):
+            """The configuration's dense feed-forward, ``wide`` wide."""
+            if cfg.mlp == "silu_gated":
+                gate_up = dense(2 * wide, fc1)(h)
+                m = jax.nn.silu(gate_up[..., :wide]) * gate_up[..., wide:]
+            else:
+                m = nn.gelu(dense(wide, fc1)(h))
+            return dense(cfg.emb_dim, fc2)(act_store(m, cfg))
+
+        def routed(h):
+            """Routed experts that drop nothing, the shared expert
+            beside them (parallel/moe.py has the core)."""
+            from ..parallel.moe import routed_experts  # noqa: PLC0415
+
+            b, s, d = h.shape
+            held, ff = cfg.held_experts, cfg.routed_width
+            stacked = nn.initializers.lecun_normal(batch_axis=(0,))
+            bias = self.variable(
+                "moe_state", "bias", lambda: jax.random.uniform(
+                    self.make_rng("params"), (cfg.routed_experts,),
+                    jnp.float32, -0.05, 0.05))
+            y, routing = routed_experts(
+                h.reshape(b * s, d),
+                self.param("router", nn.initializers.normal(0.02),
+                           (d, cfg.routed_experts), jnp.float32),
+                bias.value,
+                self.param("experts_fc1", stacked, (held, d, 2 * ff),
+                           jnp.float32),
+                self.param("experts_fc2", stacked, (held, ff, d),
+                           jnp.float32),
+                top_k=cfg.routed_top_k, scaling=cfg.routed_scaling,
+                first_held=cfg.routed_first_held, dtype=cfg.dtype,
+                # initialising makes variables from shapes: the grouped
+                # matmul's stand-in keeps the kernel out of that program
+                interpret=True if self.is_initializing() else None)
+            if self.is_mutable_collection("moe_stats"):
+                self.variable("moe_stats", "rows", lambda: None).value = \
+                    routing.group_sizes[:held]
+                self.variable("moe_stats", "dropped", lambda: None).value \
+                    = routing.dropped
+                self.variable("moe_stats", "load", lambda: None).value = \
+                    routing.load
+            y = y.reshape(b, s, d)
+            if cfg.shared_experts > 0:
+                with jax.named_scope(scopes.MOE_SHARED):
+                    y = y + feed_forward(h, cfg.shared_experts * ff,
+                                         "shared_fc1", "shared_fc2")
+            return y
+
         def mlp(h):
+            if self.ffn == "routed":
+                return routed(h)
             if cfg.moe_experts > 0:
                 from ..parallel.moe import (  # noqa: PLC0415
                     moe_flax_params, moe_mlp,
@@ -502,12 +683,7 @@ class Block(nn.Module):
                 # y inherits ln2's fp32; keep the residual stream in the
                 # compute dtype like the dense-MLP path does
                 return y.astype(cfg.dtype)
-            if cfg.mlp == "silu_gated":
-                gate_up = dense(2 * width, "fc1")(h)
-                m = jax.nn.silu(gate_up[..., :width]) * gate_up[..., width:]
-            else:
-                m = nn.gelu(dense(width, "fc1")(h))
-            return dense(cfg.emb_dim, "fc2")(act_store(m, cfg))
+            return feed_forward(h, width, "fc1", "fc2")
 
         mixer = {}
         if self.layer_type == "mamba":
@@ -539,6 +715,24 @@ class Block(nn.Module):
                 )
 
             mixer["ssm"] = ssm
+        elif self.layer_type == "mla":
+            heads = cfg.num_heads
+
+            def mla(h):
+                return mla_mixer(
+                    cfg, h, positions, rope_tabs,
+                    q_a=dense(cfg.q_lora_rank, "q_a"),
+                    q_a_norm=_norm(cfg, "q_a_norm"),
+                    q_b=dense(heads * (cfg.qk_nope_head_dim
+                                       + cfg.qk_rope_head_dim), "q_b"),
+                    kv_a=dense(cfg.kv_lora_rank + cfg.qk_rope_head_dim,
+                               "kv_a"),
+                    kv_a_norm=_norm(cfg, "kv_a_norm"),
+                    kv_b=dense(heads * (cfg.qk_nope_head_dim
+                                        + cfg.v_head_dim), "kv_b"),
+                    proj=dense(cfg.emb_dim, "proj"))
+
+            mixer["mla"] = mla
         else:
             mixer["qkv"] = dense(cfg.emb_dim + 2 * kv_dim, "qkv")
             mixer["proj"] = dense(cfg.emb_dim, "proj")
@@ -546,6 +740,30 @@ class Block(nn.Module):
             cfg, x, positions, rope_tabs,
             ln1=_norm(cfg, "ln1"), ln2=_norm(cfg, "ln2"), mlp=mlp, **mixer,
         )
+
+
+class MTP(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3's report, section
+    2.2): ``[norm(emb(t_{i+1})) ; norm(h_i)]`` through ``eh_proj`` back to
+    the stream's width, one more block of the last layer's kind, a final
+    norm of its own.  The caller passes the result through the model's
+    own head."""
+
+    cfg: TransformerConfig
+    block_cls: Any
+    layer_type: str
+    ffn: str
+
+    @nn.compact
+    def __call__(self, h, emb_next, positions, rope_tabs):
+        cfg = self.cfg
+        both = jnp.concatenate(
+            [_norm(cfg, "enorm")(emb_next), _norm(cfg, "hnorm")(h)], axis=-1)
+        x = nn.Dense(cfg.emb_dim, dtype=cfg.dtype, use_bias=False,
+                     name="eh_proj")(both)
+        x = self.block_cls(cfg, self.layer_type, self.ffn, name="block")(
+            x, positions, rope_tabs)
+        return _norm(cfg, "norm")(x)
 
 
 class GPT(nn.Module):
@@ -564,13 +782,18 @@ class GPT(nn.Module):
       but the flash/reference/ring attention impls mask assuming
       contiguous per-shard rows.
 
-    Returns logits ``[batch, seq, vocab]`` in fp32.
+    Returns logits ``[batch, seq, vocab]`` in fp32.  With a
+    multi-token-prediction module (``cfg.mtp_modules``) and
+    ``next_tokens`` (``tokens`` shifted left by one: position ``i``
+    holds token ``i + 1``) it returns the pair ``(logits, mtp_logits)``,
+    the second for the token after next, through the same head.
     """
 
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, tokens, pos_offset=0, positions=None):
+    def __call__(self, tokens, pos_offset=0, positions=None,
+                 next_tokens=None):
         cfg = self.cfg
         wte = nn.Embed(cfg.vocab_size, cfg.emb_dim, dtype=cfg.dtype,
                        name="wte")
@@ -615,7 +838,7 @@ class GPT(nn.Module):
 
             # once for ALL blocks: under remat a per-block recompute would
             # re-run the transcendentals in the backward pass too
-            rope_tabs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+            rope_tabs = rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
         block_cls = Block
         if cfg.remat:
             block_cls = nn.remat(
@@ -623,12 +846,14 @@ class GPT(nn.Module):
                 policy=getattr(jax.checkpoint_policies, cfg.remat_policy),
             )
         for i in range(cfg.num_layers):
-            x = block_cls(cfg, cfg.layer_type(i), name=f"block{i}")(
-                x, positions, rope_tabs)
-        # Final norm, LM head and the fp32 cast under one scope, like
-        # the raw-weights epilogue (tensor_parallel._gpt_head).
-        with jax.named_scope(scopes.HEAD):
-            x = _norm(cfg, "lnf")(x)
+            x = block_cls(cfg, cfg.layer_type(i), cfg.ffn_type(i),
+                          name=f"block{i}")(x, positions, rope_tabs)
+        if not cfg.tie_embeddings:
+            # one module, so that the prediction module's pass shares it
+            untied = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
+                              use_bias=False, name="head")
+
+        def head(x):
             if cfg.tie_embeddings:
                 # one matrix, two uses: it receives both gradients
                 logits = jnp.einsum(
@@ -636,13 +861,36 @@ class GPT(nn.Module):
                     wte.embedding.astype(cfg.dtype),
                     preferred_element_type=jnp.float32)
             else:
-                logits = nn.Dense(
-                    cfg.vocab_size, dtype=cfg.dtype, use_bias=False,
-                    name="head"
-                )(x).astype(jnp.float32)
+                logits = untied(x).astype(jnp.float32)
             if cfg.logits_scaling != 1.0:
                 logits = logits / cfg.logits_scaling
             return logits
+
+        # Final norm, LM head and the fp32 cast under one scope, like
+        # the raw-weights epilogue (tensor_parallel._gpt_head).
+        with jax.named_scope(scopes.HEAD):
+            logits = head(_norm(cfg, "lnf")(x))
+        if cfg.mtp_modules == 0 or (next_tokens is None
+                                    and not self.is_initializing()):
+            return logits
+        if next_tokens is None:
+            next_tokens = tokens  # initialising: only the shapes matter
+        # The prediction module reads the last block's output BEFORE the
+        # final norm and the embedding of the next token, and predicts
+        # the token after next through the same embedding and head,
+        # which so receive both gradients.
+        with jax.named_scope(scopes.MTP):
+            with jax.named_scope(scopes.EMBED):
+                nxt = wte(next_tokens)
+                if cfg.embedding_multiplier != 1.0:
+                    nxt = nxt * cfg.embedding_multiplier
+            last = cfg.num_layers - 1
+            x = MTP(cfg, block_cls, cfg.layer_type(last),
+                    cfg.ffn_type(last), name="mtp")(
+                        x, nxt, positions, rope_tabs)
+            with jax.named_scope(scopes.HEAD):
+                mtp_logits = head(x)
+        return logits, mtp_logits
 
 
 # Named sizes (GPT-2 family geometry; head_dim 64, MXU-friendly widths).
@@ -670,6 +918,28 @@ GPT_CONFIGS = {
         ssm_conv=4, ssm_chunk=256,
         # a Mamba block's matmul outputs are 0.47 GB at 8192 tokens:
         # keep only each block's input
+        remat_policy="nothing_saveable",
+    ),
+    # https://huggingface.co/zai-org/GLM-4.7-Flash config.json
+    # (model_type glm4_moe_lite): latent attention in every layer, layer
+    # 0 a dense gated feed-forward of 10240, the other 46 layers 64
+    # routed experts of 1536 (sigmoid scores, 4 a token, weights
+    # normalised and scaled 1.8, nothing dropped) beside one shared
+    # expert, one multi-token-prediction module, an untied head.
+    # Training path only (require_gpt2_block says who refuses it).
+    "glm-4.7-flash": TransformerConfig(
+        vocab_size=154880, num_layers=47, emb_dim=2048, max_len=202752,
+        layer_types=("mla",) * 47, num_heads=20, num_kv_heads=20,
+        q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256,
+        pos_embedding="rope", rope_theta=1e6,
+        mlp_ratio=5, mlp="silu_gated", norm="rmsnorm", norm_eps=1e-5,
+        use_bias=False, tie_embeddings=False,
+        routed_experts=64, routed_top_k=4, routed_width=1536,
+        routed_scaling=1.8, shared_experts=1, dense_layers_first=1,
+        mtp_modules=1,
+        # 8192 x 5120 queries, keys and values a block: keep only each
+        # block's input
         remat_policy="nothing_saveable",
     ),
 }

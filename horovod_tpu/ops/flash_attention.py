@@ -36,9 +36,12 @@ Design (the standard flash recurrence, TPU-shaped):
   accumulator and a whole kv row's dk and dv accumulators resident in
   VMEM, wherever those fit the VMEM the call states (a function of the
   shape alone: ``_fused_bwd_vmem_bytes`` against
-  ``_FUSED_BWD_VMEM_LIMIT``; 8192 keys at head size 64 or 128).
-  A longer sequence takes the two passes it replaced (dk/dv, then dq,
-  each recomputing ``p`` and ``ds``), whose VMEM does not grow with S.
+  ``_FUSED_BWD_VMEM_LIMIT``; a head's channels fill whole 128-lane
+  tiles, so that is 8192 keys at head size 64 or 128 and 4096 keys at
+  head size 256).  A longer sequence takes the two passes it replaced
+  (dk/dv, then dq, each recomputing ``p`` and ``ds``), whose VMEM does
+  not grow with S: 8192 keys at head size 256, latent attention's shape
+  in ``glm47f_train_s8192``, run there.
   Per call at 128 x 1024 x 64 (chip runs of PR 29): two passes 0.973 +
   0.679 ms, one kernel 1.025; at 32 query over 8 K/V heads x 8192 x 64,
   10.72 + 7.51 against 12.00.

@@ -20,17 +20,31 @@ TPU-first —
 Layout contract for :func:`moe_mlp_ep` — call inside ``shard_map`` with
 tokens sharded over the axis and the expert weights sharded on their
 leading (expert) dim; every rank must carry the same token count.
+
+A second, **dropless** core stands beside that path (the end of this
+file: :func:`route`, :func:`grouped_ffn`, :func:`routed_experts`): scores
+over all experts, top-k, the chosen (token, expert) rows sorted by
+expert, a grouped matmul over the experts THIS chip holds, the weighted
+sum back.  No capacity, so nothing is dropped; the layer is told which
+experts it holds (``first_held``, and as many as its weights have),
+routes over all of them and computes its own experts' part of the
+result.  On one chip it runs without its exchange.  The GShard path
+above is as it was; ROADMAP C6 has the folding of the two.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["init_moe_params", "moe_mlp", "moe_mlp_ep", "MoEParams"]
+from .. import scopes
+
+__all__ = ["init_moe_params", "moe_mlp", "moe_mlp_ep", "MoEParams",
+           "Routing", "route", "grouped_ffn", "routed_experts",
+           "rebalanced", "publish_stats"]
 
 # Initialization scheme, shared by the raw-NamedTuple and flax paths so
 # the two can never drift: small-normal router, fan-in-scaled FFN.
@@ -286,3 +300,222 @@ def moe_flax_params(module, d: int, ff: int, num_experts: int) -> MoEParams:
             "b2", nn.initializers.zeros, (num_experts, d), jnp.float32
         ),
     )
+
+
+# ----------------------------------------------------------------- dropless
+
+class Routing(NamedTuple):
+    """What :func:`route` decides for ``n`` tokens and ``k`` experts a
+    token.  A *slot* is one (token, choice) pair, ``n * k`` of them,
+    numbered ``token * k + choice``."""
+
+    weights: jax.Array      # [n, k] float32: normalised over all k chosen
+    experts: jax.Array      # [n, k] int32: the chosen experts, of all E
+    order: jax.Array        # [n*k] int32: sorted row -> slot, held experts
+                            # first and by expert, the rest after them
+    group_sizes: jax.Array  # [held + 1] int32: rows of each held expert,
+                            # then the rows whose expert lives elsewhere
+    dropped: jax.Array      # int32 scalar: slots of a held expert that
+                            # the sort left no row for (there is no
+                            # capacity, so 0: the counter is the proof)
+    load: jax.Array         # [E] int32: the slots that chose each of ALL
+                            # experts, what the balancing update reads
+
+
+def route(x2, router, bias, *, top_k: int, scaling: float,
+          first_held: int, held: int) -> Routing:
+    """Sigmoid scores over ALL experts, in float32 whatever the stream's
+    dtype (a choice is discrete: a score rounded to bfloat16 picks
+    another expert); the ``top_k`` largest of ``score + bias``;
+    weights ``score / (sum of the chosen scores + 1e-20) * scaling``.
+    ``bias`` (the aux-free balancing correction) moves the choice and
+    never a weight, and takes no gradient.  Experts ``first_held`` to
+    ``first_held + held - 1`` are this chip's."""
+    n = x2.shape[0]
+    scores = jax.nn.sigmoid(jnp.dot(
+        x2.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, experts = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scaling
+    local = experts.reshape(n * top_k) - first_held
+    is_held = (local >= 0) & (local < held)
+    key = jnp.where(is_held, local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    group_sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
+    dropped = is_held.sum(dtype=jnp.int32) - group_sizes[:held].sum()
+    load = jnp.zeros((router.shape[1],), jnp.int32).at[
+        experts.reshape(n * top_k)].add(1)
+    return Routing(weights, experts.astype(jnp.int32), order, group_sizes,
+                   dropped, load)
+
+
+def rebalanced(moe_state, moe_stats, rate: float, axis_name=None):
+    """The aux-free balancing update (``noaux_tc``; DeepSeek-V3's report,
+    section 2.1.2) of every expert layer's selection bias after a step:
+    ``bias_e += rate * sign(mean load - load_e)``, an expert chosen more
+    often than the mean a little less likely next step.  ``moe_state`` and
+    ``moe_stats`` are the model's two collections (per layer ``bias`` and
+    ``load``, the slots that chose each of all the experts in the step
+    just run); under ``axis_name`` the load is summed over the chips that
+    bring tokens, so that every copy of the bias moves alike.  A training
+    recipe like the optimizer's, so the step calls it, not the model."""
+    from flax.traverse_util import flatten_dict, unflatten_dict  # noqa: PLC0415
+
+    stats = flatten_dict(moe_stats)
+    out = {}
+    for path, bias in flatten_dict(moe_state).items():
+        load = stats[path[:-1] + ("load",)].astype(jnp.float32)
+        if axis_name is not None:
+            load = lax.psum(load, axis_name)
+        out[path] = bias + rate * jnp.sign(load.mean() - load)
+    return unflatten_dict(out)
+
+
+@jax.custom_vjp
+def _to_experts(x2, order, inverse):
+    """Every token's row once for each of its ``k`` choices, in expert
+    order: ``x2[order // k]``, ``[n, d] -> [n k, d]``.  The backward pass
+    gathers the rows' gradients back into slot order (``g[inverse]``) and
+    sums a token's ``k``: no scatter-add, and no ``[n k, d]`` copy of the
+    tokens before the sort."""
+    return _slot_rows(x2, order)
+
+
+def _slot_rows(x2, order):
+    return jnp.take(x2, order // (order.shape[0] // x2.shape[0]), axis=0)
+
+
+def _to_experts_fwd(x2, order, inverse):
+    return _slot_rows(x2, order), (inverse, x2.shape[0])
+
+
+def _to_experts_bwd(res, g):
+    inverse, n = res
+    back = jnp.take(g, inverse, axis=0).reshape(n, -1, g.shape[-1])
+    return back.sum(axis=1, dtype=jnp.float32).astype(g.dtype), None, None
+
+
+_to_experts.defvjp(_to_experts_fwd, _to_experts_bwd)
+
+
+@jax.custom_vjp
+def _from_experts(ys, order, inverse):
+    """The experts' rows back in slot order, ``ys[inverse]``; the
+    backward pass is the gather ``g[order]``."""
+    return jnp.take(ys, inverse, axis=0)
+
+
+def _from_experts_fwd(ys, order, inverse):
+    return jnp.take(ys, inverse, axis=0), order
+
+
+def _from_experts_bwd(order, g):
+    return jnp.take(g, order, axis=0), None, None
+
+
+_from_experts.defvjp(_from_experts_fwd, _from_experts_bwd)
+
+# (rows, contraction, columns) of a grouped-matmul tile on the chip
+GMM_TILING = (512, 1024, 1024)
+
+
+def _gmm(lhs, rhs, group_sizes, interpret: bool):
+    """``lhs [rows, k]`` times ``rhs [held, k, n]``, the rows of group
+    ``g`` (consecutive, ``group_sizes[g]`` of them) with ``rhs[g]``; the
+    rows after the last held group come out zero.  On the chip jax's
+    Pallas grouped matmul (``pallas.ops.tpu.megablox``): its grid is the
+    row tiles that hold a held expert's rows, so its time follows the
+    rows routed here and not ``rows x held``.  Off the chip
+    (``interpret``) ``lax.ragged_dot`` computes the same, the last
+    group against a zero matrix."""
+    if interpret:
+        rhs = jnp.concatenate([rhs, jnp.zeros_like(rhs[:1])])
+        return lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=lhs.dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm  # noqa: PLC0415
+
+    tiling = tuple(min(t, d) for t, d in zip(
+        GMM_TILING, (lhs.shape[0], lhs.shape[1], rhs.shape[2])))
+    return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling)
+
+
+def grouped_ffn(xs, gate_up, down, group_sizes, *, dtype=jnp.bfloat16,
+                interpret: bool = False):
+    """The gated feed-forward ``W_down(silu(x W_gate) * (x W_up))`` of
+    every held expert on its own rows: ``xs [rows, d]`` in expert order,
+    ``gate_up [held, d, 2 ff]`` (gate then up), ``down [held, ff, d]``,
+    ``group_sizes [held + 1]``.  Rows past the held groups give zero."""
+    ff = down.shape[1]
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        h = _gmm(xs.astype(dtype), gate_up.astype(dtype), group_sizes,
+                 interpret)
+        act = (jax.nn.silu(h[:, :ff]) * h[:, ff:]).astype(dtype)
+        return _gmm(act, down.astype(dtype), group_sizes, interpret)
+
+
+def routed_experts(x2, router, bias, gate_up, down, *, top_k: int,
+                   scaling: float, first_held: int = 0,
+                   dtype=jnp.bfloat16, interpret: Optional[bool] = None):
+    """The routed part of a dropless expert layer on flat tokens
+    ``x2 [n, d]``: ``sum over the chosen AND held experts e of
+    w_e FFN_e(x)``.  What the experts held elsewhere would have added is
+    left out (their share of the weights is not renormalised away).
+    ``interpret=None`` takes the kernel on backend ``tpu`` and its
+    stand-in on ``cpu``: one rule for every kernel of the package,
+    ``flash_attention._interpret_for_backend``, looked up through that
+    module as ``ops/ssd.py`` does (a compile for the chip from a machine
+    without one replaces it there).  Returns ``(y [n, d], routing)``."""
+    if interpret is None:
+        from ..ops import flash_attention  # noqa: PLC0415
+
+        interpret = flash_attention._interpret_for_backend(
+            jax.default_backend())
+    n, d = x2.shape
+    held = gate_up.shape[0]
+    with jax.named_scope(scopes.MOE_ROUTE):
+        routing = route(x2, router, bias, top_k=top_k, scaling=scaling,
+                        first_held=first_held, held=held)
+        order = routing.order
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n * top_k, dtype=jnp.int32))
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        xs = _to_experts(x2.astype(dtype), order, inverse)
+    ys = grouped_ffn(xs, gate_up, down, routing.group_sizes, dtype=dtype,
+                     interpret=interpret)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        back = _from_experts(ys, order, inverse).reshape(n, top_k, d)
+        y = jnp.einsum("nkd,nk->nd", back.astype(jnp.float32),
+                       routing.weights)
+    return y.astype(dtype), routing
+
+
+def publish_stats(stats, registry=None) -> dict:
+    """An expert layer's counters, from the device state the model keeps
+    them in (collection ``moe_stats``: per layer ``rows`` [held] and
+    ``dropped``, of the last step), as gauges of the metrics registry
+    (``obs/registry.py``) and as the dict returned: per layer the rows
+    routed to held experts, the largest held expert's rows over the
+    mean, and the rows dropped (``load`` is the balancing update's).
+    Read after a step, on the host: never from a callback inside it."""
+    import numpy as np  # noqa: PLC0415
+    from flax.traverse_util import flatten_dict  # noqa: PLC0415
+
+    from ..obs.registry import get_registry  # noqa: PLC0415
+
+    registry = registry or get_registry()
+    flat = flatten_dict(jax.device_get(stats), sep="/")
+    out = {}
+    for path, rows in sorted(flat.items()):
+        if not path.endswith("/rows"):
+            continue
+        layer = path[:-len("/rows")]
+        rows = np.asarray(rows)
+        mean = float(rows.mean())
+        entry = {"rows_held": int(rows.sum()),
+                 "max_over_mean": float(rows.max()) / mean if mean else 0.0,
+                 "rows_dropped": int(flat[layer + "/dropped"])}
+        for name, value in entry.items():
+            registry.gauge(f"moe.{name}", layer=layer).set(value)
+        out[layer] = entry
+    return out

@@ -20,7 +20,18 @@ ATTN = "attn"                          # a block's attention half
 SSM = "ssm"                            # a block's Mamba-2 mixer half: in_proj,
                                        # conv, scan, gated norm, out_proj
 SSD_SCAN = "ssd_scan"                  # the state-space scan alone, inside ssm
+MLA_PROJ = "mla_proj"                  # latent attention, inside attn: the
+                                       # two low-rank paths, their norms,
+                                       # RoPE, the shared rotary key
 MLP = "mlp"                            # a block's MLP half
+MOE_ROUTE = "moe_route"                # inside mlp: router matmul, sigmoid,
+                                       # top-k, the sort by expert
+MOE_DISPATCH = "moe_dispatch"          # inside mlp: rows gathered into
+                                       # expert order and back
+MOE_EXPERTS = "moe_experts"            # inside mlp: the grouped matmuls
+MOE_SHARED = "moe_shared"              # inside mlp: the shared expert
+MTP = "mtp"                            # the multi-token-prediction module
+                                       # and its pass through the head
 EMBED = "embed"                        # token and position embedding
 HEAD = "head"                          # final norm and output projection
 STEM = "stem"                          # ResNet's first conv and pool
@@ -28,5 +39,6 @@ KV_GATHER = "kv_gather"                # paged decode: pages -> contiguous KV
 KV_SCATTER = "kv_scatter"              # paged prefill: KV -> pages
 SAMPLE = "sample"                      # the token pick
 
-SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, SSM, SSD_SCAN,
-          MLP, EMBED, HEAD, STEM, KV_GATHER, KV_SCATTER, SAMPLE)
+SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ, SSM,
+          SSD_SCAN, MLP, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED,
+          MTP, EMBED, HEAD, STEM, KV_GATHER, KV_SCATTER, SAMPLE)

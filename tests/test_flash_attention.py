@@ -671,6 +671,14 @@ _GATE_CASES = [
      _ONE_KERNEL),
     ("float32_16384x64_does_not", (1, 16384, 2, 64), 2, jnp.float32, None,
      _TWO_PASSES),
+    # head size 256 (latent attention: 192 + 64 query and key channels,
+    # values of 256): a kv row's accumulators are four times head size
+    # 64's, so the one kernel ends at 4096 keys and glm47f_train_s8192
+    # takes the two passes
+    ("head_256_4096_keys_fit", (1, 4096, 20, 256), 20, jnp.bfloat16, None,
+     _ONE_KERNEL),
+    ("glm47f_1x8192x20x256", (1, 8192, 20, 256), 20, jnp.bfloat16, None,
+     _TWO_PASSES),
 ]
 
 
@@ -694,3 +702,37 @@ def test_backward_path_follows_the_shape(shape, kv_heads, dtype, scale,
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv)
     assert list(_pallas_calls(jaxpr.jaxpr)) == ["flash_fwd"] + backward
+
+
+@pytest.mark.parametrize("backward", ["one_kernel", "two_passes"])
+def test_head_size_256_matches_the_plain_attention(monkeypatch, backward):
+    """Twice the head size of any older case and four times a GPT cell's
+    (``mla_mixer`` hands the kernels q, k, v of ``[b, s, 20, 256]``):
+    the forward and the three gradients against ``local_attention``,
+    through the one-kernel backward and through the two passes, which is
+    what 8192 keys take at this head size."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    if backward == "two_passes":
+        monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", 0)
+    q, k, v = _qkv(b=1, s=128, h=2, d=256, seed=5)
+    weight = jnp.asarray(np.random.RandomState(6).randn(*q.shape),
+                         jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=64, block_k=32)
+
+    def plain(q, k, v):
+        return local_attention(q, k, v, causal=True)
+
+    kernels = list(_pallas_calls(jax.make_jaxpr(jax.grad(
+        lambda *a: flash(*a).sum(), argnums=(0, 1, 2)))(q, k, v).jaxpr))
+    assert kernels == ["flash_fwd"] + (
+        _ONE_KERNEL if backward == "one_kernel" else _TWO_PASSES)
+    np.testing.assert_allclose(flash(q, k, v), plain(q, k, v), atol=2e-5)
+    got = jax.grad(lambda *a: (flash(*a) * weight).sum(),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (plain(*a) * weight).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5, err_msg=name)

@@ -1,0 +1,401 @@
+"""GLM-4.7-Flash's mechanisms on the training path (``glm4_moe_lite``):
+latent attention, routed experts that drop nothing with a chip's share of
+them, a shared expert, a multi-token-prediction module.  The program
+(``models/transformer.py``, ``parallel/moe.py``) against the plain
+reference ``tests/glm_reference.py`` on seeded weights; the shares of the
+experts adding up to the uncut layer; the counters; the published values
+of the named size and the count of its cut; the paths that refuse it.
+All on the CPU at small sizes: hidden 64, 4 heads of 16 + 8 against
+values of 24, latent ranks 24 and 16, 16 experts of width 32 of which 4
+are held from expert 4 on, 3 a token, one dense layer before two expert
+layers.
+"""
+
+import os
+import sys
+from dataclasses import replace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import glm_reference as ref  # noqa: E402
+
+from horovod_tpu.models.transformer import (GPT_CONFIGS, Block,  # noqa: E402
+                                            gpt)
+from horovod_tpu.parallel import moe  # noqa: E402
+
+SMALL = dict(
+    num_layers=3, layer_types=("mla",) * 3, vocab_size=256, emb_dim=64,
+    num_heads=4, num_kv_heads=4, q_lora_rank=24, kv_lora_rank=16,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=24, mlp_ratio=3,
+    routed_experts=16, routed_held=4, routed_first_held=4, routed_top_k=3,
+    routed_width=32, max_len=64, attention_impl="reference",
+    dtype=jnp.float32)
+CONFIG = dict(
+    num_hidden_layers=3, hidden_size=64, num_attention_heads=4,
+    q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=24, rope_theta=1e6, rms_norm_eps=1e-5,
+    n_routed_experts=4, first_held_expert=4, num_experts_per_tok=3,
+    routed_scaling_factor=1.8, mtp_loss_weight=0.3)
+SEQ = 32
+TOKENS = jax.random.randint(jax.random.PRNGKey(0), (2, SEQ + 2), 0, 256)
+
+
+def small_model(**overrides):
+    return gpt("glm-4.7-flash", **{**SMALL, **overrides})
+
+
+def init(model, key=1):
+    """Seeded variables; the router ten times its initial size so that
+    the scores spread over (0, 1) at this width, and the norms' weights
+    away from 1 (at 1 the final norm before the prediction module's own
+    norm changes nothing, and a reference that takes the stream after it
+    could not be told from one that takes it before)."""
+    variables = model.init(jax.random.PRNGKey(key), TOKENS[:, :SEQ])
+
+    def moved(path, leaf):
+        name = jax.tree_util.keystr(path)
+        if "router" in name:
+            return leaf * 10.0
+        if "scale" in name:
+            return leaf + 0.3 * jax.random.normal(
+                jax.random.PRNGKey(len(name)), leaf.shape)
+        return leaf
+
+    return {**variables, "params": jax.tree_util.tree_map_with_path(
+        moved, variables["params"])}
+
+
+def program_losses(model, variables, tokens):
+    logits, mtp_logits = model.apply(
+        {k: variables[k] for k in ("params", "moe_state")},
+        tokens[:, :-2], next_tokens=tokens[:, 1:-1])
+    return (ref._cross_entropy(logits, tokens[:, 1:-1]).mean(),
+            ref._cross_entropy(mtp_logits, tokens[:, 2:]).mean())
+
+
+def program_loss(model, variables, tokens):
+    main, mtp = program_losses(model, variables, tokens)
+    return main + CONFIG["mtp_loss_weight"] * mtp
+
+
+@pytest.mark.parametrize("attention", ["reference", "flash"])
+def test_model_matches_plain_reference(attention):
+    """Logits of both heads, both losses and every leaf of the gradient,
+    with the reference attention and through the flash kernels (the
+    Pallas interpreter): query and key channels 16 + 8 = the values' 24."""
+    model = small_model(attention_impl=attention)
+    variables = init(model)
+    with jax.default_matmul_precision("highest"):
+        got = model.apply(
+            {k: variables[k] for k in ("params", "moe_state")},
+            TOKENS[:, :-2], next_tokens=TOKENS[:, 1:-1])
+        want = ref.forward(CONFIG, variables, TOKENS[:, :-2],
+                           TOKENS[:, 1:-1])
+        for name, a, b in zip(("logits", "mtp_logits"), got, want):
+            np.testing.assert_allclose(a, b, atol=2e-4, err_msg=name)
+        np.testing.assert_allclose(
+            program_losses(model, variables, TOKENS),
+            ref.losses(CONFIG, variables, TOKENS), atol=1e-5)
+        got_grads = jax.grad(lambda p: program_loss(
+            model, {**variables, "params": p}, TOKENS))(variables["params"])
+        want_grads = jax.grad(lambda p: ref.loss(
+            CONFIG, {**variables, "params": p}, TOKENS))(variables["params"])
+    flat_got = dict(jax.tree_util.tree_leaves_with_path(got_grads))
+    flat_want = dict(jax.tree_util.tree_leaves_with_path(want_grads))
+    assert flat_got.keys() == flat_want.keys()
+    for path, want_leaf in flat_want.items():
+        scale = float(jnp.abs(want_leaf).max())
+        assert scale > 0, f"{path}: the reference's gradient is zero"
+        np.testing.assert_allclose(
+            flat_got[path], want_leaf, atol=2e-4 * scale + 1e-7,
+            err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("depart", [
+    "bias_in_weights", "rope_per_head_key", "mtp_after_norm",
+    "concat_swapped"])
+def test_comparison_fails_on_a_seeded_departure(depart):
+    model = small_model()
+    variables = init(model)
+    with jax.default_matmul_precision("highest"):
+        got = program_loss(model, variables, TOKENS)
+        sound = ref.loss(CONFIG, variables, TOKENS)
+        departed = ref.loss(CONFIG, variables, TOKENS, depart)
+    assert abs(got - sound) < 1e-5
+    assert abs(got - departed) > 1e-4
+
+
+def _block(cfg, first_held, held):
+    return Block(replace(cfg, routed_first_held=first_held,
+                         routed_held=held), "mla", "routed")
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Four chips hold four experts each of sixteen.  Every share computes
+    the same attention and the same shared expert, and its own experts'
+    part of the routed sum: the routed parts of all four, with the rest
+    counted ONCE, are the whole layer as the uncut reference gives it."""
+    from horovod_tpu.ops.rope import rope_tables
+
+    cfg = small_model().cfg
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, SEQ, 64))
+    positions = jnp.arange(SEQ)
+    tabs = rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
+    whole = _block(cfg, 0, 16)
+    variables = whole.init(jax.random.PRNGKey(4), x, positions, tabs)
+    p = dict(variables["params"])
+    p["router"] = p["router"] * 10.0
+    state = {"moe_state": variables["moe_state"]}
+
+    def share(first, fc2_scale=1.0):
+        mine = {**p, "experts_fc1": p["experts_fc1"][first:first + 4],
+                "experts_fc2": p["experts_fc2"][first:first + 4] * fc2_scale}
+        return _block(cfg, first, 4).apply({"params": mine, **state}, x,
+                                           positions, tabs)
+
+    with jax.default_matmul_precision("highest"):
+        alike = share(0, fc2_scale=0.0)   # the stream, attention, shared
+        total = alike + sum(share(first) - alike for first in (0, 4, 8, 12))
+        uncut = ref.block({**CONFIG, "n_routed_experts": 16,
+                           "first_held_expert": 0}, p,
+                          variables["moe_state"]["bias"], x)
+        one = share(4)
+    np.testing.assert_allclose(total, uncut, atol=2e-5)
+    # and one share alone is NOT the layer: it leaves out 12 experts
+    assert float(jnp.abs(one - uncut).max()) > 1e-2
+
+
+def _routed(x, router, bias, fc1, fc2, first_held=0):
+    return moe.routed_experts(x, router, bias, fc1, fc2, top_k=3,
+                              scaling=1.8, first_held=first_held,
+                              dtype=jnp.float32)
+
+
+def _layer(seed=5, n=96, d=32, experts=16, held=4, ff=24):
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return (jax.random.normal(k[0], (n, d)),
+            jax.random.normal(k[1], (d, experts)) * 0.3,
+            jax.random.uniform(k[2], (experts,), minval=-0.05, maxval=0.05),
+            jax.random.normal(k[3], (held, d, 2 * ff)) * 0.2,
+            jax.random.normal(k[4], (held, ff, d)) * 0.2)
+
+
+def test_nothing_is_dropped_when_every_token_chooses_the_same_expert():
+    """A bias of +10 on expert 5 makes all 96 tokens choose it (a GShard
+    capacity of 2 x 96 x 3 / 16 = 36 would drop 60 of them): its group
+    holds 96 rows, the counter of dropped rows reads 0, and every token's
+    output has the expert's term, as the reference computes it."""
+    x, router, bias, fc1, fc2 = _layer()
+    bias = bias.at[5].set(10.0)
+    y, routing = _routed(x, router, bias, fc1, fc2, first_held=4)
+    assert int(routing.group_sizes[1]) == 96
+    assert int(routing.dropped) == 0
+    assert int(routing.group_sizes.sum()) == 96 * 3
+    config = {**CONFIG, "num_experts_per_tok": 3}
+    blk = {"router": router, "experts_fc1": fc1, "experts_fc2": fc2}
+    weights = ref.routing_weights(config, blk, bias, x)
+    want = sum(weights[:, 4 + e, None] * ref._gated(x, fc1[e], fc2[e])
+               for e in range(4))
+    assert float(weights[:, 5].min()) > 0
+    np.testing.assert_allclose(y, want, atol=1e-5)
+
+
+def test_the_bias_moves_the_choice_and_not_the_weights():
+    x, router, bias, fc1, fc2 = _layer()
+    scores = jax.nn.sigmoid(x @ router)
+    plain = moe.route(x, router, jnp.zeros_like(bias), top_k=3, scaling=1.8,
+                      first_held=4, held=4)
+    biased = moe.route(x, router, bias.at[7].set(0.5), top_k=3, scaling=1.8,
+                       first_held=4, held=4)
+    assert int((biased.experts == 7).sum()) > int((plain.experts == 7).sum())
+    for routing in (plain, biased):
+        chosen = jnp.take_along_axis(scores, routing.experts, axis=-1)
+        np.testing.assert_allclose(
+            routing.weights, chosen / chosen.sum(-1, keepdims=True) * 1.8,
+            rtol=1e-6)
+    # a program that let the bias into the weights would give it a gradient
+    grad = jax.grad(lambda b: _routed(x, router, b, fc1, fc2, 4)[0].sum())(
+        bias)
+    assert float(jnp.abs(grad).max()) == 0.0
+
+
+def test_the_balancing_update_evens_the_load_and_moves_no_weight():
+    """``rebalanced`` is the aux-free update the configuration names
+    (``noaux_tc``): every expert's bias moves by ``rate`` against the sign
+    of its load's distance from the mean.  The load counts the slots of
+    ALL experts, the held ones' part of it being the rows the grouped
+    matmul sees; a router that overloads four experts is brought back to
+    an even share, step by step, by the bias alone."""
+    x, router, _, _, _ = _layer(n=512)
+    x = x.at[:, 0].set(1.0)             # one channel every token shares
+    router = router.at[0, 4:8].add(0.6)  # pulls every token to four experts
+    state = {"layer": {"bias": jnp.zeros((16,))}}
+
+    def routed(state):
+        return moe.route(x, router, state["layer"]["bias"], top_k=3,
+                         scaling=1.8, first_held=4, held=4)
+
+    first = routed(state)
+    assert first.load.sum() == 512 * 3
+    assert first.load[4:8].tolist() == first.group_sizes[:4].tolist()
+    even = 512 * 3 * 4 / 16
+    assert int(first.group_sizes[:4].sum()) > 1.25 * even
+    once = moe.rebalanced(state, {"layer": {"load": first.load}}, 0.01)
+    np.testing.assert_allclose(
+        once["layer"]["bias"],
+        0.01 * jnp.sign(first.load.mean() - first.load), rtol=1e-6)
+    for _ in range(60):
+        state = moe.rebalanced(
+            state, {"layer": {"load": routed(state).load}}, 0.01)
+    last = routed(state)
+    assert abs(int(last.group_sizes[:4].sum()) / even - 1.0) < 0.1
+    # the weights are the scores' alone, whatever the bias has become
+    chosen = jnp.take_along_axis(jax.nn.sigmoid(x @ router), last.experts,
+                                 axis=-1)
+    np.testing.assert_allclose(
+        last.weights, chosen / chosen.sum(-1, keepdims=True) * 1.8,
+        rtol=1e-5)
+
+
+def test_the_rows_of_the_last_step_are_state_and_not_parameters():
+    """``moe_stats`` holds each expert layer's rows per held expert, its
+    dropped rows and the load of every routed expert; ``moe_state`` the
+    selection bias, seeded non-zero;
+    neither is under ``params``.  ``publish_stats`` turns the first into
+    the registry's gauges."""
+    from horovod_tpu.obs.registry import MetricsRegistry
+
+    model = small_model()
+    variables = init(model)
+    assert set(variables) == {"params", "moe_state", "moe_stats"}
+    assert set(variables["moe_state"]) == {"block1", "block2", "mtp"}
+    assert float(jnp.abs(variables["moe_state"]["block1"]["bias"]).min()) > 0
+    _, new = model.apply(variables, TOKENS[:, :-2],
+                         next_tokens=TOKENS[:, 1:-1], mutable=["moe_stats"])
+    registry = MetricsRegistry()
+    stats = moe.publish_stats(new["moe_stats"], registry)
+    assert set(stats) == {"block1", "block2", "mtp/block"}
+    routing = moe.route(jnp.zeros((4, 8)), jnp.zeros((8, 16)),
+                        jnp.arange(16.0), top_k=3, scaling=1.0,
+                        first_held=12, held=4)
+    assert routing.group_sizes.tolist() == [0, 4, 4, 4, 0]
+    for layer, entry in stats.items():
+        rows = new["moe_stats"]
+        for part in layer.split("/"):
+            rows = rows[part]
+        assert entry["rows_held"] == int(rows["rows"].sum()) > 0
+        assert entry["rows_dropped"] == 0
+        assert entry["max_over_mean"] >= 1.0
+        assert registry.gauge("moe.rows_held", layer=layer).value == \
+            entry["rows_held"]
+    # an apply that does not ask for the counters leaves them alone
+    assert model.apply(variables, TOKENS[:, :SEQ]).shape == (2, SEQ, 256)
+
+
+PUBLISHED = dict(
+    vocab_size=154880, num_layers=47, emb_dim=2048, num_heads=20, kv_heads=20,
+    q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+    qk_rope_head_dim=64, v_head_dim=256, rope_theta=1e6, norm_eps=1e-5,
+    routed_experts=64, held_experts=64, routed_top_k=4, routed_width=1536,
+    routed_scaling=1.8, shared_experts=1, dense_layers_first=1,
+    mtp_modules=1, max_len=202752, tie_embeddings=False, use_bias=False,
+    norm="rmsnorm", mlp="silu_gated", pos_embedding="rope")
+
+
+def test_named_configuration_holds_the_published_values():
+    cfg = GPT_CONFIGS["glm-4.7-flash"]
+    for key, value in PUBLISHED.items():
+        assert getattr(cfg, key) == value, key
+    assert cfg.mlp_ratio * cfg.emb_dim == 10240
+    assert set(cfg.layer_types) == {"mla"} and len(cfg.layer_types) == 47
+    assert [cfg.ffn_type(i) for i in (0, 1, 46)] == [
+        "dense", "routed", "routed"]
+
+
+def test_the_cut_counts_706518528_parameters():
+    """The benchmark's cut from the named size: depth 47 -> 5 (the dense
+    layer and four expert layers), 8 of 64 experts held, an eighth of the
+    vocabulary; every width as published (ISSUE 32 has the sum)."""
+    model = gpt("glm-4.7-flash", num_layers=5, layer_types=("mla",) * 5,
+                routed_held=8, vocab_size=19360)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    count = lambda tree: sum(x.size for x in jax.tree.leaves(tree))
+    p = shapes["params"]
+    assert count(p["block0"]) == 84_677_888
+    assert count(p["block1"]) == 106_829_056
+    assert count(p["mtp"]) == 115_223_808
+    assert count(p["wte"]) == count(p["head"]) == 39_649_280
+    assert count(p) == 706_518_528
+    # the selection bias and the counters are state: no gradient, no moment
+    assert count(shapes["moe_state"]) == 5 * 64
+    # per expert layer: rows of 8 held experts, rows dropped, and the
+    # load of all 64
+    assert count(shapes["moe_stats"]) == 5 * (8 + 1 + 64)
+
+
+def _refusals():
+    from horovod_tpu.models import decode
+    from horovod_tpu.models.transformer import raw_block_forward
+    from horovod_tpu.parallel import pipeline, tensor_parallel
+    from horovod_tpu.serve.engine import SlotEngine
+
+    x = jnp.zeros((1, 8, 64))
+    return {
+        "generate": lambda c, t: decode.generate(c, {}, t, 4),
+        "prefill": lambda c, t: decode.prefill(c, {}, t),
+        "decode_step": lambda c, t: decode.decode_step(c, {}, None, t[:, 0]),
+        "init_cache": lambda c, t: decode.init_cache(c, 1),
+        "init_paged_pool": lambda c, t: decode.init_paged_pool(c, 4, 8, 2),
+        "slot_engine": lambda c, t: SlotEngine(c, {}, 2),
+        "stack_tp_params": lambda c, t: tensor_parallel.stack_tp_params(
+            {}, c, 2),
+        "tp_gpt_apply": lambda c, t: tensor_parallel.tp_gpt_apply(
+            {}, {}, c, t, "tp"),
+        "stack_pp_params": lambda c, t: pipeline.stack_pp_params({}, c, 2),
+        "pp_gpt_apply": lambda c, t: pipeline.pp_gpt_apply(
+            {}, {}, c, t, "pp", microbatches=1),
+        "raw_block_forward": lambda c, t: raw_block_forward(
+            c, {}, x, jnp.arange(8), None),
+    }
+
+
+# Decode, serve, tensor and pipeline parallelism build GPT-2's block from
+# raw weights: they refuse latent attention (by ``layer_types``), routed
+# experts and a prediction module by name, before anything is traced.
+@pytest.mark.parametrize("setting", ["layer_types", "routed_experts",
+                                     "mtp_modules"])
+@pytest.mark.parametrize("path", sorted(_refusals()))
+def test_paths_refuse_what_they_cannot_run(path, setting):
+    gpt2 = gpt("nano").cfg
+    cfg = {"layer_types": small_model().cfg,
+           "routed_experts": replace(gpt2, mlp="silu_gated",
+                                     routed_experts=8, routed_top_k=2,
+                                     routed_width=32),
+           "mtp_modules": replace(gpt2, mtp_modules=1)}[setting]
+    with pytest.raises(ValueError, match=setting):
+        _refusals()[path](cfg, jnp.zeros((1, 8), jnp.int32))
+
+
+@pytest.mark.parametrize("override,message", [
+    ({"moe_experts": 4}, "two expert layers"),
+    ({"mlp": "gelu"}, "routed experts are silu-gated"),
+    ({"routed_top_k": 17}, "routed_top_k"),
+    ({"routed_first_held": 14}, "held experts"),
+    ({"mtp_modules": 2}, "one prediction module"),
+    ({"pos_embedding": "learned"}, "pos_embedding must be 'rope'"),
+    ({"v_head_dim": 0}, "needs positive"),
+])
+def test_configuration_refuses_what_it_cannot_mean(override, message):
+    with pytest.raises(ValueError, match=message):
+        small_model(**override)
+
+
+def test_flash_refuses_unequal_head_sizes():
+    model = small_model(attention_impl="flash", v_head_dim=16)
+    with pytest.raises(ValueError, match="one head size"):
+        model.init(jax.random.PRNGKey(0), TOKENS[:, :SEQ])

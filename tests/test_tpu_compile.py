@@ -147,6 +147,12 @@ _BACKWARD_PATHS = [
     # as one kernel this one asks for 32.63 MiB
     ("over_the_budget_16384x64", (1, 16384, 8, 64), 8, jnp.bfloat16, None,
      None, False),
+    # latent attention's shape in glm47f_train_s8192: head size 256 keeps
+    # a kv row's accumulators at 32 MiB, so the two passes run
+    ("glm47f_1x8192x20x256", (1, 8192, 20, 256), 20, jnp.bfloat16, None,
+     None, False),
+    ("head_256_fits_4096_keys", (1, 4096, 20, 256), 20, jnp.bfloat16, None,
+     None, True),
 ]
 
 
@@ -176,6 +182,36 @@ def test_flash_backward_path_compiles_for_v5e(one_chip, shape, kv_heads,
     text = jax.jit(backward).lower(q, kv, kv).compile().as_text()
     assert "flash_bwd_dkdv" in text
     assert ("flash_bwd_dq" not in text) == one_kernel
+
+
+def test_grouped_expert_matmuls_compile_for_v5e(one_chip):
+    """The dropless expert layer's grouped feed-forward at
+    glm47f_train_s8192's shape (8192 tokens x 4 choices = 32768 rows of
+    2048, 8 held experts of 1536, a ninth group for the rows whose expert
+    lives elsewhere), forward and backward: jax's Pallas grouped matmul,
+    whose grid follows the group sizes (five calls: the first matmul
+    forward, and each matmul's two gradients, ``gmm`` for the rows and
+    ``tgmm`` for the weights), and no ``ragged-dot`` beside it."""
+    from horovod_tpu.parallel.moe import grouped_ffn
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    args = (shape((32768, 2048), jnp.bfloat16),
+            shape((8, 2048, 3072), jnp.float32),
+            shape((8, 1536, 2048), jnp.float32), shape((9,), jnp.int32))
+
+    def backward(xs, fc1, fc2, sizes):
+        return jax.grad(
+            lambda *a: grouped_ffn(*a, sizes, interpret=False).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2))(xs, fc1, fc2)
+
+    compiled = jax.jit(backward).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 5
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    # the widest temporaries are the [32768, 3072] buffers, 192 MiB each
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
 @pytest.fixture
