@@ -71,10 +71,14 @@ class TransformerConfig:
     # short sequences, so the large default is shape-safe.
     flash_block_q: int = 512
     flash_block_k: int = 256
-    # Rematerialize each block in the backward pass, keeping only matmul
-    # outputs with no batch dims (the standard TPU transformer remat
-    # policy): trades HBM for recomputed elementwise FLOPs, buying larger
-    # per-chip batches — the MFU lever when activations bound the batch.
+    # Rematerialize each block in the backward pass: trades HBM for
+    # recomputed FLOPs, buying larger per-chip batches — the MFU lever
+    # when activations bound the batch.  A block keeps what remat_policy
+    # says and, always, what its Pallas kernels' forward made (flash
+    # attention's o and lse, the scan's y and chunk states:
+    # block_remat_policy): a kernel's cost is quadratic in the sequence
+    # (the scan's, a chunk's square a chunk) and its output one
+    # activation of the stream, so it is never run a second time.
     remat: bool = False
     # Mixture-of-experts MLP (parallel/moe.py): >0 replaces every block's
     # dense MLP with moe_experts experts (GShard one-hot dispatch, static
@@ -111,7 +115,9 @@ class TransformerConfig:
     logits_scaling: float = 1.0        # logits = head(x) / scaling
     tie_embeddings: bool = False       # the head is wte, transposed
     # Which of a rematerialized block's values are kept for the backward
-    # pass (a name in jax.checkpoint_policies).
+    # pass beside its kernels' outputs (a name in
+    # jax.checkpoint_policies; the default keeps matmul outputs with no
+    # batch dims, the standard TPU transformer policy).
     remat_policy: str = "dots_with_no_batch_dims_saveable"
     # The Mamba-2 mixer: ssm_heads heads of ssm_head_dim (their product is
     # the inner width), a state of ssm_state per head channel, B and C
@@ -841,10 +847,7 @@ class GPT(nn.Module):
             rope_tabs = rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
         block_cls = Block
         if cfg.remat:
-            block_cls = nn.remat(
-                Block,
-                policy=getattr(jax.checkpoint_policies, cfg.remat_policy),
-            )
+            block_cls = nn.remat(Block, policy=block_remat_policy(cfg))
         for i in range(cfg.num_layers):
             x = block_cls(cfg, cfg.layer_type(i), cfg.ffn_type(i),
                           name=f"block{i}")(x, positions, rope_tabs)
@@ -893,6 +896,41 @@ class GPT(nn.Module):
         return logits, mtp_logits
 
 
+def block_remat_policy(cfg: TransformerConfig):
+    """What a rematerialised block keeps: whatever ``cfg.remat_policy``
+    keeps, and always what a Pallas kernel's forward made
+    (``scopes.KERNEL_OUTPUTS``, named in the kernels' ``custom_vjp``
+    forward rules), so the recompute never runs a kernel a second time.
+
+    The decision is taken when the step is differentiated, so it is
+    counted there: gauges ``remat.kept_values{name}`` and
+    ``remat.kept_mib{name}`` of the metrics registry hold, by name, how
+    many kernel outputs the blocks of the last such trace kept and their
+    size, from the shapes the policy was shown.  One policy a model
+    trace: its tally starts at nothing."""
+    from ..obs.registry import get_registry  # noqa: PLC0415
+
+    policies = jax.checkpoint_policies
+    is_kernel_output = policies.save_only_these_names(*scopes.KERNEL_OUTPUTS)
+    kept = {}
+
+    def kernel_outputs(prim, *avals, **params):
+        if not is_kernel_output(prim, *avals, **params):
+            return False
+        name = params["name"]
+        values, nbytes = kept.get(name, (0, 0))
+        values += 1
+        nbytes += sum(a.size * a.dtype.itemsize for a in avals)
+        kept[name] = values, nbytes
+        registry = get_registry()
+        registry.gauge("remat.kept_values", name=name).set(values)
+        registry.gauge("remat.kept_mib", name=name).set(nbytes / 2 ** 20)
+        return True
+
+    return policies.save_from_both_policies(
+        getattr(policies, cfg.remat_policy), kernel_outputs)
+
+
 # Named sizes (GPT-2 family geometry; head_dim 64, MXU-friendly widths).
 GPT_CONFIGS = {
     "nano": TransformerConfig(num_layers=3, num_heads=4, emb_dim=128,
@@ -917,7 +955,9 @@ GPT_CONFIGS = {
         ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=1,
         ssm_conv=4, ssm_chunk=256,
         # a Mamba block's matmul outputs are 0.47 GB at 8192 tokens:
-        # keep only each block's input
+        # keep each block's input and, as every policy does, what its
+        # kernels made (the scan's y and states 128 MiB a block, the
+        # attention layer's o and lse 33 MiB)
         remat_policy="nothing_saveable",
     ),
     # https://huggingface.co/zai-org/GLM-4.7-Flash config.json
@@ -938,8 +978,10 @@ GPT_CONFIGS = {
         routed_experts=64, routed_top_k=4, routed_width=1536,
         routed_scaling=1.8, shared_experts=1, dense_layers_first=1,
         mtp_modules=1,
-        # 8192 x 5120 queries, keys and values a block: keep only each
-        # block's input
+        # 8192 x 5120 queries, keys and values a block: keep each
+        # block's input and, as every policy does, what its kernels made
+        # (o 80 MiB and lse 0.6 MiB a block: a second flash forward
+        # costs 8.5 ms at 8192 tokens)
         remat_policy="nothing_saveable",
     ),
 }

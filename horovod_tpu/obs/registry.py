@@ -197,13 +197,15 @@ class MetricsRegistry:
             )
         return inst
 
-    def counter(self, name: str, **tags: str) -> Counter:
+    # ``name`` is positional only: a tag may itself be called ``name``
+    # (``remat.kept_values{name=flash_out}``).
+    def counter(self, name: str, /, **tags: str) -> Counter:
         return self._get(Counter, name, tags)
 
-    def gauge(self, name: str, **tags: str) -> Gauge:
+    def gauge(self, name: str, /, **tags: str) -> Gauge:
         return self._get(Gauge, name, tags)
 
-    def histogram(self, name: str, **tags: str) -> Histogram:
+    def histogram(self, name: str, /, **tags: str) -> Histogram:
         return self._get(Histogram, name, tags)
 
     def remove_matching(self, prefix: str) -> int:

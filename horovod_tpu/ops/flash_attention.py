@@ -65,8 +65,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .. import scopes
 
 NEG_INF = float(jnp.finfo(jnp.float32).min) / 2
 # dot_general numbers for a.T @ b: contract the rows (the keys) of both.
@@ -168,6 +171,10 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, h, hkv, window,
                interpret):
     o, lse = _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv,
                                window, interpret)
+    # named here, not at the call site: the residual has to be the named
+    # value, or a rematerialised block reruns the kernel to get it
+    o = checkpoint_name(o, scopes.FLASH_OUT)
+    lse = checkpoint_name(lse, scopes.FLASH_LSE)
     return o, (q, k, v, o, lse)
 
 

@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -164,12 +165,20 @@ def ssd_scan(x, dt, A, B, C, D, chunk: int):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
 def _ssd(x, dt, A, B, C, D, chunk, hb, interpret):
-    return _forward(x, dt, A, B, C, D, chunk, hb, interpret, False)[0]
+    y, _ = _forward(x, dt, A, B, C, D, chunk, hb, interpret, False)
+    return y.reshape(x.shape)
 
 
 def _ssd_fwd(x, dt, A, B, C, D, chunk, hb, interpret):
     y, states = _forward(x, dt, A, B, C, D, chunk, hb, interpret, True)
-    return y, (x, dt, A, B, C, D, states)
+    # both, or a rematerialised block reruns the call: the backward reads
+    # the states, the gated norm's recompute reads y.  y as the kernel
+    # wrote it, heads folded into lanes: kept as [.., heads, head_dim] it
+    # costs a copy and a convert a layer (64 of 128 lanes; 1.2 ms a
+    # layer at 8192 x 64 x 64, my chip runs, PR 33)
+    y = checkpoint_name(y, scopes.SSD_OUT)
+    states = checkpoint_name(states, scopes.SSD_STATES)
+    return y.reshape(x.shape), (x, dt, A, B, C, D, states)
 
 
 def _ssd_bwd(chunk, hb, interpret, res, dy):
@@ -464,8 +473,7 @@ def _forward(x, dt, A, B, C, D, chunk, hb, interpret, save_states):
         interpret=interpret,
         name="ssd_fwd",
     )(D.astype(_F32), *_layouts(x, dt, A, B, C, chunk, hb))
-    y = out[0].reshape(b, s, h, p)
-    return y, (out[1] if save_states else None)
+    return out[0], (out[1] if save_states else None)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "hb", "interpret"))

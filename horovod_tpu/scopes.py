@@ -1,4 +1,5 @@
-"""The names the program gives its own work in a device trace.
+"""The names the program gives its own work: scopes in a device trace,
+and the values a rematerialised block always keeps.
 
 Every ``jax.named_scope`` of the package takes its name from here, and
 the device-trace reduction (``obs/profile.py:scope_of``) files an
@@ -38,6 +39,17 @@ STEM = "stem"                          # ResNet's first conv and pool
 KV_GATHER = "kv_gather"                # paged decode: pages -> contiguous KV
 KV_SCATTER = "kv_scatter"              # paged prefill: KV -> pages
 SAMPLE = "sample"                      # the token pick
+
+# What a Pallas kernel's forward made, named (``checkpoint_name``) inside
+# its ``custom_vjp`` forward rule so that the value returned and the
+# residual are one named value.  A rematerialised block keeps these
+# whatever its policy (``models/transformer.py:block_remat_policy``):
+# the recompute finds them and does not run the kernel a second time.
+FLASH_OUT = "flash_out"                # flash attention's o
+FLASH_LSE = "flash_lse"                # and its log-sum-exp rows
+SSD_OUT = "ssd_out"                    # the state-space scan's y
+SSD_STATES = "ssd_states"              # and its chunk-start states
+KERNEL_OUTPUTS = (FLASH_OUT, FLASH_LSE, SSD_OUT, SSD_STATES)
 
 SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ, SSM,
           SSD_SCAN, MLP, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED,

@@ -1,12 +1,19 @@
 """Model zoo tests: shapes, dtypes, trainability, SyncBatchNorm variant."""
 
+import functools
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
 
-from horovod_tpu import models
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import remat_cases  # noqa: E402
+
+from horovod_tpu import models  # noqa: E402
 
 
 def test_convnet_and_mlp_shapes():
@@ -128,34 +135,29 @@ def test_graft_entry_dryrun_multichip():
     g.dryrun_multichip(8)
 
 
-def test_gpt_remat_matches_no_remat():
-    """cfg.remat=True (dots-saveable block remat) is a pure memory/compute
-    trade: outputs AND gradients must match the non-remat model exactly on
-    the same params."""
+@functools.lru_cache(maxsize=None)
+def _no_remat(mixer):
+    loss, params = remat_cases.build(mixer)
+    return jax.value_and_grad(loss)(params)
+
+
+@pytest.mark.parametrize("policy", remat_cases.POLICIES)
+@pytest.mark.parametrize("mixer", sorted(remat_cases.MIXERS))
+def test_gpt_remat_matches_no_remat(mixer, policy):
+    """cfg.remat=True is a pure memory/compute trade, whatever the
+    blocks' mixer (the reference attention, the flash kernels, latent
+    attention with a prediction module, Mamba-2) and whichever policy
+    says what else a block keeps beside its kernels' outputs: loss AND
+    gradients must match the non-remat model on the same params."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
-    import optax
 
-    from horovod_tpu.models.transformer import gpt
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import remat_cases
 
-    tokens = jnp.asarray(
-        np.random.RandomState(0).randint(0, 1024, size=(2, 32)), jnp.int32
-    )
-    base = gpt("nano", attention_impl="reference")
-    rematted = gpt("nano", attention_impl="reference", remat=True)
-    params = base.init(jax.random.PRNGKey(0), tokens)
-
-    def loss_fn(model):
-        def f(p):
-            logits = model.apply(p, tokens)
-            return optax.softmax_cross_entropy_with_integer_labels(
-                logits, tokens
-            ).mean()
-        return f
-
-    l0, g0 = jax.value_and_grad(loss_fn(base))(params)
-    l1, g1 = jax.value_and_grad(loss_fn(rematted))(params)
+    l0, g0 = _no_remat(mixer)
+    rematted, params = remat_cases.build(mixer, remat=True, policy=policy)
+    l1, g1 = jax.value_and_grad(rematted)(params)
     np.testing.assert_allclose(float(l0), float(l1), rtol=1e-6)
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
