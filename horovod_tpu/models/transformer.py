@@ -30,6 +30,7 @@ TPU-first choices:
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass, field, replace
 from typing import Any, Optional
 
@@ -39,6 +40,14 @@ import jax.numpy as jnp
 
 from .. import scopes
 from ..parallel.moe import DEFAULT_GROUP_SIZE as MOE_DEFAULT_GROUP_SIZE
+
+
+# What ``layer_types`` may name: one mixer per layer.  The last two are
+# attention layers told apart by their mask (and, where
+# ``rope_layer_types`` says so, by their positions).
+ATTENTION_LAYER_TYPES = ("attention", "mla", "sliding_attention",
+                         "full_attention")
+LAYER_TYPES = ATTENTION_LAYER_TYPES + ("mamba",)
 
 
 @dataclass(frozen=True)
@@ -164,6 +173,27 @@ class TransformerConfig:
     # model then also returns logits for the token after next wherever
     # it is handed next_tokens.
     mtp_modules: int = 0
+    # ---- the attention layer's own settings; every default is GPT-2's.
+    # One head's channels (None = emb_dim // num_heads): q is num_heads *
+    # head_size wide, k and v kv_heads * head_size, whatever emb_dim is.
+    head_size: Optional[int] = None
+    # Layer types "sliding_attention" and "full_attention" are attention
+    # layers that differ in their mask alone: the first sees its last
+    # attention_window keys, the second every earlier key whatever
+    # attention_window says.  (Types "attention" and "mla", and a model
+    # with layer_types=None, take attention_window as it stands.)
+    # Which layer types rotate q and k under pos_embedding="rope"
+    # (None = every attention layer); the others see no positions.
+    rope_layer_types: Optional[tuple] = None
+    # The configuration's norm over each head's channels of q and of k
+    # (one scale of head_dim each, shared by the heads), before RoPE.
+    qk_norm: bool = False
+    # att * sigmoid(gate(h)) before proj: a gate as wide as q, from the
+    # normed stream the queries are made of.
+    attention_gate: bool = False
+    # A norm on each branch's OUTPUT before the residual add, beside the
+    # two on its input: four norms a block.
+    post_norms: bool = False
 
     def __post_init__(self):
         if self.num_kv_heads is not None:
@@ -185,12 +215,17 @@ class TransformerConfig:
                 f"mlp must be 'gelu' or 'silu_gated', got {self.mlp!r}")
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
-            unknown = set(self.layer_types) - {"attention", "mamba", "mla"}
+            unknown = set(self.layer_types) - set(LAYER_TYPES)
             if unknown or len(self.layer_types) != self.num_layers:
                 raise ValueError(
-                    f"layer_types must name 'attention', 'mamba' or 'mla' "
+                    f"layer_types must name one of {LAYER_TYPES} "
                     f"for each of num_layers={self.num_layers} layers, got "
                     f"{self.layer_types!r}")
+            if "sliding_attention" in self.layer_types and (
+                    self.attention_window is None):
+                raise ValueError(
+                    "a 'sliding_attention' layer sees its last "
+                    "attention_window keys: attention_window must be set")
             if "mamba" in self.layer_types and (
                     self.ssm_heads <= 0
                     or self.ssm_heads % self.ssm_groups):
@@ -231,10 +266,30 @@ class TransformerConfig:
             raise ValueError(
                 f"mtp_modules={self.mtp_modules}: one prediction module is "
                 f"implemented, or none")
+        if self.head_size is not None and self.head_size <= 0:
+            raise ValueError(
+                f"head_size={self.head_size} must be positive (None = "
+                f"emb_dim // num_heads)")
+        if self.rope_layer_types is not None:
+            object.__setattr__(self, "rope_layer_types",
+                               tuple(self.rope_layer_types))
+            unknown = set(self.rope_layer_types) - set(ATTENTION_LAYER_TYPES)
+            if unknown or self.pos_embedding != "rope":
+                raise ValueError(
+                    f"rope_layer_types names the layer types among "
+                    f"{ATTENTION_LAYER_TYPES} that rotate under "
+                    f"pos_embedding='rope', got {self.rope_layer_types!r} "
+                    f"with pos_embedding={self.pos_embedding!r}")
+            if "mla" in (self.layer_types or ()) and (
+                    "mla" not in self.rope_layer_types):
+                raise ValueError(
+                    "an 'mla' layer rotates its rotary channels: "
+                    "rope_layer_types must name 'mla'")
 
     @property
     def head_dim(self) -> int:
-        return self.emb_dim // self.num_heads
+        return (self.head_size if self.head_size is not None
+                else self.emb_dim // self.num_heads)
 
     @property
     def kv_heads(self) -> int:
@@ -243,6 +298,18 @@ class TransformerConfig:
 
     def layer_type(self, i: int) -> str:
         return self.layer_types[i] if self.layer_types else "attention"
+
+    def window_of(self, layer_type: Optional[str]) -> Optional[int]:
+        """The keys a layer of this type sees beside the causal mask:
+        its last ``attention_window``, or ``None`` for all of them."""
+        return (None if layer_type == "full_attention"
+                else self.attention_window)
+
+    def rotates(self, layer_type: str) -> bool:
+        """Does a layer of this type turn q and k by position?"""
+        return self.pos_embedding == "rope" and (
+            self.rope_layer_types is None
+            or layer_type in self.rope_layer_types)
 
     def ffn_type(self, i: int) -> str:
         """``"routed"`` where layer ``i``'s feed-forward is the dropless
@@ -281,7 +348,9 @@ def require_gpt2_block(cfg: TransformerConfig, who: str) -> None:
     for setting in ("routed_experts", "mtp_modules", "norm", "norm_eps",
                     "mlp", "use_bias", "tie_embeddings",
                     "embedding_multiplier", "residual_multiplier",
-                    "logits_scaling", "attention_scale"):
+                    "logits_scaling", "attention_scale", "head_size",
+                    "rope_layer_types", "qk_norm", "attention_gate",
+                    "post_norms"):
         if getattr(cfg, setting) != getattr(gpt2, setting):
             raise ValueError(
                 f"{who} implements GPT-2's block only "
@@ -293,21 +362,46 @@ def require_gpt2_block(cfg: TransformerConfig, who: str) -> None:
             f"configuration says pos_embedding='none'")
 
 
-def _attend(cfg: TransformerConfig, q, k, v, positions):
+def _attend(cfg: TransformerConfig, q, k, v, positions,
+            layer_type: Optional[str] = None):
     """Dispatch to the configured attention schedule (always causal).
     ``positions``: int [s_local] global positions of the local rows —
-    used by schedules that mask in global coordinates."""
-    if cfg.attention_impl == "flash":
-        from ..ops.flash_attention import flash_attention  # noqa: PLC0415
+    used by schedules that mask in global coordinates.  ``layer_type``
+    decides the window (``cfg.window_of``); a call that has one traces
+    under the scope ``attn_window``, so a device trace tells the banded
+    kernels from the full ones."""
+    window = cfg.window_of(layer_type)
+    with (jax.named_scope(scopes.ATTN_WINDOW) if window is not None
+          else contextlib.nullcontext()):
+        return _attend_schedule(cfg, q, k, v, positions, layer_type, window)
 
+
+def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
+                     window):
+    if cfg.attention_impl == "flash":
+        from ..obs.registry import get_registry  # noqa: PLC0415
+        from ..ops.flash_attention import (  # noqa: PLC0415
+            flash_attention, tile_counts,
+        )
+
+        # counted while the step is traced, like remat.kept_values: the
+        # (q, k) tiles this call's grid walks and those that do work
+        live, grid = tile_counts(
+            q.shape[0] * q.shape[2], q.shape[1], cfg.flash_block_q,
+            cfg.flash_block_k, causal=True, window=window)
+        registry = get_registry()
+        label = layer_type or "attention"
+        registry.gauge("flash.tiles_live", layer_type=label).set(live)
+        registry.gauge("flash.tiles_grid", layer_type=label).set(grid)
         return flash_attention(
             q, k, v, causal=True,
             block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
-            window=cfg.attention_window, scale=cfg.attention_scale,
+            window=window, scale=cfg.attention_scale,
         )
-    if cfg.attention_window is not None:
+    if window is not None and cfg.attention_impl != "reference":
         raise ValueError(
-            "attention_window is flash-only; "
+            "attention_window is flash-only on a chip (the reference "
+            "schedule takes it for tests); "
             f"attention_impl={cfg.attention_impl!r} does not support it"
         )
     if cfg.kv_heads != cfg.num_heads and cfg.attention_impl in (
@@ -354,7 +448,7 @@ def _attend(cfg: TransformerConfig, q, k, v, positions):
     # local_attention masks from scalar offsets: valid because every
     # non-zigzag layout is contiguous per shard (zigzag never routes here)
     return local_attention(
-        q, k, v, causal=True, scale=cfg.attention_scale,
+        q, k, v, causal=True, scale=cfg.attention_scale, window=window,
         q_offset=positions[0], kv_offset=positions[0]
     )
 
@@ -449,7 +543,9 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
                ln1, ln2, mlp, qkv=None, proj=None, ssm=None, mla=None,
                num_heads: Optional[int] = None,
                num_kv_heads: Optional[int] = None,
-               attend=None):
+               attend=None, layer_type: Optional[str] = None,
+               q_norm=None, k_norm=None, gate=None,
+               post_attn_norm=None, post_mlp_norm=None):
     """THE pre-norm block wiring — the single source of truth.
 
     ``norm → mixer → (+res) → norm → feed-forward → (+res)``, each
@@ -475,6 +571,16 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
     decode path (models/decode.py) supplies one that appends to its
     cache and attends the single query against the prefix, so decoding
     reuses THIS wiring instead of a third copy.
+
+    The attention half's optional steps, each a callable where the
+    configuration asks for it and ``None`` where not: ``q_norm`` and
+    ``k_norm`` over each head's channels after the head split and before
+    RoPE; ``gate``, from the same normed stream as the queries and as
+    wide, whose sigmoid multiplies the attended values before ``proj``
+    (scope ``attn_gate``); ``post_attn_norm`` and ``post_mlp_norm`` on a
+    branch's output before its residual add.  ``layer_type`` gives the
+    attention call its window (``cfg.window_of``); the caller hands in
+    ``rope_tabs=None`` for a layer that sees no positions.
     """
     b, s, _ = x.shape
     nh = num_heads if num_heads is not None else cfg.num_heads
@@ -483,7 +589,9 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
     q_dim = nh * hd
     kv_dim = nkv * hd
 
-    def add(x, delta):
+    def add(x, delta, post=None):
+        if post is not None:
+            delta = post(delta).astype(x.dtype)
         if cfg.residual_multiplier == 1.0:
             return x + delta
         return x + cfg.residual_multiplier * delta
@@ -494,10 +602,10 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
     # tree stays ``block<i>/{ln1,qkv,proj,ln2,fc1,fc2}``.
     if ssm is not None:
         with jax.named_scope(scopes.SSM):
-            x = add(x, act_store(ssm(ln1(x)), cfg))
+            x = add(x, act_store(ssm(ln1(x)), cfg), post_attn_norm)
     elif mla is not None:
         with jax.named_scope(scopes.ATTN):
-            x = add(x, act_store(mla(ln1(x)), cfg))
+            x = add(x, act_store(mla(ln1(x)), cfg), post_attn_norm)
     else:
         with jax.named_scope(scopes.ATTN):
             h = ln1(x)
@@ -505,6 +613,10 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
             q = fused[..., :q_dim].reshape(b, s, nh, hd)
             k = fused[..., q_dim:q_dim + kv_dim].reshape(b, s, nkv, hd)
             v = fused[..., q_dim + kv_dim:].reshape(b, s, nkv, hd)
+            if q_norm is not None:
+                q = q_norm(q).astype(fused.dtype)
+            if k_norm is not None:
+                k = k_norm(k).astype(fused.dtype)
             if rope_tabs is not None:
                 from ..ops.rope import apply_rope_tables  # noqa: PLC0415
 
@@ -517,13 +629,19 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
                     # geometry
                     attend_cfg = replace(cfg, num_heads=nh,
                                          num_kv_heads=nkv, emb_dim=q_dim)
-                att_4d = _attend(attend_cfg, q, k, v, positions)
+                att_4d = _attend(attend_cfg, q, k, v, positions,
+                                 layer_type)
             else:
                 att_4d = attend(q, k, v)
-            att = act_store(att_4d.reshape(b, s, q_dim), cfg)
-            x = add(x, act_store(proj(att), cfg))
+            att = att_4d.reshape(b, s, q_dim)
+            if gate is not None:
+                with jax.named_scope(scopes.ATTN_GATE):
+                    att = (att * jax.nn.sigmoid(
+                        gate(h).astype(jnp.float32))).astype(att.dtype)
+            att = act_store(att, cfg)
+            x = add(x, act_store(proj(att), cfg), post_attn_norm)
     with jax.named_scope(scopes.MLP):
-        return add(x, act_store(mlp(ln2(x)), cfg))
+        return add(x, act_store(mlp(ln2(x)), cfg), post_mlp_norm)
 
 
 def raw_layer_norm(x, scale, bias, eps: float = 1e-6):
@@ -740,8 +858,20 @@ class Block(nn.Module):
 
             mixer["mla"] = mla
         else:
-            mixer["qkv"] = dense(cfg.emb_dim + 2 * kv_dim, "qkv")
+            q_dim = cfg.num_heads * cfg.head_dim
+            mixer["qkv"] = dense(q_dim + 2 * kv_dim, "qkv")
             mixer["proj"] = dense(cfg.emb_dim, "proj")
+            mixer["layer_type"] = self.layer_type
+            if cfg.qk_norm:
+                mixer["q_norm"] = _norm(cfg, "q_norm")
+                mixer["k_norm"] = _norm(cfg, "k_norm")
+            if cfg.attention_gate:
+                mixer["gate"] = dense(q_dim, "gate")
+            if not cfg.rotates(self.layer_type):
+                rope_tabs = None
+        if cfg.post_norms:
+            mixer["post_attn_norm"] = _norm(cfg, "post_attn_norm")
+            mixer["post_mlp_norm"] = _norm(cfg, "post_mlp_norm")
         return block_math(
             cfg, x, positions, rope_tabs,
             ln1=_norm(cfg, "ln1"), ln2=_norm(cfg, "ln2"), mlp=mlp, **mixer,
@@ -982,6 +1112,35 @@ GPT_CONFIGS = {
         # block's input and, as every policy does, what its kernels made
         # (o 80 MiB and lse 0.6 MiB a block: a second flash forward
         # costs 8.5 ms at 8192 tokens)
+        remat_policy="nothing_saveable",
+    ),
+    # https://huggingface.co/arcee-ai/Trinity-Mini config.json
+    # (model_type afmoe): 32 query heads over 4 key/value heads of 128
+    # (q 4096 wide on a stream of 2048), three sliding_attention layers
+    # (window 2048, rotary) to one full_attention layer (no positions),
+    # a norm over each head of q and k, a sigmoid output gate, four
+    # norms a block, the embedding times sqrt(2048); two dense layers
+    # of 6144, then 128 routed experts of 1024 (sigmoid scores, 8 a
+    # token, weights normalised and scaled 2.826, nothing dropped)
+    # beside one shared expert; an untied head.
+    # Training path only (require_gpt2_block says who refuses it).
+    "trinity-mini": TransformerConfig(
+        vocab_size=200192, num_layers=32, emb_dim=2048, max_len=131072,
+        layer_types=tuple(
+            "full_attention" if i % 4 == 3 else "sliding_attention"
+            for i in range(32)),
+        num_heads=32, num_kv_heads=4, head_size=128,
+        attention_window=2048, pos_embedding="rope", rope_theta=10000.0,
+        rope_layer_types=("sliding_attention",), qk_norm=True,
+        attention_gate=True, post_norms=True,
+        embedding_multiplier=2048 ** 0.5,
+        mlp_ratio=3, mlp="silu_gated", norm="rmsnorm", norm_eps=1e-5,
+        use_bias=False, tie_embeddings=False,
+        routed_experts=128, routed_top_k=8, routed_width=1024,
+        routed_scaling=2.826, shared_experts=1, dense_layers_first=2,
+        # 8192 x 5120 queries, keys and values and a 8192 x 4096 gate a
+        # block: keep each block's input and, as every policy does, what
+        # its kernels made (o 64 MiB and lse 1 MiB a block)
         remat_policy="nothing_saveable",
     ),
 }

@@ -147,6 +147,28 @@ def flash_attention(
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
+def tile_counts(rows: int, seq: int, block_q: int, block_k: int, *,
+                causal: bool, window: Optional[int] = None):
+    """``(live, grid)``: of the ``(q, k)`` tiles one call's grid walks
+    (``rows`` batch x head rows of ``seq`` keys, tiles as ``_pick_block``
+    makes them), how many the kernels' ``needed`` predicate admits.  The
+    grid is whole whatever the mask: a dead tile costs its grid step and
+    its DMA and no arithmetic.  Plain Python on shapes, for a counter
+    set while a step is traced."""
+    bq, bk = _pick_block(seq, block_q), _pick_block(seq, block_k)
+    nq, nk = seq // bq, seq // bk
+    if window is not None and window >= seq:
+        window = None
+    live = 0
+    for i in range(nq):
+        for j in range(nk):
+            needed = j * bk <= (i + 1) * bq - 1 if causal else True
+            if window is not None:
+                needed = needed and (j + 1) * bk - 1 >= i * bq - (window - 1)
+            live += needed
+    return rows * live, rows * nq * nk
+
+
 def _interpret_for_backend(backend: str) -> bool:
     if backend == "tpu":
         return False

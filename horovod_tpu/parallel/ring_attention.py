@@ -96,6 +96,7 @@ def local_attention(
     scale: Optional[float] = None,
     q_offset=0,
     kv_offset=0,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Plain softmax attention on local (unpartitioned) q/k/v.
 
@@ -103,14 +104,22 @@ def local_attention(
     are the global positions of the first local row — the causal mask is
     computed in *global* coordinates so sharded callers get the right
     triangle.  The single-device reference that the distributed schedules
-    must reproduce bit-for-bit (up to fp associativity).
+    must reproduce bit-for-bit (up to fp associativity).  ``window=W``
+    (with ``causal``) lets a query see its last ``W`` keys, itself
+    included: the flash kernel's window, as an explicit mask.
     """
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window} needs causal=True and window >= 1")
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
         q_pos = q_offset + jnp.arange(q.shape[1])
         kv_pos = kv_offset + jnp.arange(k.shape[1])
         s = jnp.where(kv_pos[None, :] > q_pos[:, None], -jnp.inf, s)
+        if window is not None:
+            s = jnp.where(kv_pos[None, :] < q_pos[:, None] - (window - 1),
+                          -jnp.inf, s)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 

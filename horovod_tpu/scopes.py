@@ -24,6 +24,11 @@ SSD_SCAN = "ssd_scan"                  # the state-space scan alone, inside ssm
 MLA_PROJ = "mla_proj"                  # latent attention, inside attn: the
                                        # two low-rank paths, their norms,
                                        # RoPE, the shared rotary key
+ATTN_WINDOW = "attn_window"            # inside attn: the attention call of
+                                       # a layer that has a window, so its
+                                       # flash kernels carry the name
+ATTN_GATE = "attn_gate"                # inside attn: the output gate's
+                                       # matmul, sigmoid and product
 MLP = "mlp"                            # a block's MLP half
 MOE_ROUTE = "moe_route"                # inside mlp: router matmul, sigmoid,
                                        # top-k, the sort by expert
@@ -51,6 +56,7 @@ SSD_OUT = "ssd_out"                    # the state-space scan's y
 SSD_STATES = "ssd_states"              # and its chunk-start states
 KERNEL_OUTPUTS = (FLASH_OUT, FLASH_LSE, SSD_OUT, SSD_STATES)
 
-SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ, SSM,
-          SSD_SCAN, MLP, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED,
-          MTP, EMBED, HEAD, STEM, KV_GATHER, KV_SCATTER, SAMPLE)
+SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ,
+          ATTN_WINDOW, ATTN_GATE, SSM, SSD_SCAN, MLP, MOE_ROUTE,
+          MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MTP, EMBED, HEAD, STEM,
+          KV_GATHER, KV_SCATTER, SAMPLE)

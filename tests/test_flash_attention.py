@@ -436,8 +436,9 @@ class TestSlidingWindow:
             flash_attention(q, k, v, causal=True, window=0)
 
     def test_model_plumbing(self):
-        """attention_window reaches the kernel through the GPT config,
-        and non-flash impls reject it."""
+        """attention_window reaches the kernel through the GPT config;
+        the reference schedule takes it as an explicit mask, the
+        sequence-parallel schedules reject it."""
         from horovod_tpu.models.transformer import gpt
 
         toks = jnp.asarray(
@@ -462,8 +463,16 @@ class TestSlidingWindow:
         ref = gpt("nano", num_layers=2, num_heads=4, emb_dim=64,
                   vocab_size=512, max_len=32, dtype=jnp.float32,
                   attention_impl="reference", attention_window=8)
-        with pytest.raises(ValueError, match="flash-only"):
-            ref.apply(params, toks)
+        np.testing.assert_allclose(
+            np.asarray(ref.apply(params, toks)), np.asarray(out_w),
+            atol=2e-4, rtol=2e-4,
+        )
+        for impl in ("ring", "zigzag", "ulysses"):
+            sp = gpt("nano", num_layers=2, num_heads=4, emb_dim=64,
+                     vocab_size=512, max_len=32, dtype=jnp.float32,
+                     attention_impl=impl, sp_axis="sp", attention_window=8)
+            with pytest.raises(ValueError, match="flash-only"):
+                sp.apply(params, toks, positions=jnp.arange(32))
 
 
 # (id, causal, window, q heads, kv heads, block_q, block_k) at S=256, d=64:
@@ -736,3 +745,70 @@ def test_head_size_256_matches_the_plain_attention(monkeypatch, backward):
                     argnums=(0, 1, 2))(q, k, v)
     for name, a, b in zip(("dq", "dk", "dv"), got, want):
         np.testing.assert_allclose(a, b, atol=2e-5, err_msg=name)
+
+
+# The shapes ``trinitym_train_s8192`` brings, scaled down: 8 query heads a
+# key/value head, head size 128, a window that is a multiple of neither
+# tile (64 x 32) and, last, S = W + 1: only the first key of the last row
+# falls out of the band.
+_WINDOW_CASES = [
+    ("window_72_of_256", 256, 72),
+    ("s_is_window_plus_1", 128, 127),
+]
+
+
+@pytest.mark.parametrize("backward", ["one_kernel", "two_passes"])
+@pytest.mark.parametrize("seq,window", [c[1:] for c in _WINDOW_CASES],
+                         ids=[c[0] for c in _WINDOW_CASES])
+def test_window_at_grouped_heads_of_128(monkeypatch, backward, seq, window):
+    """The banded kernels at 8 query heads a key/value head and head size
+    128: the forward and the three gradients against the dense masked
+    oracle, through the one-kernel backward and through the two passes,
+    and ``local_attention(window=...)`` against the same oracle."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    if backward == "two_passes":
+        monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", 0)
+    rng = np.random.RandomState(7)
+    mk = lambda heads: jnp.asarray(
+        rng.randn(1, seq, heads, 128) * 0.5, jnp.float32)
+    q, k, v = mk(8), mk(1), mk(1)
+    weight = jnp.asarray(rng.randn(*q.shape), jnp.float32)
+    scale = 128 ** -0.5
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=64,
+                               block_k=32, window=window)
+
+    def oracle(q, k, v):
+        return TestSlidingWindow._oracle(q, k, v, scale, window)
+
+    def plain(q, k, v):
+        rep = lambda x: jnp.repeat(x, 8, axis=2)
+        return local_attention(q, rep(k), rep(v), causal=True,
+                               window=window)
+
+    kernels = list(_pallas_calls(jax.make_jaxpr(jax.grad(
+        lambda *a: flash(*a).sum(), argnums=(0, 1, 2)))(q, k, v).jaxpr))
+    assert kernels == ["flash_fwd"] + (
+        _ONE_KERNEL if backward == "one_kernel" else _TWO_PASSES)
+    np.testing.assert_allclose(flash(q, k, v), oracle(q, k, v), atol=2e-5)
+    np.testing.assert_allclose(plain(q, k, v), oracle(q, k, v), atol=2e-5)
+    # the band bites: the last row does not see key 0
+    full = flash_attention(q, k, v, causal=True, block_q=64, block_k=32)
+    assert float(jnp.abs(full - flash(q, k, v))[:, -1].max()) > 1e-4
+    grads = lambda f: jax.grad(lambda *a: (f(*a) * weight).sum(),
+                               argnums=(0, 1, 2))(q, k, v)
+    want = grads(oracle)
+    for name, a, b in zip(("dq", "dk", "dv"), grads(flash), want):
+        np.testing.assert_allclose(a, b, atol=5e-5, err_msg=name)
+    for name, a, b in zip(("dq", "dk", "dv"), grads(plain), want):
+        np.testing.assert_allclose(a, b, atol=5e-5, err_msg=name)
+
+
+def test_local_attention_refuses_a_window_it_cannot_mean():
+    q, k, v = _qkv()
+    with pytest.raises(ValueError, match="causal"):
+        local_attention(q, k, v, causal=False, window=8)
+    with pytest.raises(ValueError, match="window >= 1"):
+        local_attention(q, k, v, causal=True, window=0)

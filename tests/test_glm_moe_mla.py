@@ -365,10 +365,16 @@ def _refusals():
 
 
 # Decode, serve, tensor and pipeline parallelism build GPT-2's block from
-# raw weights: they refuse latent attention (by ``layer_types``), routed
-# experts and a prediction module by name, before anything is traced.
-@pytest.mark.parametrize("setting", ["layer_types", "routed_experts",
-                                     "mtp_modules"])
+# raw weights: they refuse latent attention and window / full attention
+# layers (by ``layer_types``), routed experts, a prediction module, and
+# each setting of the attention layer that Trinity-Mini (``afmoe``)
+# brought (a head size of its own, rotary in some layer types only, head
+# norms, the output gate, four norms a block) by name, before anything is
+# traced.
+@pytest.mark.parametrize("setting", [
+    "layer_types", "routed_experts", "mtp_modules", "sliding_attention",
+    "head_size", "rope_layer_types", "qk_norm", "attention_gate",
+    "post_norms"])
 @pytest.mark.parametrize("path", sorted(_refusals()))
 def test_paths_refuse_what_they_cannot_run(path, setting):
     gpt2 = gpt("nano").cfg
@@ -376,7 +382,17 @@ def test_paths_refuse_what_they_cannot_run(path, setting):
            "routed_experts": replace(gpt2, mlp="silu_gated",
                                      routed_experts=8, routed_top_k=2,
                                      routed_width=32),
-           "mtp_modules": replace(gpt2, mtp_modules=1)}[setting]
+           "mtp_modules": replace(gpt2, mtp_modules=1),
+           "sliding_attention": replace(
+               gpt2, attention_window=8, layer_types=(
+                   "sliding_attention", "sliding_attention",
+                   "full_attention")),
+           "head_size": replace(gpt2, head_size=64),
+           "rope_layer_types": replace(gpt2, pos_embedding="rope",
+                                       rope_layer_types=("attention",)),
+           "qk_norm": replace(gpt2, qk_norm=True),
+           "attention_gate": replace(gpt2, attention_gate=True),
+           "post_norms": replace(gpt2, post_norms=True)}[setting]
     with pytest.raises(ValueError, match=setting):
         _refusals()[path](cfg, jnp.zeros((1, 8), jnp.int32))
 
@@ -389,6 +405,14 @@ def test_paths_refuse_what_they_cannot_run(path, setting):
     ({"mtp_modules": 2}, "one prediction module"),
     ({"pos_embedding": "learned"}, "pos_embedding must be 'rope'"),
     ({"v_head_dim": 0}, "needs positive"),
+    # what Trinity-Mini's settings cannot mean
+    ({"layer_types": ("mla", "mla", "sliding_attention")},
+     "attention_window must be set"),
+    ({"layer_types": ("mla", "mla", "windowed")}, "layer_types must name"),
+    ({"head_size": 0}, "head_size=0 must be positive"),
+    ({"rope_layer_types": ("sliding_attention",)},
+     "rope_layer_types must name 'mla'"),
+    ({"rope_layer_types": ("mla", "mamba")}, "rope_layer_types names"),
 ])
 def test_configuration_refuses_what_it_cannot_mean(override, message):
     with pytest.raises(ValueError, match=message):
