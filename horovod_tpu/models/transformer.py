@@ -158,9 +158,10 @@ class TransformerConfig:
     # This chip holds routed_held of them (None = all) from
     # routed_first_held on; the router scores all.  The selection bias
     # (collection "moe_state") and the last step's rows per held expert
-    # and load of every routed expert (collection "moe_stats") are state,
-    # not parameters; the training step moves the bias against the load
-    # (parallel/moe.py:rebalanced).
+    # and load of every routed expert, with the count of the steps in
+    # which the layer passed its row bound (collection "moe_stats"), are
+    # state, not parameters; the training step moves the bias against the
+    # load (parallel/moe.py:rebalanced).
     routed_experts: int = 0
     routed_held: Optional[int] = None
     routed_first_held: int = 0
@@ -779,6 +780,14 @@ class Block(nn.Module):
                     = routing.dropped
                 self.variable("moe_stats", "load", lambda: None).value = \
                     routing.load
+                # counted up from the state's making: the steps in which
+                # this layer ran on the whole slot buffer
+                overflows = self.variable(
+                    "moe_stats", "overflow_steps",
+                    lambda: jnp.zeros((), jnp.int32))
+                if not self.is_initializing():
+                    overflows.value = overflows.value + jnp.asarray(
+                        routing.overflowed, jnp.int32)
             y = y.reshape(b, s, d)
             if cfg.shared_experts > 0:
                 with jax.named_scope(scopes.MOE_SHARED):

@@ -28,12 +28,17 @@ expert, a grouped matmul over the experts THIS chip holds, the weighted
 sum back.  No capacity, so nothing is dropped; the layer is told which
 experts it holds (``first_held``, and as many as its weights have),
 routes over all of them and computes its own experts' part of the
-result.  On one chip it runs without its exchange.  The GShard path
-above is as it was; ROADMAP C6 has the folding of the two.
+result.  The sort puts the held experts' rows first, so the layer works
+on a static number of the sort's first rows (:func:`row_bound`, from the
+shapes) and not on every slot, with the whole-buffer computation behind a
+``lax.cond`` for the step whose held rows pass that.  On one chip it runs
+without its exchange.  The GShard path above is as it was; ROADMAP C6 has
+the folding of the two.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple, Optional
 
 import jax
@@ -43,7 +48,7 @@ from jax import lax
 from .. import scopes
 
 __all__ = ["init_moe_params", "moe_mlp", "moe_mlp_ep", "MoEParams",
-           "Routing", "route", "grouped_ffn", "routed_experts",
+           "Routing", "route", "grouped_ffn", "routed_experts", "row_bound",
            "rebalanced", "publish_stats"]
 
 # Initialization scheme, shared by the raw-NamedTuple and flax paths so
@@ -320,6 +325,10 @@ class Routing(NamedTuple):
                             # capacity, so 0: the counter is the proof)
     load: jax.Array         # [E] int32: the slots that chose each of ALL
                             # experts, what the balancing update reads
+    overflowed: jax.Array = False  # bool scalar: the held experts' rows
+                            # passed the layer's row bound, so it ran on
+                            # the whole slot buffer (``routed_experts``
+                            # sets it; ``route`` knows no bound)
 
 
 def route(x2, router, bias, *, top_k: int, scaling: float,
@@ -372,72 +381,102 @@ def rebalanced(moe_state, moe_stats, rate: float, axis_name=None):
     return unflatten_dict(out)
 
 
-@jax.custom_vjp
-def _to_experts(x2, order, inverse):
-    """Every token's row once for each of its ``k`` choices, in expert
-    order: ``x2[order // k]``, ``[n, d] -> [n k, d]``.  The backward pass
-    gathers the rows' gradients back into slot order (``g[inverse]``) and
-    sums a token's ``k``: no scatter-add, and no ``[n k, d]`` copy of the
-    tokens before the sort."""
-    return _slot_rows(x2, order)
+def _rows(values, index):
+    """``values[index]`` for an index that cannot leave ``values`` (a
+    permutation's part, or it over ``k``): no select after the gather."""
+    return jnp.take(values, index, axis=0, mode="clip")
 
 
-def _slot_rows(x2, order):
-    return jnp.take(x2, order // (order.shape[0] // x2.shape[0]), axis=0)
+def _slots(rows, inverse, k: int):
+    """``rows`` (values of the sort's first rows) by choice and token,
+    ``[k, n, ...]``: choice ``j`` of token ``t`` takes
+    ``rows[inverse[t k + j]]``, and a row of zeros where its row lies past
+    ``rows`` (its expert is held elsewhere).  Choice first, so that a
+    choice's rows are a ``[n, d]`` array in whole tiles and a sum over the
+    choices adds ``k`` of them: ``[n, k, d]`` puts ``k`` inside a tile,
+    and the chip copies the array to sum it."""
+    past = rows.shape[0]
+    if past < inverse.shape[0]:
+        rows = jnp.concatenate([rows, jnp.zeros_like(rows[:1])])
+    return _rows(rows, jnp.minimum(inverse, past).reshape(-1, k).T)
 
-
-def _to_experts_fwd(x2, order, inverse):
-    return _slot_rows(x2, order), (inverse, x2.shape[0])
-
-
-def _to_experts_bwd(res, g):
-    inverse, n = res
-    back = jnp.take(g, inverse, axis=0).reshape(n, -1, g.shape[-1])
-    return back.sum(axis=1, dtype=jnp.float32).astype(g.dtype), None, None
-
-
-_to_experts.defvjp(_to_experts_fwd, _to_experts_bwd)
-
-
-@jax.custom_vjp
-def _from_experts(ys, order, inverse):
-    """The experts' rows back in slot order, ``ys[inverse]``; the
-    backward pass is the gather ``g[order]``."""
-    return jnp.take(ys, inverse, axis=0)
-
-
-def _from_experts_fwd(ys, order, inverse):
-    return jnp.take(ys, inverse, axis=0), order
-
-
-def _from_experts_bwd(order, g):
-    return jnp.take(g, order, axis=0), None, None
-
-
-_from_experts.defvjp(_from_experts_fwd, _from_experts_bwd)
 
 # (rows, contraction, columns) of a grouped-matmul tile on the chip
 GMM_TILING = (512, 1024, 1024)
+# The expert layer works on this many even shares of the slots
+# (``row_bound``).  2: once the balancing has settled, a layer's held
+# experts get 0.98 to 1.03 shares; on the way there a layer peaked at 1.3
+# to 1.9 shares over eighteen seeds where 16 of 128 experts are held
+# (never past two), and at 1.1 to 4.0 over twenty where 8 of 64 are: past
+# two in one to eight layer-steps of the first twenty steps on fourteen of
+# those seeds, never later (PERF.md section 6).  A row of the bound costs
+# every step (gather, cast, gate, the kernel's select: 5.6 and 10.7 ms a
+# step for two shares more); a step over the bound costs its layer the
+# whole buffer once (``routed_experts``: +8.5 ms there), exact and
+# counted.  Two shares, a quarter of the slots where an eighth is held,
+# lose those few early layer-steps and win every other step; four would
+# keep most of them and pay twice the dead rows ever after.
+ROW_BOUND_SHARES = 2
 
 
-def _gmm(lhs, rhs, group_sizes, interpret: bool):
+def row_bound(n: int, top_k: int, held: int, experts: int) -> int:
+    """The rows of the sort that an expert layer computes on, from its
+    shapes alone: ``ROW_BOUND_SHARES`` even shares of the ``n * top_k``
+    slots for ``held`` of ``experts``, a whole number of the grouped
+    matmul's row tiles, and never more than the slots; all of them where
+    every expert is held."""
+    slots, tile = n * top_k, GMM_TILING[0]
+    if held >= experts:
+        return slots
+    share = -(-slots * held * ROW_BOUND_SHARES // experts)
+    return min(slots, -(-share // tile) * tile)
+
+
+def _gmm(lhs, rhs, group_sizes, interpret: bool, transpose_rhs=False):
     """``lhs [rows, k]`` times ``rhs [held, k, n]``, the rows of group
-    ``g`` (consecutive, ``group_sizes[g]`` of them) with ``rhs[g]``; the
-    rows after the last held group come out zero.  On the chip jax's
-    Pallas grouped matmul (``pallas.ops.tpu.megablox``): its grid is the
-    row tiles that hold a held expert's rows, so its time follows the
-    rows routed here and not ``rows x held``.  Off the chip
-    (``interpret``) ``lax.ragged_dot`` computes the same, the last
-    group against a zero matrix."""
+    ``g`` (consecutive, ``group_sizes[g]`` of them) with ``rhs[g]`` (with
+    its transpose under ``transpose_rhs``: ``lhs [rows, n]`` then); the
+    rows of the last group, which has no matrix, come out zero.  On the
+    chip jax's Pallas grouped matmul (``pallas.ops.tpu.megablox``): its
+    grid is the row tiles that hold a held expert's rows, so its time
+    follows the rows routed here and not ``rows x held``, and it zeroes
+    the rows it did not visit by a select over ``rows``.  Where there is
+    no chip (``interpret``: the tests' CPU) ``lax.ragged_dot`` stands in
+    for it, the last group against a zero matrix."""
     if interpret:
+        rhs = rhs.swapaxes(1, 2) if transpose_rhs else rhs
         rhs = jnp.concatenate([rhs, jnp.zeros_like(rhs[:1])])
         return lax.ragged_dot(lhs, rhs, group_sizes,
                               preferred_element_type=lhs.dtype)
-    from jax.experimental.pallas.ops.tpu.megablox import gmm  # noqa: PLC0415
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm  # noqa: PLC0415
 
-    tiling = tuple(min(t, d) for t, d in zip(
-        GMM_TILING, (lhs.shape[0], lhs.shape[1], rhs.shape[2])))
-    return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling)
+    return gmm(lhs, rhs, group_sizes, lhs.dtype, _tiling(lhs, rhs),
+               transpose_rhs=transpose_rhs)
+
+
+def _tiling(lhs, rhs):
+    """``GMM_TILING`` for ``lhs [rows, .]`` and matrices ``rhs [held, k,
+    n]``, cut to them; one tiling for a matmul and both its gradients."""
+    return tuple(min(t, d) for t, d in zip(GMM_TILING,
+                                           (lhs.shape[0], *rhs.shape[1:])))
+
+
+def _gmm_bwd(lhs, rhs, group_sizes, interpret: bool, grad):
+    """``_gmm``'s gradients by ``lhs`` and ``rhs``: the same kernel with
+    each matrix transposed for the rows, and ``tgmm`` (group ``g``'s
+    ``lhs_g^T grad_g``, float32 sums over the group's own row tiles) for
+    the matrices, with the forward call's tiling: the pair that
+    ``megablox.ops.gmm``'s own rule runs (taking the gradients through
+    that rule traces each forward kernel once more, for nothing: a
+    second of a cell's set-up); the stand-in's are ``jax``'s."""
+    if interpret:
+        return jax.vjp(lambda lhs, rhs: _gmm(lhs, rhs, group_sizes, True),
+                       lhs, rhs)[1](grad)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm  # noqa: PLC0415
+
+    return (_gmm(grad, rhs, group_sizes, False, transpose_rhs=True),
+            tgmm(lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype,
+                 _tiling(lhs, rhs), num_actual_groups=rhs.shape[0]))
 
 
 def grouped_ffn(xs, gate_up, down, group_sizes, *, dtype=jnp.bfloat16,
@@ -445,13 +484,162 @@ def grouped_ffn(xs, gate_up, down, group_sizes, *, dtype=jnp.bfloat16,
     """The gated feed-forward ``W_down(silu(x W_gate) * (x W_up))`` of
     every held expert on its own rows: ``xs [rows, d]`` in expert order,
     ``gate_up [held, d, 2 ff]`` (gate then up), ``down [held, ff, d]``,
-    ``group_sizes [held + 1]``.  Rows past the held groups give zero."""
-    ff = down.shape[1]
+    ``group_sizes [held + 1]`` that add up to ``rows``: the held experts'
+    rows, then what is left of ``rows``, which no expert here computes
+    and which comes out zero (``_gmm``).  ``rows`` is what the caller
+    gathered: ``routed_experts`` runs this feed-forward (``_ffn``, with
+    the backward rule below) on the sort's first ``row_bound`` rows, not
+    on the slot buffer."""
+    return _grouped_ffn(interpret, xs.astype(dtype), gate_up.astype(dtype),
+                        down.astype(dtype), group_sizes)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _grouped_ffn(interpret, xs, gate_up, down, sizes):
+    return _ffn(xs, gate_up, down, sizes, interpret)[0]
+
+
+def _grouped_ffn_fwd(interpret, xs, gate_up, down, sizes):
+    ys, h = _ffn(xs, gate_up, down, sizes, interpret)
+    return ys, (xs, h, gate_up, down, sizes)
+
+
+def _grouped_ffn_bwd(interpret, kept, d_ys):
+    return (*_ffn_bwd(*kept, interpret, d_ys), None)
+
+
+_grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)
+
+
+def _gate(h):
+    """``silu(gate) * up`` of ``h = [gate | up]``."""
+    ff = h.shape[1] // 2
+    return (jax.nn.silu(h[:, :ff]) * h[:, ff:]).astype(h.dtype)
+
+
+def _ffn(xs, gate_up, down, sizes, interpret: bool):
+    """``grouped_ffn`` on operands of one dtype: the rows' outputs and
+    ``h = xs W_[gate | up]``, what its backward pass reads again."""
     with jax.named_scope(scopes.MOE_EXPERTS):
-        h = _gmm(xs.astype(dtype), gate_up.astype(dtype), group_sizes,
-                 interpret)
-        act = (jax.nn.silu(h[:, :ff]) * h[:, ff:]).astype(dtype)
-        return _gmm(act, down.astype(dtype), group_sizes, interpret)
+        h = _gmm(xs, gate_up, sizes, interpret)
+        return _gmm(_gate(h), down, sizes, interpret), h
+
+
+def _ffn_bwd(xs, h, gate_up, down, sizes, interpret: bool, d_ys):
+    """``_ffn``'s gradients by ``xs`` and both matrices, from ``xs`` and
+    ``h``: no grouped matmul of the forward pass runs again."""
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        act, gate_bwd = jax.vjp(_gate, h)
+        d_act, d_down = _gmm_bwd(act, down, sizes, interpret, d_ys)
+        d_xs, d_gate_up = _gmm_bwd(xs, gate_up, sizes, interpret,
+                                   *gate_bwd(d_act))
+        return d_xs, d_gate_up, d_down
+
+
+def _head(rows: int, order, held_sizes):
+    """The sort's first ``rows`` rows (slots), and the groups they fall
+    into: the held experts' rows, then what is left of ``rows``."""
+    return order[:rows], jnp.append(held_sizes, rows - held_sizes.sum())
+
+
+# ``_forward`` and ``_backward`` run under ``jax.jit`` so that the step's
+# module holds each of them once a shape and not once a layer, rule and
+# side: without it a cell's warm ``compile_s`` stands 6 s over the
+# parent's (the bound on ``setup_s`` is a tenth: 4 s), with it 1.7 to 2.7.
+# It is not free: around the inlined callee the compiler writes the
+# ``[k, n, d]`` rows out in float32 before it sums them, 10 ms of a 320 ms
+# step where 16 of 128 experts are held, 0.7 of 447 where 8 of 64 are
+# (PERF.md section 6).
+@partial(jax.jit, static_argnums=(0, 1))
+def _forward(rows: int, interpret: bool, x2, weights, order, inverse,
+             held_sizes, gate_up, down):
+    """The held experts' weighted outputs in token order ``[n, d]``
+    (float32), computed on the first ``rows`` rows of the sort (static;
+    the held experts' rows are among them when ``held_sizes.sum() <=
+    rows``), and what the backward pass reads again, all ``[rows, .]``:
+    the gathered rows, ``h`` and the experts' outputs.  A token's ``k``
+    slots are summed in float32 in the order of its choices, a slot whose
+    row lies past ``rows`` adding exactly zero (``_slots``)."""
+    k = weights.shape[1]
+    head, sizes = _head(rows, order, held_sizes)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        xs = _rows(x2, head // k)
+    ys, h = _ffn(xs, gate_up, down, sizes, interpret)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        y = jnp.einsum("knd,nk->nd",
+                       _slots(ys, inverse, k).astype(jnp.float32), weights)
+    return y, (xs, h, ys)
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def _backward(rows: int, interpret: bool, kept, weights, order, inverse,
+              held_sizes, gate_up, down, g):
+    """``_forward``'s gradients by the tokens, the weights and both
+    matrices, on ``[rows, .]`` buffers alone: the tokens' gradients
+    gathered to the rows (``g[head // k]``), weighted for the experts'
+    outputs, multiplied with them and summed for the weights; and the
+    rows' gradients gathered back by choice and summed over a token's
+    ``k`` in float32: no scatter-add, no ``[n k, d]`` product."""
+    xs, h, ys = kept
+    k = weights.shape[1]
+    head, sizes = _head(rows, order, held_sizes)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        g_rows = _rows(g, head // k)
+        d_ys = g_rows * _rows(weights.reshape(-1), head)[:, None]
+        d_weights = _slots((ys.astype(jnp.float32) * g_rows).sum(-1),
+                           inverse, k).T
+    d_xs, d_gate_up, d_down = _ffn_bwd(xs, h, gate_up, down, sizes,
+                                       interpret, d_ys.astype(ys.dtype))
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        d_x2 = _slots(d_xs, inverse, k).sum(axis=0, dtype=jnp.float32)
+    return d_x2.astype(d_xs.dtype), d_weights, d_gate_up, d_down
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _experts(bound: int, interpret: bool, overflowed, operands):
+    """``_forward`` on the sort's first ``bound`` rows, or on every slot
+    in a step whose held rows pass ``bound`` (``overflowed``, a device
+    value; ``None`` where the bound is every slot, and then there is no
+    branch).  The two ``lax.cond`` sit inside the rules of one
+    ``custom_vjp``, so ``jax`` differentiates neither: a branch is traced
+    once forward and once backward, and the side not taken hands nothing
+    over but zeros of the bounded side's ``[bound, .]`` shapes (it
+    computes its own forward again, in the step that takes it)."""
+    return _experts_fwd(bound, interpret, overflowed, operands)[0]
+
+
+def _experts_fwd(bound, interpret, overflowed, operands):
+    bounded = partial(_forward, bound, interpret)
+    if overflowed is None:
+        y, kept = bounded(*operands)
+    else:
+        def whole(*operands):
+            y, kept = _forward(operands[2].shape[0], interpret,
+                                     *operands)
+            return y, jax.tree.map(lambda a: jnp.zeros_like(a[:bound]), kept)
+
+        y, kept = lax.cond(overflowed, whole, bounded, *operands)
+    return y, (overflowed, operands, kept)
+
+
+def _experts_bwd(bound, interpret, res, g):
+    overflowed, operands, kept = res
+    bounded = partial(_backward, bound, interpret)
+    if overflowed is None:
+        grads = bounded(kept, *operands[1:], g)
+    else:
+        def whole(_, *rest):
+            slots = operands[2].shape[0]
+            _, kept = _forward(slots, interpret, operands[0],
+                                     *rest[:-1])
+            return _backward(slots, interpret, kept, *rest)
+
+        grads = lax.cond(overflowed, whole, bounded, kept, *operands[1:], g)
+    d_x2, d_weights, d_gate_up, d_down = grads
+    return None, (d_x2, d_weights, None, None, None, d_gate_up, d_down)
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
 
 
 def routed_experts(x2, router, bias, gate_up, down, *, top_k: int,
@@ -461,6 +649,17 @@ def routed_experts(x2, router, bias, gate_up, down, *, top_k: int,
     ``x2 [n, d]``: ``sum over the chosen AND held experts e of
     w_e FFN_e(x)``.  What the experts held elsewhere would have added is
     left out (their share of the weights is not renormalised away).
+
+    The sort puts the held experts' rows first, so everything between it
+    and the result runs on the first ``row_bound(...)`` rows, a static
+    number from the shapes; a slot whose row lies past them takes zero on
+    the way back.  A step in which the held experts get more rows than
+    that runs the same computation, with the same kernels, on all
+    ``n * top_k`` rows instead (``lax.cond`` on the device: nothing is
+    fetched, nothing is dropped or capped) and says so in
+    ``routing.overflowed``.  Where every expert is held the bound is
+    ``n * top_k`` and there is no branch.
+
     ``interpret=None`` takes the kernel on backend ``tpu`` and its
     stand-in on ``cpu``: one rule for every kernel of the package,
     ``flash_attention._interpret_for_backend``, looked up through that
@@ -471,7 +670,7 @@ def routed_experts(x2, router, bias, gate_up, down, *, top_k: int,
 
         interpret = flash_attention._interpret_for_backend(
             jax.default_backend())
-    n, d = x2.shape
+    n = x2.shape[0]
     held = gate_up.shape[0]
     with jax.named_scope(scopes.MOE_ROUTE):
         routing = route(x2, router, bias, top_k=top_k, scaling=scaling,
@@ -479,25 +678,31 @@ def routed_experts(x2, router, bias, gate_up, down, *, top_k: int,
         order = routing.order
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(n * top_k, dtype=jnp.int32))
-    with jax.named_scope(scopes.MOE_DISPATCH):
-        xs = _to_experts(x2.astype(dtype), order, inverse)
-    ys = grouped_ffn(xs, gate_up, down, routing.group_sizes, dtype=dtype,
-                     interpret=interpret)
-    with jax.named_scope(scopes.MOE_DISPATCH):
-        back = _from_experts(ys, order, inverse).reshape(n, top_k, d)
-        y = jnp.einsum("nkd,nk->nd", back.astype(jnp.float32),
-                       routing.weights)
+    bound = row_bound(n, top_k, held, router.shape[1])
+    held_sizes = routing.group_sizes[:held]
+    overflowed = held_sizes.sum() > bound if bound < n * top_k else None
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        # cast once, outside the branch: both sides read the same copy
+        matrices = gate_up.astype(dtype), down.astype(dtype)
+    y = _experts(bound, interpret, overflowed, (
+        x2.astype(dtype), routing.weights, order, inverse, held_sizes,
+        *matrices))
+    if overflowed is not None:
+        routing = routing._replace(overflowed=overflowed)
     return y.astype(dtype), routing
 
 
 def publish_stats(stats, registry=None) -> dict:
     """An expert layer's counters, from the device state the model keeps
     them in (collection ``moe_stats``: per layer ``rows`` [held] and
-    ``dropped``, of the last step), as gauges of the metrics registry
+    ``dropped``, of the last step, and ``overflow_steps``, counted up
+    since the state was made), as gauges of the metrics registry
     (``obs/registry.py``) and as the dict returned: per layer the rows
     routed to held experts, the largest held expert's rows over the
-    mean, and the rows dropped (``load`` is the balancing update's).
-    Read after a step, on the host: never from a callback inside it."""
+    mean, the rows dropped, and the steps in which the layer passed its
+    row bound and ran on the whole slot buffer (``load`` is the
+    balancing update's).  Read after a step, on the host: never from a
+    callback inside it."""
     import numpy as np  # noqa: PLC0415
     from flax.traverse_util import flatten_dict  # noqa: PLC0415
 
@@ -514,7 +719,8 @@ def publish_stats(stats, registry=None) -> dict:
         mean = float(rows.mean())
         entry = {"rows_held": int(rows.sum()),
                  "max_over_mean": float(rows.max()) / mean if mean else 0.0,
-                 "rows_dropped": int(flat[layer + "/dropped"])}
+                 "rows_dropped": int(flat[layer + "/dropped"]),
+                 "overflow_steps": int(flat[layer + "/overflow_steps"])}
         for name, value in entry.items():
             registry.gauge(f"moe.{name}", layer=layer).set(value)
         out[layer] = entry

@@ -296,6 +296,210 @@ def test_the_rows_of_the_last_step_are_state_and_not_parameters():
     assert model.apply(variables, TOKENS[:, :SEQ]).shape == (2, SEQ, 256)
 
 
+# ------------------------------------------------------------ the row bound
+# The sort puts the held experts' rows first, so the layer computes on the
+# first ``row_bound`` rows of it (two even shares, in whole row tiles of
+# the grouped matmul) and on all ``n * top_k`` only in a step whose held
+# rows pass that.  512 tokens, 3 a token, experts 4 and 5 held of 16: two
+# shares are 384 rows, one tile of 512, a third of the 1536 slots.
+
+@pytest.mark.parametrize("shape,rows", [
+    ((8192, 4, 8, 64), 8192),      # glm47f_train_s8192: a quarter of 32768
+    ((8192, 8, 16, 128), 16384),   # trinitym_train_s8192: of 65536
+    ((8192, 4, 64, 64), 32768),    # every expert held: all the slots
+    ((1000, 4, 2, 16), 1024),      # 1000 rows of share, in tiles of 512
+    ((512, 3, 2, 16), 512),        # the layers below
+    ((96, 3, 4, 16), 288),         # a tile holds more than the slots: all
+])
+def test_the_row_bound_follows_from_the_shapes(shape, rows):
+    assert moe.row_bound(*shape) == rows
+
+
+def _branches(fn, *args):
+    def count(jaxpr):
+        return sum((eqn.primitive.name == "cond") + sum(
+            count(sub) for sub in jax.core.jaxprs_in_params(eqn.params))
+            for eqn in jaxpr.eqns)
+
+    return count(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("held,branches", [(16, 0), (2, 1)])
+def test_a_layer_that_holds_every_expert_has_no_branch(held, branches):
+    """``held == E``: the bound is every slot and the program has no
+    ``cond`` in it; an eighth held: one."""
+    layer = _layer(n=512, held=held)
+    assert _branches(lambda *a: _routed(*a)[0], *layer) == branches
+    assert bool(_routed(*layer)[1].overflowed) is False
+
+
+def _outcome(layer, dtype, first_held=4):
+    """``y``, the routing, and the gradients of a weighted sum of ``y``
+    by the tokens, the router and both expert matrices."""
+    x, router, bias, fc1, fc2 = layer
+    probe = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def run(x, router, fc1, fc2):
+        y, routing = moe.routed_experts(
+            x, router, bias, fc1, fc2, top_k=3, scaling=1.8,
+            first_held=first_held, dtype=dtype)
+        return (y.astype(jnp.float32) * probe).sum(), (y, routing)
+
+    (_, (y, routing)), grads = jax.value_and_grad(
+        run, argnums=(0, 1, 2, 3), has_aux=True)(x.astype(dtype), router,
+                                                 fc1, fc2)
+    return y, routing, grads
+
+
+def _whole_buffer(monkeypatch):
+    """So many shares that the bound is every slot: the layer as it is
+    where every expert is held, one computation on ``n * top_k`` rows."""
+    monkeypatch.setattr(moe, "ROW_BOUND_SHARES", 10 ** 6)
+
+
+def _assert_bitwise(ours, whole):
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a, np.float32), np.asarray(b, np.float32)), ours, whole)
+
+
+def _assert_last_bit(ours, whole):
+    """Within 1e-6 of the largest element, a float32 sum's last bit, and
+    for a bfloat16 array one rounding of the element besides (the last
+    bit of the float32 sum it was rounded from decides a tie now and
+    then: one element of 8192).  Two XLA programs need not round
+    a float32 sum alike, and off the chip they do not: the CPU's compiler
+    contracts a multiply and an add of the weighted sum into one rounding
+    in one program and not in the other, and the gradient of the stand-in
+    (``lax.ragged_dot``) is a dense contraction over ALL the rows it was
+    handed, a held expert's among zeros, which the CPU's matmul blocks
+    otherwise over 512 rows than over 1536 (the chip's ``tgmm`` walks a
+    group's own row tiles, the same on both sides)."""
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        np.asarray(a, np.float32), np.asarray(b, np.float32),
+        rtol=2.0 ** -7 if b.dtype == jnp.bfloat16 else 0,
+        atol=1e-6 * float(jnp.abs(b).max())), ours, whole)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_bounded_rows_give_the_whole_buffers_result_bit_for_bit(
+        dtype, monkeypatch):
+    """The fast side against the computation on all 1536 rows (a row is
+    computed from its own group's matrix alone, and a row past the bound
+    adds an exact zero either way): ``y`` and the gradients of the
+    tokens, the router and both expert matrices every bit in bfloat16,
+    the cells' dtype, and to float32's last bit in float32
+    (``_assert_last_bit`` says why not closer)."""
+    layer = _layer(n=512, held=2)
+    y, routing, grads = _outcome(layer, dtype)
+    assert not bool(routing.overflowed) and int(routing.dropped) == 0
+    assert 0 < int(routing.group_sizes[:2].sum()) <= 512
+    _whole_buffer(monkeypatch)
+    whole_y, whole_routing, whole_grads = _outcome(layer, dtype)
+    assert _branches(lambda *a: _routed(*a)[0], *layer) == 0
+    _assert_bitwise(routing.weights, whole_routing.weights)
+    (_assert_bitwise if dtype == jnp.bfloat16 else _assert_last_bit)(
+        (y, grads), (whole_y, whole_grads))
+    assert float(jnp.abs(grads[1]).max()) > 0      # the router's is there
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_more_rows_than_the_bound_take_the_whole_buffer(dtype, monkeypatch):
+    """A bias of +10 on expert 5 sends all 512 tokens to it: with expert
+    4's rows the held experts get more than the 512 rows of the bound, the
+    layer says so, runs on all 1536 rows and drops nothing: every bit of
+    the expert matrices' gradients is the whole-buffer computation's
+    (this side contracts over 1536 rows too); ``y`` and the tokens' and
+    the router's gradients to the last bit (the branch is a program of
+    its own: ``_assert_last_bit``)."""
+    x, router, bias, fc1, fc2 = _layer(n=512, held=2)
+    layer = (x, router, bias.at[5].set(10.0), fc1, fc2)
+    y, routing, grads = _outcome(layer, dtype)
+    assert bool(routing.overflowed) and int(routing.dropped) == 0
+    assert int(routing.group_sizes[1]) == 512
+    assert int(routing.group_sizes[:2].sum()) > moe.row_bound(512, 3, 2, 16)
+    _whole_buffer(monkeypatch)
+    whole_y, whole_routing, whole_grads = _outcome(layer, dtype)
+    assert not bool(whole_routing.overflowed)      # no bound, none passed
+    _assert_bitwise(grads[2:], whole_grads[2:])
+    _assert_last_bit((y, grads[:2]), (whole_y, whole_grads[:2]))
+    assert float(jnp.abs(y).min(axis=-1).max()) > 0
+
+
+def _small_tiles(monkeypatch, shares):
+    """The small model routes 64 tokens x 3: a row tile of 512 holds all
+    192 slots and its layers have no branch.  With tiles of 16 rows and
+    ``shares`` even shares of 48, the bound is under the slots."""
+    monkeypatch.setattr(moe, "GMM_TILING", (16,) + moe.GMM_TILING[1:])
+    monkeypatch.setattr(moe, "ROW_BOUND_SHARES", shares)
+
+
+def test_the_overflow_counter_counts_steps_and_is_published(monkeypatch):
+    """``moe_stats`` carries ``overflow_steps`` a layer from step to
+    step: a selection bias that sends every token of block 1 to two held
+    experts puts it over its 96 rows in each of two steps, the other
+    layers stay under theirs, nothing is dropped, and ``publish_stats``
+    sets the gauge ``moe.overflow_steps{layer}``."""
+    from horovod_tpu.obs.registry import MetricsRegistry
+
+    _small_tiles(monkeypatch, shares=2)
+    model = small_model()
+    variables = init(model)
+    assert int(variables["moe_stats"]["block1"]["overflow_steps"]) == 0
+    skewed = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: leaf.at[5:7].set(10.0)
+        if "block1" in jax.tree_util.keystr(path) else leaf,
+        variables["moe_state"])
+    variables = {**variables, "moe_state": skewed}
+    for step in (1, 2):
+        _, new = model.apply(variables, TOKENS[:, :-2],
+                             next_tokens=TOKENS[:, 1:-1],
+                             mutable=["moe_stats"])
+        variables = {**variables, "moe_stats": new["moe_stats"]}
+        registry = MetricsRegistry()
+        stats = moe.publish_stats(new["moe_stats"], registry)
+        assert {layer: entry["overflow_steps"]
+                for layer, entry in stats.items()} == {
+            "block1": step, "block2": 0, "mtp/block": 0}
+        assert stats["block1"]["rows_held"] > 96 >= max(
+            stats["block2"]["rows_held"], stats["mtp/block"]["rows_held"])
+        assert all(entry["rows_dropped"] == 0 for entry in stats.values())
+        assert registry.gauge("moe.overflow_steps",
+                              layer="block1").value == step
+
+
+@pytest.mark.parametrize("policy", ["dots_with_no_batch_dims_saveable",
+                                    "nothing_saveable"])
+def test_a_rematerialised_block_keeps_both_sides_of_the_bound_exact(
+        policy, monkeypatch):
+    """Under ``nn.remat`` with ``block_remat_policy`` (the case of
+    tests/remat_cases.py: latent attention, routed experts, a prediction
+    module) the bounded layers give the loss and the gradients of the
+    model whose layers have no bound.  One even share of 48 rows for a
+    bound, so that the seeded routing puts some layers over it and leaves
+    some under: both sides of the branch run, forward, recomputed and
+    backward."""
+    model = small_model(remat=True, remat_policy=policy)
+    variables = init(model)
+
+    def outcome():
+        return jax.value_and_grad(
+            lambda p: program_loss(model, {**variables, "params": p},
+                                   TOKENS))(variables["params"])
+
+    unbounded = outcome()
+    _small_tiles(monkeypatch, shares=1)
+    _, new = model.apply(variables, TOKENS[:, :-2],
+                         next_tokens=TOKENS[:, 1:-1], mutable=["moe_stats"])
+    over = [entry["overflow_steps"]
+            for entry in moe.publish_stats(new["moe_stats"]).values()]
+    assert sorted(set(over)) == [0, 1]
+    loss, grads = outcome()
+    np.testing.assert_allclose(float(loss), float(unbounded[0]), rtol=1e-6)
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
+        grads, unbounded[1])
+
+
 PUBLISHED = dict(
     vocab_size=154880, num_layers=47, emb_dim=2048, num_heads=20, kv_heads=20,
     q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
@@ -333,9 +537,9 @@ def test_the_cut_counts_706518528_parameters():
     assert count(p) == 706_518_528
     # the selection bias and the counters are state: no gradient, no moment
     assert count(shapes["moe_state"]) == 5 * 64
-    # per expert layer: rows of 8 held experts, rows dropped, and the
-    # load of all 64
-    assert count(shapes["moe_stats"]) == 5 * (8 + 1 + 64)
+    # per expert layer: rows of 8 held experts, rows dropped, the load
+    # of all 64, and the steps in which the layer passed its row bound
+    assert count(shapes["moe_stats"]) == 5 * (8 + 1 + 64 + 1)
 
 
 def _refusals():

@@ -214,6 +214,136 @@ def test_grouped_expert_matmuls_compile_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+def _wide_rows(text, rows):
+    """Where a compiled program holds arrays of ``rows`` rows and more
+    than one column: ``(outside, sides)``, the shapes outside every
+    ``conditional`` and, for each conditional, the shapes inside each of
+    its branches (with what the branch calls), as ``{shape: count}``."""
+    import re
+
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            bodies[name].append(line)
+    called = {
+        name: set(re.findall(
+            r"(?:to_apply|calls|body|condition|true_computation"
+            r"|false_computation)=%?([\w.\-]+)", "\n".join(lines)))
+        | {c.strip().lstrip("%") for group in re.findall(
+            r"branch_computations=\{([^}]*)\}", "\n".join(lines))
+           for c in group.split(",")}
+        for name, lines in bodies.items()}
+
+    def reach(name, seen):
+        if name in bodies and name not in seen:
+            seen.add(name)
+            for other in called[name]:
+                reach(other, seen)
+        return seen
+
+    wide = re.compile(r"= \(?((?:bf16|f32|s32|pred)\[%d,\d+\])" % rows)
+
+    def shapes(names):
+        found = {}
+        for name in names:
+            for line in bodies[name]:
+                for shape in wide.findall(line):
+                    if not shape.endswith(",1]"):      # a gather's indices
+                        found[shape] = found.get(shape, 0) + 1
+        return found
+
+    sides, inside = [], set()
+    for lines in bodies.values():
+        for line in lines:
+            branches = re.search(r" conditional\(.*branch_computations="
+                                 r"\{([^}]*)\}", line)
+            if branches:
+                reached = [reach(b.strip().lstrip("%"), set())
+                           for b in branches.group(1).split(",")]
+                sides.append([shapes(r) for r in reached])
+                inside |= set().union(*reached)
+    return shapes(set(bodies) - inside), sides
+
+
+@pytest.mark.parametrize("experts,held,top_k,ff,bound", [
+    (64, 8, 4, 1536, 8192),      # glm47f_train_s8192: 32768 slots
+    (128, 16, 8, 1024, 16384),   # trinitym_train_s8192: 65536 slots
+])
+def test_bounded_expert_layer_compiles_for_v5e(one_chip, experts, held,
+                                               top_k, ff, bound):
+    """The dropless expert layer at both cells' shapes, rematerialised,
+    forward and backward, with the compiled kernels.  Outside the branch
+    nothing has ``n * top_k`` rows.  The side that stays under the row
+    bound holds such an array only where a gather brings rows back to
+    slot order (``_slots``: the way back to the tokens and the tokens'
+    gradient, in the layer's dtype, ``d`` wide); every gate, cast, select
+    and grouped matmul there is on ``[bound, .]`` buffers.  The other
+    side is the whole-buffer computation with the same kernels.  The
+    bounded side calls the Pallas grouped matmul as the layer without a
+    bound does: twice forward, twice in the recomputed forward, four
+    times backward (``gmm`` by the rows, ``tgmm`` by the matrices; the
+    forward calls that ``jax.vjp`` traces there are dropped), and no
+    computation holds kernels of both sides."""
+    import re
+
+    from horovod_tpu.parallel import moe
+
+    n, d = 8192, 2048
+    assert moe.row_bound(n, top_k, held, experts) == bound
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    args = (shape((n, d), jnp.bfloat16), shape((d, experts), jnp.float32),
+            shape((held, d, 2 * ff), jnp.float32),
+            shape((held, ff, d), jnp.float32), shape((experts,), jnp.float32))
+
+    def step(x2, router, fc1, fc2, bias):
+        def loss(x2, router, fc1, fc2):
+            y, _ = moe.routed_experts(x2, router, bias, fc1, fc2,
+                                      top_k=top_k, scaling=1.8,
+                                      interpret=False)
+            return y.astype(jnp.float32).sum()
+
+        return jax.value_and_grad(
+            jax.checkpoint(
+                loss, policy=jax.checkpoint_policies.nothing_saveable),
+            argnums=(0, 1, 2, 3))(x2, router, fc1, fc2)
+
+    text = jax.jit(step).lower(*args).compile().as_text()
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    outside, sides = _wide_rows(text, n * top_k)
+    assert outside == {}
+    gathered = f"bf16[{n * top_k},{d}]"
+    for under, over in sides:
+        assert set(under) <= {gathered}, under
+    assert any(f"bf16[{n * top_k},{2 * ff}]" in over for _, over in sides)
+    # the Pallas calls: which computation holds each, and its rows (a
+    # ``tgmm`` gives matrices, [held, ., .]: 0 here)
+    where, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+        elif "tpu_custom_call" in line and "pallas_call" in line:
+            rows = re.search(r"= bf16\[(\d+),\d+\]", line)
+            where.setdefault(name, []).append(
+                int(rows.group(1)) if rows else 0)
+    assert "ENTRY" not in where and all(
+        bound not in rows or n * top_k not in rows for rows in where.values())
+    calls = sorted(rows for side in where.values() for rows in side)
+    assert calls.count(bound) == 6 and calls.count(0) == 4
+    # the other side: forward (the compiler may merge its two passes:
+    # nothing lies between them here), forward again and by the rows
+    assert calls.count(n * top_k) in (6, 8) and len(calls) in (16, 18)
+
+
 @pytest.fixture
 def compiled_kernels(monkeypatch):
     """``ssd_scan`` takes its interpret mode from the default backend,

@@ -195,6 +195,57 @@ def test_the_shares_add_up_to_the_uncut_layer():
     assert float(jnp.abs(one - uncut).max()) > 1e-2
 
 
+@pytest.mark.parametrize("skew,overflowed", [(0.0, False), (10.0, True)])
+def test_eight_of_128_on_the_row_bound_is_the_whole_buffers_layer(
+        skew, overflowed, monkeypatch):
+    """Trinity-Mini's routing at a small size: 8 a token of 128 experts,
+    16 held from expert 16 on, 256 tokens in bfloat16.  Two even shares
+    of the 2048 slots are 512 rows, one row tile of the grouped matmul, a
+    quarter of the buffer as in the cell.  Under the bound, and with a
+    selection bias that sends every token to three held experts over it,
+    ``y`` and the gradients of the tokens, the router and both expert
+    matrices are those of the computation on all 2048 rows: every bit
+    (over the bound ``y`` and the tokens' and the router's gradients to
+    the last bit: ``tests/test_glm_moe_mla.py:_assert_last_bit`` has
+    why)."""
+    from test_glm_moe_mla import _assert_bitwise, _assert_last_bit
+
+    from horovod_tpu.parallel import moe
+
+    k = jax.random.split(jax.random.PRNGKey(7), 6)
+    x = jax.random.normal(k[0], (256, 32)).astype(jnp.bfloat16)
+    router = jax.random.normal(k[1], (32, 128)) * 0.3
+    bias = jax.random.uniform(k[2], (128,), minval=-0.05, maxval=0.05)
+    bias = bias.at[20:23].add(skew)
+    fc1 = jax.random.normal(k[3], (16, 32, 48)) * 0.2
+    fc2 = jax.random.normal(k[4], (16, 24, 32)) * 0.2
+    probe = jax.random.normal(k[5], x.shape)
+    assert moe.row_bound(256, 8, 16, 128) == 512
+
+    def outcome():
+        def run(x, router, fc1, fc2):
+            y, routing = moe.routed_experts(
+                x, router, bias, fc1, fc2, top_k=8, scaling=2.826,
+                first_held=16)
+            return (y.astype(jnp.float32) * probe).sum(), (y, routing)
+
+        (_, (y, routing)), grads = jax.value_and_grad(
+            run, argnums=(0, 1, 2, 3), has_aux=True)(x, router, fc1, fc2)
+        return y, routing, grads
+
+    y, routing, grads = outcome()
+    assert bool(routing.overflowed) is overflowed
+    assert (int(routing.group_sizes[:16].sum()) > 512) is overflowed
+    assert int(routing.dropped) == 0
+    monkeypatch.setattr(moe, "ROW_BOUND_SHARES", 10 ** 6)   # no bound
+    whole_y, _, whole_grads = outcome()
+
+    _assert_bitwise(grads[2:], whole_grads[2:])
+    (_assert_last_bit if overflowed else _assert_bitwise)(
+        (y, grads[:2]), (whole_y, whole_grads[:2]))
+    assert float(jnp.abs(grads[1]).max()) > 0
+
+
 PUBLISHED = dict(
     vocab_size=200192, num_layers=32, emb_dim=2048, num_heads=32,
     kv_heads=4, head_dim=128, attention_window=2048, rope_theta=10000.0,
@@ -245,7 +296,9 @@ def test_the_cut_counts_705473792_parameters():
     assert count(p) == 705_473_792
     # the selection bias and the counters are state: no gradient, no moment
     assert count(shapes["moe_state"]) == 4 * 128
-    assert count(shapes["moe_stats"]) == 4 * (16 + 1 + 128)
+    # per expert layer: rows of 16 held experts, rows dropped, the load
+    # of all 128, and the steps in which the layer passed its row bound
+    assert count(shapes["moe_stats"]) == 4 * (16 + 1 + 128 + 1)
 
 
 def test_the_defaults_are_gpt2s():
@@ -270,7 +323,9 @@ def test_the_defaults_are_gpt2s():
 # whole variable tree, taken on the commit before this file existed
 TREES = {"small": (149, "6cdd1d23a276d671"),
          "granite-4.0-h-micro": (458, "3fbd116a94e7256c"),
-         "glm-4.7-flash": (864, "e237eeb235e94b3f")}
+         # 47 expert layers' ``overflow_steps`` (collection "moe_stats")
+         # beside the 864 leaves it had; ``params`` as they were
+         "glm-4.7-flash": (911, "02dcf7089015504d")}
 
 
 @pytest.mark.parametrize("size", sorted(TREES))
