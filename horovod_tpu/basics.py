@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
@@ -232,6 +233,7 @@ def init(comm=None) -> Topology:
     membership is fixed by the coordination service.
     """
     global _topology
+    t_start = time.perf_counter()
     with _state_lock:
         if _topology is not None:
             return _topology
@@ -359,6 +361,12 @@ def init(comm=None) -> Topology:
                 from . import _engine_registry  # noqa: PLC0415
 
                 _engine_registry.get_engine()
+    # The set-up log's record of this call (obs/profile.py): device
+    # discovery, jax.distributed where it was started here, the
+    # topology, the hooks.
+    from .obs import profile as _profile  # noqa: PLC0415
+
+    _profile.log_interval("init", "hvd.init", t_start)
     return _topology
 
 

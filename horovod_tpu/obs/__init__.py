@@ -36,7 +36,7 @@ quantitative):
   Chrome-trace waterfall plus a ttft/tpot latency-decomposition
   report.
 * **MFU profiler** (obs/profile.py) — model-FLOPs accounting
-  (compiled ``cost_analysis()`` with analytic fallbacks) over measured
+  (the compiled artifact's ``cost_analysis()``) over measured
   step time, published live as ``perf.mfu`` / ``perf.model_tflops`` /
   ``perf.step_ms`` gauges.
 * **goodput ledger** (obs/goodput.py) — the wall-clock axis: every

@@ -6,10 +6,7 @@ a step:
 * **Model FLOPs per step** — preferred source: XLA's own post-fusion
   cost analysis of the compiled artifact (:func:`flops_from_compiled`,
   the PR-9 HLO-inspector spirit: a property of the artifact, not a
-  hand-derived guess).  Fallback when the executable cannot be
-  inspected: analytic formulas keyed off the bench model builders
-  (:func:`analytic_step_flops` — the 6N + 12·L·s·d transformer rule and
-  a per-model conv table), flagged ``source: analytic``.
+  hand-derived guess).
 * **Device peak FLOP/s** — a small per-platform table
   (:data:`PEAK_FLOPS`, public TPU spec sheets).  The kind ``cpu``
   gets a nominal order-of-magnitude entry marked **estimate-only**: a
@@ -25,10 +22,15 @@ a step:
 Two further measurement layers live here, both fed by names the
 program itself puts where the work happens:
 
-* **Compile log** — :func:`compile_log`: one record per
+* **Set-up log** — :func:`compile_log`: the process's set-up as
+  intervals on ``time.perf_counter()``.  One record per outermost
   ``jax.monitoring`` compile event (program, phase ``trace`` / ``lower``
-  / ``backend``, seconds, ``time.perf_counter()`` at the end, and on
-  the backend record the persistent cache's ``hit`` or ``miss``).
+  / ``backend``, seconds, ``t_start`` and ``t_end``; on a trace record
+  the jitted helpers traced inside it, summed by name; on the backend
+  record the persistent cache's ``hit`` or ``miss``, the seconds its
+  load took and the seconds it saved), one for ``hvd.init()`` (phase
+  ``init``) and, on Linux, a first one from the process's start to this
+  module's import (phase ``process``).
   ``utils/compile_cache.enable_compile_cache()`` registers the listener,
   so every entry point has it before its first compile; the registry
   carries ``compile.seconds{phase}``, ``compile.cache_hits`` and
@@ -70,6 +72,7 @@ __all__ = [
     "compile_log",
     "compile_summary",
     "install_compile_listener",
+    "log_interval",
     "DeviceTrace",
     "device_trace",
     "SliceSchedule",
@@ -79,8 +82,6 @@ __all__ = [
     "CPU_PEAK_ESTIMATE",
     "peak_flops",
     "flops_from_compiled",
-    "transformer_step_flops",
-    "analytic_step_flops",
     "MFUProfiler",
 ]
 
@@ -130,8 +131,7 @@ def peak_flops(device_kind: str, dtype: str = "bf16"
 def flops_from_compiled(compiled) -> Optional[float]:
     """Per-device FLOPs of one execution of a compiled executable, as
     XLA counts them post-fusion (``cost_analysis()``).  Returns None
-    when the backend exposes no analysis — callers fall back to
-    :func:`analytic_step_flops`."""
+    when the backend exposes no analysis."""
     try:
         ca = compiled.cost_analysis()
     except Exception:
@@ -141,78 +141,6 @@ def flops_from_compiled(compiled) -> Optional[float]:
     except (AttributeError, TypeError, ValueError):
         return None
     return v if v > 0 else None
-
-
-# -- analytic fallbacks ------------------------------------------------------
-
-def _transformer_param_count(cfg) -> int:
-    """Parameter count of models/transformer.py's GPT for a config —
-    kept in lockstep with the flax module (wte + learned wpe + per-block
-    qkv/proj/mlp/2LN + final LN + untied head)."""
-    d = cfg.emb_dim
-    kv_dim = cfg.kv_heads * cfg.head_dim
-    mlp_hidden = cfg.mlp_ratio * d
-    per_block = (
-        d * (d + 2 * kv_dim) + (d + 2 * kv_dim)   # qkv (+bias)
-        + d * d + d                                # proj
-        + d * mlp_hidden + mlp_hidden              # mlp up
-        + mlp_hidden * d + d                       # mlp down
-        + 4 * d                                    # 2 x LayerNorm
-    )
-    n = cfg.vocab_size * d + cfg.num_layers * per_block
-    n += 2 * d                                     # final LayerNorm
-    n += d * cfg.vocab_size                        # untied head
-    if cfg.pos_embedding == "learned":
-        n += cfg.max_len * d
-    return n
-
-
-def transformer_step_flops(cfg, batch_size: int, seq_len: int,
-                           training: bool = True) -> float:
-    """Analytic model FLOPs for one step over ``batch_size`` sequences
-    of ``seq_len`` tokens: the standard 6N-per-token rule (2N forward,
-    4N backward) plus the attention term 12·L·s·d per token (4·s·d
-    forward for QKᵀ and AV, tripled for training).  ``training=False``
-    gives the forward-only 2N + 4·L·s·d (the decode-step shape)."""
-    n = _transformer_param_count(cfg)
-    tokens = batch_size * seq_len
-    per_tok_mat = (6 if training else 2) * n
-    per_tok_attn = (12 if training else 4) * cfg.num_layers * seq_len \
-        * cfg.emb_dim
-    return float(tokens) * (per_tok_mat + per_tok_attn)
-
-
-# Forward FLOPs per image at 224x224 (published per-model numbers,
-# 2 x MACs); training approximated as 3 x forward.
-_CONV_FWD_FLOPS_224 = {
-    "resnet18": 3.6e9,
-    "resnet50": 8.2e9,
-    "resnet101": 15.2e9,
-    "vgg16": 31.0e9,
-    "vgg19": 39.0e9,
-    "inception3": 11.4e9,
-}
-
-
-def analytic_step_flops(model_name: str, batch_size: int,
-                        seq_len: Optional[int] = None,
-                        image_size: int = 224) -> Optional[float]:
-    """Analytic per-step training FLOPs keyed off the model names of
-    ``testing/steps.py``'s builders.  None for a model the tables
-    don't know — the caller then reports no MFU rather than a wrong
-    one."""
-    if model_name.startswith("gpt-"):
-        from ..models.transformer import GPT_CONFIGS  # noqa: PLC0415
-
-        cfg = GPT_CONFIGS.get(model_name[len("gpt-"):])
-        if cfg is None or not seq_len:
-            return None
-        return transformer_step_flops(cfg, batch_size, seq_len)
-    fwd = _CONV_FWD_FLOPS_224.get(model_name)
-    if fwd is None:
-        return None
-    scale = (image_size / 224.0) ** 2
-    return 3.0 * fwd * scale * batch_size
 
 
 class MFUProfiler:
@@ -291,109 +219,208 @@ _CACHE_VERDICTS = {
     "/jax/compilation_cache/cache_hits": "hit",
     "/jax/compilation_cache/cache_misses": "miss",
 }
+# What a hit cost and what it saved, as JAX reports them just before the
+# backend duration of the program that hit (jax/_src/compiler.py).
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s",
+}
 COMPILE_LOG_CAPACITY = 4096
 COMPILE_LANE = "compile"
+CHILDREN_KEPT = 16
+
+
+def _process_start() -> Optional[float]:
+    """When this process started, on ``time.perf_counter()``'s clock
+    (Linux: CLOCK_MONOTONIC), from field 22 of ``/proc/self/stat``
+    (clock ticks since boot).  None where that cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        since_boot = ticks / os.sysconf("SC_CLK_TCK")
+        return since_boot - (time.clock_gettime(time.CLOCK_BOOTTIME)
+                             - time.perf_counter())
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+class _Pending(threading.local):
+    """What one thread holds between JAX's events: how deep in nested
+    phases it is, the outermost one and its children so far, and what
+    the cache has said since the thread's last ``backend`` record."""
+
+    def __init__(self):
+        self.depth, self.outermost = 0, None
+        self.children, self.cache = {}, {}
 
 
 class _CompileLog:
-    """Bounded in-process log of ``jax.monitoring``'s compile events.
+    """Bounded in-process log of the set-up's phases, as intervals on
+    ``time.perf_counter()``: ``jax.monitoring``'s compile events and the
+    program's own records (:func:`log_interval`).  ``clock`` is one
+    ``(perf_counter, time.time)`` pair, which lays any record on the
+    wall clock of the span ring and of a device trace's host plane.
 
-    JAX reports the persistent cache's verdict as a bare event just
-    before the backend-compile duration of the same program, on the
-    same thread: the verdict is held per thread and lands on that
-    backend record, so a miss names its program.
+    JAX reports the persistent cache's verdict, and after a hit the
+    seconds the load took and saved, just before the backend-compile
+    duration of the same program, on the same thread: they are held per
+    thread and land on that backend record, so a miss names its program.
 
     The phases nest: while ``local_step`` is traced or lowered, every
     jitted helper it calls (``jnp.add``, ``_where``, ...: thousands for
     a 24-layer model) is traced inside it and reports a duration of its
     own.  JAX also reports each phase's START (a scalar event); the
     depth kept from those per thread lets only the outermost phase into
-    the log, whose seconds already hold the nested ones."""
+    the log, whose seconds already hold the nested ones.  Of an
+    outermost ``trace`` the direct children are summed by name into its
+    ``children`` (one dict update an event, only while JAX traces): the
+    record's seconds less theirs is the tracing of the model's own
+    Python."""
 
-    def __init__(self):
+    def __init__(self, started: Optional[float] = None):
         self.records = collections.deque(maxlen=COMPILE_LOG_CAPACITY)
         self.installed = False
-        self._pending = threading.local()
+        self.clock = (time.perf_counter(), time.time())
+        self._pending = _Pending()
+        if started is not None and started <= self.clock[0]:
+            self.records.append(
+                _interval("process", "process", started, self.clock[0]))
 
     def on_event(self, event: str, **_kw) -> None:
         verdict = _CACHE_VERDICTS.get(event)
         if verdict is not None:
-            self._pending.verdict = verdict
+            self._pending.cache["cache"] = verdict
 
     def on_start(self, event: str, _value, **_kw) -> None:
-        if event in _COMPILE_PHASES:
-            self._pending.depth = getattr(self._pending, "depth", 0) + 1
+        phase = _COMPILE_PHASES.get(event)
+        if phase is not None:
+            pending = self._pending
+            if not pending.depth:
+                pending.outermost, pending.children = phase, {}
+            pending.depth += 1
 
     def on_duration(self, event: str, seconds: float, **kw) -> None:
+        pending = self._pending
+        if event in _CACHE_SECONDS:
+            pending.cache[_CACHE_SECONDS[event]] = float(seconds)
+            return
         phase = _COMPILE_PHASES.get(event)
         if phase is None:
             return
-        depth = max(getattr(self._pending, "depth", 0) - 1, 0)
-        self._pending.depth = depth
-        if depth:
+        pending.depth = max(pending.depth - 1, 0)
+        if pending.depth:
+            if pending.depth == 1 and phase == "trace" == pending.outermost:
+                child = pending.children.setdefault(
+                    str(kw.get("fun_name", "")), [0, 0.0])
+                child[0] += 1
+                child[1] += seconds
             return
         from . import trace as obs_trace  # noqa: PLC0415
         from .registry import get_registry  # noqa: PLC0415
 
+        t_end = time.perf_counter()
         seconds = float(seconds)
         # JAX says ``step`` when it traces and ``jit(step)`` when it
         # lowers and compiles: one program, one name.
         program = re.sub(r"^jit\((.*)\)$", r"\1",
                          str(kw.get("fun_name", "")))
-        record = {"program": program, "phase": phase,
-                  "seconds": seconds, "t_end": time.perf_counter()}
+        record = _interval(program, phase, t_end - seconds, t_end, seconds)
         reg = get_registry()
         reg.counter("compile.seconds", phase=phase).inc(seconds)
-        if phase == "backend":
-            verdict = getattr(self._pending, "verdict", None)
-            self._pending.verdict = None
+        if phase == "trace":
+            record["children"] = _largest(pending.children)
+        elif phase == "backend":
+            held, pending.cache = pending.cache, {}
+            record.update({"cache_load_s": 0.0, **held})
+            reg.counter("compile.seconds", phase="cache_load").inc(
+                record["cache_load_s"])
+            verdict = held.get("cache")
             if verdict is not None:
-                record["cache"] = verdict
                 reg.counter("compile.cache_hits" if verdict == "hit"
                             else "compile.cache_misses").inc()
             if obs_trace.enabled():
-                t1 = time.time()
+                t1 = self.clock[1] + t_end - self.clock[0]
                 obs_trace.add_span(
                     COMPILE_LANE, "compile", t1 - seconds, t1,
-                    program=record["program"], cache=verdict)
+                    program=program, cache=verdict)
         self.records.append(record)
 
 
-_COMPILE_LOG = _CompileLog()
+def _interval(program: str, phase: str, t_start: float, t_end: float,
+              seconds: Optional[float] = None) -> dict:
+    return {"program": program, "phase": phase,
+            "seconds": t_end - t_start if seconds is None else seconds,
+            "t_start": t_start, "t_end": t_end}
+
+
+def _largest(children: Dict[str, list]) -> Dict[str, list]:
+    """The :data:`CHILDREN_KEPT` entries ``{name: [count, seconds]}``
+    with the most seconds, the rest summed into ``"other"``."""
+    ranked = sorted(children.items(), key=lambda kv: -kv[1][1])
+    kept = dict(ranked[:CHILDREN_KEPT])
+    if ranked[CHILDREN_KEPT:]:
+        rest = kept.setdefault("other", [0, 0.0])
+        for _, (count, seconds) in ranked[CHILDREN_KEPT:]:
+            rest[0] += count
+            rest[1] += seconds
+    return kept
+
+
+# The process's log: its first record runs from the process's start to
+# this module's import, where the start can be known.
+_COMPILE_LOG = _CompileLog(_process_start())
 
 
 def install_compile_listener() -> None:
-    """Register the compile log with ``jax.monitoring``, once a
+    """Register the set-up log with ``jax.monitoring``, once a
     process.  Called by ``utils/compile_cache.enable_compile_cache()``;
     the listeners run only when JAX traces, lowers or compiles."""
-    if _COMPILE_LOG.installed:
+    log = _COMPILE_LOG
+    if log.installed:
         return
     import jax.monitoring  # noqa: PLC0415
 
-    jax.monitoring.register_event_listener(_COMPILE_LOG.on_event)
-    jax.monitoring.register_scalar_listener(_COMPILE_LOG.on_start)
-    jax.monitoring.register_event_duration_secs_listener(
-        _COMPILE_LOG.on_duration)
-    _COMPILE_LOG.installed = True
+    jax.monitoring.register_event_listener(log.on_event)
+    jax.monitoring.register_scalar_listener(log.on_start)
+    jax.monitoring.register_event_duration_secs_listener(log.on_duration)
+    log.clock = (time.perf_counter(), time.time())
+    log.installed = True
+
+
+def log_interval(phase: str, program: str, t_start: float) -> None:
+    """One of the program's own set-up phases, from ``t_start`` (on
+    ``time.perf_counter()``) to now: ``hvd.init()`` is ``init``."""
+    _COMPILE_LOG.records.append(
+        _interval(program, phase, t_start, time.perf_counter()))
 
 
 def compile_log() -> List[dict]:
-    """The compile events so far, oldest first: ``{"program", "phase",
-    "seconds", "t_end"}`` with ``phase`` one of ``trace``, ``lower``,
-    ``backend`` and ``t_end`` on ``time.perf_counter()``; a backend
-    record of a program that went through the persistent cache also
-    has ``"cache": "hit" | "miss"``.  The newest
-    :data:`COMPILE_LOG_CAPACITY` records are kept."""
+    """The set-up's records so far, oldest first: ``{"program", "phase",
+    "seconds", "t_start", "t_end"}``, the stamps on
+    ``time.perf_counter()``.  ``phase`` is ``trace``, ``lower`` or
+    ``backend`` for JAX's compile events (``t_start`` is ``t_end`` less
+    ``seconds``), ``init`` for ``hvd.init()`` and ``process`` for the
+    first record (the process's start to this module's import; Linux
+    only).  A trace record has ``children``: ``{name: [count, seconds]}``
+    of the jitted helpers traced directly inside it, the sixteen largest
+    and ``"other"``.  A backend record has ``cache_load_s``, and where
+    the persistent cache was asked ``"cache": "hit" | "miss"`` and after
+    a hit ``saved_s``.  The newest :data:`COMPILE_LOG_CAPACITY` records
+    are kept."""
     return [dict(r) for r in list(_COMPILE_LOG.records)]
 
 
 def compile_summary() -> dict:
-    """The log in one line, for a drain summary: seconds by phase, the
+    """The log in one line, for a drain summary: seconds by phase (and
+    ``cache_load``, the part of ``backend`` the cache's loads took), the
     cache's hits and misses, and the programs that missed."""
-    seconds = {phase: 0.0 for phase in _COMPILE_PHASES.values()}
+    seconds = dict.fromkeys(
+        (*_COMPILE_PHASES.values(), "cache_load", "init"), 0.0)
     hits, missed = 0, []
     for r in compile_log():
-        seconds[r["phase"]] += r["seconds"]
+        if r["phase"] in seconds:
+            seconds[r["phase"]] += r["seconds"]
+        seconds["cache_load"] += r.get("cache_load_s", 0.0)
         if r.get("cache") == "hit":
             hits += 1
         elif r.get("cache") == "miss":

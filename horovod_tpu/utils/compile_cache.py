@@ -7,9 +7,10 @@ directory is set in code; otherwise the cache is ``<checkout>/.jax_cache``
 (git-ignored).  The path is part of the cache key, so it is never a
 temporary name, a pid or a time.
 
-The same call arms the program's compile log
+The same call arms the program's set-up log
 (``horovod_tpu.obs.profile.compile_log()``): what was traced, lowered
-and compiled, for how long, and whether the cache had it.
+and compiled, from when to when, whether the cache had it and what its
+load took.
 """
 
 from __future__ import annotations
@@ -47,9 +48,10 @@ def enable_compile_cache() -> Optional[str]:
     from ..obs.profile import install_compile_listener  # noqa: PLC0415
 
     # Every entry point calls this before its first compile, so this is
-    # where the compile log (obs/profile.py: program, phase, seconds,
-    # cache hit or miss) starts listening — on the CPU too, where only
-    # the cache itself is left alone.
+    # where the set-up log (obs/profile.py: program, phase, interval,
+    # cache hit or miss and the load's seconds) starts listening and
+    # takes its clock pair — on the CPU too, where only the cache
+    # itself is left alone.
     install_compile_listener()
     if jax.config.jax_platforms == "cpu":
         return None

@@ -532,6 +532,366 @@ def test_compile_log_puts_the_cache_verdict_on_the_backend_record():
 
 
 # ---------------------------------------------------------------------------
+# the set-up log (ISSUE 37): intervals, the cache's seconds, hvd.init,
+# the nested traces, and the benchmark's seven readers
+# ---------------------------------------------------------------------------
+
+TRACE_EVENT, LOWER_EVENT, BACKEND_EVENT = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration")
+RETRIEVAL_EVENT, SAVED_EVENT = (
+    "/jax/compilation_cache/cache_retrieval_time_sec",
+    "/jax/compilation_cache/compile_time_saved_sec")
+
+
+def test_every_record_is_an_interval_on_the_runners_clock():
+    enable_compile_cache()
+
+    def doubled_for_the_interval_case(x):
+        return x * 2.0
+
+    t_before = time.perf_counter()
+    jax.jit(doubled_for_the_interval_case)(jnp.ones((4,))).block_until_ready()
+    t_after = time.perf_counter()
+    log = profile.compile_log()
+    assert log and all(r["t_start"] <= r["t_end"] for r in log)
+    mine = [r for r in log
+            if r["program"] == "doubled_for_the_interval_case"]
+    assert [r["phase"] for r in mine] == ["trace", "lower", "backend"]
+    for r in mine:
+        assert r["t_end"] - r["t_start"] == pytest.approx(r["seconds"],
+                                                          abs=1e-6)
+        assert t_before <= r["t_start"] and r["t_end"] <= t_after
+    # one after the other, as JAX runs them
+    assert mine[0]["t_end"] <= mine[1]["t_start"] + 1e-3
+    assert mine[1]["t_end"] <= mine[2]["t_start"] + 1e-3
+    assert set(mine[0]["children"]) == {"multiply"}   # x * 2.0
+    assert mine[2]["cache_load_s"] == 0.0             # no cache on the CPU
+
+
+def test_the_caches_seconds_land_on_the_same_threads_backend_record():
+    import threading
+
+    log = profile._CompileLog()
+    log.on_event("/jax/compilation_cache/cache_hits")
+    log.on_duration(SAVED_EVENT, 41.5)
+    log.on_duration(RETRIEVAL_EVENT, 1.25)
+    # another thread compiles in between: it sees none of it
+    other = threading.Thread(target=log.on_duration,
+                             args=(BACKEND_EVENT, 3.0),
+                             kwargs={"fun_name": "jit(elsewhere)"})
+    other.start()
+    other.join(timeout=10)
+    assert not other.is_alive()
+    log.on_duration(TRACE_EVENT, 0.2, fun_name="step")   # not a backend
+    log.on_duration(BACKEND_EVENT, 1.5, fun_name="jit(step)")
+    log.on_duration(BACKEND_EVENT, 0.5, fun_name="jit(next)")
+    got = {r["program"]: r for r in log.records if r["phase"] == "backend"}
+    assert got["step"]["cache_load_s"] == 1.25
+    assert got["step"]["saved_s"] == 41.5 and got["step"]["cache"] == "hit"
+    for name in ("elsewhere", "next"):
+        assert got[name]["cache_load_s"] == 0.0
+        assert "saved_s" not in got[name] and "cache" not in got[name]
+    assert get_registry().counter(
+        "compile.seconds", phase="cache_load").value == 1.25
+    reset_registry()
+
+
+def test_a_real_hit_in_the_persistent_cache_carries_its_load(tmp_path):
+    """The CPU is left without a cache by ``enable_compile_cache``; this
+    case turns one on to see JAX's own events arrive in the order the
+    log relies on."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    enable_compile_cache()
+    settings = {"jax_compilation_cache_dir": str(tmp_path),
+                "jax_persistent_cache_min_compile_time_secs": 0,
+                "jax_persistent_cache_min_entry_size_bytes": -1}
+    before = {k: getattr(jax.config, k) for k in settings}
+
+    def same_program_twice():
+        def matmul_for_the_cache_case(x):
+            return jnp.sin(x) @ x
+        return jax.jit(matmul_for_the_cache_case)
+
+    try:
+        for key, value in settings.items():
+            jax.config.update(key, value)
+        compilation_cache.reset_cache()
+        x = jnp.ones((32, 32))
+        same_program_twice()(x).block_until_ready()
+        same_program_twice()(x).block_until_ready()
+    finally:
+        for key, value in before.items():
+            jax.config.update(key, value)
+        compilation_cache.reset_cache()
+    miss, hit = [r for r in profile.compile_log()
+                 if r["program"] == "matmul_for_the_cache_case"
+                 and r["phase"] == "backend"]
+    assert miss["cache"] == "miss" and miss["cache_load_s"] == 0.0
+    assert "saved_s" not in miss
+    assert hit["cache"] == "hit" and "saved_s" in hit
+    assert 0.0 < hit["cache_load_s"] <= hit["seconds"]
+
+
+def test_hvd_init_is_one_record_after_the_process_record(tmp_path):
+    """A fresh process, as an entry point runs: the listener, then
+    ``hvd.init()`` twice."""
+    import subprocess
+
+    script = (
+        "import json, time\n"
+        "from horovod_tpu.utils.compile_cache import enable_compile_cache\n"
+        "import horovod_tpu as hvd\n"
+        "enable_compile_cache(); enable_compile_cache()\n"
+        "hvd.init(); hvd.init()\n"
+        "from horovod_tpu.obs import profile\n"
+        "print(json.dumps({'log': profile.compile_log(),\n"
+        "                  'now': time.perf_counter(),\n"
+        "                  'summary': profile.compile_summary()}))\n")
+    out = subprocess.run(
+        [sys.executable, "-c", script], cwd=ROOT, timeout=240,
+        capture_output=True, text=True, check=True,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    log = got["log"]
+    inits = [r for r in log if r["phase"] == "init"]
+    assert len(inits) == 1 and inits[0]["program"] == "hvd.init"
+    assert 0.0 < inits[0]["seconds"] == pytest.approx(
+        inits[0]["t_end"] - inits[0]["t_start"])
+    assert got["summary"]["seconds"]["init"] == pytest.approx(
+        inits[0]["seconds"], abs=1e-3)
+    if not os.path.exists("/proc/self/stat"):
+        assert all(r["phase"] != "process" for r in log)
+        return
+    assert [r["phase"] for r in log].count("process") == 1
+    first = log[0]
+    assert first["phase"] == "process" and first["program"] == "process"
+    # the interpreter's start and the imports of jax and the package:
+    # some tenths of a second at least, and before everything else
+    assert 0.1 < first["seconds"] < 120.0
+    assert first["t_start"] < first["t_end"] <= inits[0]["t_start"]
+    assert got["now"] - first["t_start"] < 240.0
+
+
+def test_no_process_record_where_the_start_cannot_be_read(monkeypatch):
+    assert profile._process_start() is not None or not os.path.exists(
+        "/proc/self/stat")
+    monkeypatch.setattr(profile, "_COMPILE_LOG", profile._CompileLog(None))
+    profile.log_interval("init", "hvd.init", time.perf_counter() - 0.5)
+    (only,) = profile.compile_log()
+    assert only["phase"] == "init"
+    assert only["seconds"] == pytest.approx(0.5, abs=0.05)
+    assert _setup_reader("setup_uncovered_s").read(
+        {"stamps": [time.perf_counter() + 1.0, time.perf_counter() + 2.0]}
+    ) is None
+
+
+def test_children_of_a_trace_are_its_jitted_helpers_by_name():
+    enable_compile_cache()
+
+    @jax.jit
+    def helper_called_three_times(x):
+        return jnp.tanh(x) * 2.0     # tanh, multiply: a level deeper
+
+    def outer_with_a_thrice_called_helper(x):
+        for _ in range(3):
+            x = helper_called_three_times(x)
+        return x
+
+    n = len(profile.compile_log())
+    jax.jit(outer_with_a_thrice_called_helper)(
+        jnp.ones((5,))).block_until_ready()
+    (traced,) = [r for r in profile.compile_log()[n:]
+                 if r["phase"] == "trace"]
+    assert traced["program"] == "outer_with_a_thrice_called_helper"
+    assert set(traced["children"]) == {"helper_called_three_times"}
+    count, seconds = traced["children"]["helper_called_three_times"]
+    assert count == 3 and 0.0 < seconds <= traced["seconds"]
+    # only a trace has children: lowering is one module a program
+    assert all("children" not in r for r in profile.compile_log()[n:]
+               if r["phase"] != "trace")
+
+
+def test_the_sixteen_largest_children_are_kept_and_the_rest_is_other():
+    log = profile._CompileLog()
+    log.on_start(TRACE_EVENT, 0.0, fun_name="step")
+    for i in range(17):
+        for _ in range(2):
+            log.on_start(TRACE_EVENT, 0.0, fun_name=f"helper{i}")
+            # a helper's own helper is the helper's time, not a child
+            log.on_start(TRACE_EVENT, 0.0, fun_name="deeper")
+            log.on_duration(TRACE_EVENT, 0.001, fun_name="deeper")
+            log.on_duration(TRACE_EVENT, 0.01 * (i + 1), fun_name=f"helper{i}")
+    # what a lowering traces is the lowering's time
+    log.on_duration(TRACE_EVENT, 9.0, fun_name="step")
+    log.on_start(LOWER_EVENT, 0.0, fun_name="jit(step)")
+    log.on_start(TRACE_EVENT, 0.0, fun_name="traced_by_a_lowering_rule")
+    log.on_duration(TRACE_EVENT, 0.1, fun_name="traced_by_a_lowering_rule")
+    log.on_duration(LOWER_EVENT, 1.0, fun_name="jit(step)")
+    traced, lowered = log.records
+    children = traced["children"]
+    assert len(children) == profile.CHILDREN_KEPT + 1
+    assert "helper0" not in children and "deeper" not in children
+    assert children["other"] == [2, pytest.approx(0.02)]
+    assert children["helper16"] == [2, pytest.approx(0.34)]
+    assert sum(s for _, s in children.values()) == pytest.approx(
+        0.02 * sum(range(1, 18)))
+    assert traced["seconds"] == 9.0
+    assert lowered["phase"] == "lower" and "children" not in lowered
+    reset_registry()
+
+
+def test_compile_summary_carries_the_caches_load_and_init(monkeypatch):
+    log = profile._CompileLog(started=1.0)
+    monkeypatch.setattr(profile, "_COMPILE_LOG", log)
+    profile.log_interval("init", "hvd.init", time.perf_counter() - 0.25)
+    log.on_event("/jax/compilation_cache/cache_hits")
+    log.on_duration(RETRIEVAL_EVENT, 0.75)
+    log.on_duration(BACKEND_EVENT, 1.0, fun_name="jit(step)")
+    log.on_duration(BACKEND_EVENT, 2.0, fun_name="jit(init)")
+    summary = profile.compile_summary()
+    assert summary["seconds"] == {
+        "trace": 0.0, "lower": 0.0, "backend": 3.0, "cache_load": 0.75,
+        "init": pytest.approx(0.25, abs=0.05)}   # and no "process"
+    assert summary["cache_hits"] == 1 and summary["cache_misses"] == 0
+    assert [r["phase"] for r in profile.compile_log()][0] == "process"
+    reset_registry()
+
+
+def test_the_log_and_its_children_stay_bounded():
+    log = profile._CompileLog()
+    for i in range(profile.COMPILE_LOG_CAPACITY + 50):
+        log.on_start(TRACE_EVENT, 0.0, fun_name=f"program{i}")
+        for j in range(40):
+            log.on_start(TRACE_EVENT, 0.0, fun_name=f"helper{j}")
+            log.on_duration(TRACE_EVENT, 0.001, fun_name=f"helper{j}")
+        log.on_duration(TRACE_EVENT, 0.1, fun_name=f"program{i}")
+    assert len(log.records) == profile.COMPILE_LOG_CAPACITY
+    assert log.records[-1]["program"] == \
+        f"program{profile.COMPILE_LOG_CAPACITY + 49}"
+    for r in log.records:
+        assert len(r["children"]) <= profile.CHILDREN_KEPT + 1
+        assert sum(c for c, _ in r["children"].values()) == 40
+    reset_registry()
+
+
+def test_the_compile_span_lies_where_the_record_lies(monkeypatch, tmp_path):
+    """One clock pair lays a record on the span ring's wall clock."""
+    monkeypatch.setenv(envmod.TRACE, str(tmp_path) + os.sep)
+    obs_trace.reset_buffer()
+    try:
+        log = profile._CompileLog()
+        perf, wall = log.clock
+        assert abs(wall - time.time()) < 5.0
+        log.on_duration(BACKEND_EVENT, 0.5, fun_name="jit(step)")
+        (record,) = log.records
+        (span,) = [s for s in obs_trace.get_buffer().snapshot()
+                   if s["trace"] == profile.COMPILE_LANE]
+        assert span["t0"] == pytest.approx(
+            wall + record["t_start"] - perf, abs=1e-6)
+        assert span["dur"] == pytest.approx(0.5, abs=1e-6)
+    finally:
+        obs_trace.reset_buffer()
+        reset_registry()
+
+
+def _setup_reader(name):
+    sys.path.insert(0, ROOT)
+    try:
+        from benchmark.harness import registry
+    finally:
+        sys.path.remove(ROOT)
+    return registry.load_module(os.path.join(
+        ROOT, "benchmark", "metrics", name + ".py"))
+
+
+def _recorded(program, phase, t_start, t_end, **extra):
+    return {"program": program, "phase": phase, "seconds": t_end - t_start,
+            "t_start": t_start, "t_end": t_end, **extra}
+
+
+# A set-up as the log records it: the window's first stamp at 60.0, one
+# step 0.5 s long.  ``warm_up`` overlaps the step's backend phase (a
+# second thread): a union counts the overlap once.
+SETUP_LOG = [
+    _recorded("process", "process", 10.0, 14.0),
+    _recorded("hvd.init", "init", 15.0, 16.5),
+    _recorded("make_state", "trace", 17.0, 18.0, children={}),
+    _recorded("make_state", "lower", 18.0, 18.5),
+    _recorded("make_state", "backend", 18.5, 20.5, cache="hit",
+              cache_load_s=0.25, saved_s=30.0),
+    _recorded("local_step", "trace", 25.0, 33.0, children={}),
+    _recorded("local_step", "lower", 33.0, 37.0),
+    _recorded("local_step", "backend", 37.0, 40.0, cache="hit",
+              cache_load_s=1.5, saved_s=60.0),
+    _recorded("warm_up", "backend", 39.0, 41.0, cache_load_s=0.0),
+    # after the window's first stamp: the checks' program
+    _recorded("program_loss", "trace", 80.0, 89.0, children={}),
+    _recorded("program_loss", "backend", 89.0, 99.0, cache="miss",
+              cache_load_s=0.0),
+]
+SETUP_RUN = {"stamps": [60.0, 60.5, 61.0, 61.5]}
+# the step is ``local_step`` (15 s of 20.5); what the parent's log holds
+# of the same set-up: no intervals, no ``init``, no ``process``
+PARENTS_LOG = [{k: v for k, v in r.items()
+                if k in ("program", "phase", "seconds", "t_end", "cache")}
+               for r in SETUP_LOG if r["phase"] not in ("process", "init")]
+# covered: 4 + 1.5 + 3.5 + [25, 41] = 25; from 10.0 to 60.0 - 0.5
+SETUP_READINGS = {
+    "step_trace_s": (8.0, 8.0), "step_lower_s": (4.0, 4.0),
+    "step_backend_s": (3.0, 3.0), "cache_load_s": (1.75, None),
+    "state_programs_s": (5.5, None), "hvd_init_s": (1.5, None),
+    "setup_uncovered_s": (49.5 - 25.0, None)}
+
+
+@pytest.mark.parametrize("name", sorted(SETUP_READINGS))
+def test_setup_readers_on_a_recorded_log(monkeypatch, name):
+    reading, on_the_parent = SETUP_READINGS[name]
+    monkeypatch.setattr(profile, "compile_log", lambda: list(SETUP_LOG))
+    assert _setup_reader(name).read(SETUP_RUN) == pytest.approx(reading)
+    # the accepted readers read what they read before, on both logs
+    for log in (SETUP_LOG, PARENTS_LOG):
+        monkeypatch.setattr(profile, "compile_log", lambda log=log: list(log))
+        assert _setup_reader("compile_trace_lower_s").read(
+            SETUP_RUN) == pytest.approx(13.5)
+        assert _setup_reader("compile_cache_misses").read(SETUP_RUN) == 0
+    got = _setup_reader(name).read(SETUP_RUN)
+    assert got == (on_the_parent if on_the_parent is None
+                   else pytest.approx(on_the_parent))
+    # nothing to read: no stamps (a served run), an empty log
+    assert _setup_reader(name).read({"requests": []}) is None
+    monkeypatch.setattr(profile, "compile_log", lambda: [])
+    assert _setup_reader(name).read(SETUP_RUN) is None
+
+
+def test_the_setup_readers_count_overlapping_records_once(monkeypatch):
+    covered = _setup_reader("setup_uncovered_s").covered
+    assert covered([]) == 0.0
+    assert covered(SETUP_LOG[:9]) == pytest.approx(25.0)
+    nested = [_recorded("a", "trace", 0.0, 10.0),
+              _recorded("b", "trace", 2.0, 3.0),
+              _recorded("c", "lower", 9.0, 12.0),
+              _recorded("d", "backend", 20.0, 21.0)]
+    assert covered(nested) == pytest.approx(13.0)
+    assert covered([{"t_end": 3.0}]) is None
+    # a cold run: every program missed, and the reader says 0, not None
+    cold = [dict(r, cache="miss", cache_load_s=0.0) if "cache" in r else r
+            for r in SETUP_LOG]
+    monkeypatch.setattr(profile, "compile_log", lambda: cold)
+    assert _setup_reader("cache_load_s").read(SETUP_RUN) == 0.0
+    assert _setup_reader("compile_cache_misses").read(SETUP_RUN) == 2
+    # the step is chosen by its seconds, not by its name or its place
+    late = [dict(r, program="make_state" if r["program"] == "local_step"
+                 else "local_step" if r["program"] == "make_state"
+                 else r["program"]) for r in SETUP_LOG]
+    monkeypatch.setattr(profile, "compile_log", lambda: late)
+    assert _setup_reader("step_trace_s").read(SETUP_RUN) == 8.0
+    assert _setup_reader("state_programs_s").read(SETUP_RUN) == 5.5
+
+
+# ---------------------------------------------------------------------------
 # the serving rank's hook
 # ---------------------------------------------------------------------------
 

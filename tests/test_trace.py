@@ -27,10 +27,8 @@ from horovod_tpu.obs import trace_merge
 from horovod_tpu.obs.profile import (
     CPU_PEAK_ESTIMATE,
     MFUProfiler,
-    analytic_step_flops,
     flops_from_compiled,
     peak_flops,
-    transformer_step_flops,
 )
 from horovod_tpu.obs.registry import MetricsRegistry
 from horovod_tpu.testing import faults
@@ -345,47 +343,6 @@ def test_peak_flops_table_and_estimate_flag():
     assert peak32 == 275e12 / 4
     peak_cpu, est_cpu = peak_flops("cpu")
     assert peak_cpu == CPU_PEAK_ESTIMATE and est_cpu is True
-
-
-def test_transformer_flops_against_hand_computed_bench_shape():
-    """The analytic fallback for the bench gpt shape, checked two ways:
-    the parameter count against the REAL flax module's leaf count, and
-    the step FLOPs against the 6N + 12*L*s*d rule computed by hand."""
-    jax = pytest.importorskip("jax")
-    import jax.numpy as jnp
-
-    from horovod_tpu.models.transformer import GPT_CONFIGS, gpt
-    from horovod_tpu.obs.profile import _transformer_param_count
-
-    cfg = GPT_CONFIGS["nano"]
-    # reference attention: the flash kernel needs a newer pallas than
-    # the container pins, and the impl does not change the param count
-    model = gpt("nano", attention_impl="reference")
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8),
-                                                         jnp.int32))
-    real_n = sum(x.size for x in jax.tree_util.tree_leaves(params))
-    assert _transformer_param_count(cfg) == real_n
-
-    batch, seq = 4, 128
-    n = real_n
-    hand = batch * seq * (6 * n
-                          + 12 * cfg.num_layers * seq * cfg.emb_dim)
-    assert transformer_step_flops(cfg, batch, seq) == pytest.approx(hand)
-    assert analytic_step_flops("gpt-nano", batch, seq) == \
-        pytest.approx(hand)
-    # inference shape: forward-only
-    fwd = batch * seq * (2 * n + 4 * cfg.num_layers * seq * cfg.emb_dim)
-    assert transformer_step_flops(cfg, batch, seq, training=False) == \
-        pytest.approx(fwd)
-
-
-def test_analytic_conv_table_and_unknown_model():
-    assert analytic_step_flops("resnet50", 32) == \
-        pytest.approx(3.0 * 8.2e9 * 32)
-    # half-resolution images cost a quarter of the FLOPs
-    assert analytic_step_flops("resnet50", 32, image_size=112) == \
-        pytest.approx(3.0 * 8.2e9 * 32 / 4)
-    assert analytic_step_flops("made-up-model", 32) is None
 
 
 def test_mfu_profiler_gauge_math():
