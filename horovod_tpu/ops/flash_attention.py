@@ -32,19 +32,25 @@ Design (the standard flash recurrence, TPU-shaped):
   ``jax.custom_vjp`` so the op drops into training.  ``delta =
   rowsum(do * o)`` is computed once per call, outside the kernels
   (0.025 ms there), not once per tile.  ONE kernel forms each tile's
-  ``p`` and ``ds`` once and takes dq, dk and dv from them, dq's
-  accumulator and a whole kv row's dk and dv accumulators resident in
-  VMEM, wherever those fit the VMEM the call states (a function of the
-  shape alone: ``_fused_bwd_vmem_bytes`` against
-  ``_FUSED_BWD_VMEM_LIMIT``; a head's channels fill whole 128-lane
-  tiles, so that is 8192 keys at head size 64 or 128 and 4096 keys at
-  head size 256).  A longer sequence takes the two passes it replaced
-  (dk/dv, then dq, each recomputing ``p`` and ``ds``), whose VMEM does
-  not grow with S: 8192 keys at head size 256, latent attention's shape
-  in ``glm47f_train_s8192``, run there.
+  ``p`` and ``ds`` once and takes dq, dk and dv from them; which sums
+  stay resident in VMEM is read from the shape alone (``backward_form``:
+  what each form holds against ``_FUSED_BWD_VMEM_LIMIT``, the VMEM the
+  call states).  Q tile outermost, dq's accumulator and a whole kv row's
+  dk and dv accumulators resident, wherever that fits (a head's channels
+  fill whole 128-lane tiles, so 8192 keys at head size 64 or 128 and
+  4096 keys at head size 256); else K tile outermost, the tile's dk and
+  dv accumulators and the kv row's dq resident (8192 keys at head size
+  256, latent attention's shape in ``glm47f_train_s8192``, up to 26624;
+  16384 keys at head size 64).  A longer sequence takes the two passes
+  the one kernel replaced (dk/dv, then dq, each recomputing ``p`` and
+  ``ds``), whose VMEM does not grow with S.
   Per call at 128 x 1024 x 64 (chip runs of PR 29): two passes 0.973 +
   0.679 ms, one kernel 1.025; at 32 query over 8 K/V heads x 8192 x 64,
-  10.72 + 7.51 against 12.00.
+  10.72 + 7.51 against 12.00.  At 20 x 8192 x 256 (chip runs of PR 38):
+  two passes 21.21 ms, one kernel with dq resident 14.49 (2.66 us a
+  needed tile for 3.90); the kv row's dk and dv in two spans of 4096
+  keys with dq's float32 partials summed after the call 14.60 + 0.61, in
+  four spans 16.25.
 * Off-TPU (the CPU test mesh) the same kernel runs through the Pallas
   interpreter, so correctness tests don't need TPU hardware.
 * The ``pallas_call`` sites are named ``flash_fwd``, ``flash_bwd_dkdv``
@@ -217,19 +223,20 @@ def _kv_row(zi, h: int, hkv: int):
 
 # The VMEM the one-kernel backward states (``vmem_limit_bytes``): twice
 # a v5e's default scoped limit, a quarter of its VMEM.  A backward whose
-# resident dk/dv accumulators and tiles fit it runs as one kernel; a
-# longer sequence takes the two passes, whose VMEM does not grow with S.
+# resident accumulators and tiles fit it runs as one kernel, in the form
+# ``backward_form`` reads from the shape; a longer sequence takes the two
+# passes, whose VMEM does not grow with S.
 _FUSED_BWD_VMEM_LIMIT = 32 * 2 ** 20
 
 
 def _fused_bwd_vmem_bytes(s: int, d: int, bq: int, bk: int,
                           itemsize: int) -> int:
-    """VMEM the one-kernel backward holds for a kv row of ``s`` keys,
-    every buffer's minor dimension padded to the 128 lanes its tiles
-    occupy: the two float32 accumulators, the dk and dv output blocks
-    (two buffers each), the streamed tiles (two buffers each), dq's
-    accumulator and six score-sized float32 temporaries.  The TPU
-    compiler asked 32.63 MiB for 16384 x 64 in bfloat16 inside a
+    """VMEM the one-kernel backward holds for a kv row of ``s`` keys with
+    the Q tile outermost, every buffer's minor dimension padded to the
+    128 lanes its tiles occupy: the two float32 accumulators, the dk and
+    dv output blocks (two buffers each), the streamed tiles (two buffers
+    each), dq's accumulator and six score-sized float32 temporaries.  The
+    TPU compiler asked 32.63 MiB for 16384 x 64 in bfloat16 inside a
     differentiated ``flash_attention`` (sandbox compile for a v5e,
     PR 29), where this counts 36.1; the unpadded count, 19.6, was wrong
     there."""
@@ -237,6 +244,44 @@ def _fused_bwd_vmem_bytes(s: int, d: int, bq: int, bk: int,
     resident = 2 * s * lanes * 4 + 2 * 2 * s * lanes * itemsize
     tiles = 2 * (3 * bq + 2 * bk) * lanes * itemsize
     return resident + tiles + d * bq * 4 + 6 * bk * bq * 4
+
+
+def _dq_resident_bwd_vmem_bytes(s: int, d: int, bq: int, bk: int,
+                                itemsize: int, group: int) -> int:
+    """VMEM the one-kernel backward holds with the K tile outermost: dq of
+    a kv row's ``group`` query heads as ``[group * nq, d, bq]`` float32
+    (channels on sublanes, queries on lanes), the K tile's two float32
+    accumulators, the streamed tiles and output blocks (q, do, dq of
+    ``bq`` rows, k, v, dk, dv of ``bk``; two buffers each, 128 lanes at
+    least) and six score-sized float32 temporaries.  14.0 MiB for 8192
+    keys at head size 256; the TPU compiler takes that shape inside a
+    stated 14 MiB (sandbox compile for a v5e, PR 38)."""
+    lanes = -(-d // 128) * 128
+    dq_rows = group * (s // bq) * (-(-d // 8) * 8) * (-(-bq // 128) * 128)
+    resident = dq_rows * 4 + 2 * bk * lanes * 4
+    tiles = 2 * (3 * bq + 4 * bk) * lanes * itemsize
+    return resident + tiles + 6 * bk * bq * 4
+
+
+def backward_form(seq: int, head_dim: int, group: int, itemsize: int,
+                  block_q: int = 512, block_k: int = 256) -> str:
+    """Which backward a call of this shape runs, from the shape alone
+    (``group`` query heads a key/value head, tiles as ``_pick_block``
+    makes them) against the VMEM the one kernel states.
+    ``"dkdv_resident"``: one kernel, Q tile outermost, a kv row's dk and
+    dv accumulators resident (PR 29's), wherever it fits.
+    ``"dq_resident"``: one kernel, K tile outermost, the group's dq rows
+    resident, where that fits instead.  ``"two_passes"`` above both.
+    ``_flash_bwd_pallas`` branches on it and ``models/transformer.py``
+    sets its gauges from it while a step is traced."""
+    bq, bk = _pick_block(seq, block_q), _pick_block(seq, block_k)
+    if (_fused_bwd_vmem_bytes(seq, head_dim, bq, bk, itemsize)
+            <= _FUSED_BWD_VMEM_LIMIT):
+        return "dkdv_resident"
+    if (_dq_resident_bwd_vmem_bytes(seq, head_dim, bq, bk, itemsize, group)
+            <= _FUSED_BWD_VMEM_LIMIT):
+        return "dq_resident"
+    return "two_passes"
 
 
 def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
@@ -351,25 +396,36 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
     forward's saved logsumexp; ``delta`` = rowsum(do*o) is the standard
     softmax-backward correction.
 
-    One kernel (grid z, nq, nk; call site ``flash_bwd_dkdv``, which here
-    covers dq, dk AND dv) wherever ``_fused_bwd_vmem_bytes`` of the shape
-    fits ``_FUSED_BWD_VMEM_LIMIT``: Q tile fixed, K tiles stream, each
-    needed tile's P and dS formed once.  dq accumulates as [d, bq],
-    turned once at the flush; dk and dv accumulate in two [S, d] float32
-    scratch buffers that live for a whole kv row — under GQA the group's
-    query heads are consecutive z and fold into them — zeroed at the
-    row's first grid step and written, cast once, to the (1, S, d)
-    output blocks at its last.  No partial sum goes through HBM.  The
-    other orientation (K tile fixed, dq resident as [group, S, d]) read
-    1.093 ms a call where this one reads 1.025, and 13.08 against 12.00
-    at 8192 keys with grouped heads (chip runs of PR 29).
+    One kernel (call site ``flash_bwd_dkdv``, which here covers dq, dk
+    AND dv), each needed tile's P and dS formed once, in the form
+    ``backward_form`` reads from the shape; no partial sum goes through
+    HBM in either.
+    ``"dkdv_resident"`` (grid z, nq, nk), wherever
+    ``_fused_bwd_vmem_bytes`` fits ``_FUSED_BWD_VMEM_LIMIT``: Q tile
+    fixed, K tiles stream.  dq accumulates as [d, bq], turned once at
+    the flush; dk and dv accumulate in two [S, d] float32 scratch
+    buffers that live for a whole kv row — under GQA the group's query
+    heads are consecutive z and fold into them — zeroed at the row's
+    first grid step and written, cast once, to the (1, S, d) output
+    blocks at its last.
+    ``"dq_resident"`` (grid z_kv, nk, nq*group), where
+    ``_dq_resident_bwd_vmem_bytes`` fits instead: K tile fixed,
+    (q-head-in-group, Q tile) pairs stream; dk and dv of the tile
+    accumulate as [bk, d] and flush at the last pair, dq of the kv row's
+    query heads as [group*nq, d, bq], zeroed in the first K tile's
+    sweep and written, turned and cast, in the last's (dq's block index
+    stays put until then, so each block goes to HBM once).  It adds the
+    float32 terms in the order the two passes add them.  Per call at
+    128 x 1024 x 64 this orientation read 1.093 ms where the other reads
+    1.025, and 13.08 against 12.00 at 8192 keys with grouped heads (chip
+    runs of PR 29: dq there as a whole-row output block); at 20 x 8192 x
+    256, where the other does not fit, 14.49 ms against the two passes'
+    21.21, the same stating 16, 24 or 32 MiB (chip runs of PR 38).
 
-    Two passes above that limit, each recomputing P and dS, VMEM
+    Two passes above both limits, each recomputing P and dS, VMEM
     independent of S:
-    Pass A (grid z_kv, nk, nq*group; ``flash_bwd_dkdv``): K tile fixed,
-    (q-head-in-group, Q tile) pairs stream sequentially; dk/dv
-    accumulate in VMEM scratch — under GQA the whole group's
-    contribution folds into one kv row — and flush at the last pair.
+    Pass A (grid z_kv, nk, nq*group; ``flash_bwd_dkdv``): the
+    K-outermost kernel without dq.
     Pass B (grid z, nq, nk; ``flash_bwd_dq``): Q tile fixed, K tiles
     stream; dq accumulates (as [d, bq], turned once at the flush).
     """
@@ -378,6 +434,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
     group = h // hkv
     nq, nk = s // bq, s // bk
     f32 = jnp.float32
+    form = backward_form(s, d, group, q.dtype.itemsize, bq, bk)
+    with_dq = form == "dq_resident"   # the K-outermost kernel takes dq too
     # delta is computed once per call and shared by all kernels, which
     # read it and lse as (1, bq) rows of a [Z, nq, 1, bq] view (a block
     # equal to the last two dims is legal for any bq).  What the chip
@@ -426,8 +484,14 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
         ds = p * (dp - delta_ref[0, 0])
         return qb, kb, dob, p, ds
 
-    def kernel_dkdv(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc):
+    def kernel_k_outer(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       *refs):
+        """K tile fixed, (q head in group, Q tile) pairs stream: dk and dv
+        of the tile, and ``with_dq`` dq too, from the same p and ds."""
+        if with_dq:
+            dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
+        else:
+            dk_ref, dv_ref, dk_acc, dv_acc = refs
         j = pl.program_id(1)
         t = pl.program_id(2)          # (q head in group) * nq + (q tile)
         i = t % nq
@@ -437,19 +501,33 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
             dk_acc[...] = jnp.zeros_like(dk_acc)
             dv_acc[...] = jnp.zeros_like(dv_acc)
 
+        if with_dq:
+            @pl.when(j == 0)
+            def _init_dq():
+                dq_acc[t] = jnp.zeros((d, bq), f32)
+
         @pl.when(_tile_needed(i, j))
         def _compute():
-            qb, _, dob, p, ds = _recompute_p_ds(
+            qb, kb, dob, p, ds = _recompute_p_ds(
                 q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, j
             )
             dv_acc[...] += jnp.dot(p, dob, preferred_element_type=f32)
             dk_acc[...] += jnp.dot(ds, qb,
                                    preferred_element_type=f32) * scale
+            if with_dq:
+                dq_acc[t] += lax.dot_general(
+                    kb, ds, _CONTRACT_ROWS, preferred_element_type=f32,
+                ) * scale                               # [d, bq]
 
         @pl.when(t == nq * group - 1)
         def _flush():
             dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+        if with_dq:
+            @pl.when(j == nk - 1)
+            def _flush_dq():
+                dq_ref[0] = dq_acc[t].T.astype(dq_ref.dtype)
 
     def kernel_dq(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                   dq_ref, dq_acc):
@@ -517,8 +595,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
     qkv_spec = lambda tile, which: pl.BlockSpec((1, tile, d), which)
     stat_spec = lambda which: pl.BlockSpec((1, 1, 1, bq), which)
 
-    if (_fused_bwd_vmem_bytes(s, d, bq, bk, q.dtype.itemsize)
-            <= _FUSED_BWD_VMEM_LIMIT):
+    if form == "dkdv_resident":
         q_tile = lambda zi, ii, ji: (zi, ii, 0)
         q_stat = lambda zi, ii, ji: (zi, ii, 0, 0)
         kv_tile = lambda zi, ii, ji: (_kv_row(zi, h, hkv), ji, 0)
@@ -559,38 +636,55 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
         )(q, k, v, do, lse_r, delta_r)
 
     def _qrow(zi, ti):
-        """Pass-A q row for kv row ``zi`` and inner step ``ti``."""
+        """The K-outermost q row for kv row ``zi`` and inner step ``ti``."""
         return (zi // hkv) * h + (zi % hkv) * group + ti // nq
 
-    dk, dv = pl.pallas_call(
-        kernel_dkdv,
+    q_tile = lambda zi, ji, ti: (_qrow(zi, ti), ti % nq, 0)
+    q_stat = lambda zi, ji, ti: (_qrow(zi, ti), ti % nq, 0, 0)
+    kv_tile = lambda zi, ji, ti: (zi, ji, 0)
+
+    def dq_tile(zi, ji, ti):
+        # dq's block index moves only in the last K tile's sweep, where
+        # the kernel writes it: each block goes to HBM once
+        last = jnp.where(ji == nk - 1, ti, 0)
+        return (_qrow(zi, last), last % nq, 0)
+
+    out_specs = [qkv_spec(bk, kv_tile), qkv_spec(bk, kv_tile)]
+    out_shape = [jax.ShapeDtypeStruct((z_kv, s, d), k.dtype),
+                 jax.ShapeDtypeStruct((z_kv, s, d), v.dtype)]
+    scratch_shapes = [pltpu.VMEM((bk, d), f32), pltpu.VMEM((bk, d), f32)]
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+    )
+    if with_dq:
+        out_specs.append(qkv_spec(bq, dq_tile))
+        out_shape.append(jax.ShapeDtypeStruct((z, s, d), q.dtype))
+        scratch_shapes.append(pltpu.VMEM((nq * group, d, bq), f32))
+        compiler_params = pltpu.CompilerParams(
+            # dq accumulates across the K tiles too
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_FUSED_BWD_VMEM_LIMIT,
+        )
+    dk, dv, *dq = pl.pallas_call(
+        kernel_k_outer,
         grid=(z_kv, nk, nq * group),
         in_specs=[
-            qkv_spec(bq, lambda zi, ji, ti: (_qrow(zi, ti), ti % nq, 0)),
-            qkv_spec(bk, lambda zi, ji, ti: (zi, ji, 0)),   # k
-            qkv_spec(bk, lambda zi, ji, ti: (zi, ji, 0)),   # v
-            qkv_spec(bq, lambda zi, ji, ti: (_qrow(zi, ti), ti % nq, 0)),
-            stat_spec(lambda zi, ji, ti: (_qrow(zi, ti), ti % nq, 0, 0)),
-            stat_spec(lambda zi, ji, ti: (_qrow(zi, ti), ti % nq, 0, 0)),
+            qkv_spec(bq, q_tile),
+            qkv_spec(bk, kv_tile),
+            qkv_spec(bk, kv_tile),
+            qkv_spec(bq, q_tile),       # do
+            stat_spec(q_stat),          # lse
+            stat_spec(q_stat),          # delta
         ],
-        out_specs=[
-            qkv_spec(bk, lambda zi, ji, ti: (zi, ji, 0)),
-            qkv_spec(bk, lambda zi, ji, ti: (zi, ji, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((z_kv, s, d), k.dtype),
-            jax.ShapeDtypeStruct((z_kv, s, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), f32),
-            pltpu.VMEM((bk, d), f32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch_shapes,
+        compiler_params=compiler_params,
         interpret=interpret,
         name="flash_bwd_dkdv",
     )(q, k, v, do, lse_r, delta_r)
+    if with_dq:
+        return dq[0], dk, dv
     (dq,) = pl.pallas_call(
         kernel_dq,
         grid=(z, nq, nk),

@@ -605,6 +605,7 @@ _BWD_PATH_CASES = [
 ]
 
 
+@pytest.mark.parametrize("form", ["dkdv_resident", "dq_resident"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize(
@@ -612,13 +613,15 @@ _BWD_PATH_CASES = [
     ids=[c[0] for c in _BWD_PATH_CASES],
 )
 def test_one_kernel_backward_matches_two_passes_and_oracle(
-        monkeypatch, causal, window, h, hkv, s, bq, bk, scale, dtype):
+        monkeypatch, causal, window, h, hkv, s, bq, bk, scale, dtype, form):
     """The backward as one kernel (dq, dk and dv from one p and ds a
-    tile, a kv row's accumulators resident) against the two passes it
+    tile), in both its forms (a kv row's dk and dv accumulators resident
+    under the Q tiles; a kv row's dq resident under the K tiles, which is
+    what 8192 keys at head size 256 take), against the two passes it
     replaced and against the blockwise scan.  One kernel and two passes
     add the same float32 terms in the same order (a dk row block gets
-    its terms by query head, then Q tile, in both), so they agree to the
-    bit; the scan sums in another order."""
+    its terms by query head, then Q tile, a dq block by K tile, in all
+    three), so they agree to the bit; the scan sums in another order."""
     from horovod_tpu.ops import flash_attention as fa
 
     b, d = 2, 16
@@ -634,8 +637,12 @@ def test_one_kernel_backward_matches_two_passes_and_oracle(
         return list(_pallas_calls(
             jax.make_jaxpr(lambda: fa._flash_bwd_pallas(*args))().jaxpr))
 
-    one = fa._flash_bwd_pallas(*args)
-    assert kernels() == ["flash_bwd_dkdv"]
+    assert fa.backward_form(s, d, h // hkv, q.dtype.itemsize, bq,
+                            bk) == "dkdv_resident"
+    with monkeypatch.context() as forced:
+        forced.setattr(fa, "backward_form", lambda *a: form)
+        one = fa._flash_bwd_pallas(*args)
+        assert kernels() == ["flash_bwd_dkdv"]
     monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", 0)
     two = fa._flash_bwd_pallas(*args)
     assert kernels() == ["flash_bwd_dkdv", "flash_bwd_dq"]
@@ -654,54 +661,65 @@ def test_one_kernel_backward_matches_two_passes_and_oracle(
                 f"{name}, {which}: {err:.3g} of the largest entry")
 
 
-def _pallas_calls(jaxpr):
+def _pallas_calls(jaxpr, what=lambda params: params["name"]):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            yield eqn.params["name"]
+            yield what(eqn.params)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _pallas_calls(sub)
+            yield from _pallas_calls(sub, what)
 
 
-# (id, q shape [B,S,H,D], kv heads, dtype, scale, the backward's kernels)
+# (id, q shape [B,S,H,D], kv heads, dtype, scale, the backward's form)
 _ONE_KERNEL = ["flash_bwd_dkdv"]
 _TWO_PASSES = ["flash_bwd_dkdv", "flash_bwd_dq"]
 _GATE_CASES = [
     ("gpt2m_train_8x1024x16x64", (8, 1024, 16, 64), 16, jnp.bfloat16, None,
-     _ONE_KERNEL),
+     "dkdv_resident"),
     ("granite4hm_1x8192x32on8x64", (1, 8192, 32, 64), 8, jnp.bfloat16,
-     0.015625, _ONE_KERNEL),
-    ("longest_fused_8192x128", (1, 8192, 4, 128), 2, jnp.bfloat16, None,
-     _ONE_KERNEL),
-    ("over_the_budget_16384x64", (1, 16384, 4, 64), 4, jnp.bfloat16, None,
-     _TWO_PASSES),
+     0.015625, "dkdv_resident"),
+    ("trinitym_1x8192x32on4x128", (1, 8192, 32, 128), 4, jnp.bfloat16, None,
+     "dkdv_resident"),
+    ("longest_kv_row_8192x128", (1, 8192, 4, 128), 2, jnp.bfloat16, None,
+     "dkdv_resident"),
+    # past a kv row's dk and dv, the row's dq: 4 MiB at head size 64
+    ("dq_fits_at_16384x64", (1, 16384, 4, 64), 4, jnp.bfloat16, None,
+     "dq_resident"),
     ("over_the_budget_131072x128", (1, 131072, 4, 128), 2, jnp.bfloat16,
-     None, _TWO_PASSES),
+     None, "two_passes"),
     ("float32_8192x64_fits", (1, 8192, 2, 64), 2, jnp.float32, None,
-     _ONE_KERNEL),
-    ("float32_16384x64_does_not", (1, 16384, 2, 64), 2, jnp.float32, None,
-     _TWO_PASSES),
+     "dkdv_resident"),
+    ("float32_16384x64_dq_fits", (1, 16384, 2, 64), 2, jnp.float32, None,
+     "dq_resident"),
     # head size 256 (latent attention: 192 + 64 query and key channels,
-    # values of 256): a kv row's accumulators are four times head size
-    # 64's, so the one kernel ends at 4096 keys and glm47f_train_s8192
-    # takes the two passes
+    # values of 256): a kv row's dk and dv accumulators are four times
+    # head size 64's and end at 4096 keys; glm47f_train_s8192's 8192 keep
+    # dq resident (8 MiB), which ends at 26624 keys
     ("head_256_4096_keys_fit", (1, 4096, 20, 256), 20, jnp.bfloat16, None,
-     _ONE_KERNEL),
+     "dkdv_resident"),
     ("glm47f_1x8192x20x256", (1, 8192, 20, 256), 20, jnp.bfloat16, None,
-     _TWO_PASSES),
+     "dq_resident"),
+    ("head_256_longest_dq_26624", (1, 26624, 2, 256), 2, jnp.bfloat16, None,
+     "dq_resident"),
+    ("head_256_first_two_passes_27136", (1, 27136, 2, 256), 2, jnp.bfloat16,
+     None, "two_passes"),
+    ("head_256_grouped_8_on_1_8192", (1, 8192, 8, 256), 1, jnp.bfloat16,
+     None, "two_passes"),
 ]
 
 
 @pytest.mark.parametrize(
-    "shape,kv_heads,dtype,scale,backward", [c[1:] for c in _GATE_CASES],
+    "shape,kv_heads,dtype,scale,form", [c[1:] for c in _GATE_CASES],
     ids=[c[0] for c in _GATE_CASES],
 )
-def test_backward_path_follows_the_shape(shape, kv_heads, dtype, scale,
-                                         backward):
+def test_backward_path_follows_the_shape(shape, kv_heads, dtype, scale, form):
     """Which backward runs is read from the ``pallas_call`` names in the
     differentiated jaxpr, as a device trace would read it: one kernel
-    (under the name ``flash_bwd_dkdv``) at both benchmark shapes and up
-    to the VMEM the call states, ``flash_bwd_dq`` beside it only above."""
-    b, s, _, d = shape
+    (under the name ``flash_bwd_dkdv``) at every benchmark shape and up
+    to the VMEM the call states, ``flash_bwd_dq`` beside it only above;
+    and ``backward_form``, which the gauges read, says the same."""
+    from horovod_tpu.ops.flash_attention import backward_form
+
+    b, s, h, d = shape
     q = jax.ShapeDtypeStruct(shape, dtype)
     kv = jax.ShapeDtypeStruct((b, s, kv_heads, d), dtype)
 
@@ -709,21 +727,83 @@ def test_backward_path_follows_the_shape(shape, kv_heads, dtype, scale,
         return flash_attention(q, k, v, causal=True, scale=scale,
                                interpret=True).astype(jnp.float32).sum()
 
+    assert backward_form(s, d, h // kv_heads,
+                         jnp.dtype(dtype).itemsize) == form
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv)
-    assert list(_pallas_calls(jaxpr.jaxpr)) == ["flash_fwd"] + backward
+    assert list(_pallas_calls(jaxpr.jaxpr)) == ["flash_fwd"] + (
+        _TWO_PASSES if form == "two_passes" else _ONE_KERNEL)
 
 
-@pytest.mark.parametrize("backward", ["one_kernel", "two_passes"])
-def test_head_size_256_matches_the_plain_attention(monkeypatch, backward):
-    """Twice the head size of any older case and four times a GPT cell's
-    (``mla_mixer`` hands the kernels q, k, v of ``[b, s, 20, 256]``):
-    the forward and the three gradients against ``local_attention``,
-    through the one-kernel backward and through the two passes, which is
-    what 8192 keys take at this head size."""
+# (form, gauge flash.bwd_kernels, gauge flash.bwd_dq_resident, the
+# backward's names and grids in a layer) at 64 keys of 16 channels in
+# float32 and 32 x 16 tiles
+_GAUGE_CASES = [
+    ("dkdv_resident", 1, 0, [("flash_bwd_dkdv", (8, 2, 4))]),
+    ("dq_resident", 1, 1, [("flash_bwd_dkdv", (8, 4, 2))]),
+    ("two_passes", 2, 0, [("flash_bwd_dkdv", (8, 4, 2)),
+                          ("flash_bwd_dq", (8, 2, 4))]),
+]
+
+
+@pytest.mark.parametrize("form,kernels,dq_resident,backward",
+                         _GAUGE_CASES, ids=[c[0] for c in _GAUGE_CASES])
+def test_the_gauges_say_which_backward_the_step_holds(
+        monkeypatch, form, kernels, dq_resident, backward):
+    """``flash.bwd_kernels`` and ``flash.bwd_dq_resident``, set while a
+    two-layer model is traced, against the ``pallas_call`` names and
+    grids of its differentiated jaxpr, at a shape on each side of both
+    gates (the limit patched to what the form holds, or to nothing):
+    gauge and kernel read one function, ``backward_form``."""
+    from horovod_tpu.obs.registry import get_registry
+    from horovod_tpu.ops import flash_attention as fa
+
+    limit = {"dkdv_resident": fa._FUSED_BWD_VMEM_LIMIT,
+             "dq_resident": fa._dq_resident_bwd_vmem_bytes(
+                 64, 16, 32, 16, 4, 1),
+             "two_passes": 0}[form]
+    monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", limit)
+    assert fa.backward_form(64, 16, 1, 4, 32, 16) == form
+    model = gpt("nano", num_layers=2, num_heads=4, emb_dim=64,
+                vocab_size=512, max_len=64, dtype=jnp.float32,
+                flash_block_q=32, flash_block_k=16)
+    toks = jnp.asarray(
+        np.random.RandomState(3).randint(0, 512, (2, 64)), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), toks)
+    registry = get_registry()
+    for name in ("flash.bwd_kernels", "flash.bwd_dq_resident"):
+        registry.gauge(name, layer_type="attention").set(-1)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: model.apply(p, toks).sum()))(params)
+    calls = list(_pallas_calls(
+        jaxpr.jaxpr, lambda p: (p["name"], tuple(p["grid_mapping"].grid))))
+    assert calls == [("flash_fwd", (8, 2, 4))] * 2 + backward * 2
+    assert registry.gauge("flash.bwd_kernels",
+                          layer_type="attention").value == kernels
+    assert registry.gauge("flash.bwd_dq_resident",
+                          layer_type="attention").value == dq_resident
+    assert sum(name != "flash_fwd" for name, _ in calls) == 2 * kernels
+
+
+def _force(monkeypatch, backward):
+    """Take the named backward whatever the shape says."""
     from horovod_tpu.ops import flash_attention as fa
 
     if backward == "two_passes":
         monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", 0)
+    elif backward == "dq_resident":
+        monkeypatch.setattr(fa, "backward_form", lambda *a: backward)
+    return _TWO_PASSES if backward == "two_passes" else _ONE_KERNEL
+
+
+@pytest.mark.parametrize("backward",
+                         ["one_kernel", "dq_resident", "two_passes"])
+def test_head_size_256_matches_the_plain_attention(monkeypatch, backward):
+    """Twice the head size of any older case and four times a GPT cell's
+    (``mla_mixer`` hands the kernels q, k, v of ``[b, s, 20, 256]``):
+    the forward and the three gradients against ``local_attention``,
+    through the one-kernel backward in both its forms (the second is
+    what 8192 keys take at this head size) and through the two passes."""
+    names = _force(monkeypatch, backward)
     q, k, v = _qkv(b=1, s=128, h=2, d=256, seed=5)
     weight = jnp.asarray(np.random.RandomState(6).randn(*q.shape),
                          jnp.float32)
@@ -736,8 +816,7 @@ def test_head_size_256_matches_the_plain_attention(monkeypatch, backward):
 
     kernels = list(_pallas_calls(jax.make_jaxpr(jax.grad(
         lambda *a: flash(*a).sum(), argnums=(0, 1, 2)))(q, k, v).jaxpr))
-    assert kernels == ["flash_fwd"] + (
-        _ONE_KERNEL if backward == "one_kernel" else _TWO_PASSES)
+    assert kernels == ["flash_fwd"] + names
     np.testing.assert_allclose(flash(q, k, v), plain(q, k, v), atol=2e-5)
     got = jax.grad(lambda *a: (flash(*a) * weight).sum(),
                    argnums=(0, 1, 2))(q, k, v)
@@ -757,18 +836,17 @@ _WINDOW_CASES = [
 ]
 
 
-@pytest.mark.parametrize("backward", ["one_kernel", "two_passes"])
+@pytest.mark.parametrize("backward",
+                         ["one_kernel", "dq_resident", "two_passes"])
 @pytest.mark.parametrize("seq,window", [c[1:] for c in _WINDOW_CASES],
                          ids=[c[0] for c in _WINDOW_CASES])
 def test_window_at_grouped_heads_of_128(monkeypatch, backward, seq, window):
     """The banded kernels at 8 query heads a key/value head and head size
     128: the forward and the three gradients against the dense masked
-    oracle, through the one-kernel backward and through the two passes,
-    and ``local_attention(window=...)`` against the same oracle."""
-    from horovod_tpu.ops import flash_attention as fa
-
-    if backward == "two_passes":
-        monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", 0)
+    oracle, through the one-kernel backward in both its forms and
+    through the two passes, and ``local_attention(window=...)`` against
+    the same oracle."""
+    names = _force(monkeypatch, backward)
     rng = np.random.RandomState(7)
     mk = lambda heads: jnp.asarray(
         rng.randn(1, seq, heads, 128) * 0.5, jnp.float32)
@@ -790,8 +868,7 @@ def test_window_at_grouped_heads_of_128(monkeypatch, backward, seq, window):
 
     kernels = list(_pallas_calls(jax.make_jaxpr(jax.grad(
         lambda *a: flash(*a).sum(), argnums=(0, 1, 2)))(q, k, v).jaxpr))
-    assert kernels == ["flash_fwd"] + (
-        _ONE_KERNEL if backward == "one_kernel" else _TWO_PASSES)
+    assert kernels == ["flash_fwd"] + names
     np.testing.assert_allclose(flash(q, k, v), oracle(q, k, v), atol=2e-5)
     np.testing.assert_allclose(plain(q, k, v), oracle(q, k, v), atol=2e-5)
     # the band bites: the last row does not see key 0

@@ -211,18 +211,25 @@ def test_resnet50_parameter_tree_is_letter_for_letter_the_same():
     assert _paths(shapes) == sorted(want)
 
 
-@pytest.mark.parametrize("vmem_limit, names", [
-    (None, ["flash_bwd_dkdv", "flash_fwd"]),           # one backward kernel
-    (0, ["flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]),      # two passes
-], ids=["one_kernel", "two_passes"])
+@pytest.mark.parametrize("form, names", [
+    ("dkdv_resident", ["flash_bwd_dkdv", "flash_fwd"]),
+    # the one kernel with the K tile outermost, under the same name
+    ("dq_resident", ["flash_bwd_dkdv", "flash_fwd"]),
+    ("two_passes", ["flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]),
+], ids=["one_kernel", "one_kernel_dq_resident", "two_passes"])
 def test_named_flash_kernels_are_bitwise_the_unnamed_ones(
-        monkeypatch, vmem_limit, names):
+        monkeypatch, form, names):
     """A kernel's name is metadata: forward, dq, dk and dv in interpret
-    mode are bit for bit what the unnamed ``pallas_call`` gives, on both
-    backward paths."""
-    if vmem_limit is not None:
-        monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", vmem_limit)
-        jax.clear_caches()
+    mode are bit for bit what the unnamed ``pallas_call`` gives, on all
+    three backward paths (a limit that a kv row's dq fits and its dk and
+    dv do not gives the second, none the third)."""
+    limit = {"dkdv_resident": fa._FUSED_BWD_VMEM_LIMIT,
+             "dq_resident": fa._dq_resident_bwd_vmem_bytes(
+                 64, 16, 16, 16, 4, 1),
+             "two_passes": 0}[form]
+    monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", limit)
+    jax.clear_caches()
+    assert fa.backward_form(64, 16, 1, 4, 16, 16) == form
     rng = np.random.RandomState(3)
     q, k, v = (jnp.asarray(rng.randn(2, 64, 4, 16), jnp.float32) * 0.3
                for _ in range(3))

@@ -144,15 +144,26 @@ _BACKWARD_PATHS = [
      None, True),
     ("longest_fp32_4096x256_window", (1, 4096, 2, 256), 2, jnp.float32,
      None, 1024, True),
-    # as one kernel this one asks for 32.63 MiB
-    ("over_the_budget_16384x64", (1, 16384, 8, 64), 8, jnp.bfloat16, None,
-     None, False),
-    # latent attention's shape in glm47f_train_s8192: head size 256 keeps
-    # a kv row's accumulators at 32 MiB, so the two passes run
+    # with a kv row's dk and dv resident this one asks for 32.63 MiB; its
+    # dq is 4 MiB, so the K-outermost kernel takes it
+    ("dq_resident_16384x64", (1, 16384, 8, 64), 8, jnp.bfloat16, None,
+     None, True),
+    # latent attention's shape in glm47f_train_s8192: head size 256 puts
+    # a kv row's dk and dv accumulators at 32 MiB and its dq at 8
     ("glm47f_1x8192x20x256", (1, 8192, 20, 256), 20, jnp.bfloat16, None,
-     None, False),
+     None, True),
     ("head_256_fits_4096_keys", (1, 4096, 20, 256), 20, jnp.bfloat16, None,
      None, True),
+    # the longest rows whose dq the gate admits, counted to 32 MiB exactly
+    # and to 31.5, and the first shapes past them
+    ("longest_dq_26624x256", (1, 26624, 2, 256), 2, jnp.bfloat16, None,
+     None, True),
+    ("longest_fp32_dq_24064x256_window", (1, 24064, 2, 256), 2, jnp.float32,
+     None, 1024, True),
+    ("two_passes_27136x256", (1, 27136, 2, 256), 2, jnp.bfloat16, None,
+     None, False),
+    ("two_passes_8_on_1_8192x256", (1, 8192, 8, 256), 1, jnp.bfloat16, None,
+     None, False),
 ]
 
 
@@ -164,9 +175,9 @@ def test_flash_backward_path_compiles_for_v5e(one_chip, shape, kv_heads,
                                               dtype, scale, window,
                                               one_kernel):
     """The backward as ONE kernel (under the name ``flash_bwd_dkdv``, no
-    ``flash_bwd_dq`` beside it) at both benchmark shapes and at the
-    longest rows the shape gate admits, compiled inside the
-    ``vmem_limit_bytes`` the call states (the TPU compiler refuses a
+    ``flash_bwd_dq`` beside it) at every benchmark shape and at the
+    longest rows the shape gate admits in either form, compiled inside
+    the ``vmem_limit_bytes`` the call states (the TPU compiler refuses a
     kernel that needs more); the two passes above the budget."""
     b, s, _, d = shape
     q = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
