@@ -31,6 +31,7 @@ TPU-first choices:
 from __future__ import annotations
 
 import contextlib
+import math
 from dataclasses import dataclass, field, replace
 from typing import Any, Optional
 
@@ -42,12 +43,17 @@ from .. import scopes
 from ..parallel.moe import DEFAULT_GROUP_SIZE as MOE_DEFAULT_GROUP_SIZE
 
 
-# What ``layer_types`` may name: one mixer per layer.  The last two are
-# attention layers told apart by their mask (and, where
-# ``rope_layer_types`` says so, by their positions).
+# What ``layer_types`` may name: one mixer per layer.
+# ``sliding_attention`` and ``full_attention`` are attention layers told
+# apart by their mask (and, where ``rope_layer_types`` says so, by their
+# positions); ``cross_attention`` makes queries only and reads the keys
+# and values of the layer ``shared_kv_layer`` names.  ``mamba`` is the
+# Mamba-2 mixer, ``selective_scan`` the Mamba-1 mixer, and ``gmu`` a
+# gated memory unit: a gate on the scan output of the layer
+# ``memory_layer`` names.
 ATTENTION_LAYER_TYPES = ("attention", "mla", "sliding_attention",
-                         "full_attention")
-LAYER_TYPES = ATTENTION_LAYER_TYPES + ("mamba",)
+                         "full_attention", "cross_attention")
+LAYER_TYPES = ATTENTION_LAYER_TYPES + ("mamba", "selective_scan", "gmu")
 
 
 @dataclass(frozen=True)
@@ -195,6 +201,36 @@ class TransformerConfig:
     # A norm on each branch's OUTPUT before the residual add, beside the
     # two on its input: four norms a block.
     post_norms: bool = False
+    # The feed-forward's biases where they differ from the attention
+    # projections' (None = use_bias).
+    mlp_bias: Optional[bool] = None
+    # Differential attention (arXiv:2410.05258) in every attention layer:
+    # query and key heads pair up (even, odd), a pair's two softmax maps
+    # read the pair's values (twice a head wide) and the second is
+    # subtracted lambda times; an RMSNorm over the pair's 2 * head_dim
+    # channels, then (1 - lambda_init).  lambda = exp(lq1 . lk1) -
+    # exp(lq2 . lk2) + lambda_init from four learned vectors a layer,
+    # lambda_init = 0.8 - 0.6 exp(-0.3 i) with i the layer's index in
+    # the whole model: first_layer_index + its place here, so that a
+    # slice of a model keeps its layers' own.
+    differential_attention: bool = False
+    first_layer_index: int = 0
+    # Values that cross layers beside the residual stream.  Every
+    # "cross_attention" layer reads the keys and values that layer
+    # shared_kv_layer (an attention layer before them) made for itself;
+    # every "gmu" layer reads the scan output, before its gate, of layer
+    # memory_layer (a "selective_scan" layer before them).  The block
+    # that makes a value returns it beside x and the readers take it as
+    # an argument (GPT.__call__), through jax.checkpoint where the
+    # blocks are rematerialised; its gradient is the sum over readers.
+    shared_kv_layer: Optional[int] = None
+    memory_layer: Optional[int] = None
+    # The Mamba-1 mixer (layer type "selective_scan", ops/
+    # selective_scan.py): ssm_width inner channels (the gated memory
+    # units' width too), each with a state of ssm_state, a causal
+    # depthwise conv of ssm_conv taps, dt through a rank of ssm_dt_rank.
+    ssm_width: int = 0
+    ssm_dt_rank: int = 0
 
     def __post_init__(self):
         if self.num_kv_heads is not None:
@@ -233,6 +269,17 @@ class TransformerConfig:
                 raise ValueError(
                     f"a 'mamba' layer needs ssm_heads={self.ssm_heads} to be "
                     f"a positive multiple of ssm_groups={self.ssm_groups}")
+            if "selective_scan" in self.layer_types and min(
+                    self.ssm_width, self.ssm_dt_rank, self.ssm_state) <= 0:
+                raise ValueError(
+                    f"a 'selective_scan' layer needs positive ssm_width="
+                    f"{self.ssm_width}, ssm_dt_rank={self.ssm_dt_rank} and "
+                    f"ssm_state={self.ssm_state}")
+            self._check_handed_on(
+                "cross_attention", "shared_kv_layer",
+                ("attention", "sliding_attention", "full_attention"))
+            self._check_handed_on("gmu", "memory_layer",
+                                  ("selective_scan",))
             if "mla" in self.layer_types:
                 sizes = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                          "qk_rope_head_dim", "v_head_dim")
@@ -263,6 +310,22 @@ class TransformerConfig:
                     f"0 < routed_top_k <= routed_experts, a routed_width "
                     f"and held experts {self.routed_first_held}.."
                     f"{self.routed_first_held + held - 1} among them")
+        if self.layer_types is None and (
+                self.shared_kv_layer is not None
+                or self.memory_layer is not None):
+            raise ValueError(
+                "shared_kv_layer and memory_layer name a layer of "
+                "layer_types: layer_types must be set")
+        if self.differential_attention:
+            if self.num_heads % 2 or self.kv_heads % 2:
+                raise ValueError(
+                    f"differential attention pairs its heads: num_heads="
+                    f"{self.num_heads} and kv heads={self.kv_heads} must "
+                    f"be even")
+            if "mla" in (self.layer_types or ()):
+                raise ValueError(
+                    "differential attention is implemented for the "
+                    "attention layers that split one qkv, not for 'mla'")
         if self.mtp_modules not in (0, 1):
             raise ValueError(
                 f"mtp_modules={self.mtp_modules}: one prediction module is "
@@ -287,6 +350,34 @@ class TransformerConfig:
                     "an 'mla' layer rotates its rotary channels: "
                     "rope_layer_types must name 'mla'")
 
+    def _check_handed_on(self, reader: str, setting: str, makers: tuple):
+        """Layers of type ``reader`` need ``setting`` to name an earlier
+        layer of one of the types ``makers``; without readers it stays
+        unset."""
+        at = getattr(self, setting)
+        readers = [i for i, kind in enumerate(self.layer_types)
+                   if kind == reader]
+        if not readers and at is None:
+            return
+        if not readers or at is None or not (
+                0 <= at < min(readers) and self.layer_types[at] in makers):
+            raise ValueError(
+                f"a {reader!r} layer reads what layer {setting} made: "
+                f"{setting}={at!r} must name a layer of type "
+                f"{' or '.join(makers)} before the first of them, and be "
+                f"None where there is none (layer_types="
+                f"{self.layer_types!r})")
+
+    def hands_on(self, i: int) -> Optional[str]:
+        """What layer ``i`` returns beside the stream for later layers:
+        ``"kv"``, ``"memory"`` or nothing."""
+        return ("kv" if i == self.shared_kv_layer
+                else "memory" if i == self.memory_layer else None)
+
+    @property
+    def ffn_bias(self) -> bool:
+        return self.use_bias if self.mlp_bias is None else self.mlp_bias
+
     @property
     def head_dim(self) -> int:
         return (self.head_size if self.head_size is not None
@@ -303,7 +394,7 @@ class TransformerConfig:
     def window_of(self, layer_type: Optional[str]) -> Optional[int]:
         """The keys a layer of this type sees beside the causal mask:
         its last ``attention_window``, or ``None`` for all of them."""
-        return (None if layer_type == "full_attention"
+        return (None if layer_type in ("full_attention", "cross_attention")
                 else self.attention_window)
 
     def rotates(self, layer_type: str) -> bool:
@@ -351,7 +442,8 @@ def require_gpt2_block(cfg: TransformerConfig, who: str) -> None:
                     "embedding_multiplier", "residual_multiplier",
                     "logits_scaling", "attention_scale", "head_size",
                     "rope_layer_types", "qk_norm", "attention_gate",
-                    "post_norms"):
+                    "post_norms", "mlp_bias", "differential_attention",
+                    "first_layer_index", "shared_kv_layer", "memory_layer"):
         if getattr(cfg, setting) != getattr(gpt2, setting):
             raise ValueError(
                 f"{who} implements GPT-2's block only "
@@ -370,11 +462,49 @@ def _attend(cfg: TransformerConfig, q, k, v, positions,
     used by schedules that mask in global coordinates.  ``layer_type``
     decides the window (``cfg.window_of``); a call that has one traces
     under the scope ``attn_window``, so a device trace tells the banded
-    kernels from the full ones."""
+    kernels from the full ones, and the call of a layer that reads
+    another layer's keys and values under ``attn_cross``."""
     window = cfg.window_of(layer_type)
     with (jax.named_scope(scopes.ATTN_WINDOW) if window is not None
-          else contextlib.nullcontext()):
+          else jax.named_scope(scopes.ATTN_CROSS)
+          if layer_type == "cross_attention" else contextlib.nullcontext()):
         return _attend_schedule(cfg, q, k, v, positions, layer_type, window)
+
+
+def differential_lambda_init(layer_index: int) -> float:
+    """``0.8 - 0.6 exp(-0.3 i)``, ``i`` the layer's index in the whole
+    model (arXiv:2410.05258, section 2.1)."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer_index)
+
+
+def _attend_differential(cfg: TransformerConfig, q, k, v, positions,
+                         layer_type, *, lambdas, subln, lambda_init):
+    """Differential attention over ``q`` [b, s, heads, hd] and ``k``,
+    ``v`` [b, s, kv heads, hd].  Sub-heads pair up (even, odd): pair
+    ``p``'s two maps ``softmax(q1 k1^T)`` and ``softmax(q2 k2^T)`` each
+    read the pair's values ``[v_even ; v_odd]``, 2 hd wide.  The
+    schedules take one head size, so the four products (two maps by two
+    halves of the values) are ONE call over four times the pairs: each
+    score map is computed twice (a kernel with values twice as wide as
+    keys would compute it once).  ``lambdas`` are the four learned
+    vectors, ``subln`` the norm over a pair's 2 hd channels.  Returns
+    [b, s, heads // 2, 2 hd]."""
+    b, s, nh, hd = q.shape
+    halves = lambda t: (t[:, :, 0::2], t[:, :, 1::2])
+    (q1, q2), (k1, k2), (v1, v2) = halves(q), halves(k), halves(v)
+    out = _attend(cfg, jnp.concatenate([q1, q1, q2, q2], axis=2),
+                  jnp.concatenate([k1, k1, k2, k2], axis=2),
+                  jnp.concatenate([v1, v2, v1, v2], axis=2),
+                  positions, layer_type)
+    out = out.reshape(b, s, 4, nh // 2, hd)
+    with jax.named_scope(scopes.ATTN_DIFF):
+        first = jnp.concatenate([out[:, :, 0], out[:, :, 1]], axis=-1)
+        second = jnp.concatenate([out[:, :, 2], out[:, :, 3]], axis=-1)
+        lq1, lk1, lq2, lk2 = (t.astype(jnp.float32) for t in lambdas)
+        lam = (jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2))
+               + lambda_init)
+        att = first.astype(jnp.float32) - lam * second.astype(jnp.float32)
+        return (subln(att) * (1.0 - lambda_init)).astype(q.dtype)
 
 
 def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
@@ -472,6 +602,15 @@ def act_store(y, cfg: TransformerConfig):
     return jnp.asarray(jnp.asarray(y, cfg.act_store_dtype), cfg.dtype)
 
 
+def causal_depthwise_conv(x, kernel, bias):
+    """``x`` [b, s, channels] through a depthwise conv of ``kernel``
+    [taps, channels] and ``bias``, float32: output ``t`` reads inputs
+    ``t-(taps-1) .. t``, zeros before the sequence."""
+    s, taps = x.shape[1], kernel.shape[0]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(padded[:, k:k + s] * kernel[k] for k in range(taps)) + bias
+
+
 def mamba_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
                 conv_bias, dt_bias, a_log, d_skip, norm_scale, out_proj):
     """The Mamba-2 mixer on the normed stream ``h`` [b, s, emb]: one
@@ -491,13 +630,8 @@ def mamba_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
     z = fused[..., :inner]
     xbc = fused[..., inner:2 * inner + 2 * bc]
     dt = fused[..., 2 * inner + 2 * bc:]
-    # output t reads inputs t-(taps-1) .. t, zeros before the sequence
-    taps = conv_kernel.shape[0]
-    padded = jnp.pad(xbc.astype(jnp.float32),
-                     ((0, 0), (taps - 1, 0), (0, 0)))
-    conv = sum(padded[:, k:k + s] * conv_kernel[k]
-               for k in range(taps)) + conv_bias
-    xbc = jax.nn.silu(conv).astype(fused.dtype)
+    xbc = jax.nn.silu(causal_depthwise_conv(
+        xbc, conv_kernel, conv_bias)).astype(fused.dtype)
     x = xbc[..., :inner].reshape(b, s, heads, cfg.ssm_head_dim)
     B = xbc[..., inner:inner + bc].reshape(b, s, cfg.ssm_groups,
                                            cfg.ssm_state)
@@ -510,6 +644,49 @@ def mamba_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
     normed = gated * jax.lax.rsqrt(
         jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + cfg.norm_eps)
     return out_proj(normed * norm_scale)
+
+
+def selective_scan_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
+                         conv_bias, x_proj, dt_proj, a_log, d_skip,
+                         out_proj):
+    """The Mamba-1 mixer on the normed stream ``h`` [b, s, emb]: one
+    projection to the conv's input ``u`` and the gate ``z``; a causal
+    depthwise conv and silu over ``u``; ``x_proj(u)`` gives a rank-
+    ``ssm_dt_rank`` vector, ``B`` and ``C``; ``dt = softplus(dt_proj(.))``
+    a channel; the selective scan (``ops/selective_scan.py``); the gate;
+    the output projection (no norm inside the mixer).  The projections
+    are callables like ``block_math``'s (``dt_proj`` returns float32 with
+    its bias), the rest raw arrays.  ``dt``, ``A`` and everything the
+    scan carries are float32.  Returns the residual delta and the scan's
+    output ``y`` (with its ``D u`` term, BEFORE the gate): the memory a
+    gated memory unit reads."""
+    from ..obs.registry import get_registry  # noqa: PLC0415
+    from ..ops.selective_scan import kept_mib, selective_scan  # noqa: PLC0415
+
+    b, s, _ = h.shape
+    inner, n, rank = cfg.ssm_width, cfg.ssm_state, cfg.ssm_dt_rank
+    fused = in_proj(h)
+    u, z = fused[..., :inner], fused[..., inner:]
+    u = jax.nn.silu(causal_depthwise_conv(
+        u, conv_kernel, conv_bias)).astype(fused.dtype)
+    low = x_proj(u)
+    dt = jax.nn.softplus(dt_proj(low[..., :rank]).astype(jnp.float32))
+    # counted while the step is traced: what one layer's scan keeps
+    get_registry().gauge("sscan.kept_mib").set(kept_mib(b, s, inner, n))
+    y = selective_scan(u, dt, -jnp.exp(a_log.astype(jnp.float32)),
+                       low[..., rank:rank + n], low[..., rank + n:], d_skip)
+    gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return out_proj(gated.astype(fused.dtype)), y
+
+
+def gmu_mixer(h, memory, *, in_proj, out_proj):
+    """A gated memory unit (arXiv:2507.06607) on the normed stream ``h``:
+    ``out_proj(silu(in_proj(h)) * memory)``, ``memory`` [b, s, width]
+    the scan output another layer handed on.  Returns the residual
+    delta."""
+    gate = in_proj(h)
+    gated = jax.nn.silu(gate.astype(jnp.float32)) * memory.astype(jnp.float32)
+    return out_proj(gated.astype(gate.dtype))
 
 
 def mla_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *, q_a,
@@ -554,7 +731,9 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
                num_kv_heads: Optional[int] = None,
                attend=None, layer_type: Optional[str] = None,
                q_norm=None, k_norm=None, gate=None,
-               post_attn_norm=None, post_mlp_norm=None):
+               post_attn_norm=None, post_mlp_norm=None,
+               gmu=None, differential=None, shared_kv=None,
+               hand_on: Optional[str] = None):
     """THE pre-norm block wiring — the single source of truth.
 
     ``norm → mixer → (+res) → norm → feed-forward → (+res)``, each
@@ -590,6 +769,17 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
     branch's output before its residual add.  ``layer_type`` gives the
     attention call its window (``cfg.window_of``); the caller hands in
     ``rope_tabs=None`` for a layer that sees no positions.
+
+    Values that cross layers: with ``hand_on="memory"`` the ``ssm``
+    callable returns ``(delta, scan output)``, with ``hand_on="kv"`` the
+    attention half's ``(k, v)`` as it attends them is handed on, and the
+    function returns ``(x, value)`` instead of ``x``.  A layer that
+    reads one gets it from its caller: ``gmu`` is a callable of the
+    normed stream that closes over the memory (scope ``gmu``);
+    ``shared_kv=(k, v)`` makes ``qkv`` a projection to the queries alone
+    (scope ``attn_cross`` around the attention call).  ``differential``
+    holds ``lambdas``, ``subln`` and ``lambda_init`` where the attention
+    is differential (:func:`_attend_differential`, scope ``attn_diff``).
     """
     b, s, _ = x.shape
     nh = num_heads if num_heads is not None else cfg.num_heads
@@ -609,9 +799,16 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
     # a device trace tells the mixer from the MLP whatever XLA names the
     # fusions.  A scope is metadata: it names no parameter, so the flax
     # tree stays ``block<i>/{ln1,qkv,proj,ln2,fc1,fc2}``.
+    handed = None
     if ssm is not None:
         with jax.named_scope(scopes.SSM):
-            x = add(x, act_store(ssm(ln1(x)), cfg), post_attn_norm)
+            delta = ssm(ln1(x))
+            if hand_on == "memory":
+                delta, handed = delta
+            x = add(x, act_store(delta, cfg), post_attn_norm)
+    elif gmu is not None:
+        with jax.named_scope(scopes.GMU):
+            x = add(x, act_store(gmu(ln1(x)), cfg), post_attn_norm)
     elif mla is not None:
         with jax.named_scope(scopes.ATTN):
             x = add(x, act_store(mla(ln1(x)), cfg), post_attn_norm)
@@ -620,8 +817,11 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
             h = ln1(x)
             fused = qkv(h)
             q = fused[..., :q_dim].reshape(b, s, nh, hd)
-            k = fused[..., q_dim:q_dim + kv_dim].reshape(b, s, nkv, hd)
-            v = fused[..., q_dim + kv_dim:].reshape(b, s, nkv, hd)
+            if shared_kv is not None:
+                k, v = shared_kv
+            else:
+                k = fused[..., q_dim:q_dim + kv_dim].reshape(b, s, nkv, hd)
+                v = fused[..., q_dim + kv_dim:].reshape(b, s, nkv, hd)
             if q_norm is not None:
                 q = q_norm(q).astype(fused.dtype)
             if k_norm is not None:
@@ -631,7 +831,12 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
 
                 q = apply_rope_tables(q, *rope_tabs)
                 k = apply_rope_tables(k, *rope_tabs)
-            if attend is None:
+            if hand_on == "kv":
+                handed = (k, v)
+            if differential is not None:
+                att_4d = _attend_differential(cfg, q, k, v, positions,
+                                              layer_type, **differential)
+            elif attend is None:
                 attend_cfg = cfg
                 if nh != cfg.num_heads or nkv != cfg.kv_heads:
                     # per-rank head shard: _attend sees the LOCAL head
@@ -650,7 +855,8 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
             att = act_store(att, cfg)
             x = add(x, act_store(proj(att), cfg), post_attn_norm)
     with jax.named_scope(scopes.MLP):
-        return add(x, act_store(mlp(ln2(x)), cfg), post_mlp_norm)
+        x = add(x, act_store(mlp(ln2(x)), cfg), post_mlp_norm)
+    return x if hand_on is None else (x, handed)
 
 
 def raw_layer_norm(x, scale, bias, eps: float = 1e-6):
@@ -726,34 +932,46 @@ class Block(nn.Module):
     """Pre-norm block: norm → mixer → +res, norm → MLP → +res.
 
     The wiring lives in :func:`block_math`; this module only declares
-    the flax parameters (the attention mixer's, the Mamba-2 mixer's or
-    latent attention's, by ``layer_type``; a dense feed-forward's or the
-    routed experts', by ``ffn``) and hands their applications in as
-    callables.
+    the flax parameters (the attention mixer's, a state-space mixer's, a
+    gated memory unit's or latent attention's, by ``layer_type``; a
+    dense feed-forward's or the routed experts', by ``ffn``) and hands
+    their applications in as callables.  ``hand_on`` says what the block
+    returns beside ``x`` for later layers (``cfg.hands_on``);
+    ``layer_index`` is the layer's index in the whole model.  A
+    ``cross_attention`` block is called with ``shared_kv``, a ``gmu``
+    block with ``memory``.
     """
 
     cfg: TransformerConfig
     layer_type: str = "attention"
     ffn: str = "dense"
+    hand_on: Optional[str] = None
+    layer_index: int = 0
 
     @nn.compact
-    def __call__(self, x, positions, rope_tabs=None):
+    def __call__(self, x, positions, rope_tabs=None, shared_kv=None,
+                 memory=None):
         cfg = self.cfg
         kv_dim = cfg.kv_heads * cfg.head_dim
         width = cfg.mlp_ratio * cfg.emb_dim
 
-        def dense(features, name):
-            return nn.Dense(features, dtype=cfg.dtype,
-                            use_bias=cfg.use_bias, name=name)
+        def dense(features, name, use_bias=cfg.use_bias):
+            return nn.Dense(features, dtype=cfg.dtype, use_bias=use_bias,
+                            name=name)
+
+        def unbiased(features, name):
+            return dense(features, name, use_bias=False)
 
         def feed_forward(h, wide, fc1, fc2):
             """The configuration's dense feed-forward, ``wide`` wide."""
+            layer = lambda features, name: dense(features, name,
+                                                 cfg.ffn_bias)
             if cfg.mlp == "silu_gated":
-                gate_up = dense(2 * wide, fc1)(h)
+                gate_up = layer(2 * wide, fc1)(h)
                 m = jax.nn.silu(gate_up[..., :wide]) * gate_up[..., wide:]
             else:
-                m = nn.gelu(dense(wide, fc1)(h))
-            return dense(cfg.emb_dim, fc2)(act_store(m, cfg))
+                m = nn.gelu(layer(wide, fc1)(h))
+            return layer(cfg.emb_dim, fc2)(act_store(m, cfg))
 
         def routed(h):
             """Routed experts that drop nothing, the shared expert
@@ -856,6 +1074,38 @@ class Block(nn.Module):
                 )
 
             mixer["ssm"] = ssm
+        elif self.layer_type == "selective_scan":
+            inner, n = cfg.ssm_width, cfg.ssm_state
+
+            def ssm(h):
+                delta, y = selective_scan_mixer(
+                    cfg, h,
+                    in_proj=unbiased(2 * inner, "in_proj"),
+                    conv_kernel=self.param(
+                        "conv_kernel", _conv_init, (cfg.ssm_conv, inner),
+                        jnp.float32),
+                    conv_bias=self.param(
+                        "conv_bias", nn.initializers.zeros, (inner,),
+                        jnp.float32),
+                    x_proj=unbiased(cfg.ssm_dt_rank + 2 * n, "x_proj"),
+                    # float32 out: dt is float32 from its making on
+                    dt_proj=nn.Dense(inner, dtype=jnp.float32,
+                                     bias_init=_dt_bias_init,
+                                     name="dt_proj"),
+                    a_log=self.param(
+                        "A_log", lambda *_: jnp.broadcast_to(jnp.log(
+                            jnp.arange(1, n + 1, dtype=jnp.float32)),
+                            (inner, n))),
+                    d_skip=self.param("D", nn.initializers.ones, (inner,),
+                                      jnp.float32),
+                    out_proj=unbiased(cfg.emb_dim, "out_proj"))
+                return (delta, y) if self.hand_on == "memory" else delta
+
+            mixer["ssm"] = ssm
+        elif self.layer_type == "gmu":
+            mixer["gmu"] = lambda h: gmu_mixer(
+                h, memory, in_proj=unbiased(cfg.ssm_width, "in_proj"),
+                out_proj=unbiased(cfg.emb_dim, "out_proj"))
         elif self.layer_type == "mla":
             heads = cfg.num_heads
 
@@ -876,9 +1126,23 @@ class Block(nn.Module):
             mixer["mla"] = mla
         else:
             q_dim = cfg.num_heads * cfg.head_dim
-            mixer["qkv"] = dense(q_dim + 2 * kv_dim, "qkv")
+            if self.layer_type == "cross_attention":
+                mixer["qkv"] = dense(q_dim, "q")
+                mixer["shared_kv"] = shared_kv
+            else:
+                mixer["qkv"] = dense(q_dim + 2 * kv_dim, "qkv")
             mixer["proj"] = dense(cfg.emb_dim, "proj")
             mixer["layer_type"] = self.layer_type
+            if cfg.differential_attention:
+                vector = lambda name: self.param(
+                    name, nn.initializers.normal(0.1), (cfg.head_dim,),
+                    jnp.float32)
+                mixer["differential"] = dict(
+                    lambdas=[vector(name) for name in (
+                        "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")],
+                    subln=nn.RMSNorm(epsilon=cfg.norm_eps,
+                                     dtype=jnp.float32, name="subln"),
+                    lambda_init=differential_lambda_init(self.layer_index))
             if cfg.qk_norm:
                 mixer["q_norm"] = _norm(cfg, "q_norm")
                 mixer["k_norm"] = _norm(cfg, "k_norm")
@@ -891,7 +1155,8 @@ class Block(nn.Module):
             mixer["post_mlp_norm"] = _norm(cfg, "post_mlp_norm")
         return block_math(
             cfg, x, positions, rope_tabs,
-            ln1=_norm(cfg, "ln1"), ln2=_norm(cfg, "ln2"), mlp=mlp, **mixer,
+            ln1=_norm(cfg, "ln1"), ln2=_norm(cfg, "ln2"), mlp=mlp,
+            hand_on=self.hand_on, **mixer,
         )
 
 
@@ -995,9 +1260,29 @@ class GPT(nn.Module):
         block_cls = Block
         if cfg.remat:
             block_cls = nn.remat(Block, policy=block_remat_policy(cfg))
+        # what a layer made for later layers, beside the stream
+        handed = {"kv": None, "memory": None}
+        if cfg.layer_types:
+            from ..obs.registry import get_registry  # noqa: PLC0415
+
+            # counted while the step is traced: the layers that read
+            # each handed-on value (its gradient sums over them)
+            for gauge, reader in (("shared.kv_readers", "cross_attention"),
+                                  ("shared.memory_readers", "gmu")):
+                get_registry().gauge(gauge).set(
+                    cfg.layer_types.count(reader))
         for i in range(cfg.num_layers):
-            x = block_cls(cfg, cfg.layer_type(i), cfg.ffn_type(i),
-                          name=f"block{i}")(x, positions, rope_tabs)
+            kind, hand_on = cfg.layer_type(i), cfg.hands_on(i)
+            block = block_cls(cfg, kind, cfg.ffn_type(i), hand_on,
+                              cfg.first_layer_index + i, name=f"block{i}")
+            if kind == "cross_attention":
+                x = block(x, positions, rope_tabs, handed["kv"])
+            elif kind == "gmu":
+                x = block(x, positions, rope_tabs, None, handed["memory"])
+            else:
+                x = block(x, positions, rope_tabs)
+            if hand_on is not None:
+                x, handed[hand_on] = x
         if not cfg.tie_embeddings:
             # one module, so that the prediction module's pass shares it
             untied = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
@@ -1158,6 +1443,35 @@ GPT_CONFIGS = {
         # 8192 x 5120 queries, keys and values and a 8192 x 4096 gate a
         # block: keep each block's input and, as every policy does, what
         # its kernels made (o 64 MiB and lse 1 MiB a block)
+        remat_policy="nothing_saveable",
+    ),
+    # https://huggingface.co/microsoft/Phi-4-mini-flash-reasoning
+    # config.json (model_type phi4flash; arXiv:2507.06607): a self-decoder
+    # of Mamba-1 layers (even) and sliding-window attention layers (odd,
+    # window 512) up to layer 15, layer 16 the Mamba-1 layer whose scan
+    # output is the memory, layer 17 the one full-attention layer whose
+    # keys and values are the cache, then a cross-decoder of gated memory
+    # units (even) and cross-attention layers (odd) that read those two.
+    # Differential attention (40 sub-heads over 20 of 64), LayerNorm,
+    # biases on the attention projections only, no positions, a tied
+    # head.  Training path only (require_gpt2_block says who refuses it).
+    "phi-4-mini-flash-reasoning": TransformerConfig(
+        vocab_size=200064, num_layers=32, emb_dim=2560, max_len=262144,
+        layer_types=tuple(
+            ("selective_scan" if i <= 16 else "gmu") if i % 2 == 0
+            else "sliding_attention" if i <= 15
+            else "full_attention" if i == 17 else "cross_attention"
+            for i in range(32)),
+        shared_kv_layer=17, memory_layer=16,
+        num_heads=40, num_kv_heads=20, differential_attention=True,
+        attention_window=512, pos_embedding="none",
+        mlp_ratio=4, mlp="silu_gated", norm="layernorm", norm_eps=1e-5,
+        use_bias=True, mlp_bias=False, tie_embeddings=True,
+        ssm_width=5120, ssm_state=16, ssm_conv=4, ssm_dt_rank=160,
+        # 8192 x 10240 of in_proj and 8192 x 20480 of the feed-forward a
+        # block: keep each block's input and, as every policy does, what
+        # its kernels made (the scan's y 80 MiB and states 20 MiB, a
+        # differential layer's o 160 MiB and lse 2.5 MiB)
         remat_policy="nothing_saveable",
     ),
 }

@@ -18,9 +18,14 @@ ALLREDUCE = "allreduce"                # every traced allreduce, whatever XLA
                                        # calls it (all-reduce.81, psum.220)
 OPTIMIZER_UPDATE = "optimizer_update"  # the wrapped optimizer's update
 ATTN = "attn"                          # a block's attention half
-SSM = "ssm"                            # a block's Mamba-2 mixer half: in_proj,
-                                       # conv, scan, gated norm, out_proj
-SSD_SCAN = "ssd_scan"                  # the state-space scan alone, inside ssm
+SSM = "ssm"                            # a block's state-space mixer half,
+                                       # Mamba-2's or Mamba-1's: in_proj,
+                                       # conv, scan, gate, out_proj
+SSD_SCAN = "ssd_scan"                  # Mamba-2's scan alone, inside ssm
+SELECTIVE_SCAN = "selective_scan"      # Mamba-1's scan alone, inside ssm
+GMU = "gmu"                            # a block's gated-memory-unit half:
+                                       # in_proj, the product with the scan
+                                       # memory another layer made, out_proj
 MLA_PROJ = "mla_proj"                  # latent attention, inside attn: the
                                        # two low-rank paths, their norms,
                                        # RoPE, the shared rotary key
@@ -29,6 +34,13 @@ ATTN_WINDOW = "attn_window"            # inside attn: the attention call of
                                        # flash kernels carry the name
 ATTN_GATE = "attn_gate"                # inside attn: the output gate's
                                        # matmul, sigmoid and product
+ATTN_CROSS = "attn_cross"              # inside attn: the attention call of
+                                       # a layer that reads the keys and
+                                       # values another layer made
+ATTN_DIFF = "attn_diff"                # inside attn: differential
+                                       # attention's lambda, subtraction,
+                                       # sub-norm and scale (the attention
+                                       # calls stay outside it)
 MLP = "mlp"                            # a block's MLP half
 MOE_ROUTE = "moe_route"                # inside mlp: router matmul, sigmoid,
                                        # top-k, the sort by expert
@@ -54,9 +66,13 @@ FLASH_OUT = "flash_out"                # flash attention's o
 FLASH_LSE = "flash_lse"                # and its log-sum-exp rows
 SSD_OUT = "ssd_out"                    # the state-space scan's y
 SSD_STATES = "ssd_states"              # and its chunk-start states
-KERNEL_OUTPUTS = (FLASH_OUT, FLASH_LSE, SSD_OUT, SSD_STATES)
+SSCAN_OUT = "sscan_out"                # the selective scan's y
+SSCAN_STATES = "sscan_states"          # and its time-block-start states
+KERNEL_OUTPUTS = (FLASH_OUT, FLASH_LSE, SSD_OUT, SSD_STATES, SSCAN_OUT,
+                  SSCAN_STATES)
 
 SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ,
-          ATTN_WINDOW, ATTN_GATE, SSM, SSD_SCAN, MLP, MOE_ROUTE,
+          ATTN_WINDOW, ATTN_GATE, ATTN_CROSS, ATTN_DIFF, SSM, SSD_SCAN,
+          SELECTIVE_SCAN, GMU, MLP, MOE_ROUTE,
           MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MTP, EMBED, HEAD, STEM,
           KV_GATHER, KV_SCATTER, SAMPLE)

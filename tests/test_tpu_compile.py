@@ -428,6 +428,90 @@ def test_ssd_scan_refuses_a_shape_over_its_vmem(one_chip, compiled_kernels):
             *_cell_scan_args(one_chip))
 
 
+# phi-4-mini-flash-reasoning's two kernels at the published widths and the
+# benchmark cell's 1 x 8192 tokens (benchmark/workloads/
+# phi4mf_train_s8192.json): the Mamba-1 selective scan over 5120 channels
+# of 16 states, and differential attention's one stacked flash call (four
+# times 20 pairs of sub-heads over four times 10, head size 64), banded
+# at window 512 (one K tile wide) and full.
+@pytest.mark.parametrize("direction,kernels,temporaries_mib", [
+    ("forward", ["sscan_fwd"], 96), ("backward", ["sscan_fwd", "sscan_bwd"],
+                                     480)])
+def test_selective_scan_compiles_for_v5e(one_chip, compiled_kernels,
+                                         direction, kernels,
+                                         temporaries_mib):
+    """Both Pallas kernels inside the VMEM their calls state, no
+    ``while`` outside them (the walk over the tokens is inside the
+    kernel), and nothing of ``[seq, channels, state]`` in HBM (2.5 GiB in
+    float32).  What the design keeps in HBM beside its arguments: ``B``
+    and ``C`` spread over 128 lanes (32 MiB each in bfloat16, forward and
+    again backward) and, under differentiation, the 20 MiB of states at
+    each time block's start; the backward's ``d dt`` is 160 MiB."""
+    from horovod_tpu.ops.selective_scan import selective_scan
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (shaped(1, 8192, 5120), shaped(1, 8192, 5120, dtype=jnp.float32),
+            shaped(5120, 16, dtype=jnp.float32), shaped(1, 8192, 16),
+            shaped(1, 8192, 16), shaped(5120, dtype=jnp.float32))
+
+    def backward(*a):
+        return jax.grad(
+            lambda *a: selective_scan(*a).astype(jnp.float32).sum(),
+            argnums=tuple(range(6)))(*a)
+
+    compiled = jax.jit(selective_scan if direction == "forward"
+                       else backward).lower(*args).compile()
+    text = compiled.as_text()
+    assert " while(" not in text
+    for name in ("sscan_fwd", "sscan_bwd"):
+        assert (f"/{name}/" in text) == (name in kernels), name
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < temporaries_mib * 2 ** 20)
+
+
+def test_selective_scan_refuses_what_its_tiles_cannot_take(
+        one_chip, compiled_kernels):
+    from horovod_tpu.ops.selective_scan import selective_scan
+
+    def shaped(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def args(channels, state):
+        return (shaped(1, 256, channels), shaped(1, 256, channels),
+                shaped(channels, state), shaped(1, 256, state),
+                shaped(1, 256, state), shaped(channels))
+
+    with pytest.raises(ValueError, match="channels=192 is not a multiple "
+                       "of 128"):
+        jax.jit(selective_scan).lower(*args(192, 16))
+    with pytest.raises(ValueError, match="state=4 has to be a multiple of "
+                       "8"):
+        jax.jit(selective_scan).lower(*args(256, 4))
+
+
+@pytest.mark.parametrize("window", [512, None])
+def test_differential_flash_call_compiles_for_v5e(one_chip, window):
+    """80 query rows over 40 key/value rows of 64 at 8192 tokens, forward
+    and the one-kernel backward."""
+    q = jax.ShapeDtypeStruct((1, 8192, 80, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 40, 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def backward(q, k, v):
+        return jax.grad(
+            lambda *a: flash_attention(
+                *a, causal=True, window=window,
+                interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(backward).lower(q, kv, kv).compile().as_text()
+    assert "flash_fwd" in text and "flash_bwd_dkdv" in text
+    assert "flash_bwd_dq" not in text
+
+
 # The gradient plane's "proof of overlap" (optim/overlap.py), read from
 # the artifact that matters.  XLA:CPU merges the buckets' all-reduces, so
 # its text proves nothing either way; this is the TPU compiler's, for the
