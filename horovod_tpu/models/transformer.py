@@ -482,24 +482,28 @@ def _attend_differential(cfg: TransformerConfig, q, k, v, positions,
     """Differential attention over ``q`` [b, s, heads, hd] and ``k``,
     ``v`` [b, s, kv heads, hd].  Sub-heads pair up (even, odd): pair
     ``p``'s two maps ``softmax(q1 k1^T)`` and ``softmax(q2 k2^T)`` each
-    read the pair's values ``[v_even ; v_odd]``, 2 hd wide.  The
-    schedules take one head size, so the four products (two maps by two
-    halves of the values) are ONE call over four times the pairs: each
-    score map is computed twice (a kernel with values twice as wide as
-    keys would compute it once).  ``lambdas`` are the four learned
-    vectors, ``subln`` the norm over a pair's 2 hd channels.  Returns
-    [b, s, heads // 2, 2 hd]."""
+    read the pair's values ``[v_even ; v_odd]``, 2 hd wide.  ONE call at
+    the algorithm's own shape: the queries ``[q1 ; q2]`` (heads rows of
+    hd) on the keys ``[k1 ; k2]`` (kv heads rows of hd), and under both
+    halves of the keys the pairs' values, 2 hd wide (a reshape of ``v``,
+    repeated once: 42 MB a layer at Phi-4-mini-flash's 8192 tokens), so
+    each score map is formed once and its ``P V`` carries the pair's 2 hd
+    channels side by side.  Query row ``i`` reads key/value row
+    ``i // group`` as in any grouped call.  The flash kernels take values
+    wider than keys (``ops/flash_attention.py``), the reference and
+    Ulysses schedules are einsums that never asked, and the ring and
+    zigzag schedules size their accumulator by the values: every
+    schedule gets this one call.
+    ``lambdas`` are the four learned vectors, ``subln`` the norm over a
+    pair's 2 hd channels.  Returns [b, s, heads // 2, 2 hd]."""
     b, s, nh, hd = q.shape
-    halves = lambda t: (t[:, :, 0::2], t[:, :, 1::2])
-    (q1, q2), (k1, k2), (v1, v2) = halves(q), halves(k), halves(v)
-    out = _attend(cfg, jnp.concatenate([q1, q1, q2, q2], axis=2),
-                  jnp.concatenate([k1, k1, k2, k2], axis=2),
-                  jnp.concatenate([v1, v2, v1, v2], axis=2),
+    halves = lambda t: jnp.concatenate([t[:, :, 0::2], t[:, :, 1::2]], axis=2)
+    pairs = v.reshape(b, s, v.shape[2] // 2, 2 * hd)
+    out = _attend(cfg, halves(q), halves(k),
+                  jnp.concatenate([pairs, pairs], axis=2),
                   positions, layer_type)
-    out = out.reshape(b, s, 4, nh // 2, hd)
     with jax.named_scope(scopes.ATTN_DIFF):
-        first = jnp.concatenate([out[:, :, 0], out[:, :, 1]], axis=-1)
-        second = jnp.concatenate([out[:, :, 2], out[:, :, 3]], axis=-1)
+        first, second = out[:, :, :nh // 2], out[:, :, nh // 2:]
         lq1, lk1, lq2, lk2 = (t.astype(jnp.float32) for t in lambdas)
         lam = (jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2))
                + lambda_init)
@@ -523,7 +527,8 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
             cfg.flash_block_k, causal=True, window=window)
         form = backward_form(
             q.shape[1], q.shape[3], q.shape[2] // k.shape[2],
-            q.dtype.itemsize, cfg.flash_block_q, cfg.flash_block_k)
+            q.dtype.itemsize, cfg.flash_block_q, cfg.flash_block_k,
+            v.shape[3])
         registry = get_registry()
         label = layer_type or "attention"
         registry.gauge("flash.tiles_live", layer_type=label).set(live)
@@ -532,6 +537,7 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
             2 if form == "two_passes" else 1)
         registry.gauge("flash.bwd_dq_resident", layer_type=label).set(
             int(form == "dq_resident"))
+        registry.gauge("flash.value_dim", layer_type=label).set(v.shape[3])
         return flash_attention(
             q, k, v, causal=True,
             block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,

@@ -51,6 +51,34 @@ Design (the standard flash recurrence, TPU-shaped):
   needed tile for 3.90); the kv row's dk and dv in two spans of 4096
   keys with dq's float32 partials summed after the call 14.60 + 0.61, in
   four spans 16.25.
+* The value width is the values' own (``dv = v.shape[-1]``; PR 42): ``v``,
+  ``o``, ``do`` and dv carry it, ``q``, ``k``, dq and dk the head size
+  ``d``, and the default scale stays ``d ** -0.5``.  The kernel bodies
+  do not know the difference (``k q^T`` contracts ``d``; ``P V``, ``dp =
+  v do^T`` and ``dv += p do`` contract or produce ``dv``): what reads it
+  is the block shapes, the forward's ``acc`` as ``[dv, bq]``, the
+  backward's dv accumulator (``[S, dv]`` with the Q tile outermost,
+  ``[bk, dv]`` with the K tile outermost, beside dk's ``[S, d]`` /
+  ``[bk, d]``; dq's ``[d, bq]`` rows keep ``d`` in every form) and the
+  VMEM counts behind ``backward_form``, the dk and dv halves each at its
+  own padded lanes (8192 keys of 64 with values of 128 count 8192 x
+  64's 20.1 MiB: both widths pad to 128 lanes).  A call with ``dv == d``
+  is the program it was, spec for spec.  The caller with two widths is
+  differential attention (``models/transformer.py:
+  _attend_differential``): 40 query rows of 64 on 20 key/value rows with
+  values of 128, each score map formed once where a stacked call at
+  head size 64 over 80 rows on 40 formed it twice.  At 1 x 8192, bf16,
+  causal, per call alone with its layout (chip runs of PR 42): forward
+  12.49 ms for the stacked call's 22.30, forward and backward 28.21 for
+  52.90; banded at window 512, 8.21 for 14.08 and 17.10 for 29.98.  The
+  same maps through ``dv == d`` kernels with ``q`` and ``k`` zero-padded
+  to 128 channels read 12.45 and 28.95 (8.16 and 17.23 banded): keys of
+  64 or of 128, a tile with values of 128 costs the same.  In the step
+  of ``phi4mf_train_s8192`` the kernels alone: a full causal call 11.65
+  ms forward and 15.68 backward (10 880 live 512 x 256 tiles: 1.07 and
+  1.44 us each, where the stacked call's 21 760 cost 0.96 and 1.44), a
+  banded call 6.55 and 8.72 (2 480 live tiles, and 18 000 dead grid
+  steps at 0.22 and 0.29 us each).
 * Off-TPU (the CPU test mesh) the same kernel runs through the Pallas
   interpreter, so correctness tests don't need TPU hardware.
 * The ``pallas_call`` sites are named ``flash_fwd``, ``flash_bwd_dkdv``
@@ -102,7 +130,15 @@ def flash_attention(
     window: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Flash attention over ``[batch, seq, heads, head_dim]`` inputs.
+    """Flash attention over ``q`` ``[batch, seq, heads, head_dim]``, ``k``
+    ``[batch, seq, kv heads, head_dim]`` and ``v`` ``[batch, seq, kv heads,
+    value_dim]``; returns ``[batch, seq, heads, value_dim]``.  The value
+    width is the values' own: it need not be the keys' (differential
+    attention reads values twice as wide as its keys), and the default
+    scale stays ``head_dim ** -0.5``.  What has to match: batch and
+    sequence of all three, the key/value head count of ``k`` and ``v``,
+    the head size of ``q`` and ``k``, and ``heads`` a multiple of ``kv
+    heads`` (MQA/GQA); anything else raises.
 
     Differentiable; numerically matches
     :func:`horovod_tpu.parallel.local_attention` to fp32 tolerance.
@@ -118,9 +154,10 @@ def flash_attention(
     to plain causal.
     """
     b, s, h, d = q.shape
-    if k.shape != v.shape:
+    if k.shape[:3] != v.shape[:3]:
         raise ValueError(
-            f"flash_attention requires matching k/v shapes, got "
+            f"flash_attention requires k and v matching in batch, sequence "
+            f"and head count (the value width is v's own), got "
             f"{k.shape}/{v.shape}"
         )
     hkv = k.shape[2]
@@ -146,11 +183,11 @@ def flash_attention(
     # k/v fold to [B*HKV, S, D] and the kernels' index maps route each q
     # head to its kv group — no broadcast materialization.
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(
-        b * x.shape[2], s, d
+        b * x.shape[2], s, x.shape[3]
     )
     out = _flash(fold(q), fold(k), fold(v), causal, scale_, bq, bk,
                  h, hkv, window, bool(interpret))
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)
 
 
 def tile_counts(rows: int, seq: int, block_q: int, block_k: int, *,
@@ -229,45 +266,57 @@ def _kv_row(zi, h: int, hkv: int):
 _FUSED_BWD_VMEM_LIMIT = 32 * 2 ** 20
 
 
+def _lanes(width: int) -> int:
+    """A minor dimension padded to the 128 lanes its tiles occupy."""
+    return -(-width // 128) * 128
+
+
 def _fused_bwd_vmem_bytes(s: int, d: int, bq: int, bk: int,
-                          itemsize: int) -> int:
+                          itemsize: int, dv: Optional[int] = None) -> int:
     """VMEM the one-kernel backward holds for a kv row of ``s`` keys with
     the Q tile outermost, every buffer's minor dimension padded to the
-    128 lanes its tiles occupy: the two float32 accumulators, the dk and
-    dv output blocks (two buffers each), the streamed tiles (two buffers
-    each), dq's accumulator and six score-sized float32 temporaries.  The
+    128 lanes its tiles occupy, the keys' side (q, k, dq, dk) at head
+    size ``d`` and the values' (v, do, dv) at ``dv`` (``None``: ``d``):
+    the two float32 accumulators, the dk and dv output blocks (two
+    buffers each), the streamed tiles (two buffers each), dq's
+    accumulator and six score-sized float32 temporaries.  The
     TPU compiler asked 32.63 MiB for 16384 x 64 in bfloat16 inside a
     differentiated ``flash_attention`` (sandbox compile for a v5e,
     PR 29), where this counts 36.1; the unpadded count, 19.6, was wrong
     there."""
-    lanes = -(-d // 128) * 128
-    resident = 2 * s * lanes * 4 + 2 * 2 * s * lanes * itemsize
-    tiles = 2 * (3 * bq + 2 * bk) * lanes * itemsize
+    keys, values = _lanes(d), _lanes(d if dv is None else dv)
+    resident = s * (keys + values) * (4 + 2 * itemsize)
+    tiles = 2 * ((2 * bq + bk) * keys + (bq + bk) * values) * itemsize
     return resident + tiles + d * bq * 4 + 6 * bk * bq * 4
 
 
 def _dq_resident_bwd_vmem_bytes(s: int, d: int, bq: int, bk: int,
-                                itemsize: int, group: int) -> int:
+                                itemsize: int, group: int,
+                                dv: Optional[int] = None) -> int:
     """VMEM the one-kernel backward holds with the K tile outermost: dq of
     a kv row's ``group`` query heads as ``[group * nq, d, bq]`` float32
     (channels on sublanes, queries on lanes), the K tile's two float32
     accumulators, the streamed tiles and output blocks (q, do, dq of
     ``bq`` rows, k, v, dk, dv of ``bk``; two buffers each, 128 lanes at
-    least) and six score-sized float32 temporaries.  14.0 MiB for 8192
+    least; v, do and dv at the value width ``dv``, ``None``: ``d``) and
+    six score-sized float32 temporaries.  14.0 MiB for 8192
     keys at head size 256; the TPU compiler takes that shape inside a
     stated 14 MiB (sandbox compile for a v5e, PR 38)."""
-    lanes = -(-d // 128) * 128
-    dq_rows = group * (s // bq) * (-(-d // 8) * 8) * (-(-bq // 128) * 128)
-    resident = dq_rows * 4 + 2 * bk * lanes * 4
-    tiles = 2 * (3 * bq + 4 * bk) * lanes * itemsize
+    keys, values = _lanes(d), _lanes(d if dv is None else dv)
+    dq_rows = group * (s // bq) * (-(-d // 8) * 8) * _lanes(bq)
+    resident = dq_rows * 4 + bk * (keys + values) * 4
+    tiles = 2 * ((2 * bq + 2 * bk) * keys
+                 + (bq + 2 * bk) * values) * itemsize
     return resident + tiles + 6 * bk * bq * 4
 
 
 def backward_form(seq: int, head_dim: int, group: int, itemsize: int,
-                  block_q: int = 512, block_k: int = 256) -> str:
+                  block_q: int = 512, block_k: int = 256,
+                  value_dim: Optional[int] = None) -> str:
     """Which backward a call of this shape runs, from the shape alone
     (``group`` query heads a key/value head, tiles as ``_pick_block``
-    makes them) against the VMEM the one kernel states.
+    makes them, values ``value_dim`` wide: ``None`` says as wide as the
+    keys) against the VMEM the one kernel states.
     ``"dkdv_resident"``: one kernel, Q tile outermost, a kv row's dk and
     dv accumulators resident (PR 29's), wherever it fits.
     ``"dq_resident"``: one kernel, K tile outermost, the group's dq rows
@@ -275,29 +324,32 @@ def backward_form(seq: int, head_dim: int, group: int, itemsize: int,
     ``_flash_bwd_pallas`` branches on it and ``models/transformer.py``
     sets its gauges from it while a step is traced."""
     bq, bk = _pick_block(seq, block_q), _pick_block(seq, block_k)
-    if (_fused_bwd_vmem_bytes(seq, head_dim, bq, bk, itemsize)
+    if (_fused_bwd_vmem_bytes(seq, head_dim, bq, bk, itemsize, value_dim)
             <= _FUSED_BWD_VMEM_LIMIT):
         return "dkdv_resident"
-    if (_dq_resident_bwd_vmem_bytes(seq, head_dim, bq, bk, itemsize, group)
-            <= _FUSED_BWD_VMEM_LIMIT):
+    if (_dq_resident_bwd_vmem_bytes(seq, head_dim, bq, bk, itemsize, group,
+                                    value_dim) <= _FUSED_BWD_VMEM_LIMIT):
         return "dq_resident"
     return "two_passes"
 
 
 def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
                       interpret):
-    """Returns (o [Z,S,D], lse [Z,S]) with Z = batch*heads.
+    """Returns (o [Z,S,DV], lse [Z,S]) with Z = batch*heads and DV the
+    values' width.
 
     K tiles live on the innermost grid dimension, so only (1, bk, d) of K
-    and V are resident per step — VMEM peak is O(bq*d + bk*d), independent
-    of S (the long-context requirement).  The online-softmax state (acc
-    [d, bq], m and l [1, bq]: transposed like the tile) persists across
+    and (1, bk, dv) of V are resident per step — VMEM peak is O(bq*dv +
+    bk*(d + dv)), independent of S (the long-context requirement).  The
+    online-softmax state (acc
+    [dv, bq], m and l [1, bq]: transposed like the tile) persists across
     the sequential K dimension in VMEM scratch and is flushed to the
     output block at the last K tile; lse leaves as one row per Q tile.
     GQA/MQA: k/v have Z_kv = batch*hkv rows; the index map routes each q
     head to its group.
     """
     z, s, d = q.shape
+    dv = v.shape[-1]
     nq, nk = s // bq, s // bk
 
     def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref):
@@ -347,7 +399,7 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
             l_ref[...] = l_ref[...] * corr + p.sum(0, keepdims=True)
             acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
                 vb, p, _CONTRACT_ROWS, preferred_element_type=jnp.float32,
-            )                                          # [d, bq]
+            )                                          # [dv, bq]
             m_ref[...] = m_new
 
         @pl.when(j == nk - 1)
@@ -362,19 +414,19 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
             pl.BlockSpec((1, bq, d), lambda zi, qi, ki: (zi, qi, 0)),
             pl.BlockSpec((1, bk, d),
                          lambda zi, qi, ki: (_kv_row(zi, h, hkv), ki, 0)),
-            pl.BlockSpec((1, bk, d),
+            pl.BlockSpec((1, bk, dv),
                          lambda zi, qi, ki: (_kv_row(zi, h, hkv), ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda zi, qi, ki: (zi, qi, 0)),
+            pl.BlockSpec((1, bq, dv), lambda zi, qi, ki: (zi, qi, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda zi, qi, ki: (zi, qi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((z, s, d), q.dtype),
+            jax.ShapeDtypeStruct((z, s, dv), q.dtype),
             jax.ShapeDtypeStruct((z, nq, 1, bq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((d, bq), jnp.float32),   # acc
+            pltpu.VMEM((dv, bq), jnp.float32),  # acc
             pltpu.VMEM((1, bq), jnp.float32),   # running max m
             pltpu.VMEM((1, bq), jnp.float32),   # running sum l
         ],
@@ -403,15 +455,17 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
     ``"dkdv_resident"`` (grid z, nq, nk), wherever
     ``_fused_bwd_vmem_bytes`` fits ``_FUSED_BWD_VMEM_LIMIT``: Q tile
     fixed, K tiles stream.  dq accumulates as [d, bq], turned once at
-    the flush; dk and dv accumulate in two [S, d] float32 scratch
-    buffers that live for a whole kv row — under GQA the group's query
-    heads are consecutive z and fold into them — zeroed at the row's
-    first grid step and written, cast once, to the (1, S, d) output
-    blocks at its last.
+    the flush; dk and dv accumulate in float32 scratch buffers of [S, d]
+    and [S, dv] (the values' width: v, do, o and dv carry it, q, k, dq
+    and dk the head size) that live for a whole kv row — under GQA the
+    group's query heads are consecutive z and fold into them — zeroed at
+    the row's first grid step and written, cast once, to the (1, S, d)
+    and (1, S, dv) output blocks at its last.
     ``"dq_resident"`` (grid z_kv, nk, nq*group), where
     ``_dq_resident_bwd_vmem_bytes`` fits instead: K tile fixed,
     (q-head-in-group, Q tile) pairs stream; dk and dv of the tile
-    accumulate as [bk, d] and flush at the last pair, dq of the kv row's
+    accumulate as [bk, d] and [bk, dv] and flush at the last pair, dq of
+    the kv row's
     query heads as [group*nq, d, bq], zeroed in the first K tile's
     sweep and written, turned and cast, in the last's (dq's block index
     stays put until then, so each block goes to HBM once).  It adds the
@@ -430,11 +484,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
     stream; dq accumulates (as [d, bq], turned once at the flush).
     """
     z, s, d = q.shape
-    z_kv = k.shape[0]
+    z_kv, dv = k.shape[0], v.shape[-1]
     group = h // hkv
     nq, nk = s // bq, s // bk
     f32 = jnp.float32
-    form = backward_form(s, d, group, q.dtype.itemsize, bq, bk)
+    form = backward_form(s, d, group, q.dtype.itemsize, bq, bk, dv)
     with_dq = form == "dq_resident"   # the K-outermost kernel takes dq too
     # delta is computed once per call and shared by all kernels, which
     # read it and lse as (1, bq) rows of a [Z, nq, 1, bq] view (a block
@@ -592,7 +646,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
             dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
-    qkv_spec = lambda tile, which: pl.BlockSpec((1, tile, d), which)
+    # q, k, dq, dk are ``d`` wide; v, do, dv the values' own ``dv``
+    k_spec = lambda tile, which: pl.BlockSpec((1, tile, d), which)
+    v_spec = lambda tile, which: pl.BlockSpec((1, tile, dv), which)
     stat_spec = lambda which: pl.BlockSpec((1, 1, 1, bq), which)
 
     if form == "dkdv_resident":
@@ -604,27 +660,27 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
             kernel_fused,
             grid=(z, nq, nk),
             in_specs=[
-                qkv_spec(bq, q_tile),
-                qkv_spec(bk, kv_tile),
-                qkv_spec(bk, kv_tile),
-                qkv_spec(bq, q_tile),       # do
+                k_spec(bq, q_tile),
+                k_spec(bk, kv_tile),
+                v_spec(bk, kv_tile),
+                v_spec(bq, q_tile),         # do
                 stat_spec(q_stat),          # lse
                 stat_spec(q_stat),          # delta
             ],
             out_specs=[
-                qkv_spec(bq, q_tile),
-                qkv_spec(s, kv_whole),
-                qkv_spec(s, kv_whole),
+                k_spec(bq, q_tile),
+                k_spec(s, kv_whole),
+                v_spec(s, kv_whole),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((z, s, d), q.dtype),
                 jax.ShapeDtypeStruct((z_kv, s, d), k.dtype),
-                jax.ShapeDtypeStruct((z_kv, s, d), v.dtype),
+                jax.ShapeDtypeStruct((z_kv, s, dv), v.dtype),
             ],
             scratch_shapes=[
                 pltpu.VMEM((d, bq), f32),
                 pltpu.VMEM((s, d), f32),
-                pltpu.VMEM((s, d), f32),
+                pltpu.VMEM((s, dv), f32),
             ],
             compiler_params=pltpu.CompilerParams(
                 # dk and dv accumulate across all three axes
@@ -649,15 +705,15 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
         last = jnp.where(ji == nk - 1, ti, 0)
         return (_qrow(zi, last), last % nq, 0)
 
-    out_specs = [qkv_spec(bk, kv_tile), qkv_spec(bk, kv_tile)]
+    out_specs = [k_spec(bk, kv_tile), v_spec(bk, kv_tile)]
     out_shape = [jax.ShapeDtypeStruct((z_kv, s, d), k.dtype),
-                 jax.ShapeDtypeStruct((z_kv, s, d), v.dtype)]
-    scratch_shapes = [pltpu.VMEM((bk, d), f32), pltpu.VMEM((bk, d), f32)]
+                 jax.ShapeDtypeStruct((z_kv, s, dv), v.dtype)]
+    scratch_shapes = [pltpu.VMEM((bk, d), f32), pltpu.VMEM((bk, dv), f32)]
     compiler_params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
     )
     if with_dq:
-        out_specs.append(qkv_spec(bq, dq_tile))
+        out_specs.append(k_spec(bq, dq_tile))
         out_shape.append(jax.ShapeDtypeStruct((z, s, d), q.dtype))
         scratch_shapes.append(pltpu.VMEM((nq * group, d, bq), f32))
         compiler_params = pltpu.CompilerParams(
@@ -665,14 +721,14 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_FUSED_BWD_VMEM_LIMIT,
         )
-    dk, dv, *dq = pl.pallas_call(
+    dk, dvalues, *dq = pl.pallas_call(
         kernel_k_outer,
         grid=(z_kv, nk, nq * group),
         in_specs=[
-            qkv_spec(bq, q_tile),
-            qkv_spec(bk, kv_tile),
-            qkv_spec(bk, kv_tile),
-            qkv_spec(bq, q_tile),       # do
+            k_spec(bq, q_tile),
+            k_spec(bk, kv_tile),
+            v_spec(bk, kv_tile),
+            v_spec(bq, q_tile),         # do
             stat_spec(q_stat),          # lse
             stat_spec(q_stat),          # delta
         ],
@@ -684,19 +740,19 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
         name="flash_bwd_dkdv",
     )(q, k, v, do, lse_r, delta_r)
     if with_dq:
-        return dq[0], dk, dv
+        return dq[0], dk, dvalues
     (dq,) = pl.pallas_call(
         kernel_dq,
         grid=(z, nq, nk),
         in_specs=[
-            qkv_spec(bq, lambda zi, ii, ji: (zi, ii, 0)),
-            qkv_spec(bk, lambda zi, ii, ji: (_kv_row(zi, h, hkv), ji, 0)),
-            qkv_spec(bk, lambda zi, ii, ji: (_kv_row(zi, h, hkv), ji, 0)),
-            qkv_spec(bq, lambda zi, ii, ji: (zi, ii, 0)),
+            k_spec(bq, lambda zi, ii, ji: (zi, ii, 0)),
+            k_spec(bk, lambda zi, ii, ji: (_kv_row(zi, h, hkv), ji, 0)),
+            v_spec(bk, lambda zi, ii, ji: (_kv_row(zi, h, hkv), ji, 0)),
+            v_spec(bq, lambda zi, ii, ji: (zi, ii, 0)),
             stat_spec(lambda zi, ii, ji: (zi, ii, 0, 0)),
             stat_spec(lambda zi, ii, ji: (zi, ii, 0, 0)),
         ],
-        out_specs=[qkv_spec(bq, lambda zi, ii, ji: (zi, ii, 0))],
+        out_specs=[k_spec(bq, lambda zi, ii, ji: (zi, ii, 0))],
         out_shape=[jax.ShapeDtypeStruct((z, s, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((d, bq), f32)],
         compiler_params=pltpu.CompilerParams(
@@ -705,14 +761,15 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
         interpret=interpret,
         name="flash_bwd_dq",
     )(q, k, v, do, lse_r, delta_r)
-    return dq, dk, dv
+    return dq, dk, dvalues
 
 
 def _flash_bwd_blockwise(q, k, v, o, lse, do, causal, scale, bk,
                          window=None):
     """Blockwise flash backward (pure JAX scan over K tiles) — kept as the
     differential reference for the Pallas backward (tests pin equality)
-    and as a debugging fallback.
+    and as a debugging fallback.  ``v`` and ``do`` may be wider or
+    narrower than ``q`` and ``k``: dv comes back at the values' width.
     """
     z, s, d = q.shape
     nk = s // bk
@@ -744,8 +801,8 @@ def _flash_bwd_blockwise(q, k, v, o, lse, do, causal, scale, bk,
     dq, (dks, dvs) = lax.scan(
         body, jnp.zeros_like(qf), jnp.arange(nk)
     )
-    # stacked [nk, Z, bk, D] -> [Z, S, D]
-    unfold = lambda t: t.transpose(1, 0, 2, 3).reshape(z, s, d)
+    # stacked [nk, Z, bk, D] -> [Z, S, D], D the keys' or the values'
+    unfold = lambda t: t.transpose(1, 0, 2, 3).reshape(z, s, t.shape[-1])
     return (
         dq.astype(q.dtype),
         unfold(dks).astype(k.dtype),

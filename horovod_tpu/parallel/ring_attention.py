@@ -57,8 +57,9 @@ __all__ = [
 def _online_softmax_update(state, q_sub, k_sub, v_sub, scale, mask=None):
     """One online-softmax accumulation of ``q_sub`` (fp32) against a K/V
     block — the single definition of the m/l/o recurrence shared by the
-    contiguous ring and the zigzag ring.  ``state`` is ``(o [b,sq,h,d],
-    m [b,h,sq], l [b,h,sq])`` in fp32; ``mask`` is a bool ``[sq, sk]``
+    contiguous ring and the zigzag ring.  ``state`` is ``(o [b,sq,h,dv],
+    m [b,h,sq], l [b,h,sq])`` in fp32, ``dv`` the values' own width
+    (it need not be the keys'); ``mask`` is a bool ``[sq, sk]``
     (True = masked) used only for diagonal/partial blocks."""
     o, m, l = state
     if q_sub.shape[2] != k_sub.shape[2]:
@@ -192,7 +193,7 @@ def ring_attention(
         v_blk = lax.ppermute(v_blk, axis_name, perm)
         return (k_blk, v_blk, o, m, l), None
 
-    o0 = jnp.zeros((b, s_local, h, d), jnp.float32)
+    o0 = jnp.zeros((b, s_local, h, v.shape[3]), jnp.float32)
     m0 = jnp.full((b, h, s_local), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((b, h, s_local), jnp.float32)
     (k_, v_, o, m, l), _ = lax.scan(
@@ -290,7 +291,7 @@ def ring_attention_zigzag(
 
     def init_state():
         return (
-            jnp.zeros((b, half, h, d), jnp.float32),
+            jnp.zeros((b, half, h, v.shape[3]), jnp.float32),
             jnp.full((b, h, half), -jnp.inf, jnp.float32),
             jnp.zeros((b, h, half), jnp.float32),
         )

@@ -575,18 +575,20 @@ def test_row_statistics_across_tiles(causal, window, h, hkv, bq, bk, dtype):
 def _grouped_blockwise(q, k, v, o, lse, do, causal, scale, bk, window, h,
                        hkv):
     """`_flash_bwd_blockwise` knows no grouped heads: give every query
-    head its own copy of its kv row and fold dk and dv back."""
+    head its own copy of its kv row and fold dk and dv back (each at its
+    own width: the values' need not be the keys')."""
     from horovod_tpu.ops.flash_attention import _flash_bwd_blockwise
 
-    z, s, d = q.shape
+    z, s, _ = q.shape
     b, group = z // h, h // hkv
     f32 = jnp.float32
     rep = lambda t: jnp.repeat(
-        t.astype(f32).reshape(b, hkv, 1, s, d), group, 2
-    ).reshape(z, s, d)
+        t.astype(f32).reshape(b, hkv, 1, s, t.shape[-1]), group, 2
+    ).reshape(z, s, t.shape[-1])
     dq, dk, dv = _flash_bwd_blockwise(q.astype(f32), rep(k), rep(v), o, lse,
                                       do, causal, scale, bk, window=window)
-    fold = lambda t: t.reshape(b, hkv, group, s, d).sum(2).reshape(-1, s, d)
+    fold = lambda t: t.reshape(b, hkv, group, s, t.shape[-1]).sum(2).reshape(
+        -1, s, t.shape[-1])
     return dq, fold(dk), fold(dv)
 
 
@@ -781,6 +783,9 @@ def test_the_gauges_say_which_backward_the_step_holds(
                           layer_type="attention").value == kernels
     assert registry.gauge("flash.bwd_dq_resident",
                           layer_type="attention").value == dq_resident
+    # values as wide as keys: the head size, 64 / 4
+    assert registry.gauge("flash.value_dim",
+                          layer_type="attention").value == 16
     assert sum(name != "flash_fwd" for name, _ in calls) == 2 * kernels
 
 
@@ -881,6 +886,173 @@ def test_window_at_grouped_heads_of_128(monkeypatch, backward, seq, window):
         np.testing.assert_allclose(a, b, atol=5e-5, err_msg=name)
     for name, a, b in zip(("dq", "dk", "dv"), grads(plain), want):
         np.testing.assert_allclose(a, b, atol=5e-5, err_msg=name)
+
+
+# ------------------------------------------ values wider than keys (PR 42)
+# The value width is the values' own: differential attention reads values
+# twice as wide as its keys (phi4mf_train_s8192: 40 query rows of 64 over 20
+# key/value rows, values 128).  Grouped heads 2:1 as there, tiles 16 x 8.
+_WIDTH_MASKS = [
+    ("causal", True, None),
+    ("causal_window_20", True, 20),   # a multiple of neither tile
+    ("noncausal", False, None),
+]
+
+
+@pytest.mark.parametrize("backward",
+                         ["one_kernel", "dq_resident", "two_passes"])
+@pytest.mark.parametrize("causal,window", [c[1:] for c in _WIDTH_MASKS],
+                         ids=[c[0] for c in _WIDTH_MASKS])
+@pytest.mark.parametrize("dv", [32, 8], ids=["values_2d", "values_half_d"])
+def test_values_of_another_width_than_the_keys(monkeypatch, dv, causal,
+                                               window, backward):
+    """``v`` twice and half as wide as ``q`` and ``k`` (16): the forward
+    against ``local_attention`` (an einsum, which never asked for one
+    width), and dq, dk, dv of each of the three backward forms against the
+    blockwise scan at the values' own width; the scale is the keys'."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    names = _force(monkeypatch, backward)
+    b, s, h, hkv, d, bq, bk = 2, 64, 4, 2, 16, 16, 8
+    rng = np.random.RandomState(13)
+    mk = lambda heads, width: jnp.asarray(
+        rng.randn(b, s, heads, width) * 0.7, jnp.float32)
+    q, k, v, do = mk(h, d), mk(hkv, d), mk(hkv, dv), mk(h, dv)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               block_q=bq, block_k=bk)
+
+    rep = lambda x: jnp.repeat(x, h // hkv, axis=2)
+    out = flash(q, k, v)
+    assert out.shape == (b, s, h, dv)
+    np.testing.assert_allclose(
+        out, local_attention(q, rep(k), rep(v), causal=causal,
+                             window=window), atol=2e-5)
+    grad = jax.grad(lambda *a: (flash(*a) * do).sum(), argnums=(0, 1, 2))
+    assert list(_pallas_calls(jax.make_jaxpr(grad)(q, k, v).jaxpr)) \
+        == ["flash_fwd"] + names
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, s, x.shape[3])
+    o, lse = fa._flash_fwd_kernel(fold(q), fold(k), fold(v), causal,
+                                  d ** -0.5, bq, bk, h, hkv, window, True)
+    want = _grouped_blockwise(fold(q), fold(k), fold(v), o, lse, fold(do),
+                              causal, d ** -0.5, bk, window, h, hkv)
+    for name, a, r in zip(("dq", "dk", "dv"), grad(q, k, v), want):
+        assert a.shape == (b, s, h if name == "dq" else hkv,
+                           dv if name == "dv" else d), name
+        np.testing.assert_allclose(fold(a), r, atol=2e-5, err_msg=name)
+
+
+def test_flash_attention_refuses_k_and_v_of_different_rows():
+    """The width is v's own; batch, sequence and key/value head count are
+    not."""
+    q, k, v = _qkv(h=4, d=16)
+    with pytest.raises(ValueError, match="matching in batch, sequence"):
+        flash_attention(q, k[:, :, :2], v)
+    with pytest.raises(ValueError, match="matching in batch, sequence"):
+        flash_attention(q, k, v[:, :32])
+    with pytest.raises(ValueError, match="head_dim must match"):
+        flash_attention(q, k[..., :8], v)
+
+
+# (id, keys, head size, value width, group, the form, the Q-outermost
+# count, the K-outermost count) in bfloat16 at 512 x 256 tiles: the
+# benchmark cells' shapes read the bytes they read before the counts took
+# a value width (PR 38's tree), and 8192 x (64, 128), the Phi cell's
+# one-pass differential call, pads both widths to 128 lanes and reads
+# 8192 x 64's count.
+_COUNT_CASES = [
+    ("gpt2m_1024x64", 1024, 64, None, 1, "dkdv_resident", 6422528,
+     4980736),
+    ("granite4hm_8192x64", 8192, 64, None, 4, "dkdv_resident", 21102592,
+     13107200),
+    ("trinitym_8192x128", 8192, 128, None, 8, "dkdv_resident", 21233664,
+     38273024),
+    ("glm47f_8192x256", 8192, 256, None, 1, "dq_resident", 39321600,
+     14680064),
+    ("phi4mf_8192x64_values_128", 8192, 64, 128, 2, "dkdv_resident",
+     21102592, 8912896),
+]
+
+
+@pytest.mark.parametrize("seq,d,dv,group,form,q_outer,k_outer",
+                         [c[1:] for c in _COUNT_CASES],
+                         ids=[c[0] for c in _COUNT_CASES])
+def test_vmem_counts_at_the_cells_shapes(seq, d, dv, group, form, q_outer,
+                                         k_outer):
+    from horovod_tpu.ops import flash_attention as fa
+
+    assert fa._fused_bwd_vmem_bytes(seq, d, 512, 256, 2, dv) == q_outer
+    assert fa._dq_resident_bwd_vmem_bytes(seq, d, 512, 256, 2, group,
+                                          dv) == k_outer
+    assert fa.backward_form(seq, d, group, 2, value_dim=dv) == form
+    if dv is None:
+        # a value width that is the head size changes nothing
+        assert fa._fused_bwd_vmem_bytes(seq, d, 512, 256, 2, d) == q_outer
+        assert fa._dq_resident_bwd_vmem_bytes(seq, d, 512, 256, 2, group,
+                                              d) == k_outer
+        assert fa.backward_form(seq, d, group, 2) == form
+        assert fa.backward_form(seq, d, group, 2, 512, 256, d) == form
+    else:
+        # the dk and dv halves each at their own padded lanes: values of
+        # 512 put 8192 keys over the Q-outermost form's limit, and dq,
+        # 64 wide, stays resident under the K tiles
+        assert fa._fused_bwd_vmem_bytes(seq, d, 512, 256, 2, 512) \
+            > fa._FUSED_BWD_VMEM_LIMIT > q_outer
+        assert fa.backward_form(seq, d, group, 2,
+                                value_dim=512) == "dq_resident"
+
+
+def _kernel_signature(eqn_params):
+    """What a ``pallas_call`` holds that the chip would see: its name and
+    grid, the kernel's block and scratch refs, its outputs and the VMEM
+    it states."""
+    return (eqn_params["name"], tuple(eqn_params["grid_mapping"].grid),
+            [str(v.aval) for v in eqn_params["jaxpr"].invars],
+            [str(a) for a in eqn_params["out_avals"]],
+            eqn_params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes)
+
+
+def test_a_call_with_values_as_wide_as_keys_is_the_program_it_was():
+    """granite's call (32 query over 8 key/value heads of 64 at 8192
+    tokens, bfloat16): the ``pallas_call``s of the differentiated jaxpr,
+    listed as PR 38's tree made them.  The five cells that send
+    ``dv == d`` run this program; only the value width of a call that
+    has one moves a block, a scratch buffer or an output."""
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 64), jnp.bfloat16)
+
+    def calls(v):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, interpret=True
+            ).astype(jnp.float32).sum(), argnums=(0, 1, 2)))(q, kv, v)
+        return list(_pallas_calls(jaxpr.jaxpr, _kernel_signature))
+
+    bf = lambda *shape: "Ref{bfloat16[%s]}" % ",".join(map(str, shape))
+    stat = "Ref{float32[1,1,1,512]}"
+    vmem = lambda *shape: "Ref<vmem>{float32[%s]}" % ",".join(map(str, shape))
+    arr = lambda *shape: "bfloat16[%s]" % ",".join(map(str, shape))
+
+    def listed(dv):
+        return [
+            ("flash_fwd", (32, 16, 32),
+             [bf(1, 512, 64), bf(1, 256, 64), bf(1, 256, dv),
+              bf(1, 512, dv), stat,
+              vmem(dv, 512), vmem(1, 512), vmem(1, 512)],
+             [arr(32, 8192, dv), "float32[32,16,1,512]"], None),
+            ("flash_bwd_dkdv", (32, 16, 32),
+             [bf(1, 512, 64), bf(1, 256, 64), bf(1, 256, dv),
+              bf(1, 512, dv), stat, stat,
+              bf(1, 512, 64), bf(1, 8192, 64), bf(1, 8192, dv),
+              vmem(64, 512), vmem(8192, 64), vmem(8192, dv)],
+             [arr(32, 8192, 64), arr(8, 8192, 64), arr(8, 8192, dv)],
+             32 * 2 ** 20),
+        ]
+
+    assert calls(kv) == listed(64)
+    wide = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16)
+    assert calls(wide) == listed(128)
 
 
 def test_local_attention_refuses_a_window_it_cannot_mean():

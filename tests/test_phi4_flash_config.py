@@ -176,15 +176,67 @@ def test_the_new_layers_carry_their_scopes_and_gauges():
     assert registry.gauge("shared.kv_readers").value == 2
     assert registry.gauge("shared.memory_readers").value == 2
     assert registry.gauge("sscan.kept_mib").value == kept_mib(2, SEQ, 128, 16)
-    # one stacked call a layer: 2 sequences x 4 x 4 pairs of sub-heads
-    rows = 2 * 16
+    # one call a layer over the pairs' own rows (2 sequences x 8
+    # sub-heads, not four times the pairs), values as wide as a pair
+    rows = 2 * 8
     for kind in ("sliding_attention", "full_attention", "cross_attention"):
         assert registry.gauge("flash.tiles_grid", layer_type=kind).value \
             == rows * (SEQ // 16) * (SEQ // 4)
+        assert registry.gauge("flash.value_dim", layer_type=kind).value == 16
     assert registry.gauge("flash.tiles_live",
                           layer_type="cross_attention").value == rows * 12
     assert registry.gauge("flash.tiles_live",
                           layer_type="sliding_attention").value == rows * 10
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention",
+                                  "cross_attention"])
+def test_a_differential_layer_is_one_flash_call_at_the_pairs_shape(kind):
+    """At the published widths and the benchmark cell's 8192 tokens (only
+    traced): 40 sub-heads of 64 over 20, so ONE ``flash_fwd`` over 40
+    rows, its keys 64 wide and its values and output 128, and nothing
+    stacks four groups of heads before it (the parent's call ran each
+    score map twice, over 80 rows on 40)."""
+    from horovod_tpu.models.transformer import _attend_differential
+
+    cfg = GPT_CONFIGS[NAME]
+    assert cfg.attention_impl == "flash"
+    shaped = lambda heads: jax.ShapeDtypeStruct((1, 8192, heads, 64),
+                                                jnp.bfloat16)
+    lambdas = [jnp.full((64,), 0.1)] * 4
+    jaxpr = jax.make_jaxpr(lambda q, k, v: _attend_differential(
+        cfg, q, k, v, jnp.arange(8192), kind, lambdas=lambdas,
+        subln=lambda t: t, lambda_init=0.5))(
+            shaped(40), shaped(20), shaped(20))
+    assert jaxpr.out_avals[0].shape == (1, 8192, 20, 128)
+    equations = list(_equations(jaxpr.jaxpr))
+    calls = [e.params for e in equations
+             if e.primitive.name == "pallas_call"]
+    assert [c["name"] for c in calls] == ["flash_fwd"]
+    blocks = [v.aval.shape for v in calls[0]["jaxpr"].invars[:4]]
+    assert blocks == [(1, 512, 64), (1, 256, 64), (1, 256, 128),
+                      (1, 512, 128)]                       # q, k, v, o
+    assert tuple(calls[0]["grid_mapping"].grid) == (40, 16, 32)
+    assert [a.shape for a in calls[0]["out_avals"]][0] == (40, 8192, 128)
+    joins = [len(e.invars) for e in equations
+             if e.primitive.name == "concatenate"]
+    assert joins and max(joins) == 2, joins
+    # what the benchmark's builder leaves in ran["flash_tiles"]: half the
+    # stacked call's 40 960 walked and 21 760 / 4 960 live tiles
+    from horovod_tpu.obs.registry import get_registry
+
+    gauge = lambda name: get_registry().gauge(name, layer_type=kind).value
+    assert gauge("flash.tiles_grid") == 20480
+    assert gauge("flash.tiles_live") == (
+        2480 if kind == "sliding_attention" else 10880)
+    assert gauge("flash.value_dim") == 128
 
 
 # ------------------------------------------------------------- refusals

@@ -227,3 +227,42 @@ def test_zigzag_positions_match_layout():
         np.testing.assert_array_equal(
             shard, np.asarray(zigzag_positions(i, size, s_local))
         )
+
+
+@pytest.mark.parametrize("schedule", ["ring", "zigzag", "ulysses"])
+def test_values_wider_than_keys(schedule):
+    """The value width is the values' own in every schedule, as in the
+    flash kernels (differential attention sends values twice as wide as
+    its keys, over half as many key/value heads): the output and the
+    three gradients against ``local_attention``, causal."""
+    from horovod_tpu.parallel import (
+        ring_attention_zigzag, zigzag_shard, zigzag_unshard,
+    )
+
+    rng = np.random.RandomState(6)
+    mk = lambda heads, width: jnp.asarray(
+        rng.randn(B, S, heads, width), jnp.float32) * 0.3
+    q, k, v, w = mk(H, D), mk(H // 2, D), mk(H // 2, 2 * D), mk(H, 2 * D)
+    rep = lambda t: jnp.repeat(t, 2, axis=2)
+
+    def plain(q, k, v):
+        return local_attention(q, rep(k), rep(v), causal=True)
+
+    def sharded(q, k, v):
+        if schedule == "ring":
+            return _sharded(ring_attention, causal=True)(q, k, v)
+        if schedule == "ulysses":   # attends at full heads
+            return _sharded(ulysses_attention, causal=True)(
+                q, rep(k), rep(v))
+        zz = lambda t: zigzag_shard(t, 8, axis=1)
+        return zigzag_unshard(
+            _sharded(ring_attention_zigzag)(zz(q), zz(k), zz(v)), 8, axis=1)
+
+    out = sharded(q, k, v)
+    assert out.shape == (B, S, H, 2 * D)
+    np.testing.assert_allclose(out, plain(q, k, v), atol=2e-5, rtol=2e-5)
+    got, want = (jax.grad(lambda *a: (f(*a) * w).sum(),
+                          argnums=(0, 1, 2))(q, k, v)
+                 for f in (sharded, plain))
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5, err_msg=name)

@@ -100,33 +100,54 @@ def test_scan_keeps_its_states_and_refuses_what_does_not_fit():
 def test_differential_attention_matches_the_dense_formula(attention, kind,
                                                           window):
     """``(1 - lam0) RMSNorm(P1 V - lam P2 V)`` with the maps written out
-    densely: 8 sub-heads over 4, so query pair p reads K/V pair p // 2."""
+    densely: 8 sub-heads over 4, so query pair p reads K/V pair p // 2 and
+    both of its maps read that pair's values, 2 hd wide.  The output and
+    the gradient into q, k, v, the four lambda vectors and the sub-norm's
+    scale; through the flash schedule that is ONE call over the 8
+    sub-heads on 4 key rows with values 2 hd wide."""
     cfg = small_model(attention_impl=attention).cfg
     b, s, hd = 2, SEQ, 16
-    ks = jax.random.split(jax.random.PRNGKey(5), 8)
+    ks = jax.random.split(jax.random.PRNGKey(5), 9)
     q = jax.random.normal(ks[0], (b, s, 8, hd))
     k = jax.random.normal(ks[1], (b, s, 4, hd))
     v = jax.random.normal(ks[2], (b, s, 4, hd))
     lambdas = [0.5 * jax.random.normal(ks[3 + i], (hd,)) for i in range(4)]
     scale = 1.0 + 0.3 * jax.random.normal(ks[7], (2 * hd,))
+    weight = jax.random.normal(ks[8], (b, s, 4, 2 * hd))
     lam0 = differential_lambda_init(17)
-    subln = lambda t: t * jax.lax.rsqrt(
-        jnp.mean(t * t, axis=-1, keepdims=True) + 1e-5) * scale
-    got = _attend_differential(cfg, q, k, v, jnp.arange(s), kind,
-                               lambdas=lambdas, subln=subln, lambda_init=lam0)
-
     t, u = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
     seen = (u <= t) if window is None else (u <= t) & (t - u < window)
-    lam = (jnp.exp(lambdas[0] @ lambdas[1]) - jnp.exp(lambdas[2] @ lambdas[3])
-           + lam0)
-    want = []
-    for p in range(4):
-        wide = jnp.concatenate([v[:, :, 2 * (p // 2)],
-                                v[:, :, 2 * (p // 2) + 1]], axis=-1)
-        maps = [jax.nn.softmax(jnp.where(seen, jnp.einsum(
-            "bqd,bkd->bqk", q[:, :, 2 * p + j], k[:, :, 2 * (p // 2) + j])
-            / 4.0, -jnp.inf), axis=-1) for j in range(2)]
-        want.append(subln(maps[0] @ wide - lam * (maps[1] @ wide))
-                    * (1.0 - lam0))
-    np.testing.assert_allclose(got, jnp.stack(want, axis=2), atol=2e-5)
+
+    def subln(t, scale):
+        return t * jax.lax.rsqrt(
+            jnp.mean(t * t, axis=-1, keepdims=True) + 1e-5) * scale
+
+    def program(q, k, v, lambdas, scale):
+        return _attend_differential(
+            cfg, q, k, v, jnp.arange(s), kind, lambdas=lambdas,
+            subln=lambda t: subln(t, scale), lambda_init=lam0)
+
+    def dense(q, k, v, lambdas, scale):
+        lam = (jnp.exp(lambdas[0] @ lambdas[1])
+               - jnp.exp(lambdas[2] @ lambdas[3]) + lam0)
+        want = []
+        for p in range(4):
+            wide = jnp.concatenate([v[:, :, 2 * (p // 2)],
+                                    v[:, :, 2 * (p // 2) + 1]], axis=-1)
+            maps = [jax.nn.softmax(jnp.where(seen, jnp.einsum(
+                "bqd,bkd->bqk", q[:, :, 2 * p + j], k[:, :, 2 * (p // 2) + j])
+                / 4.0, -jnp.inf), axis=-1) for j in range(2)]
+            want.append(subln(maps[0] @ wide - lam * (maps[1] @ wide), scale)
+                        * (1.0 - lam0))
+        return jnp.stack(want, axis=2)
+
+    args = (q, k, v, lambdas, scale)
+    np.testing.assert_allclose(program(*args), dense(*args), atol=2e-5)
+    got, want = (jax.grad(lambda *a: (f(*a) * weight).sum(),
+                          argnums=(0, 1, 2, 3, 4))(*args)
+                 for f in (program, dense))
+    for name, a, r in zip(("q", "k", "v", "lambdas", "scale"), got, want):
+        for leaf, want_leaf in zip(jax.tree.leaves(a), jax.tree.leaves(r)):
+            np.testing.assert_allclose(leaf, want_leaf, atol=5e-5,
+                                       rtol=2e-3, err_msg=name)
     assert lam0 == pytest.approx(0.8 - 0.6 * math.exp(-5.1))

@@ -431,9 +431,9 @@ def test_ssd_scan_refuses_a_shape_over_its_vmem(one_chip, compiled_kernels):
 # phi-4-mini-flash-reasoning's two kernels at the published widths and the
 # benchmark cell's 1 x 8192 tokens (benchmark/workloads/
 # phi4mf_train_s8192.json): the Mamba-1 selective scan over 5120 channels
-# of 16 states, and differential attention's one stacked flash call (four
-# times 20 pairs of sub-heads over four times 10, head size 64), banded
-# at window 512 (one K tile wide) and full.
+# of 16 states, and differential attention's one flash call (40 query
+# sub-heads of 64 over 20 rows of keys of 64 and values of 128: the pairs'
+# own shape), banded at window 512 (one K tile wide) and full.
 @pytest.mark.parametrize("direction,kernels,temporaries_mib", [
     ("forward", ["sscan_fwd"], 96), ("backward", ["sscan_fwd", "sscan_bwd"],
                                      480)])
@@ -493,12 +493,16 @@ def test_selective_scan_refuses_what_its_tiles_cannot_take(
 
 @pytest.mark.parametrize("window", [512, None])
 def test_differential_flash_call_compiles_for_v5e(one_chip, window):
-    """80 query rows over 40 key/value rows of 64 at 8192 tokens, forward
-    and the one-kernel backward."""
-    q = jax.ShapeDtypeStruct((1, 8192, 80, 64), jnp.bfloat16,
+    """40 query rows of 64 over 20 key/value rows, the values 128 wide,
+    at 8192 tokens: forward and the one-kernel backward with a kv row's
+    dk ``[8192, 64]`` and dv ``[8192, 128]`` resident, inside the 32 MiB
+    the call states."""
+    q = jax.ShapeDtypeStruct((1, 8192, 40, 64), jnp.bfloat16,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, 8192, 40, 64), jnp.bfloat16,
-                              sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 8192, 20, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 8192, 20, 128), jnp.bfloat16,
+                             sharding=one_chip)
 
     def backward(q, k, v):
         return jax.grad(
@@ -507,7 +511,7 @@ def test_differential_flash_call_compiles_for_v5e(one_chip, window):
                 interpret=False).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
-    text = jax.jit(backward).lower(q, kv, kv).compile().as_text()
+    text = jax.jit(backward).lower(q, k, v).compile().as_text()
     assert "flash_fwd" in text and "flash_bwd_dkdv" in text
     assert "flash_bwd_dq" not in text
 
