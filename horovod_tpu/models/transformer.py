@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import scopes
+from ..parallel.moe import ACTIVATIONS, SCORE_RULES
 from ..parallel.moe import DEFAULT_GROUP_SIZE as MOE_DEFAULT_GROUP_SIZE
 
 
@@ -176,6 +177,28 @@ class TransformerConfig:
     routed_scaling: float = 1.0
     shared_experts: int = 0
     dense_layers_first: int = 0
+    # What the router reads: "ffn_input", the normed stream the experts
+    # read (after the attention half), or "layer_input", the residual
+    # stream as the layer receives it, before ln1: the decision is then
+    # made ahead of the attention half and applied to the normed stream
+    # after it (parallel/moe.py: routing_decision, apply_routing).
+    routed_router_input: str = "ffn_input"
+    # How the router's outputs become a choice and weights
+    # (parallel/moe.py:SCORE_RULES): "sigmoid" scores with the selection
+    # bias and routed_scaling, or "softmax_chosen", the routed_top_k
+    # largest raw logits and a softmax over those alone; that rule has no
+    # bias, so the layer keeps no "moe_state" and nothing but a loss
+    # holds the load even.
+    routed_scores: str = "sigmoid"
+    # The gate's activation in a routed expert: act(x W_gate) * (x W_up)
+    # (parallel/moe.py:ACTIVATIONS).
+    routed_activation: str = "silu"
+    # > 0: every expert layer sows its load-balance loss (E * sum_e f_e
+    # P_e over the layer's own tokens, 1.0 at an even load; scope
+    # "moe_balance") into the "losses" collection as "moe_balance" and
+    # keeps it in "moe_stats"; the training step adds
+    # routed_balance_coef * their sum to its loss.
+    routed_balance_coef: float = 0.0
     # Multi-token-prediction modules after the last block (0 or 1): the
     # model then also returns logits for the token after next wherever
     # it is handed next_tokens.
@@ -310,6 +333,18 @@ class TransformerConfig:
                     f"0 < routed_top_k <= routed_experts, a routed_width "
                     f"and held experts {self.routed_first_held}.."
                     f"{self.routed_first_held + held - 1} among them")
+        for setting, allowed in (
+                ("routed_router_input", ("ffn_input", "layer_input")),
+                ("routed_scores", SCORE_RULES),
+                ("routed_activation", tuple(ACTIVATIONS))):
+            if getattr(self, setting) not in allowed:
+                raise ValueError(
+                    f"{setting} must be one of {allowed}, got "
+                    f"{getattr(self, setting)!r}")
+        if self.routed_balance_coef < 0:
+            raise ValueError(
+                f"routed_balance_coef={self.routed_balance_coef} is a "
+                f"loss's weight: it must not be negative")
         if self.layer_types is None and (
                 self.shared_kv_layer is not None
                 or self.memory_layer is not None):
@@ -437,7 +472,9 @@ def require_gpt2_block(cfg: TransformerConfig, who: str) -> None:
         raise ValueError(
             f"{who} runs attention layers only: layer_types="
             f"{cfg.layer_types!r} holds a layer it has no state for")
-    for setting in ("routed_experts", "mtp_modules", "norm", "norm_eps",
+    for setting in ("routed_experts", "routed_router_input", "routed_scores",
+                    "routed_activation", "routed_balance_coef",
+                    "mtp_modules", "norm", "norm_eps",
                     "mlp", "use_bias", "tie_embeddings",
                     "embedding_multiplier", "residual_multiplier",
                     "logits_scaling", "attention_scale", "head_size",
@@ -739,7 +776,7 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
                q_norm=None, k_norm=None, gate=None,
                post_attn_norm=None, post_mlp_norm=None,
                gmu=None, differential=None, shared_kv=None,
-               hand_on: Optional[str] = None):
+               hand_on: Optional[str] = None, route=None):
     """THE pre-norm block wiring — the single source of truth.
 
     ``norm → mixer → (+res) → norm → feed-forward → (+res)``, each
@@ -786,6 +823,14 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
     (scope ``attn_cross`` around the attention call).  ``differential``
     holds ``lambdas``, ``subln`` and ``lambda_init`` where the attention
     is differential (:func:`_attend_differential`, scope ``attn_diff``).
+
+    ``route``: where the configuration's router reads the layer's input,
+    a callable applied to the block's input ``x`` before ``ln1`` (it
+    traces under the scope ``moe_route``, here at the block's top); what
+    it decides is handed to ``mlp`` as a second argument, which applies
+    it to ``ln2`` of the stream after the mixer.  In a rematerialised
+    block the decision crosses the mixer half inside one
+    ``jax.checkpoint``, and its recompute is the sort again.
     """
     b, s, _ = x.shape
     nh = num_heads if num_heads is not None else cfg.num_heads
@@ -806,6 +851,7 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
     # fusions.  A scope is metadata: it names no parameter, so the flax
     # tree stays ``block<i>/{ln1,qkv,proj,ln2,fc1,fc2}``.
     handed = None
+    decided = () if route is None else (route(x),)
     if ssm is not None:
         with jax.named_scope(scopes.SSM):
             delta = ssm(ln1(x))
@@ -861,7 +907,7 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
             att = act_store(att, cfg)
             x = add(x, act_store(proj(att), cfg), post_attn_norm)
     with jax.named_scope(scopes.MLP):
-        x = add(x, act_store(mlp(ln2(x)), cfg), post_mlp_norm)
+        x = add(x, act_store(mlp(ln2(x), *decided), cfg), post_mlp_norm)
     return x if hand_on is None else (x, handed)
 
 
@@ -979,29 +1025,48 @@ class Block(nn.Module):
                 m = nn.gelu(layer(wide, fc1)(h))
             return layer(cfg.emb_dim, fc2)(act_store(m, cfg))
 
-        def routed(h):
+        def decide(x2):
+            """The router's decision for the flat tokens ``x2 [n, d]``
+            (parallel/moe.py:routing_decision): the stream the experts
+            read, or the layer's input where the router stands before
+            attention."""
+            from ..parallel.moe import routing_decision  # noqa: PLC0415
+
+            bias = None
+            if cfg.routed_scores == "sigmoid":
+                bias = self.variable(
+                    "moe_state", "bias", lambda: jax.random.uniform(
+                        self.make_rng("params"), (cfg.routed_experts,),
+                        jnp.float32, -0.05, 0.05)).value
+            router = self.param("router", nn.initializers.normal(0.02),
+                                (x2.shape[-1], cfg.routed_experts),
+                                jnp.float32)
+            return routing_decision(
+                x2, router, bias, top_k=cfg.routed_top_k,
+                scaling=cfg.routed_scaling,
+                first_held=cfg.routed_first_held, held=cfg.held_experts,
+                score_rule=cfg.routed_scores,
+                balance=cfg.routed_balance_coef > 0)
+
+        def routed(h, routing=None):
             """Routed experts that drop nothing, the shared expert
-            beside them (parallel/moe.py has the core)."""
-            from ..parallel.moe import routed_experts  # noqa: PLC0415
+            beside them (parallel/moe.py has the core), by the decision
+            made from the layer's input or, without one, from ``h``."""
+            from ..parallel.moe import apply_routing  # noqa: PLC0415
 
             b, s, d = h.shape
             held, ff = cfg.held_experts, cfg.routed_width
             stacked = nn.initializers.lecun_normal(batch_axis=(0,))
-            bias = self.variable(
-                "moe_state", "bias", lambda: jax.random.uniform(
-                    self.make_rng("params"), (cfg.routed_experts,),
-                    jnp.float32, -0.05, 0.05))
-            y, routing = routed_experts(
-                h.reshape(b * s, d),
-                self.param("router", nn.initializers.normal(0.02),
-                           (d, cfg.routed_experts), jnp.float32),
-                bias.value,
+            x2 = h.reshape(b * s, d)
+            if routing is None:
+                routing = decide(x2)
+            y, routing = apply_routing(
+                routing, x2,
                 self.param("experts_fc1", stacked, (held, d, 2 * ff),
                            jnp.float32),
                 self.param("experts_fc2", stacked, (held, ff, d),
                            jnp.float32),
-                top_k=cfg.routed_top_k, scaling=cfg.routed_scaling,
-                first_held=cfg.routed_first_held, dtype=cfg.dtype,
+                dtype=cfg.dtype, activation=cfg.routed_activation,
                 # initialising makes variables from shapes: the grouped
                 # matmul's stand-in keeps the kernel out of that program
                 interpret=True if self.is_initializing() else None)
@@ -1020,6 +1085,13 @@ class Block(nn.Module):
                 if not self.is_initializing():
                     overflows.value = overflows.value + jnp.asarray(
                         routing.overflowed, jnp.int32)
+                if routing.balance is not None:
+                    self.variable("moe_stats", "balance_loss",
+                                  lambda: None).value = routing.balance
+            if routing.balance is not None:
+                # as the GShard path sows its auxiliary loss: the step
+                # adds routed_balance_coef times the layers' sum
+                self.sow("losses", "moe_balance", routing.balance)
             y = y.reshape(b, s, d)
             if cfg.shared_experts > 0:
                 with jax.named_scope(scopes.MOE_SHARED):
@@ -1027,9 +1099,9 @@ class Block(nn.Module):
                                          "shared_fc1", "shared_fc2")
             return y
 
-        def mlp(h):
+        def mlp(h, routing=None):
             if self.ffn == "routed":
-                return routed(h)
+                return routed(h, routing)
             if cfg.moe_experts > 0:
                 from ..parallel.moe import (  # noqa: PLC0415
                     moe_flax_params, moe_mlp,
@@ -1159,6 +1231,8 @@ class Block(nn.Module):
         if cfg.post_norms:
             mixer["post_attn_norm"] = _norm(cfg, "post_attn_norm")
             mixer["post_mlp_norm"] = _norm(cfg, "post_mlp_norm")
+        if self.ffn == "routed" and cfg.routed_router_input == "layer_input":
+            mixer["route"] = lambda x: decide(x.reshape(-1, x.shape[-1]))
         return block_math(
             cfg, x, positions, rope_tabs,
             ln1=_norm(cfg, "ln1"), ln2=_norm(cfg, "ln2"), mlp=mlp,
@@ -1478,6 +1552,36 @@ GPT_CONFIGS = {
         # block: keep each block's input and, as every policy does, what
         # its kernels made (the scan's y 80 MiB and states 20 MiB, a
         # differential layer's o 160 MiB and lse 2.5 MiB)
+        remat_policy="nothing_saveable",
+    ),
+    # https://huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct
+    # config.json (model_name smallthinker_21b_instruct): 28 query heads
+    # over 4 key/value heads of 128, layer 0 of every four a
+    # full_attention layer without positions, the other three
+    # sliding_attention layers (window 4096, rotary, theta 1.5e6); in
+    # every layer 64 routed experts of 768, 6 a token, no shared expert,
+    # no dense layer; the router reads the layer's input ahead of
+    # attention, its weights are a softmax over the six chosen logits
+    # (no selection bias), the experts' gate is a ReLU; a load-balance
+    # loss keeps the load even (its coefficient is the family's
+    # convention, not a published key); an untied head.
+    # Training path only (require_gpt2_block says who refuses it).
+    "smallthinker-21ba3b-instruct": TransformerConfig(
+        vocab_size=151936, num_layers=52, emb_dim=2560, max_len=16384,
+        layer_types=tuple(
+            "full_attention" if i % 4 == 0 else "sliding_attention"
+            for i in range(52)),
+        num_heads=28, num_kv_heads=4, head_size=128,
+        attention_window=4096, pos_embedding="rope", rope_theta=1.5e6,
+        rope_layer_types=("sliding_attention",),
+        mlp="silu_gated", norm="rmsnorm", norm_eps=1e-6,
+        use_bias=False, tie_embeddings=False,
+        routed_experts=64, routed_top_k=6, routed_width=768,
+        routed_router_input="layer_input", routed_scores="softmax_chosen",
+        routed_activation="relu", routed_balance_coef=0.001,
+        # 16384 x 4608 queries, keys and values a block: keep each
+        # block's input and, as every policy does, what its kernels made
+        # (o 112 MiB and lse 1.75 MiB a block)
         remat_policy="nothing_saveable",
     ),
 }

@@ -32,8 +32,13 @@ result.  The sort puts the held experts' rows first, so the layer works
 on a static number of the sort's first rows (:func:`row_bound`, from the
 shapes) and not on every slot, with the whole-buffer computation behind a
 ``lax.cond`` for the step whose held rows pass that.  On one chip it runs
-without its exchange.  The GShard path above is as it was; ROADMAP C6 has
-the folding of the two.
+without its exchange.  The layer is two halves, a decision
+(:func:`routing_decision`: which experts, with which weights, and the
+sort) and its application (:func:`apply_routing`), because a model may
+decide from one tensor and dispatch another (a router that reads the
+layer's input, ahead of attention); :func:`routed_experts` is the two on
+one tensor.  The GShard path above is as it was; ROADMAP C6 has the
+folding of the two.
 """
 
 from __future__ import annotations
@@ -49,7 +54,8 @@ from .. import scopes
 
 __all__ = ["init_moe_params", "moe_mlp", "moe_mlp_ep", "MoEParams",
            "Routing", "route", "grouped_ffn", "routed_experts", "row_bound",
-           "rebalanced", "publish_stats"]
+           "routing_decision", "apply_routing", "rebalanced",
+           "publish_stats", "SCORE_RULES", "ACTIVATIONS"]
 
 # Initialization scheme, shared by the raw-NamedTuple and flax paths so
 # the two can never drift: small-normal router, fan-in-scaled FFN.
@@ -327,26 +333,58 @@ class Routing(NamedTuple):
                             # experts, what the balancing update reads
     overflowed: jax.Array = False  # bool scalar: the held experts' rows
                             # passed the layer's row bound, so it ran on
-                            # the whole slot buffer (``routed_experts``
+                            # the whole slot buffer (``apply_routing``
                             # sets it; ``route`` knows no bound)
+    inverse: Optional[jax.Array] = None  # [n*k] int32: slot -> sorted row
+                            # (``routing_decision`` sets it)
+    balance: Optional[jax.Array] = None  # float32 scalar, where asked for:
+                            # E * sum_e f_e P_e, 1.0 at an even load
+
+
+# How a router's outputs become a choice and its weights (``route``).
+SCORE_RULES = ("sigmoid", "softmax_chosen")
 
 
 def route(x2, router, bias, *, top_k: int, scaling: float,
-          first_held: int, held: int) -> Routing:
-    """Sigmoid scores over ALL experts, in float32 whatever the stream's
-    dtype (a choice is discrete: a score rounded to bfloat16 picks
-    another expert); the ``top_k`` largest of ``score + bias``;
-    weights ``score / (sum of the chosen scores + 1e-20) * scaling``.
-    ``bias`` (the aux-free balancing correction) moves the choice and
-    never a weight, and takes no gradient.  Experts ``first_held`` to
-    ``first_held + held - 1`` are this chip's."""
+          first_held: int, held: int, score_rule: str = "sigmoid",
+          balance: bool = False) -> Routing:
+    """Scores over ALL experts, in float32 whatever the stream's dtype (a
+    choice is discrete: a score rounded to bfloat16 picks another
+    expert), by ``score_rule``.  ``"sigmoid"``: sigmoid scores; the
+    ``top_k`` largest of ``score + bias``; weights ``score / (sum of the
+    chosen scores + 1e-20) * scaling``; ``bias`` (the aux-free balancing
+    correction) moves the choice and never a weight, and takes no
+    gradient.  ``"softmax_chosen"``: the ``top_k`` largest raw logits and
+    a softmax over those alone (the full softmax renormalised over the
+    chosen), times ``scaling``; no bias (``None``), so nothing here holds
+    the load even.  Experts ``first_held`` to ``first_held + held - 1``
+    are this chip's.
+
+    ``balance`` asks for the load-balance loss beside the decision (scope
+    ``moe_balance``): ``E * sum_e f_e P_e``, ``f_e`` the share of the
+    ``n * top_k`` slots that chose expert ``e`` (a count: no gradient),
+    ``P_e`` the mean over tokens of the full softmax of the logits; 1.0
+    at an even load, and its gradient reaches the router through ``P``
+    alone."""
     n = x2.shape[0]
-    scores = jax.nn.sigmoid(jnp.dot(
+    logits = jnp.dot(
         x2.astype(jnp.float32), router.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
-    _, experts = lax.top_k(scores + lax.stop_gradient(bias), top_k)
-    chosen = jnp.take_along_axis(scores, experts, axis=-1)
-    weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scaling
+        precision=lax.Precision.HIGHEST)
+    if score_rule == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, experts = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+        chosen = jnp.take_along_axis(scores, experts, axis=-1)
+        weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scaling
+    elif score_rule == "softmax_chosen":
+        if bias is not None:
+            raise ValueError(
+                "score_rule='softmax_chosen' chooses by the raw logits: "
+                "it takes no selection bias")
+        chosen, experts = lax.top_k(logits, top_k)
+        weights = jax.nn.softmax(chosen, axis=-1) * scaling
+    else:
+        raise ValueError(f"score_rule must be one of {SCORE_RULES}, got "
+                         f"{score_rule!r}")
     local = experts.reshape(n * top_k) - first_held
     is_held = (local >= 0) & (local < held)
     key = jnp.where(is_held, local, held)
@@ -355,8 +393,15 @@ def route(x2, router, bias, *, top_k: int, scaling: float,
     dropped = is_held.sum(dtype=jnp.int32) - group_sizes[:held].sum()
     load = jnp.zeros((router.shape[1],), jnp.int32).at[
         experts.reshape(n * top_k)].add(1)
-    return Routing(weights, experts.astype(jnp.int32), order, group_sizes,
-                   dropped, load)
+    routing = Routing(weights, experts.astype(jnp.int32), order, group_sizes,
+                      dropped, load)
+    if balance:
+        with jax.named_scope(scopes.MOE_BALANCE):
+            share = load.astype(jnp.float32) / (n * top_k)
+            mean = jax.nn.softmax(logits, axis=-1).mean(axis=0)
+            routing = routing._replace(
+                balance=router.shape[1] * jnp.sum(share * mean))
+    return routing
 
 
 def rebalanced(moe_state, moe_stats, rate: float, axis_name=None):
@@ -479,57 +524,64 @@ def _gmm_bwd(lhs, rhs, group_sizes, interpret: bool, grad):
                  _tiling(lhs, rhs), num_actual_groups=rhs.shape[0]))
 
 
+# The gate's activation in a gated expert: act(x W_gate) * (x W_up).
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def grouped_ffn(xs, gate_up, down, group_sizes, *, dtype=jnp.bfloat16,
-                interpret: bool = False):
-    """The gated feed-forward ``W_down(silu(x W_gate) * (x W_up))`` of
-    every held expert on its own rows: ``xs [rows, d]`` in expert order,
+                interpret: bool = False, activation: str = "silu"):
+    """The gated feed-forward ``W_down(act(x W_gate) * (x W_up))`` of
+    every held expert on its own rows (``activation``, a name in
+    ``ACTIVATIONS``: silu, or relu): ``xs [rows, d]`` in expert order,
     ``gate_up [held, d, 2 ff]`` (gate then up), ``down [held, ff, d]``,
     ``group_sizes [held + 1]`` that add up to ``rows``: the held experts'
     rows, then what is left of ``rows``, which no expert here computes
     and which comes out zero (``_gmm``).  ``rows`` is what the caller
-    gathered: ``routed_experts`` runs this feed-forward (``_ffn``, with
+    gathered: ``apply_routing`` runs this feed-forward (``_ffn``, with
     the backward rule below) on the sort's first ``row_bound`` rows, not
     on the slot buffer."""
-    return _grouped_ffn(interpret, xs.astype(dtype), gate_up.astype(dtype),
-                        down.astype(dtype), group_sizes)
+    return _grouped_ffn(interpret, activation, xs.astype(dtype),
+                        gate_up.astype(dtype), down.astype(dtype),
+                        group_sizes)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _grouped_ffn(interpret, xs, gate_up, down, sizes):
-    return _ffn(xs, gate_up, down, sizes, interpret)[0]
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _grouped_ffn(interpret, activation, xs, gate_up, down, sizes):
+    return _ffn(xs, gate_up, down, sizes, interpret, activation)[0]
 
 
-def _grouped_ffn_fwd(interpret, xs, gate_up, down, sizes):
-    ys, h = _ffn(xs, gate_up, down, sizes, interpret)
+def _grouped_ffn_fwd(interpret, activation, xs, gate_up, down, sizes):
+    ys, h = _ffn(xs, gate_up, down, sizes, interpret, activation)
     return ys, (xs, h, gate_up, down, sizes)
 
 
-def _grouped_ffn_bwd(interpret, kept, d_ys):
-    return (*_ffn_bwd(*kept, interpret, d_ys), None)
+def _grouped_ffn_bwd(interpret, activation, kept, d_ys):
+    return (*_ffn_bwd(*kept, interpret, activation, d_ys), None)
 
 
 _grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)
 
 
-def _gate(h):
-    """``silu(gate) * up`` of ``h = [gate | up]``."""
+def _gate(activation: str, h):
+    """``act(gate) * up`` of ``h = [gate | up]``."""
     ff = h.shape[1] // 2
-    return (jax.nn.silu(h[:, :ff]) * h[:, ff:]).astype(h.dtype)
+    return (ACTIVATIONS[activation](h[:, :ff]) * h[:, ff:]).astype(h.dtype)
 
 
-def _ffn(xs, gate_up, down, sizes, interpret: bool):
+def _ffn(xs, gate_up, down, sizes, interpret: bool, activation: str):
     """``grouped_ffn`` on operands of one dtype: the rows' outputs and
     ``h = xs W_[gate | up]``, what its backward pass reads again."""
     with jax.named_scope(scopes.MOE_EXPERTS):
         h = _gmm(xs, gate_up, sizes, interpret)
-        return _gmm(_gate(h), down, sizes, interpret), h
+        return _gmm(_gate(activation, h), down, sizes, interpret), h
 
 
-def _ffn_bwd(xs, h, gate_up, down, sizes, interpret: bool, d_ys):
+def _ffn_bwd(xs, h, gate_up, down, sizes, interpret: bool, activation: str,
+             d_ys):
     """``_ffn``'s gradients by ``xs`` and both matrices, from ``xs`` and
     ``h``: no grouped matmul of the forward pass runs again."""
     with jax.named_scope(scopes.MOE_EXPERTS):
-        act, gate_bwd = jax.vjp(_gate, h)
+        act, gate_bwd = jax.vjp(partial(_gate, activation), h)
         d_act, d_down = _gmm_bwd(act, down, sizes, interpret, d_ys)
         d_xs, d_gate_up = _gmm_bwd(xs, gate_up, sizes, interpret,
                                    *gate_bwd(d_act))
@@ -550,9 +602,9 @@ def _head(rows: int, order, held_sizes):
 # ``[k, n, d]`` rows out in float32 before it sums them, 10 ms of a 320 ms
 # step where 16 of 128 experts are held, 0.7 of 447 where 8 of 64 are
 # (PERF.md section 6).
-@partial(jax.jit, static_argnums=(0, 1))
-def _forward(rows: int, interpret: bool, x2, weights, order, inverse,
-             held_sizes, gate_up, down):
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _forward(rows: int, interpret: bool, activation: str, x2, weights, order,
+             inverse, held_sizes, gate_up, down):
     """The held experts' weighted outputs in token order ``[n, d]``
     (float32), computed on the first ``rows`` rows of the sort (static;
     the held experts' rows are among them when ``held_sizes.sum() <=
@@ -564,16 +616,16 @@ def _forward(rows: int, interpret: bool, x2, weights, order, inverse,
     head, sizes = _head(rows, order, held_sizes)
     with jax.named_scope(scopes.MOE_DISPATCH):
         xs = _rows(x2, head // k)
-    ys, h = _ffn(xs, gate_up, down, sizes, interpret)
+    ys, h = _ffn(xs, gate_up, down, sizes, interpret, activation)
     with jax.named_scope(scopes.MOE_DISPATCH):
         y = jnp.einsum("knd,nk->nd",
                        _slots(ys, inverse, k).astype(jnp.float32), weights)
     return y, (xs, h, ys)
 
 
-@partial(jax.jit, static_argnums=(0, 1))
-def _backward(rows: int, interpret: bool, kept, weights, order, inverse,
-              held_sizes, gate_up, down, g):
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _backward(rows: int, interpret: bool, activation: str, kept, weights,
+              order, inverse, held_sizes, gate_up, down, g):
     """``_forward``'s gradients by the tokens, the weights and both
     matrices, on ``[rows, .]`` buffers alone: the tokens' gradients
     gathered to the rows (``g[head // k]``), weighted for the experts'
@@ -589,14 +641,16 @@ def _backward(rows: int, interpret: bool, kept, weights, order, inverse,
         d_weights = _slots((ys.astype(jnp.float32) * g_rows).sum(-1),
                            inverse, k).T
     d_xs, d_gate_up, d_down = _ffn_bwd(xs, h, gate_up, down, sizes,
-                                       interpret, d_ys.astype(ys.dtype))
+                                       interpret, activation,
+                                       d_ys.astype(ys.dtype))
     with jax.named_scope(scopes.MOE_DISPATCH):
         d_x2 = _slots(d_xs, inverse, k).sum(axis=0, dtype=jnp.float32)
     return d_x2.astype(d_xs.dtype), d_weights, d_gate_up, d_down
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _experts(bound: int, interpret: bool, overflowed, operands):
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _experts(bound: int, interpret: bool, activation: str, overflowed,
+             operands):
     """``_forward`` on the sort's first ``bound`` rows, or on every slot
     in a step whose held rows pass ``bound`` (``overflowed``, a device
     value; ``None`` where the bound is every slot, and then there is no
@@ -605,34 +659,35 @@ def _experts(bound: int, interpret: bool, overflowed, operands):
     once forward and once backward, and the side not taken hands nothing
     over but zeros of the bounded side's ``[bound, .]`` shapes (it
     computes its own forward again, in the step that takes it)."""
-    return _experts_fwd(bound, interpret, overflowed, operands)[0]
+    return _experts_fwd(bound, interpret, activation, overflowed,
+                        operands)[0]
 
 
-def _experts_fwd(bound, interpret, overflowed, operands):
-    bounded = partial(_forward, bound, interpret)
+def _experts_fwd(bound, interpret, activation, overflowed, operands):
+    bounded = partial(_forward, bound, interpret, activation)
     if overflowed is None:
         y, kept = bounded(*operands)
     else:
         def whole(*operands):
-            y, kept = _forward(operands[2].shape[0], interpret,
-                                     *operands)
+            y, kept = _forward(operands[2].shape[0], interpret, activation,
+                               *operands)
             return y, jax.tree.map(lambda a: jnp.zeros_like(a[:bound]), kept)
 
         y, kept = lax.cond(overflowed, whole, bounded, *operands)
     return y, (overflowed, operands, kept)
 
 
-def _experts_bwd(bound, interpret, res, g):
+def _experts_bwd(bound, interpret, activation, res, g):
     overflowed, operands, kept = res
-    bounded = partial(_backward, bound, interpret)
+    bounded = partial(_backward, bound, interpret, activation)
     if overflowed is None:
         grads = bounded(kept, *operands[1:], g)
     else:
         def whole(_, *rest):
             slots = operands[2].shape[0]
-            _, kept = _forward(slots, interpret, operands[0],
-                                     *rest[:-1])
-            return _backward(slots, interpret, kept, *rest)
+            _, kept = _forward(slots, interpret, activation, operands[0],
+                               *rest[:-1])
+            return _backward(slots, interpret, activation, kept, *rest)
 
         grads = lax.cond(overflowed, whole, bounded, kept, *operands[1:], g)
     d_x2, d_weights, d_gate_up, d_down = grads
@@ -642,13 +697,32 @@ def _experts_bwd(bound, interpret, res, g):
 _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
-def routed_experts(x2, router, bias, gate_up, down, *, top_k: int,
-                   scaling: float, first_held: int = 0,
-                   dtype=jnp.bfloat16, interpret: Optional[bool] = None):
-    """The routed part of a dropless expert layer on flat tokens
-    ``x2 [n, d]``: ``sum over the chosen AND held experts e of
-    w_e FFN_e(x)``.  What the experts held elsewhere would have added is
-    left out (their share of the weights is not renormalised away).
+def routing_decision(x2, router, bias, *, top_k: int, scaling: float,
+                     first_held: int, held: int,
+                     score_rule: str = "sigmoid",
+                     balance: bool = False) -> Routing:
+    """The first half of the layer, from the tensor the router reads
+    (``x2 [n, d]``): :func:`route` and the sort's inverse, under the
+    scope ``moe_route``.  What it returns says everything
+    :func:`apply_routing` needs about ``n`` tokens, so the tensor that is
+    dispatched may be another one of as many rows (the stream after
+    attention, for a router that reads the layer's input)."""
+    n = x2.shape[0]
+    with jax.named_scope(scopes.MOE_ROUTE):
+        routing = route(x2, router, bias, top_k=top_k, scaling=scaling,
+                        first_held=first_held, held=held,
+                        score_rule=score_rule, balance=balance)
+        inverse = jnp.zeros_like(routing.order).at[routing.order].set(
+            jnp.arange(n * top_k, dtype=jnp.int32))
+    return routing._replace(inverse=inverse)
+
+
+def apply_routing(routing: Routing, x2, gate_up, down, *,
+                  dtype=jnp.bfloat16, interpret: Optional[bool] = None,
+                  activation: str = "silu"):
+    """The second half: ``sum over the chosen AND held experts e of
+    w_e FFN_e(x)`` for the rows of ``x2 [n, d]``, by a decision
+    (:func:`routing_decision`) over ``n`` tokens.
 
     The sort puts the held experts' rows first, so everything between it
     and the result runs on the first ``row_bound(...)`` rows, a static
@@ -670,26 +744,37 @@ def routed_experts(x2, router, bias, gate_up, down, *, top_k: int,
 
         interpret = flash_attention._interpret_for_backend(
             jax.default_backend())
-    n = x2.shape[0]
+    n, top_k = routing.weights.shape
     held = gate_up.shape[0]
-    with jax.named_scope(scopes.MOE_ROUTE):
-        routing = route(x2, router, bias, top_k=top_k, scaling=scaling,
-                        first_held=first_held, held=held)
-        order = routing.order
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(n * top_k, dtype=jnp.int32))
-    bound = row_bound(n, top_k, held, router.shape[1])
+    bound = row_bound(n, top_k, held, routing.load.shape[0])
     held_sizes = routing.group_sizes[:held]
     overflowed = held_sizes.sum() > bound if bound < n * top_k else None
     with jax.named_scope(scopes.MOE_EXPERTS):
         # cast once, outside the branch: both sides read the same copy
         matrices = gate_up.astype(dtype), down.astype(dtype)
-    y = _experts(bound, interpret, overflowed, (
-        x2.astype(dtype), routing.weights, order, inverse, held_sizes,
-        *matrices))
+    y = _experts(bound, interpret, activation, overflowed, (
+        x2.astype(dtype), routing.weights, routing.order, routing.inverse,
+        held_sizes, *matrices))
     if overflowed is not None:
         routing = routing._replace(overflowed=overflowed)
     return y.astype(dtype), routing
+
+
+def routed_experts(x2, router, bias, gate_up, down, *, top_k: int,
+                   scaling: float, first_held: int = 0,
+                   dtype=jnp.bfloat16, interpret: Optional[bool] = None):
+    """The routed part of a dropless expert layer on flat tokens
+    ``x2 [n, d]``, decided from the tensor it dispatches: the two halves
+    (:func:`routing_decision` with sigmoid scores and a selection bias,
+    :func:`apply_routing` with a silu gate) in turn.  What the experts
+    held elsewhere would have added is left out (their share of the
+    weights is not renormalised away).  Returns ``(y [n, d],
+    routing)``."""
+    routing = routing_decision(
+        x2, router, bias, top_k=top_k, scaling=scaling,
+        first_held=first_held, held=gate_up.shape[0])
+    return apply_routing(routing, x2, gate_up, down, dtype=dtype,
+                         interpret=interpret)
 
 
 def publish_stats(stats, registry=None) -> dict:
@@ -701,8 +786,9 @@ def publish_stats(stats, registry=None) -> dict:
     routed to held experts, the largest held expert's rows over the
     mean, the rows dropped, and the steps in which the layer passed its
     row bound and ran on the whole slot buffer (``load`` is the
-    balancing update's).  Read after a step, on the host: never from a
-    callback inside it."""
+    balancing update's), and, where the layer computes it, the last
+    step's load-balance loss (``balance_loss``: 1.0 is an even load).
+    Read after a step, on the host: never from a callback inside it."""
     import numpy as np  # noqa: PLC0415
     from flax.traverse_util import flatten_dict  # noqa: PLC0415
 
@@ -721,6 +807,8 @@ def publish_stats(stats, registry=None) -> dict:
                  "max_over_mean": float(rows.max()) / mean if mean else 0.0,
                  "rows_dropped": int(flat[layer + "/dropped"]),
                  "overflow_steps": int(flat[layer + "/overflow_steps"])}
+        if layer + "/balance_loss" in flat:
+            entry["balance_loss"] = float(flat[layer + "/balance_loss"])
         for name, value in entry.items():
             registry.gauge(f"moe.{name}", layer=layer).set(value)
         out[layer] = entry

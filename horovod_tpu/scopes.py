@@ -42,8 +42,12 @@ ATTN_DIFF = "attn_diff"                # inside attn: differential
                                        # sub-norm and scale (the attention
                                        # calls stay outside it)
 MLP = "mlp"                            # a block's MLP half
-MOE_ROUTE = "moe_route"                # inside mlp: router matmul, sigmoid,
-                                       # top-k, the sort by expert
+MOE_ROUTE = "moe_route"                # router matmul, scores, top-k, the
+                                       # sort by expert: inside mlp, or at
+                                       # the block's top where the router
+                                       # reads the layer's input
+MOE_BALANCE = "moe_balance"            # inside moe_route: the load-balance
+                                       # loss (full softmax, its mean)
 MOE_DISPATCH = "moe_dispatch"          # inside mlp: rows gathered into
                                        # expert order and back
 MOE_EXPERTS = "moe_experts"            # inside mlp: the grouped matmuls
@@ -73,6 +77,6 @@ KERNEL_OUTPUTS = (FLASH_OUT, FLASH_LSE, SSD_OUT, SSD_STATES, SSCAN_OUT,
 
 SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ,
           ATTN_WINDOW, ATTN_GATE, ATTN_CROSS, ATTN_DIFF, SSM, SSD_SCAN,
-          SELECTIVE_SCAN, GMU, MLP, MOE_ROUTE,
+          SELECTIVE_SCAN, GMU, MLP, MOE_ROUTE, MOE_BALANCE,
           MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MTP, EMBED, HEAD, STEM,
           KV_GATHER, KV_SCATTER, SAMPLE)
