@@ -553,7 +553,7 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
     if cfg.attention_impl == "flash":
         from ..obs.registry import get_registry  # noqa: PLC0415
         from ..ops.flash_attention import (  # noqa: PLC0415
-            backward_form, flash_attention, tile_counts,
+            backward_plan, flash_attention, tile_counts,
         )
 
         # counted while the step is traced, like remat.kept_values: the
@@ -562,7 +562,7 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
         live, grid = tile_counts(
             q.shape[0] * q.shape[2], q.shape[1], cfg.flash_block_q,
             cfg.flash_block_k, causal=True, window=window)
-        form = backward_form(
+        form, vmem = backward_plan(
             q.shape[1], q.shape[3], q.shape[2] // k.shape[2],
             q.dtype.itemsize, cfg.flash_block_q, cfg.flash_block_k,
             v.shape[3])
@@ -574,6 +574,8 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
             2 if form == "two_passes" else 1)
         registry.gauge("flash.bwd_dq_resident", layer_type=label).set(
             int(form == "dq_resident"))
+        registry.gauge("flash.bwd_vmem_mib", layer_type=label).set(
+            -(-vmem // 2 ** 20))
         registry.gauge("flash.value_dim", layer_type=label).set(v.shape[3])
         return flash_attention(
             q, k, v, causal=True,

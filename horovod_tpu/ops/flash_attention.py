@@ -33,24 +33,32 @@ Design (the standard flash recurrence, TPU-shaped):
   rowsum(do * o)`` is computed once per call, outside the kernels
   (0.025 ms there), not once per tile.  ONE kernel forms each tile's
   ``p`` and ``ds`` once and takes dq, dk and dv from them; which sums
-  stay resident in VMEM is read from the shape alone (``backward_form``:
-  what each form holds against ``_FUSED_BWD_VMEM_LIMIT``, the VMEM the
-  call states).  Q tile outermost, dq's accumulator and a whole kv row's
-  dk and dv accumulators resident, wherever that fits (a head's channels
-  fill whole 128-lane tiles, so 8192 keys at head size 64 or 128 and
-  4096 keys at head size 256); else K tile outermost, the tile's dk and
-  dv accumulators and the kv row's dq resident (8192 keys at head size
-  256, latent attention's shape in ``glm47f_train_s8192``, up to 26624;
-  16384 keys at head size 64).  A longer sequence takes the two passes
-  the one kernel replaced (dk/dv, then dq, each recomputing ``p`` and
-  ``ds``), whose VMEM does not grow with S.
+  stay resident in VMEM is read from the shape alone (``backward_plan``:
+  what each form holds against ``_FUSED_BWD_VMEM_LIMIT``, the 32 MiB the
+  call then states).  Q tile outermost, dq's accumulator and a whole kv
+  row's dk and dv accumulators resident, wherever that fits (a head's
+  channels fill whole 128-lane tiles, so 8192 keys at head size 64 or
+  128 and 4096 keys at head size 256); else K tile outermost, the tile's
+  dk and dv accumulators and the kv row's dq resident (8192 keys at head
+  size 256, latent attention's shape in ``glm47f_train_s8192``, up to
+  26624; 16384 keys at head size 64).  Where neither fits the 32 MiB,
+  whichever of the two counts less, stating its own count, up to
+  ``_FUSED_BWD_VMEM_CEILING``, 48 of the chip's 128 MiB (PR 44: 16384
+  keys at head size 128 with seven query heads a key/value head,
+  ``smallthinker_train_s16384``, Q tile outermost at 36.25 MiB; up to
+  22016 keys there, 43008 at head size 256 with dq resident).  A longer
+  sequence takes the two passes the one kernel replaced (dk/dv, then dq,
+  each recomputing ``p`` and ``ds``), whose VMEM does not grow with S.
   Per call at 128 x 1024 x 64 (chip runs of PR 29): two passes 0.973 +
   0.679 ms, one kernel 1.025; at 32 query over 8 K/V heads x 8192 x 64,
   10.72 + 7.51 against 12.00.  At 20 x 8192 x 256 (chip runs of PR 38):
   two passes 21.21 ms, one kernel with dq resident 14.49 (2.66 us a
   needed tile for 3.90); the kv row's dk and dv in two spans of 4096
   keys with dq's float32 partials summed after the call 14.60 + 0.61, in
-  four spans 16.25.
+  four spans 16.25.  At 28 query over 4 K/V heads x 16384 x 128 (chip
+  runs of PR 44): two passes 73.30 ms, one kernel stating 37 MiB 40.68
+  (1.38 us a live tile for 2.48); banded at window 4096, 53.45 against
+  25.21 (the two passes walk the 43 232 dead grid steps twice).
 * The value width is the values' own (``dv = v.shape[-1]``; PR 42): ``v``,
   ``o``, ``do`` and dv carry it, ``q``, ``k``, dq and dk the head size
   ``d``, and the default scale stays ``d ** -0.5``.  The kernel bodies
@@ -258,12 +266,16 @@ def _kv_row(zi, h: int, hkv: int):
     return (zi // h) * hkv + (zi % h) // (h // hkv)
 
 
-# The VMEM the one-kernel backward states (``vmem_limit_bytes``): twice
-# a v5e's default scoped limit, a quarter of its VMEM.  A backward whose
-# resident accumulators and tiles fit it runs as one kernel, in the form
-# ``backward_form`` reads from the shape; a longer sequence takes the two
-# passes, whose VMEM does not grow with S.
+# The VMEM a one-kernel backward states (``vmem_limit_bytes``) wherever its
+# resident accumulators and tiles fit it: twice a v5e's default scoped
+# limit, a quarter of its VMEM.  ``backward_plan`` reads the form from
+# the shape against it.
 _FUSED_BWD_VMEM_LIMIT = 32 * 2 ** 20
+# What a call that fits neither form in the limit above may still take
+# as one kernel (PR 44): the form that counts less, stating its own count
+# (a whole MiB) and not the constant, up to three eighths of the VMEM.
+# Above it the two passes, whose VMEM does not grow with S.
+_FUSED_BWD_VMEM_CEILING = 48 * 2 ** 20
 
 
 def _lanes(width: int) -> int:
@@ -310,27 +322,45 @@ def _dq_resident_bwd_vmem_bytes(s: int, d: int, bq: int, bk: int,
     return resident + tiles + 6 * bk * bq * 4
 
 
-def backward_form(seq: int, head_dim: int, group: int, itemsize: int,
+def backward_plan(seq: int, head_dim: int, group: int, itemsize: int,
                   block_q: int = 512, block_k: int = 256,
-                  value_dim: Optional[int] = None) -> str:
-    """Which backward a call of this shape runs, from the shape alone
+                  value_dim: Optional[int] = None):
+    """``(form, vmem_limit_bytes)``: which backward a call of this shape
+    runs and the VMEM its one kernel states, from the shape alone
     (``group`` query heads a key/value head, tiles as ``_pick_block``
     makes them, values ``value_dim`` wide: ``None`` says as wide as the
-    keys) against the VMEM the one kernel states.
+    keys).
     ``"dkdv_resident"``: one kernel, Q tile outermost, a kv row's dk and
-    dv accumulators resident (PR 29's), wherever it fits.
-    ``"dq_resident"``: one kernel, K tile outermost, the group's dq rows
-    resident, where that fits instead.  ``"two_passes"`` above both.
+    dv accumulators resident (PR 29's), wherever it fits
+    ``_FUSED_BWD_VMEM_LIMIT``.  ``"dq_resident"``: one kernel, K tile
+    outermost, the group's dq rows resident, where that fits the limit
+    instead.  Both then state the limit, so a call that fit it before
+    PR 44 is the program it was.  Above both, whichever of the two
+    counts less, if that fits ``_FUSED_BWD_VMEM_CEILING``, stating its
+    own count rounded up to a MiB (16384 keys at head size 128 with
+    seven query heads a key/value head: 36.25 MiB with the Q tile
+    outermost, ``smallthinker_train_s16384``).  ``"two_passes"`` above
+    that, which state nothing (0).
     ``_flash_bwd_pallas`` branches on it and ``models/transformer.py``
     sets its gauges from it while a step is traced."""
     bq, bk = _pick_block(seq, block_q), _pick_block(seq, block_k)
-    if (_fused_bwd_vmem_bytes(seq, head_dim, bq, bk, itemsize, value_dim)
-            <= _FUSED_BWD_VMEM_LIMIT):
-        return "dkdv_resident"
-    if (_dq_resident_bwd_vmem_bytes(seq, head_dim, bq, bk, itemsize, group,
-                                    value_dim) <= _FUSED_BWD_VMEM_LIMIT):
-        return "dq_resident"
-    return "two_passes"
+    q_outer = _fused_bwd_vmem_bytes(seq, head_dim, bq, bk, itemsize,
+                                    value_dim)
+    k_outer = _dq_resident_bwd_vmem_bytes(seq, head_dim, bq, bk, itemsize,
+                                          group, value_dim)
+    if q_outer <= _FUSED_BWD_VMEM_LIMIT:
+        return "dkdv_resident", _FUSED_BWD_VMEM_LIMIT
+    if k_outer <= _FUSED_BWD_VMEM_LIMIT:
+        return "dq_resident", _FUSED_BWD_VMEM_LIMIT
+    count, form = min((q_outer, "dkdv_resident"), (k_outer, "dq_resident"))
+    if count <= _FUSED_BWD_VMEM_CEILING:
+        return form, -(-count // 2 ** 20) * 2 ** 20
+    return "two_passes", 0
+
+
+def backward_form(*shape, **tiles) -> str:
+    """The form alone of ``backward_plan`` (same arguments)."""
+    return backward_plan(*shape, **tiles)[0]
 
 
 def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
@@ -453,7 +483,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
     ``backward_form`` reads from the shape; no partial sum goes through
     HBM in either.
     ``"dkdv_resident"`` (grid z, nq, nk), wherever
-    ``_fused_bwd_vmem_bytes`` fits ``_FUSED_BWD_VMEM_LIMIT``: Q tile
+    ``_fused_bwd_vmem_bytes`` fits ``_FUSED_BWD_VMEM_LIMIT`` (or, past
+    it in both forms, counts less than the other and fits
+    ``_FUSED_BWD_VMEM_CEILING``, the call stating that count): Q tile
     fixed, K tiles stream.  dq accumulates as [d, bq], turned once at
     the flush; dk and dv accumulate in float32 scratch buffers of [S, d]
     and [S, dv] (the values' width: v, do, o and dv carry it, q, k, dq
@@ -476,8 +508,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
     256, where the other does not fit, 14.49 ms against the two passes'
     21.21, the same stating 16, 24 or 32 MiB (chip runs of PR 38).
 
-    Two passes above both limits, each recomputing P and dS, VMEM
-    independent of S:
+    Two passes above the ceiling in both forms, each recomputing P and
+    dS, VMEM independent of S:
     Pass A (grid z_kv, nk, nq*group; ``flash_bwd_dkdv``): the
     K-outermost kernel without dq.
     Pass B (grid z, nq, nk; ``flash_bwd_dq``): Q tile fixed, K tiles
@@ -488,7 +520,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
     group = h // hkv
     nq, nk = s // bq, s // bk
     f32 = jnp.float32
-    form = backward_form(s, d, group, q.dtype.itemsize, bq, bk, dv)
+    form, vmem_limit = backward_plan(s, d, group, q.dtype.itemsize, bq, bk,
+                                     dv)
     with_dq = form == "dq_resident"   # the K-outermost kernel takes dq too
     # delta is computed once per call and shared by all kernels, which
     # read it and lse as (1, bq) rows of a [Z, nq, 1, bq] view (a block
@@ -685,7 +718,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
             compiler_params=pltpu.CompilerParams(
                 # dk and dv accumulate across all three axes
                 dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-                vmem_limit_bytes=_FUSED_BWD_VMEM_LIMIT,
+                vmem_limit_bytes=vmem_limit,
             ),
             interpret=interpret,
             name="flash_bwd_dkdv",
@@ -719,7 +752,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
         compiler_params = pltpu.CompilerParams(
             # dq accumulates across the K tiles too
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=_FUSED_BWD_VMEM_LIMIT,
+            vmem_limit_bytes=vmem_limit,
         )
     dk, dvalues, *dq = pl.pallas_call(
         kernel_k_outer,

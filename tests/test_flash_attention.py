@@ -642,10 +642,10 @@ def test_one_kernel_backward_matches_two_passes_and_oracle(
     assert fa.backward_form(s, d, h // hkv, q.dtype.itemsize, bq,
                             bk) == "dkdv_resident"
     with monkeypatch.context() as forced:
-        forced.setattr(fa, "backward_form", lambda *a: form)
+        _force_form(forced, form)
         one = fa._flash_bwd_pallas(*args)
         assert kernels() == ["flash_bwd_dkdv"]
-    monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", 0)
+    _vmem_limits(monkeypatch, 0)
     two = fa._flash_bwd_pallas(*args)
     assert kernels() == ["flash_bwd_dkdv", "flash_bwd_dq"]
     ref = _grouped_blockwise(q, k, v, o, lse, do, causal, scale, bk, window,
@@ -661,6 +661,27 @@ def test_one_kernel_backward_matches_two_passes_and_oracle(
             err = np.abs(got - want).max() / np.abs(want).max()
             assert err <= tol, (
                 f"{name}, {which}: {err:.3g} of the largest entry")
+
+
+def _vmem_limits(monkeypatch, limit, ceiling=None):
+    """The VMEM a one-kernel backward may state, as the shape gate reads
+    it: ``limit`` for the forms in their order and ``ceiling`` for the
+    smaller count above it (``None``: no room above the limit, so 0
+    leaves the two passes alone)."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", limit)
+    monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_CEILING",
+                        limit if ceiling is None else ceiling)
+
+
+def _force_form(monkeypatch, form):
+    """The one-kernel backward in the named form whatever the shape
+    says, stating the limit a small shape states."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "backward_plan",
+                        lambda *a: (form, fa._FUSED_BWD_VMEM_LIMIT))
 
 
 def _pallas_calls(jaxpr, what=lambda params: params["name"]):
@@ -702,10 +723,27 @@ _GATE_CASES = [
      "dq_resident"),
     ("head_256_longest_dq_26624", (1, 26624, 2, 256), 2, jnp.bfloat16, None,
      "dq_resident"),
-    ("head_256_first_two_passes_27136", (1, 27136, 2, 256), 2, jnp.bfloat16,
-     None, "two_passes"),
+    # past 32 MiB in both forms the smaller count decides, up to 48 MiB
+    # (PR 44; these two ran the two passes before it): 32.5 MiB of dq
+    # here, 37.5 MiB of dk and dv for eight query heads on one
+    ("head_256_first_past_the_limit_27136", (1, 27136, 2, 256), 2,
+     jnp.bfloat16, None, "dq_resident"),
     ("head_256_grouped_8_on_1_8192", (1, 8192, 8, 256), 1, jnp.bfloat16,
-     None, "two_passes"),
+     None, "dkdv_resident"),
+    # smallthinker_train_s16384: seven query heads a key/value head, a
+    # kv row's dk and dv 36.25 MiB (its dq 60.5)
+    ("smallthinker_1x16384x28on4x128", (1, 16384, 28, 128), 4, jnp.bfloat16,
+     None, "dkdv_resident"),
+    # the longest rows under the ceiling, 47.25 and 48 MiB, and the first
+    # past it
+    ("longest_under_the_ceiling_22016x128", (1, 22016, 7, 128), 1,
+     jnp.bfloat16, None, "dkdv_resident"),
+    ("first_over_the_ceiling_22528x128", (1, 22528, 7, 128), 1,
+     jnp.bfloat16, None, "two_passes"),
+    ("head_256_longest_dq_under_the_ceiling_43008", (1, 43008, 2, 256), 2,
+     jnp.bfloat16, None, "dq_resident"),
+    ("head_256_first_over_the_ceiling_43520", (1, 43520, 2, 256), 2,
+     jnp.bfloat16, None, "two_passes"),
 ]
 
 
@@ -717,8 +755,9 @@ def test_backward_path_follows_the_shape(shape, kv_heads, dtype, scale, form):
     """Which backward runs is read from the ``pallas_call`` names in the
     differentiated jaxpr, as a device trace would read it: one kernel
     (under the name ``flash_bwd_dkdv``) at every benchmark shape and up
-    to the VMEM the call states, ``flash_bwd_dq`` beside it only above;
-    and ``backward_form``, which the gauges read, says the same."""
+    to the ceiling of what a call may state, ``flash_bwd_dq`` beside it
+    only above; and ``backward_form``, which the gauges read, says the
+    same."""
     from horovod_tpu.ops.flash_attention import backward_form
 
     b, s, h, d = shape
@@ -736,34 +775,45 @@ def test_backward_path_follows_the_shape(shape, kv_heads, dtype, scale, form):
         _TWO_PASSES if form == "two_passes" else _ONE_KERNEL)
 
 
-# (form, gauge flash.bwd_kernels, gauge flash.bwd_dq_resident, the
+# (id, the limit and the ceiling the gate reads, form, gauges
+# flash.bwd_kernels, flash.bwd_dq_resident and flash.bwd_vmem_mib, the
 # backward's names and grids in a layer) at 64 keys of 16 channels in
-# float32 and 32 x 16 tiles
+# float32 and 32 x 16 tiles, where dk and dv resident count 334 KiB and dq
+# resident 204: the limit as it stands; one that only dq fits; none, with
+# the ceiling as it stands (the smaller count, stated itself: a whole
+# MiB); none and no ceiling
+_DQ_FITS = "what dq resident counts"
 _GAUGE_CASES = [
-    ("dkdv_resident", 1, 0, [("flash_bwd_dkdv", (8, 2, 4))]),
-    ("dq_resident", 1, 1, [("flash_bwd_dkdv", (8, 4, 2))]),
-    ("two_passes", 2, 0, [("flash_bwd_dkdv", (8, 4, 2)),
-                          ("flash_bwd_dq", (8, 2, 4))]),
+    ("dkdv_resident", None, None, "dkdv_resident", 1, 0, 32,
+     [("flash_bwd_dkdv", (8, 2, 4))]),
+    ("dq_resident", _DQ_FITS, None, "dq_resident", 1, 1, 1,
+     [("flash_bwd_dkdv", (8, 4, 2))]),
+    ("over_the_limit_the_smaller_count", 0, 48 * 2 ** 20, "dq_resident",
+     1, 1, 1, [("flash_bwd_dkdv", (8, 4, 2))]),
+    ("two_passes", 0, 0, "two_passes", 2, 0, 0,
+     [("flash_bwd_dkdv", (8, 4, 2)), ("flash_bwd_dq", (8, 2, 4))]),
 ]
 
 
-@pytest.mark.parametrize("form,kernels,dq_resident,backward",
-                         _GAUGE_CASES, ids=[c[0] for c in _GAUGE_CASES])
+@pytest.mark.parametrize(
+    "limit,ceiling,form,kernels,dq_resident,vmem_mib,backward",
+    [c[1:] for c in _GAUGE_CASES], ids=[c[0] for c in _GAUGE_CASES])
 def test_the_gauges_say_which_backward_the_step_holds(
-        monkeypatch, form, kernels, dq_resident, backward):
-    """``flash.bwd_kernels`` and ``flash.bwd_dq_resident``, set while a
-    two-layer model is traced, against the ``pallas_call`` names and
-    grids of its differentiated jaxpr, at a shape on each side of both
-    gates (the limit patched to what the form holds, or to nothing):
-    gauge and kernel read one function, ``backward_form``."""
+        monkeypatch, limit, ceiling, form, kernels, dq_resident, vmem_mib,
+        backward):
+    """``flash.bwd_kernels``, ``flash.bwd_dq_resident`` and
+    ``flash.bwd_vmem_mib``, set while a two-layer model is traced,
+    against the ``pallas_call`` names, grids and stated VMEM of its
+    differentiated jaxpr, at a shape on each side of the gates (the
+    limit patched to what a form holds, or to nothing): gauge and kernel
+    read one function, ``backward_plan``."""
     from horovod_tpu.obs.registry import get_registry
     from horovod_tpu.ops import flash_attention as fa
 
-    limit = {"dkdv_resident": fa._FUSED_BWD_VMEM_LIMIT,
-             "dq_resident": fa._dq_resident_bwd_vmem_bytes(
-                 64, 16, 32, 16, 4, 1),
-             "two_passes": 0}[form]
-    monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", limit)
+    if limit == _DQ_FITS:
+        limit = fa._dq_resident_bwd_vmem_bytes(64, 16, 32, 16, 4, 1)
+    if limit is not None:
+        _vmem_limits(monkeypatch, limit, ceiling)
     assert fa.backward_form(64, 16, 1, 4, 32, 16) == form
     model = gpt("nano", num_layers=2, num_heads=4, emb_dim=64,
                 vocab_size=512, max_len=64, dtype=jnp.float32,
@@ -772,17 +822,24 @@ def test_the_gauges_say_which_backward_the_step_holds(
         np.random.RandomState(3).randint(0, 512, (2, 64)), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), toks)
     registry = get_registry()
-    for name in ("flash.bwd_kernels", "flash.bwd_dq_resident"):
+    gauges = ("flash.bwd_kernels", "flash.bwd_dq_resident",
+              "flash.bwd_vmem_mib")
+    for name in gauges:
         registry.gauge(name, layer_type="attention").set(-1)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p: model.apply(p, toks).sum()))(params)
     calls = list(_pallas_calls(
         jaxpr.jaxpr, lambda p: (p["name"], tuple(p["grid_mapping"].grid))))
     assert calls == [("flash_fwd", (8, 2, 4))] * 2 + backward * 2
-    assert registry.gauge("flash.bwd_kernels",
-                          layer_type="attention").value == kernels
-    assert registry.gauge("flash.bwd_dq_resident",
-                          layer_type="attention").value == dq_resident
+    assert [registry.gauge(name, layer_type="attention").value
+            for name in gauges] == [kernels, dq_resident, vmem_mib]
+    # the gauge is what the one kernel states, the two passes nothing
+    stated = [limit for name, limit in _pallas_calls(
+        jaxpr.jaxpr, lambda p: (p["name"], _stated_vmem(p)))
+        if name != "flash_fwd"]
+    assert stated == ([None] * 4 if form == "two_passes" else
+                      [fa.backward_plan(64, 16, 1, 4, 32, 16)[1]] * 2)
+    assert all(-(-b // 2 ** 20) == vmem_mib for b in stated if b)
     # values as wide as keys: the head size, 64 / 4
     assert registry.gauge("flash.value_dim",
                           layer_type="attention").value == 16
@@ -794,9 +851,9 @@ def _force(monkeypatch, backward):
     from horovod_tpu.ops import flash_attention as fa
 
     if backward == "two_passes":
-        monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", 0)
+        _vmem_limits(monkeypatch, 0)
     elif backward == "dq_resident":
-        monkeypatch.setattr(fa, "backward_form", lambda *a: backward)
+        _force_form(monkeypatch, backward)
     return _TWO_PASSES if backward == "two_passes" else _ONE_KERNEL
 
 
@@ -1003,6 +1060,12 @@ def test_vmem_counts_at_the_cells_shapes(seq, d, dv, group, form, q_outer,
                                 value_dim=512) == "dq_resident"
 
 
+def _stated_vmem(eqn_params):
+    """The ``vmem_limit_bytes`` a ``pallas_call`` states (``None``: the
+    compiler's default)."""
+    return eqn_params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+
+
 def _kernel_signature(eqn_params):
     """What a ``pallas_call`` holds that the chip would see: its name and
     grid, the kernel's block and scratch refs, its outputs and the VMEM
@@ -1010,7 +1073,7 @@ def _kernel_signature(eqn_params):
     return (eqn_params["name"], tuple(eqn_params["grid_mapping"].grid),
             [str(v.aval) for v in eqn_params["jaxpr"].invars],
             [str(a) for a in eqn_params["out_avals"]],
-            eqn_params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes)
+            _stated_vmem(eqn_params))
 
 
 def test_a_call_with_values_as_wide_as_keys_is_the_program_it_was():
@@ -1053,6 +1116,58 @@ def test_a_call_with_values_as_wide_as_keys_is_the_program_it_was():
     assert calls(kv) == listed(64)
     wide = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16)
     assert calls(wide) == listed(128)
+
+
+# (id, q shape [B,S,H,D], kv heads, value width, the backward's form, the
+# MiB it states): the attention call of every flash cell.  The first five
+# serve seven cells and read what PR 43's tree read, form and stated
+# limit (32 MiB, the module's constant); the sixth left the two passes in
+# PR 44 and states its own count.
+_CELL_CALLS = [
+    ("gpt2m_train_s1024_and_dp4", (8, 1024, 16, 64), 16, 64,
+     "dkdv_resident", 32),
+    ("granite4hm_train_s8192", (1, 8192, 32, 64), 8, 64, "dkdv_resident",
+     32),
+    ("glm47f_train_s8192", (1, 8192, 20, 256), 20, 256, "dq_resident", 32),
+    ("trinitym_train_s8192", (1, 8192, 32, 128), 4, 128, "dkdv_resident",
+     32),
+    ("phi4mf_train_s8192", (1, 8192, 40, 64), 20, 128, "dkdv_resident", 32),
+    ("smallthinker_train_s16384", (1, 16384, 28, 128), 4, 128,
+     "dkdv_resident", 37),
+]
+
+
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "window_512"])
+@pytest.mark.parametrize("shape,kv_heads,dv,form,mib",
+                         [c[1:] for c in _CELL_CALLS],
+                         ids=[c[0] for c in _CELL_CALLS])
+def test_every_cells_call_keeps_its_form_and_the_vmem_it_states(
+        shape, kv_heads, dv, form, mib, window):
+    """The one backward ``pallas_call`` of each cell's attention shape,
+    full and banded, read from the differentiated jaxpr: its grid says
+    the form (Q tile outermost ``(z, nq, nk)``, K tile outermost ``(z_kv,
+    nk, nq * group)``) and its params the ``vmem_limit_bytes``.  A call
+    that fit 32 MiB before PR 44 states those 32 MiB still, so its
+    lowered text, and with it the compile cache's key, is what it was."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    b, s, h, d = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((b, s, kv_heads, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((b, s, kv_heads, dv), jnp.bfloat16)
+    group, nq, nk = h // kv_heads, s // 512, s // 256
+    assert fa.backward_plan(s, d, group, 2, value_dim=dv) \
+        == (form, mib * 2 ** 20)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: flash_attention(
+            *a, causal=True, window=window, interpret=True
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2)))(q, k, v)
+    calls = list(_pallas_calls(jaxpr.jaxpr, lambda p: (
+        p["name"], tuple(p["grid_mapping"].grid), _stated_vmem(p))))
+    grid = ((b * h, nq, nk) if form == "dkdv_resident"
+            else (b * kv_heads, nk, nq * group))
+    assert calls == [("flash_fwd", (b * h, nq, nk), None),
+                     ("flash_bwd_dkdv", grid, mib * 2 ** 20)]
 
 
 def test_local_attention_refuses_a_window_it_cannot_mean():

@@ -505,11 +505,24 @@ def test_the_cut_counts_656529920_parameters():
     assert _count(shapes["moe_stats"]) == 4 * (16 + 1 + 64 + 1 + 1)
 
 
-def test_the_backward_at_16384_keys_is_the_two_passes():
-    from horovod_tpu.ops.flash_attention import backward_form, tile_counts
+def test_the_backward_at_16384_keys_is_one_kernel():
+    """The cell's call, ``[28 on 4, 16384, 128]`` in bfloat16: a kv row's
+    dk and dv resident count 36.25 MiB and its dq 60.5, over the 32 MiB
+    either form has to fit to be taken in its turn, so the smaller count
+    decides and the call states it, a whole MiB (PR 44; the two passes
+    before it).  Half the length fits the 32 MiB and states them."""
+    from horovod_tpu.ops.flash_attention import (
+        _dq_resident_bwd_vmem_bytes, _fused_bwd_vmem_bytes, backward_form,
+        backward_plan, tile_counts,
+    )
 
-    assert backward_form(16384, 128, 7, 2) == "two_passes"
+    assert _fused_bwd_vmem_bytes(16384, 128, 512, 256, 2) == 36.25 * 2 ** 20
+    assert _dq_resident_bwd_vmem_bytes(16384, 128, 512, 256, 2,
+                                       7) == 60.5 * 2 ** 20
+    assert backward_form(16384, 128, 7, 2) == "dkdv_resident"
+    assert backward_plan(16384, 128, 7, 2) == ("dkdv_resident", 37 * 2 ** 20)
     assert backward_form(8192, 128, 7, 2) == "dkdv_resident"
+    assert backward_plan(8192, 128, 7, 2) == ("dkdv_resident", 32 * 2 ** 20)
     # a head's grid of 32 x 64 tiles: the full layer's causal half and
     # the band of a 4096-key window
     assert tile_counts(1, 16384, 512, 256, causal=True) == (1056, 2048)
@@ -521,10 +534,13 @@ def test_the_backward_at_16384_keys_is_the_two_passes():
 def test_two_passes_agree_with_the_one_kernel_at_seven_to_a_kv_head(
         monkeypatch, window):
     """Seven query heads on one key/value head (the first group that is
-    no power of two), a full and a banded call: the two-pass kernels the
-    16 384-key cell runs against the one-kernel form, every bit, and both
-    against the blockwise scan."""
-    from test_flash_attention import _grouped_blockwise, _pallas_calls
+    no power of two), a full and a banded call: the one kernel with the
+    Q tile outermost, which the 16 384-key cell runs since PR 44, against
+    the two passes it ran before, every bit, and both against the
+    blockwise scan."""
+    from test_flash_attention import (
+        _grouped_blockwise, _pallas_calls, _vmem_limits,
+    )
 
     from horovod_tpu.ops import flash_attention as fa
 
@@ -541,7 +557,7 @@ def test_two_passes_agree_with_the_one_kernel_at_seven_to_a_kv_head(
         jax.make_jaxpr(lambda: fa._flash_bwd_pallas(*args))().jaxpr))
     one = fa._flash_bwd_pallas(*args)
     assert kernels() == ["flash_bwd_dkdv"]
-    monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", 0)
+    _vmem_limits(monkeypatch, 0)
     two = fa._flash_bwd_pallas(*args)
     assert kernels() == ["flash_bwd_dkdv", "flash_bwd_dq"]
     oracle = _grouped_blockwise(q, k, v, o, lse, do, True, scale, bk, window,

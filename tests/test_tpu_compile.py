@@ -160,9 +160,26 @@ _BACKWARD_PATHS = [
      None, True),
     ("longest_fp32_dq_24064x256_window", (1, 24064, 2, 256), 2, jnp.float32,
      None, 1024, True),
-    ("two_passes_27136x256", (1, 27136, 2, 256), 2, jnp.bfloat16, None,
-     None, False),
-    ("two_passes_8_on_1_8192x256", (1, 8192, 8, 256), 1, jnp.bfloat16, None,
+    # past 32 MiB in both forms (PR 44; the two passes before it): the
+    # smaller count, stated itself, 33 MiB of dq and 38 of dk and dv
+    ("first_past_the_limit_27136x256", (1, 27136, 2, 256), 2, jnp.bfloat16,
+     None, None, True),
+    ("grouped_8_on_1_8192x256", (1, 8192, 8, 256), 1, jnp.bfloat16, None,
+     None, True),
+    # smallthinker_train_s16384's call, full and banded: 37 MiB stated
+    ("smallthinker_28on4x16384x128", (1, 16384, 28, 128), 4, jnp.bfloat16,
+     None, None, True),
+    ("smallthinker_28on4x16384x128_window", (1, 16384, 28, 128), 4,
+     jnp.bfloat16, None, 4096, True),
+    # the longest rows under the 48 MiB ceiling in either form and in
+    # float32, and the first past it
+    ("longest_under_the_ceiling_22016x128", (1, 22016, 7, 128), 1,
+     jnp.bfloat16, None, None, True),
+    ("longest_fp32_under_the_ceiling_14336x128", (1, 14336, 7, 128), 1,
+     jnp.float32, None, None, True),
+    ("longest_dq_under_the_ceiling_43008x256", (1, 43008, 2, 256), 2,
+     jnp.bfloat16, None, None, True),
+    ("two_passes_22528x128", (1, 22528, 7, 128), 1, jnp.bfloat16, None,
      None, False),
 ]
 
@@ -176,9 +193,10 @@ def test_flash_backward_path_compiles_for_v5e(one_chip, shape, kv_heads,
                                               one_kernel):
     """The backward as ONE kernel (under the name ``flash_bwd_dkdv``, no
     ``flash_bwd_dq`` beside it) at every benchmark shape and at the
-    longest rows the shape gate admits in either form, compiled inside
-    the ``vmem_limit_bytes`` the call states (the TPU compiler refuses a
-    kernel that needs more); the two passes above the budget."""
+    longest rows the shape gate admits in either form, under the limit
+    and under the ceiling above it, compiled inside the
+    ``vmem_limit_bytes`` the call states (the TPU compiler refuses a
+    kernel that needs more); the two passes above the ceiling."""
     b, s, _, d = shape
     q = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((b, s, kv_heads, d), dtype, sharding=one_chip)
