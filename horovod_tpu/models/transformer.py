@@ -553,12 +553,13 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
     if cfg.attention_impl == "flash":
         from ..obs.registry import get_registry  # noqa: PLC0415
         from ..ops.flash_attention import (  # noqa: PLC0415
-            backward_plan, flash_attention, tile_counts,
+            backward_plan, flash_attention, forward_plan, tile_counts,
         )
 
         # counted while the step is traced, like remat.kept_values: the
         # (q, k) tiles this call's grid walks and those that do work, and
-        # the backward the kernels' own gate reads from this shape
+        # the forward and the backward the kernels' own gates read from
+        # this shape
         live, grid = tile_counts(
             q.shape[0] * q.shape[2], q.shape[1], cfg.flash_block_q,
             cfg.flash_block_k, causal=True, window=window)
@@ -566,10 +567,17 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
             q.shape[1], q.shape[3], q.shape[2] // k.shape[2],
             q.dtype.itemsize, cfg.flash_block_q, cfg.flash_block_k,
             v.shape[3])
+        resident, fwd_vmem = forward_plan(
+            q.shape[1], q.shape[3], v.shape[3], q.dtype.itemsize,
+            cfg.flash_block_q, cfg.flash_block_k)
         registry = get_registry()
         label = layer_type or "attention"
         registry.gauge("flash.tiles_live", layer_type=label).set(live)
         registry.gauge("flash.tiles_grid", layer_type=label).set(grid)
+        registry.gauge("flash.fwd_kv_resident", layer_type=label).set(
+            int(resident))
+        registry.gauge("flash.fwd_vmem_mib", layer_type=label).set(
+            fwd_vmem // 2 ** 20)
         registry.gauge("flash.bwd_kernels", layer_type=label).set(
             2 if form == "two_passes" else 1)
         registry.gauge("flash.bwd_dq_resident", layer_type=label).set(

@@ -9,9 +9,14 @@ that keeps the (S, S) score matrix out of HBM entirely.
 Design (the standard flash recurrence, TPU-shaped):
 
 * Grid ``(batch*heads, S/block_q, S/block_k)``; each program owns one Q
-  tile and one (1, block_k, d) K/V tile in VMEM — the online-softmax
-  state rides VMEM scratch across the sequential K grid dimension, so
-  peak memory is O(block_q*d + block_k*d), independent of S.
+  tile and one (block_k, d) K/V tile — the online-softmax state rides
+  VMEM scratch across the sequential K grid dimension.  Wherever a kv
+  row's K and V fit VMEM (``forward_plan``; every benchmark shape, up to
+  30208 keys at head size 128 in bfloat16) the forward holds the row
+  resident, fetched from HBM once for all the grid steps that read it,
+  and slices its tile (PR 46); a longer row streams (1, block_k, d)
+  tiles, one a grid step, and peak memory is O(block_q*d + block_k*d),
+  independent of S.
 * fp32 accumulators regardless of input dtype (bf16 in, bf16 out, fp32
   softmax state — the MXU-native mixed precision).
 * Every kernel forms its score tile TRANSPOSED (``k @ q.T``: keys on
@@ -204,8 +209,10 @@ def tile_counts(rows: int, seq: int, block_q: int, block_k: int, *,
     (``rows`` batch x head rows of ``seq`` keys, tiles as ``_pick_block``
     makes them), how many the kernels' ``needed`` predicate admits.  The
     grid is whole whatever the mask: a dead tile costs its grid step and
-    its DMA and no arithmetic.  Plain Python on shapes, for a counter
-    set while a step is traced."""
+    no arithmetic, and a DMA of K and V tiles only where those stream
+    (the one-kernel backward's, and the forward's of a kv row too long
+    to stay resident: ``forward_plan``).  Plain Python on shapes, for a
+    counter set while a step is traced."""
     bq, bk = _pick_block(seq, block_q), _pick_block(seq, block_k)
     nq, nk = seq // bq, seq // bk
     if window is not None and window >= seq:
@@ -283,6 +290,11 @@ def _lanes(width: int) -> int:
     return -(-width // 128) * 128
 
 
+def _whole_mib(count: int) -> int:
+    """A byte count rounded up to a MiB: what a call states of its own."""
+    return -(-count // 2 ** 20) * 2 ** 20
+
+
 def _fused_bwd_vmem_bytes(s: int, d: int, bq: int, bk: int,
                           itemsize: int, dv: Optional[int] = None) -> int:
     """VMEM the one-kernel backward holds for a kv row of ``s`` keys with
@@ -354,7 +366,7 @@ def backward_plan(seq: int, head_dim: int, group: int, itemsize: int,
         return "dq_resident", _FUSED_BWD_VMEM_LIMIT
     count, form = min((q_outer, "dkdv_resident"), (k_outer, "dq_resident"))
     if count <= _FUSED_BWD_VMEM_CEILING:
-        return form, -(-count // 2 ** 20) * 2 ** 20
+        return form, _whole_mib(count)
     return "two_passes", 0
 
 
@@ -363,24 +375,84 @@ def backward_form(*shape, **tiles) -> str:
     return backward_plan(*shape, **tiles)[0]
 
 
+# The scoped VMEM the TPU compiler gives a kernel that states none.  A
+# forward whose count fits it states nothing, so XLA schedules around it
+# as around the call it was (PR 31: what moves XLA's prefetch around a
+# Pallas call is the VMEM the call states).
+_DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
+
+
+def _fwd_resident_vmem_bytes(s: int, d: int, dv: int, bq: int, bk: int,
+                             itemsize: int) -> int:
+    """VMEM the forward holds with a kv row's K and V resident, every
+    buffer's minor dimension padded to the 128 lanes its tiles occupy:
+    the row's K and V blocks, the q and o tiles and the lse row (two
+    buffers each), the float32 state (acc, m, l), the float32 copies of
+    the three tiles the body reads and two score-sized float32
+    temporaries.  The TPU compiler asks 17.31 MiB for 16384 keys of 128
+    in bfloat16, where this counts 18.31, and 18.12 for 8192 of 256,
+    where it counts 19.56 (sandbox compiles for a v5e on folded
+    operands, PR 46)."""
+    keys, values = _lanes(d), _lanes(dv)
+    rows = 2 * s * (keys + values) * itemsize
+    tiles = 2 * (bq * (keys + values) * itemsize + 8 * _lanes(bq) * 4)
+    state = (-(-dv // 8) * 8 + 16) * _lanes(bq) * 4
+    copies = (bq * keys + bk * (keys + values)) * 4
+    return rows + tiles + state + copies + 2 * bk * _lanes(bq) * 4
+
+
+def forward_plan(seq: int, head_dim: int, value_dim: int, itemsize: int,
+                 block_q: int = 512, block_k: int = 256):
+    """``(resident, vmem_limit_bytes)``: how the forward of this shape
+    holds K and V and the VMEM its call states, from the shape alone
+    (tiles as ``_pick_block`` makes them).  Resident: a kv row's K and V
+    whole in VMEM, fetched once a row (under GQA once for the group's
+    query heads, ``group x nq x nk`` grid steps), wherever the count
+    fits ``_FUSED_BWD_VMEM_LIMIT``; above it (1, block_k, .) tiles
+    stream, one a grid step, VMEM independent of S.  The call states a
+    limit only where the count passes the compiler's default scoped
+    limit, and then the count rounded up to a MiB (0: nothing stated).
+    ``_flash_fwd_kernel`` branches on it and ``models/transformer.py``
+    sets its gauges from it while a step is traced."""
+    bq, bk = _pick_block(seq, block_q), _pick_block(seq, block_k)
+    count = _fwd_resident_vmem_bytes(seq, head_dim, value_dim, bq, bk,
+                                     itemsize)
+    if count > _FUSED_BWD_VMEM_LIMIT:
+        return False, 0
+    if count <= _DEFAULT_SCOPED_VMEM:
+        return True, 0
+    return True, _whole_mib(count)
+
+
 def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
                       interpret):
     """Returns (o [Z,S,DV], lse [Z,S]) with Z = batch*heads and DV the
     values' width.
 
-    K tiles live on the innermost grid dimension, so only (1, bk, d) of K
-    and (1, bk, dv) of V are resident per step — VMEM peak is O(bq*dv +
-    bk*(d + dv)), independent of S (the long-context requirement).  The
-    online-softmax state (acc
-    [dv, bq], m and l [1, bq]: transposed like the tile) persists across
-    the sequential K dimension in VMEM scratch and is flushed to the
-    output block at the last K tile; lse leaves as one row per Q tile.
-    GQA/MQA: k/v have Z_kv = batch*hkv rows; the index map routes each q
-    head to its group.
+    Grid ``(z, nq, nk)``, K tiles innermost, whole whatever the mask.
+    The online-softmax state (acc [dv, bq], m and l [1, bq]: transposed
+    like the tile) persists across the sequential K dimension in VMEM
+    scratch and is flushed to the output block at the last K tile; lse
+    leaves as one row per Q tile.  GQA/MQA: k/v have Z_kv = batch*hkv
+    rows; the index map routes each q head to its group.
+
+    How K and V get to VMEM is read from the shape (``forward_plan``).
+    Wherever a kv row fits, its K and V are whole-row blocks ``(1, s,
+    d)`` and ``(1, s, dv)`` whose block index moves once a kv row; the
+    body slices the tile it needs.  The row is fetched from HBM once for
+    the ``group x nq x nk`` grid steps that read it, where (1, bk, .)
+    tiles were fetched once a grid step, live or dead, ``group x nq``
+    times over (PR 46), and a dead grid step copies nothing.  Above the
+    limit the streamed tiles: only (1, bk, d) of K and (1, bk, dv) of V
+    are resident per step and VMEM peak is O(bq*dv + bk*(d + dv)),
+    independent of S (the long-context requirement).  Both forms run the
+    same tile arithmetic in the same order: ``o`` and ``lse`` are equal
+    to the bit.
     """
     z, s, d = q.shape
     dv = v.shape[-1]
     nq, nk = s // bq, s // bk
+    resident, vmem_limit = forward_plan(s, d, dv, q.dtype.itemsize, bq, bk)
 
     def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref):
         i = pl.program_id(1)
@@ -394,7 +466,8 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
 
         # Causal: K tiles strictly above the diagonal contribute
         # nothing; with a window, tiles entirely below the band are dead
-        # too — skip both (their DMA is pipelined regardless).
+        # too — skip both (a dead step of the resident form costs its
+        # grid step alone, of the streamed form its tiles' DMA too).
         needed = (j * bk <= (i + 1) * bq - 1) if causal else (j >= 0)
         if window is not None:
             needed = jnp.logical_and(
@@ -407,9 +480,11 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
             # a query's statistic is one lane of a (1, bq) row.  A row
             # whose keys are all masked so far sums placeholders (p = 1)
             # that corr wipes at its first live tile.
+            rows = (pl.ds(pl.multiple_of(j * bk, bk), bk) if resident
+                    else slice(None))      # a streamed block is the tile
             qb = q_ref[0].astype(jnp.float32) * scale  # [bq, d]
-            kb = k_ref[0].astype(jnp.float32)          # [bk, d]
-            vb = v_ref[0].astype(jnp.float32)
+            kb = k_ref[0, rows, :].astype(jnp.float32)  # [bk, d]
+            vb = v_ref[0, rows, :].astype(jnp.float32)
             st = jnp.dot(kb, qb.T, preferred_element_type=jnp.float32)
             if causal:
                 k_pos = j * bk + lax.broadcasted_iota(
@@ -437,15 +512,19 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
             o_ref[0] = (acc_ref[...] / l_ref[...]).T.astype(o_ref.dtype)
             lse_ref[0, 0] = m_ref[...] + jnp.log(l_ref[...])
 
+    def kv_block(width):
+        # resident: the whole row, its block index moving once a kv row
+        return pl.BlockSpec(
+            (1, s if resident else bk, width),
+            lambda zi, qi, ki: (_kv_row(zi, h, hkv), 0 if resident else ki, 0))
+
     o, lse = pl.pallas_call(
         kernel,
         grid=(z, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda zi, qi, ki: (zi, qi, 0)),
-            pl.BlockSpec((1, bk, d),
-                         lambda zi, qi, ki: (_kv_row(zi, h, hkv), ki, 0)),
-            pl.BlockSpec((1, bk, dv),
-                         lambda zi, qi, ki: (_kv_row(zi, h, hkv), ki, 0)),
+            kv_block(d),
+            kv_block(dv),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, dv), lambda zi, qi, ki: (zi, qi, 0)),
@@ -462,6 +541,7 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit or None,
         ),
         interpret=interpret,
         name="flash_fwd",

@@ -1079,7 +1079,8 @@ def _kernel_signature(eqn_params):
 def test_a_call_with_values_as_wide_as_keys_is_the_program_it_was():
     """granite's call (32 query over 8 key/value heads of 64 at 8192
     tokens, bfloat16): the ``pallas_call``s of the differentiated jaxpr,
-    listed as PR 38's tree made them.  The five cells that send
+    listed as PR 38's tree made them but for the forward's K and V
+    blocks, whole kv rows since PR 46.  The five cells that send
     ``dv == d`` run this program; only the value width of a call that
     has one moves a block, a scratch buffer or an output."""
     q = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16)
@@ -1100,7 +1101,7 @@ def test_a_call_with_values_as_wide_as_keys_is_the_program_it_was():
     def listed(dv):
         return [
             ("flash_fwd", (32, 16, 32),
-             [bf(1, 512, 64), bf(1, 256, 64), bf(1, 256, dv),
+             [bf(1, 512, 64), bf(1, 8192, 64), bf(1, 8192, dv),
               bf(1, 512, dv), stat,
               vmem(dv, 512), vmem(1, 512), vmem(1, 512)],
              [arr(32, 8192, dv), "float32[32,16,1,512]"], None),
@@ -1166,8 +1167,192 @@ def test_every_cells_call_keeps_its_form_and_the_vmem_it_states(
         p["name"], tuple(p["grid_mapping"].grid), _stated_vmem(p))))
     grid = ((b * h, nq, nk) if form == "dkdv_resident"
             else (b * kv_heads, nk, nq * group))
-    assert calls == [("flash_fwd", (b * h, nq, nk), None),
+    assert calls == [("flash_fwd", (b * h, nq, nk),
+                      fa.forward_plan(s, d, dv, 2)[1] or None),
                      ("flash_bwd_dkdv", grid, mib * 2 ** 20)]
+
+
+def _forward_call(eqn_params):
+    """What a ``flash_fwd`` ``pallas_call`` holds of K and V: the rows of
+    their blocks (the whole kv row where it is resident, ``block_k``
+    where tiles stream) and the VMEM the call states."""
+    k_block, v_block = eqn_params["grid_mapping"].block_mappings[1:3]
+    rows = {int(m.block_shape[1].block_size) for m in (k_block, v_block)}
+    assert len(rows) == 1 and k_block.pipeline_mode is None
+    return rows.pop(), _stated_vmem(eqn_params)
+
+
+# (id, causal, window, h, hkv, s, d, dv, bq, bk)
+_FWD_FORM_CASES = [
+    ("causal", True, None, 2, 2, 64, 16, 16, 32, 16),
+    ("noncausal", False, None, 2, 2, 64, 16, 16, 32, 16),
+    ("window_20", True, 20, 2, 2, 64, 16, 16, 32, 8),
+    ("window_of_one_tile", True, 8, 2, 2, 64, 16, 16, 16, 16),
+    ("mqa_4_on_1", True, None, 4, 1, 64, 16, 16, 32, 16),
+    ("gqa_7_to_a_kv_head", True, None, 14, 2, 64, 16, 16, 32, 16),
+    ("gqa_7_window", True, 24, 7, 1, 96, 16, 16, 32, 16),
+    ("values_128_on_keys_64", True, None, 4, 2, 64, 64, 128, 32, 16),
+    ("values_128_on_keys_64_window", True, 20, 4, 2, 64, 64, 128, 32, 16),
+    ("values_narrower", False, None, 2, 1, 64, 16, 8, 32, 16),
+    ("one_tile", True, None, 2, 1, 32, 16, 16, 32, 32),
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "causal,window,h,hkv,s,d,dv,bq,bk", [c[1:] for c in _FWD_FORM_CASES],
+    ids=[c[0] for c in _FWD_FORM_CASES])
+def test_resident_forward_equals_streamed_to_the_bit(
+        monkeypatch, causal, window, h, hkv, s, d, dv, bq, bk, dtype):
+    """The forward with a kv row's K and V resident in VMEM (whole-row
+    blocks whose index moves once a kv row, the body slicing its tile)
+    against the streamed tiles it replaced wherever a row fits (forced
+    here by a limit no row fits, as ``_vmem_limits`` forces the two
+    backward passes): the same tiles, the same float32 sums in the same
+    order, so ``o`` and ``lse`` are equal to the bit; and against the
+    plain attention."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    b = 2
+    rng = np.random.RandomState(5)
+    mk = lambda heads, width: jnp.asarray(
+        rng.randn(b * heads, s, width) * 0.7, dtype)
+    q, k, v = mk(h, d), mk(hkv, d), mk(hkv, dv)
+    args = (q, k, v, causal, d ** -0.5, bq, bk, h, hkv, window, True)
+
+    def forward():
+        call, = _pallas_calls(
+            jax.make_jaxpr(lambda: fa._flash_fwd_kernel(*args))().jaxpr,
+            _forward_call)
+        return call, fa._flash_fwd_kernel(*args)
+
+    assert fa.forward_plan(s, d, dv, q.dtype.itemsize, bq, bk) == (True, 0)
+    call, resident = forward()
+    assert call == (s, None)
+    _vmem_limits(monkeypatch, 0)
+    assert fa.forward_plan(s, d, dv, q.dtype.itemsize, bq, bk) == (False, 0)
+    call, streamed = forward()
+    assert call == (bk, None)
+    for part, a, t in zip(("o", "lse"), resident, streamed):
+        assert a.dtype == t.dtype and a.shape == t.shape
+        np.testing.assert_array_equal(
+            np.asarray(a, np.float32), np.asarray(t, np.float32),
+            err_msg=f"{part}: resident against streamed")
+    unfold = lambda x: x.reshape(b, -1, s, x.shape[-1]).transpose(0, 2, 1, 3)
+    kx, vx = (jnp.repeat(unfold(x).astype(jnp.float32), h // hkv, axis=2)
+              for x in (k, v))
+    want = local_attention(unfold(q).astype(jnp.float32), kx, vx,
+                           causal=causal, window=window)
+    err = np.abs(np.asarray(unfold(resident[0]), np.float32)
+                 - np.asarray(want)).max()
+    assert err <= (2e-5 if dtype == jnp.float32 else 3e-2), err
+
+
+# (id, keys, head size, value width, itemsize, resident, the MiB the call
+# states, the bytes counted) at 512 x 256 tiles: every flash cell's
+# forward holds its kv row resident; the two whose rows are 16 MiB in
+# their two buffers (GLM's 8192 keys of 256, SmallThinker's 16384 of 128)
+# pass the compiler's default scoped limit and state their count, the
+# others state nothing.  The TPU compiler asks 18.12 and 17.31 MiB for
+# those two (sandbox compiles for a described v5e, PR 46).
+_FWD_COUNT_CASES = [
+    ("gpt2m_1024x64", 1024, 64, 64, 2, True, 0, 3342336),
+    ("granite4hm_8192x64", 8192, 64, 64, 2, True, 0, 10682368),
+    ("glm47f_8192x256", 8192, 256, 256, 2, True, 20, 20512768),
+    ("trinitym_8192x128", 8192, 128, 128, 2, True, 0, 10813440),
+    ("phi4mf_8192x64_values_128", 8192, 64, 128, 2, True, 0, 10813440),
+    ("smallthinker_16384x128", 16384, 128, 128, 2, True, 19, 19202048),
+    # the first row that states a limit, the longest that stays resident
+    # and the first whose tiles stream, at head sizes 128 and 256 and in
+    # float32
+    ("last_that_states_nothing_13824x128", 13824, 128, 128, 2, True, 0,
+     16580608),
+    ("first_that_states_its_count_14336x128", 14336, 128, 128, 2, True, 17,
+     17104896),
+    ("longest_resident_row_30208x128", 30208, 128, 128, 2, True, 32,
+     33357824),
+    ("first_streamed_row_30720x128", 30720, 128, 128, 2, False, 0,
+     33882112),
+    ("longest_resident_row_14336x256", 14336, 256, 256, 2, True, 32,
+     33095680),
+    ("first_streamed_row_14848x256", 14848, 256, 256, 2, False, 0,
+     34144256),
+    ("float32_longest_resident_row_14848x128", 14848, 128, 128, 4, True, 32,
+     33357824),
+    ("float32_first_streamed_row_15360x128", 15360, 128, 128, 4, False, 0,
+     34406400),
+]
+
+
+@pytest.mark.parametrize("seq,d,dv,itemsize,resident,mib,count",
+                         [c[1:] for c in _FWD_COUNT_CASES],
+                         ids=[c[0] for c in _FWD_COUNT_CASES])
+def test_forward_plan_at_the_cells_shapes(seq, d, dv, itemsize, resident,
+                                          mib, count):
+    from horovod_tpu.ops import flash_attention as fa
+
+    assert fa._fwd_resident_vmem_bytes(seq, d, dv, 512, 256,
+                                       itemsize) == count
+    assert fa.forward_plan(seq, d, dv, itemsize) == (resident, mib * 2 ** 20)
+    assert fa.forward_plan(seq, d, dv, itemsize, 512, 256) == (
+        resident, mib * 2 ** 20)
+    # the rule: resident wherever the count fits the limit the backward's
+    # forms share, a stated MiB only past the default scoped limit
+    assert resident == (count <= fa._FUSED_BWD_VMEM_LIMIT)
+    assert (mib > 0) == (fa._DEFAULT_SCOPED_VMEM < count
+                         <= fa._FUSED_BWD_VMEM_LIMIT)
+    if mib:
+        assert 0 <= mib * 2 ** 20 - count < 2 ** 20
+
+
+# (id, the limit and the default scoped limit the gate reads, the
+# forward's plan, gauges flash.fwd_kv_resident and flash.fwd_vmem_mib, the
+# rows of the K and V blocks) at 64 keys of 16 channels in float32 and
+# 32 x 16 tiles, where the resident forward counts 264 KiB: the limits as
+# they stand; a default the count passes (the count stated, a whole MiB);
+# no room
+_FWD_GAUGE_CASES = [
+    ("resident", None, None, (True, 0), 1, 0, 64),
+    ("resident_stating_its_count", None, 0, (True, 2 ** 20), 1, 1, 64),
+    ("streamed", 0, None, (False, 0), 0, 0, 16),
+]
+
+
+@pytest.mark.parametrize("limit,default,plan,resident,vmem_mib,rows",
+                         [c[1:] for c in _FWD_GAUGE_CASES],
+                         ids=[c[0] for c in _FWD_GAUGE_CASES])
+def test_the_gauges_say_which_forward_the_step_holds(
+        monkeypatch, limit, default, plan, resident, vmem_mib, rows):
+    """``flash.fwd_kv_resident`` and ``flash.fwd_vmem_mib``, set while a
+    two-layer model is traced, against the K and V blocks and the stated
+    VMEM of the ``flash_fwd`` calls in its jaxpr, on each side of
+    ``forward_plan``'s gates: gauge and kernel read one function."""
+    from horovod_tpu.obs.registry import get_registry
+    from horovod_tpu.ops import flash_attention as fa
+
+    if default is not None:
+        monkeypatch.setattr(fa, "_DEFAULT_SCOPED_VMEM", default)
+    if limit is not None:
+        _vmem_limits(monkeypatch, limit)
+    assert fa.forward_plan(64, 16, 16, 4, 32, 16) == plan
+    model = gpt("nano", num_layers=2, num_heads=4, emb_dim=64,
+                vocab_size=512, max_len=64, dtype=jnp.float32,
+                flash_block_q=32, flash_block_k=16)
+    toks = jnp.asarray(
+        np.random.RandomState(3).randint(0, 512, (2, 64)), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), toks)
+    registry = get_registry()
+    gauges = ("flash.fwd_kv_resident", "flash.fwd_vmem_mib")
+    for name in gauges:
+        registry.gauge(name, layer_type="attention").set(-1)
+    jaxpr = jax.make_jaxpr(lambda p: model.apply(p, toks))(params)
+    calls = list(_pallas_calls(jaxpr.jaxpr, lambda p: (
+        p["name"], tuple(p["grid_mapping"].grid)) + _forward_call(p)))
+    # the grid is whole in either form
+    assert calls == [("flash_fwd", (8, 2, 4), rows, plan[1] or None)] * 2
+    assert [registry.gauge(name, layer_type="attention").value
+            for name in gauges] == [resident, vmem_mib]
 
 
 def test_local_attention_refuses_a_window_it_cannot_mean():

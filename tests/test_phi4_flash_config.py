@@ -221,8 +221,9 @@ def test_a_differential_layer_is_one_flash_call_at_the_pairs_shape(kind):
              if e.primitive.name == "pallas_call"]
     assert [c["name"] for c in calls] == ["flash_fwd"]
     blocks = [v.aval.shape for v in calls[0]["jaxpr"].invars[:4]]
-    assert blocks == [(1, 512, 64), (1, 256, 64), (1, 256, 128),
-                      (1, 512, 128)]                       # q, k, v, o
+    # q, k, v, o: since PR 46 a kv row's K and V whole, fetched once a row
+    assert blocks == [(1, 512, 64), (1, 8192, 64), (1, 8192, 128),
+                      (1, 512, 128)]
     assert tuple(calls[0]["grid_mapping"].grid) == (40, 16, 32)
     assert [a.shape for a in calls[0]["out_avals"]][0] == (40, 8192, 128)
     joins = [len(e.invars) for e in equations
@@ -237,6 +238,8 @@ def test_a_differential_layer_is_one_flash_call_at_the_pairs_shape(kind):
     assert gauge("flash.tiles_live") == (
         2480 if kind == "sliding_attention" else 10880)
     assert gauge("flash.value_dim") == 128
+    assert gauge("flash.fwd_kv_resident") == 1
+    assert gauge("flash.fwd_vmem_mib") == 0
 
 
 # ------------------------------------------------------------- refusals

@@ -213,6 +213,69 @@ def test_flash_backward_path_compiles_for_v5e(one_chip, shape, kv_heads,
     assert ("flash_bwd_dq" not in text) == one_kernel
 
 
+# (id, q shape [B,S,H,D], kv heads, dtype, window, the rows of the K and V
+# blocks, the MiB stated): the forward with a kv row's K and V resident
+# (PR 46) at the two cells whose rows pass the default scoped limit and
+# state their count, at a cell that states nothing, at the longest rows the
+# gate admits, bfloat16 and float32, and at the first row past it, whose
+# tiles stream.
+_FORWARD_FORMS = [
+    ("smallthinker_28on4x16384x128", (1, 16384, 28, 128), 4, jnp.bfloat16,
+     None, 16384, 19),
+    ("smallthinker_28on4x16384x128_window", (1, 16384, 28, 128), 4,
+     jnp.bfloat16, 4096, 16384, 19),
+    ("glm47f_20on20x8192x256", (1, 8192, 20, 256), 20, jnp.bfloat16, None,
+     8192, 20),
+    ("trinitym_32on4x8192x128_window", (1, 8192, 32, 128), 4, jnp.bfloat16,
+     2048, 8192, 0),
+    ("longest_resident_row_30208x128", (1, 30208, 2, 128), 1, jnp.bfloat16,
+     None, 30208, 32),
+    ("longest_fp32_resident_row_14848x128_window", (1, 14848, 2, 128), 1,
+     jnp.float32, 1024, 14848, 32),
+    ("first_streamed_row_30720x128", (1, 30720, 2, 128), 1, jnp.bfloat16,
+     None, 256, 0),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,kv_heads,dtype,window,rows,mib",
+    [c[1:] for c in _FORWARD_FORMS], ids=[c[0] for c in _FORWARD_FORMS])
+def test_flash_forward_form_compiles_for_v5e(one_chip, shape, kv_heads,
+                                             dtype, window, rows, mib):
+    """The forward kernel on folded operands, which stay in HBM (through
+    ``flash_attention`` alone in a jit XLA may hand the call ``k`` and
+    ``v`` in its own VMEM space, and the compile says nothing of the
+    blocks): the ``pallas_call`` holds whole-kv-row K and V blocks
+    wherever ``forward_plan`` says resident, and the TPU compiler takes
+    them inside the scoped VMEM the call states, or inside its default
+    where it states none (it refuses a kernel that needs more)."""
+    import re
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    b, s, h, d = shape
+    assert fa.forward_plan(s, d, d, jnp.dtype(dtype).itemsize) == (
+        rows == s, mib * 2 ** 20)
+    q = jax.ShapeDtypeStruct((b * h, s, d), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b * kv_heads, s, d), dtype,
+                              sharding=one_chip)
+
+    def forward(q, k, v):
+        return fa._flash_fwd_kernel(q, k, v, True, d ** -0.5, 512, 256, h,
+                                    kv_heads, window, False)
+
+    (call,) = [e.params for e in jax.make_jaxpr(forward)(q, kv, kv).eqns
+               if e.primitive.name == "pallas_call"]
+    for block in call["grid_mapping"].block_mappings[1:3]:
+        assert block.block_shape[1].block_size == rows
+    text = jax.jit(forward).lower(q, kv, kv).compile().as_text()
+    (line,) = [l for l in text.splitlines()
+               if "custom-call(" in l and "flash_fwd" in l]
+    stated = re.findall(r'"scoped_memory_configs":\[([^\]]*)\]', line)
+    assert stated == ([f'{{"memory_space":"1","offset":"0",'
+                       f'"size":"{mib * 2 ** 20}"}}'] if mib else [""])
+
+
 def test_grouped_expert_matmuls_compile_for_v5e(one_chip):
     """The dropless expert layer's grouped feed-forward at
     glm47f_train_s8192's shape (8192 tokens x 4 choices = 32768 rows of
