@@ -33,7 +33,7 @@ The block wiring is NOT re-implemented here: each step runs
 prefix — so GQA head routing, fp8 activation storage, and any future
 block change flow into decoding automatically.  RoPE is applied inside
 the override (per-slot positions need per-row angle tables, which the
-shared ``[s, half]`` broadcast in ``block_math`` cannot express), with
+shared ``[s, half]`` broadcast in ``attention_mixer`` cannot express), with
 the same fp32 rotation math as ``ops/rope.py``.
 
 Dense blocks only (MoE is training-path-only, parallel/moe.py).
@@ -224,7 +224,7 @@ def decode_step(cfg: TransformerConfig, params, cache, tokens_t,
 
         def attend(q, k_t, v_t, _i=i):
             # q [b, 1, nh, hd]; k_t/v_t [b, 1, nkv, hd].  RoPE applies
-            # HERE (per-row tables); block_math skipped it because we
+            # HERE (per-row tables); attention_mixer skipped it because we
             # passed rope_tabs=None.  Append at each slot's own
             # position, then attend against that slot's prefix.
             nonlocal k_new, v_new
@@ -301,7 +301,7 @@ def prefill(cfg: TransformerConfig, params, tokens, max_len=None,
     for i in range(cfg.num_layers):
 
         def attend(q, k_t, v_t, _i=i):
-            # k_t/v_t [b, s, nkv, hd], rope-applied by block_math (the
+            # k_t/v_t [b, s, nkv, hd], rope-applied by attention_mixer (the
             # shared [s, half] tables are exactly right here: every row
             # sits at positions 0..s-1) — write the whole prompt's K/V
             # in one shot, then attend every query against the prefix.
@@ -492,7 +492,7 @@ def decode_step_paged(cfg: TransformerConfig, params, pool, tables,
 
         def attend(q, k_t, v_t, _i=i):
             # q [b, 1, nh, hd]; k_t/v_t [b, 1, nkv, hd].  RoPE per-row
-            # here (block_math got rope_tabs=None), append into the
+            # here (attention_mixer got rope_tabs=None), append into the
             # slot's current page, then gather the block table back
             # into the virtually contiguous [b, virt, nkv, hd] prefix.
             nonlocal k_new, v_new

@@ -32,7 +32,7 @@ from __future__ import annotations
 
 import contextlib
 import math
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, fields, replace
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -460,32 +460,45 @@ class TransformerConfig:
         return self.ssm_heads * self.ssm_head_dim
 
 
+# What the raw-weights paths honour of a configuration: the sizes, the
+# positions, the attention schedule and its tiles, the activation store.
+# ``remat`` and ``remat_policy`` are the flax model's and change no
+# mathematics (the pipeline takes its own ``remat``); ``layer_types`` may
+# say "attention" in every layer; the GShard expert layer (``moe_*``)
+# each path refuses in its own words, from the weights it is handed.
+RAW_BLOCK_SETTINGS = frozenset({
+    "vocab_size", "num_layers", "num_heads", "num_kv_heads", "emb_dim",
+    "mlp_ratio", "max_len", "dtype", "attention_impl", "sp_axis",
+    "attention_window", "pos_embedding", "rope_theta", "flash_block_q",
+    "flash_block_k", "act_store_dtype", "remat", "remat_policy",
+    "layer_types", "moe_experts", "moe_top_k", "moe_capacity_factor",
+    "moe_group_size",
+})
+
+
 def require_gpt2_block(cfg: TransformerConfig, who: str) -> None:
     """Refuse, before anything is traced, a configuration that ``who``
     cannot run: the decode and serving paths, the tensor-parallel and
     the pipeline schedules build on :func:`block_math` with GPT-2's five
     callables from raw weights (LayerNorm, biased dense projections, a
     gelu MLP, attention in every layer) and would run that wiring under
-    another model's name."""
+    another model's name.  Every setting outside ``RAW_BLOCK_SETTINGS``
+    has to stand at its default: a field a later architecture adds is
+    refused here until these paths implement it."""
     gpt2 = TransformerConfig()
     if cfg.layer_types and set(cfg.layer_types) != {"attention"}:
         raise ValueError(
             f"{who} runs attention layers only: layer_types="
             f"{cfg.layer_types!r} holds a layer it has no state for")
-    for setting in ("routed_experts", "routed_router_input", "routed_scores",
-                    "routed_activation", "routed_balance_coef",
-                    "mtp_modules", "norm", "norm_eps",
-                    "mlp", "use_bias", "tie_embeddings",
-                    "embedding_multiplier", "residual_multiplier",
-                    "logits_scaling", "attention_scale", "head_size",
-                    "rope_layer_types", "qk_norm", "attention_gate",
-                    "post_norms", "mlp_bias", "differential_attention",
-                    "first_layer_index", "shared_kv_layer", "memory_layer"):
-        if getattr(cfg, setting) != getattr(gpt2, setting):
-            raise ValueError(
-                f"{who} implements GPT-2's block only "
-                f"({setting}={getattr(gpt2, setting)!r}); this "
-                f"configuration says {setting}={getattr(cfg, setting)!r}")
+    refused = [f.name for f in fields(cfg)
+               if f.name not in RAW_BLOCK_SETTINGS
+               and getattr(cfg, f.name) != getattr(gpt2, f.name)]
+    if refused:
+        says = lambda c: ", ".join(
+            f"{setting}={getattr(c, setting)!r}" for setting in refused)
+        raise ValueError(
+            f"{who} implements GPT-2's block only ({says(gpt2)}); this "
+            f"configuration says {says(cfg)}")
     if cfg.pos_embedding == "none":
         raise ValueError(
             f"{who} implements learned and rotary positions; this "
@@ -553,43 +566,20 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
     if cfg.attention_impl == "flash":
         from ..obs.registry import get_registry  # noqa: PLC0415
         from ..ops.flash_attention import (  # noqa: PLC0415
-            backward_plan, flash_attention, forward_plan, tile_counts,
+            flash_attention, flash_plan,
         )
 
-        # counted while the step is traced, like remat.kept_values: the
-        # (q, k) tiles this call's grid walks and those that do work, and
-        # the forward and the backward the kernels' own gates read from
-        # this shape
-        live, grid = tile_counts(
-            q.shape[0] * q.shape[2], q.shape[1], cfg.flash_block_q,
-            cfg.flash_block_k, causal=True, window=window)
-        form, vmem = backward_plan(
-            q.shape[1], q.shape[3], q.shape[2] // k.shape[2],
-            q.dtype.itemsize, cfg.flash_block_q, cfg.flash_block_k,
-            v.shape[3])
-        resident, fwd_vmem = forward_plan(
-            q.shape[1], q.shape[3], v.shape[3], q.dtype.itemsize,
-            cfg.flash_block_q, cfg.flash_block_k)
-        registry = get_registry()
+        # counted while the step is traced, like remat.kept_values: what
+        # the kernels' own plan says of this call
+        call = dict(causal=True, block_q=cfg.flash_block_q,
+                    block_k=cfg.flash_block_k, window=window)
+        plan = flash_plan(q, k, v, **call)
         label = layer_type or "attention"
-        registry.gauge("flash.tiles_live", layer_type=label).set(live)
-        registry.gauge("flash.tiles_grid", layer_type=label).set(grid)
-        registry.gauge("flash.fwd_kv_resident", layer_type=label).set(
-            int(resident))
-        registry.gauge("flash.fwd_vmem_mib", layer_type=label).set(
-            fwd_vmem // 2 ** 20)
-        registry.gauge("flash.bwd_kernels", layer_type=label).set(
-            2 if form == "two_passes" else 1)
-        registry.gauge("flash.bwd_dq_resident", layer_type=label).set(
-            int(form == "dq_resident"))
-        registry.gauge("flash.bwd_vmem_mib", layer_type=label).set(
-            -(-vmem // 2 ** 20))
-        registry.gauge("flash.value_dim", layer_type=label).set(v.shape[3])
-        return flash_attention(
-            q, k, v, causal=True,
-            block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
-            window=window, scale=cfg.attention_scale,
-        )
+        gauge = lambda name: get_registry().gauge(name, layer_type=label)
+        gauge("flash.tiles_live").set(plan.tiles_live)
+        gauge("flash.tiles_grid").set(plan.tiles_grid)
+        gauge("flash.bwd_kernels").set(plan.bwd_kernels)
+        return flash_attention(q, k, v, scale=cfg.attention_scale, **call)
     if window is not None and cfg.attention_impl != "reference":
         raise ValueError(
             "attention_window is flash-only on a chip (the reference "
@@ -778,61 +768,117 @@ def mla_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *, q_a,
     return proj(act_store(att.reshape(b, s, nh * vd), cfg))
 
 
-def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
-               ln1, ln2, mlp, qkv=None, proj=None, ssm=None, mla=None,
-               num_heads: Optional[int] = None,
-               num_kv_heads: Optional[int] = None,
-               attend=None, layer_type: Optional[str] = None,
-               q_norm=None, k_norm=None, gate=None,
+def attention_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *,
+                    qkv, proj, num_heads: Optional[int] = None,
+                    num_kv_heads: Optional[int] = None, attend=None,
+                    layer_type: Optional[str] = None, q_norm=None,
+                    k_norm=None, gate=None, differential=None,
+                    shared_kv=None, hand_on: Optional[str] = None):
+    """Attention on the normed stream ``h`` [b, s, emb]: ``qkv →
+    split-heads → rope → attend → proj``.  ``qkv`` and ``proj`` are
+    callables like ``block_math``'s (flax modules, raw-weight closures,
+    or psum-rejoined tensor-parallel closures).  Returns the residual
+    delta, or ``(delta, (k, v))`` with ``hand_on="kv"``: the keys and
+    values as this layer attends them, for the layers that read them.
+
+    ``num_heads`` / ``num_kv_heads`` override the config's head counts
+    for callers operating on a per-rank head shard (TP).  ``attend``
+    overrides the attention schedule itself: a callable ``(q, k, v) ->
+    att`` over the rope-applied ``[b, s, heads, head_dim]`` tensors — the
+    KV-cache decode path (models/decode.py) supplies one that appends to
+    its cache and attends the single query against the prefix, so
+    decoding reuses THIS wiring instead of a third copy.
+
+    The optional steps, each a callable where the configuration asks for
+    it and ``None`` where not: ``q_norm`` and ``k_norm`` over each
+    head's channels after the head split and before RoPE; ``gate``, from
+    the same normed stream as the queries and as wide, whose sigmoid
+    multiplies the attended values before ``proj`` (scope
+    ``attn_gate``).  ``layer_type`` gives the attention call its window
+    (``cfg.window_of``); the caller hands in ``rope_tabs=None`` for a
+    layer that sees no positions.  ``shared_kv=(k, v)``, what another
+    layer handed on, makes ``qkv`` a projection to the queries alone
+    (scope ``attn_cross`` around the attention call).  ``differential``
+    holds ``lambdas``, ``subln`` and ``lambda_init`` where the attention
+    is differential (:func:`_attend_differential`, scope ``attn_diff``).
+    """
+    b, s, _ = h.shape
+    nh = num_heads if num_heads is not None else cfg.num_heads
+    nkv = num_kv_heads if num_kv_heads is not None else cfg.kv_heads
+    hd = cfg.head_dim
+    q_dim = nh * hd
+    kv_dim = nkv * hd
+    fused = qkv(h)
+    q = fused[..., :q_dim].reshape(b, s, nh, hd)
+    if shared_kv is not None:
+        k, v = shared_kv
+    else:
+        k = fused[..., q_dim:q_dim + kv_dim].reshape(b, s, nkv, hd)
+        v = fused[..., q_dim + kv_dim:].reshape(b, s, nkv, hd)
+    if q_norm is not None:
+        q = q_norm(q).astype(fused.dtype)
+    if k_norm is not None:
+        k = k_norm(k).astype(fused.dtype)
+    if rope_tabs is not None:
+        from ..ops.rope import apply_rope_tables  # noqa: PLC0415
+
+        q = apply_rope_tables(q, *rope_tabs)
+        k = apply_rope_tables(k, *rope_tabs)
+    if differential is not None:
+        att_4d = _attend_differential(cfg, q, k, v, positions, layer_type,
+                                      **differential)
+    elif attend is None:
+        attend_cfg = cfg
+        if nh != cfg.num_heads or nkv != cfg.kv_heads:
+            # per-rank head shard: _attend sees the LOCAL head geometry
+            attend_cfg = replace(cfg, num_heads=nh, num_kv_heads=nkv,
+                                 emb_dim=q_dim)
+        att_4d = _attend(attend_cfg, q, k, v, positions, layer_type)
+    else:
+        att_4d = attend(q, k, v)
+    att = att_4d.reshape(b, s, q_dim)
+    if gate is not None:
+        with jax.named_scope(scopes.ATTN_GATE):
+            att = (att * jax.nn.sigmoid(
+                gate(h).astype(jnp.float32))).astype(att.dtype)
+    delta = proj(act_store(att, cfg))
+    return (delta, (k, v)) if hand_on == "kv" else delta
+
+
+# The scope a layer type's mixer traces under; any type not named here
+# is an attention layer and traces under ``attn``.
+MIXER_SCOPES = {"mamba": scopes.SSM, "selective_scan": scopes.SSM,
+                "gmu": scopes.GMU}
+
+
+def block_math(cfg: TransformerConfig, x, *, ln1, mixer, ln2, mlp,
+               layer_type: Optional[str] = None,
                post_attn_norm=None, post_mlp_norm=None,
-               gmu=None, differential=None, shared_kv=None,
                hand_on: Optional[str] = None, route=None):
     """THE pre-norm block wiring — the single source of truth.
 
     ``norm → mixer → (+res) → norm → feed-forward → (+res)``, each
-    residual added through ``cfg.residual_multiplier``.  The mixer is
-    attention (``qkv → split-heads → rope → attend → proj``) or, where
-    the caller hands in ``ssm`` or ``mla``, whatever that callable makes
-    of the normed stream (the Mamba-2 mixer, :func:`mamba_mixer`, under
-    the scope ``ssm``; latent attention, :func:`mla_mixer`, under
-    ``attn``).  Shared by
-    the flax :class:`Block`, the raw-weights pipeline-parallel block
-    (:func:`raw_block_forward`), and the Megatron tensor-parallel block
-    (``parallel/tensor_parallel.py``) so a change to the block (a bias
-    flag, a norm variant, the head split) is made exactly once.
+    residual added through ``cfg.residual_multiplier``.  ``mixer`` is a
+    callable of the normed stream that returns the residual DELTA:
+    :func:`attention_mixer`, :func:`mla_mixer`, :func:`mamba_mixer`,
+    :func:`selective_scan_mixer` or :func:`gmu_mixer` with the caller's
+    parameterized layer applications closed over; it traces under the
+    scope of its ``layer_type`` (``MIXER_SCOPES``: ``ssm``, ``gmu`` or
+    ``attn``).  Shared by the flax :class:`Block`, the raw-weights
+    pipeline-parallel and decode block (:func:`raw_block_forward`), and
+    the Megatron tensor-parallel block (``parallel/tensor_parallel.py``),
+    so a change to the block (a norm variant, the residual's scale, a
+    post-norm) is made exactly once, and a new architecture is a new
+    mixer function, not an edit here.
 
-    Callers supply the parameterized layer applications as callables
-    (flax modules, raw-weight closures, or psum-rejoined tensor-parallel
-    closures); ``proj``, ``ssm`` and ``mlp`` return the residual DELTA
-    (this function adds it to the stream).  ``num_heads`` /
-    ``num_kv_heads`` override the config's head counts for callers
-    operating on a per-rank head shard (TP).  ``attend`` overrides the
-    attention schedule itself: a callable ``(q, k, v) -> att`` over the
-    rope-applied ``[b, s, heads, head_dim]`` tensors — the KV-cache
-    decode path (models/decode.py) supplies one that appends to its
-    cache and attends the single query against the prefix, so decoding
-    reuses THIS wiring instead of a third copy.
+    ``ln1``, ``ln2``, ``mlp`` and the optional ``post_attn_norm`` and
+    ``post_mlp_norm`` (on a branch's output before its residual add) are
+    callables too; ``mlp`` returns its DELTA.
 
-    The attention half's optional steps, each a callable where the
-    configuration asks for it and ``None`` where not: ``q_norm`` and
-    ``k_norm`` over each head's channels after the head split and before
-    RoPE; ``gate``, from the same normed stream as the queries and as
-    wide, whose sigmoid multiplies the attended values before ``proj``
-    (scope ``attn_gate``); ``post_attn_norm`` and ``post_mlp_norm`` on a
-    branch's output before its residual add.  ``layer_type`` gives the
-    attention call its window (``cfg.window_of``); the caller hands in
-    ``rope_tabs=None`` for a layer that sees no positions.
-
-    Values that cross layers: with ``hand_on="memory"`` the ``ssm``
-    callable returns ``(delta, scan output)``, with ``hand_on="kv"`` the
-    attention half's ``(k, v)`` as it attends them is handed on, and the
-    function returns ``(x, value)`` instead of ``x``.  A layer that
-    reads one gets it from its caller: ``gmu`` is a callable of the
-    normed stream that closes over the memory (scope ``gmu``);
-    ``shared_kv=(k, v)`` makes ``qkv`` a projection to the queries alone
-    (scope ``attn_cross`` around the attention call).  ``differential``
-    holds ``lambdas``, ``subln`` and ``lambda_init`` where the attention
-    is differential (:func:`_attend_differential`, scope ``attn_diff``).
+    Values that cross layers: with ``hand_on`` (``"kv"`` or
+    ``"memory"``) the mixer returns ``(delta, value)`` and the function
+    returns ``(x, value)`` instead of ``x``.  A layer that reads one
+    has it closed over in its mixer.
 
     ``route``: where the configuration's router reads the layer's input,
     a callable applied to the block's input ``x`` before ``ln1`` (it
@@ -842,13 +888,6 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
     block the decision crosses the mixer half inside one
     ``jax.checkpoint``, and its recompute is the sort again.
     """
-    b, s, _ = x.shape
-    nh = num_heads if num_heads is not None else cfg.num_heads
-    nkv = num_kv_heads if num_kv_heads is not None else cfg.kv_heads
-    hd = cfg.head_dim
-    q_dim = nh * hd
-    kv_dim = nkv * hd
-
     def add(x, delta, post=None):
         if post is not None:
             delta = post(delta).astype(x.dtype)
@@ -861,61 +900,13 @@ def block_math(cfg: TransformerConfig, x, positions, rope_tabs, *,
     # fusions.  A scope is metadata: it names no parameter, so the flax
     # tree stays ``block<i>/{ln1,qkv,proj,ln2,fc1,fc2}``.
     handed = None
+    scope = MIXER_SCOPES.get(layer_type, scopes.ATTN)
     decided = () if route is None else (route(x),)
-    if ssm is not None:
-        with jax.named_scope(scopes.SSM):
-            delta = ssm(ln1(x))
-            if hand_on == "memory":
-                delta, handed = delta
-            x = add(x, act_store(delta, cfg), post_attn_norm)
-    elif gmu is not None:
-        with jax.named_scope(scopes.GMU):
-            x = add(x, act_store(gmu(ln1(x)), cfg), post_attn_norm)
-    elif mla is not None:
-        with jax.named_scope(scopes.ATTN):
-            x = add(x, act_store(mla(ln1(x)), cfg), post_attn_norm)
-    else:
-        with jax.named_scope(scopes.ATTN):
-            h = ln1(x)
-            fused = qkv(h)
-            q = fused[..., :q_dim].reshape(b, s, nh, hd)
-            if shared_kv is not None:
-                k, v = shared_kv
-            else:
-                k = fused[..., q_dim:q_dim + kv_dim].reshape(b, s, nkv, hd)
-                v = fused[..., q_dim + kv_dim:].reshape(b, s, nkv, hd)
-            if q_norm is not None:
-                q = q_norm(q).astype(fused.dtype)
-            if k_norm is not None:
-                k = k_norm(k).astype(fused.dtype)
-            if rope_tabs is not None:
-                from ..ops.rope import apply_rope_tables  # noqa: PLC0415
-
-                q = apply_rope_tables(q, *rope_tabs)
-                k = apply_rope_tables(k, *rope_tabs)
-            if hand_on == "kv":
-                handed = (k, v)
-            if differential is not None:
-                att_4d = _attend_differential(cfg, q, k, v, positions,
-                                              layer_type, **differential)
-            elif attend is None:
-                attend_cfg = cfg
-                if nh != cfg.num_heads or nkv != cfg.kv_heads:
-                    # per-rank head shard: _attend sees the LOCAL head
-                    # geometry
-                    attend_cfg = replace(cfg, num_heads=nh,
-                                         num_kv_heads=nkv, emb_dim=q_dim)
-                att_4d = _attend(attend_cfg, q, k, v, positions,
-                                 layer_type)
-            else:
-                att_4d = attend(q, k, v)
-            att = att_4d.reshape(b, s, q_dim)
-            if gate is not None:
-                with jax.named_scope(scopes.ATTN_GATE):
-                    att = (att * jax.nn.sigmoid(
-                        gate(h).astype(jnp.float32))).astype(att.dtype)
-            att = act_store(att, cfg)
-            x = add(x, act_store(proj(att), cfg), post_attn_norm)
+    with jax.named_scope(scope):
+        delta = mixer(ln1(x))
+        if hand_on is not None:
+            delta, handed = delta
+        x = add(x, act_store(delta, cfg), post_attn_norm)
     with jax.named_scope(scopes.MLP):
         x = add(x, act_store(mlp(ln2(x), *decided), cfg), post_mlp_norm)
     return x if hand_on is None else (x, handed)
@@ -956,13 +947,13 @@ def raw_block_forward(cfg: TransformerConfig, p, x, positions, rope_tabs,
         return raw_dense(p["fc2"], dt)(m)
 
     return block_math(
-        cfg, x, positions, rope_tabs,
+        cfg, x,
         ln1=lambda h: raw_layer_norm(h, p["ln1"]["scale"], p["ln1"]["bias"]),
-        qkv=raw_dense(p["qkv"], dt),
-        proj=raw_dense(p["proj"], dt),
+        mixer=lambda h: attention_mixer(
+            cfg, h, positions, rope_tabs, qkv=raw_dense(p["qkv"], dt),
+            proj=raw_dense(p["proj"], dt), attend=attend),
         ln2=lambda h: raw_layer_norm(h, p["ln2"]["scale"], p["ln2"]["bias"]),
         mlp=mlp,
-        attend=attend,
     )
 
 
@@ -997,7 +988,8 @@ class Block(nn.Module):
     the flax parameters (the attention mixer's, a state-space mixer's, a
     gated memory unit's or latent attention's, by ``layer_type``; a
     dense feed-forward's or the routed experts', by ``ffn``) and hands
-    their applications in as callables.  ``hand_on`` says what the block
+    their applications in as callables: one ``mixer`` closure over the
+    layer type's mixer function, the norms and ``mlp``.  ``hand_on`` says what the block
     returns beside ``x`` for later layers (``cfg.hands_on``);
     ``layer_index`` is the layer's index in the whole model.  A
     ``cross_attention`` block is called with ``shared_kv``, a ``gmu``
@@ -1132,13 +1124,12 @@ class Block(nn.Module):
                 return y.astype(cfg.dtype)
             return feed_forward(h, width, "fc1", "fc2")
 
-        mixer = {}
         if self.layer_type == "mamba":
             inner, heads = cfg.ssm_inner, cfg.ssm_heads
             conv_dim = inner + 2 * cfg.ssm_groups * cfg.ssm_state
             zeros, ones = nn.initializers.zeros, nn.initializers.ones
 
-            def ssm(h):
+            def mixer(h):
                 return mamba_mixer(
                     cfg, h,
                     in_proj=nn.Dense(inner + conv_dim + heads,
@@ -1161,11 +1152,10 @@ class Block(nn.Module):
                                       use_bias=False, name="out_proj"),
                 )
 
-            mixer["ssm"] = ssm
         elif self.layer_type == "selective_scan":
             inner, n = cfg.ssm_width, cfg.ssm_state
 
-            def ssm(h):
+            def mixer(h):
                 delta, y = selective_scan_mixer(
                     cfg, h,
                     in_proj=unbiased(2 * inner, "in_proj"),
@@ -1189,15 +1179,14 @@ class Block(nn.Module):
                     out_proj=unbiased(cfg.emb_dim, "out_proj"))
                 return (delta, y) if self.hand_on == "memory" else delta
 
-            mixer["ssm"] = ssm
         elif self.layer_type == "gmu":
-            mixer["gmu"] = lambda h: gmu_mixer(
+            mixer = lambda h: gmu_mixer(
                 h, memory, in_proj=unbiased(cfg.ssm_width, "in_proj"),
                 out_proj=unbiased(cfg.emb_dim, "out_proj"))
         elif self.layer_type == "mla":
             heads = cfg.num_heads
 
-            def mla(h):
+            def mixer(h):
                 return mla_mixer(
                     cfg, h, positions, rope_tabs,
                     q_a=dense(cfg.q_lora_rank, "q_a"),
@@ -1211,42 +1200,44 @@ class Block(nn.Module):
                                         + cfg.v_head_dim), "kv_b"),
                     proj=dense(cfg.emb_dim, "proj"))
 
-            mixer["mla"] = mla
         else:
             q_dim = cfg.num_heads * cfg.head_dim
+            attn = {}
             if self.layer_type == "cross_attention":
-                mixer["qkv"] = dense(q_dim, "q")
-                mixer["shared_kv"] = shared_kv
+                attn["qkv"] = dense(q_dim, "q")
+                attn["shared_kv"] = shared_kv
             else:
-                mixer["qkv"] = dense(q_dim + 2 * kv_dim, "qkv")
-            mixer["proj"] = dense(cfg.emb_dim, "proj")
-            mixer["layer_type"] = self.layer_type
+                attn["qkv"] = dense(q_dim + 2 * kv_dim, "qkv")
+            attn["proj"] = dense(cfg.emb_dim, "proj")
             if cfg.differential_attention:
                 vector = lambda name: self.param(
                     name, nn.initializers.normal(0.1), (cfg.head_dim,),
                     jnp.float32)
-                mixer["differential"] = dict(
+                attn["differential"] = dict(
                     lambdas=[vector(name) for name in (
                         "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")],
                     subln=nn.RMSNorm(epsilon=cfg.norm_eps,
                                      dtype=jnp.float32, name="subln"),
                     lambda_init=differential_lambda_init(self.layer_index))
             if cfg.qk_norm:
-                mixer["q_norm"] = _norm(cfg, "q_norm")
-                mixer["k_norm"] = _norm(cfg, "k_norm")
+                attn["q_norm"] = _norm(cfg, "q_norm")
+                attn["k_norm"] = _norm(cfg, "k_norm")
             if cfg.attention_gate:
-                mixer["gate"] = dense(q_dim, "gate")
-            if not cfg.rotates(self.layer_type):
-                rope_tabs = None
+                attn["gate"] = dense(q_dim, "gate")
+            tabs = rope_tabs if cfg.rotates(self.layer_type) else None
+            mixer = lambda h: attention_mixer(
+                cfg, h, positions, tabs, layer_type=self.layer_type,
+                hand_on=self.hand_on, **attn)
+        block = {}
         if cfg.post_norms:
-            mixer["post_attn_norm"] = _norm(cfg, "post_attn_norm")
-            mixer["post_mlp_norm"] = _norm(cfg, "post_mlp_norm")
+            block["post_attn_norm"] = _norm(cfg, "post_attn_norm")
+            block["post_mlp_norm"] = _norm(cfg, "post_mlp_norm")
         if self.ffn == "routed" and cfg.routed_router_input == "layer_input":
-            mixer["route"] = lambda x: decide(x.reshape(-1, x.shape[-1]))
+            block["route"] = lambda x: decide(x.reshape(-1, x.shape[-1]))
         return block_math(
-            cfg, x, positions, rope_tabs,
-            ln1=_norm(cfg, "ln1"), ln2=_norm(cfg, "ln2"), mlp=mlp,
-            hand_on=self.hand_on, **mixer,
+            cfg, x, ln1=_norm(cfg, "ln1"), mixer=mixer,
+            ln2=_norm(cfg, "ln2"), mlp=mlp, layer_type=self.layer_type,
+            hand_on=self.hand_on, **block,
         )
 
 

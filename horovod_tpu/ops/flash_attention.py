@@ -6,17 +6,26 @@ The reference framework has no kernels of its own — its FLOPs live in
 cuDNN via TF/torch; on TPU the idiomatic equivalent is a Pallas kernel
 that keeps the (S, S) score matrix out of HBM entirely.
 
-Design (the standard flash recurrence, TPU-shaped):
+Design (the standard flash recurrence, TPU-shaped; what the chip said of
+each choice is in ``docs/performance.md``, "The flash kernels on the
+chip"):
 
-* Grid ``(batch*heads, S/block_q, S/block_k)``; each program owns one Q
-  tile and one (block_k, d) K/V tile — the online-softmax state rides
-  VMEM scratch across the sequential K grid dimension.  Wherever a kv
-  row's K and V fit VMEM (``forward_plan``; every benchmark shape, up to
-  30208 keys at head size 128 in bfloat16) the forward holds the row
-  resident, fetched from HBM once for all the grid steps that read it,
-  and slices its tile (PR 46); a longer row streams (1, block_k, d)
-  tiles, one a grid step, and peak memory is O(block_q*d + block_k*d),
-  independent of S.
+* What a call runs is decided once, from its shapes alone, by
+  :func:`flash_plan`: the tiles, the live and grid tile counts, how the
+  forward holds K and V, the backward's form and the VMEM each states.
+  ``flash_attention`` makes the :class:`FlashPlan` and hands it to the
+  kernels as their static argument; nothing below asks again, and a
+  caller that wants to know what its call will do asks the same
+  function.
+* Forward: grid ``(batch*heads, S/block_q, S/block_k)``; each program
+  owns one Q tile and one (block_k, d) K/V tile — the online-softmax
+  state rides VMEM scratch across the sequential K grid dimension.
+  Wherever a kv row's K and V fit ``_FUSED_BWD_VMEM_LIMIT`` (every
+  benchmark shape, up to 30208 keys at head size 128 in bfloat16) the
+  forward holds the row resident, fetched from HBM once for all the
+  grid steps that read it, and slices its tile; a longer row streams
+  (1, block_k, d) tiles, one a grid step, and peak memory is
+  O(block_q*d + block_k*d), independent of S.
 * fp32 accumulators regardless of input dtype (bf16 in, bf16 out, fp32
   softmax state — the MXU-native mixed precision).
 * Every kernel forms its score tile TRANSPOSED (``k @ q.T``: keys on
@@ -26,45 +35,31 @@ Design (the standard flash recurrence, TPU-shaped):
   sublanes with elementwise work, broadcast back the same way, and
   crosses HBM as ``[Z, S]``, a 2 KB row per Q tile.  Nothing in a tile
   loop goes through the cross-lane unit and no score-sized tile is
-  ever transposed.  On a TPU v5e at 128 x 1024 x 64, bf16, causal,
-  512 x 256 tiles, per call (chip runs of PR 26): forward 1.434 ms with
-  the statistics as lane-replicated ``(block_q, 128)`` columns reduced
-  and re-broadcast per tile, 0.750 with the columns reduced once per
-  row block, 0.633 as rows; dk/dv 1.210 -> 0.973, dq 0.860 -> 0.679.
+  ever transposed.
 * Causal programs stop their K loop at the diagonal tile — the upper
-  triangle is never computed, not just masked.
+  triangle is never computed, not just masked; with a window, nor are
+  the tiles entirely below the band.  The grid is whole whatever the
+  mask: a dead tile costs its grid step and no arithmetic.
 * Backward is a blockwise recompute from the saved logsumexp, wired via
   ``jax.custom_vjp`` so the op drops into training.  ``delta =
-  rowsum(do * o)`` is computed once per call, outside the kernels
-  (0.025 ms there), not once per tile.  ONE kernel forms each tile's
-  ``p`` and ``ds`` once and takes dq, dk and dv from them; which sums
-  stay resident in VMEM is read from the shape alone (``backward_plan``:
-  what each form holds against ``_FUSED_BWD_VMEM_LIMIT``, the 32 MiB the
-  call then states).  Q tile outermost, dq's accumulator and a whole kv
-  row's dk and dv accumulators resident, wherever that fits (a head's
-  channels fill whole 128-lane tiles, so 8192 keys at head size 64 or
-  128 and 4096 keys at head size 256); else K tile outermost, the tile's
-  dk and dv accumulators and the kv row's dq resident (8192 keys at head
-  size 256, latent attention's shape in ``glm47f_train_s8192``, up to
-  26624; 16384 keys at head size 64).  Where neither fits the 32 MiB,
+  rowsum(do * o)`` is computed once per call, outside the kernels.  ONE
+  kernel forms each tile's ``p`` and ``ds`` once and takes dq, dk and dv
+  from them, in one of two forms.  ``"dkdv_resident"``: Q tile
+  outermost, dq's accumulator and a whole kv row's dk and dv
+  accumulators resident, wherever that fits ``_FUSED_BWD_VMEM_LIMIT``,
+  the 32 MiB the call then states (a head's channels fill whole 128-lane
+  tiles, so 8192 keys at head size 64 or 128 and 4096 keys at head size
+  256).  ``"dq_resident"``: K tile outermost, the tile's dk and dv
+  accumulators and the kv row's dq resident, where that fits instead
+  (8192 keys at head size 256, latent attention's shape, up to 26624;
+  16384 keys at head size 64).  Where neither fits the 32 MiB,
   whichever of the two counts less, stating its own count, up to
-  ``_FUSED_BWD_VMEM_CEILING``, 48 of the chip's 128 MiB (PR 44: 16384
-  keys at head size 128 with seven query heads a key/value head,
-  ``smallthinker_train_s16384``, Q tile outermost at 36.25 MiB; up to
-  22016 keys there, 43008 at head size 256 with dq resident).  A longer
-  sequence takes the two passes the one kernel replaced (dk/dv, then dq,
-  each recomputing ``p`` and ``ds``), whose VMEM does not grow with S.
-  Per call at 128 x 1024 x 64 (chip runs of PR 29): two passes 0.973 +
-  0.679 ms, one kernel 1.025; at 32 query over 8 K/V heads x 8192 x 64,
-  10.72 + 7.51 against 12.00.  At 20 x 8192 x 256 (chip runs of PR 38):
-  two passes 21.21 ms, one kernel with dq resident 14.49 (2.66 us a
-  needed tile for 3.90); the kv row's dk and dv in two spans of 4096
-  keys with dq's float32 partials summed after the call 14.60 + 0.61, in
-  four spans 16.25.  At 28 query over 4 K/V heads x 16384 x 128 (chip
-  runs of PR 44): two passes 73.30 ms, one kernel stating 37 MiB 40.68
-  (1.38 us a live tile for 2.48); banded at window 4096, 53.45 against
-  25.21 (the two passes walk the 43 232 dead grid steps twice).
-* The value width is the values' own (``dv = v.shape[-1]``; PR 42): ``v``,
+  ``_FUSED_BWD_VMEM_CEILING``, 48 of the chip's 128 MiB (16384 keys at
+  head size 128 with seven query heads a key/value head, Q tile
+  outermost at 36.25 MiB; up to 22016 keys there, 43008 at head size 256
+  with dq resident).  ``"two_passes"`` for a longer row: dk/dv, then dq,
+  each recomputing ``p`` and ``ds``, VMEM independent of S.
+* The value width is the values' own (``dv = v.shape[-1]``): ``v``,
   ``o``, ``do`` and dv carry it, ``q``, ``k``, dq and dk the head size
   ``d``, and the default scale stays ``d ** -0.5``.  The kernel bodies
   do not know the difference (``k q^T`` contracts ``d``; ``P V``, ``dp =
@@ -73,25 +68,11 @@ Design (the standard flash recurrence, TPU-shaped):
   backward's dv accumulator (``[S, dv]`` with the Q tile outermost,
   ``[bk, dv]`` with the K tile outermost, beside dk's ``[S, d]`` /
   ``[bk, d]``; dq's ``[d, bq]`` rows keep ``d`` in every form) and the
-  VMEM counts behind ``backward_form``, the dk and dv halves each at its
-  own padded lanes (8192 keys of 64 with values of 128 count 8192 x
-  64's 20.1 MiB: both widths pad to 128 lanes).  A call with ``dv == d``
-  is the program it was, spec for spec.  The caller with two widths is
-  differential attention (``models/transformer.py:
-  _attend_differential``): 40 query rows of 64 on 20 key/value rows with
-  values of 128, each score map formed once where a stacked call at
-  head size 64 over 80 rows on 40 formed it twice.  At 1 x 8192, bf16,
-  causal, per call alone with its layout (chip runs of PR 42): forward
-  12.49 ms for the stacked call's 22.30, forward and backward 28.21 for
-  52.90; banded at window 512, 8.21 for 14.08 and 17.10 for 29.98.  The
-  same maps through ``dv == d`` kernels with ``q`` and ``k`` zero-padded
-  to 128 channels read 12.45 and 28.95 (8.16 and 17.23 banded): keys of
-  64 or of 128, a tile with values of 128 costs the same.  In the step
-  of ``phi4mf_train_s8192`` the kernels alone: a full causal call 11.65
-  ms forward and 15.68 backward (10 880 live 512 x 256 tiles: 1.07 and
-  1.44 us each, where the stacked call's 21 760 cost 0.96 and 1.44), a
-  banded call 6.55 and 8.72 (2 480 live tiles, and 18 000 dead grid
-  steps at 0.22 and 0.29 us each).
+  VMEM counts, the dk and dv halves each at its own padded lanes.  A
+  call with ``dv == d`` is the program it was, spec for spec.  The
+  caller with two widths is differential attention
+  (``models/transformer.py:_attend_differential``): 40 query rows of 64
+  on 20 key/value rows with values of 128, each score map formed once.
 * Off-TPU (the CPU test mesh) the same kernel runs through the Pallas
   interpreter, so correctness tests don't need TPU hardware.
 * The ``pallas_call`` sites are named ``flash_fwd``, ``flash_bwd_dkdv``
@@ -106,6 +87,7 @@ Design (the standard flash recurrence, TPU-shaped):
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -129,6 +111,118 @@ def _pick_block(seq: int, want: int) -> int:
     while seq % b:
         b //= 2
     return max(b, 1)
+
+
+@dataclasses.dataclass(frozen=True)
+class FlashPlan:
+    """What one flash call runs, read from its shapes alone
+    (:func:`flash_plan`); the kernels' static argument."""
+
+    heads: int
+    kv_heads: int
+    block_q: int                # the tiles as ``_pick_block`` made them
+    block_k: int
+    causal: bool
+    window: Optional[int]       # None where it reaches every earlier key
+    # Of the (q, k) tiles the grid walks over all batch x head rows, how
+    # many the kernels' ``needed`` predicate admits.  A dead tile costs
+    # its grid step and no arithmetic, and a DMA of K and V tiles only
+    # where those stream (the one-kernel backward's, and the forward's
+    # of a kv row too long to stay resident).
+    tiles_live: int
+    tiles_grid: int
+    # The forward: a kv row's K and V whole in VMEM (fetched once a row,
+    # under GQA once for the group's query heads), or (1, block_k, .)
+    # tiles streamed, one a grid step, VMEM independent of S; and the
+    # VMEM its call states (0: nothing, the compiler's default).
+    fwd_kv_resident: bool
+    fwd_vmem_bytes: int
+    # The backward: "dkdv_resident" (one kernel, Q tile outermost, a kv
+    # row's dk and dv accumulators resident), "dq_resident" (one kernel,
+    # K tile outermost, the group's dq rows resident) or "two_passes";
+    # and the VMEM its one kernel states (0: the two passes state none).
+    bwd_form: str
+    bwd_vmem_bytes: int
+
+    @property
+    def bwd_kernels(self) -> int:
+        return 2 if self.bwd_form == "two_passes" else 1
+
+
+def flash_plan(q, k, v, *, causal: bool = False, block_q: int = 512,
+               block_k: int = 256,
+               window: Optional[int] = None) -> FlashPlan:
+    """The :class:`FlashPlan` of ``flash_attention(q, k, v, ...)`` with
+    the same keyword arguments, from the shapes and the dtype of ``q``,
+    ``k`` and ``v`` alone (arrays or ``jax.ShapeDtypeStruct``); raises
+    what that call would raise of them.  Plain Python, for the call
+    itself and for whoever counts while a step is traced.
+
+    The forward holds a kv row resident wherever
+    ``_fwd_resident_vmem_bytes`` fits ``_FUSED_BWD_VMEM_LIMIT`` and
+    states a limit only where the count passes the compiler's default
+    scoped limit, then the count rounded up to a MiB.  The backward is
+    ``"dkdv_resident"`` wherever ``_fused_bwd_vmem_bytes`` fits
+    ``_FUSED_BWD_VMEM_LIMIT``, ``"dq_resident"`` where
+    ``_dq_resident_bwd_vmem_bytes`` fits it instead, both then stating
+    the limit; above both, whichever of the two counts less, if that
+    fits ``_FUSED_BWD_VMEM_CEILING``, stating its own count rounded up
+    to a MiB; ``"two_passes"`` above that."""
+    b, s, h, d = q.shape
+    if k.shape[:3] != v.shape[:3]:
+        raise ValueError(
+            f"flash_attention requires k and v matching in batch, sequence "
+            f"and head count (the value width is v's own), got "
+            f"{k.shape}/{v.shape}"
+        )
+    hkv, dv = k.shape[2], v.shape[3]
+    if k.shape[0] != b or k.shape[1] != s or k.shape[3] != d or h % hkv:
+        raise ValueError(
+            f"flash_attention q {q.shape} incompatible with k/v {k.shape}: "
+            "batch/seq/head_dim must match and num_heads must be a "
+            "multiple of num_kv_heads (MQA/GQA)"
+        )
+    if window is not None:
+        if not causal:
+            raise ValueError("window requires causal=True")
+        if window < 1:
+            raise ValueError(f"window must be >= 1, got {window}")
+        if window >= s:
+            window = None  # full causal; skip/mask logic not needed
+    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
+    nq, nk = s // bq, s // bk
+    live = 0
+    for i in range(nq):
+        for j in range(nk):
+            needed = j * bk <= (i + 1) * bq - 1 if causal else True
+            if window is not None:
+                needed = needed and (j + 1) * bk - 1 >= i * bq - (window - 1)
+            live += needed
+    itemsize = jnp.dtype(q.dtype).itemsize
+
+    fwd = _fwd_resident_vmem_bytes(s, d, dv, bq, bk, itemsize)
+    resident = fwd <= _FUSED_BWD_VMEM_LIMIT
+    stated = resident and fwd > _DEFAULT_SCOPED_VMEM
+
+    q_outer = _fused_bwd_vmem_bytes(s, d, bq, bk, itemsize, dv)
+    k_outer = _dq_resident_bwd_vmem_bytes(s, d, bq, bk, itemsize, h // hkv,
+                                          dv)
+    if q_outer <= _FUSED_BWD_VMEM_LIMIT:
+        form, bwd_vmem = "dkdv_resident", _FUSED_BWD_VMEM_LIMIT
+    elif k_outer <= _FUSED_BWD_VMEM_LIMIT:
+        form, bwd_vmem = "dq_resident", _FUSED_BWD_VMEM_LIMIT
+    else:
+        count, form = min((q_outer, "dkdv_resident"),
+                          (k_outer, "dq_resident"))
+        bwd_vmem = _whole_mib(count)
+        if count > _FUSED_BWD_VMEM_CEILING:
+            form, bwd_vmem = "two_passes", 0
+    return FlashPlan(
+        heads=h, kv_heads=hkv, block_q=bq, block_k=bk, causal=causal,
+        window=window, tiles_live=b * h * live, tiles_grid=b * h * nq * nk,
+        fwd_kv_resident=resident,
+        fwd_vmem_bytes=_whole_mib(fwd) if stated else 0,
+        bwd_form=form, bwd_vmem_bytes=bwd_vmem)
 
 
 def flash_attention(
@@ -166,30 +260,10 @@ def flash_attention(
     so compute scales with ``S*W``, not ``S^2``; ``W >= S`` degenerates
     to plain causal.
     """
+    plan = flash_plan(q, k, v, causal=causal, block_q=block_q,
+                      block_k=block_k, window=window)
     b, s, h, d = q.shape
-    if k.shape[:3] != v.shape[:3]:
-        raise ValueError(
-            f"flash_attention requires k and v matching in batch, sequence "
-            f"and head count (the value width is v's own), got "
-            f"{k.shape}/{v.shape}"
-        )
-    hkv = k.shape[2]
-    if k.shape[0] != b or k.shape[1] != s or k.shape[3] != d or h % hkv:
-        raise ValueError(
-            f"flash_attention q {q.shape} incompatible with k/v {k.shape}: "
-            "batch/seq/head_dim must match and num_heads must be a "
-            "multiple of num_kv_heads (MQA/GQA)"
-        )
-    if window is not None:
-        if not causal:
-            raise ValueError("window requires causal=True")
-        if window < 1:
-            raise ValueError(f"window must be >= 1, got {window}")
-        if window >= s:
-            window = None  # full causal; skip/mask logic not needed
     scale_ = scale if scale is not None else d ** -0.5
-    bq = _pick_block(s, block_q)
-    bk = _pick_block(s, block_k)
     if interpret is None:
         interpret = _interpret_for_backend(jax.default_backend())
     # [B,S,H,D] -> [B*H, S, D]: one grid row per (batch, head).  GQA/MQA:
@@ -198,33 +272,8 @@ def flash_attention(
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(
         b * x.shape[2], s, x.shape[3]
     )
-    out = _flash(fold(q), fold(k), fold(v), causal, scale_, bq, bk,
-                 h, hkv, window, bool(interpret))
+    out = _flash(fold(q), fold(k), fold(v), plan, scale_, bool(interpret))
     return out.reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)
-
-
-def tile_counts(rows: int, seq: int, block_q: int, block_k: int, *,
-                causal: bool, window: Optional[int] = None):
-    """``(live, grid)``: of the ``(q, k)`` tiles one call's grid walks
-    (``rows`` batch x head rows of ``seq`` keys, tiles as ``_pick_block``
-    makes them), how many the kernels' ``needed`` predicate admits.  The
-    grid is whole whatever the mask: a dead tile costs its grid step and
-    no arithmetic, and a DMA of K and V tiles only where those stream
-    (the one-kernel backward's, and the forward's of a kv row too long
-    to stay resident: ``forward_plan``).  Plain Python on shapes, for a
-    counter set while a step is traced."""
-    bq, bk = _pick_block(seq, block_q), _pick_block(seq, block_k)
-    nq, nk = seq // bq, seq // bk
-    if window is not None and window >= seq:
-        window = None
-    live = 0
-    for i in range(nq):
-        for j in range(nk):
-            needed = j * bk <= (i + 1) * bq - 1 if causal else True
-            if window is not None:
-                needed = needed and (j + 1) * bk - 1 >= i * bq - (window - 1)
-            live += needed
-    return rows * live, rows * nq * nk
 
 
 def _interpret_for_backend(backend: str) -> bool:
@@ -239,18 +288,14 @@ def _interpret_for_backend(backend: str) -> bool:
     )
 
 
-@functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
-def _flash(q, k, v, causal, scale, bq, bk, h, hkv, window, interpret):
-    o, _ = _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv,
-                             window, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, plan, scale, interpret):
+    o, _ = _flash_fwd_kernel(q, k, v, plan, scale, interpret)
     return o
 
 
-def _flash_fwd(q, k, v, causal, scale, bq, bk, h, hkv, window,
-               interpret):
-    o, lse = _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv,
-                               window, interpret)
+def _flash_fwd(q, k, v, plan, scale, interpret):
+    o, lse = _flash_fwd_kernel(q, k, v, plan, scale, interpret)
     # named here, not at the call site: the residual has to be the named
     # value, or a rematerialised block reruns the kernel to get it
     o = checkpoint_name(o, scopes.FLASH_OUT)
@@ -258,11 +303,9 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, h, hkv, window,
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, scale, bq, bk, h, hkv, window, interpret, res,
-               do):
+def _flash_bwd(plan, scale, interpret, res, do):
     q, k, v, o, lse = res
-    return _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
-                             h, hkv, window, interpret)
+    return _flash_bwd_pallas(q, k, v, o, lse, do, plan, scale, interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -275,14 +318,19 @@ def _kv_row(zi, h: int, hkv: int):
 
 # The VMEM a one-kernel backward states (``vmem_limit_bytes``) wherever its
 # resident accumulators and tiles fit it: twice a v5e's default scoped
-# limit, a quarter of its VMEM.  ``backward_plan`` reads the form from
-# the shape against it.
+# limit, a quarter of its VMEM.  ``flash_plan`` reads the backward's form
+# from the shape against it, and whether the forward holds a kv row.
 _FUSED_BWD_VMEM_LIMIT = 32 * 2 ** 20
 # What a call that fits neither form in the limit above may still take
-# as one kernel (PR 44): the form that counts less, stating its own count
-# (a whole MiB) and not the constant, up to three eighths of the VMEM.
+# as one kernel: the form that counts less, stating its own count (a
+# whole MiB) and not the constant, up to three eighths of the VMEM.
 # Above it the two passes, whose VMEM does not grow with S.
 _FUSED_BWD_VMEM_CEILING = 48 * 2 ** 20
+# The scoped VMEM the TPU compiler gives a kernel that states none.  A
+# forward whose count fits it states nothing, so XLA schedules around it
+# as around the call it was (what moves XLA's prefetch around a Pallas
+# call is the VMEM the call states).
+_DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
 
 
 def _lanes(width: int) -> int:
@@ -334,54 +382,6 @@ def _dq_resident_bwd_vmem_bytes(s: int, d: int, bq: int, bk: int,
     return resident + tiles + 6 * bk * bq * 4
 
 
-def backward_plan(seq: int, head_dim: int, group: int, itemsize: int,
-                  block_q: int = 512, block_k: int = 256,
-                  value_dim: Optional[int] = None):
-    """``(form, vmem_limit_bytes)``: which backward a call of this shape
-    runs and the VMEM its one kernel states, from the shape alone
-    (``group`` query heads a key/value head, tiles as ``_pick_block``
-    makes them, values ``value_dim`` wide: ``None`` says as wide as the
-    keys).
-    ``"dkdv_resident"``: one kernel, Q tile outermost, a kv row's dk and
-    dv accumulators resident (PR 29's), wherever it fits
-    ``_FUSED_BWD_VMEM_LIMIT``.  ``"dq_resident"``: one kernel, K tile
-    outermost, the group's dq rows resident, where that fits the limit
-    instead.  Both then state the limit, so a call that fit it before
-    PR 44 is the program it was.  Above both, whichever of the two
-    counts less, if that fits ``_FUSED_BWD_VMEM_CEILING``, stating its
-    own count rounded up to a MiB (16384 keys at head size 128 with
-    seven query heads a key/value head: 36.25 MiB with the Q tile
-    outermost, ``smallthinker_train_s16384``).  ``"two_passes"`` above
-    that, which state nothing (0).
-    ``_flash_bwd_pallas`` branches on it and ``models/transformer.py``
-    sets its gauges from it while a step is traced."""
-    bq, bk = _pick_block(seq, block_q), _pick_block(seq, block_k)
-    q_outer = _fused_bwd_vmem_bytes(seq, head_dim, bq, bk, itemsize,
-                                    value_dim)
-    k_outer = _dq_resident_bwd_vmem_bytes(seq, head_dim, bq, bk, itemsize,
-                                          group, value_dim)
-    if q_outer <= _FUSED_BWD_VMEM_LIMIT:
-        return "dkdv_resident", _FUSED_BWD_VMEM_LIMIT
-    if k_outer <= _FUSED_BWD_VMEM_LIMIT:
-        return "dq_resident", _FUSED_BWD_VMEM_LIMIT
-    count, form = min((q_outer, "dkdv_resident"), (k_outer, "dq_resident"))
-    if count <= _FUSED_BWD_VMEM_CEILING:
-        return form, _whole_mib(count)
-    return "two_passes", 0
-
-
-def backward_form(*shape, **tiles) -> str:
-    """The form alone of ``backward_plan`` (same arguments)."""
-    return backward_plan(*shape, **tiles)[0]
-
-
-# The scoped VMEM the TPU compiler gives a kernel that states none.  A
-# forward whose count fits it states nothing, so XLA schedules around it
-# as around the call it was (PR 31: what moves XLA's prefetch around a
-# Pallas call is the VMEM the call states).
-_DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
-
-
 def _fwd_resident_vmem_bytes(s: int, d: int, dv: int, bq: int, bk: int,
                              itemsize: int) -> int:
     """VMEM the forward holds with a kv row's K and V resident, every
@@ -401,33 +401,9 @@ def _fwd_resident_vmem_bytes(s: int, d: int, dv: int, bq: int, bk: int,
     return rows + tiles + state + copies + 2 * bk * _lanes(bq) * 4
 
 
-def forward_plan(seq: int, head_dim: int, value_dim: int, itemsize: int,
-                 block_q: int = 512, block_k: int = 256):
-    """``(resident, vmem_limit_bytes)``: how the forward of this shape
-    holds K and V and the VMEM its call states, from the shape alone
-    (tiles as ``_pick_block`` makes them).  Resident: a kv row's K and V
-    whole in VMEM, fetched once a row (under GQA once for the group's
-    query heads, ``group x nq x nk`` grid steps), wherever the count
-    fits ``_FUSED_BWD_VMEM_LIMIT``; above it (1, block_k, .) tiles
-    stream, one a grid step, VMEM independent of S.  The call states a
-    limit only where the count passes the compiler's default scoped
-    limit, and then the count rounded up to a MiB (0: nothing stated).
-    ``_flash_fwd_kernel`` branches on it and ``models/transformer.py``
-    sets its gauges from it while a step is traced."""
-    bq, bk = _pick_block(seq, block_q), _pick_block(seq, block_k)
-    count = _fwd_resident_vmem_bytes(seq, head_dim, value_dim, bq, bk,
-                                     itemsize)
-    if count > _FUSED_BWD_VMEM_LIMIT:
-        return False, 0
-    if count <= _DEFAULT_SCOPED_VMEM:
-        return True, 0
-    return True, _whole_mib(count)
-
-
-def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
-                      interpret):
+def _flash_fwd_kernel(q, k, v, plan: FlashPlan, scale, interpret):
     """Returns (o [Z,S,DV], lse [Z,S]) with Z = batch*heads and DV the
-    values' width.
+    values' width, for folded operands and the call's ``plan``.
 
     Grid ``(z, nq, nk)``, K tiles innermost, whole whatever the mask.
     The online-softmax state (acc [dv, bq], m and l [1, bq]: transposed
@@ -436,13 +412,13 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
     leaves as one row per Q tile.  GQA/MQA: k/v have Z_kv = batch*hkv
     rows; the index map routes each q head to its group.
 
-    How K and V get to VMEM is read from the shape (``forward_plan``).
+    How K and V get to VMEM is the plan's ``fwd_kv_resident``.
     Wherever a kv row fits, its K and V are whole-row blocks ``(1, s,
     d)`` and ``(1, s, dv)`` whose block index moves once a kv row; the
     body slices the tile it needs.  The row is fetched from HBM once for
     the ``group x nq x nk`` grid steps that read it, where (1, bk, .)
-    tiles were fetched once a grid step, live or dead, ``group x nq``
-    times over (PR 46), and a dead grid step copies nothing.  Above the
+    tiles would be fetched once a grid step, live or dead, ``group x
+    nq`` times over, and a dead grid step copies nothing.  Above the
     limit the streamed tiles: only (1, bk, d) of K and (1, bk, dv) of V
     are resident per step and VMEM peak is O(bq*dv + bk*(d + dv)),
     independent of S (the long-context requirement).  Both forms run the
@@ -451,8 +427,9 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
     """
     z, s, d = q.shape
     dv = v.shape[-1]
+    bq, bk, h, hkv = plan.block_q, plan.block_k, plan.heads, plan.kv_heads
+    causal, window, resident = plan.causal, plan.window, plan.fwd_kv_resident
     nq, nk = s // bq, s // bk
-    resident, vmem_limit = forward_plan(s, d, dv, q.dtype.itemsize, bq, bk)
 
     def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref):
         i = pl.program_id(1)
@@ -541,7 +518,7 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=vmem_limit or None,
+            vmem_limit_bytes=plan.fwd_vmem_bytes or None,
         ),
         interpret=interpret,
         name="flash_fwd",
@@ -549,19 +526,18 @@ def _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv, window,
     return o, lse.reshape(z, s)
 
 
-def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
-                      h, hkv, window, interpret):
+def _flash_bwd_pallas(q, k, v, o, lse, do, plan: FlashPlan, scale,
+                      interpret):
     """Pallas flash backward, tiled, skipping fully-masked causal blocks
-    (the scan fallback below computes the whole upper triangle and
-    streams O(S*bk) score tiles through HBM — on a causal LM that is ~2x
-    wasted FLOPs and the dominant HBM stream).  P is recomputed from the
-    forward's saved logsumexp; ``delta`` = rowsum(do*o) is the standard
-    softmax-backward correction.
+    (a plain scan over K tiles, the tests' oracle, computes the whole
+    upper triangle and streams O(S*bk) score tiles through HBM — on a
+    causal LM that is ~2x wasted FLOPs and the dominant HBM stream).  P
+    is recomputed from the forward's saved logsumexp; ``delta`` =
+    rowsum(do*o) is the standard softmax-backward correction.
 
     One kernel (call site ``flash_bwd_dkdv``, which here covers dq, dk
-    AND dv), each needed tile's P and dS formed once, in the form
-    ``backward_form`` reads from the shape; no partial sum goes through
-    HBM in either.
+    AND dv), each needed tile's P and dS formed once, in the plan's
+    ``bwd_form``; no partial sum goes through HBM in either.
     ``"dkdv_resident"`` (grid z, nq, nk), wherever
     ``_fused_bwd_vmem_bytes`` fits ``_FUSED_BWD_VMEM_LIMIT`` (or, past
     it in both forms, counts less than the other and fits
@@ -581,12 +557,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
     query heads as [group*nq, d, bq], zeroed in the first K tile's
     sweep and written, turned and cast, in the last's (dq's block index
     stays put until then, so each block goes to HBM once).  It adds the
-    float32 terms in the order the two passes add them.  Per call at
-    128 x 1024 x 64 this orientation read 1.093 ms where the other reads
-    1.025, and 13.08 against 12.00 at 8192 keys with grouped heads (chip
-    runs of PR 29: dq there as a whole-row output block); at 20 x 8192 x
-    256, where the other does not fit, 14.49 ms against the two passes'
-    21.21, the same stating 16, 24 or 32 MiB (chip runs of PR 38).
+    float32 terms in the order the two passes add them.
 
     Two passes above the ceiling in both forms, each recomputing P and
     dS, VMEM independent of S:
@@ -597,22 +568,21 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
     """
     z, s, d = q.shape
     z_kv, dv = k.shape[0], v.shape[-1]
+    bq, bk, h, hkv = plan.block_q, plan.block_k, plan.heads, plan.kv_heads
+    causal, window = plan.causal, plan.window
+    form, vmem_limit = plan.bwd_form, plan.bwd_vmem_bytes
     group = h // hkv
     nq, nk = s // bq, s // bk
     f32 = jnp.float32
-    form, vmem_limit = backward_plan(s, d, group, q.dtype.itemsize, bq, bk,
-                                     dv)
     with_dq = form == "dq_resident"   # the K-outermost kernel takes dq too
     # delta is computed once per call and shared by all kernels, which
     # read it and lse as (1, bq) rows of a [Z, nq, 1, bq] view (a block
-    # equal to the last two dims is legal for any bq).  What the chip
-    # said of the alternatives (PR 26, per call at 128 x 1024 x 64):
-    # recomputing delta per tile from an o tile cost a multiply and a
-    # cross-lane sum per tile and one more 64 KB DMA per grid step;
-    # delta and lse as [Z, S, 128] lane-replicated arrays took that work
-    # out of the kernels and gave it back as 256 KB DMAs per dk/dv grid
-    # step and a second 64 MiB broadcast (kernels 2.069 -> 2.085 ms, the
-    # whole backward 2.687 -> 2.769); as rows 1.652 and 2.062.
+    # equal to the last two dims is legal for any bq).  Recomputing
+    # delta per tile from an o tile costs a multiply and a cross-lane
+    # sum per tile and one more DMA per grid step; delta and lse as
+    # [Z, S, 128] lane-replicated arrays take that work out of the
+    # kernels and give it back as larger DMAs per grid step and a second
+    # broadcast.
     delta = (do.astype(f32) * o.astype(f32)).sum(-1)
     lse_r, delta_r = (x.reshape(z, nq, 1, bq) for x in (lse, delta))
 
@@ -875,49 +845,3 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
         name="flash_bwd_dq",
     )(q, k, v, do, lse_r, delta_r)
     return dq, dk, dvalues
-
-
-def _flash_bwd_blockwise(q, k, v, o, lse, do, causal, scale, bk,
-                         window=None):
-    """Blockwise flash backward (pure JAX scan over K tiles) — kept as the
-    differential reference for the Pallas backward (tests pin equality)
-    and as a debugging fallback.  ``v`` and ``do`` may be wider or
-    narrower than ``q`` and ``k``: dv comes back at the values' width.
-    """
-    z, s, d = q.shape
-    nk = s // bk
-    qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
-    dof, of = do.astype(jnp.float32), o.astype(jnp.float32)
-    delta = (dof * of).sum(-1)  # [Z,S]
-    q_pos = jnp.arange(s)
-
-    def body(dq, j):
-        kb = lax.dynamic_slice_in_dim(kf, j * bk, bk, axis=1)
-        vb = lax.dynamic_slice_in_dim(vf, j * bk, bk, axis=1)
-        st = jnp.einsum("zqd,zkd->zqk", qf, kb) * scale
-        p = jnp.exp(st - lse[..., None])  # exact softmax: exp(s-m)/l
-        if causal:
-            k_pos = j * bk + jnp.arange(bk)
-            p = jnp.where(k_pos[None, :] > q_pos[:, None], 0.0, p)
-            if window is not None:
-                p = jnp.where(
-                    k_pos[None, :] < q_pos[:, None] - (window - 1),
-                    0.0, p,
-                )
-        dp = jnp.einsum("zqd,zkd->zqk", dof, vb)
-        ds = p * (dp - delta[..., None])
-        dq = dq + jnp.einsum("zqk,zkd->zqd", ds, kb) * scale
-        dk_j = jnp.einsum("zqk,zqd->zkd", ds, qf) * scale
-        dv_j = jnp.einsum("zqk,zqd->zkd", p, dof)
-        return dq, (dk_j, dv_j)
-
-    dq, (dks, dvs) = lax.scan(
-        body, jnp.zeros_like(qf), jnp.arange(nk)
-    )
-    # stacked [nk, Z, bk, D] -> [Z, S, D], D the keys' or the values'
-    unfold = lambda t: t.transpose(1, 0, 2, 3).reshape(z, s, t.shape[-1])
-    return (
-        dq.astype(q.dtype),
-        unfold(dks).astype(k.dtype),
-        unfold(dvs).astype(v.dtype),
-    )

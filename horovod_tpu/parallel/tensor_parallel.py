@@ -248,11 +248,12 @@ def _tp_block(cfg, p, rep, x, positions, rope_tabs, tp_axis, tp,
     proj/fc2 closures — each row-parallel matmul rejoined by one psum,
     its bias applied once after (the bias lives on the replicated
     tree).  ``attend`` overrides the attention schedule exactly as in
-    ``block_math`` — the width-sharded paged decode path
+    ``attention_mixer`` — the width-sharded paged decode path
     (models/decode.py) supplies one that appends to its per-shard KV
     pages and attends its own heads."""
     from ..models.transformer import (  # noqa: PLC0415
-        block_math, raw_dense, raw_layer_norm, require_gpt2_block,
+        attention_mixer, block_math, raw_dense, raw_layer_norm,
+        require_gpt2_block,
     )
 
     require_gpt2_block(cfg, "parallel.tensor_parallel")
@@ -271,17 +272,17 @@ def _tp_block(cfg, p, rep, x, positions, rope_tabs, tp_axis, tp,
         )
 
     return block_math(
-        cfg, x, positions, rope_tabs,
+        cfg, x,
         ln1=lambda h: raw_layer_norm(h, rep["ln1"]["scale"],
                                      rep["ln1"]["bias"]),
-        qkv=raw_dense(p["qkv"], dt),
-        proj=row(p["proj"]["kernel"], rep["proj_bias"]),
+        mixer=lambda h: attention_mixer(
+            cfg, h, positions, rope_tabs, qkv=raw_dense(p["qkv"], dt),
+            proj=row(p["proj"]["kernel"], rep["proj_bias"]),
+            num_heads=cfg.num_heads // tp,
+            num_kv_heads=cfg.kv_heads // tp, attend=attend),
         ln2=lambda h: raw_layer_norm(h, rep["ln2"]["scale"],
                                      rep["ln2"]["bias"]),
         mlp=mlp,
-        num_heads=cfg.num_heads // tp,
-        num_kv_heads=cfg.kv_heads // tp,
-        attend=attend,
     )
 
 

@@ -9,9 +9,8 @@ as JSON: window and busy seconds, device time by scope (``attn``,
 kernel (``flash_fwd``; ``flash_bwd_dkdv``, the one backward kernel, which
 covers dq, dk and dv; ``flash_bwd_dq`` only where neither a kv row's dk
 and dv nor its dq fit the VMEM a one-kernel call may state and the
-backward ran as two passes: ``ops/flash_attention.py:backward_form``),
-idle time by
-span.  Needs the chip: on the CPU backend nothing is recorded.
+backward ran as two passes: ``ops/flash_attention.py:flash_plan``),
+idle time by span.  Needs the chip: on the CPU backend nothing is recorded.
 """
 
 from __future__ import annotations

@@ -15,6 +15,8 @@ import pytest
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from flash_oracle import (flash_bwd_blockwise, folded_plan, force_form,
+                          plan_of, traced_calls)
 from horovod_tpu.models import TransformerConfig, gpt
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel import local_attention
@@ -195,7 +197,7 @@ class TestPallasBackward:
     ])
     def test_pallas_bwd_matches_scan_bwd(self, causal, window):
         from horovod_tpu.ops.flash_attention import (
-            _flash_bwd_blockwise, _flash_bwd_pallas, _flash_fwd_kernel,
+            _flash_bwd_pallas, _flash_fwd_kernel,
         )
 
         rng = np.random.RandomState(0)
@@ -204,12 +206,11 @@ class TestPallasBackward:
             jnp.asarray(rng.randn(z, s, d), jnp.float32) for _ in range(4)
         )
         scale = d ** -0.5
-        o, lse = _flash_fwd_kernel(q, k, v, causal, scale, bq, bk, 1, 1,
-                                   window, True)
-        ref = _flash_bwd_blockwise(q, k, v, o, lse, do, causal, scale, bk,
-                                   window=window)
-        got = _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, bq, bk,
-                                1, 1, window, True)
+        plan = folded_plan(q, k, v, causal, bq, bk, window=window)
+        o, lse = _flash_fwd_kernel(q, k, v, plan, scale, True)
+        ref = flash_bwd_blockwise(q, k, v, o, lse, do, causal, scale, bk,
+                                  window=window)
+        got = _flash_bwd_pallas(q, k, v, o, lse, do, plan, scale, True)
         for name, a, b in zip(("dq", "dk", "dv"), got, ref):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5,
@@ -219,7 +220,7 @@ class TestPallasBackward:
 
     def test_pallas_bwd_uneven_blocks(self):
         from horovod_tpu.ops.flash_attention import (
-            _flash_bwd_blockwise, _flash_bwd_pallas, _flash_fwd_kernel,
+            _flash_bwd_pallas, _flash_fwd_kernel,
         )
 
         rng = np.random.RandomState(1)
@@ -228,11 +229,10 @@ class TestPallasBackward:
             jnp.asarray(rng.randn(z, s, d), jnp.float32) for _ in range(4)
         )
         scale = d ** -0.5
-        o, lse = _flash_fwd_kernel(q, k, v, True, scale, bq, bk, 1, 1,
-                                   None, True)
-        ref = _flash_bwd_blockwise(q, k, v, o, lse, do, True, scale, bk)
-        got = _flash_bwd_pallas(q, k, v, o, lse, do, True, scale, bq, bk,
-                                1, 1, None, True)
+        plan = folded_plan(q, k, v, True, bq, bk)
+        o, lse = _flash_fwd_kernel(q, k, v, plan, scale, True)
+        ref = flash_bwd_blockwise(q, k, v, o, lse, do, True, scale, bk)
+        got = _flash_bwd_pallas(q, k, v, o, lse, do, plan, scale, True)
         for a, b in zip(got, ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-5, rtol=1e-5)
@@ -543,8 +543,10 @@ def test_row_statistics_across_tiles(causal, window, h, hkv, bq, bk, dtype):
     want_g = jax.grad(loss(reference), argnums=(0, 1, 2))(qf, kf, vf)
 
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, s, d)
-    _, lse = _flash_fwd_kernel(fold(q), fold(k), fold(v), causal, scale,
-                               bq, bk, h, hkv, window, True)
+    folded = fold(q), fold(k), fold(v)
+    _, lse = _flash_fwd_kernel(
+        *folded, folded_plan(*folded, causal, bq, bk, h, hkv, window),
+        scale, True)
     want_lse = jax.nn.logsumexp(scores(qf, kf), axis=-1).reshape(-1, s)
     # the running max moved in every K tile of the last row
     last_row = np.asarray(scores(qf, kf))[0, 0, -1]
@@ -574,19 +576,17 @@ def test_row_statistics_across_tiles(causal, window, h, hkv, bq, bk, dtype):
 
 def _grouped_blockwise(q, k, v, o, lse, do, causal, scale, bk, window, h,
                        hkv):
-    """`_flash_bwd_blockwise` knows no grouped heads: give every query
+    """`flash_bwd_blockwise` knows no grouped heads: give every query
     head its own copy of its kv row and fold dk and dv back (each at its
     own width: the values' need not be the keys')."""
-    from horovod_tpu.ops.flash_attention import _flash_bwd_blockwise
-
     z, s, _ = q.shape
     b, group = z // h, h // hkv
     f32 = jnp.float32
     rep = lambda t: jnp.repeat(
         t.astype(f32).reshape(b, hkv, 1, s, t.shape[-1]), group, 2
     ).reshape(z, s, t.shape[-1])
-    dq, dk, dv = _flash_bwd_blockwise(q.astype(f32), rep(k), rep(v), o, lse,
-                                      do, causal, scale, bk, window=window)
+    dq, dk, dv = flash_bwd_blockwise(q.astype(f32), rep(k), rep(v), o, lse,
+                                     do, causal, scale, bk, window=window)
     fold = lambda t: t.reshape(b, hkv, group, s, t.shape[-1]).sum(2).reshape(
         -1, s, t.shape[-1])
     return dq, fold(dk), fold(dv)
@@ -624,6 +624,8 @@ def test_one_kernel_backward_matches_two_passes_and_oracle(
     add the same float32 terms in the same order (a dk row block gets
     its terms by query head, then Q tile, a dq block by K tile, in all
     three), so they agree to the bit; the scan sums in another order."""
+    from dataclasses import replace
+
     from horovod_tpu.ops import flash_attention as fa
 
     b, d = 2, 16
@@ -631,23 +633,22 @@ def test_one_kernel_backward_matches_two_passes_and_oracle(
     mk = lambda heads: jnp.asarray(rng.randn(b * heads, s, d) * 0.7, dtype)
     q, do, k, v = mk(h), mk(h), mk(hkv), mk(hkv)
     scale = d ** -0.5 if scale is None else scale
-    o, lse = fa._flash_fwd_kernel(q, k, v, causal, scale, bq, bk, h, hkv,
-                                  window, True)
-    args = (q, k, v, o, lse, do, causal, scale, bq, bk, h, hkv, window, True)
+    plan = folded_plan(q, k, v, causal, bq, bk, h, hkv, window)
+    o, lse = fa._flash_fwd_kernel(q, k, v, plan, scale, True)
 
-    def kernels():
-        return list(_pallas_calls(
-            jax.make_jaxpr(lambda: fa._flash_bwd_pallas(*args))().jaxpr))
+    def backward(plan):
+        run = lambda: fa._flash_bwd_pallas(q, k, v, o, lse, do, plan, scale,
+                                           True)
+        return run(), list(_pallas_calls(jax.make_jaxpr(run)().jaxpr))
 
-    assert fa.backward_form(s, d, h // hkv, q.dtype.itemsize, bq,
-                            bk) == "dkdv_resident"
-    with monkeypatch.context() as forced:
-        _force_form(forced, form)
-        one = fa._flash_bwd_pallas(*args)
-        assert kernels() == ["flash_bwd_dkdv"]
+    assert plan.bwd_form == "dkdv_resident"
+    one, names = backward(replace(plan, bwd_form=form))
+    assert names == ["flash_bwd_dkdv"]
     _vmem_limits(monkeypatch, 0)
-    two = fa._flash_bwd_pallas(*args)
-    assert kernels() == ["flash_bwd_dkdv", "flash_bwd_dq"]
+    plan = folded_plan(q, k, v, causal, bq, bk, h, hkv, window)
+    assert (plan.bwd_form, plan.bwd_vmem_bytes) == ("two_passes", 0)
+    two, names = backward(plan)
+    assert names == ["flash_bwd_dkdv", "flash_bwd_dq"]
     ref = _grouped_blockwise(q, k, v, o, lse, do, causal, scale, bk, window,
                              h, hkv)
     tol = 2e-6 if dtype == jnp.float32 else 1e-2
@@ -673,15 +674,6 @@ def _vmem_limits(monkeypatch, limit, ceiling=None):
     monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", limit)
     monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_CEILING",
                         limit if ceiling is None else ceiling)
-
-
-def _force_form(monkeypatch, form):
-    """The one-kernel backward in the named form whatever the shape
-    says, stating the limit a small shape states."""
-    from horovod_tpu.ops import flash_attention as fa
-
-    monkeypatch.setattr(fa, "backward_plan",
-                        lambda *a: (form, fa._FUSED_BWD_VMEM_LIMIT))
 
 
 def _pallas_calls(jaxpr, what=lambda params: params["name"]):
@@ -756,9 +748,8 @@ def test_backward_path_follows_the_shape(shape, kv_heads, dtype, scale, form):
     differentiated jaxpr, as a device trace would read it: one kernel
     (under the name ``flash_bwd_dkdv``) at every benchmark shape and up
     to the ceiling of what a call may state, ``flash_bwd_dq`` beside it
-    only above; and ``backward_form``, which the gauges read, says the
-    same."""
-    from horovod_tpu.ops.flash_attention import backward_form
+    only above; and the plan of the same call says the same."""
+    from horovod_tpu.ops.flash_attention import flash_plan
 
     b, s, h, d = shape
     q = jax.ShapeDtypeStruct(shape, dtype)
@@ -768,16 +759,16 @@ def test_backward_path_follows_the_shape(shape, kv_heads, dtype, scale, form):
         return flash_attention(q, k, v, causal=True, scale=scale,
                                interpret=True).astype(jnp.float32).sum()
 
-    assert backward_form(s, d, h // kv_heads,
-                         jnp.dtype(dtype).itemsize) == form
+    assert flash_plan(q, kv, kv, causal=True).bwd_form == form
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv)
     assert list(_pallas_calls(jaxpr.jaxpr)) == ["flash_fwd"] + (
         _TWO_PASSES if form == "two_passes" else _ONE_KERNEL)
 
 
-# (id, the limit and the ceiling the gate reads, form, gauges
-# flash.bwd_kernels, flash.bwd_dq_resident and flash.bwd_vmem_mib, the
-# backward's names and grids in a layer) at 64 keys of 16 channels in
+# (id, the limit and the ceiling the gate reads, form, gauge
+# flash.bwd_kernels, whether the plan keeps dq resident and the MiB it
+# states, the backward's names and grids in a layer) at 64 keys of 16
+# channels in
 # float32 and 32 x 16 tiles, where dk and dv resident count 334 KiB and dq
 # resident 204: the limit as it stands; one that only dq fits; none, with
 # the ceiling as it stands (the smaller count, stated itself: a whole
@@ -801,12 +792,13 @@ _GAUGE_CASES = [
 def test_the_gauges_say_which_backward_the_step_holds(
         monkeypatch, limit, ceiling, form, kernels, dq_resident, vmem_mib,
         backward):
-    """``flash.bwd_kernels``, ``flash.bwd_dq_resident`` and
-    ``flash.bwd_vmem_mib``, set while a two-layer model is traced,
-    against the ``pallas_call`` names, grids and stated VMEM of its
-    differentiated jaxpr, at a shape on each side of the gates (the
-    limit patched to what a form holds, or to nothing): gauge and kernel
-    read one function, ``backward_plan``."""
+    """``flash.bwd_kernels``, set while a two-layer model is traced, and
+    the plan of the traced calls (its form, the VMEM it states, the
+    value width it was made for) against the ``pallas_call`` names, grids
+    and stated VMEM of the model's differentiated jaxpr, at a shape on
+    each side of the gates (the limit patched to what a form holds, or
+    to nothing): gauge and kernel read one record, the call's
+    ``FlashPlan``."""
     from horovod_tpu.obs.registry import get_registry
     from horovod_tpu.ops import flash_attention as fa
 
@@ -814,35 +806,39 @@ def test_the_gauges_say_which_backward_the_step_holds(
         limit = fa._dq_resident_bwd_vmem_bytes(64, 16, 32, 16, 4, 1)
     if limit is not None:
         _vmem_limits(monkeypatch, limit, ceiling)
-    assert fa.backward_form(64, 16, 1, 4, 32, 16) == form
+    assert plan_of(64, 16, 1, 4, 32, 16).bwd_form == form
     model = gpt("nano", num_layers=2, num_heads=4, emb_dim=64,
                 vocab_size=512, max_len=64, dtype=jnp.float32,
                 flash_block_q=32, flash_block_k=16)
     toks = jnp.asarray(
         np.random.RandomState(3).randint(0, 512, (2, 64)), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), toks)
-    registry = get_registry()
-    gauges = ("flash.bwd_kernels", "flash.bwd_dq_resident",
-              "flash.bwd_vmem_mib")
-    for name in gauges:
-        registry.gauge(name, layer_type="attention").set(-1)
+    gauge = get_registry().gauge("flash.bwd_kernels", layer_type="attention")
+    gauge.set(-1)
+    qkv = jax.ShapeDtypeStruct((2, 64, 4, 16), jnp.float32)
+    of_the_shape = fa.flash_plan(qkv, qkv, qkv, causal=True, block_q=32,
+                                 block_k=16)
+    traced = traced_calls(monkeypatch)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p: model.apply(p, toks).sum()))(params)
     calls = list(_pallas_calls(
         jaxpr.jaxpr, lambda p: (p["name"], tuple(p["grid_mapping"].grid))))
     assert calls == [("flash_fwd", (8, 2, 4))] * 2 + backward * 2
-    assert [registry.gauge(name, layer_type="attention").value
-            for name in gauges] == [kernels, dq_resident, vmem_mib]
-    # the gauge is what the one kernel states, the two passes nothing
+    # one plan, made twice a layer: for the gauges and for the kernels
+    (shapes, plan), = set(traced)
+    assert len(traced) == 4
+    assert [gauge.value, plan.bwd_kernels, plan.bwd_form == "dq_resident",
+            -(-plan.bwd_vmem_bytes // 2 ** 20)] == [
+                kernels, kernels, bool(dq_resident), vmem_mib]
+    # the plan holds what the one kernel states, the two passes nothing
     stated = [limit for name, limit in _pallas_calls(
         jaxpr.jaxpr, lambda p: (p["name"], _stated_vmem(p)))
         if name != "flash_fwd"]
     assert stated == ([None] * 4 if form == "two_passes" else
-                      [fa.backward_plan(64, 16, 1, 4, 32, 16)[1]] * 2)
+                      [plan.bwd_vmem_bytes] * 2)
     assert all(-(-b // 2 ** 20) == vmem_mib for b in stated if b)
-    # values as wide as keys: the head size, 64 / 4
-    assert registry.gauge("flash.value_dim",
-                          layer_type="attention").value == 16
+    # values as wide as keys, the head size, 64 / 4
+    assert shapes == ((2, 64, 4, 16),) * 3 and plan == of_the_shape
     assert sum(name != "flash_fwd" for name, _ in calls) == 2 * kernels
 
 
@@ -853,7 +849,7 @@ def _force(monkeypatch, backward):
     if backward == "two_passes":
         _vmem_limits(monkeypatch, 0)
     elif backward == "dq_resident":
-        _force_form(monkeypatch, backward)
+        force_form(monkeypatch, backward)
     return _TWO_PASSES if backward == "two_passes" else _ONE_KERNEL
 
 
@@ -990,8 +986,10 @@ def test_values_of_another_width_than_the_keys(monkeypatch, dv, causal,
     assert list(_pallas_calls(jax.make_jaxpr(grad)(q, k, v).jaxpr)) \
         == ["flash_fwd"] + names
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, s, x.shape[3])
-    o, lse = fa._flash_fwd_kernel(fold(q), fold(k), fold(v), causal,
-                                  d ** -0.5, bq, bk, h, hkv, window, True)
+    folded = fold(q), fold(k), fold(v)
+    o, lse = fa._flash_fwd_kernel(
+        *folded, folded_plan(*folded, causal, bq, bk, h, hkv, window),
+        d ** -0.5, True)
     want = _grouped_blockwise(fold(q), fold(k), fold(v), o, lse, fold(do),
                               causal, d ** -0.5, bk, window, h, hkv)
     for name, a, r in zip(("dq", "dk", "dv"), grad(q, k, v), want):
@@ -1042,22 +1040,22 @@ def test_vmem_counts_at_the_cells_shapes(seq, d, dv, group, form, q_outer,
     assert fa._fused_bwd_vmem_bytes(seq, d, 512, 256, 2, dv) == q_outer
     assert fa._dq_resident_bwd_vmem_bytes(seq, d, 512, 256, 2, group,
                                           dv) == k_outer
-    assert fa.backward_form(seq, d, group, 2, value_dim=dv) == form
+    assert plan_of(seq, d, group, 2, value_dim=dv).bwd_form == form
     if dv is None:
         # a value width that is the head size changes nothing
         assert fa._fused_bwd_vmem_bytes(seq, d, 512, 256, 2, d) == q_outer
         assert fa._dq_resident_bwd_vmem_bytes(seq, d, 512, 256, 2, group,
                                               d) == k_outer
-        assert fa.backward_form(seq, d, group, 2) == form
-        assert fa.backward_form(seq, d, group, 2, 512, 256, d) == form
+        assert plan_of(seq, d, group, 2) == plan_of(
+            seq, d, group, 2, 512, 256, d)
     else:
         # the dk and dv halves each at their own padded lanes: values of
         # 512 put 8192 keys over the Q-outermost form's limit, and dq,
         # 64 wide, stays resident under the K tiles
         assert fa._fused_bwd_vmem_bytes(seq, d, 512, 256, 2, 512) \
             > fa._FUSED_BWD_VMEM_LIMIT > q_outer
-        assert fa.backward_form(seq, d, group, 2,
-                                value_dim=512) == "dq_resident"
+        assert plan_of(seq, d, group, 2,
+                       value_dim=512).bwd_form == "dq_resident"
 
 
 def _stated_vmem(eqn_params):
@@ -1157,8 +1155,8 @@ def test_every_cells_call_keeps_its_form_and_the_vmem_it_states(
     k = jax.ShapeDtypeStruct((b, s, kv_heads, d), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((b, s, kv_heads, dv), jnp.bfloat16)
     group, nq, nk = h // kv_heads, s // 512, s // 256
-    assert fa.backward_plan(s, d, group, 2, value_dim=dv) \
-        == (form, mib * 2 ** 20)
+    plan = fa.flash_plan(q, k, v, causal=True, window=window)
+    assert (plan.bwd_form, plan.bwd_vmem_bytes) == (form, mib * 2 ** 20)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda *a: flash_attention(
             *a, causal=True, window=window, interpret=True
@@ -1168,7 +1166,7 @@ def test_every_cells_call_keeps_its_form_and_the_vmem_it_states(
     grid = ((b * h, nq, nk) if form == "dkdv_resident"
             else (b * kv_heads, nk, nq * group))
     assert calls == [("flash_fwd", (b * h, nq, nk),
-                      fa.forward_plan(s, d, dv, 2)[1] or None),
+                      plan.fwd_vmem_bytes or None),
                      ("flash_bwd_dkdv", grid, mib * 2 ** 20)]
 
 
@@ -1219,20 +1217,18 @@ def test_resident_forward_equals_streamed_to_the_bit(
     mk = lambda heads, width: jnp.asarray(
         rng.randn(b * heads, s, width) * 0.7, dtype)
     q, k, v = mk(h, d), mk(hkv, d), mk(hkv, dv)
-    args = (q, k, v, causal, d ** -0.5, bq, bk, h, hkv, window, True)
 
-    def forward():
-        call, = _pallas_calls(
-            jax.make_jaxpr(lambda: fa._flash_fwd_kernel(*args))().jaxpr,
-            _forward_call)
-        return call, fa._flash_fwd_kernel(*args)
+    def forward(resident):
+        plan = folded_plan(q, k, v, causal, bq, bk, h, hkv, window)
+        assert (plan.fwd_kv_resident, plan.fwd_vmem_bytes) == (resident, 0)
+        run = lambda: fa._flash_fwd_kernel(q, k, v, plan, d ** -0.5, True)
+        call, = _pallas_calls(jax.make_jaxpr(run)().jaxpr, _forward_call)
+        return call, run()
 
-    assert fa.forward_plan(s, d, dv, q.dtype.itemsize, bq, bk) == (True, 0)
-    call, resident = forward()
+    call, resident = forward(True)
     assert call == (s, None)
     _vmem_limits(monkeypatch, 0)
-    assert fa.forward_plan(s, d, dv, q.dtype.itemsize, bq, bk) == (False, 0)
-    call, streamed = forward()
+    call, streamed = forward(False)
     assert call == (bk, None)
     for part, a, t in zip(("o", "lse"), resident, streamed):
         assert a.dtype == t.dtype and a.shape == t.shape
@@ -1294,9 +1290,10 @@ def test_forward_plan_at_the_cells_shapes(seq, d, dv, itemsize, resident,
 
     assert fa._fwd_resident_vmem_bytes(seq, d, dv, 512, 256,
                                        itemsize) == count
-    assert fa.forward_plan(seq, d, dv, itemsize) == (resident, mib * 2 ** 20)
-    assert fa.forward_plan(seq, d, dv, itemsize, 512, 256) == (
+    plan = plan_of(seq, d, 1, itemsize, value_dim=dv)
+    assert (plan.fwd_kv_resident, plan.fwd_vmem_bytes) == (
         resident, mib * 2 ** 20)
+    assert plan == plan_of(seq, d, 1, itemsize, 512, 256, dv)
     # the rule: resident wherever the count fits the limit the backward's
     # forms share, a stated MiB only past the default scoped limit
     assert resident == (count <= fa._FUSED_BWD_VMEM_LIMIT)
@@ -1307,8 +1304,9 @@ def test_forward_plan_at_the_cells_shapes(seq, d, dv, itemsize, resident,
 
 
 # (id, the limit and the default scoped limit the gate reads, the
-# forward's plan, gauges flash.fwd_kv_resident and flash.fwd_vmem_mib, the
-# rows of the K and V blocks) at 64 keys of 16 channels in float32 and
+# forward's plan at the shape, the same of the traced calls' plan as
+# resident 1 / 0 and whole MiB, the rows of the K and V blocks) at 64
+# keys of 16 channels in float32 and
 # 32 x 16 tiles, where the resident forward counts 264 KiB: the limits as
 # they stand; a default the count passes (the count stated, a whole MiB);
 # no room
@@ -1324,35 +1322,35 @@ _FWD_GAUGE_CASES = [
                          ids=[c[0] for c in _FWD_GAUGE_CASES])
 def test_the_gauges_say_which_forward_the_step_holds(
         monkeypatch, limit, default, plan, resident, vmem_mib, rows):
-    """``flash.fwd_kv_resident`` and ``flash.fwd_vmem_mib``, set while a
-    two-layer model is traced, against the K and V blocks and the stated
-    VMEM of the ``flash_fwd`` calls in its jaxpr, on each side of
-    ``forward_plan``'s gates: gauge and kernel read one function."""
-    from horovod_tpu.obs.registry import get_registry
+    """The plan of the calls a two-layer model traces (whether the
+    forward holds a kv row resident, the VMEM it states) against the K
+    and V blocks and the stated VMEM of the ``flash_fwd`` calls in the
+    model's jaxpr, on each side of the forward's gates: whoever asks and
+    the kernel read one record, the call's ``FlashPlan``."""
     from horovod_tpu.ops import flash_attention as fa
 
     if default is not None:
         monkeypatch.setattr(fa, "_DEFAULT_SCOPED_VMEM", default)
     if limit is not None:
         _vmem_limits(monkeypatch, limit)
-    assert fa.forward_plan(64, 16, 16, 4, 32, 16) == plan
+    at_the_shape = plan_of(64, 16, 1, 4, 32, 16)
+    assert (at_the_shape.fwd_kv_resident, at_the_shape.fwd_vmem_bytes) == plan
     model = gpt("nano", num_layers=2, num_heads=4, emb_dim=64,
                 vocab_size=512, max_len=64, dtype=jnp.float32,
                 flash_block_q=32, flash_block_k=16)
     toks = jnp.asarray(
         np.random.RandomState(3).randint(0, 512, (2, 64)), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), toks)
-    registry = get_registry()
-    gauges = ("flash.fwd_kv_resident", "flash.fwd_vmem_mib")
-    for name in gauges:
-        registry.gauge(name, layer_type="attention").set(-1)
+    asked = traced_calls(monkeypatch)
     jaxpr = jax.make_jaxpr(lambda p: model.apply(p, toks))(params)
     calls = list(_pallas_calls(jaxpr.jaxpr, lambda p: (
         p["name"], tuple(p["grid_mapping"].grid)) + _forward_call(p)))
     # the grid is whole in either form
     assert calls == [("flash_fwd", (8, 2, 4), rows, plan[1] or None)] * 2
-    assert [registry.gauge(name, layer_type="attention").value
-            for name in gauges] == [resident, vmem_mib]
+    (_, traced), = set(asked)
+    assert len(asked) == 4
+    assert [int(traced.fwd_kv_resident),
+            traced.fwd_vmem_bytes // 2 ** 20] == [resident, vmem_mib]
 
 
 def test_local_attention_refuses_a_window_it_cannot_mean():

@@ -151,17 +151,19 @@ def test_a_block_makes_the_new_modules_only_where_asked():
     assert tree["block4"]["in_proj"]["kernel"].shape == (64, 128)
 
 
-def test_the_new_layers_carry_their_scopes_and_gauges():
+def test_the_new_layers_carry_their_scopes_and_gauges(monkeypatch):
     """A step traced through the kernels names the scan
     ``ssm/selective_scan``, a memory unit ``gmu``, a cross layer's
     attention call ``attn/attn_cross`` and differential attention's own
     arithmetic ``attn/attn_diff`` (the window layer's call keeps
     ``attn_window``), and leaves the readers of each handed-on value and
     what a scan keeps."""
+    from flash_oracle import traced_calls
     from horovod_tpu.obs.registry import get_registry
 
     model = small_model(attention_impl="flash")
     variables = init(model)
+    calls = traced_calls(monkeypatch)
     text = jax.jit(lambda v, t: program_loss(model, v, t)).lower(
         variables, TOKENS).as_text(debug_info=True)
     for scope in ("block0/ssm/selective_scan/", "block1/attn/attn_window/",
@@ -182,7 +184,8 @@ def test_the_new_layers_carry_their_scopes_and_gauges():
     for kind in ("sliding_attention", "full_attention", "cross_attention"):
         assert registry.gauge("flash.tiles_grid", layer_type=kind).value \
             == rows * (SEQ // 16) * (SEQ // 4)
-        assert registry.gauge("flash.value_dim", layer_type=kind).value == 16
+    # every differential call's values, 2 x 8 wide on keys of 8
+    assert {(q[3], k[3], v[3]) for (q, k, v), _ in calls} == {(8, 8, 16)}
     assert registry.gauge("flash.tiles_live",
                           layer_type="cross_attention").value == rows * 12
     assert registry.gauge("flash.tiles_live",
@@ -198,15 +201,18 @@ def _equations(jaxpr):
 
 @pytest.mark.parametrize("kind", ["sliding_attention", "full_attention",
                                   "cross_attention"])
-def test_a_differential_layer_is_one_flash_call_at_the_pairs_shape(kind):
+def test_a_differential_layer_is_one_flash_call_at_the_pairs_shape(
+        monkeypatch, kind):
     """At the published widths and the benchmark cell's 8192 tokens (only
     traced): 40 sub-heads of 64 over 20, so ONE ``flash_fwd`` over 40
     rows, its keys 64 wide and its values and output 128, and nothing
     stacks four groups of heads before it (the parent's call ran each
     score map twice, over 80 rows on 40)."""
+    from flash_oracle import traced_calls
     from horovod_tpu.models.transformer import _attend_differential
 
     cfg = GPT_CONFIGS[NAME]
+    asked = traced_calls(monkeypatch)
     assert cfg.attention_impl == "flash"
     shaped = lambda heads: jax.ShapeDtypeStruct((1, 8192, heads, 64),
                                                 jnp.bfloat16)
@@ -237,9 +243,13 @@ def test_a_differential_layer_is_one_flash_call_at_the_pairs_shape(kind):
     assert gauge("flash.tiles_grid") == 20480
     assert gauge("flash.tiles_live") == (
         2480 if kind == "sliding_attention" else 10880)
-    assert gauge("flash.value_dim") == 128
-    assert gauge("flash.fwd_kv_resident") == 1
-    assert gauge("flash.fwd_vmem_mib") == 0
+    # and the plan of that call: values of 128 on keys of 64, the kv row
+    # resident, nothing stated
+    ((q, k, v), plan), = set(asked)
+    assert (q[3], k[3], v[3]) == (64, 64, 128)
+    assert (plan.fwd_kv_resident, plan.fwd_vmem_bytes) == (True, 0)
+    assert (plan.tiles_live, plan.tiles_grid) == (
+        gauge("flash.tiles_live"), gauge("flash.tiles_grid"))
 
 
 # ------------------------------------------------------------- refusals

@@ -20,6 +20,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
+from flash_oracle import plan_of
 from horovod_tpu.models.resnet import ResNet18, ResNet50
 from horovod_tpu.models.transformer import gpt
 from horovod_tpu.obs import profile
@@ -231,7 +232,7 @@ def test_named_flash_kernels_are_bitwise_the_unnamed_ones(
     # no room above the limit: the shape is on the path the limit says
     monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_CEILING", limit)
     jax.clear_caches()
-    assert fa.backward_form(64, 16, 1, 4, 16, 16) == form
+    assert plan_of(64, 16, 1, 4, 16, 16).bwd_form == form
     rng = np.random.RandomState(3)
     q, k, v = (jnp.asarray(rng.randn(2, 64, 4, 16), jnp.float32) * 0.3
                for _ in range(3))
@@ -395,11 +396,15 @@ def test_every_named_scope_of_the_package_is_a_constant_of_scopes():
             with open(os.path.join(folder, name)) as f:
                 text = f.read()
             for arg in re.findall(r"named_scope\(([^)]*)\)", text):
-                if arg in ("scope", ""):       # optim._scoped's parameter,
-                    continue                   # prose in a docstring
+                if arg in ("scope", ""):       # optim._scoped's parameter
+                    continue                   # and block_math's; prose
                 assert arg.startswith("scopes."), (name, arg)
                 used.add(getattr(scopes, arg[len("scopes."):]))
     used.add(scopes.OPTIMIZER_UPDATE)          # passed to optim._scoped
+    # block_math's ``scope``: the mixer's, by layer type, else ``attn``
+    from horovod_tpu.models.transformer import MIXER_SCOPES
+
+    used |= set(MIXER_SCOPES.values()) | {scopes.ATTN}
     assert used == constants
 
 

@@ -511,23 +511,26 @@ def test_the_backward_at_16384_keys_is_one_kernel():
     either form has to fit to be taken in its turn, so the smaller count
     decides and the call states it, a whole MiB (PR 44; the two passes
     before it).  Half the length fits the 32 MiB and states them."""
+    from flash_oracle import plan_of
     from horovod_tpu.ops.flash_attention import (
-        _dq_resident_bwd_vmem_bytes, _fused_bwd_vmem_bytes, backward_form,
-        backward_plan, tile_counts,
+        _dq_resident_bwd_vmem_bytes, _fused_bwd_vmem_bytes,
     )
 
     assert _fused_bwd_vmem_bytes(16384, 128, 512, 256, 2) == 36.25 * 2 ** 20
     assert _dq_resident_bwd_vmem_bytes(16384, 128, 512, 256, 2,
                                        7) == 60.5 * 2 ** 20
-    assert backward_form(16384, 128, 7, 2) == "dkdv_resident"
-    assert backward_plan(16384, 128, 7, 2) == ("dkdv_resident", 37 * 2 ** 20)
-    assert backward_form(8192, 128, 7, 2) == "dkdv_resident"
-    assert backward_plan(8192, 128, 7, 2) == ("dkdv_resident", 32 * 2 ** 20)
+    backward = lambda plan: (plan.bwd_form, plan.bwd_vmem_bytes)
+    assert plan_of(16384, 128, 7, 2).bwd_form == "dkdv_resident"
+    assert backward(plan_of(16384, 128, 7, 2)) == (
+        "dkdv_resident", 37 * 2 ** 20)
+    assert plan_of(8192, 128, 7, 2).bwd_form == "dkdv_resident"
+    assert backward(plan_of(8192, 128, 7, 2)) == (
+        "dkdv_resident", 32 * 2 ** 20)
     # a head's grid of 32 x 64 tiles: the full layer's causal half and
     # the band of a 4096-key window
-    assert tile_counts(1, 16384, 512, 256, causal=True) == (1056, 2048)
-    assert tile_counts(1, 16384, 512, 256, causal=True,
-                       window=4096) == (504, 2048)
+    tiles = lambda plan: (plan.tiles_live, plan.tiles_grid)
+    assert tiles(plan_of(16384, 128)) == (1056, 2048)
+    assert tiles(plan_of(16384, 128, window=4096)) == (504, 2048)
 
 
 @pytest.mark.parametrize("window", [None, 20], ids=["full", "banded"])
@@ -538,6 +541,7 @@ def test_two_passes_agree_with_the_one_kernel_at_seven_to_a_kv_head(
     Q tile outermost, which the 16 384-key cell runs since PR 44, against
     the two passes it ran before, every bit, and both against the
     blockwise scan."""
+    from flash_oracle import folded_plan
     from test_flash_attention import (
         _grouped_blockwise, _pallas_calls, _vmem_limits,
     )
@@ -550,16 +554,19 @@ def test_two_passes_agree_with_the_one_kernel_at_seven_to_a_kv_head(
                                    jnp.float32)
     q, do, k, v = mk(h), mk(h), mk(hkv), mk(hkv)
     scale = d ** -0.5
-    o, lse = fa._flash_fwd_kernel(q, k, v, True, scale, bq, bk, h, hkv,
-                                  window, True)
-    args = (q, k, v, o, lse, do, True, scale, bq, bk, h, hkv, window, True)
-    kernels = lambda: list(_pallas_calls(
-        jax.make_jaxpr(lambda: fa._flash_bwd_pallas(*args))().jaxpr))
-    one = fa._flash_bwd_pallas(*args)
-    assert kernels() == ["flash_bwd_dkdv"]
+    plan = lambda: folded_plan(q, k, v, True, bq, bk, h, hkv, window)
+    o, lse = fa._flash_fwd_kernel(q, k, v, plan(), scale, True)
+
+    def backward(plan):
+        run = lambda: fa._flash_bwd_pallas(q, k, v, o, lse, do, plan, scale,
+                                           True)
+        return run(), list(_pallas_calls(jax.make_jaxpr(run)().jaxpr))
+
+    one, kernels = backward(plan())
+    assert kernels == ["flash_bwd_dkdv"]
     _vmem_limits(monkeypatch, 0)
-    two = fa._flash_bwd_pallas(*args)
-    assert kernels() == ["flash_bwd_dkdv", "flash_bwd_dq"]
+    two, kernels = backward(plan())
+    assert kernels == ["flash_bwd_dkdv", "flash_bwd_dq"]
     oracle = _grouped_blockwise(q, k, v, o, lse, do, True, scale, bk, window,
                                 h, hkv)
     for name, a, t, r in zip(("dq", "dk", "dv"), one, two, oracle):

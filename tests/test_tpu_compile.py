@@ -246,7 +246,7 @@ def test_flash_forward_form_compiles_for_v5e(one_chip, shape, kv_heads,
     ``flash_attention`` alone in a jit XLA may hand the call ``k`` and
     ``v`` in its own VMEM space, and the compile says nothing of the
     blocks): the ``pallas_call`` holds whole-kv-row K and V blocks
-    wherever ``forward_plan`` says resident, and the TPU compiler takes
+    wherever the call's plan says resident, and the TPU compiler takes
     them inside the scoped VMEM the call states, or inside its default
     where it states none (it refuses a kernel that needs more)."""
     import re
@@ -254,15 +254,17 @@ def test_flash_forward_form_compiles_for_v5e(one_chip, shape, kv_heads,
     from horovod_tpu.ops import flash_attention as fa
 
     b, s, h, d = shape
-    assert fa.forward_plan(s, d, d, jnp.dtype(dtype).itemsize) == (
+    unfolded = lambda heads: jax.ShapeDtypeStruct((b, s, heads, d), dtype)
+    plan = fa.flash_plan(unfolded(h), unfolded(kv_heads), unfolded(kv_heads),
+                         causal=True, window=window)
+    assert (plan.fwd_kv_resident, plan.fwd_vmem_bytes) == (
         rows == s, mib * 2 ** 20)
     q = jax.ShapeDtypeStruct((b * h, s, d), dtype, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((b * kv_heads, s, d), dtype,
                               sharding=one_chip)
 
     def forward(q, k, v):
-        return fa._flash_fwd_kernel(q, k, v, True, d ** -0.5, 512, 256, h,
-                                    kv_heads, window, False)
+        return fa._flash_fwd_kernel(q, k, v, plan, d ** -0.5, False)
 
     (call,) = [e.params for e in jax.make_jaxpr(forward)(q, kv, kv).eqns
                if e.primitive.name == "pallas_call"]

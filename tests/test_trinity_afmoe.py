@@ -362,7 +362,7 @@ def test_the_window_layers_carry_their_scopes_and_tile_counts():
     layer's call carries no window scope), and leaves, by layer type,
     the tiles its grid walks and those that do work."""
     from horovod_tpu.obs.registry import get_registry
-    from horovod_tpu.ops.flash_attention import tile_counts
+    from flash_oracle import plan_of
 
     model = small_model(attention_impl="flash")
     variables = init(model)
@@ -384,8 +384,8 @@ def test_the_window_layers_carry_their_scopes_and_tile_counts():
     # 9.. (tiles 2 and 3): 4 + 6 against 4 + 8
     assert live["full_attention"] == rows * 12
     assert live["sliding_attention"] == rows * 10
-    assert tile_counts(rows, SEQ, 16, 4, causal=True, window=8) == (
+    tiles = lambda plan: (plan.tiles_live, plan.tiles_grid)
+    assert tiles(plan_of(SEQ, 16, 1, 4, 16, 4, window=8, rows=rows)) == (
         rows * 10, grid)
-    assert tile_counts(1, 8192, 512, 256, causal=True) == (272, 512)
-    assert tile_counts(1, 8192, 512, 256, causal=True,
-                       window=2048) == (140, 512)
+    assert tiles(plan_of(8192, 128)) == (272, 512)
+    assert tiles(plan_of(8192, 128, window=2048)) == (140, 512)
