@@ -49,12 +49,14 @@ from ..parallel.moe import DEFAULT_GROUP_SIZE as MOE_DEFAULT_GROUP_SIZE
 # apart by their mask (and, where ``rope_layer_types`` says so, by their
 # positions); ``cross_attention`` makes queries only and reads the keys
 # and values of the layer ``shared_kv_layer`` names.  ``mamba`` is the
-# Mamba-2 mixer, ``selective_scan`` the Mamba-1 mixer, and ``gmu`` a
+# Mamba-2 mixer, ``selective_scan`` the Mamba-1 mixer, ``gmu`` a
 # gated memory unit: a gate on the scan output of the layer
-# ``memory_layer`` names.
+# ``memory_layer`` names, and ``conv`` a gated short convolution: a
+# causal depthwise filter of ``conv_taps`` taps between two gates.
 ATTENTION_LAYER_TYPES = ("attention", "mla", "sliding_attention",
                          "full_attention", "cross_attention")
-LAYER_TYPES = ATTENTION_LAYER_TYPES + ("mamba", "selective_scan", "gmu")
+LAYER_TYPES = ATTENTION_LAYER_TYPES + ("mamba", "selective_scan", "gmu",
+                                       "conv")
 
 
 @dataclass(frozen=True)
@@ -254,6 +256,12 @@ class TransformerConfig:
     # depthwise conv of ssm_conv taps, dt through a rank of ssm_dt_rank.
     ssm_width: int = 0
     ssm_dt_rank: int = 0
+    # The dense feed-forward's width where it is no whole multiple of
+    # emb_dim (None = mlp_ratio * emb_dim).
+    mlp_width: Optional[int] = None
+    # The gated short convolution (layer type "conv"): the taps of its
+    # causal depthwise filter, the current token's included.
+    conv_taps: int = 3
 
     def __post_init__(self):
         if self.num_kv_heads is not None:
@@ -369,6 +377,14 @@ class TransformerConfig:
             raise ValueError(
                 f"head_size={self.head_size} must be positive (None = "
                 f"emb_dim // num_heads)")
+        if self.mlp_width is not None and self.mlp_width <= 0:
+            raise ValueError(
+                f"mlp_width={self.mlp_width} must be positive (None = "
+                f"mlp_ratio * emb_dim)")
+        if self.conv_taps <= 0:
+            raise ValueError(
+                f"conv_taps={self.conv_taps}: a 'conv' layer's filter "
+                f"reads at least the current token")
         if self.rope_layer_types is not None:
             object.__setattr__(self, "rope_layer_types",
                                tuple(self.rope_layer_types))
@@ -408,6 +424,12 @@ class TransformerConfig:
         ``"kv"``, ``"memory"`` or nothing."""
         return ("kv" if i == self.shared_kv_layer
                 else "memory" if i == self.memory_layer else None)
+
+    @property
+    def ffn_width(self) -> int:
+        """The dense feed-forward's width."""
+        return (self.mlp_width if self.mlp_width is not None
+                else self.mlp_ratio * self.emb_dim)
 
     @property
     def ffn_bias(self) -> bool:
@@ -645,13 +667,15 @@ def act_store(y, cfg: TransformerConfig):
     return jnp.asarray(jnp.asarray(y, cfg.act_store_dtype), cfg.dtype)
 
 
-def causal_depthwise_conv(x, kernel, bias):
+def causal_depthwise_conv(x, kernel, bias=None):
     """``x`` [b, s, channels] through a depthwise conv of ``kernel``
-    [taps, channels] and ``bias``, float32: output ``t`` reads inputs
-    ``t-(taps-1) .. t``, zeros before the sequence."""
+    [taps, channels] and ``bias`` (``None``: the filter has none),
+    float32: output ``t`` reads inputs ``t-(taps-1) .. t``, zeros before
+    the sequence."""
     s, taps = x.shape[1], kernel.shape[0]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    return sum(padded[:, k:k + s] * kernel[k] for k in range(taps)) + bias
+    out = sum(padded[:, k:k + s] * kernel[k] for k in range(taps))
+    return out if bias is None else out + bias
 
 
 def mamba_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
@@ -720,6 +744,37 @@ def selective_scan_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
                        low[..., rank:rank + n], low[..., rank + n:], d_skip)
     gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
     return out_proj(gated.astype(fused.dtype)), y
+
+
+def short_conv_filter_bytes(batch: int, seq: int, channels: int,
+                            itemsize: int) -> int:
+    """What one gated short convolution's elementwise chain has to move
+    in a training step, from shapes: forward it reads ``B``, ``C`` and
+    ``u`` and writes ``z``; backward it reads those three and ``dz`` and
+    writes their three gradients.  Eleven arrays of ``[batch, seq,
+    channels]`` in the compute dtype; the filter's taps are a few KiB and
+    a recompute is not counted."""
+    return 11 * batch * seq * channels * itemsize
+
+
+def short_conv_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
+                     out_proj):
+    """The gated short convolution on the normed stream ``h`` [b, s,
+    emb]: one projection to ``[B ; C ; u]``, each emb wide; ``z = C *
+    conv(B * u)``, the conv a causal depthwise filter of
+    ``conv_kernel`` [taps, emb] without a bias; the output projection.
+    ``in_proj`` and ``out_proj`` are callables like ``block_math``'s
+    (matmuls in the compute dtype), the gates and the filter float32 as
+    :func:`causal_depthwise_conv` computes them, under the scope
+    ``short_conv_filter``.  Returns the residual delta."""
+    d = h.shape[-1]
+    fused = in_proj(h)
+    with jax.named_scope(scopes.SHORT_CONV_FILTER):
+        gate_b, gate_c, u = (fused[..., i * d:(i + 1) * d].astype(jnp.float32)
+                             for i in range(3))
+        z = gate_c * causal_depthwise_conv(gate_b * u, conv_kernel)
+        z = z.astype(fused.dtype)
+    return out_proj(z)
 
 
 def gmu_mixer(h, memory, *, in_proj, out_proj):
@@ -848,7 +903,7 @@ def attention_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *,
 # The scope a layer type's mixer traces under; any type not named here
 # is an attention layer and traces under ``attn``.
 MIXER_SCOPES = {"mamba": scopes.SSM, "selective_scan": scopes.SSM,
-                "gmu": scopes.GMU}
+                "gmu": scopes.GMU, "conv": scopes.SHORT_CONV}
 
 
 def block_math(cfg: TransformerConfig, x, *, ln1, mixer, ln2, mlp,
@@ -861,10 +916,11 @@ def block_math(cfg: TransformerConfig, x, *, ln1, mixer, ln2, mlp,
     residual added through ``cfg.residual_multiplier``.  ``mixer`` is a
     callable of the normed stream that returns the residual DELTA:
     :func:`attention_mixer`, :func:`mla_mixer`, :func:`mamba_mixer`,
-    :func:`selective_scan_mixer` or :func:`gmu_mixer` with the caller's
-    parameterized layer applications closed over; it traces under the
-    scope of its ``layer_type`` (``MIXER_SCOPES``: ``ssm``, ``gmu`` or
-    ``attn``).  Shared by the flax :class:`Block`, the raw-weights
+    :func:`selective_scan_mixer`, :func:`gmu_mixer` or
+    :func:`short_conv_mixer` with the caller's parameterized layer
+    applications closed over; it traces under the scope of its
+    ``layer_type`` (``MIXER_SCOPES``: ``ssm``, ``gmu``, ``short_conv``
+    or ``attn``).  Shared by the flax :class:`Block`, the raw-weights
     pipeline-parallel and decode block (:func:`raw_block_forward`), and
     the Megatron tensor-parallel block (``parallel/tensor_parallel.py``),
     so a change to the block (a norm variant, the residual's scale, a
@@ -986,8 +1042,9 @@ class Block(nn.Module):
 
     The wiring lives in :func:`block_math`; this module only declares
     the flax parameters (the attention mixer's, a state-space mixer's, a
-    gated memory unit's or latent attention's, by ``layer_type``; a
-    dense feed-forward's or the routed experts', by ``ffn``) and hands
+    gated memory unit's, a gated short convolution's or latent
+    attention's, by ``layer_type``; a dense feed-forward's or the routed
+    experts', by ``ffn``) and hands
     their applications in as callables: one ``mixer`` closure over the
     layer type's mixer function, the norms and ``mlp``.  ``hand_on`` says what the block
     returns beside ``x`` for later layers (``cfg.hands_on``);
@@ -1007,7 +1064,7 @@ class Block(nn.Module):
                  memory=None):
         cfg = self.cfg
         kv_dim = cfg.kv_heads * cfg.head_dim
-        width = cfg.mlp_ratio * cfg.emb_dim
+        width = cfg.ffn_width
 
         def dense(features, name, use_bias=cfg.use_bias):
             return nn.Dense(features, dtype=cfg.dtype, use_bias=use_bias,
@@ -1183,6 +1240,13 @@ class Block(nn.Module):
             mixer = lambda h: gmu_mixer(
                 h, memory, in_proj=unbiased(cfg.ssm_width, "in_proj"),
                 out_proj=unbiased(cfg.emb_dim, "out_proj"))
+        elif self.layer_type == "conv":
+            mixer = lambda h: short_conv_mixer(
+                cfg, h, in_proj=unbiased(3 * cfg.emb_dim, "in_proj"),
+                conv_kernel=self.param(
+                    "conv_kernel", _conv_init,
+                    (cfg.conv_taps, cfg.emb_dim), jnp.float32),
+                out_proj=unbiased(cfg.emb_dim, "out_proj"))
         elif self.layer_type == "mla":
             heads = cfg.num_heads
 
@@ -1352,6 +1416,15 @@ class GPT(nn.Module):
                                   ("shared.memory_readers", "gmu")):
                 get_registry().gauge(gauge).set(
                     cfg.layer_types.count(reader))
+            convs = cfg.layer_types.count("conv")
+            if convs:
+                # the gated short convolutions of the program and what
+                # their elementwise chains move a step
+                get_registry().gauge("short_conv.layers").set(convs)
+                get_registry().gauge("short_conv.filter_bytes").set(
+                    convs * short_conv_filter_bytes(
+                        tokens.shape[0], s, cfg.emb_dim,
+                        jnp.dtype(cfg.dtype).itemsize))
         for i in range(cfg.num_layers):
             kind, hand_on = cfg.layer_type(i), cfg.hands_on(i)
             block = block_cls(cfg, kind, cfg.ffn_type(i), hand_on,
@@ -1583,6 +1656,33 @@ GPT_CONFIGS = {
         # 16384 x 4608 queries, keys and values a block: keep each
         # block's input and, as every policy does, what its kernels made
         # (o 112 MiB and lse 1.75 MiB a block)
+        remat_policy="nothing_saveable",
+    ),
+    # https://huggingface.co/LiquidAI/LFM2-24B-A2B config.json
+    # (model_type lfm2_moe): gated short convolutions (three taps, no
+    # bias) three to one with grouped-query attention layers (32 query
+    # heads over 8 key/value heads of 64, a norm over each head of q and
+    # k, rotary theta 1e6) at published indices 2, 6, ..., 38; layers 0
+    # and 1 a silu-gated feed-forward of 11776, the other 38 hold 64
+    # routed experts of 1536 (sigmoid scores, 4 a token, a selection
+    # bias, weights normalised, scaling 1, nothing dropped), no shared
+    # expert; a tied head.
+    # Training path only (require_gpt2_block says who refuses it).
+    "lfm2-24b-a2b": TransformerConfig(
+        vocab_size=65536, num_layers=40, emb_dim=2048, max_len=128000,
+        layer_types=tuple("full_attention" if i % 4 == 2 else "conv"
+                          for i in range(40)),
+        num_heads=32, num_kv_heads=8, qk_norm=True,
+        pos_embedding="rope", rope_theta=1e6,
+        rope_layer_types=("full_attention",), conv_taps=3,
+        mlp_width=11776, mlp="silu_gated", norm="rmsnorm", norm_eps=1e-5,
+        use_bias=False, tie_embeddings=True,
+        routed_experts=64, routed_top_k=4, routed_width=1536,
+        routed_scaling=1.0, shared_experts=0, dense_layers_first=2,
+        # 32768 x 6144 of in_proj and 32768 x 23552 of the dense
+        # feed-forward a block: keep each block's input and, as every
+        # policy does, what its kernels made (the attention layer's o
+        # 128 MiB and lse 4 MiB)
         remat_policy="nothing_saveable",
     ),
 }

@@ -26,6 +26,12 @@ SELECTIVE_SCAN = "selective_scan"      # Mamba-1's scan alone, inside ssm
 GMU = "gmu"                            # a block's gated-memory-unit half:
                                        # in_proj, the product with the scan
                                        # memory another layer made, out_proj
+SHORT_CONV = "short_conv"              # a block's gated-short-convolution
+                                       # half: in_proj, the gates and the
+                                       # filter, out_proj
+SHORT_CONV_FILTER = "short_conv_filter"  # inside short_conv: the
+                                       # elementwise chain alone (B * u,
+                                       # the taps, C *)
 MLA_PROJ = "mla_proj"                  # latent attention, inside attn: the
                                        # two low-rank paths, their norms,
                                        # RoPE, the shared rotary key
@@ -77,6 +83,6 @@ KERNEL_OUTPUTS = (FLASH_OUT, FLASH_LSE, SSD_OUT, SSD_STATES, SSCAN_OUT,
 
 SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ,
           ATTN_WINDOW, ATTN_GATE, ATTN_CROSS, ATTN_DIFF, SSM, SSD_SCAN,
-          SELECTIVE_SCAN, GMU, MLP, MOE_ROUTE, MOE_BALANCE,
-          MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MTP, EMBED, HEAD, STEM,
-          KV_GATHER, KV_SCATTER, SAMPLE)
+          SELECTIVE_SCAN, GMU, SHORT_CONV, SHORT_CONV_FILTER, MLP,
+          MOE_ROUTE, MOE_BALANCE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED,
+          MTP, EMBED, HEAD, STEM, KV_GATHER, KV_SCATTER, SAMPLE)

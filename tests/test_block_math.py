@@ -28,8 +28,8 @@ STUBS = dict(ln1=lambda x: x + 1.0, ln2=lambda x: x - 2.0,
                          ids=["keeps_its_value", "hands_on"])
 @pytest.mark.parametrize("layer_type,scope,hand_on", [
     ("mamba", "ssm", "memory"), ("selective_scan", "ssm", "memory"),
-    ("gmu", "gmu", "memory"), ("mla", "attn", "kv"),
-    ("attention", "attn", "kv")])
+    ("gmu", "gmu", "memory"), ("conv", "short_conv", "memory"),
+    ("mla", "attn", "kv"), ("attention", "attn", "kv")])
 def test_block_math_adds_the_scaled_post_normed_delta(layer_type, scope,
                                                       hand_on, hands_on):
     """``x + m * post(mixer(ln1(x)))``, then the same around the
@@ -54,7 +54,7 @@ def test_block_math_adds_the_scaled_post_normed_delta(layer_type, scope,
         np.testing.assert_array_equal(handed, (X + 1.0) - 11.0)
     np.testing.assert_allclose(out, want, rtol=1e-6)
     text = jax.jit(block).lower(X).as_text(debug_info=True)
-    others = {"ssm", "gmu", "attn"} - {scope}
+    others = {"ssm", "gmu", "short_conv", "attn"} - {scope}
     assert f"/{scope}/mul" in text and "/mlp/mul" in text
     assert not any(f"/{other}/" in text for other in others)
 
@@ -106,4 +106,4 @@ def test_raw_block_paths_refuse_every_setting_they_do_not_honour(setting):
 
 def test_the_settings_the_raw_block_paths_honour_are_fields():
     assert RAW_BLOCK_SETTINGS <= {f.name for f in fields(TransformerConfig)}
-    assert len(fields(TransformerConfig)) == 68
+    assert len(fields(TransformerConfig)) == 70

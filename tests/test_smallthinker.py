@@ -286,15 +286,20 @@ def test_the_gates_handwritten_backward_is_jax_grads(activation):
 # sha256 (16 digits) over y, the routing's weights, experts, order, group
 # sizes and load, and the gradients by the tokens, the router and both
 # expert matrices, as the commit before this file (PR 42's tree) computes
-# them on the CPU: GLM-4.7-Flash's routing (4 a token of 64, 8 held from
-# expert 8 on, scaling 1.8) and Trinity-Mini's (8 of 128, 16 held from
-# expert 16 on, scaling 2.826), under the row bound and, with a skewed
-# bias, over it.
+# them on the CPU in float32: GLM-4.7-Flash's routing (4 a token of 64, 8
+# held from expert 8 on, scaling 1.8) and Trinity-Mini's (8 of 128, 16
+# held from expert 16 on, scaling 2.826), under the row bound and, with a
+# skewed bias, over it.  The bfloat16 cases have no digest (``None``): a
+# bfloat16 matmul's last bit is the host CPU's (two of the four digests
+# that stood here passed on one checking machine and failed on the next,
+# PR 48), so they are held to what no machine moves: the choice, the sort
+# and the counts exactly those of the float32 case on the same rounded
+# tokens, the float outputs within ``BF16_OF_FLOAT32`` of it.
 PARENTS = {
-    ("glm", "bfloat16", 0.0): ("aaafe08461bf8613", False),
-    ("trinity", "bfloat16", 0.0): ("cf8333064155b6c4", False),
-    ("glm", "bfloat16", 10.0): ("c9aa25539ac78a2f", True),
-    ("trinity", "bfloat16", 10.0): ("7c6abc24cbb0d936", True),
+    ("glm", "bfloat16", 0.0): (None, False),
+    ("trinity", "bfloat16", 0.0): (None, False),
+    ("glm", "bfloat16", 10.0): (None, True),
+    ("trinity", "bfloat16", 10.0): (None, True),
     ("glm", "float32", 0.0): ("c318dfe0b707aa3f", False),
     ("trinity", "float32", 0.0): ("89be5dbba7e11726", False),
     ("glm", "float32", 10.0): ("873b351ba66b9a7b", True),
@@ -304,15 +309,25 @@ ROUTINGS = {"glm": dict(n=512, experts=64, held=8, first=8, top_k=4,
                         scaling=1.8),
             "trinity": dict(n=256, experts=128, held=16, first=16, top_k=8,
                             scaling=2.826)}
+# The largest difference of a bfloat16 result from the float32 one, over
+# the float32 one's largest entry: bfloat16 keeps eight bits (2 ** -8 =
+# 0.0039 a rounding) and a result here is three matmuls deep with sums
+# over 24 to 32 products; the readings of the four cases on this machine
+# lie between 0.0037 and 0.0114 (the weights are float32 in both and
+# read 0), so the limit stands two and a half times over the largest.
+BF16_OF_FLOAT32 = 0.03
 
 
 @pytest.mark.parametrize("which,dtype,skew", sorted(PARENTS))
 def test_routed_experts_on_one_tensor_is_what_the_parent_computed(
         which, dtype, skew):
     """``routed_experts`` is now a decision and its application in turn;
-    on one tensor, with sigmoid scores, a bias and a silu gate, it is bit
-    for bit the one function it was: forward, the routing and all four
-    gradients, on both sides of the row bound."""
+    on one tensor, with sigmoid scores, a bias and a silu gate, it is the
+    one function it was: forward, the routing and all four gradients, on
+    both sides of the row bound.  In float32 bit for bit what the parent
+    computed; in bfloat16 bit for bit its own two halves called in turn,
+    its discrete outputs those of the float32 case and its float outputs
+    within a stated limit of it."""
     shape, dtype = ROUTINGS[which], jnp.dtype(dtype)
     n, experts, held, first = (shape[k] for k in (
         "n", "experts", "held", "first"))
@@ -326,23 +341,55 @@ def test_routed_experts_on_one_tensor_is_what_the_parent_computed(
     fc2 = jax.random.normal(k[4], (held, ff, d)) * 0.2
     probe = jax.random.normal(k[5], x.shape)
 
-    def run(x, router, fc1, fc2):
-        y, routing = moe.routed_experts(
+    def whole(x, router, fc1, fc2, dtype):
+        return moe.routed_experts(
             x, router, bias, fc1, fc2, top_k=shape["top_k"],
             scaling=shape["scaling"], first_held=first, dtype=dtype)
-        return (y.astype(jnp.float32) * probe).sum(), (y, routing)
 
-    (_, (y, routing)), grads = jax.value_and_grad(
-        run, argnums=(0, 1, 2, 3), has_aux=True)(x, router, fc1, fc2)
-    digest = hashlib.sha256()
-    for a in (y, routing.weights, routing.experts, routing.order,
-              routing.group_sizes, routing.load, *grads):
-        digest.update(np.asarray(
-            a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a
-        ).tobytes())
-    assert (digest.hexdigest()[:16], bool(routing.overflowed)) == PARENTS[
-        which, dtype.name, skew]
-    assert routing.balance is None      # nothing computed that was not
+    def halves(x, router, fc1, fc2, dtype):
+        routing = moe.routing_decision(
+            x, router, bias, top_k=shape["top_k"], scaling=shape["scaling"],
+            first_held=first, held=held)
+        return moe.apply_routing(routing, x, fc1, fc2, dtype=dtype)
+
+    def results(layer, x, dtype):
+        def run(x, router, fc1, fc2):
+            y, routing = layer(x, router, fc1, fc2, dtype)
+            return (y.astype(jnp.float32) * probe).sum(), (y, routing)
+
+        (_, (y, routing)), grads = jax.value_and_grad(
+            run, argnums=(0, 1, 2, 3), has_aux=True)(x, router, fc1, fc2)
+        assert routing.balance is None      # nothing computed that was not
+        discrete = (routing.experts, routing.order, routing.group_sizes,
+                    routing.load, routing.overflowed)
+        return (y, routing.weights, *grads), discrete
+
+    floats, discrete = results(whole, x, dtype)
+    digest, overflowed = PARENTS[which, dtype.name, skew]
+    assert bool(discrete[-1]) == overflowed
+    if digest is not None:
+        sha = hashlib.sha256()
+        y, weights, *grads = floats
+        for a in (y, weights, *discrete[:-1], *grads):
+            sha.update(np.asarray(a).tobytes())
+        assert sha.hexdigest()[:16] == digest
+        return
+    # the two halves in turn, in this dtype on this machine: every bit
+    for got, want in zip(results(halves, x, dtype), (floats, discrete)):
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(
+                np.asarray(a.astype(jnp.float32)),
+                np.asarray(b.astype(jnp.float32)))
+    # the float32 case on the same (rounded) tokens: the choice is made in
+    # float32 whatever the stream's dtype, so it is the same choice
+    exact, same_choice = results(whole, x.astype(jnp.float32), jnp.float32)
+    for a, b in zip(discrete, same_choice):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for name, a, b in zip(("y", "weights", "dx", "drouter", "dfc1", "dfc2"),
+                          floats, exact):
+        apart = float(jnp.abs(a.astype(jnp.float32) - b).max())
+        assert apart <= BF16_OF_FLOAT32 * float(jnp.abs(b).max()), (
+            name, apart, float(jnp.abs(b).max()))
 
 
 def test_a_decision_from_one_tensor_is_applied_to_another():
