@@ -600,6 +600,7 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
         gauge = lambda name: get_registry().gauge(name, layer_type=label)
         gauge("flash.tiles_live").set(plan.tiles_live)
         gauge("flash.tiles_grid").set(plan.tiles_grid)
+        gauge("flash.tiles_mask").set(plan.tiles_mask)
         gauge("flash.bwd_kernels").set(plan.bwd_kernels)
         return flash_attention(q, k, v, scale=cfg.attention_scale, **call)
     if window is not None and cfg.attention_impl != "reference":

@@ -11,15 +11,16 @@ each choice is in ``docs/performance.md``, "The flash kernels on the
 chip"):
 
 * What a call runs is decided once, from its shapes alone, by
-  :func:`flash_plan`: the tiles, the live and grid tile counts, how the
-  forward holds K and V, the backward's form and the VMEM each states.
-  ``flash_attention`` makes the :class:`FlashPlan` and hands it to the
-  kernels as their static argument; nothing below asks again, and a
-  caller that wants to know what its call will do asks the same
-  function.
-* Forward: grid ``(batch*heads, S/block_q, S/block_k)``; each program
-  owns one Q tile and one (block_k, d) K/V tile — the online-softmax
-  state rides VMEM scratch across the sequential K grid dimension.
+  :func:`flash_plan`: the tiles, the table of the live ones and the tile
+  counts, how the forward holds K and V, the backward's form and the
+  VMEM each states.  ``flash_attention`` makes the :class:`FlashPlan`
+  and hands it to the kernels as their static argument; nothing below
+  asks again, and a caller that wants to know what its call will do asks
+  the same function.
+* Forward: grid ``(batch*heads, steps)``, the second axis one head's
+  live (Q tile, K tile) pairs, Q tile major; each program owns one Q
+  tile and one (block_k, d) K/V tile — the online-softmax state rides
+  VMEM scratch across a Q row's consecutive steps.
   Wherever a kv row's K and V fit ``_FUSED_BWD_VMEM_LIMIT`` (every
   benchmark shape, up to 30208 keys at head size 128 in bfloat16) the
   forward holds the row resident, fetched from HBM once for all the
@@ -36,20 +37,36 @@ chip"):
   crosses HBM as ``[Z, S]``, a 2 KB row per Q tile.  Nothing in a tile
   loop goes through the cross-lane unit and no score-sized tile is
   ever transposed.
-* Causal programs stop their K loop at the diagonal tile — the upper
-  triangle is never computed, not just masked; with a window, nor are
-  the tiles entirely below the band.  The grid is whole whatever the
-  mask: a dead tile costs its grid step and no arithmetic.
+* The grids walk the live tiles alone.  A causal call's upper triangle
+  is never visited, not just masked; with a window, nor are the tiles
+  entirely below the band.  ``flash_plan`` lists one head's live pairs
+  (``_tile_live``, the one predicate) and every kernel takes them as a
+  small int32 table by scalar prefetch (``pltpu.PrefetchScalarGridSpec``:
+  the table is in SMEM before the body runs): the index maps and the
+  body read the step's ``i`` and ``j`` from it, and its ``edges`` column
+  says where an accumulator opens and closes (``_q_major_walk`` for the
+  forward and the Q-outermost backward, ``_k_major_walk`` for the
+  K-outermost one).  One path, chosen by the mask: a call without a mask
+  has the whole rectangle for its table.  A table past
+  ``_TILE_TABLE_SMEM_LIMIT`` (no benchmark shape; 131072 keys in two
+  passes) is not made: the same kernels then walk the ``nq x nk``
+  rectangle by arithmetic on the step and skip a dead tile's body, which
+  costs its grid step (0.07-0.35 us, and where tiles stream their DMA)
+  and no arithmetic.  Either walk adds the same float32 terms in the
+  same order: the results are equal to the bit.
 * Backward is a blockwise recompute from the saved logsumexp, wired via
   ``jax.custom_vjp`` so the op drops into training.  ``delta =
   rowsum(do * o)`` is computed once per call, outside the kernels.  ONE
   kernel forms each tile's ``p`` and ``ds`` once and takes dq, dk and dv
   from them, in one of two forms.  ``"dkdv_resident"``: Q tile
-  outermost, dq's accumulator and a whole kv row's dk and dv
+  outermost (the forward's table), dq's accumulator and a whole kv
+  row's dk and dv
   accumulators resident, wherever that fits ``_FUSED_BWD_VMEM_LIMIT``,
   the 32 MiB the call then states (a head's channels fill whole 128-lane
   tiles, so 8192 keys at head size 64 or 128 and 4096 keys at head size
-  256).  ``"dq_resident"``: K tile outermost, the tile's dk and dv
+  256).  ``"dq_resident"``: K tile outermost (for each K tile the
+  query heads' Q tiles that see it; a pair's dq is written at its last
+  live K tile), the tile's dk and dv
   accumulators and the kv row's dq resident, where that fits instead
   (8192 keys at head size 256, latent attention's shape, up to 26624;
   16384 keys at head size 64).  Where neither fits the 32 MiB,
@@ -89,10 +106,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
@@ -124,13 +142,20 @@ class FlashPlan:
     block_k: int
     causal: bool
     window: Optional[int]       # None where it reaches every earlier key
-    # Of the (q, k) tiles the grid walks over all batch x head rows, how
-    # many the kernels' ``needed`` predicate admits.  A dead tile costs
-    # its grid step and no arithmetic, and a DMA of K and V tiles only
-    # where those stream (the one-kernel backward's, and the forward's
-    # of a kv row too long to stay resident).
+    # Over all batch x head rows: the (q, k) tiles ``_tile_live`` admits
+    # under the call's mask, the steps the kernels' grids walk, and the
+    # whole ``nq x nk`` rectangle.  The grid walks the live tiles alone
+    # (``tiles_grid == tiles_live``) wherever their table fits
+    # ``_TILE_TABLE_SMEM_LIMIT``; past it the rectangle, where a dead
+    # tile costs its grid step and no arithmetic.
     tiles_live: int
     tiles_grid: int
+    tiles_mask: int
+    # One head's live (Q tile, K tile) pairs, Q tile major: the table the
+    # grids walk, the kernels' scalar-prefetch operand (``_q_major_walk``;
+    # ``_k_major_walk`` turns it for the K-outermost kernel).  ``None``
+    # where the grid stays the rectangle.
+    live_tiles: Optional[tuple] = dataclasses.field(repr=False)
     # The forward: a kv row's K and V whole in VMEM (fetched once a row,
     # under GQA once for the group's query heads), or (1, block_k, .)
     # tiles streamed, one a grid step, VMEM independent of S; and the
@@ -191,13 +216,8 @@ def flash_plan(q, k, v, *, causal: bool = False, block_q: int = 512,
             window = None  # full causal; skip/mask logic not needed
     bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
     nq, nk = s // bq, s // bk
-    live = 0
-    for i in range(nq):
-        for j in range(nk):
-            needed = j * bk <= (i + 1) * bq - 1 if causal else True
-            if window is not None:
-                needed = needed and (j + 1) * bk - 1 >= i * bq - (window - 1)
-            live += needed
+    live = tuple((i, j) for i in range(nq) for j in range(nk)
+                 if _tile_live(i, j, bq, bk, causal, window))
     itemsize = jnp.dtype(q.dtype).itemsize
 
     fwd = _fwd_resident_vmem_bytes(s, d, dv, bq, bk, itemsize)
@@ -217,9 +237,17 @@ def flash_plan(q, k, v, *, causal: bool = False, block_q: int = 512,
         bwd_vmem = _whole_mib(count)
         if count > _FUSED_BWD_VMEM_CEILING:
             form, bwd_vmem = "two_passes", 0
+    # the call's largest table: the forward's, or the K-outermost
+    # backward's, a row a query head of the group
+    columns = (_Q_MAJOR_COLUMNS if form == "dkdv_resident"
+               else _K_MAJOR_COLUMNS * (h // hkv))
+    walks_table = 4 * columns * len(live) <= _TILE_TABLE_SMEM_LIMIT
     return FlashPlan(
         heads=h, kv_heads=hkv, block_q=bq, block_k=bk, causal=causal,
-        window=window, tiles_live=b * h * live, tiles_grid=b * h * nq * nk,
+        window=window, tiles_live=b * h * len(live),
+        tiles_grid=b * h * (len(live) if walks_table else nq * nk),
+        tiles_mask=b * h * nq * nk,
+        live_tiles=live if walks_table else None,
         fwd_kv_resident=resident,
         fwd_vmem_bytes=_whole_mib(fwd) if stated else 0,
         bwd_form=form, bwd_vmem_bytes=bwd_vmem)
@@ -333,6 +361,153 @@ _FUSED_BWD_VMEM_CEILING = 48 * 2 ** 20
 _DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
 
 
+# The SMEM a call's tile table may take (the int32 columns its kernels
+# prefetch, ``_Q_MAJOR_COLUMNS`` or ``_K_MAJOR_COLUMNS`` a step): past it
+# ``flash_plan`` keeps the rectangle.  LFM2's K-outermost backward, the
+# largest table of any benchmark shape, is 4160 live tiles x 4 query
+# heads a group x 6 columns: 390 KiB.
+_TILE_TABLE_SMEM_LIMIT = 512 * 2 ** 10
+_Q_MAJOR_COLUMNS = 3    # i, j, edges
+_K_MAJOR_COLUMNS = 6    # j, g, i, edges, and the (g, i) that flushes next
+
+
+def _tile_live(i, j, bq: int, bk: int, causal: bool, window: Optional[int]):
+    """Does Q tile ``i`` see K tile ``j``?  Causal: not if the K tile is
+    entirely above the diagonal; with a window, not if it is entirely
+    below the band either.  One definition for the plan's table (Python
+    ints) and for the kernels that keep the rectangle (traced)."""
+    live = (j * bk <= (i + 1) * bq - 1) if causal else True
+    if window is not None:
+        live = live & ((j + 1) * bk - 1 >= i * bq - (window - 1))
+    return live
+
+
+class _Walk(NamedTuple):
+    """How a grid's second axis walks one head's (or, K tile outermost,
+    one kv row's) tiles.  ``tile`` and ``edges`` take the step ``t`` and
+    the table's refs, as an index map gets them."""
+
+    tables: tuple       # the int32 columns the call prefetches to SMEM
+    steps: int          # the extent of the grid's second axis
+    tile: Callable      # -> the step's tile: (i, j), or (j, g, i)
+    edges: Callable     # -> the body's predicates, ``live`` last
+    flushing: Optional[Callable] = None  # K-major: -> the (g, i) whose
+    #                     dq block leaves VMEM next, at ``t`` or after
+
+
+def _bit(edges, n: int):
+    return ((edges >> n) & 1) == 1
+
+
+def _run_edges(outer: np.ndarray) -> np.ndarray:
+    """Bit 0 on the first step of every run of equal ``outer`` indices,
+    bit 1 on the last."""
+    turn = np.flatnonzero(np.diff(outer)) + 1
+    edges = np.zeros_like(outer)
+    edges[np.r_[0, turn]] += 1
+    edges[np.r_[turn - 1, len(outer) - 1]] += 2
+    return edges
+
+
+def _q_major_table(live_tiles) -> np.ndarray:
+    """``[3, T]``: a head's live tiles Q tile major, and each step's
+    edges: bit 0 on the first live tile of its Q row, bit 1 on the
+    last."""
+    i, j = np.asarray(live_tiles, np.int32).T
+    return np.stack([i, j, _run_edges(i)])
+
+
+def _q_major_walk(plan: FlashPlan, nq: int, nk: int) -> _Walk:
+    """Q tile outermost, K tiles innermost.  ``tile``: ``(i, j)``;
+    ``edges``: the Q row's first step, its last, live."""
+    if plan.live_tiles is None:
+        def tile(t):
+            return t // nk, t % nk
+
+        def edges(t):
+            i, j = tile(t)
+            return j == 0, j == nk - 1, _tile_live(
+                i, j, plan.block_q, plan.block_k, plan.causal, plan.window)
+
+        return _Walk((), nq * nk, tile, edges)
+    table = _q_major_table(plan.live_tiles)
+
+    def tile(t, qi, kj, _):
+        return qi[t], kj[t]
+
+    def edges(t, qi, kj, edge):
+        return _bit(edge[t], 0), _bit(edge[t], 1), True
+
+    return _Walk(tuple(table), table.shape[1], tile, edges)
+
+
+def _k_major_table(live_tiles, nq: int, group: int) -> np.ndarray:
+    """``[6, T]``: for each K tile ``j`` in turn, for each query head
+    ``g`` of the group, the Q tiles ``i`` that see it: rows ``j``, ``g``,
+    ``i``; the step's edges (bit 0 on the K tile's first step, bit 1 on
+    its last, bit 2 where the step is the pair ``(g, i)``'s first live
+    ``j``, bit 3 where it is its last); and the ``(g, i)`` of the next
+    step at or after this one with bit 3 set, the pair whose dq block
+    leaves VMEM next."""
+    qi, kj = np.asarray(live_tiles, np.int32).T
+    order = np.argsort(kj, kind="stable")       # K-major, i ascending
+    seen = np.unique(kj, return_counts=True)[1]
+    # every K tile's run of Q tiles, once a head of the group
+    steps = np.concatenate([
+        np.tile(run, group) for run in np.split(order, np.cumsum(seen)[:-1])])
+    j, i = kj[steps], qi[steps]
+    g = np.concatenate([np.repeat(np.arange(group, dtype=np.int32), n)
+                        for n in seen])
+    low, high = (np.full(nq, fill, np.int32) for fill in (j.max() + 1, -1))
+    np.minimum.at(low, qi, kj)
+    np.maximum.at(high, qi, kj)
+    opens, closes = j == low[i], j == high[i]
+    # the next closing step at or after each step: the last step closes
+    nxt = np.flatnonzero(closes)[np.searchsorted(
+        np.flatnonzero(closes), np.arange(len(j)))]
+    edges = _run_edges(j) + (4 * opens + 8 * closes).astype(np.int32)
+    return np.stack([j, g, i, edges, g[nxt], i[nxt]])
+
+
+def _k_major_walk(plan: FlashPlan, nq: int, nk: int, group: int) -> _Walk:
+    """K tile outermost, (query head in group, Q tile) pairs innermost.
+    ``tile``: ``(j, g, i)``; ``edges``: the K tile's first step, its
+    last, the pair's first K tile (dq's accumulator is zeroed), its last
+    (dq's block is written), live."""
+    if plan.live_tiles is None:
+        pairs = group * nq
+
+        def tile(t):
+            return t // pairs, (t % pairs) // nq, t % nq
+
+        def edges(t):
+            j, _, i = tile(t)
+            return (t % pairs == 0, t % pairs == pairs - 1, j == 0,
+                    j == nk - 1, _tile_live(i, j, plan.block_q, plan.block_k,
+                                            plan.causal, plan.window))
+
+        def flushing(t):
+            # dq's block index moves only in the last K tile's sweep
+            j, g, i = tile(t)
+            return (jnp.where(j == nk - 1, g, 0),
+                    jnp.where(j == nk - 1, i, 0))
+
+        return _Walk((), nk * pairs, tile, edges, flushing)
+    table = _k_major_table(plan.live_tiles, nq, group)
+
+    def tile(t, kj, qg, qi, *_):
+        return kj[t], qg[t], qi[t]
+
+    def edges(t, kj, qg, qi, edge, *_):
+        e = edge[t]
+        return _bit(e, 0), _bit(e, 1), _bit(e, 2), _bit(e, 3), True
+
+    def flushing(t, kj, qg, qi, edge, fg, fi):
+        return fg[t], fi[t]
+
+    return _Walk(tuple(table), table.shape[1], tile, edges, flushing)
+
+
 def _lanes(width: int) -> int:
     """A minor dimension padded to the 128 lanes its tiles occupy."""
     return -(-width // 128) * 128
@@ -405,20 +580,20 @@ def _flash_fwd_kernel(q, k, v, plan: FlashPlan, scale, interpret):
     """Returns (o [Z,S,DV], lse [Z,S]) with Z = batch*heads and DV the
     values' width, for folded operands and the call's ``plan``.
 
-    Grid ``(z, nq, nk)``, K tiles innermost, whole whatever the mask.
-    The online-softmax state (acc [dv, bq], m and l [1, bq]: transposed
-    like the tile) persists across the sequential K dimension in VMEM
-    scratch and is flushed to the output block at the last K tile; lse
-    leaves as one row per Q tile.  GQA/MQA: k/v have Z_kv = batch*hkv
-    rows; the index map routes each q head to its group.
+    Grid ``(z, steps)``: the second axis walks a head's live tiles Q
+    tile major (``_q_major_walk``), K tiles innermost.  The
+    online-softmax state (acc [dv, bq], m and l [1, bq]: transposed like
+    the tile) persists across a Q row's steps in VMEM scratch, is set at
+    the row's first live tile and flushed to the output block at its
+    last; lse leaves as one row per Q tile.  GQA/MQA: k/v have Z_kv =
+    batch*hkv rows; the index map routes each q head to its group.
 
     How K and V get to VMEM is the plan's ``fwd_kv_resident``.
     Wherever a kv row fits, its K and V are whole-row blocks ``(1, s,
     d)`` and ``(1, s, dv)`` whose block index moves once a kv row; the
     body slices the tile it needs.  The row is fetched from HBM once for
-    the ``group x nq x nk`` grid steps that read it, where (1, bk, .)
-    tiles would be fetched once a grid step, live or dead, ``group x
-    nq`` times over, and a dead grid step copies nothing.  Above the
+    all the grid steps that read it, where (1, bk, .) tiles would be
+    fetched once a grid step, ``group x nq`` times over.  Above the
     limit the streamed tiles: only (1, bk, d) of K and (1, bk, dv) of V
     are resident per step and VMEM peak is O(bq*dv + bk*(d + dv)),
     independent of S (the long-context requirement).  Both forms run the
@@ -430,28 +605,24 @@ def _flash_fwd_kernel(q, k, v, plan: FlashPlan, scale, interpret):
     bq, bk, h, hkv = plan.block_q, plan.block_k, plan.heads, plan.kv_heads
     causal, window, resident = plan.causal, plan.window, plan.fwd_kv_resident
     nq, nk = s // bq, s // bk
+    walk = _q_major_walk(plan, nq, nk)
+    columns = len(walk.tables)
 
-    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref):
-        i = pl.program_id(1)
-        j = pl.program_id(2)
+    def kernel(*refs):
+        table = refs[:columns]
+        q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = \
+            refs[columns:]
+        t = pl.program_id(1)
+        i, j = walk.tile(t, *table)
+        first, last, live = walk.edges(t, *table)
 
-        @pl.when(j == 0)
+        @pl.when(first)
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
 
-        # Causal: K tiles strictly above the diagonal contribute
-        # nothing; with a window, tiles entirely below the band are dead
-        # too — skip both (a dead step of the resident form costs its
-        # grid step alone, of the streamed form its tiles' DMA too).
-        needed = (j * bk <= (i + 1) * bq - 1) if causal else (j >= 0)
-        if window is not None:
-            needed = jnp.logical_and(
-                needed, (j + 1) * bk - 1 >= i * bq - (window - 1)
-            )
-
-        @pl.when(needed)
+        @pl.when(live)
         def _compute():
             # the tile transposed: keys on sublanes, queries on lanes, so
             # a query's statistic is one lane of a (1, bq) row.  A row
@@ -484,86 +655,95 @@ def _flash_fwd_kernel(q, k, v, plan: FlashPlan, scale, interpret):
             )                                          # [dv, bq]
             m_ref[...] = m_new
 
-        @pl.when(j == nk - 1)
+        @pl.when(last)
         def _flush():
             o_ref[0] = (acc_ref[...] / l_ref[...]).T.astype(o_ref.dtype)
             lse_ref[0, 0] = m_ref[...] + jnp.log(l_ref[...])
+
+    q_tile = lambda zi, t, *table: (zi, walk.tile(t, *table)[0], 0)
 
     def kv_block(width):
         # resident: the whole row, its block index moving once a kv row
         return pl.BlockSpec(
             (1, s if resident else bk, width),
-            lambda zi, qi, ki: (_kv_row(zi, h, hkv), 0 if resident else ki, 0))
+            lambda zi, t, *table: (
+                _kv_row(zi, h, hkv),
+                0 if resident else walk.tile(t, *table)[1], 0))
 
     o, lse = pl.pallas_call(
         kernel,
-        grid=(z, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda zi, qi, ki: (zi, qi, 0)),
-            kv_block(d),
-            kv_block(dv),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, dv), lambda zi, qi, ki: (zi, qi, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda zi, qi, ki: (zi, qi, 0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=columns,
+            grid=(z, walk.steps),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), q_tile),
+                kv_block(d),
+                kv_block(dv),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, dv), q_tile),
+                pl.BlockSpec((1, 1, 1, bq), lambda *at: (*q_tile(*at), 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((dv, bq), jnp.float32),  # acc
+                pltpu.VMEM((1, bq), jnp.float32),   # running max m
+                pltpu.VMEM((1, bq), jnp.float32),   # running sum l
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((z, s, dv), q.dtype),
             jax.ShapeDtypeStruct((z, nq, 1, bq), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((dv, bq), jnp.float32),  # acc
-            pltpu.VMEM((1, bq), jnp.float32),   # running max m
-            pltpu.VMEM((1, bq), jnp.float32),   # running sum l
-        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=plan.fwd_vmem_bytes or None,
         ),
         interpret=interpret,
         name="flash_fwd",
-    )(q, k, v)
+    )(*walk.tables, q, k, v)
     return o, lse.reshape(z, s)
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, plan: FlashPlan, scale,
                       interpret):
-    """Pallas flash backward, tiled, skipping fully-masked causal blocks
-    (a plain scan over K tiles, the tests' oracle, computes the whole
+    """Pallas flash backward, tiled, walking the live tiles alone (a
+    plain scan over K tiles, the tests' oracle, computes the whole
     upper triangle and streams O(S*bk) score tiles through HBM — on a
     causal LM that is ~2x wasted FLOPs and the dominant HBM stream).  P
     is recomputed from the forward's saved logsumexp; ``delta`` =
     rowsum(do*o) is the standard softmax-backward correction.
 
     One kernel (call site ``flash_bwd_dkdv``, which here covers dq, dk
-    AND dv), each needed tile's P and dS formed once, in the plan's
+    AND dv), each live tile's P and dS formed once, in the plan's
     ``bwd_form``; no partial sum goes through HBM in either.
-    ``"dkdv_resident"`` (grid z, nq, nk), wherever
+    ``"dkdv_resident"`` (grid z, steps: ``_q_major_walk``, the
+    forward's table), wherever
     ``_fused_bwd_vmem_bytes`` fits ``_FUSED_BWD_VMEM_LIMIT`` (or, past
     it in both forms, counts less than the other and fits
     ``_FUSED_BWD_VMEM_CEILING``, the call stating that count): Q tile
-    fixed, K tiles stream.  dq accumulates as [d, bq], turned once at
-    the flush; dk and dv accumulate in float32 scratch buffers of [S, d]
+    fixed, K tiles stream.  dq accumulates as [d, bq], zeroed at the Q
+    row's first live tile and turned once at its last; dk and dv
+    accumulate in float32 scratch buffers of [S, d]
     and [S, dv] (the values' width: v, do, o and dv carry it, q, k, dq
     and dk the head size) that live for a whole kv row — under GQA the
     group's query heads are consecutive z and fold into them — zeroed at
     the row's first grid step and written, cast once, to the (1, S, d)
     and (1, S, dv) output blocks at its last.
-    ``"dq_resident"`` (grid z_kv, nk, nq*group), where
-    ``_dq_resident_bwd_vmem_bytes`` fits instead: K tile fixed,
-    (q-head-in-group, Q tile) pairs stream; dk and dv of the tile
-    accumulate as [bk, d] and [bk, dv] and flush at the last pair, dq of
-    the kv row's
-    query heads as [group*nq, d, bq], zeroed in the first K tile's
-    sweep and written, turned and cast, in the last's (dq's block index
-    stays put until then, so each block goes to HBM once).  It adds the
-    float32 terms in the order the two passes add them.
+    ``"dq_resident"`` (grid z_kv, steps: ``_k_major_walk``), where
+    ``_dq_resident_bwd_vmem_bytes`` fits instead: K tile fixed, the
+    (q-head-in-group, Q tile) pairs that see it stream; dk and dv of the
+    tile accumulate as [bk, d] and [bk, dv] and flush at the tile's last
+    pair, dq of the kv row's query heads as [group*nq, d, bq], each pair
+    zeroed at its first live K tile and written, turned and cast, at its
+    last (dq's block index is the pair that is written next, so it moves
+    right after each write and each block goes to HBM once).  It adds
+    the float32 terms in the order the two passes add them.
 
     Two passes above the ceiling in both forms, each recomputing P and
     dS, VMEM independent of S:
-    Pass A (grid z_kv, nk, nq*group; ``flash_bwd_dkdv``): the
-    K-outermost kernel without dq.
-    Pass B (grid z, nq, nk; ``flash_bwd_dq``): Q tile fixed, K tiles
+    Pass A (grid z_kv, steps; ``flash_bwd_dkdv``): the K-outermost
+    kernel without dq.
+    Pass B (grid z, steps; ``flash_bwd_dq``): Q tile fixed, K tiles
     stream; dq accumulates (as [d, bq], turned once at the flush).
     """
     z, s, d = q.shape
@@ -585,18 +765,6 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, plan: FlashPlan, scale,
     # broadcast.
     delta = (do.astype(f32) * o.astype(f32)).sum(-1)
     lse_r, delta_r = (x.reshape(z, nq, 1, bq) for x in (lse, delta))
-
-    def _tile_needed(i, j):
-        """Does Q tile ``i`` see K tile ``j``?  Causal: not if the K tile
-        is entirely above the diagonal; with a window, not if it is
-        entirely below the band either.  The forward's predicate, one
-        definition for all three backward kernels."""
-        needed = (j * bk <= (i + 1) * bq - 1) if causal else (j >= 0)
-        if window is not None:
-            needed = jnp.logical_and(
-                needed, (j + 1) * bk - 1 >= i * bq - (window - 1)
-            )
-        return needed
 
     def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         i, j):
@@ -621,93 +789,71 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, plan: FlashPlan, scale,
         ds = p * (dp - delta_ref[0, 0])
         return qb, kb, dob, p, ds
 
-    def kernel_k_outer(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       *refs):
-        """K tile fixed, (q head in group, Q tile) pairs stream: dk and dv
-        of the tile, and ``with_dq`` dq too, from the same p and ds."""
-        if with_dq:
-            dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
-        else:
-            dk_ref, dv_ref, dk_acc, dv_acc = refs
-        j = pl.program_id(1)
-        t = pl.program_id(2)          # (q head in group) * nq + (q tile)
-        i = t % nq
+    # q, k, dq, dk are ``d`` wide; v, do, dv the values' own ``dv``
+    k_spec = lambda tile, which: pl.BlockSpec((1, tile, d), which)
+    v_spec = lambda tile, which: pl.BlockSpec((1, tile, dv), which)
+    stat_spec = lambda which: pl.BlockSpec(
+        (1, 1, 1, bq), lambda *at: (*which(*at), 0))
 
-        @pl.when(t == 0)
-        def _init():
-            dk_acc[...] = jnp.zeros_like(dk_acc)
-            dv_acc[...] = jnp.zeros_like(dv_acc)
+    def call(kernel, name, walk, rows, q_tile, kv_tile, out_specs,
+             out_shape, scratch_shapes, semantics, vmem_limit=None):
+        """A backward kernel over ``(rows, walk.steps)``: the walk's table,
+        then q, k, v, do, lse and delta tiles in."""
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(walk.tables),
+                grid=(rows, walk.steps),
+                in_specs=[
+                    k_spec(bq, q_tile),
+                    k_spec(bk, kv_tile),
+                    v_spec(bk, kv_tile),
+                    v_spec(bq, q_tile),         # do
+                    stat_spec(q_tile),          # lse
+                    stat_spec(q_tile),          # delta
+                ],
+                out_specs=out_specs,
+                scratch_shapes=scratch_shapes,
+            ),
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=semantics,
+                vmem_limit_bytes=vmem_limit or None),
+            interpret=interpret,
+            name=name,
+        )(*walk.tables, q, k, v, do, lse_r, delta_r)
 
-        if with_dq:
-            @pl.when(j == 0)
-            def _init_dq():
-                dq_acc[t] = jnp.zeros((d, bq), f32)
+    # Q tile outermost (the forward's walk): the one kernel with dk and
+    # dv resident, and the two passes' dq
+    q_walk = _q_major_walk(plan, nq, nk)
+    q_columns = len(q_walk.tables)
+    q_outer = dict(
+        walk=q_walk, rows=z,
+        q_tile=lambda zi, t, *table: (zi, q_walk.tile(t, *table)[0], 0),
+        kv_tile=lambda zi, t, *table: (
+            _kv_row(zi, h, hkv), q_walk.tile(t, *table)[1], 0))
 
-        @pl.when(_tile_needed(i, j))
-        def _compute():
-            qb, kb, dob, p, ds = _recompute_p_ds(
-                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, j
-            )
-            dv_acc[...] += jnp.dot(p, dob, preferred_element_type=f32)
-            dk_acc[...] += jnp.dot(ds, qb,
-                                   preferred_element_type=f32) * scale
-            if with_dq:
-                dq_acc[t] += lax.dot_general(
-                    kb, ds, _CONTRACT_ROWS, preferred_element_type=f32,
-                ) * scale                               # [d, bq]
-
-        @pl.when(t == nq * group - 1)
-        def _flush():
-            dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-            dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
-
-        if with_dq:
-            @pl.when(j == nk - 1)
-            def _flush_dq():
-                dq_ref[0] = dq_acc[t].T.astype(dq_ref.dtype)
-
-    def kernel_dq(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                  dq_ref, dq_acc):
-        i = pl.program_id(1)
-        j = pl.program_id(2)
-
-        @pl.when(j == 0)
-        def _init():
-            dq_acc[...] = jnp.zeros_like(dq_acc)
-
-        @pl.when(_tile_needed(i, j))
-        def _compute():
-            _, kb, _, _, ds = _recompute_p_ds(
-                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, j
-            )
-            dq_acc[...] += lax.dot_general(
-                kb, ds, _CONTRACT_ROWS, preferred_element_type=f32,
-            ) * scale                                   # [d, bq]
-
-        @pl.when(j == nk - 1)
-        def _flush():
-            dq_ref[0] = dq_acc[...].T.astype(dq_ref.dtype)
-
-    def kernel_fused(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
+    def kernel_fused(*refs):
+        table = refs[:q_columns]
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs[q_columns:]
         zi = pl.program_id(0)
-        i = pl.program_id(1)
-        j = pl.program_id(2)
+        t = pl.program_id(1)
+        i, j = q_walk.tile(t, *table)
+        first, last, live = q_walk.edges(t, *table)
+
         # a kv row's query heads are consecutive zi: its accumulators
         # live from the first head's first tile to the last head's last
-        first_tile = jnp.logical_and(i == 0, j == 0)
-        last_tile = jnp.logical_and(i == nq - 1, j == nk - 1)
-
-        @pl.when(jnp.logical_and(zi % group == 0, first_tile))
+        @pl.when(jnp.logical_and(zi % group == 0, t == 0))
         def _init_row():
             dk_acc[...] = jnp.zeros_like(dk_acc)
             dv_acc[...] = jnp.zeros_like(dv_acc)
 
-        @pl.when(j == 0)
+        @pl.when(first)
         def _init():
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
-        @pl.when(_tile_needed(i, j))
+        @pl.when(live)
         def _compute():
             qb, kb, dob, p, ds = _recompute_p_ds(
                 q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, j
@@ -720,128 +866,142 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, plan: FlashPlan, scale,
                 kb, ds, _CONTRACT_ROWS, preferred_element_type=f32,
             ) * scale                                   # [d, bq]
 
-        @pl.when(j == nk - 1)
+        @pl.when(last)
         def _flush():
             dq_ref[0] = dq_acc[...].T.astype(dq_ref.dtype)
 
-        @pl.when(jnp.logical_and(zi % group == group - 1, last_tile))
+        @pl.when(jnp.logical_and(zi % group == group - 1,
+                                 t == q_walk.steps - 1))
         def _flush_row():
             dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
-    # q, k, dq, dk are ``d`` wide; v, do, dv the values' own ``dv``
-    k_spec = lambda tile, which: pl.BlockSpec((1, tile, d), which)
-    v_spec = lambda tile, which: pl.BlockSpec((1, tile, dv), which)
-    stat_spec = lambda which: pl.BlockSpec((1, 1, 1, bq), which)
-
     if form == "dkdv_resident":
-        q_tile = lambda zi, ii, ji: (zi, ii, 0)
-        q_stat = lambda zi, ii, ji: (zi, ii, 0, 0)
-        kv_tile = lambda zi, ii, ji: (_kv_row(zi, h, hkv), ji, 0)
-        kv_whole = lambda zi, ii, ji: (_kv_row(zi, h, hkv), 0, 0)
-        return pl.pallas_call(
-            kernel_fused,
-            grid=(z, nq, nk),
-            in_specs=[
-                k_spec(bq, q_tile),
-                k_spec(bk, kv_tile),
-                v_spec(bk, kv_tile),
-                v_spec(bq, q_tile),         # do
-                stat_spec(q_stat),          # lse
-                stat_spec(q_stat),          # delta
-            ],
-            out_specs=[
-                k_spec(bq, q_tile),
-                k_spec(s, kv_whole),
-                v_spec(s, kv_whole),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((z, s, d), q.dtype),
-                jax.ShapeDtypeStruct((z_kv, s, d), k.dtype),
-                jax.ShapeDtypeStruct((z_kv, s, dv), v.dtype),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((d, bq), f32),
-                pltpu.VMEM((s, d), f32),
-                pltpu.VMEM((s, dv), f32),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                # dk and dv accumulate across all three axes
-                dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-                vmem_limit_bytes=vmem_limit,
-            ),
-            interpret=interpret,
-            name="flash_bwd_dkdv",
-        )(q, k, v, do, lse_r, delta_r)
+        kv_whole = lambda zi, t, *table: (_kv_row(zi, h, hkv), 0, 0)
+        return call(
+            kernel_fused, "flash_bwd_dkdv", **q_outer,
+            out_specs=[k_spec(bq, q_outer["q_tile"]), k_spec(s, kv_whole),
+                       v_spec(s, kv_whole)],
+            out_shape=[jax.ShapeDtypeStruct((z, s, d), q.dtype),
+                       jax.ShapeDtypeStruct((z_kv, s, d), k.dtype),
+                       jax.ShapeDtypeStruct((z_kv, s, dv), v.dtype)],
+            scratch_shapes=[pltpu.VMEM((d, bq), f32),
+                            pltpu.VMEM((s, d), f32),
+                            pltpu.VMEM((s, dv), f32)],
+            # dk and dv accumulate across both axes
+            semantics=("arbitrary", "arbitrary"), vmem_limit=vmem_limit)
 
-    def _qrow(zi, ti):
-        """The K-outermost q row for kv row ``zi`` and inner step ``ti``."""
-        return (zi // hkv) * h + (zi % hkv) * group + ti // nq
+    walk = _k_major_walk(plan, nq, nk, group)
+    columns = len(walk.tables)
 
-    q_tile = lambda zi, ji, ti: (_qrow(zi, ti), ti % nq, 0)
-    q_stat = lambda zi, ji, ti: (_qrow(zi, ti), ti % nq, 0, 0)
-    kv_tile = lambda zi, ji, ti: (zi, ji, 0)
+    def kernel_k_outer(*refs):
+        """K tile fixed, the (q head in group, Q tile) pairs that see it
+        stream: dk and dv of the tile, and ``with_dq`` dq too, from the
+        same p and ds."""
+        table = refs[:columns]
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs = \
+            refs[columns:]
+        if with_dq:
+            dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
+        else:
+            dk_ref, dv_ref, dk_acc, dv_acc = refs
+        t = pl.program_id(1)
+        j, g, i = walk.tile(t, *table)
+        first, last, opens, closes, live = walk.edges(t, *table)
+        pair = g * nq + i
 
-    def dq_tile(zi, ji, ti):
-        # dq's block index moves only in the last K tile's sweep, where
-        # the kernel writes it: each block goes to HBM once
-        last = jnp.where(ji == nk - 1, ti, 0)
-        return (_qrow(zi, last), last % nq, 0)
+        @pl.when(first)
+        def _init():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
 
+        if with_dq:
+            @pl.when(opens)
+            def _init_dq():
+                dq_acc[pair] = jnp.zeros((d, bq), f32)
+
+        @pl.when(live)
+        def _compute():
+            qb, kb, dob, p, ds = _recompute_p_ds(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, j
+            )
+            dv_acc[...] += jnp.dot(p, dob, preferred_element_type=f32)
+            dk_acc[...] += jnp.dot(ds, qb,
+                                   preferred_element_type=f32) * scale
+            if with_dq:
+                dq_acc[pair] += lax.dot_general(
+                    kb, ds, _CONTRACT_ROWS, preferred_element_type=f32,
+                ) * scale                               # [d, bq]
+
+        @pl.when(last)
+        def _flush():
+            dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+        if with_dq:
+            @pl.when(closes)
+            def _flush_dq():
+                dq_ref[0] = dq_acc[pair].T.astype(dq_ref.dtype)
+
+    def q_row(zi, g):
+        """The q row of kv row ``zi``'s query head ``g``."""
+        return (zi // hkv) * h + (zi % hkv) * group + g
+
+    def q_tile(zi, t, *table):
+        _, g, i = walk.tile(t, *table)
+        return (q_row(zi, g), i, 0)
+
+    def dq_tile(zi, t, *table):
+        # the pair written next: the index moves right after each write,
+        # so each block goes to HBM once
+        g, i = walk.flushing(t, *table)
+        return (q_row(zi, g), i, 0)
+
+    kv_tile = lambda zi, t, *table: (zi, walk.tile(t, *table)[0], 0)
     out_specs = [k_spec(bk, kv_tile), v_spec(bk, kv_tile)]
     out_shape = [jax.ShapeDtypeStruct((z_kv, s, d), k.dtype),
                  jax.ShapeDtypeStruct((z_kv, s, dv), v.dtype)]
     scratch_shapes = [pltpu.VMEM((bk, d), f32), pltpu.VMEM((bk, dv), f32)]
-    compiler_params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-    )
     if with_dq:
         out_specs.append(k_spec(bq, dq_tile))
         out_shape.append(jax.ShapeDtypeStruct((z, s, d), q.dtype))
         scratch_shapes.append(pltpu.VMEM((nq * group, d, bq), f32))
-        compiler_params = pltpu.CompilerParams(
-            # dq accumulates across the K tiles too
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=vmem_limit,
-        )
-    dk, dvalues, *dq = pl.pallas_call(
-        kernel_k_outer,
-        grid=(z_kv, nk, nq * group),
-        in_specs=[
-            k_spec(bq, q_tile),
-            k_spec(bk, kv_tile),
-            v_spec(bk, kv_tile),
-            v_spec(bq, q_tile),         # do
-            stat_spec(q_stat),          # lse
-            stat_spec(q_stat),          # delta
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch_shapes,
-        compiler_params=compiler_params,
-        interpret=interpret,
-        name="flash_bwd_dkdv",
-    )(q, k, v, do, lse_r, delta_r)
+    dk, dvalues, *dq = call(
+        kernel_k_outer, "flash_bwd_dkdv", walk, z_kv, q_tile, kv_tile,
+        out_specs, out_shape, scratch_shapes, ("parallel", "arbitrary"),
+        vmem_limit)     # with dq, what the plan states; else nothing
     if with_dq:
         return dq[0], dk, dvalues
-    (dq,) = pl.pallas_call(
-        kernel_dq,
-        grid=(z, nq, nk),
-        in_specs=[
-            k_spec(bq, lambda zi, ii, ji: (zi, ii, 0)),
-            k_spec(bk, lambda zi, ii, ji: (_kv_row(zi, h, hkv), ji, 0)),
-            v_spec(bk, lambda zi, ii, ji: (_kv_row(zi, h, hkv), ji, 0)),
-            v_spec(bq, lambda zi, ii, ji: (zi, ii, 0)),
-            stat_spec(lambda zi, ii, ji: (zi, ii, 0, 0)),
-            stat_spec(lambda zi, ii, ji: (zi, ii, 0, 0)),
-        ],
-        out_specs=[k_spec(bq, lambda zi, ii, ji: (zi, ii, 0))],
+
+    def kernel_dq(*refs):
+        table = refs[:q_columns]
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+         dq_ref, dq_acc) = refs[q_columns:]
+        t = pl.program_id(1)
+        i, j = q_walk.tile(t, *table)
+        first, last, live = q_walk.edges(t, *table)
+
+        @pl.when(first)
+        def _init():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+
+        @pl.when(live)
+        def _compute():
+            _, kb, _, _, ds = _recompute_p_ds(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, j
+            )
+            dq_acc[...] += lax.dot_general(
+                kb, ds, _CONTRACT_ROWS, preferred_element_type=f32,
+            ) * scale                                   # [d, bq]
+
+        @pl.when(last)
+        def _flush():
+            dq_ref[0] = dq_acc[...].T.astype(dq_ref.dtype)
+
+    (dq,) = call(
+        kernel_dq, "flash_bwd_dq", **q_outer,
+        out_specs=[k_spec(bq, q_outer["q_tile"])],
         out_shape=[jax.ShapeDtypeStruct((z, s, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((d, bq), f32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(q, k, v, do, lse_r, delta_r)
+        semantics=("parallel", "arbitrary"))
     return dq, dk, dvalues

@@ -2,7 +2,7 @@
 backward as a plain scan over K tiles (the oracle the Pallas backward is
 pinned to; it lived in ``horovod_tpu/ops/flash_attention.py`` until
 PR 47 and no program called it), and a call's ``FlashPlan`` from folded
-operands or from loose sizes."""
+operands or from loose sizes, and the tile pairs a mask keeps."""
 
 import dataclasses
 
@@ -56,6 +56,24 @@ def flash_bwd_blockwise(q, k, v, o, lse, do, causal, scale, bk,
         unfold(dks).astype(k.dtype),
         unfold(dvs).astype(v.dtype),
     )
+
+
+def live_pairs(seq, bq, bk, window=None, causal=True):
+    """The (Q tile, K tile) pairs a mask keeps, Q tile major, from the
+    distances ``query - key`` between two tiles: every whole number from
+    ``first query - last key`` to ``last query - first key``.  A causal
+    query sees the keys at distances 0 to ``window - 1`` (no window: to
+    the sequence's start); a pair is live if one of its distances is
+    among them."""
+    reach = seq if window is None else window
+    pairs = []
+    for i in range(seq // bq):
+        for j in range(seq // bk):
+            nearest = i * bq - ((j + 1) * bk - 1)
+            farthest = (i + 1) * bq - 1 - j * bk
+            if not causal or (farthest >= 0 and nearest <= reach - 1):
+                pairs.append((i, j))
+    return pairs
 
 
 def folded_plan(q, k, v, causal, bq, bk, h=1, hkv=1, window=None):

@@ -16,7 +16,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flash_oracle import (flash_bwd_blockwise, folded_plan, force_form,
-                          plan_of, traced_calls)
+                          live_pairs, plan_of, traced_calls)
 from horovod_tpu.models import TransformerConfig, gpt
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel import local_attention
@@ -767,7 +767,8 @@ def test_backward_path_follows_the_shape(shape, kv_heads, dtype, scale, form):
 
 # (id, the limit and the ceiling the gate reads, form, gauge
 # flash.bwd_kernels, whether the plan keeps dq resident and the MiB it
-# states, the backward's names and grids in a layer) at 64 keys of 16
+# states, the backward's names and grids in a layer: a row of heads by the
+# 6 live tiles of the 2 x 4 a head's mask holds) at 64 keys of 16
 # channels in
 # float32 and 32 x 16 tiles, where dk and dv resident count 334 KiB and dq
 # resident 204: the limit as it stands; one that only dq fits; none, with
@@ -776,13 +777,13 @@ def test_backward_path_follows_the_shape(shape, kv_heads, dtype, scale, form):
 _DQ_FITS = "what dq resident counts"
 _GAUGE_CASES = [
     ("dkdv_resident", None, None, "dkdv_resident", 1, 0, 32,
-     [("flash_bwd_dkdv", (8, 2, 4))]),
+     [("flash_bwd_dkdv", (8, 6))]),
     ("dq_resident", _DQ_FITS, None, "dq_resident", 1, 1, 1,
-     [("flash_bwd_dkdv", (8, 4, 2))]),
+     [("flash_bwd_dkdv", (8, 6))]),
     ("over_the_limit_the_smaller_count", 0, 48 * 2 ** 20, "dq_resident",
-     1, 1, 1, [("flash_bwd_dkdv", (8, 4, 2))]),
+     1, 1, 1, [("flash_bwd_dkdv", (8, 6))]),
     ("two_passes", 0, 0, "two_passes", 2, 0, 0,
-     [("flash_bwd_dkdv", (8, 4, 2)), ("flash_bwd_dq", (8, 2, 4))]),
+     [("flash_bwd_dkdv", (8, 6)), ("flash_bwd_dq", (8, 6))]),
 ]
 
 
@@ -823,7 +824,7 @@ def test_the_gauges_say_which_backward_the_step_holds(
         lambda p: model.apply(p, toks).sum()))(params)
     calls = list(_pallas_calls(
         jaxpr.jaxpr, lambda p: (p["name"], tuple(p["grid_mapping"].grid))))
-    assert calls == [("flash_fwd", (8, 2, 4))] * 2 + backward * 2
+    assert calls == [("flash_fwd", (8, 6))] * 2 + backward * 2
     # one plan, made twice a layer: for the gauges and for the kernels
     (shapes, plan), = set(traced)
     assert len(traced) == 4
@@ -1078,9 +1079,11 @@ def test_a_call_with_values_as_wide_as_keys_is_the_program_it_was():
     """granite's call (32 query over 8 key/value heads of 64 at 8192
     tokens, bfloat16): the ``pallas_call``s of the differentiated jaxpr,
     listed as PR 38's tree made them but for the forward's K and V
-    blocks, whole kv rows since PR 46.  The five cells that send
-    ``dv == d`` run this program; only the value width of a call that
-    has one moves a block, a scratch buffer or an output."""
+    blocks, whole kv rows since PR 46, and for the grids, which since
+    PR 49 walk a head's 272 live tiles of 16 x 32 from a table of three
+    int32 columns in SMEM, the calls' first operands.  The five cells
+    that send ``dv == d`` run this program; only the value width of a
+    call that has one moves a block, a scratch buffer or an output."""
     q = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, 8192, 8, 64), jnp.bfloat16)
 
@@ -1095,16 +1098,17 @@ def test_a_call_with_values_as_wide_as_keys_is_the_program_it_was():
     stat = "Ref{float32[1,1,1,512]}"
     vmem = lambda *shape: "Ref<vmem>{float32[%s]}" % ",".join(map(str, shape))
     arr = lambda *shape: "bfloat16[%s]" % ",".join(map(str, shape))
+    table = ["Ref<smem>{int32[272]}"] * 3
 
     def listed(dv):
         return [
-            ("flash_fwd", (32, 16, 32),
-             [bf(1, 512, 64), bf(1, 8192, 64), bf(1, 8192, dv),
+            ("flash_fwd", (32, 272),
+             table + [bf(1, 512, 64), bf(1, 8192, 64), bf(1, 8192, dv),
               bf(1, 512, dv), stat,
               vmem(dv, 512), vmem(1, 512), vmem(1, 512)],
              [arr(32, 8192, dv), "float32[32,16,1,512]"], None),
-            ("flash_bwd_dkdv", (32, 16, 32),
-             [bf(1, 512, 64), bf(1, 256, 64), bf(1, 256, dv),
+            ("flash_bwd_dkdv", (32, 272),
+             table + [bf(1, 512, 64), bf(1, 256, 64), bf(1, 256, dv),
               bf(1, 512, dv), stat, stat,
               bf(1, 512, 64), bf(1, 8192, 64), bf(1, 8192, dv),
               vmem(64, 512), vmem(8192, 64), vmem(8192, dv)],
@@ -1144,28 +1148,31 @@ def test_every_cells_call_keeps_its_form_and_the_vmem_it_states(
         shape, kv_heads, dv, form, mib, window):
     """The one backward ``pallas_call`` of each cell's attention shape,
     full and banded, read from the differentiated jaxpr: its grid says
-    the form (Q tile outermost ``(z, nq, nk)``, K tile outermost ``(z_kv,
-    nk, nq * group)``) and its params the ``vmem_limit_bytes``.  A call
-    that fit 32 MiB before PR 44 states those 32 MiB still, so its
-    lowered text, and with it the compile cache's key, is what it was."""
+    the form (Q tile outermost ``(z, live)``, a head's live tiles; K
+    tile outermost ``(z_kv, live * group)``) and its params the
+    ``vmem_limit_bytes``.  A call that fit 32 MiB before PR 44 states
+    those 32 MiB still: the table changes the grid and adds the
+    prefetched columns, never the VMEM a call states."""
     from horovod_tpu.ops import flash_attention as fa
 
     b, s, h, d = shape
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     k = jax.ShapeDtypeStruct((b, s, kv_heads, d), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((b, s, kv_heads, dv), jnp.bfloat16)
-    group, nq, nk = h // kv_heads, s // 512, s // 256
+    group, live = h // kv_heads, len(live_pairs(s, 512, 256, window))
     plan = fa.flash_plan(q, k, v, causal=True, window=window)
     assert (plan.bwd_form, plan.bwd_vmem_bytes) == (form, mib * 2 ** 20)
+    assert (plan.tiles_grid, plan.tiles_mask) == (
+        b * h * live, b * h * (s // 512) * (s // 256))
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda *a: flash_attention(
             *a, causal=True, window=window, interpret=True
         ).astype(jnp.float32).sum(), argnums=(0, 1, 2)))(q, k, v)
     calls = list(_pallas_calls(jaxpr.jaxpr, lambda p: (
         p["name"], tuple(p["grid_mapping"].grid), _stated_vmem(p))))
-    grid = ((b * h, nq, nk) if form == "dkdv_resident"
-            else (b * kv_heads, nk, nq * group))
-    assert calls == [("flash_fwd", (b * h, nq, nk),
+    grid = ((b * h, live) if form == "dkdv_resident"
+            else (b * kv_heads, live * group))
+    assert calls == [("flash_fwd", (b * h, live),
                       plan.fwd_vmem_bytes or None),
                      ("flash_bwd_dkdv", grid, mib * 2 ** 20)]
 
@@ -1345,8 +1352,8 @@ def test_the_gauges_say_which_forward_the_step_holds(
     jaxpr = jax.make_jaxpr(lambda p: model.apply(p, toks))(params)
     calls = list(_pallas_calls(jaxpr.jaxpr, lambda p: (
         p["name"], tuple(p["grid_mapping"].grid)) + _forward_call(p)))
-    # the grid is whole in either form
-    assert calls == [("flash_fwd", (8, 2, 4), rows, plan[1] or None)] * 2
+    # the grid walks the 6 live tiles of a head's 2 x 4 in either form
+    assert calls == [("flash_fwd", (8, 6), rows, plan[1] or None)] * 2
     (_, traced), = set(asked)
     assert len(asked) == 4
     assert [int(traced.fwd_kv_resident),
@@ -1359,3 +1366,306 @@ def test_local_attention_refuses_a_window_it_cannot_mean():
         local_attention(q, k, v, causal=False, window=8)
     with pytest.raises(ValueError, match="window >= 1"):
         local_attention(q, k, v, causal=True, window=0)
+
+
+# --------------------------- the grids walk the live tiles alone (PR 49)
+# A head's live (Q tile, K tile) pairs come from a table the kernels
+# prefetch to SMEM, not from two grid axes and a predicate.  Tiles 32 x 16
+# over 96 keys (3 x 6 a head): no mask, the causal half, and windows under
+# both tiles, of a K tile exactly, and a multiple of neither.
+_WALK_SEQ, _WALK_BQ, _WALK_BK, _WALK_D = 96, 32, 16, 16
+_WALK_MASKS = [
+    ("noncausal", False, None),
+    ("causal", True, None),
+    ("window_8_under_the_tiles", True, 8),
+    ("window_16_a_k_tile", True, 16),
+    ("window_20_no_multiple", True, 20),
+]
+# (id, query heads, key/value heads, value width)
+_WALK_HEADS = [
+    ("h_is_hkv", 2, 2, 16),
+    ("grouped_3_values_32", 6, 2, 32),
+]
+_WALK_FORMS = ["dkdv_resident", "dq_resident", "two_passes"]
+# sha256[:16] over o, lse, dq, dk, dv of the PARENT's kernels (commit
+# 9ced719, the whole nq x nk rectangle under a ``needed`` predicate) on
+# ``_walk_inputs`` in float32, by (mask, heads) and, in ``_WALK_FORMS``'
+# order, backward form: made by running ``_walk_results`` with the
+# parent's package on the path.  The
+# bfloat16 cases pin none (a bfloat16 result's last bit is the host CPU's:
+# PR 48); they are held, as every case is, to this tree's own rectangle
+# (``live_tiles=None``), which is the parent's walk, in this process.
+_WALK_PARENT_DIGESTS = {
+    ("noncausal", "h_is_hkv"): (
+        "76aa08ded2c9e1a3", "76aa08ded2c9e1a3", "76aa08ded2c9e1a3"),
+    ("noncausal", "grouped_3_values_32"): (
+        "bea937c82125c187", "bea937c82125c187", "bea937c82125c187"),
+    ("causal", "h_is_hkv"): (
+        "c5e141324daaddf7", "c5e141324daaddf7", "c5e141324daaddf7"),
+    ("causal", "grouped_3_values_32"): (
+        "d7227a652c7734c1", "d7227a652c7734c1", "d7227a652c7734c1"),
+    ("window_8_under_the_tiles", "h_is_hkv"): (
+        "6dbf65d7680af580", "6dbf65d7680af580", "6dbf65d7680af580"),
+    ("window_8_under_the_tiles", "grouped_3_values_32"): (
+        "87d28254e926a078", "87d28254e926a078", "87d28254e926a078"),
+    ("window_16_a_k_tile", "h_is_hkv"): (
+        "343f8130bd91f4da", "343f8130bd91f4da", "343f8130bd91f4da"),
+    ("window_16_a_k_tile", "grouped_3_values_32"): (
+        "44afd7410a17617b", "44afd7410a17617b", "44afd7410a17617b"),
+    ("window_20_no_multiple", "h_is_hkv"): (
+        "cc45cc91adb5148d", "cc45cc91adb5148d", "cc45cc91adb5148d"),
+    ("window_20_no_multiple", "grouped_3_values_32"): (
+        "5975c32a82d5fa3d", "5975c32a82d5fa3d", "5975c32a82d5fa3d"),
+}
+
+
+def _walk_inputs(h, hkv, dv, dtype):
+    rng = np.random.RandomState(49)
+    mk = lambda heads, width: jnp.asarray(
+        rng.randn(2 * heads, _WALK_SEQ, width) * 0.7, dtype)
+    return mk(h, _WALK_D), mk(hkv, _WALK_D), mk(hkv, dv), mk(h, dv)
+
+
+def _walk_results(plan, q, k, v, do):
+    """o, lse, dq, dk, dv of the kernels under ``plan``, folded."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    scale = _WALK_D ** -0.5
+
+    @jax.jit
+    def run(q, k, v, do):
+        o, lse = fa._flash_fwd_kernel(q, k, v, plan, scale, True)
+        return (o, lse) + tuple(fa._flash_bwd_pallas(
+            q, k, v, o, lse, do, plan, scale, True))
+
+    return run(q, k, v, do)
+
+
+def _walk_digest(results):
+    import hashlib
+
+    sha = hashlib.sha256()
+    for a in results:
+        sha.update(np.asarray(a).tobytes())
+    return sha.hexdigest()[:16]
+
+
+def _walk_plan(causal, window, h, hkv, dv, dtype, form):
+    from dataclasses import replace
+
+    q, k, v, _ = (jax.ShapeDtypeStruct(x.shape, x.dtype)
+                  for x in _walk_inputs(h, hkv, dv, dtype))
+    plan = folded_plan(q, k, v, causal, _WALK_BQ, _WALK_BK, h, hkv, window)
+    assert (plan.bwd_form, plan.fwd_kv_resident) == ("dkdv_resident", True)
+    return replace(plan, bwd_form=form,
+                   bwd_vmem_bytes=0 if form == "two_passes"
+                   else plan.bwd_vmem_bytes)
+
+
+@pytest.mark.parametrize("form", _WALK_FORMS)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("h,hkv,dv", [c[1:] for c in _WALK_HEADS],
+                         ids=[c[0] for c in _WALK_HEADS])
+@pytest.mark.parametrize("causal,window", [c[1:] for c in _WALK_MASKS],
+                         ids=[c[0] for c in _WALK_MASKS])
+def test_the_table_walk_is_the_rectangle_to_the_bit(
+        request, causal, window, h, hkv, dv, dtype, form):
+    """Forward and every backward form over the table of live tiles
+    against the same kernels over the whole rectangle under the predicate
+    (``live_tiles=None``: what a table past the SMEM limit falls back to,
+    and what the parent ran): a dead step added nothing, so ``o``,
+    ``lse``, ``dq``, ``dk``, ``dv`` are equal to the bit, streamed
+    forward and resident alike; in float32 equal to the digest pinned
+    from the parent's kernels; and within the standing tolerances of the
+    plain attention and the blockwise scan."""
+    from dataclasses import replace
+
+    plan = _walk_plan(causal, window, h, hkv, dv, dtype, form)
+    q, k, v, do = _walk_inputs(h, hkv, dv, dtype)
+    assert plan.live_tiles is not None
+    assert plan.tiles_grid == plan.tiles_live == \
+        2 * h * len(plan.live_tiles)
+    table = _walk_results(plan, q, k, v, do)
+    others = {"the rectangle": replace(plan, live_tiles=None)}
+    if form == _WALK_FORMS[0]:   # the forward knows no backward form
+        others["the streamed forward's table"] = replace(
+            plan, fwd_kv_resident=False)
+    names = ("o", "lse", "dq", "dk", "dv")
+    for which, other in others.items():
+        for name, a, r in zip(names, table,
+                              _walk_results(other, q, k, v, do)):
+            assert a.dtype == r.dtype and a.shape == r.shape, name
+            assert np.asarray(a).tobytes() == np.asarray(r).tobytes(), (
+                f"{name}: the table against {which}")
+    mask, heads = request.node.callspec.id.split("-")[:2]
+    if dtype == jnp.float32:
+        assert _walk_digest(table) == _WALK_PARENT_DIGESTS[mask, heads][
+            _WALK_FORMS.index(form)], "the parent's kernels, to the bit"
+    scale = _WALK_D ** -0.5
+    unfold = lambda x, heads: x.reshape(2, heads, _WALK_SEQ, -1).transpose(
+        0, 2, 1, 3).astype(jnp.float32)
+    rep = lambda x: jnp.repeat(unfold(x, hkv), h // hkv, axis=2)
+    want = local_attention(unfold(q, h), rep(k), rep(v), causal=causal,
+                           window=window)
+    err = np.abs(np.asarray(unfold(table[0], h)) - np.asarray(want)).max()
+    assert err <= (2e-5 if dtype == jnp.float32 else 3e-2), err
+    ref = _grouped_blockwise(q, k, v, table[0], table[1], do, causal, scale,
+                             _WALK_BK, window, h, hkv)
+    tol = 2e-6 if dtype == jnp.float32 else 1e-2
+    for name, a, r in zip(names[2:], table[2:], ref):
+        got, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
+        assert np.abs(got - r).max() <= tol * np.abs(r).max(), name
+
+
+def _dense_live_pairs(seq, bq, bk, causal, window):
+    """A mask's live tile pairs from the positions themselves."""
+    qp, kp = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    sees = np.ones((seq, seq), bool)
+    if causal:
+        sees = kp <= qp
+        if window is not None:
+            sees &= kp >= qp - (window - 1)
+    tiles = sees.reshape(seq // bq, bq, seq // bk, bk).any((1, 3))
+    return [(int(i), int(j)) for i, j in zip(*np.nonzero(tiles))]
+
+
+# (id, S, block_q, block_k, causal, window, query heads a key/value head)
+_TABLE_CASES = [
+    (mask + "_group_%d" % group, _WALK_SEQ, _WALK_BQ, _WALK_BK, causal,
+     window, group)
+    for mask, causal, window in _WALK_MASKS for group in (1, 3)
+] + [
+    ("window_72_of_256_tiles_64x32_group_8", 256, 64, 32, True, 72, 8),
+    ("s_is_window_plus_1_group_8", 128, 64, 32, True, 127, 8),
+    ("tiles_16x16_window_of_one_tile", 64, 16, 16, True, 16, 2),
+    ("k_tiles_wider_than_q_tiles", 64, 8, 32, True, 20, 2),
+    # the cells' calls at 512 x 256: Trinity's band, Phi's, SmallThinker's
+    # band and triangle, LFM2's triangle
+    ("trinitym_8192_window_2048_group_8", 8192, 512, 256, True, 2048, 8),
+    ("phi4mf_8192_window_512_group_2", 8192, 512, 256, True, 512, 2),
+    ("smallthinker_16384_window_4096_group_7", 16384, 512, 256, True, 4096,
+     7),
+    ("smallthinker_16384_full_group_7", 16384, 512, 256, True, None, 7),
+    ("lfm2_32768_full_group_4", 32768, 512, 256, True, None, 4),
+]
+
+
+@pytest.mark.parametrize("seq,bq,bk,causal,window,group",
+                         [c[1:] for c in _TABLE_CASES],
+                         ids=[c[0] for c in _TABLE_CASES])
+def test_the_table_holds_the_live_tiles_once_in_walk_order(
+        seq, bq, bk, causal, window, group):
+    """The plan's table is exactly the pairs the mask keeps (from the
+    positions themselves at small sizes, from the tiles' distances at the
+    cells'), each once, Q tile major with K tiles ascending: the order
+    the rectangle walked them; ``len(table) * batch * heads`` is
+    ``tiles_live`` and ``tiles_grid``, the rectangle ``tiles_mask``; and
+    the Q-major columns mark each Q row's first and last live tile."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    plan = plan_of(seq, 64, group, 2, bq, bk, causal=causal, window=window,
+                   rows=2)
+    pairs = live_pairs(seq, bq, bk, window, causal)
+    if seq <= 256:
+        assert pairs == _dense_live_pairs(seq, bq, bk, causal, window)
+    assert list(plan.live_tiles) == pairs == sorted(set(pairs))
+    assert all(fa._tile_live(i, j, bq, bk, causal, plan.window)
+               for i, j in pairs)
+    heads = 2 * group
+    assert plan.tiles_live == plan.tiles_grid == heads * len(pairs)
+    assert plan.tiles_mask == heads * (seq // bq) * (seq // bk)
+    qi, kj, edges = fa._q_major_table(plan.live_tiles)
+    assert list(zip(qi.tolist(), kj.tolist())) == pairs
+    assert qi.dtype == kj.dtype == edges.dtype == np.int32
+    for t, (i, j) in enumerate(pairs):
+        row = [jj for ii, jj in pairs if ii == i] if seq <= 256 else None
+        first = t == 0 or pairs[t - 1][0] != i
+        last = t == len(pairs) - 1 or pairs[t + 1][0] != i
+        assert edges[t] == first + 2 * last, (t, i, j)
+        if row:
+            assert (first, last) == (j == row[0], j == row[-1])
+
+
+@pytest.mark.parametrize("seq,bq,bk,causal,window,group",
+                         [c[1:] for c in _TABLE_CASES],
+                         ids=[c[0] for c in _TABLE_CASES])
+def test_the_k_major_table_writes_each_dq_block_at_its_last_live_k_tile(
+        seq, bq, bk, causal, window, group):
+    """The K-outermost kernel's table: for each K tile in turn, for each
+    query head of the group, the Q tiles that see it, Q tiles ascending
+    (the order the rectangle walked them, so dk and dv sum in the
+    parent's order).  dk and dv's accumulators open on a K tile's first
+    step and close on its last; a ``(g, i)`` pair's dq opens on its first
+    live K tile and is written on its LAST (the rectangle wrote at ``j ==
+    nk - 1``, which under a mask most pairs never reach live); and dq's
+    block index, the pair written next, holds still up to each write and
+    moves right after it, so a block is one run of steps and goes to HBM
+    once."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    nq = seq // bq
+    plan = plan_of(seq, 64, group, 2, bq, bk, causal=causal, window=window)
+    pairs = set(plan.live_tiles)
+    kj, qg, qi, edges, fg, fi = fa._k_major_table(plan.live_tiles, nq, group)
+    steps = list(zip(kj.tolist(), qg.tolist(), qi.tolist()))
+    assert steps == [(j, g, i) for j in range(seq // bk)
+                     for g in range(group)
+                     for i in range(nq) if (i, j) in pairs]
+    assert len(steps) == group * len(pairs) == len(set(steps))
+    first_j, last_j = {}, {}
+    for i, j in sorted(pairs):
+        first_j.setdefault(i, j)
+        last_j[i] = j
+    total = len(steps)
+    for t, (j, g, i) in enumerate(steps):
+        assert edges[t] == (
+            (t == 0 or steps[t - 1][0] != j)
+            + 2 * (t == total - 1 or steps[t + 1][0] != j)
+            + 4 * (j == first_j[i]) + 8 * (j == last_j[i])), (t, j, g, i)
+    closing = [t for t in range(total) if edges[t] & 8]
+    assert sorted((steps[t][1], steps[t][2]) for t in closing) == [
+        (g, i) for g in range(group) for i in range(nq)]
+    assert closing[-1] == total - 1
+    blocks = list(zip(fg.tolist(), fi.tolist()))
+    start = 0
+    for t in closing:   # each write ends the run of its own block index
+        assert set(blocks[start:t + 1]) == {steps[t][1:]}, t
+        start = t + 1
+
+
+def test_a_table_past_the_smem_limit_keeps_the_rectangle(monkeypatch):
+    """The plan says from the shape whether the call's largest table (the
+    forward's three columns, the K-outermost backward's six a query head
+    of the group) fits ``_TILE_TABLE_SMEM_LIMIT``: every cell's call does,
+    LFM2's 16 640 steps the largest at 390 KiB; 131 072 keys in two
+    passes do not, and keep the rectangle, as any call does with the
+    limit at nothing."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    assert fa._TILE_TABLE_SMEM_LIMIT == 512 * 2 ** 10
+    lfm2 = plan_of(32768, 64, 4, rows=8)
+    assert lfm2.bwd_form == "dq_resident"
+    assert 4 * fa._k_major_table(lfm2.live_tiles, 64, 4).size == 399_360
+    for seq, d, group, window in [(1024, 64, 1, None), (8192, 64, 4, None),
+                                  (8192, 256, 1, None), (8192, 128, 8, 2048),
+                                  (8192, 64, 2, 512), (16384, 128, 7, 4096),
+                                  (16384, 128, 7, None)]:
+        plan = plan_of(seq, d, group, window=window)
+        assert plan.live_tiles and plan.tiles_grid == plan.tiles_live
+    long = plan_of(131072, 128, 2)
+    assert (long.bwd_form, long.live_tiles) == ("two_passes", None)
+    assert long.tiles_grid == long.tiles_mask == 2 * 256 * 512
+    assert long.tiles_live == 2 * 65792
+    monkeypatch.setattr(fa, "_TILE_TABLE_SMEM_LIMIT", 0)
+    small = plan_of(64, 16, 1, 4, 32, 16)
+    assert (small.live_tiles, small.tiles_live, small.tiles_grid,
+            small.tiles_mask) == (None, 6, 8, 8)
+    q, k, v = _qkv(b=1, s=64, h=2, d=16)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: flash_attention(
+        *a, causal=True, block_q=32, block_k=16).sum(),
+        argnums=(0, 1, 2)))(q, k, v)
+    assert list(_pallas_calls(jaxpr.jaxpr, lambda p: (
+        p["name"], tuple(p["grid_mapping"].grid),
+        p["grid_mapping"].num_index_operands))) == [
+            ("flash_fwd", (2, 8), 0), ("flash_bwd_dkdv", (2, 8), 0)]

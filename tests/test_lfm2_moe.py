@@ -377,8 +377,12 @@ def test_the_cells_flash_call_streams_forward_and_holds_dq_backward():
         "dq_resident", 37 * 2 ** 20, 1)
     assert plan.bwd_vmem_bytes == 38_797_312
     assert (plan.heads, plan.kv_heads, plan.window) == (32, 8, None)
-    # a head's grid of 64 x 128 tiles, the causal half and its diagonal
-    assert (plan.tiles_live, plan.tiles_grid) == (32 * 4160, 32 * 8192)
+    # of a head's 64 x 128 tiles the causal half and its diagonal, which
+    # is all the grid walks since PR 49: the K-outermost table, six
+    # columns a step, four query heads a group, is 390 KiB of SMEM
+    assert (plan.tiles_live, plan.tiles_mask) == (32 * 4160, 32 * 8192)
+    assert plan.tiles_grid == plan.tiles_live
+    assert len(plan.live_tiles) == 4160
     half = plan_of(16384, 64, 4, 2, rows=8)
     assert (half.fwd_kv_resident, half.bwd_form, half.bwd_vmem_bytes) == (
         True, "dq_resident", 32 * 2 ** 20)

@@ -182,8 +182,11 @@ def test_the_new_layers_carry_their_scopes_and_gauges(monkeypatch):
     # sub-heads, not four times the pairs), values as wide as a pair
     rows = 2 * 8
     for kind in ("sliding_attention", "full_attention", "cross_attention"):
-        assert registry.gauge("flash.tiles_grid", layer_type=kind).value \
+        assert registry.gauge("flash.tiles_mask", layer_type=kind).value \
             == rows * (SEQ // 16) * (SEQ // 4)
+        # the grid walks the live tiles alone (PR 49)
+        assert registry.gauge("flash.tiles_grid", layer_type=kind).value \
+            == registry.gauge("flash.tiles_live", layer_type=kind).value
     # every differential call's values, 2 x 8 wide on keys of 8
     assert {(q[3], k[3], v[3]) for (q, k, v), _ in calls} == {(8, 8, 16)}
     assert registry.gauge("flash.tiles_live",
@@ -226,11 +229,15 @@ def test_a_differential_layer_is_one_flash_call_at_the_pairs_shape(
     calls = [e.params for e in equations
              if e.primitive.name == "pallas_call"]
     assert [c["name"] for c in calls] == ["flash_fwd"]
-    blocks = [v.aval.shape for v in calls[0]["jaxpr"].invars[:4]]
-    # q, k, v, o: since PR 46 a kv row's K and V whole, fetched once a row
+    # after the table's three columns (PR 49) q, k, v, o: since PR 46 a
+    # kv row's K and V whole, fetched once a row
+    blocks = [v.aval.shape for v in calls[0]["jaxpr"].invars[3:7]]
     assert blocks == [(1, 512, 64), (1, 8192, 64), (1, 8192, 128),
                       (1, 512, 128)]
-    assert tuple(calls[0]["grid_mapping"].grid) == (40, 16, 32)
+    # 40 rows by a head's live tiles of 16 x 32 (PR 49: the rectangle
+    # before it)
+    assert tuple(calls[0]["grid_mapping"].grid) == (
+        40, 62 if kind == "sliding_attention" else 272)
     assert [a.shape for a in calls[0]["out_avals"]][0] == (40, 8192, 128)
     joins = [len(e.invars) for e in equations
              if e.primitive.name == "concatenate"]
@@ -240,16 +247,17 @@ def test_a_differential_layer_is_one_flash_call_at_the_pairs_shape(
     from horovod_tpu.obs.registry import get_registry
 
     gauge = lambda name: get_registry().gauge(name, layer_type=kind).value
-    assert gauge("flash.tiles_grid") == 20480
-    assert gauge("flash.tiles_live") == (
+    assert gauge("flash.tiles_mask") == 20480
+    assert gauge("flash.tiles_live") == gauge("flash.tiles_grid") == (
         2480 if kind == "sliding_attention" else 10880)
     # and the plan of that call: values of 128 on keys of 64, the kv row
     # resident, nothing stated
     ((q, k, v), plan), = set(asked)
     assert (q[3], k[3], v[3]) == (64, 64, 128)
     assert (plan.fwd_kv_resident, plan.fwd_vmem_bytes) == (True, 0)
-    assert (plan.tiles_live, plan.tiles_grid) == (
-        gauge("flash.tiles_live"), gauge("flash.tiles_grid"))
+    assert (plan.tiles_live, plan.tiles_grid, plan.tiles_mask) == (
+        gauge("flash.tiles_live"), gauge("flash.tiles_grid"),
+        gauge("flash.tiles_mask"))
 
 
 # ------------------------------------------------------------- refusals

@@ -573,11 +573,14 @@ def test_the_backward_at_16384_keys_is_one_kernel():
     assert plan_of(8192, 128, 7, 2).bwd_form == "dkdv_resident"
     assert backward(plan_of(8192, 128, 7, 2)) == (
         "dkdv_resident", 32 * 2 ** 20)
-    # a head's grid of 32 x 64 tiles: the full layer's causal half and
-    # the band of a 4096-key window
-    tiles = lambda plan: (plan.tiles_live, plan.tiles_grid)
+    # of a head's 32 x 64 tiles the full layer's causal half and the
+    # band of a 4096-key window, and the grid walks those alone (PR 49)
+    tiles = lambda plan: (plan.tiles_live, plan.tiles_mask)
     assert tiles(plan_of(16384, 128)) == (1056, 2048)
     assert tiles(plan_of(16384, 128, window=4096)) == (504, 2048)
+    for plan in (plan_of(16384, 128, 7), plan_of(16384, 128, 7,
+                                                 window=4096)):
+        assert plan.tiles_grid == plan.tiles_live == 7 * len(plan.live_tiles)
 
 
 @pytest.mark.parametrize("window", [None, 20], ids=["full", "banded"])

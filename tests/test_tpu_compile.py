@@ -285,6 +285,83 @@ def test_flash_forward_form_compiles_for_v5e(one_chip, shape, kv_heads,
                        f'"size":"{mib * 2 ** 20}"}}'] if mib else [""])
 
 
+# (id, q shape [B,S,H,D], kv heads, window, the backward's form, a head's
+# live tiles, the table's columns forward and backward): the two cells
+# ISSUE 49 claims in.  LFM2's K-outermost table is the largest of any
+# cell, 6 x 16 640 int32 (390 KiB of SMEM).
+_TILE_TABLES = [
+    ("lfm2_32on8x32768x64", (1, 32768, 32, 64), 8, None, "dq_resident",
+     4160, 3, 6),
+    ("smallthinker_28on4x16384x128_window", (1, 16384, 28, 128), 4, 4096,
+     "dkdv_resident", 504, 3, 3),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,kv_heads,window,form,live,fwd_columns,bwd_columns",
+    [c[1:] for c in _TILE_TABLES], ids=[c[0] for c in _TILE_TABLES])
+def test_flash_tile_table_compiles_for_v5e(one_chip, shape, kv_heads, window,
+                                           form, live, fwd_columns,
+                                           bwd_columns):
+    """The grids walk a table of the live tiles (PR 49): the forward and
+    the one backward kernel of the two longest cells take the table's
+    int32 columns as scalar-prefetch operands, their grids are ``(rows,
+    steps)`` with the live tiles alone for steps, and the TPU compiler
+    takes table and kernel inside the VMEM the call states, which is what
+    the plan stated before there was a table."""
+    import re
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    b, s, h, d = shape
+    group = h // kv_heads
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, kv_heads, d), jnp.bfloat16,
+                              sharding=one_chip)
+    plan = fa.flash_plan(q, kv, kv, causal=True, window=window)
+    assert (plan.bwd_form, len(plan.live_tiles)) == (form, live)
+    assert plan.tiles_grid == plan.tiles_live == b * h * live
+
+    def backward(q, k, v):
+        return jax.grad(
+            lambda *a: flash_attention(
+                *a, causal=True, window=window,
+                interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                mapping = eqn.params["grid_mapping"]
+                yield (eqn.params["name"], tuple(mapping.grid),
+                       mapping.num_index_operands)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    steps = live if form == "dkdv_resident" else live * group
+    assert list(calls(jax.make_jaxpr(backward)(q, kv, kv).jaxpr)) == [
+        ("flash_fwd", (b * h, live), fwd_columns),
+        ("flash_bwd_dkdv",
+         (b * h if form == "dkdv_resident" else b * kv_heads, steps),
+         bwd_columns)]
+    text = jax.jit(backward).lower(q, kv, kv).compile().as_text()
+    for name, columns, tensors, extent, stated in (
+            ("flash_fwd", fwd_columns, 3, live, plan.fwd_vmem_bytes),
+            ("flash_bwd_dkdv", bwd_columns, 6, steps, plan.bwd_vmem_bytes)):
+        (line,) = [l for l in text.splitlines()
+                   if "custom-call(" in l and name in l.split("(")[0]]
+        # the table's columns before q, k, v (do, lse, delta)
+        operands = line.split("custom-call(")[1].split(")")[0].split(", ")
+        assert len(operands) == columns + tensors, (name, operands)
+        assert f"s32[{extent}]" in text, name
+        sizes = re.findall(
+            r'"scoped_memory_configs":\[\{[^\]]*"size":"(\d+)"', line)
+        # inside a whole program a call that states nothing shows the
+        # compiler's default
+        assert sizes == [str(stated or fa._DEFAULT_SCOPED_VMEM)], (
+            name, sizes)
+
+
 def test_grouped_expert_matmuls_compile_for_v5e(one_chip):
     """The dropless expert layer's grouped feed-forward at
     glm47f_train_s8192's shape (8192 tokens x 4 choices = 32768 rows of

@@ -373,19 +373,23 @@ def test_the_window_layers_carry_their_scopes_and_tile_counts():
     assert "block2/attn/attn_window" not in text
     registry = get_registry()
     rows = 2 * 8
-    grid = rows * (SEQ // 16) * (SEQ // 4)
+    mask = rows * (SEQ // 16) * (SEQ // 4)
     live = {kind: registry.gauge("flash.tiles_live", layer_type=kind).value
             for kind in set(KINDS)}
     for kind in set(KINDS):
+        assert registry.gauge("flash.tiles_mask",
+                              layer_type=kind).value == mask
+        # the grid walks the live tiles alone (PR 49)
         assert registry.gauge("flash.tiles_grid",
-                              layer_type=kind).value == grid
+                              layer_type=kind).value == live[kind]
     # q tile 0 sees k tiles 0-3; q tile 1 (rows 16-31) k tiles 4-7 and,
     # in a window layer, of the earlier ones only those that hold keys
     # 9.. (tiles 2 and 3): 4 + 6 against 4 + 8
     assert live["full_attention"] == rows * 12
     assert live["sliding_attention"] == rows * 10
-    tiles = lambda plan: (plan.tiles_live, plan.tiles_grid)
+    tiles = lambda plan: (plan.tiles_live, plan.tiles_grid,
+                          plan.tiles_mask)
     assert tiles(plan_of(SEQ, 16, 1, 4, 16, 4, window=8, rows=rows)) == (
-        rows * 10, grid)
-    assert tiles(plan_of(8192, 128)) == (272, 512)
-    assert tiles(plan_of(8192, 128, window=2048)) == (140, 512)
+        rows * 10, rows * 10, mask)
+    assert tiles(plan_of(8192, 128)) == (272, 272, 512)
+    assert tiles(plan_of(8192, 128, window=2048)) == (140, 140, 512)
