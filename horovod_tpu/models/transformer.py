@@ -1112,10 +1112,18 @@ class Block(nn.Module):
             """Routed experts that drop nothing, the shared expert
             beside them (parallel/moe.py has the core), by the decision
             made from the layer's input or, without one, from ``h``."""
-            from ..parallel.moe import apply_routing  # noqa: PLC0415
+            from ..obs.registry import get_registry  # noqa: PLC0415
+            from ..parallel.moe import (  # noqa: PLC0415
+                apply_routing, ffn_tile_fill,
+            )
 
             b, s, d = h.shape
             held, ff = cfg.held_experts, cfg.routed_width
+            # set while the step is traced, like flash.tiles_*: the share
+            # of what the six grouped matmuls multiply that is needed
+            get_registry().gauge(
+                "moe.gmm_tile_fill", layer="/".join(self.path)).set(
+                    ffn_tile_fill(d, ff, cfg.dtype))
             stacked = nn.initializers.lecun_normal(batch_axis=(0,))
             x2 = h.reshape(b * s, d)
             if routing is None:

@@ -446,8 +446,15 @@ def _slots(rows, inverse, k: int):
     return _rows(rows, jnp.minimum(inverse, past).reshape(-1, k).T)
 
 
-# (rows, contraction, columns) of a grouped-matmul tile on the chip
-GMM_TILING = (512, 1024, 1024)
+# The rows of a grouped-matmul tile on the chip: ``row_bound`` rounds to
+# it, so every ``[row_bound, .]`` buffer follows it.
+GMM_ROW_TILE = 512
+# What a grouped-matmul call's blocks (each twice: Pallas fetches one
+# while the kernel works on the other) and its float32 accumulator may
+# take of VMEM: three quarters of the 16 MiB the compiler gives a kernel
+# that states no limit (megablox states none), the rest for the kernel's
+# own temporaries (the float32 select where it stores a tile).
+GMM_VMEM_BYTES = 12 * 2 ** 20
 # The expert layer works on this many even shares of the slots
 # (``row_bound``).  2: once the balancing has settled, a layer's held
 # experts get 0.98 to 1.03 shares; on the way there a layer peaked at 1.3
@@ -470,7 +477,7 @@ def row_bound(n: int, top_k: int, held: int, experts: int) -> int:
     slots for ``held`` of ``experts``, a whole number of the grouped
     matmul's row tiles, and never more than the slots; all of them where
     every expert is held."""
-    slots, tile = n * top_k, GMM_TILING[0]
+    slots, tile = n * top_k, GMM_ROW_TILE
     if held >= experts:
         return slots
     share = -(-slots * held * ROW_BOUND_SHARES // experts)
@@ -495,33 +502,100 @@ def _gmm(lhs, rhs, group_sizes, interpret: bool, transpose_rhs=False):
                               preferred_element_type=lhs.dtype)
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm  # noqa: PLC0415
 
-    return gmm(lhs, rhs, group_sizes, lhs.dtype, _tiling(lhs, rhs),
+    tiles = gmm_tiles(*lhs.shape, rhs.shape[1 if transpose_rhs else 2],
+                      lhs.dtype.itemsize)
+    return gmm(lhs, rhs, group_sizes, lhs.dtype, tiles,
                transpose_rhs=transpose_rhs)
 
 
-def _tiling(lhs, rhs):
-    """``GMM_TILING`` for ``lhs [rows, .]`` and matrices ``rhs [held, k,
-    n]``, cut to them; one tiling for a matmul and both its gradients."""
-    return tuple(min(t, d) for t, d in zip(GMM_TILING,
-                                           (lhs.shape[0], *rhs.shape[1:])))
+def _divisors(size: int):
+    """The multiples of 128 (a lane tile) that divide ``size``; where
+    there is none, ``size`` itself up to 1024 (a block as wide as the
+    array, or tiles that the kernel pads and masks)."""
+    return [t for t in range(128, size + 1, 128) if size % t == 0] or [
+        min(size, 1024)]
+
+
+def gmm_tiles(rows: int, k: int, n: int, itemsize: int,
+              weights_out: bool = False):
+    """``(tm, tk, tn)`` of one grouped-matmul call, from its own rows,
+    contraction ``k`` and output width ``n`` and the operands' item size:
+    ``gmm``'s ``[rows, k] x [k, n]`` a group, or under ``weights_out``
+    ``tgmm``'s ``[k, rows] x [rows, n]`` a group (``k`` and ``n`` the
+    matrix's two sides, the rows contracted).  ``tk`` and ``tn`` divide
+    ``k`` and ``n`` (``_divisors``): the kernel rounds a dimension up to
+    whole tiles, and the MXU multiplies the padding (and the VPU masks the
+    last ``k`` tile of both operands).  Of the pairs whose blocks fit
+    ``GMM_VMEM_BYTES`` (``[tm, tk]``, ``[tk, tn]`` and ``[tm, tn]`` twice
+    each, and the output's block once more in float32: ``[tm, tn]``, or
+    ``[tk, tn]`` under ``weights_out``) the one with the largest matrix
+    block ``tk tn``, which has the fewest grid steps and fetches the rows
+    the fewest times; between equals the squarer, so ``(1024, 1024)``
+    wherever 1024 divides both.  ``tm`` is ``GMM_ROW_TILE`` (all the rows
+    where they are fewer)."""
+    tm = min(GMM_ROW_TILE, rows)
+    pairs = [(tk, tn) for tk in _divisors(k) for tn in _divisors(n)
+             if gmm_vmem_bytes(tm, tk, tn, itemsize, weights_out)
+             <= GMM_VMEM_BYTES]
+    return (tm, *max(pairs, key=lambda p: (p[0] * p[1], -abs(p[0] - p[1]))))
+
+
+def gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int,
+                   weights_out: bool = False) -> int:
+    """What ``gmm_tiles`` counts against ``GMM_VMEM_BYTES``: the three
+    blocks twice each and the float32 accumulator of the output's."""
+    return (2 * itemsize * (tm * tk + tk * tn + tm * tn)
+            + 4 * (tk if weights_out else tm) * tn)
+
+
+def ffn_calls(d: int, ff: int):
+    """The six grouped matmuls of one gated expert feed-forward and its
+    gradients as ``(k, n, weights_out)``: ``hidden -> [gate | up]`` and
+    ``ff -> hidden`` forward, the same two transposed for the rows'
+    gradients, and ``tgmm`` for each matrix's."""
+    return ((d, 2 * ff, False), (ff, d, False), (d, ff, False),
+            (2 * ff, d, False), (ff, d, True), (d, 2 * ff, True))
+
+
+def tile_fill(calls, tiles) -> float:
+    """What share of the multiply-adds that the kernels execute for
+    ``calls`` (``ffn_calls``) under ``tiles`` (a ``(tm, tk, tn)`` each) is
+    needed: ``k n`` over ``ceil(k / tk) tk ceil(n / tn) tn``, summed over
+    the calls (so weighted by their operations).  1.0 where every tile
+    divides its call."""
+    up = lambda size, tile: -(-size // tile) * tile
+    return sum(k * n for k, n, _ in calls) / sum(
+        up(k, tk) * up(n, tn) for (k, n, _), (_, tk, tn) in zip(calls, tiles))
+
+
+def ffn_tile_fill(d: int, ff: int, dtype) -> float:
+    """``tile_fill`` of an expert layer of hidden ``d`` and width ``ff``
+    under the tiles its calls get (gauge ``moe.gmm_tile_fill``)."""
+    calls = ffn_calls(d, ff)
+    size = jnp.dtype(dtype).itemsize
+    return tile_fill(calls, [gmm_tiles(GMM_ROW_TILE, k, n, size, out)
+                             for k, n, out in calls])
 
 
 def _gmm_bwd(lhs, rhs, group_sizes, interpret: bool, grad):
     """``_gmm``'s gradients by ``lhs`` and ``rhs``: the same kernel with
     each matrix transposed for the rows, and ``tgmm`` (group ``g``'s
     ``lhs_g^T grad_g``, float32 sums over the group's own row tiles) for
-    the matrices, with the forward call's tiling: the pair that
-    ``megablox.ops.gmm``'s own rule runs (taking the gradients through
-    that rule traces each forward kernel once more, for nothing: a
-    second of a cell's set-up); the stand-in's are ``jax``'s."""
+    the matrices, each with the tiles of its own ``k`` and ``n``
+    (``gmm_tiles``): the pair of kernels that ``megablox.ops.gmm``'s own
+    rule runs (taking the gradients through that rule traces each forward
+    kernel once more, for nothing: a second of a cell's set-up); the
+    stand-in's are ``jax``'s."""
     if interpret:
         return jax.vjp(lambda lhs, rhs: _gmm(lhs, rhs, group_sizes, True),
                        lhs, rhs)[1](grad)
     from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm  # noqa: PLC0415
 
+    tiles = gmm_tiles(*lhs.shape, grad.shape[1], lhs.dtype.itemsize,
+                      weights_out=True)
     return (_gmm(grad, rhs, group_sizes, False, transpose_rhs=True),
-            tgmm(lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype,
-                 _tiling(lhs, rhs), num_actual_groups=rhs.shape[0]))
+            tgmm(lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype, tiles,
+                 num_actual_groups=rhs.shape[0]))
 
 
 # The gate's activation in a gated expert: act(x W_gate) * (x W_up).

@@ -429,7 +429,7 @@ def _small_tiles(monkeypatch, shares):
     """The small model routes 64 tokens x 3: a row tile of 512 holds all
     192 slots and its layers have no branch.  With tiles of 16 rows and
     ``shares`` even shares of 48, the bound is under the slots."""
-    monkeypatch.setattr(moe, "GMM_TILING", (16,) + moe.GMM_TILING[1:])
+    monkeypatch.setattr(moe, "GMM_ROW_TILE", 16)
     monkeypatch.setattr(moe, "ROW_BOUND_SHARES", shares)
 
 
@@ -498,6 +498,22 @@ def test_a_rematerialised_block_keeps_both_sides_of_the_bound_exact(
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
         grads, unbounded[1])
+
+
+def test_the_tile_fill_gauge_is_set_while_the_step_is_traced():
+    """``moe.gmm_tile_fill{layer}``, under the layers' names that
+    ``publish_stats`` uses: every grouped matmul's tiles divide it."""
+    from horovod_tpu.obs.registry import get_registry, reset_registry
+
+    reset_registry()
+    model = small_model()
+    jax.eval_shape(lambda v: model.apply(
+        v, TOKENS[:, :-2], next_tokens=TOKENS[:, 1:-1],
+        mutable=["moe_stats"]), init(model))
+    fills = {m["tags"]["layer"]: m["value"]
+             for m in get_registry().snapshot()
+             if m["name"] == "moe.gmm_tile_fill"}
+    assert fills == {"block1": 1.0, "block2": 1.0, "mtp/block": 1.0}
 
 
 PUBLISHED = dict(

@@ -362,22 +362,39 @@ def test_flash_tile_table_compiles_for_v5e(one_chip, shape, kv_heads, window,
             name, sizes)
 
 
-def test_grouped_expert_matmuls_compile_for_v5e(one_chip):
-    """The dropless expert layer's grouped feed-forward at
-    glm47f_train_s8192's shape (8192 tokens x 4 choices = 32768 rows of
-    2048, 8 held experts of 1536, a ninth group for the rows whose expert
-    lives elsewhere), forward and backward: jax's Pallas grouped matmul,
-    whose grid follows the group sizes (five calls: the first matmul
-    forward, and each matmul's two gradients, ``gmm`` for the rows and
-    ``tgmm`` for the weights), and no ``ragged-dot`` beside it."""
+# (id, rows, hidden, held experts, expert width): the whole slot buffer of
+# the GLM, Trinity and SmallThinker cells, the LFM2 cell's row bound
+_EXPERT_SHAPES = [
+    ("glm47f_train_s8192", 32768, 2048, 8, 1536),
+    ("trinitym_train_s8192", 65536, 2048, 16, 1024),
+    ("smallthinker_train_s16384", 98304, 2560, 16, 768),
+    ("lfm2_train_s32768", 32768, 2048, 8, 1536),
+]
+
+
+@pytest.mark.parametrize("rows,hidden,held,width",
+                         [case[1:] for case in _EXPERT_SHAPES],
+                         ids=[case[0] for case in _EXPERT_SHAPES])
+def test_grouped_expert_matmuls_compile_for_v5e(one_chip, rows, hidden, held,
+                                                width):
+    """The dropless expert layer's grouped feed-forward at each expert
+    cell's shape (glm47f_train_s8192: 8192 tokens x 4 choices = 32768
+    rows of 2048, 8 held experts of 1536, a ninth group for the rows whose
+    expert lives elsewhere), forward and backward: jax's Pallas grouped
+    matmul, whose grid follows the group sizes (five calls: the first
+    matmul forward, and each matmul's two gradients, ``gmm`` for the rows
+    and ``tgmm`` for the weights), and no ``ragged-dot`` beside it.  The
+    chip's compiler accepts the tiles ``gmm_tiles`` gives each call: their
+    blocks fit the VMEM a kernel that states no limit may use."""
     from horovod_tpu.parallel.moe import grouped_ffn
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    args = (shape((32768, 2048), jnp.bfloat16),
-            shape((8, 2048, 3072), jnp.float32),
-            shape((8, 1536, 2048), jnp.float32), shape((9,), jnp.int32))
+    args = (shape((rows, hidden), jnp.bfloat16),
+            shape((held, hidden, 2 * width), jnp.float32),
+            shape((held, width, hidden), jnp.float32),
+            shape((held + 1,), jnp.int32))
 
     def backward(xs, fc1, fc2, sizes):
         return jax.grad(
@@ -388,8 +405,10 @@ def test_grouped_expert_matmuls_compile_for_v5e(one_chip):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 5
     assert "ragged-dot" not in text and "ragged_dot" not in text
-    # the widest temporaries are the [32768, 3072] buffers, 192 MiB each
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+    # the widest temporaries are the [rows, 2 width] buffers, 192 MiB
+    # each at GLM's shape, where the limit is 1 GiB: 16 / 3 of them
+    widest = rows * 2 * width * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * widest // 3
 
 
 def _wide_rows(text, rows):
