@@ -51,12 +51,14 @@ from ..parallel.moe import DEFAULT_GROUP_SIZE as MOE_DEFAULT_GROUP_SIZE
 # and values of the layer ``shared_kv_layer`` names.  ``mamba`` is the
 # Mamba-2 mixer, ``selective_scan`` the Mamba-1 mixer, ``gmu`` a
 # gated memory unit: a gate on the scan output of the layer
-# ``memory_layer`` names, and ``conv`` a gated short convolution: a
-# causal depthwise filter of ``conv_taps`` taps between two gates.
+# ``memory_layer`` names, ``conv`` a gated short convolution: a
+# causal depthwise filter of ``conv_taps`` taps between two gates, and
+# ``kda`` a gated delta rule with a decay per channel of the key
+# (ops/kda.py).
 ATTENTION_LAYER_TYPES = ("attention", "mla", "sliding_attention",
                          "full_attention", "cross_attention")
 LAYER_TYPES = ATTENTION_LAYER_TYPES + ("mamba", "selective_scan", "gmu",
-                                       "conv")
+                                       "conv", "kda")
 
 
 @dataclass(frozen=True)
@@ -148,11 +150,14 @@ class TransformerConfig:
     ssm_conv: int = 4
     ssm_chunk: int = 256
     # Latent attention (layer type "mla"): queries through a rank
-    # q_lora_rank, keys and values through one latent of kv_lora_rank,
+    # q_lora_rank (0: one matrix, no rank and no norm), keys and values
+    # through one latent of kv_lora_rank,
     # each with a norm of its own (the configuration's); a head's query and key are
     # qk_nope_head_dim latent-made channels beside qk_rope_head_dim
     # rotary ones, the rotary key one vector shared by all heads; values
-    # are v_head_dim wide.
+    # are v_head_dim wide.  The rotary channels turn where the layer
+    # type rotates (``rotates("mla")``); where it does not they are plain
+    # channels, the shared key's among them.
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -262,6 +267,17 @@ class TransformerConfig:
     # The gated short convolution (layer type "conv"): the taps of its
     # causal depthwise filter, the current token's included.
     conv_taps: int = 3
+    # Kimi Delta Attention (layer type "kda", ops/kda.py): kda_heads
+    # heads whose keys and values are kda_head_dim wide (the two low-rank
+    # gates' rank too), a causal depthwise filter of kda_conv taps on q,
+    # k and v, the rule in chunks of kda_chunk tokens (a power of two
+    # that divides the sequence: the rule refuses any other length), a
+    # state kept for the backward every kda_states_every chunks.
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    kda_chunk: int = 64
+    kda_states_every: int = 4
 
     def __post_init__(self):
         if self.num_kv_heads is not None:
@@ -311,16 +327,29 @@ class TransformerConfig:
                 ("attention", "sliding_attention", "full_attention"))
             self._check_handed_on("gmu", "memory_layer",
                                   ("selective_scan",))
+            if "kda" in self.layer_types and (
+                    min(self.kda_heads, self.kda_head_dim, self.kda_conv,
+                        self.kda_states_every) <= 0 or self.kda_chunk <= 0
+                    or self.kda_chunk & (self.kda_chunk - 1)):
+                raise ValueError(
+                    f"a 'kda' layer needs positive kda_heads="
+                    f"{self.kda_heads}, kda_head_dim={self.kda_head_dim}, "
+                    f"kda_conv={self.kda_conv} and kda_states_every="
+                    f"{self.kda_states_every}, and kda_chunk="
+                    f"{self.kda_chunk} a power of two")
             if "mla" in self.layer_types:
-                sizes = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                sizes = ("kv_lora_rank", "qk_nope_head_dim",
                          "qk_rope_head_dim", "v_head_dim")
-                if min(getattr(self, k) for k in sizes) <= 0:
+                if min(getattr(self, k) for k in sizes) <= 0 or (
+                        self.q_lora_rank < 0):
                     raise ValueError(
-                        f"an 'mla' layer needs positive {', '.join(sizes)}")
-                if self.pos_embedding != "rope":
+                        f"an 'mla' layer needs positive {', '.join(sizes)} "
+                        f"(q_lora_rank may be 0: one matrix)")
+                if self.pos_embedding == "learned":
                     raise ValueError(
-                        "an 'mla' layer rotates its rotary channels: "
-                        "pos_embedding must be 'rope'")
+                        "an 'mla' layer rotates its rotary channels or "
+                        "sees no positions: pos_embedding must be 'rope' "
+                        "or 'none'")
         if self.routed_experts > 0:
             if self.moe_experts > 0:
                 raise ValueError(
@@ -395,11 +424,6 @@ class TransformerConfig:
                     f"{ATTENTION_LAYER_TYPES} that rotate under "
                     f"pos_embedding='rope', got {self.rope_layer_types!r} "
                     f"with pos_embedding={self.pos_embedding!r}")
-            if "mla" in (self.layer_types or ()) and (
-                    "mla" not in self.rope_layer_types):
-                raise ValueError(
-                    "an 'mla' layer rotates its rotary channels: "
-                    "rope_layer_types must name 'mla'")
 
     def _check_handed_on(self, reader: str, setting: str, makers: tuple):
         """Layers of type ``reader`` need ``setting`` to name an earlier
@@ -476,6 +500,10 @@ class TransformerConfig:
         """The channels RoPE rotates: a whole head, or an MLA head's
         rotary part."""
         return self.qk_rope_head_dim or self.head_dim
+
+    @property
+    def kda_inner(self) -> int:
+        return self.kda_heads * self.kda_head_dim
 
     @property
     def ssm_inner(self) -> int:
@@ -778,6 +806,52 @@ def short_conv_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
     return out_proj(z)
 
 
+def kda_mixer(cfg: TransformerConfig, h, *, qkv, conv_kernel, f_a, f_b,
+              dt_bias, a_log, b_proj, g_a, g_b, norm_scale, o_proj):
+    """Kimi Delta Attention (arXiv:2510.26692) on the normed stream ``h``
+    [b, s, emb]: one projection to ``[q ; k ; v]``, each ``kda_heads x
+    kda_head_dim`` wide; a causal depthwise filter of ``conv_kernel``
+    [taps, 3 inner] and silu on all three; ``q`` and ``k`` of unit
+    length over a head's channels (``+ 1e-6`` under the root), ``q``
+    then scaled by ``kda_head_dim ** -0.5``; the log-decay a channel
+    ``g = -exp(a_log) * softplus(f_b(f_a(h)) + dt_bias)`` (``a_log`` one
+    number a head); ``beta = sigmoid(b_proj(h))`` a head; the gated
+    delta rule (``ops/kda.py``) in chunks of ``kda_chunk`` tokens, which
+    refuses a sequence the chunk does not divide; an RMS norm over each head's channels with the one scale
+    ``norm_scale`` times ``sigmoid(g_b(g_a(h)))``; the output
+    projection.  The projections are callables like ``block_math``'s
+    (matmuls in the compute dtype), the rest raw arrays; the chain from
+    the filters to the rule's inputs is float32 under the scope
+    ``kda_prep`` (``q``, ``k`` and ``v`` enter the rule in the compute
+    dtype, ``g`` and ``beta`` in float32).  Returns the residual
+    delta."""
+    from ..ops.kda import kda  # noqa: PLC0415
+
+    b, s, _ = h.shape
+    heads, hd, inner = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_inner
+    by_head = lambda t: t.reshape(b, s, heads, hd)
+    fused = qkv(h)
+    decay, beta, gate = f_b(f_a(h)), b_proj(h), g_b(g_a(h))
+    with jax.named_scope(scopes.KDA_PREP):
+        mixed = jax.nn.silu(causal_depthwise_conv(fused, conv_kernel))
+        q, k, v = (by_head(mixed[..., i * inner:(i + 1) * inner])
+                   for i in range(3))
+        unit = lambda t: t * jax.lax.rsqrt(
+            jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+        q, k = unit(q) * hd ** -0.5, unit(k)
+        g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * by_head(
+            jax.nn.softplus(decay.astype(jnp.float32) + dt_bias))
+        beta = jax.nn.sigmoid(beta.astype(jnp.float32))
+        q, k, v = (t.astype(fused.dtype) for t in (q, k, v))
+    o = kda(q, k, v, g, beta, chunk=cfg.kda_chunk,
+            states_every=cfg.kda_states_every).astype(jnp.float32)
+    normed = o * jax.lax.rsqrt(
+        jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps)
+    gated = normed * norm_scale * jax.nn.sigmoid(
+        by_head(gate.astype(jnp.float32)))
+    return o_proj(gated.reshape(b, s, inner).astype(fused.dtype))
+
+
 def gmu_mixer(h, memory, *, in_proj, out_proj):
     """A gated memory unit (arXiv:2507.06607) on the normed stream ``h``:
     ``out_proj(silu(in_proj(h)) * memory)``, ``memory`` [b, s, width]
@@ -788,34 +862,36 @@ def gmu_mixer(h, memory, *, in_proj, out_proj):
     return out_proj(gated.astype(gate.dtype))
 
 
-def mla_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *, q_a,
-              q_a_norm, q_b, kv_a, kv_a_norm, kv_b, proj):
+def mla_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *, q_b,
+              kv_a, kv_a_norm, kv_b, proj, q_a=None, q_a_norm=None):
     """Latent attention on the normed stream ``h`` [b, s, emb]: queries
-    ``q_b(norm(q_a(h)))``, heads of ``[nope ; rope]``; ``kv_a(h)`` gives
-    the latent and ONE rotary key for all heads; ``kv_b(norm(latent))``
-    gives each head's ``[k_nope ; v]``.  RoPE (``ops/rope.py``, split
-    halves) turns the rotary parts only; a head's key is its own
-    ``k_nope`` beside the shared rotary key.  Scores over the whole
-    ``nope + rope`` channels, causal, through the configured schedule.
-    The seven layers are callables like ``block_math``'s.  Returns the
-    residual delta."""
-    from ..ops.rope import apply_rope_tables  # noqa: PLC0415
-
+    ``q_b(norm(q_a(h)))``, or ``q_b(h)`` where the configuration has no
+    query rank (``q_a`` is ``None``), heads of ``[nope ; rope]``;
+    ``kv_a(h)`` gives the latent and ONE rotary key for all heads;
+    ``kv_b(norm(latent))`` gives each head's ``[k_nope ; v]``.  RoPE
+    (``ops/rope.py``, split halves) turns the rotary parts only, and
+    nothing where the caller hands in ``rope_tabs=None`` (a layer that
+    sees no positions: the rotary channels are plain ones); a head's key
+    is its own ``k_nope`` beside the shared rotary key.  Scores over the
+    whole ``nope + rope`` channels, causal, through the configured
+    schedule; the values may be narrower than the keys.  The layers are
+    callables like ``block_math``'s.  Returns the residual delta."""
     b, s, _ = h.shape
     nh, latent = cfg.num_heads, cfg.kv_lora_rank
     nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
-    if nope + rope != vd and cfg.attention_impl == "flash":
-        raise ValueError(
-            f"the flash kernels take one head size: qk_nope_head_dim + "
-            f"qk_rope_head_dim = {nope + rope}, v_head_dim = {vd}")
     with jax.named_scope(scopes.MLA_PROJ):
-        q = q_b(q_a_norm(q_a(h))).reshape(b, s, nh, nope + rope)
+        q = q_b(h if q_a is None else q_a_norm(q_a(h)))
+        q = q.reshape(b, s, nh, nope + rope)
         kv = kv_a(h)
         k_v = kv_b(kv_a_norm(kv[..., :latent])).reshape(b, s, nh, nope + vd)
-        q_rope = apply_rope_tables(q[..., nope:], *rope_tabs)
-        k_rope = apply_rope_tables(kv[..., None, latent:], *rope_tabs)
-        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        k_rope = kv[..., None, latent:]
+        if rope_tabs is not None:
+            from ..ops.rope import apply_rope_tables  # noqa: PLC0415
+
+            q_rope = apply_rope_tables(q[..., nope:], *rope_tabs)
+            k_rope = apply_rope_tables(k_rope, *rope_tabs)
+            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
         k = jnp.concatenate(
             [k_v[..., :nope], jnp.broadcast_to(k_rope, (b, s, nh, rope))],
             axis=-1)
@@ -904,7 +980,8 @@ def attention_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *,
 # The scope a layer type's mixer traces under; any type not named here
 # is an attention layer and traces under ``attn``.
 MIXER_SCOPES = {"mamba": scopes.SSM, "selective_scan": scopes.SSM,
-                "gmu": scopes.GMU, "conv": scopes.SHORT_CONV}
+                "gmu": scopes.GMU, "conv": scopes.SHORT_CONV,
+                "kda": scopes.KDA}
 
 
 def block_math(cfg: TransformerConfig, x, *, ln1, mixer, ln2, mlp,
@@ -917,11 +994,12 @@ def block_math(cfg: TransformerConfig, x, *, ln1, mixer, ln2, mlp,
     residual added through ``cfg.residual_multiplier``.  ``mixer`` is a
     callable of the normed stream that returns the residual DELTA:
     :func:`attention_mixer`, :func:`mla_mixer`, :func:`mamba_mixer`,
-    :func:`selective_scan_mixer`, :func:`gmu_mixer` or
-    :func:`short_conv_mixer` with the caller's parameterized layer
+    :func:`selective_scan_mixer`, :func:`gmu_mixer`,
+    :func:`short_conv_mixer` or :func:`kda_mixer` with the caller's
+    parameterized layer
     applications closed over; it traces under the scope of its
-    ``layer_type`` (``MIXER_SCOPES``: ``ssm``, ``gmu``, ``short_conv``
-    or ``attn``).  Shared by the flax :class:`Block`, the raw-weights
+    ``layer_type`` (``MIXER_SCOPES``: ``ssm``, ``gmu``, ``short_conv``,
+    ``kda`` or ``attn``).  Shared by the flax :class:`Block`, the raw-weights
     pipeline-parallel and decode block (:func:`raw_block_forward`), and
     the Megatron tensor-parallel block (``parallel/tensor_parallel.py``),
     so a change to the block (a norm variant, the residual's scale, a
@@ -1043,8 +1121,8 @@ class Block(nn.Module):
 
     The wiring lives in :func:`block_math`; this module only declares
     the flax parameters (the attention mixer's, a state-space mixer's, a
-    gated memory unit's, a gated short convolution's or latent
-    attention's, by ``layer_type``; a dense feed-forward's or the routed
+    gated memory unit's, a gated short convolution's, Kimi Delta
+    Attention's or latent attention's, by ``layer_type``; a dense feed-forward's or the routed
     experts', by ``ffn``) and hands
     their applications in as callables: one ``mixer`` closure over the
     layer type's mixer function, the norms and ``mlp``.  ``hand_on`` says what the block
@@ -1256,14 +1334,35 @@ class Block(nn.Module):
                     "conv_kernel", _conv_init,
                     (cfg.conv_taps, cfg.emb_dim), jnp.float32),
                 out_proj=unbiased(cfg.emb_dim, "out_proj"))
+        elif self.layer_type == "kda":
+            heads, hd, inner = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_inner
+            mixer = lambda h: kda_mixer(
+                cfg, h, qkv=unbiased(3 * inner, "qkv"),
+                conv_kernel=self.param(
+                    "conv_kernel", _conv_init, (cfg.kda_conv, 3 * inner),
+                    jnp.float32),
+                f_a=unbiased(hd, "f_a"), f_b=unbiased(inner, "f_b"),
+                dt_bias=self.param("dt_bias", _dt_bias_init, (inner,),
+                                   jnp.float32),
+                a_log=self.param(
+                    "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                        key, shape, jnp.float32, 1.0, 16.0)), (heads,)),
+                b_proj=unbiased(heads, "b_proj"),
+                g_a=unbiased(hd, "g_a"), g_b=unbiased(inner, "g_b"),
+                norm_scale=self.param("o_norm", nn.initializers.ones,
+                                      (hd,), jnp.float32),
+                o_proj=unbiased(cfg.emb_dim, "o_proj"))
         elif self.layer_type == "mla":
             heads = cfg.num_heads
+            query = {}
+            if cfg.q_lora_rank > 0:
+                query = dict(q_a=dense(cfg.q_lora_rank, "q_a"),
+                             q_a_norm=_norm(cfg, "q_a_norm"))
+            tabs = rope_tabs if cfg.rotates("mla") else None
 
             def mixer(h):
                 return mla_mixer(
-                    cfg, h, positions, rope_tabs,
-                    q_a=dense(cfg.q_lora_rank, "q_a"),
-                    q_a_norm=_norm(cfg, "q_a_norm"),
+                    cfg, h, positions, tabs, **query,
                     q_b=dense(heads * (cfg.qk_nope_head_dim
                                        + cfg.qk_rope_head_dim), "q_b"),
                     kv_a=dense(cfg.kv_lora_rank + cfg.qk_rope_head_dim,
@@ -1434,6 +1533,18 @@ class GPT(nn.Module):
                     convs * short_conv_filter_bytes(
                         tokens.shape[0], s, cfg.emb_dim,
                         jnp.dtype(cfg.dtype).itemsize))
+            kdas = cfg.layer_types.count("kda")
+            if kdas:
+                from ..ops.kda import kept_mib  # noqa: PLC0415
+
+                # the delta-rule layers of the program, the chunk their
+                # rule runs at and what one layer keeps for its backward
+                get_registry().gauge("kda.layers").set(kdas)
+                get_registry().gauge("kda.chunk").set(cfg.kda_chunk)
+                get_registry().gauge("kda.kept_mib").set(kept_mib(
+                    tokens.shape[0], s, cfg.kda_heads, cfg.kda_head_dim,
+                    cfg.kda_head_dim, cfg.kda_chunk, cfg.kda_states_every,
+                    jnp.dtype(cfg.dtype).itemsize))
         for i in range(cfg.num_layers):
             kind, hand_on = cfg.layer_type(i), cfg.hands_on(i)
             block = block_cls(cfg, kind, cfg.ffn_type(i), hand_on,
@@ -1692,6 +1803,35 @@ GPT_CONFIGS = {
         # feed-forward a block: keep each block's input and, as every
         # policy does, what its kernels made (the attention layer's o
         # 128 MiB and lse 4 MiB)
+        remat_policy="nothing_saveable",
+    ),
+    # https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct
+    # config.json (model_type kimi_linear; arXiv:2510.26692): Kimi Delta
+    # Attention layers (32 heads of 128, a four-tap filter on q, k and v,
+    # a decay per channel of the key) three to one with latent attention
+    # layers at published layers 4, 8, ..., 24 and 27 (no query rank, a
+    # latent of 512, keys of 128 + 64 over values of 128, NO positions
+    # anywhere: the recurrence carries the order); layer 0 a silu-gated
+    # feed-forward of 9216, the other 26 hold 256 routed experts of 1024
+    # (sigmoid scores, 8 a token, a selection bias, weights normalised
+    # and scaled 2.446, nothing dropped) beside one shared expert; an
+    # untied head.
+    # Training path only (require_gpt2_block says who refuses it).
+    "kimi-linear-48b-a3b-instruct": TransformerConfig(
+        vocab_size=163840, num_layers=27, emb_dim=2304, max_len=1048576,
+        layer_types=tuple("mla" if i % 4 == 3 or i == 26 else "kda"
+                          for i in range(27)),
+        num_heads=32, num_kv_heads=32, q_lora_rank=0, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        kda_heads=32, kda_head_dim=128, kda_conv=4, pos_embedding="none",
+        mlp_ratio=4, mlp="silu_gated", norm="rmsnorm", norm_eps=1e-5,
+        use_bias=False, tie_embeddings=False,
+        routed_experts=256, routed_top_k=8, routed_width=1024,
+        routed_scaling=2.446, shared_experts=1, dense_layers_first=1,
+        # 16384 x 12288 of q, k and v and three 16384 x 4096 gates a
+        # block: keep each block's input and, as every policy does, what
+        # its kernels made (the rule's o 128 MiB and states 128 MiB, the
+        # latent layer's o 128 MiB and lse 2 MiB)
         remat_policy="nothing_saveable",
     ),
 }

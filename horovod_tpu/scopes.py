@@ -32,6 +32,15 @@ SHORT_CONV = "short_conv"              # a block's gated-short-convolution
 SHORT_CONV_FILTER = "short_conv_filter"  # inside short_conv: the
                                        # elementwise chain alone (B * u,
                                        # the taps, C *)
+KDA = "kda"                            # a block's Kimi-Delta-Attention half:
+                                       # the projections, filters, norms,
+                                       # gates, the chunk rule, the gated
+                                       # norm, o_proj
+KDA_PREP = "kda_prep"                  # inside kda: the float32 elementwise
+                                       # chain between the projections and
+                                       # the rule (three filters with silu,
+                                       # two L2 norms, softplus, the decay)
+KDA_SCAN = "kda_scan"                  # inside kda: the chunk rule alone
 MLA_PROJ = "mla_proj"                  # latent attention, inside attn: the
                                        # two low-rank paths, their norms,
                                        # RoPE, the shared rotary key
@@ -78,11 +87,14 @@ SSD_OUT = "ssd_out"                    # the state-space scan's y
 SSD_STATES = "ssd_states"              # and its chunk-start states
 SSCAN_OUT = "sscan_out"                # the selective scan's y
 SSCAN_STATES = "sscan_states"          # and its time-block-start states
+KDA_OUT = "kda_out"                    # the gated delta rule's o
+KDA_STATES = "kda_states"              # and its group-start states
 KERNEL_OUTPUTS = (FLASH_OUT, FLASH_LSE, SSD_OUT, SSD_STATES, SSCAN_OUT,
-                  SSCAN_STATES)
+                  SSCAN_STATES, KDA_OUT, KDA_STATES)
 
 SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ,
           ATTN_WINDOW, ATTN_GATE, ATTN_CROSS, ATTN_DIFF, SSM, SSD_SCAN,
-          SELECTIVE_SCAN, GMU, SHORT_CONV, SHORT_CONV_FILTER, MLP,
+          SELECTIVE_SCAN, GMU, SHORT_CONV, SHORT_CONV_FILTER, KDA,
+          KDA_PREP, KDA_SCAN, MLP,
           MOE_ROUTE, MOE_BALANCE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED,
           MTP, EMBED, HEAD, STEM, KV_GATHER, KV_SCATTER, SAMPLE)
