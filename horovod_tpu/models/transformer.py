@@ -31,6 +31,7 @@ TPU-first choices:
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 from dataclasses import dataclass, fields, replace
 from typing import Any, Optional
@@ -806,6 +807,25 @@ def short_conv_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
     return out_proj(z)
 
 
+def kda_prep_chain(fused, conv_kernel, decay, dt_bias, a_log):
+    """The float32 chain in front of the delta rule as XLA compiles it:
+    what ``ops/kda_prep.py``'s kernels compute (same arguments, same
+    results), for the shapes they do not take (``kda_prep.plan``), and
+    what the tests hold them against."""
+    b, s, inner = decay.shape
+    heads = a_log.shape[0]
+    by_head = lambda t: t.reshape(b, s, heads, inner // heads)
+    mixed = jax.nn.silu(causal_depthwise_conv(fused, conv_kernel))
+    q, k, v = (by_head(mixed[..., i * inner:(i + 1) * inner])
+               for i in range(3))
+    unit = lambda t: t * jax.lax.rsqrt(
+        jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+    q, k = unit(q) * (inner // heads) ** -0.5, unit(k)
+    g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * by_head(
+        jax.nn.softplus(decay.astype(jnp.float32) + dt_bias))
+    return (*(t.astype(fused.dtype) for t in (q, k, v)), g)
+
+
 def kda_mixer(cfg: TransformerConfig, h, *, qkv, conv_kernel, f_a, f_b,
               dt_bias, a_log, b_proj, g_a, g_b, norm_scale, o_proj):
     """Kimi Delta Attention (arXiv:2510.26692) on the normed stream ``h``
@@ -825,6 +845,7 @@ def kda_mixer(cfg: TransformerConfig, h, *, qkv, conv_kernel, f_a, f_b,
     ``kda_prep`` (``q``, ``k`` and ``v`` enter the rule in the compute
     dtype, ``g`` and ``beta`` in float32).  Returns the residual
     delta."""
+    from ..ops import kda_prep  # noqa: PLC0415
     from ..ops.kda import kda  # noqa: PLC0415
 
     b, s, _ = h.shape
@@ -833,16 +854,12 @@ def kda_mixer(cfg: TransformerConfig, h, *, qkv, conv_kernel, f_a, f_b,
     fused = qkv(h)
     decay, beta, gate = f_b(f_a(h)), b_proj(h), g_b(g_a(h))
     with jax.named_scope(scopes.KDA_PREP):
-        mixed = jax.nn.silu(causal_depthwise_conv(fused, conv_kernel))
-        q, k, v = (by_head(mixed[..., i * inner:(i + 1) * inner])
-                   for i in range(3))
-        unit = lambda t: t * jax.lax.rsqrt(
-            jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
-        q, k = unit(q) * hd ** -0.5, unit(k)
-        g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * by_head(
-            jax.nn.softplus(decay.astype(jnp.float32) + dt_bias))
+        tiles = kda_prep.plan(s, heads, hd, conv_kernel.shape[0])
+        prep = kda_prep_chain if tiles is None else functools.partial(
+            kda_prep.kda_prep, tiles=tiles)
+        q, k, v, g = prep(fused, conv_kernel, decay, dt_bias, a_log)
+        # a head's one number a token, a few hundred KB: XLA's
         beta = jax.nn.sigmoid(beta.astype(jnp.float32))
-        q, k, v = (t.astype(fused.dtype) for t in (q, k, v))
     o = kda(q, k, v, g, beta, chunk=cfg.kda_chunk,
             states_every=cfg.kda_states_every).astype(jnp.float32)
     normed = o * jax.lax.rsqrt(
@@ -1535,11 +1552,17 @@ class GPT(nn.Module):
                         jnp.dtype(cfg.dtype).itemsize))
             kdas = cfg.layer_types.count("kda")
             if kdas:
+                from ..ops import kda_prep  # noqa: PLC0415
                 from ..ops.kda import kept_mib  # noqa: PLC0415
 
-                # the delta-rule layers of the program, the chunk their
-                # rule runs at and what one layer keeps for its backward
+                # the delta-rule layers of the program, those whose
+                # float32 chain takes the kernels (all or none: they
+                # share a shape), the chunk their rule runs at and what
+                # one layer keeps for its backward
                 get_registry().gauge("kda.layers").set(kdas)
+                get_registry().gauge("kda.prep_kernel_layers").set(
+                    0 if kda_prep.plan(s, cfg.kda_heads, cfg.kda_head_dim,
+                                       cfg.kda_conv) is None else kdas)
                 get_registry().gauge("kda.chunk").set(cfg.kda_chunk)
                 get_registry().gauge("kda.kept_mib").set(kept_mib(
                     tokens.shape[0], s, cfg.kda_heads, cfg.kda_head_dim,

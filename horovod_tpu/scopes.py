@@ -39,7 +39,12 @@ KDA = "kda"                            # a block's Kimi-Delta-Attention half:
 KDA_PREP = "kda_prep"                  # inside kda: the float32 elementwise
                                        # chain between the projections and
                                        # the rule (three filters with silu,
-                                       # two L2 norms, softplus, the decay)
+                                       # two L2 norms, softplus, the decay):
+                                       # the kernels kda_prep_fwd and
+                                       # kda_prep_bwd of ops/kda_prep.py
+                                       # where the shape allows (gauge
+                                       # kda.prep_kernel_layers), else XLA's
+                                       # fusions; beta's sigmoid either way
 KDA_SCAN = "kda_scan"                  # inside kda: the chunk rule alone
 MLA_PROJ = "mla_proj"                  # latent attention, inside attn: the
                                        # two low-rank paths, their norms,

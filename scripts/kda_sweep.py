@@ -1,20 +1,26 @@
 #!/usr/bin/env python
-"""Stand-alone timings of the chunked gated delta rule (``ops/kda.py``)
-on the chip, forward alone and forward with backward, at one layer's
-shape of the cell ``kimilin_train_s16384`` (1 x 16384 tokens, 32 heads of
-128, bfloat16, decays as the model draws them): what the module's
-constants were chosen from (PERF.md section 6, PR 51).
+"""Stand-alone timings of Kimi Delta Attention's two halves on the
+chip, forward alone and forward with backward, at one layer's shape of
+the cell ``kimilin_train_s16384`` (1 x 16384 tokens, 32 heads of 128,
+bfloat16, decays as the model draws them): what the modules' constants
+were chosen from (PERF.md section 6, PRs 51 and 52).
 
-Each variant is ``chunk,states_every,sub_block,precision`` (``highest``
-or ``high`` for the Gram products and the triangular inverse); the first
-is what the others' ``o`` and gradients are held against.  Needs the
-chip; prints one JSON line a variant and appends it to
-``chiprun_out/kda_sweep.jsonl``.
+The rule (``ops/kda.py``; the default): each variant is
+``chunk,states_every,sub_block,precision`` (``highest`` or ``high`` for
+the Gram products and the triangular inverse).  The float32 chain in
+front of it (``--chain``; ``ops/kda_prep.py``): the variant ``xla`` is
+the chain as XLA compiles it (``models/transformer.py:kda_prep_chain``),
+any other is ``token_tile,head_block,rows`` for the kernel pair (timed
+like the chain and, ``kernel_*_ms``, each call alone).  The
+first variant is what the others' outputs and gradients are held
+against.  Needs the chip; prints one JSON line a variant and appends it
+to ``chiprun_out/kda_sweep.jsonl``.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -29,11 +35,16 @@ def main():
     parser.add_argument("--heads", type=int, default=32)
     parser.add_argument("--dim", type=int, default=128)
     parser.add_argument("--iters", type=int, default=5)
-    parser.add_argument("--variants", nargs="+", default=[
-        "64,4,16,highest", "64,8,16,highest", "64,16,16,highest",
-        "64,4,8,highest", "64,4,32,highest", "64,4,16,high",
-        "32,8,16,highest", "128,2,16,highest"])
+    parser.add_argument("--chain", action="store_true")
+    parser.add_argument("--variants", nargs="+")
     args = parser.parse_args()
+    if args.variants is None:
+        args.variants = [
+            "xla", "256,4,64", "512,4,64", "512,8,64", "1024,8,64",
+            "512,8,128", "512,8,32", "512,16,64"] if args.chain else [
+            "64,4,16,highest", "64,8,16,highest", "64,16,16,highest",
+            "64,4,8,highest", "64,4,32,highest", "64,4,16,high",
+            "32,8,16,highest", "128,2,16,highest"]
 
     import jax
     import jax.numpy as jnp
@@ -42,6 +53,8 @@ def main():
     from horovod_tpu.ops import kda as kda_ops
 
     b, s, h, d = 1, args.seq, args.heads, args.dim
+    if args.chain:
+        return sweep_chain(args, b, s, h, d)
     ks = jax.random.split(jax.random.PRNGKey(0), 7)
     unit = lambda t: t * lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
     shape = (b, s, h, d)
@@ -58,17 +71,8 @@ def main():
     w = jax.random.normal(ks[6], shape).astype(jnp.bfloat16)
     inputs = (q, k, v, g, beta)
 
-    def timed(fn):
-        jax.block_until_ready(fn(*inputs))
-        jax.block_until_ready(fn(*inputs))
-        t0 = time.perf_counter()
-        for _ in range(args.iters):
-            out = fn(*inputs)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / args.iters * 1e3, out
-
+    timed = functools.partial(_timed, inputs=inputs, iters=args.iters)
     base = None
-    os.makedirs("chiprun_out", exist_ok=True)
     for variant in args.variants:
         chunk, every, sub, precision = variant.split(",")
         kda_ops.SUB_BLOCK = int(sub)
@@ -91,17 +95,110 @@ def main():
             got = [o, *grads]
             if base is None:
                 base = got
-            apart = [float(jnp.linalg.norm((x.astype(jnp.float32)
-                                            - y.astype(jnp.float32)).ravel())
-                           / jnp.linalg.norm(y.astype(jnp.float32).ravel()))
-                     for x, y in zip(got, base)]
             line = {"variant": variant, "fwd_ms": fwd_ms,
                     "fwd_bwd_ms": both_ms,
-                    "apart_o_dq_dk_dv_dg_dbeta": apart,
+                    "apart_o_dq_dk_dv_dg_dbeta": _apart(got, base),
                     "device": jax.devices()[0].device_kind}
-        print(json.dumps(line), flush=True)
-        with open("chiprun_out/kda_sweep.jsonl", "a") as f:
-            f.write(json.dumps(line) + "\n")
+        _report(line)
+
+
+def _timed(fn, inputs, iters):
+    import jax
+
+    jax.block_until_ready(fn(*inputs))
+    jax.block_until_ready(fn(*inputs))
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = fn(*inputs)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / iters * 1e3, out
+
+
+def _apart(got, base):
+    """Each array's distance from the first variant's, over its norm."""
+    import jax.numpy as jnp
+
+    flat = lambda t: t.astype(jnp.float32).ravel()
+    return [float(jnp.linalg.norm(flat(x) - flat(y))
+                  / jnp.linalg.norm(flat(y))) for x, y in zip(got, base)]
+
+
+def _report(line):
+    print(json.dumps(line), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/kda_sweep.jsonl", "a") as f:
+        f.write(json.dumps(line) + "\n")
+
+
+def sweep_chain(args, b, s, h, d):
+    """The chain alone: ``fused`` and ``decay`` as projections of a
+    normed stream would be (unit variance, bfloat16), the filter, bias
+    and ``A`` as the model draws them; the cotangents random.  Both
+    sides hand over ``[batch, seq, heads, head_dim]``, as the rule takes
+    them."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models.transformer import kda_prep_chain
+    from horovod_tpu.ops import kda_prep
+
+    inner, taps = h * d, 4
+    ks = jax.random.split(jax.random.PRNGKey(0), 9)
+    inputs = (
+        jax.random.normal(ks[0], (b, s, 3 * inner)).astype(jnp.bfloat16),
+        jax.random.uniform(ks[1], (taps, 3 * inner), jnp.float32,
+                           -taps ** -0.5, taps ** -0.5),
+        jax.random.normal(ks[2], (b, s, inner)).astype(jnp.bfloat16),
+        jnp.log(jnp.expm1(jnp.exp(jax.random.uniform(
+            ks[3], (inner,), jnp.float32, jnp.log(1e-3), jnp.log(1e-1))))),
+        jnp.log(jax.random.uniform(ks[4], (h,), jnp.float32, 1.0, 16.0)))
+    weights = tuple(
+        jax.random.normal(k, (b, s, h, d)).astype(dtype)
+        for k, dtype in zip(ks[5:], [jnp.bfloat16] * 3 + [jnp.float32]))
+    base = None
+    for variant in args.variants:
+        if variant == "xla":
+            chain = kda_prep_chain
+        else:
+            (kda_prep.TOKEN_TILE, kda_prep.HEAD_BLOCK,
+             kda_prep._ROWS) = map(int, variant.split(","))
+            tiles = kda_prep.plan(s, h, d, taps)
+            chain = functools.partial(kda_prep.kda_prep, tiles=tiles)
+        jax.clear_caches()
+
+        def both(*t, chain=chain):
+            # forward, then backward from the cotangents as given: no
+            # loss in between, whose fusion would be timed with them
+            out, pull = jax.vjp(chain, *t[:5])
+            return out, pull(tuple(t[5:]))
+
+        try:
+            fwd_ms, out = _timed(jax.jit(chain), inputs, args.iters)
+            both_ms, (_, grads) = _timed(jax.jit(both), inputs + weights,
+                                         args.iters)
+        except Exception as e:  # a variant that does not fit or compile
+            line = {"variant": variant, "error": str(e)[:300]}
+        else:
+            got = [*out, *grads]
+            if base is None:
+                base = got
+            line = {"variant": variant, "fwd_ms": fwd_ms,
+                    "fwd_bwd_ms": both_ms,
+                    "apart_q_k_v_g_dfused_dconv_ddecay_dbias_dalog":
+                        _apart(got, base),
+                    "device": jax.devices()[0].device_kind}
+            if variant != "xla":
+                # the two calls alone, on [batch, seq, inner] as they
+                # write and read it
+                shape = (d, *tiles)
+                line["kernel_fwd_ms"], _ = _timed(
+                    lambda *t: kda_prep._forward(*t, shape, False), inputs,
+                    args.iters)
+                line["kernel_bwd_ms"], _ = _timed(
+                    lambda *t: kda_prep._backward(*t, shape, False),
+                    inputs + tuple(w.reshape(b, s, inner) for w in weights),
+                    args.iters)
+        _report(line)
 
 
 if __name__ == "__main__":

@@ -487,8 +487,9 @@ def test_a_kda_block_carries_its_scopes_and_the_gauges_count_it():
     """A step traced names a KDA block's mixer half ``kda``, the
     float32 chain inside it ``kda_prep`` and the rule ``kda_scan``
     (forward and backward), the latent block's ``attn`` with
-    ``mla_proj`` inside; the gauges hold the KDA layers, the chunk and
-    what a layer keeps."""
+    ``mla_proj`` inside; the gauges hold the KDA layers, those whose
+    chain took the kernels (all at 64 tokens, none at 40), the chunk
+    and what a layer keeps."""
     from horovod_tpu.obs.registry import get_registry
     from horovod_tpu.ops.kda import kept_mib
 
@@ -510,11 +511,25 @@ def test_a_kda_block_carries_its_scopes_and_the_gauges_count_it():
     assert "block0/kda/qkv" in text and "block0/kda/o_proj" in text
     assert "block3/attn/mla_proj/" in text and "block3/kda" not in text
     assert "block0/attn" not in text and "block1/mlp/moe_route/" in text
+    # the chain is the two kernels of ops/kda_prep.py behind their inner
+    # jit, whose body (interpreted here) is lowered once for all layers
+    assert '/jvp(GPT)/block0/kda/kda_prep/jit(_forward)"' in text
+    assert ('/transpose(jvp(GPT))/block0/kda/kda_prep/jit(_backward)"'
+            in text)
+    assert '"kda_prep_fwd/' in text and '"kda_prep_bwd/' in text
     registry = get_registry()
     assert registry.gauge("kda.layers").value == 4
+    assert registry.gauge("kda.prep_kernel_layers").value == 4
     assert registry.gauge("kda.chunk").value == 16
     assert registry.gauge("kda.kept_mib").value == kept_mib(
         2, SEQ, 4, 16, 16, 16, 2, 4)
+    # 40 tokens are no whole 16-row tiles: the chain as XLA compiles it
+    short = str(jax.make_jaxpr(lambda p: program_loss(
+        small_model(kda_chunk=8), {**variables, "params": p},
+        TOKENS[:, :41]))(variables["params"]))
+    assert "kda_prep_fwd" not in short and "logistic" in short
+    assert registry.gauge("kda.layers").value == 4
+    assert registry.gauge("kda.prep_kernel_layers").value == 0
 
 
 def test_a_rematerialised_kda_block_keeps_the_rules_outputs_by_name():

@@ -82,10 +82,12 @@ def test_kimi_cell_step_compiles_for_v5e_at_two_layers(topo,
     layer with the routed experts and the shared one; the whole step
     takes the TPU compiler five minutes here, and
     ``benchmark/tools/compile_check.py kimilin_train_s16384`` is how it
-    is compiled by hand: 12.68 GiB, PERF.md section 4): the flash forward
+    is compiled by hand: 12.79 GiB, PERF.md section 4): the flash forward
     and ONE backward kernel at keys of 192 over values of 128, the
     grouped matmuls, the rule's scope forward and backward with its
-    float32 chain beside it, and the two layers' share of the memory."""
+    float32 chain beside it as the two kernels of ``ops/kda_prep.py``
+    (forward, recomputed forward and ``transpose(...)``; the gauge counts
+    the layer), and the two layers' share of the memory."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
@@ -108,6 +110,17 @@ def test_kimi_cell_step_compiles_for_v5e_at_two_layers(topo,
                   "transpose(jvp(GPT))/jvp(GPT)/checkpoint/block0/kda/"
                   "kda_scan/", "/block1/attn/mla_proj"):
         assert scope in text, scope
+    for name in ("/jvp(GPT)/block0/kda/kda_prep/jit(_forward)/kda_prep_fwd",
+                 "transpose(jvp(GPT))/jvp(GPT)/checkpoint/"
+                 "rematted_computation/block0/kda/kda_prep/jit(_forward)/"
+                 "kda_prep_fwd",
+                 "transpose(jvp(GPT))/jvp(GPT)/checkpoint/block0/kda/"
+                 "kda_prep/jit(_backward)/kda_prep_bwd"):
+        assert name in text, name
+    from horovod_tpu.obs.registry import get_registry
+
+    assert get_registry().gauge("kda.prep_kernel_layers").value == 1
+    assert get_registry().gauge("kda.layers").value == 1
     mem = compiled.memory_analysis()
     # layer 1 of the cell, the latent layer, table, head and final norm
     assert mem.argument_size_in_bytes == pytest.approx(
