@@ -1209,16 +1209,22 @@ class Block(nn.Module):
             made from the layer's input or, without one, from ``h``."""
             from ..obs.registry import get_registry  # noqa: PLC0415
             from ..parallel.moe import (  # noqa: PLC0415
-                apply_routing, ffn_tile_fill,
+                apply_routing, ffn_tile_fill, row_bound,
             )
 
             b, s, d = h.shape
             held, ff = cfg.held_experts, cfg.routed_width
             # set while the step is traced, like flash.tiles_*: the share
-            # of what the six grouped matmuls multiply that is needed
-            get_registry().gauge(
-                "moe.gmm_tile_fill", layer="/".join(self.path)).set(
-                    ffn_tile_fill(d, ff, cfg.dtype))
+            # of what the six grouped matmuls multiply that is needed, the
+            # rows every [row_bound, .] buffer carries, and the slots the
+            # layer runs on in a step that passes the bound
+            for name, value in (
+                    ("gmm_tile_fill", ffn_tile_fill(d, ff, cfg.dtype)),
+                    ("row_bound", row_bound(b * s, cfg.routed_top_k, held,
+                                            cfg.routed_experts)),
+                    ("slots", b * s * cfg.routed_top_k)):
+                get_registry().gauge(
+                    f"moe.{name}", layer="/".join(self.path)).set(value)
             stacked = nn.initializers.lecun_normal(batch_axis=(0,))
             x2 = h.reshape(b * s, d)
             if routing is None:

@@ -37,8 +37,12 @@ without its exchange.  The layer is two halves, a decision
 sort) and its application (:func:`apply_routing`), because a model may
 decide from one tensor and dispatch another (a router that reads the
 layer's input, ahead of attention); :func:`routed_experts` is the two on
-one tensor.  The GShard path above is as it was; ROADMAP C6 has the
-folding of the two.
+one tensor.  In a device trace three scopes cover it (``moe_route``,
+``moe_dispatch``, ``moe_experts``) and each names its own operations one
+level down (``horovod_tpu/scopes.py``: ``moe_logits``, ``moe_topk``,
+``moe_sort``, ``moe_unsort``; ``moe_rows_in``, ``moe_rows_out``;
+``moe_cast``, ``moe_gate``).  The GShard path above is as it was and
+carries no scope; ROADMAP C6 has the folding of the two.
 """
 
 from __future__ import annotations
@@ -366,33 +370,37 @@ def route(x2, router, bias, *, top_k: int, scaling: float,
     ``P_e`` the mean over tokens of the full softmax of the logits; 1.0
     at an even load, and its gradient reaches the router through ``P``
     alone."""
-    n = x2.shape[0]
-    logits = jnp.dot(
-        x2.astype(jnp.float32), router.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST)
-    if score_rule == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
-        _, experts = lax.top_k(scores + lax.stop_gradient(bias), top_k)
-        chosen = jnp.take_along_axis(scores, experts, axis=-1)
-        weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scaling
-    elif score_rule == "softmax_chosen":
-        if bias is not None:
-            raise ValueError(
-                "score_rule='softmax_chosen' chooses by the raw logits: "
-                "it takes no selection bias")
-        chosen, experts = lax.top_k(logits, top_k)
-        weights = jax.nn.softmax(chosen, axis=-1) * scaling
-    else:
+    if score_rule not in SCORE_RULES:
         raise ValueError(f"score_rule must be one of {SCORE_RULES}, got "
                          f"{score_rule!r}")
-    local = experts.reshape(n * top_k) - first_held
-    is_held = (local >= 0) & (local < held)
-    key = jnp.where(is_held, local, held)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    group_sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
-    dropped = is_held.sum(dtype=jnp.int32) - group_sizes[:held].sum()
-    load = jnp.zeros((router.shape[1],), jnp.int32).at[
-        experts.reshape(n * top_k)].add(1)
+    if score_rule == "softmax_chosen" and bias is not None:
+        raise ValueError(
+            "score_rule='softmax_chosen' chooses by the raw logits: "
+            "it takes no selection bias")
+    n = x2.shape[0]
+    with jax.named_scope(scopes.MOE_LOGITS):
+        logits = jnp.dot(
+            x2.astype(jnp.float32), router.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits) if score_rule == "sigmoid" else logits
+    with jax.named_scope(scopes.MOE_TOPK):
+        if score_rule == "sigmoid":
+            _, experts = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+            chosen = jnp.take_along_axis(scores, experts, axis=-1)
+            weights = (chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+                       * scaling)
+        else:
+            chosen, experts = lax.top_k(scores, top_k)
+            weights = jax.nn.softmax(chosen, axis=-1) * scaling
+    with jax.named_scope(scopes.MOE_SORT):
+        local = experts.reshape(n * top_k) - first_held
+        is_held = (local >= 0) & (local < held)
+        key = jnp.where(is_held, local, held)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        group_sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
+        dropped = is_held.sum(dtype=jnp.int32) - group_sizes[:held].sum()
+        load = jnp.zeros((router.shape[1],), jnp.int32).at[
+            experts.reshape(n * top_k)].add(1)
     routing = Routing(weights, experts.astype(jnp.int32), order, group_sizes,
                       dropped, load)
     if balance:
@@ -647,7 +655,9 @@ def _ffn(xs, gate_up, down, sizes, interpret: bool, activation: str):
     ``h = xs W_[gate | up]``, what its backward pass reads again."""
     with jax.named_scope(scopes.MOE_EXPERTS):
         h = _gmm(xs, gate_up, sizes, interpret)
-        return _gmm(_gate(activation, h), down, sizes, interpret), h
+        with jax.named_scope(scopes.MOE_GATE):
+            act = _gate(activation, h)
+        return _gmm(act, down, sizes, interpret), h
 
 
 def _ffn_bwd(xs, h, gate_up, down, sizes, interpret: bool, activation: str,
@@ -655,10 +665,12 @@ def _ffn_bwd(xs, h, gate_up, down, sizes, interpret: bool, activation: str,
     """``_ffn``'s gradients by ``xs`` and both matrices, from ``xs`` and
     ``h``: no grouped matmul of the forward pass runs again."""
     with jax.named_scope(scopes.MOE_EXPERTS):
-        act, gate_bwd = jax.vjp(partial(_gate, activation), h)
+        with jax.named_scope(scopes.MOE_GATE):
+            act, gate_bwd = jax.vjp(partial(_gate, activation), h)
         d_act, d_down = _gmm_bwd(act, down, sizes, interpret, d_ys)
-        d_xs, d_gate_up = _gmm_bwd(xs, gate_up, sizes, interpret,
-                                   *gate_bwd(d_act))
+        with jax.named_scope(scopes.MOE_GATE):
+            d_h, = gate_bwd(d_act)
+        d_xs, d_gate_up = _gmm_bwd(xs, gate_up, sizes, interpret, d_h)
         return d_xs, d_gate_up, d_down
 
 
@@ -688,10 +700,12 @@ def _forward(rows: int, interpret: bool, activation: str, x2, weights, order,
     row lies past ``rows`` adding exactly zero (``_slots``)."""
     k = weights.shape[1]
     head, sizes = _head(rows, order, held_sizes)
-    with jax.named_scope(scopes.MOE_DISPATCH):
+    with jax.named_scope(scopes.MOE_DISPATCH), \
+            jax.named_scope(scopes.MOE_ROWS_IN):
         xs = _rows(x2, head // k)
     ys, h = _ffn(xs, gate_up, down, sizes, interpret, activation)
-    with jax.named_scope(scopes.MOE_DISPATCH):
+    with jax.named_scope(scopes.MOE_DISPATCH), \
+            jax.named_scope(scopes.MOE_ROWS_OUT):
         y = jnp.einsum("knd,nk->nd",
                        _slots(ys, inverse, k).astype(jnp.float32), weights)
     return y, (xs, h, ys)
@@ -710,14 +724,17 @@ def _backward(rows: int, interpret: bool, activation: str, kept, weights,
     k = weights.shape[1]
     head, sizes = _head(rows, order, held_sizes)
     with jax.named_scope(scopes.MOE_DISPATCH):
-        g_rows = _rows(g, head // k)
-        d_ys = g_rows * _rows(weights.reshape(-1), head)[:, None]
-        d_weights = _slots((ys.astype(jnp.float32) * g_rows).sum(-1),
-                           inverse, k).T
+        with jax.named_scope(scopes.MOE_ROWS_IN):
+            g_rows = _rows(g, head // k)
+            d_ys = g_rows * _rows(weights.reshape(-1), head)[:, None]
+        with jax.named_scope(scopes.MOE_ROWS_OUT):
+            d_weights = _slots((ys.astype(jnp.float32) * g_rows).sum(-1),
+                               inverse, k).T
     d_xs, d_gate_up, d_down = _ffn_bwd(xs, h, gate_up, down, sizes,
                                        interpret, activation,
                                        d_ys.astype(ys.dtype))
-    with jax.named_scope(scopes.MOE_DISPATCH):
+    with jax.named_scope(scopes.MOE_DISPATCH), \
+            jax.named_scope(scopes.MOE_ROWS_OUT):
         d_x2 = _slots(d_xs, inverse, k).sum(axis=0, dtype=jnp.float32)
     return d_x2.astype(d_xs.dtype), d_weights, d_gate_up, d_down
 
@@ -786,8 +803,9 @@ def routing_decision(x2, router, bias, *, top_k: int, scaling: float,
         routing = route(x2, router, bias, top_k=top_k, scaling=scaling,
                         first_held=first_held, held=held,
                         score_rule=score_rule, balance=balance)
-        inverse = jnp.zeros_like(routing.order).at[routing.order].set(
-            jnp.arange(n * top_k, dtype=jnp.int32))
+        with jax.named_scope(scopes.MOE_UNSORT):
+            inverse = jnp.zeros_like(routing.order).at[routing.order].set(
+                jnp.arange(n * top_k, dtype=jnp.int32))
     return routing._replace(inverse=inverse)
 
 
@@ -823,7 +841,8 @@ def apply_routing(routing: Routing, x2, gate_up, down, *,
     bound = row_bound(n, top_k, held, routing.load.shape[0])
     held_sizes = routing.group_sizes[:held]
     overflowed = held_sizes.sum() > bound if bound < n * top_k else None
-    with jax.named_scope(scopes.MOE_EXPERTS):
+    with jax.named_scope(scopes.MOE_EXPERTS), \
+            jax.named_scope(scopes.MOE_CAST):
         # cast once, outside the branch: both sides read the same copy
         matrices = gate_up.astype(dtype), down.astype(dtype)
     y = _experts(bound, interpret, activation, overflowed, (

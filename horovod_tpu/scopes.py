@@ -66,11 +66,34 @@ MOE_ROUTE = "moe_route"                # router matmul, scores, top-k, the
                                        # sort by expert: inside mlp, or at
                                        # the block's top where the router
                                        # reads the layer's input
+MOE_LOGITS = "moe_logits"              # inside moe_route: the float32
+                                       # router matmul and the score rule's
+                                       # sigmoid
+MOE_TOPK = "moe_topk"                  # inside moe_route: top-k, the chosen
+                                       # scores, the weights' normalisation
+                                       # (or softmax over the chosen), the
+                                       # scaling
+MOE_SORT = "moe_sort"                  # inside moe_route: the keys, the
+                                       # stable argsort by expert, the two
+                                       # counts (group sizes, load)
+MOE_UNSORT = "moe_unsort"              # inside moe_route: the scatter that
+                                       # inverts the sort
 MOE_BALANCE = "moe_balance"            # inside moe_route: the load-balance
                                        # loss (full softmax, its mean)
 MOE_DISPATCH = "moe_dispatch"          # inside mlp: rows gathered into
                                        # expert order and back
+MOE_ROWS_IN = "moe_rows_in"            # inside moe_dispatch: the tokens'
+                                       # rows (backward: their gradients'
+                                       # rows) gathered into expert order
+MOE_ROWS_OUT = "moe_rows_out"          # inside moe_dispatch: the experts'
+                                       # rows gathered back by choice and
+                                       # their weighted k-way float32 sum
 MOE_EXPERTS = "moe_experts"            # inside mlp: the grouped matmuls
+MOE_CAST = "moe_cast"                  # inside moe_experts: the float32
+                                       # masters' cast to the compute dtype
+                                       # (backward: the gradients' cast back)
+MOE_GATE = "moe_gate"                  # inside moe_experts: act(gate) * up
+                                       # between the two grouped matmuls
 MOE_SHARED = "moe_shared"              # inside mlp: the shared expert
 MTP = "mtp"                            # the multi-token-prediction module
                                        # and its pass through the head
@@ -102,4 +125,6 @@ SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ,
           SELECTIVE_SCAN, GMU, SHORT_CONV, SHORT_CONV_FILTER, KDA,
           KDA_PREP, KDA_SCAN, MLP,
           MOE_ROUTE, MOE_BALANCE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED,
-          MTP, EMBED, HEAD, STEM, KV_GATHER, KV_SCATTER, SAMPLE)
+          MTP, EMBED, HEAD, STEM, KV_GATHER, KV_SCATTER, SAMPLE,
+          MOE_LOGITS, MOE_TOPK, MOE_SORT, MOE_UNSORT, MOE_ROWS_IN,
+          MOE_ROWS_OUT, MOE_CAST, MOE_GATE)

@@ -27,6 +27,12 @@ from horovod_tpu.parallel.moe import (
     moe_mlp_ep,
 )
 
+# Compiled once a shape: op by op the one-hot formulation dispatches (and
+# compiles) a few hundred primitives a call.  The calls inside ``jax.grad``
+# and ``shard_map`` below are traced already and keep the plain function.
+moe_mlp_jit = jax.jit(moe_mlp, static_argnames=(
+    "top_k", "capacity_factor", "group_size"))
+
 EP = 4
 AXIS = "ep"
 D, FF, E = 16, 32, 8
@@ -68,7 +74,7 @@ def _reference_loop(x, p: MoEParams, top_k: int):
 @pytest.mark.parametrize("top_k", [1, 2])
 def test_dense_matches_reference_loop(top_k):
     x, p = _x(), _params()
-    y, aux = moe_mlp(x, p, top_k=top_k, capacity_factor=100.0)
+    y, aux = moe_mlp_jit(x, p, top_k=top_k, capacity_factor=100.0)
     ref = _reference_loop(x, p, top_k)
     np.testing.assert_allclose(np.asarray(y), ref, atol=1e-4, rtol=1e-4)
     assert np.isfinite(float(aux)) and float(aux) > 0
@@ -78,7 +84,7 @@ def test_capacity_overflow_drops_not_corrupts():
     """capacity_factor tiny -> most tokens dropped; the kept ones still
     match the reference loop's value, dropped ones are exactly zero."""
     x, p = _x(1), _params(1)
-    y, _ = moe_mlp(x, p, top_k=1, capacity_factor=0.01)  # capacity=1
+    y, _ = moe_mlp_jit(x, p, top_k=1, capacity_factor=0.01)  # capacity=1
     ref = _reference_loop(x, p, 1)
     y2 = np.asarray(y).reshape(-1, D)
     r2 = ref.reshape(-1, D)
@@ -91,7 +97,7 @@ def test_capacity_overflow_drops_not_corrupts():
 def test_uniform_router_aux_is_one():
     x = _x(2)
     p = _params(2)._replace(router=jnp.zeros((D, E)))  # uniform gates
-    _, aux = moe_mlp(x, p, top_k=2)
+    _, aux = moe_mlp_jit(x, p, top_k=2)
     # ce is exactly 1/E; me depends on argmax ties -> me sums to 1,
     # aux = E * sum(me * 1/E) = 1 regardless of tie-breaking
     np.testing.assert_allclose(float(aux), 1.0, rtol=1e-5)
@@ -122,7 +128,7 @@ def test_ep_matches_dense_per_shard():
     ys, auxs = [], []
     per = x.shape[0] // EP
     for r in range(EP):
-        y_r, aux_r = moe_mlp(x[r * per:(r + 1) * per], p, top_k=2)
+        y_r, aux_r = moe_mlp_jit(x[r * per:(r + 1) * per], p, top_k=2)
         ys.append(np.asarray(y_r))
         auxs.append(float(aux_r))
     np.testing.assert_allclose(
@@ -138,7 +144,7 @@ def test_gradients_flow():
         y, aux = moe_mlp(x, p, top_k=2)
         return (y ** 2).mean() + 0.01 * aux
 
-    grads = jax.grad(loss)(p)
+    grads = jax.jit(jax.grad(loss))(p)
     for name, g in grads._asdict().items():
         arr = np.asarray(g)
         assert np.all(np.isfinite(arr)), name
@@ -154,10 +160,14 @@ def test_gpt_moe_trains_and_sows_aux():
     from horovod_tpu.models.transformer import gpt
 
     tokens = jnp.asarray(
-        np.random.RandomState(0).randint(0, 1024, size=(2, 32)), jnp.int32
+        np.random.RandomState(0).randint(0, 1024, size=(2, 16)), jnp.int32
     )
-    model = gpt("nano", moe_experts=4, dtype=jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    # two of nano's three blocks and sixteen positions: every block is
+    # the same expert block, and the assertions ask for no size
+    model = gpt("nano", num_layers=2, moe_experts=4, dtype=jnp.float32)
+    # compiled, here and below: op by op the initialisers, the backward
+    # pass and the update dispatch (and compile) a few thousand primitives
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     # every block carries expert weights instead of fc1/fc2
     assert "w1" in params["params"]["block0"]
     assert "fc1" not in params["params"]["block0"]
@@ -171,14 +181,19 @@ def test_gpt_moe_trains_and_sows_aux():
         return nll + 0.01 * aux, (nll, aux)
 
     tx = optax.adam(1e-3)
-    opt_state = tx.init(params)
-    losses = []
-    for _ in range(5):
+
+    @jax.jit
+    def step(params, opt_state):
         (loss, (nll, aux)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(params)
         updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        return optax.apply_updates(params, updates), opt_state, nll, aux
+
+    opt_state = tx.init(params)
+    losses = []
+    for _ in range(3):
+        params, opt_state, nll, aux = step(params, opt_state)
         losses.append(float(nll))
         assert np.isfinite(float(aux))
     assert losses[-1] < losses[0], f"MoE model did not train: {losses}"
@@ -243,7 +258,7 @@ def test_grouped_routing_matches_reference_loop():
     matches the per-token loop when capacity is ample, across group
     boundaries (n=24, group_size=8 -> 3 groups)."""
     x, p = _x(6), _params(6)  # n = 24 tokens
-    y, aux = moe_mlp(x, p, top_k=2, capacity_factor=100.0, group_size=8)
+    y, aux = moe_mlp_jit(x, p, top_k=2, capacity_factor=100.0, group_size=8)
     ref = _reference_loop(x, p, 2)
     np.testing.assert_allclose(np.asarray(y), ref, atol=1e-4, rtol=1e-4)
     assert np.isfinite(float(aux))
@@ -268,8 +283,8 @@ def test_ep_grouped_matches_dense_grouped():
         )
     )(x, p.router, p.w1, p.b1, p.w2, p.b2)
     per = x.shape[0] // EP
-    ys = [np.asarray(moe_mlp(x[r * per:(r + 1) * per], p, top_k=2,
-                             group_size=8)[0]) for r in range(EP)]
+    ys = [np.asarray(moe_mlp_jit(x[r * per:(r + 1) * per], p, top_k=2,
+                                 group_size=8)[0]) for r in range(EP)]
     np.testing.assert_allclose(
         np.asarray(y_ep), np.concatenate(ys), atol=2e-5, rtol=2e-5
     )
@@ -284,7 +299,7 @@ def test_padded_group_routing_matches_reference_loop():
         np.random.RandomState(8).randn(2, 11, D), jnp.float32
     ) * 0.5  # n = 22
     p = _params(8)
-    y, aux = moe_mlp(x, p, top_k=2, capacity_factor=100.0, group_size=8)
+    y, aux = moe_mlp_jit(x, p, top_k=2, capacity_factor=100.0, group_size=8)
     ref = _reference_loop(x, p, 2)
     np.testing.assert_allclose(np.asarray(y), ref, atol=1e-4, rtol=1e-4)
     assert np.isfinite(float(aux))
