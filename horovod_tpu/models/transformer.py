@@ -1558,17 +1558,23 @@ class GPT(nn.Module):
                         jnp.dtype(cfg.dtype).itemsize))
             kdas = cfg.layer_types.count("kda")
             if kdas:
-                from ..ops import kda_prep  # noqa: PLC0415
+                from ..ops import kda, kda_prep  # noqa: PLC0415
                 from ..ops.kda import kept_mib  # noqa: PLC0415
 
                 # the delta-rule layers of the program, those whose
-                # float32 chain takes the kernels (all or none: they
-                # share a shape), the chunk their rule runs at and what
-                # one layer keeps for its backward
+                # float32 chain and those whose rule take the kernels
+                # (all or none: they share a shape), the chunk their
+                # rule runs at and what one layer keeps for its backward
                 get_registry().gauge("kda.layers").set(kdas)
                 get_registry().gauge("kda.prep_kernel_layers").set(
                     0 if kda_prep.plan(s, cfg.kda_heads, cfg.kda_head_dim,
                                        cfg.kda_conv) is None else kdas)
+                get_registry().gauge("kda.kernel_layers").set(
+                    0 if kda.plan(s, cfg.kda_heads, cfg.kda_head_dim,
+                                  cfg.kda_head_dim, cfg.kda_chunk,
+                                  cfg.kda_states_every,
+                                  jnp.dtype(cfg.dtype).itemsize) is None
+                    else kdas)
                 get_registry().gauge("kda.chunk").set(cfg.kda_chunk)
                 get_registry().gauge("kda.kept_mib").set(kept_mib(
                     tokens.shape[0], s, cfg.kda_heads, cfg.kda_head_dim,

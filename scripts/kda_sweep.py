@@ -5,9 +5,13 @@ the cell ``kimilin_train_s16384`` (1 x 16384 tokens, 32 heads of 128,
 bfloat16, decays as the model draws them): what the modules' constants
 were chosen from (PERF.md section 6, PRs 51 and 52).
 
-The rule (``ops/kda.py``; the default): each variant is
-``chunk,states_every,sub_block,precision`` (``highest`` or ``high`` for
-the Gram products and the triangular inverse).  The float32 chain in
+The rule (``ops/kda.py``; the default): a variant is
+``chunk,states_every,sub_block,precision`` for the XLA form (``highest``
+or ``high`` for the Gram products and the triangular inverse) or
+``kernel,chunk,states_every,head_block,sub_block`` for the kernel pair
+(timed like the XLA form and, ``kernel_*_ms``, each call alone on the
+``[batch, seq, heads x dim]`` arrays it reads and writes).  The float32
+chain in
 front of it (``--chain``; ``ops/kda_prep.py``): the variant ``xla`` is
 the chain as XLA compiles it (``models/transformer.py:kda_prep_chain``),
 any other is ``token_tile,head_block,rows`` for the kernel pair (timed
@@ -42,9 +46,10 @@ def main():
         args.variants = [
             "xla", "256,4,64", "512,4,64", "512,8,64", "1024,8,64",
             "512,8,128", "512,8,32", "512,16,64"] if args.chain else [
-            "64,4,16,highest", "64,8,16,highest", "64,16,16,highest",
-            "64,4,8,highest", "64,4,32,highest", "64,4,16,high",
-            "32,8,16,highest", "128,2,16,highest"]
+            "64,4,16,highest", "kernel,64,4,4,16", "kernel,64,4,2,16",
+            "kernel,64,4,4,8", "kernel,64,4,4,32", "kernel,64,2,8,16",
+            "kernel,64,8,2,16", "64,8,16,highest", "64,4,8,highest",
+            "64,4,16,high"]
 
     import jax
     import jax.numpy as jnp
@@ -73,11 +78,19 @@ def main():
 
     timed = functools.partial(_timed, inputs=inputs, iters=args.iters)
     base = None
+    plan = kda_ops.plan
     for variant in args.variants:
-        chunk, every, sub, precision = variant.split(",")
-        kda_ops.SUB_BLOCK = int(sub)
-        kda_ops._FULL = {"highest": lax.Precision.HIGHEST,
-                         "high": lax.Precision.HIGH}[precision]
+        kernel = variant.startswith("kernel,")
+        if kernel:
+            _, chunk, every, hb, sub = variant.split(",")
+            kda_ops.HEAD_BLOCK, kda_ops.SUB_BLOCK = int(hb), int(sub)
+            kda_ops.plan = plan
+        else:
+            chunk, every, sub, precision = variant.split(",")
+            kda_ops.SUB_BLOCK = int(sub)
+            kda_ops._FULL = {"highest": lax.Precision.HIGHEST,
+                             "high": lax.Precision.HIGH}[precision]
+            kda_ops.plan = lambda *shape: None
         jax.clear_caches()
         rule = lambda *t: kda_ops.kda(*t, chunk=int(chunk),
                                       states_every=int(every))
@@ -99,7 +112,38 @@ def main():
                     "fwd_bwd_ms": both_ms,
                     "apart_o_dq_dk_dv_dg_dbeta": _apart(got, base),
                     "device": jax.devices()[0].device_kind}
+            if kernel:
+                line.update(_calls_alone(kda_ops, inputs, w, int(chunk),
+                                         int(every), args.iters))
         _report(line)
+
+
+def _calls_alone(kda_ops, inputs, w, chunk, every, iters):
+    """The two calls by themselves, on arrays with the heads already
+    folded into lanes (a ``[batch, seq, heads, dim]`` argument costs a
+    copy that the step, whose neighbours are kernels too, does not
+    pay), in ms and in us a head and chunk."""
+    import jax
+
+    b, s, h, d = inputs[0].shape
+    tiles = kda_ops.plan(s, h, d, d, chunk, every, inputs[2].dtype.itemsize)
+    if tiles is None:
+        return {"kernel": None}
+    n = kda_ops.group_chunks(s // chunk, every)
+    folded = tuple(t.reshape(b, s, -1) for t in inputs[:4]) + (inputs[4],)
+    unfold = lambda ts: tuple(t.reshape(b, s, h, d) for t in ts)
+    fwd = jax.jit(lambda *t: kda_ops._kernel_forward(
+        *unfold(t[:4]), t[4], chunk, n, *tiles))
+    fwd_ms, (_, states) = _timed(fwd, folded, iters)
+    bwd = jax.jit(lambda *t: tuple(
+        x.reshape(b, s, -1) for x in kda_ops._kernel_backward(
+            *unfold(t[:4]), t[4], t[5], t[6].reshape(b, s, h, d), chunk, n,
+            *tiles)))
+    bwd_ms, _ = _timed(bwd, folded + (states, w.reshape(b, s, -1)), iters)
+    per = 1e3 / (b * h * (s // chunk))
+    return {"kernel": list(tiles), "kernel_fwd_ms": fwd_ms,
+            "kernel_bwd_ms": bwd_ms, "fwd_us_head_chunk": fwd_ms * per,
+            "bwd_us_head_chunk": bwd_ms * per}
 
 
 def _timed(fn, inputs, iters):

@@ -520,6 +520,11 @@ def test_a_kda_block_carries_its_scopes_and_the_gauges_count_it():
     registry = get_registry()
     assert registry.gauge("kda.layers").value == 4
     assert registry.gauge("kda.prep_kernel_layers").value == 4
+    assert registry.gauge("kda.kernel_layers").value == 4
+    assert '/jvp(GPT)/block0/kda/kda_scan/jit(_kernel_forward)"' in text
+    assert ('/transpose(jvp(GPT))/block0/kda/kda_scan/'
+            'jit(_kernel_backward)"' in text)
+    assert '"kda_fwd/' in text and '"kda_bwd/' in text
     assert registry.gauge("kda.chunk").value == 16
     assert registry.gauge("kda.kept_mib").value == kept_mib(
         2, SEQ, 4, 16, 16, 16, 2, 4)

@@ -18,6 +18,7 @@ from jax.sharding import Mesh
 
 import horovod_tpu as hvd
 from horovod_tpu.ops.flash_attention import flash_attention
+from horovod_tpu.ops import kda as kda_ops
 from horovod_tpu.ops.kda import kda
 
 from test_tpu_compile import (compiled_kernels, no_compile_cache,  # noqa: F401
@@ -49,16 +50,20 @@ def test_kimi_latent_flash_call_compiles_for_v5e(one_chip, direction):
         assert "flash_bwd_dkdv" in text and "flash_bwd_dq" not in text
 
 
-def test_kda_rule_compiles_for_v5e(one_chip):
+def test_kda_rule_compiles_for_v5e(one_chip, compiled_kernels):
     """One layer's chunked gated delta rule, forward and backward, at the
     cell's shape (1 x 16 384 tokens, 32 heads of 128, bfloat16, chunk 64,
-    a state every fourth chunk): both directions are XLA's loops over the
-    groups (no Pallas call yet: ROADMAP queue A), and neither holds a
-    ``[seq, heads, 128, 128]`` array (32 GiB) nor the whole sequence's
-    ``[chunks, heads, 64, 64]`` matrices at once."""
+    a state every fourth chunk): ``plan`` gives it the kernel pair at
+    four heads a program, Mosaic takes both inside the 16 MiB they state,
+    and the compiled program holds ``kda_fwd`` and ``kda_bwd`` under the
+    rule's scope and no loop.  Its temporaries are the copies between
+    ``[seq, heads, 128]`` arguments and the kernels' ``[seq, heads x
+    128]`` (which the step, whose neighbours are kernels too, does not
+    make), far from a ``[seq, heads, 128, 128]`` array (32 GiB)."""
     def shaped(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    assert kda_ops.plan(16384, 32, 128, 128, 64, 4, 2) == (4, False)
     wide = (1, 16384, 32, 128)
     args = (shaped(*wide), shaped(*wide), shaped(*wide),
             shaped(*wide, dtype=jnp.float32),
@@ -68,8 +73,9 @@ def test_kda_rule_compiles_for_v5e(one_chip):
         lambda *t: rule(*t).astype(jnp.float32).sum(),
         argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
     text = compiled.as_text()
-    assert "while" in text and "tpu_custom_call" not in text
-    assert "jvp(kda_scan)" in text and "transpose(jvp(kda_scan))" in text
+    assert "while" not in text
+    assert "jvp(kda_scan)/jit(_kernel_forward)/kda_fwd" in text
+    assert "transpose(jvp(kda_scan))/jit(_kernel_backward)/kda_bwd" in text
     temporaries = compiled.memory_analysis().temp_size_in_bytes
     assert temporaries < 3000 * 2 ** 20, temporaries / 2 ** 20
 
@@ -84,10 +90,11 @@ def test_kimi_cell_step_compiles_for_v5e_at_two_layers(topo,
     ``benchmark/tools/compile_check.py kimilin_train_s16384`` is how it
     is compiled by hand: 12.79 GiB, PERF.md section 4): the flash forward
     and ONE backward kernel at keys of 192 over values of 128, the
-    grouped matmuls, the rule's scope forward and backward with its
-    float32 chain beside it as the two kernels of ``ops/kda_prep.py``
-    (forward, recomputed forward and ``transpose(...)``; the gauge counts
-    the layer), and the two layers' share of the memory."""
+    grouped matmuls, the rule's scope forward and backward as the two
+    kernels of ``ops/kda.py`` with its float32 chain beside it as the two
+    of ``ops/kda_prep.py`` (forward, recomputed forward and
+    ``transpose(...)``; the gauges count the layer), and the two layers'
+    share of the memory."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
@@ -115,11 +122,16 @@ def test_kimi_cell_step_compiles_for_v5e_at_two_layers(topo,
                  "rematted_computation/block0/kda/kda_prep/jit(_forward)/"
                  "kda_prep_fwd",
                  "transpose(jvp(GPT))/jvp(GPT)/checkpoint/block0/kda/"
-                 "kda_prep/jit(_backward)/kda_prep_bwd"):
+                 "kda_prep/jit(_backward)/kda_prep_bwd",
+                 "/jvp(GPT)/block0/kda/kda_scan/jit(_kernel_forward)/"
+                 "kda_fwd",
+                 "transpose(jvp(GPT))/jvp(GPT)/checkpoint/block0/kda/"
+                 "kda_scan/jit(_kernel_backward)/kda_bwd"):
         assert name in text, name
     from horovod_tpu.obs.registry import get_registry
 
     assert get_registry().gauge("kda.prep_kernel_layers").value == 1
+    assert get_registry().gauge("kda.kernel_layers").value == 1
     assert get_registry().gauge("kda.layers").value == 1
     mem = compiled.memory_analysis()
     # layer 1 of the cell, the latent layer, table, head and final norm
