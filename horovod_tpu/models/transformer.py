@@ -279,6 +279,21 @@ class TransformerConfig:
     kda_conv: int = 4
     kda_chunk: int = 64
     kda_states_every: int = 4
+    # The attention mask.  None: causal (with the layer type's window).
+    # B, a power of two: the block-diffusion training mask
+    # (arXiv:2503.09573; models/block_diffusion.py makes the step's
+    # inputs).  The sequence a call sees is then a noised copy of L
+    # tokens followed by the clean one, both in blocks of B: a noised row
+    # sees its own block's noised rows and the clean rows of strictly
+    # earlier blocks, a clean row the clean rows of its own and earlier
+    # blocks.  L is half the sequence; positions repeat (0..L-1 twice),
+    # and the head runs over the noised half alone.
+    block_diffusion: Optional[int] = None
+    # Look the tokens up in the float32 table and cast after, so that a
+    # row's gradient adds up in float32: for a step that looks ONE row up
+    # thousands of times (a mask token's), whose sum in the compute dtype
+    # loses a quarter of its norm.  The same values forward.
+    embed_grad_float32: bool = False
 
     def __post_init__(self):
         if self.num_kv_heads is not None:
@@ -399,6 +414,25 @@ class TransformerConfig:
                 raise ValueError(
                     "differential attention is implemented for the "
                     "attention layers that split one qkv, not for 'mla'")
+        if self.block_diffusion is not None:
+            block = self.block_diffusion
+            if block < 1 or block & (block - 1):
+                raise ValueError(
+                    f"block_diffusion={block} is a block length: a power "
+                    f"of two")
+            masked_otherwise = set(self.layer_types or ()) - {
+                "attention", "full_attention"}
+            if (masked_otherwise or self.attention_window is not None
+                    or self.differential_attention or self.mtp_modules
+                    or self.attention_impl not in ("flash", "reference")):
+                raise ValueError(
+                    f"block_diffusion={block} is the mask of every layer: "
+                    f"it goes with plain attention layers on the flash or "
+                    f"reference schedule, without a window, differential "
+                    f"attention or a prediction module (layer_types="
+                    f"{self.layer_types!r}, attention_window="
+                    f"{self.attention_window!r}, attention_impl="
+                    f"{self.attention_impl!r})")
         if self.mtp_modules not in (0, 1):
             raise ValueError(
                 f"mtp_modules={self.mtp_modules}: one prediction module is "
@@ -558,15 +592,21 @@ def require_gpt2_block(cfg: TransformerConfig, who: str) -> None:
 
 def _attend(cfg: TransformerConfig, q, k, v, positions,
             layer_type: Optional[str] = None):
-    """Dispatch to the configured attention schedule (always causal).
+    """Dispatch to the configured attention schedule under the
+    configuration's mask: causal, or with ``cfg.block_diffusion`` the
+    block-diffusion one over a noised copy and the clean one (the rows
+    decide it, not ``positions``, which repeat there).
     ``positions``: int [s_local] global positions of the local rows —
     used by schedules that mask in global coordinates.  ``layer_type``
     decides the window (``cfg.window_of``); a call that has one traces
     under the scope ``attn_window``, so a device trace tells the banded
-    kernels from the full ones, and the call of a layer that reads
-    another layer's keys and values under ``attn_cross``."""
+    kernels from the full ones, the call of a layer that reads another
+    layer's keys and values under ``attn_cross``, and a call under the
+    block-diffusion mask under ``attn_block_diffusion``."""
     window = cfg.window_of(layer_type)
-    with (jax.named_scope(scopes.ATTN_WINDOW) if window is not None
+    with (jax.named_scope(scopes.ATTN_BLOCK_DIFFUSION)
+          if cfg.block_diffusion is not None
+          else jax.named_scope(scopes.ATTN_WINDOW) if window is not None
           else jax.named_scope(scopes.ATTN_CROSS)
           if layer_type == "cross_attention" else contextlib.nullcontext()):
         return _attend_schedule(cfg, q, k, v, positions, layer_type, window)
@@ -622,8 +662,9 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
 
         # counted while the step is traced, like remat.kept_values: what
         # the kernels' own plan says of this call
-        call = dict(causal=True, block_q=cfg.flash_block_q,
-                    block_k=cfg.flash_block_k, window=window)
+        call = dict(causal=cfg.block_diffusion is None,
+                    block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
+                    window=window, block_diffusion=cfg.block_diffusion)
         plan = flash_plan(q, k, v, **call)
         label = layer_type or "attention"
         gauge = lambda name: get_registry().gauge(name, layer_type=label)
@@ -631,6 +672,19 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
         gauge("flash.tiles_grid").set(plan.tiles_grid)
         gauge("flash.tiles_mask").set(plan.tiles_mask)
         gauge("flash.bwd_kernels").set(plan.bwd_kernels)
+        if cfg.block_diffusion is not None:
+            from .block_diffusion import visible_pairs  # noqa: PLC0415
+
+            # the mask's own counts: the block, the rows of both copies,
+            # the (query, key) pairs it shows and those inside the live
+            # tiles the kernels compute, over batch and heads
+            rows = q.shape[0] * q.shape[2]
+            get_registry().gauge("bd.block").set(cfg.block_diffusion)
+            get_registry().gauge("bd.rows").set(q.shape[1])
+            gauge("bd.visible_pairs").set(rows * visible_pairs(
+                q.shape[1] // 2, cfg.block_diffusion))
+            gauge("bd.live_tile_pairs").set(
+                plan.tiles_live * plan.block_q * plan.block_k)
         return flash_attention(q, k, v, scale=cfg.attention_scale, **call)
     if window is not None and cfg.attention_impl != "reference":
         raise ValueError(
@@ -682,7 +736,9 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
     # local_attention masks from scalar offsets: valid because every
     # non-zigzag layout is contiguous per shard (zigzag never routes here)
     return local_attention(
-        q, k, v, causal=True, scale=cfg.attention_scale, window=window,
+        q, k, v, causal=cfg.block_diffusion is None,
+        scale=cfg.attention_scale, window=window,
+        block_diffusion=cfg.block_diffusion,
         q_offset=positions[0], kv_offset=positions[0]
     )
 
@@ -1476,6 +1532,12 @@ class GPT(nn.Module):
       but the flash/reference/ring attention impls mask assuming
       contiguous per-shard rows.
 
+    Under ``cfg.block_diffusion`` ``tokens`` holds a noised copy of
+    ``seq // 2`` tokens and then the clean one
+    (``models/block_diffusion.py:paired``), the default positions repeat
+    (``0 .. seq // 2 - 1`` twice) and the logits are those of the noised
+    half alone, ``[batch, seq // 2, vocab]``.
+
     Returns logits ``[batch, seq, vocab]`` in fp32.  With a
     multi-token-prediction module (``cfg.mtp_modules``) and
     ``next_tokens`` (``tokens`` shifted left by one: position ``i``
@@ -1492,7 +1554,13 @@ class GPT(nn.Module):
         wte = nn.Embed(cfg.vocab_size, cfg.emb_dim, dtype=cfg.dtype,
                        name="wte")
         with jax.named_scope(scopes.EMBED):
-            tok = wte(tokens)
+            if cfg.embed_grad_float32:
+                # the module casts the table and then gathers, so a row's
+                # gradient would add up in the compute dtype
+                tok = jnp.take(wte.embedding, tokens, axis=0).astype(
+                    cfg.dtype)
+            else:
+                tok = wte(tokens)
             if cfg.embedding_multiplier != 1.0:
                 tok = tok * cfg.embedding_multiplier
         s = tokens.shape[1]
@@ -1509,6 +1577,9 @@ class GPT(nn.Module):
                     "(zigzag_positions(axis_index, P, s_local))"
                 )
             positions = pos_offset + jnp.arange(s)
+            if cfg.block_diffusion is not None:
+                # a noised token and its clean twin stand at one position
+                positions = jnp.concatenate([positions[:s // 2]] * 2)
         x = tok
         if cfg.pos_embedding == "learned":
             pos_table = self.param(
@@ -1612,6 +1683,10 @@ class GPT(nn.Module):
 
         # Final norm, LM head and the fp32 cast under one scope, like
         # the raw-weights epilogue (tensor_parallel._gpt_head).
+        if cfg.block_diffusion is not None:
+            # the clean copy's last-layer outputs feed no loss (its keys
+            # and values fed the noised rows in every layer)
+            x = x[:, :s // 2]
         with jax.named_scope(scopes.HEAD):
             logits = head(_norm(cfg, "lnf")(x))
         if cfg.mtp_modules == 0 or (next_tokens is None
@@ -1867,6 +1942,35 @@ GPT_CONFIGS = {
         # block: keep each block's input and, as every policy does, what
         # its kernels made (the rule's o 128 MiB and states 128 MiB, the
         # latent layer's o 128 MiB and lse 2 MiB)
+        remat_policy="nothing_saveable",
+    ),
+    # https://huggingface.co/JetLM/SDAR-30B-A3B-Chat config.json
+    # (model_type sdar_moe; arXiv:2510.06303, trained as a block-diffusion
+    # model, arXiv:2503.09573): 32 query heads over 4 key/value heads of
+    # 128, a norm over each head of q and k, rotary over all 128 channels
+    # (theta 1e6); in every layer 128 routed experts of 768, 8 a token,
+    # weights the softmax over the eight chosen logits (norm_topk_prob),
+    # a silu gate, no shared expert, no dense layer; an untied head.  The
+    # step runs a noised copy beside the clean one under the
+    # block-diffusion mask in blocks of 4 (the Chat release's block
+    # length; config.json has no key for it) and a load-balance loss
+    # whose coefficient config.json does not publish either: 0.1, the
+    # one the benchmark's cell was measured at (its configuration file
+    # says why not Qwen3-MoE's 0.001).
+    # Training path only (require_gpt2_block says who refuses it).
+    "sdar-30b-a3b-chat": TransformerConfig(
+        vocab_size=151936, num_layers=48, emb_dim=2048, max_len=32768,
+        num_heads=32, num_kv_heads=4, head_size=128, qk_norm=True,
+        pos_embedding="rope", rope_theta=1e6, block_diffusion=4,
+        # the mask token's row is looked up once a masked position
+        embed_grad_float32=True,
+        mlp_ratio=3, mlp="silu_gated", norm="rmsnorm", norm_eps=1e-6,
+        use_bias=False, tie_embeddings=False,
+        routed_experts=128, routed_top_k=8, routed_width=768,
+        routed_scores="softmax_chosen", routed_balance_coef=0.1,
+        # 16384 x 5120 queries, keys and values a block: keep each
+        # block's input and, as every policy does, what its kernels made
+        # (o 128 MiB and lse 2 MiB a block)
         remat_policy="nothing_saveable",
     ),
 }

@@ -39,7 +39,10 @@ chip"):
   ever transposed.
 * The grids walk the live tiles alone.  A causal call's upper triangle
   is never visited, not just masked; with a window, nor are the tiles
-  entirely below the band.  ``flash_plan`` lists one head's live pairs
+  entirely below the band; under the block-diffusion mask (a noised
+  copy beside the clean one: ``flash_attention`` says what sees what),
+  nor a noised key's tiles outside its own blocks, nor any noised key's
+  from a clean row.  ``flash_plan`` lists one head's live pairs
   (``_tile_live``, the one predicate) and every kernel takes them as a
   small int32 table by scalar prefetch (``pltpu.PrefetchScalarGridSpec``:
   the table is in SMEM before the body runs): the index maps and the
@@ -142,6 +145,11 @@ class FlashPlan:
     block_k: int
     causal: bool
     window: Optional[int]       # None where it reaches every earlier key
+    # Which mask the call runs: "full" (none), "causal", "window" (the
+    # triangle under a band) or "block_diffusion", whose ``block``
+    # is the block length ``B`` (``None`` under the other three).
+    mask: str
+    block: Optional[int]
     # Over all batch x head rows: the (q, k) tiles ``_tile_live`` admits
     # under the call's mask, the steps the kernels' grids walk, and the
     # whole ``nq x nk`` rectangle.  The grid walks the live tiles alone
@@ -175,13 +183,19 @@ class FlashPlan:
 
 
 def flash_plan(q, k, v, *, causal: bool = False, block_q: int = 512,
-               block_k: int = 256,
-               window: Optional[int] = None) -> FlashPlan:
+               block_k: int = 256, window: Optional[int] = None,
+               block_diffusion: Optional[int] = None) -> FlashPlan:
     """The :class:`FlashPlan` of ``flash_attention(q, k, v, ...)`` with
     the same keyword arguments, from the shapes and the dtype of ``q``,
     ``k`` and ``v`` alone (arrays or ``jax.ShapeDtypeStruct``); raises
     what that call would raise of them.  Plain Python, for the call
     itself and for whoever counts while a step is traced.
+
+    The plan's ``mask`` says which of four the call runs: none,
+    ``causal``, ``causal`` with a ``window``, or ``block_diffusion=B``,
+    under which the tiles are cut from half the sequence so that none
+    straddles the noised and the clean copy.  Tiles, table and VMEM follow from the
+    shapes in the same way under each.
 
     The forward holds a kv row resident wherever
     ``_fwd_resident_vmem_bytes`` fits ``_FUSED_BWD_VMEM_LIMIT`` and
@@ -214,10 +228,27 @@ def flash_plan(q, k, v, *, causal: bool = False, block_q: int = 512,
             raise ValueError(f"window must be >= 1, got {window}")
         if window >= s:
             window = None  # full causal; skip/mask logic not needed
-    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
+    diffusion = None
+    if block_diffusion is not None:
+        if causal or window is not None:
+            raise ValueError(
+                "block_diffusion is a mask of its own: it takes neither "
+                "causal=True nor a window")
+        if (block_diffusion < 1 or block_diffusion & (block_diffusion - 1)
+                or s % 2 or (s // 2) % block_diffusion):
+            raise ValueError(
+                f"block_diffusion={block_diffusion} must be a power of two "
+                f"that divides half the sequence (the noised copy, then "
+                f"the clean one), got {s} rows")
+        diffusion = (s // 2, block_diffusion)
+    mask = ("block_diffusion" if diffusion else "window"
+            if window is not None else "causal" if causal else "full")
+    # under the block-diffusion mask no tile straddles the two copies
+    tiled = s // 2 if diffusion else s
+    bq, bk = _pick_block(tiled, block_q), _pick_block(tiled, block_k)
     nq, nk = s // bq, s // bk
     live = tuple((i, j) for i in range(nq) for j in range(nk)
-                 if _tile_live(i, j, bq, bk, causal, window))
+                 if _tile_live(i, j, bq, bk, causal, window, diffusion))
     itemsize = jnp.dtype(q.dtype).itemsize
 
     fwd = _fwd_resident_vmem_bytes(s, d, dv, bq, bk, itemsize)
@@ -244,7 +275,8 @@ def flash_plan(q, k, v, *, causal: bool = False, block_q: int = 512,
     walks_table = 4 * columns * len(live) <= _TILE_TABLE_SMEM_LIMIT
     return FlashPlan(
         heads=h, kv_heads=hkv, block_q=bq, block_k=bk, causal=causal,
-        window=window, tiles_live=b * h * len(live),
+        window=window, mask=mask, block=block_diffusion,
+        tiles_live=b * h * len(live),
         tiles_grid=b * h * (len(live) if walks_table else nq * nk),
         tiles_mask=b * h * nq * nk,
         live_tiles=live if walks_table else None,
@@ -263,6 +295,7 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 256,
     window: Optional[int] = None,
+    block_diffusion: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Flash attention over ``q`` ``[batch, seq, heads, head_dim]``, ``k``
@@ -287,9 +320,22 @@ def flash_attention(
     and backward (the same mechanism as the causal upper-triangle skip),
     so compute scales with ``S*W``, not ``S^2``; ``W >= S`` degenerates
     to plain causal.
+
+    ``block_diffusion=B`` (a power of two; neither ``causal`` nor a
+    window beside it) is the training mask of a block-diffusion model
+    (arXiv:2503.09573): the ``S = 2L`` rows are a noised copy of ``L``
+    tokens, then the clean one, each in blocks of ``B``.  A noised row
+    sees the noised rows of its own block and the clean rows of every
+    EARLIER block; a clean row sees the clean rows of its own block and
+    of every earlier one; no clean row sees a noised key.  Every row sees
+    its own block, so no softmax is empty.  The tiles are cut from ``L``
+    (none straddles the two copies) and the dead ones are skipped like
+    the causal call's: at 16384 rows of 512 x 256 tiles, 576 of a head's
+    2048 are live.
     """
     plan = flash_plan(q, k, v, causal=causal, block_q=block_q,
-                      block_k=block_k, window=window)
+                      block_k=block_k, window=window,
+                      block_diffusion=block_diffusion)
     b, s, h, d = q.shape
     scale_ = scale if scale is not None else d ** -0.5
     if interpret is None:
@@ -371,15 +417,73 @@ _Q_MAJOR_COLUMNS = 3    # i, j, edges
 _K_MAJOR_COLUMNS = 6    # j, g, i, edges, and the (g, i) that flushes next
 
 
-def _tile_live(i, j, bq: int, bk: int, causal: bool, window: Optional[int]):
+def _tile_live(i, j, bq: int, bk: int, causal: bool, window: Optional[int],
+               diffusion: Optional[tuple] = None):
     """Does Q tile ``i`` see K tile ``j``?  Causal: not if the K tile is
     entirely above the diagonal; with a window, not if it is entirely
-    below the band either.  One definition for the plan's table (Python
-    ints) and for the kernels that keep the rectangle (traced)."""
+    below the band either.  ``diffusion=(L, B)``, the block-diffusion
+    mask over a noised copy of ``L`` rows and then the clean one (no tile
+    straddles them): by the blocks of ``B`` the two tiles touch, each
+    counted within its own copy, a noised Q tile sees a noised K tile
+    that shares a block with it and a clean one that holds a strictly
+    earlier block; a clean Q tile sees a clean K tile that holds its own
+    or an earlier block, and no noised one.  One definition for the
+    plan's table (Python ints) and for the kernels that keep the
+    rectangle (traced): comparisons, products and shifts alone."""
+    if diffusion is not None:
+        half, block = diffusion
+        shift = block.bit_length() - 1
+        clean_q, clean_k = i * bq >= half, j * bk >= half
+        q0, k0 = i * bq - half * clean_q, j * bk - half * clean_k
+        q_first, q_last = q0 >> shift, (q0 + bq - 1) >> shift
+        k_first, k_last = k0 >> shift, (k0 + bk - 1) >> shift
+        same_copy = clean_q == clean_k
+        return ((k_first <= q_last) & same_copy
+                & (clean_q | (q_first <= k_last))
+                | (k_first < q_last) & clean_k & (clean_q != clean_k))
     live = (j * bk <= (i + 1) * bq - 1) if causal else True
     if window is not None:
         live = live & ((j + 1) * bk - 1 >= i * bq - (window - 1))
     return live
+
+
+def _plan_tile_live(plan: FlashPlan, i, j, s: int):
+    """``_tile_live`` of a plan's tiles over ``s`` rows."""
+    return _tile_live(
+        i, j, plan.block_q, plan.block_k, plan.causal, plan.window,
+        (s // 2, plan.block) if plan.mask == "block_diffusion" else None)
+
+
+def _mask_tile(plan: FlashPlan, x, fill, i, j, s: int):
+    """The transposed tile ``x`` [bk, bq] (keys on sublanes, queries on
+    lanes) of Q tile ``i`` and K tile ``j`` with ``fill`` wherever the
+    plan's mask hides the key from the query.  One definition for the
+    forward and the three backward kernels."""
+    if plan.mask == "full":
+        return x
+    bk, bq = x.shape
+    if plan.mask == "block_diffusion":
+        # block indices within each row's own copy, the keys' as a
+        # column and the queries' as a row: a noised key is seen from
+        # its own block (0 <= ahead <= 0), a clean key by a noised query
+        # from strictly later blocks (ahead >= 1) and by a clean one
+        # from its own and later ones (ahead >= 0)
+        half, shift = s // 2, plan.block.bit_length() - 1
+        clean_q, clean_k = i * bq >= half, j * bk >= half
+        k_block = (j * bk - jnp.where(clean_k, half, 0)
+                   + lax.broadcasted_iota(jnp.int32, (bk, 1), 0)) >> shift
+        q_block = (i * bq - jnp.where(clean_q, half, 0)
+                   + lax.broadcasted_iota(jnp.int32, (1, bq), 1)) >> shift
+        ahead = q_block - k_block
+        nearest = jnp.where(clean_k & jnp.logical_not(clean_q), 1, 0)
+        farthest = jnp.where(clean_k, s, 0)
+        return jnp.where((ahead >= nearest) & (ahead <= farthest), x, fill)
+    k_pos = j * bk + lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+    q_pos = i * bq + lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+    x = jnp.where(k_pos > q_pos, fill, x)
+    if plan.window is not None:
+        x = jnp.where(k_pos < q_pos - (plan.window - 1), fill, x)
+    return x
 
 
 class _Walk(NamedTuple):
@@ -426,8 +530,8 @@ def _q_major_walk(plan: FlashPlan, nq: int, nk: int) -> _Walk:
 
         def edges(t):
             i, j = tile(t)
-            return j == 0, j == nk - 1, _tile_live(
-                i, j, plan.block_q, plan.block_k, plan.causal, plan.window)
+            return j == 0, j == nk - 1, _plan_tile_live(
+                plan, i, j, nq * plan.block_q)
 
         return _Walk((), nq * nk, tile, edges)
     table = _q_major_table(plan.live_tiles)
@@ -483,8 +587,8 @@ def _k_major_walk(plan: FlashPlan, nq: int, nk: int, group: int) -> _Walk:
         def edges(t):
             j, _, i = tile(t)
             return (t % pairs == 0, t % pairs == pairs - 1, j == 0,
-                    j == nk - 1, _tile_live(i, j, plan.block_q, plan.block_k,
-                                            plan.causal, plan.window))
+                    j == nk - 1, _plan_tile_live(plan, i, j,
+                                                 nq * plan.block_q))
 
         def flushing(t):
             # dq's block index moves only in the last K tile's sweep
@@ -603,7 +707,7 @@ def _flash_fwd_kernel(q, k, v, plan: FlashPlan, scale, interpret):
     z, s, d = q.shape
     dv = v.shape[-1]
     bq, bk, h, hkv = plan.block_q, plan.block_k, plan.heads, plan.kv_heads
-    causal, window, resident = plan.causal, plan.window, plan.fwd_kv_resident
+    resident = plan.fwd_kv_resident
     nq, nk = s // bq, s // bk
     walk = _q_major_walk(plan, nq, nk)
     columns = len(walk.tables)
@@ -633,18 +737,9 @@ def _flash_fwd_kernel(q, k, v, plan: FlashPlan, scale, interpret):
             qb = q_ref[0].astype(jnp.float32) * scale  # [bq, d]
             kb = k_ref[0, rows, :].astype(jnp.float32)  # [bk, d]
             vb = v_ref[0, rows, :].astype(jnp.float32)
-            st = jnp.dot(kb, qb.T, preferred_element_type=jnp.float32)
-            if causal:
-                k_pos = j * bk + lax.broadcasted_iota(
-                    jnp.int32, (bk, bq), 0
-                )
-                q_pos = i * bq + lax.broadcasted_iota(
-                    jnp.int32, (bk, bq), 1
-                )
-                st = jnp.where(k_pos > q_pos, NEG_INF, st)
-                if window is not None:
-                    st = jnp.where(k_pos < q_pos - (window - 1),
-                                   NEG_INF, st)
+            st = _mask_tile(
+                plan, jnp.dot(kb, qb.T, preferred_element_type=jnp.float32),
+                NEG_INF, i, j, s)
             m_prev = m_ref[...]                        # [1, bq]
             m_new = jnp.maximum(m_prev, st.max(0, keepdims=True))
             p = jnp.exp(st - m_new)
@@ -749,7 +844,6 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, plan: FlashPlan, scale,
     z, s, d = q.shape
     z_kv, dv = k.shape[0], v.shape[-1]
     bq, bk, h, hkv = plan.block_q, plan.block_k, plan.heads, plan.kv_heads
-    causal, window = plan.causal, plan.window
     form, vmem_limit = plan.bwd_form, plan.bwd_vmem_bytes
     group = h // hkv
     nq, nk = s // bq, s // bk
@@ -778,13 +872,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, plan: FlashPlan, scale,
         vb = v_ref[0].astype(f32)
         dob = do_ref[0].astype(f32)
         st = jnp.dot(kb, qb.T, preferred_element_type=f32) * scale
-        p = jnp.exp(st - lse_ref[0, 0])
-        if causal:
-            k_pos = j * bk + lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
-            q_pos = i * bq + lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
-            p = jnp.where(k_pos > q_pos, 0.0, p)
-            if window is not None:
-                p = jnp.where(k_pos < q_pos - (window - 1), 0.0, p)
+        p = _mask_tile(plan, jnp.exp(st - lse_ref[0, 0]), 0.0, i, j, s)
         dp = jnp.dot(vb, dob.T, preferred_element_type=f32)
         ds = p * (dp - delta_ref[0, 0])
         return qb, kb, dob, p, ds

@@ -98,6 +98,7 @@ def local_attention(
     q_offset=0,
     kv_offset=0,
     window: Optional[int] = None,
+    block_diffusion: Optional[int] = None,
 ) -> jax.Array:
     """Plain softmax attention on local (unpartitioned) q/k/v.
 
@@ -108,10 +109,18 @@ def local_attention(
     must reproduce bit-for-bit (up to fp associativity).  ``window=W``
     (with ``causal``) lets a query see its last ``W`` keys, itself
     included: the flash kernel's window, as an explicit mask.
+    ``block_diffusion=B`` (without ``causal``) is the flash kernel's
+    block-diffusion mask, dense, over rows that hold a noised copy and
+    then the clean one (:func:`block_diffusion_mask`); the offsets do
+    not enter it.
     """
     if window is not None and (not causal or window < 1):
         raise ValueError(
             f"window={window} needs causal=True and window >= 1")
+    if block_diffusion is not None and (causal or q.shape[1] != k.shape[1]):
+        raise ValueError(
+            "block_diffusion is a mask of its own over the same rows of "
+            "q and k: it takes no causal=True")
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
@@ -121,8 +130,32 @@ def local_attention(
         if window is not None:
             s = jnp.where(kv_pos[None, :] < q_pos[:, None] - (window - 1),
                           -jnp.inf, s)
+    if block_diffusion is not None:
+        s = jnp.where(block_diffusion_mask(q.shape[1], block_diffusion),
+                      s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+
+
+def block_diffusion_mask(rows: int, block: int) -> jax.Array:
+    """``[rows, rows]`` booleans, true where row ``i`` sees key ``j`` in
+    block-diffusion training (arXiv:2503.09573, the three-part mask):
+    rows ``0 .. L-1`` are the noised copy of ``L = rows // 2`` tokens,
+    rows ``L .. 2L-1`` the clean one, both in blocks of ``block``.  A
+    noised row sees the noised rows of its own block and the clean rows
+    of strictly earlier blocks; a clean row the clean rows of its own
+    and of earlier blocks; no clean row a noised key."""
+    if rows % 2 or (rows // 2) % block:
+        raise ValueError(
+            f"block_diffusion={block} must divide half of the {rows} rows "
+            f"(a noised copy, then the clean one)")
+    half = rows // 2
+    at = jnp.arange(rows)
+    clean = at >= half
+    blk = (at - half * clean) // block
+    qb, kb, qc, kc = blk[:, None], blk[None, :], clean[:, None], clean[None, :]
+    return ((~qc & ~kc & (qb == kb)) | (~qc & kc & (kb < qb))
+            | (qc & kc & (kb <= qb)))
 
 
 def ring_attention(

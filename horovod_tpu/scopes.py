@@ -61,6 +61,14 @@ ATTN_DIFF = "attn_diff"                # inside attn: differential
                                        # attention's lambda, subtraction,
                                        # sub-norm and scale (the attention
                                        # calls stay outside it)
+ATTN_BLOCK_DIFFUSION = "attn_block_diffusion"  # inside attn: the
+                                       # attention call under the
+                                       # block-diffusion mask (a noised
+                                       # copy beside the clean one)
+DIFFUSION_NOISE = "diffusion_noise"    # the step's noising: the levels,
+                                       # the masked positions, the noised
+                                       # copy laid beside the clean one
+                                       # (models/block_diffusion.py)
 MLP = "mlp"                            # a block's MLP half
 MOE_ROUTE = "moe_route"                # router matmul, scores, top-k, the
                                        # sort by expert: inside mlp, or at
@@ -127,4 +135,5 @@ SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ,
           MOE_ROUTE, MOE_BALANCE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED,
           MTP, EMBED, HEAD, STEM, KV_GATHER, KV_SCATTER, SAMPLE,
           MOE_LOGITS, MOE_TOPK, MOE_SORT, MOE_UNSORT, MOE_ROWS_IN,
-          MOE_ROWS_OUT, MOE_CAST, MOE_GATE)
+          MOE_ROWS_OUT, MOE_CAST, MOE_GATE, ATTN_BLOCK_DIFFUSION,
+          DIFFUSION_NOISE)

@@ -362,6 +362,47 @@ def test_flash_tile_table_compiles_for_v5e(one_chip, shape, kv_heads, window,
             name, sizes)
 
 
+def test_flash_block_diffusion_mask_compiles_for_v5e(one_chip):
+    """``sdar_train_s8192_bd4``'s attention call (16 384 rows: a noised
+    copy of 8192 tokens, then the clean one; 32 query heads over 4
+    key/value heads of 128; blocks of 4): the forward and the one
+    backward kernel under the block-diffusion mask walk a table of 576
+    live tiles a head of 2048, the mask's in-tile predicate (block indices
+    as a column and a row, shifts and comparisons) lowers, and the TPU
+    compiler takes both inside the VMEM the plan states (19 and 37 MiB,
+    as SmallThinker's call at the same keys)."""
+    import re
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    plan = fa.flash_plan(q, kv, kv, block_diffusion=4)
+    assert (plan.mask, plan.bwd_form, len(plan.live_tiles)) == (
+        "block_diffusion", "dkdv_resident", 576)
+
+    def backward(q, k, v):
+        return jax.grad(
+            lambda *a: flash_attention(
+                *a, block_diffusion=4,
+                interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(backward).lower(q, kv, kv).compile().as_text()
+    for name, stated in (("flash_fwd", plan.fwd_vmem_bytes),
+                         ("flash_bwd_dkdv", plan.bwd_vmem_bytes)):
+        (line,) = [l for l in text.splitlines()
+                   if "custom-call(" in l and name in l.split("(")[0]]
+        assert "s32[576]" in text, name
+        sizes = re.findall(
+            r'"scoped_memory_configs":\[\{[^\]]*"size":"(\d+)"', line)
+        assert sizes == [str(stated)], (name, sizes)
+    assert (plan.fwd_vmem_bytes, plan.bwd_vmem_bytes) == (
+        19 * 2 ** 20, 37 * 2 ** 20)
+
+
 # (id, rows, hidden, held experts, expert width): the whole slot buffer of
 # the GLM, Trinity and SmallThinker cells, the LFM2 cell's row bound
 _EXPERT_SHAPES = [
