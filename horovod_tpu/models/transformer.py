@@ -1264,23 +1264,32 @@ class Block(nn.Module):
             beside them (parallel/moe.py has the core), by the decision
             made from the layer's input or, without one, from ``h``."""
             from ..obs.registry import get_registry  # noqa: PLC0415
+            from ..ops import moe_combine  # noqa: PLC0415
             from ..parallel.moe import (  # noqa: PLC0415
                 apply_routing, ffn_tile_fill, row_bound,
             )
 
             b, s, d = h.shape
             held, ff = cfg.held_experts, cfg.routed_width
+            bound = row_bound(b * s, cfg.routed_top_k, held,
+                              cfg.routed_experts)
             # set while the step is traced, like flash.tiles_*: the share
             # of what the six grouped matmuls multiply that is needed, the
             # rows every [row_bound, .] buffer carries, and the slots the
             # layer runs on in a step that passes the bound
             for name, value in (
                     ("gmm_tile_fill", ffn_tile_fill(d, ff, cfg.dtype)),
-                    ("row_bound", row_bound(b * s, cfg.routed_top_k, held,
-                                            cfg.routed_experts)),
+                    ("row_bound", bound),
                     ("slots", b * s * cfg.routed_top_k)):
                 get_registry().gauge(
                     f"moe.{name}", layer="/".join(self.path)).set(value)
+            # the expert layers of the program whose way back to the
+            # tokens takes the kernel (all or none: they share a shape)
+            layers = [cfg.ffn_type(i) for i in range(cfg.num_layers)]
+            get_registry().gauge("moe.combine_kernel_layers").set(
+                (layers + layers[-1:] * cfg.mtp_modules).count("routed")
+                if moe_combine.engaged(b * s, d, held, bound, cfg.dtype)
+                else 0)
             stacked = nn.initializers.lecun_normal(batch_axis=(0,))
             x2 = h.reshape(b * s, d)
             if routing is None:

@@ -25,14 +25,16 @@ A second, **dropless** core stands beside that path (the end of this
 file: :func:`route`, :func:`grouped_ffn`, :func:`routed_experts`): scores
 over all experts, top-k, the chosen (token, expert) rows sorted by
 expert, a grouped matmul over the experts THIS chip holds, the weighted
-sum back.  No capacity, so nothing is dropped; the layer is told which
-experts it holds (``first_held``, and as many as its weights have),
-routes over all of them and computes its own experts' part of the
-result.  The sort puts the held experts' rows first, so the layer works
-on a static number of the sort's first rows (:func:`row_bound`, from the
-shapes) and not on every slot, with the whole-buffer computation behind a
-``lax.cond`` for the step whose held rows pass that.  On one chip it runs
-without its exchange.  The layer is two halves, a decision
+sum back (on the chip one kernel that follows the routed rows,
+``ops/moe_combine.py``).  No capacity, so nothing is dropped; the layer
+is told which experts it holds (``first_held``, and as many as its
+weights have), routes over all of them and computes its own experts'
+part of the result.  The sort puts the held experts' rows first, so the
+layer works on a static number of the sort's first rows
+(:func:`row_bound`, from the shapes) and not on every slot, with the
+whole-buffer computation behind a ``lax.cond`` for the step whose held
+rows pass that.  On one chip it runs without its exchange.  The layer is
+two halves, a decision
 (:func:`routing_decision`: which experts, with which weights, and the
 sort) and its application (:func:`apply_routing`), because a model may
 decide from one tensor and dispatch another (a router that reads the
@@ -440,20 +442,6 @@ def _rows(values, index):
     return jnp.take(values, index, axis=0, mode="clip")
 
 
-def _slots(rows, inverse, k: int):
-    """``rows`` (values of the sort's first rows) by choice and token,
-    ``[k, n, ...]``: choice ``j`` of token ``t`` takes
-    ``rows[inverse[t k + j]]``, and a row of zeros where its row lies past
-    ``rows`` (its expert is held elsewhere).  Choice first, so that a
-    choice's rows are a ``[n, d]`` array in whole tiles and a sum over the
-    choices adds ``k`` of them: ``[n, k, d]`` puts ``k`` inside a tile,
-    and the chip copies the array to sum it."""
-    past = rows.shape[0]
-    if past < inverse.shape[0]:
-        rows = jnp.concatenate([rows, jnp.zeros_like(rows[:1])])
-    return _rows(rows, jnp.minimum(inverse, past).reshape(-1, k).T)
-
-
 # The rows of a grouped-matmul tile on the chip: ``row_bound`` rounds to
 # it, so every ``[row_bound, .]`` buffer follows it.
 GMM_ROW_TILE = 512
@@ -684,10 +672,6 @@ def _head(rows: int, order, held_sizes):
 # module holds each of them once a shape and not once a layer, rule and
 # side: without it a cell's warm ``compile_s`` stands 6 s over the
 # parent's (the bound on ``setup_s`` is a tenth: 4 s), with it 1.7 to 2.7.
-# It is not free: around the inlined callee the compiler writes the
-# ``[k, n, d]`` rows out in float32 before it sums them, 10 ms of a 320 ms
-# step where 16 of 128 experts are held, 0.7 of 447 where 8 of 64 are
-# (PERF.md section 6).
 @partial(jax.jit, static_argnums=(0, 1, 2))
 def _forward(rows: int, interpret: bool, activation: str, x2, weights, order,
              inverse, held_sizes, gate_up, down):
@@ -695,9 +679,14 @@ def _forward(rows: int, interpret: bool, activation: str, x2, weights, order,
     (float32), computed on the first ``rows`` rows of the sort (static;
     the held experts' rows are among them when ``held_sizes.sum() <=
     rows``), and what the backward pass reads again, all ``[rows, .]``:
-    the gathered rows, ``h`` and the experts' outputs.  A token's ``k``
-    slots are summed in float32 in the order of its choices, a slot whose
-    row lies past ``rows`` adding exactly zero (``_slots``)."""
+    the gathered rows, ``h`` and the experts' outputs.  The way back
+    follows the routed rows (``ops/moe_combine.py``): on the chip one
+    kernel that reads the held experts' rows once and sums a token's
+    choices in float32 in the order of the held experts; elsewhere one
+    gathered row a slot, summed in the order of the choices, a slot whose
+    row lies past ``rows`` adding exactly zero."""
+    from ..ops import moe_combine  # noqa: PLC0415
+
     k = weights.shape[1]
     head, sizes = _head(rows, order, held_sizes)
     with jax.named_scope(scopes.MOE_DISPATCH), \
@@ -706,8 +695,8 @@ def _forward(rows: int, interpret: bool, activation: str, x2, weights, order,
     ys, h = _ffn(xs, gate_up, down, sizes, interpret, activation)
     with jax.named_scope(scopes.MOE_DISPATCH), \
             jax.named_scope(scopes.MOE_ROWS_OUT):
-        y = jnp.einsum("knd,nk->nd",
-                       _slots(ys, inverse, k).astype(jnp.float32), weights)
+        y = moe_combine.combine(ys, weights, inverse, held_sizes, k=k,
+                                dtype=jnp.float32, interpret=interpret)
     return y, (xs, h, ys)
 
 
@@ -717,9 +706,12 @@ def _backward(rows: int, interpret: bool, activation: str, kept, weights,
     """``_forward``'s gradients by the tokens, the weights and both
     matrices, on ``[rows, .]`` buffers alone: the tokens' gradients
     gathered to the rows (``g[head // k]``), weighted for the experts'
-    outputs, multiplied with them and summed for the weights; and the
-    rows' gradients gathered back by choice and summed over a token's
-    ``k`` in float32: no scatter-add, no ``[n k, d]`` product."""
+    outputs, multiplied with them and summed for the weights, a row's sum
+    put at the slot the row came from; and the rows' gradients brought
+    back to the tokens as the outputs were, unweighted
+    (``moe_combine.combine``): nothing here has ``n k`` rows of ``d``."""
+    from ..ops import moe_combine  # noqa: PLC0415
+
     xs, h, ys = kept
     k = weights.shape[1]
     head, sizes = _head(rows, order, held_sizes)
@@ -728,15 +720,19 @@ def _backward(rows: int, interpret: bool, activation: str, kept, weights,
             g_rows = _rows(g, head // k)
             d_ys = g_rows * _rows(weights.reshape(-1), head)[:, None]
         with jax.named_scope(scopes.MOE_ROWS_OUT):
-            d_weights = _slots((ys.astype(jnp.float32) * g_rows).sum(-1),
-                               inverse, k).T
+            # a row past the held groups carries zeros, and a slot whose
+            # row lies past ``rows`` keeps the zero it starts with
+            d_weights = jnp.zeros((weights.size,), jnp.float32).at[head].set(
+                (ys.astype(jnp.float32) * g_rows).sum(-1),
+                unique_indices=True).reshape(weights.shape)
     d_xs, d_gate_up, d_down = _ffn_bwd(xs, h, gate_up, down, sizes,
                                        interpret, activation,
                                        d_ys.astype(ys.dtype))
     with jax.named_scope(scopes.MOE_DISPATCH), \
             jax.named_scope(scopes.MOE_ROWS_OUT):
-        d_x2 = _slots(d_xs, inverse, k).sum(axis=0, dtype=jnp.float32)
-    return d_x2.astype(d_xs.dtype), d_weights, d_gate_up, d_down
+        d_x2 = moe_combine.combine(d_xs, None, inverse, held_sizes, k=k,
+                                   dtype=d_xs.dtype, interpret=interpret)
+    return d_x2, d_weights, d_gate_up, d_down
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))

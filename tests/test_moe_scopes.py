@@ -32,6 +32,11 @@ N, D, FF, EXPERTS, HELD, TOP_K = 64, 32, 16, 8, 2, 2
 # ``_backward`` is a rule of its own and enters the scopes by the same
 # ``with``: the product with the weights and the sums over a token's
 # choices are its alone, and the gate's ``jax.vjp`` sits inside its scope.
+# ``moe_rows_out`` holds, off the chip as here, the form by the slots
+# (``ops/moe_combine.py:combine_slots``: the einsum over a token's
+# gathered rows forward, their sum backward); on the chip the kernel
+# ``moe_combine`` takes their place under the same scope
+# (``test_tpu_compile.py`` reads its name there).
 PARTS = {
     scopes.MOE_LOGITS: (scopes.MOE_ROUTE, "dot_general", "transpose("),
     scopes.MOE_TOPK: (scopes.MOE_ROUTE, "top_k", "transpose("),
@@ -120,6 +125,14 @@ def test_both_score_rules_name_the_logits_and_the_choice(score_rule):
                    score_rule == "sigmoid")
 
 
+def test_the_weights_gradient_goes_to_its_slots_by_the_rows():
+    """``d_weights`` is a sum a ROW, put at the slot the row came from
+    (a scatter of ``rows`` scalars under ``moe_rows_out``), not gathered
+    by the slots."""
+    found = under(scopes.MOE_ROWS_OUT)
+    assert any(loc.endswith("/scatter") for loc in found), sorted(found)
+
+
 def test_the_row_gauges_are_set_while_the_step_is_traced():
     """``moe.row_bound{layer}`` and ``moe.slots{layer}`` beside
     ``moe.gmm_tile_fill{layer}``, under the layers' names that
@@ -139,6 +152,8 @@ def test_the_row_gauges_are_set_while_the_step_is_traced():
         v, TOKENS[:, :-2], next_tokens=TOKENS[:, 1:-1],
         mutable=["moe_stats"]), variables)
     tokens = TOKENS[:, :-2].size
+    # off the chip no layer's way back takes the kernel
+    assert get_registry().gauge("moe.combine_kernel_layers").value == 0
     gauges = {(m["name"], m["tags"]["layer"]): m["value"]
               for m in get_registry().snapshot()
               if m["name"] in ("moe.row_bound", "moe.slots")}

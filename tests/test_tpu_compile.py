@@ -517,17 +517,20 @@ def test_bounded_expert_layer_compiles_for_v5e(one_chip, experts, held,
                                                top_k, ff, bound):
     """The dropless expert layer at both cells' shapes, rematerialised,
     forward and backward, with the compiled kernels.  Outside the branch
-    nothing has ``n * top_k`` rows.  The side that stays under the row
-    bound holds such an array only where a gather brings rows back to
-    slot order (``_slots``: the way back to the tokens and the tokens'
-    gradient, in the layer's dtype, ``d`` wide); every gate, cast, select
-    and grouped matmul there is on ``[bound, .]`` buffers.  The other
-    side is the whole-buffer computation with the same kernels.  The
-    bounded side calls the Pallas grouped matmul as the layer without a
-    bound does: twice forward, twice in the recomputed forward, four
+    nothing has ``n * top_k`` rows, and on the side that stays under the
+    row bound nothing has either: the way back to the tokens and the
+    tokens' gradient follow the routed rows (``ops/moe_combine.py``), so
+    no gather brings rows back to slot order, and every gate, cast,
+    select and grouped matmul there is on ``[bound, .]`` buffers.  The
+    other side is the whole-buffer computation with the same kernels.
+    The bounded side calls the Pallas grouped matmul as the layer without
+    a bound does: twice forward, twice in the recomputed forward, four
     times backward (``gmm`` by the rows, ``tgmm`` by the matrices; the
-    forward calls that ``jax.vjp`` traces there are dropped), and no
-    computation holds kernels of both sides."""
+    forward calls that ``jax.vjp`` traces there are dropped), and
+    ``moe_combine`` for the outputs, for them again and for the tokens'
+    gradient, each reading ``[bound, d]`` rows there and ``[n * top_k,
+    d]`` on the other side; no computation holds kernels of both
+    sides."""
     import re
 
     from horovod_tpu.parallel import moe
@@ -558,17 +561,21 @@ def test_bounded_expert_layer_compiles_for_v5e(one_chip, experts, held,
     assert "ragged-dot" not in text and "ragged_dot" not in text
     outside, sides = _wide_rows(text, n * top_k)
     assert outside == {}
-    gathered = f"bf16[{n * top_k},{d}]"
     for under, over in sides:
-        assert set(under) <= {gathered}, under
+        assert under == {}, under
     assert any(f"bf16[{n * top_k},{2 * ff}]" in over for _, over in sides)
     # the Pallas calls: which computation holds each, and its rows (a
-    # ``tgmm`` gives matrices, [held, ., .]: 0 here)
-    where, name = {}, None
+    # ``tgmm`` gives matrices, [held, ., .]: 0 here; a ``moe_combine``
+    # gives tokens: the rows it reads, its last operand's)
+    where, combines, name = {}, {}, None
     for line in text.splitlines():
         head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
         if head:
             name = head.group(1)
+        elif "tpu_custom_call" in line and "/moe_combine/" in line:
+            assert "/moe_dispatch/moe_rows_out/" in line   # its scope
+            read = re.search(r"bf16\[(\d+),%d\]\{1,0\}\}, frontend" % d, line)
+            combines.setdefault(name, []).append(int(read.group(1)))
         elif "tpu_custom_call" in line and "pallas_call" in line:
             rows = re.search(r"= bf16\[(\d+),\d+\]", line)
             where.setdefault(name, []).append(
@@ -580,6 +587,52 @@ def test_bounded_expert_layer_compiles_for_v5e(one_chip, experts, held,
     # the other side: forward (the compiler may merge its two passes:
     # nothing lies between them here), forward again and by the rows
     assert calls.count(n * top_k) in (6, 8) and len(calls) in (16, 18)
+    # the way back: forward (merged or twice) and backward a side, each
+    # computation's calls on its own side's rows
+    assert "ENTRY" not in combines and all(
+        len(set(rows)) == 1 for rows in combines.values())
+    read = sorted(rows for side in combines.values() for rows in side)
+    assert read.count(bound) in (2, 3) and read.count(n * top_k) in (2, 3)
+    assert len(read) == read.count(bound) + read.count(n * top_k)
+
+
+# tokens, choices, hidden, held, experts, rows: the widest layers of the
+# way back, SDAR's under its row bound and SmallThinker's whole buffer
+_COMBINE_SHAPES = {
+    "sdar_train_s8192_bd4": (16384, 8, 2048, 16, 128, 32768),
+    "smallthinker_whole_buffer": (16384, 6, 2560, 16, 64, 98304),
+}
+
+
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted", "plain_sum"])
+@pytest.mark.parametrize("cell", sorted(_COMBINE_SHAPES))
+def test_moe_combine_compiles_for_v5e(one_chip, cell, weighted):
+    """``moe_combine`` under the plan's tiles at the cell's shape: the
+    weighted sum into float32 (the forward pass's) and the plain sum into
+    bfloat16 (the tokens' gradient), inside the VMEM the call states."""
+    from horovod_tpu.ops import moe_combine
+
+    n, k, d, held, experts, rows = _COMBINE_SHAPES[cell]
+    tiles = moe_combine.plan(n, d, held, rows, 2)
+    assert tiles is not None
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def back(ys, weights, inverse, held_sizes):
+        return moe_combine.combine_rows(
+            ys, weights if weighted else None, inverse, held_sizes, k=k,
+            tiles=tiles, dtype=jnp.float32 if weighted else jnp.bfloat16)
+
+    text = jax.jit(back).lower(
+        shape((rows, d), jnp.bfloat16), shape((n, k), jnp.float32),
+        shape((n * k,), jnp.int32), shape((held,), jnp.int32),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "moe_combine" in text
+    # nothing by the slots: no gather, no scatter, no [n k, d] array
+    assert " gather(" not in text and " scatter(" not in text
+    assert f"[{n * k},{d}]" not in text or rows == n * k
 
 
 @pytest.fixture
