@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import scopes
+from ..ops.rope import yarn_mscale
 from ..parallel.moe import ACTIVATIONS, SCORE_RULES
 from ..parallel.moe import DEFAULT_GROUP_SIZE as MOE_DEFAULT_GROUP_SIZE
 
@@ -294,8 +295,45 @@ class TransformerConfig:
     # thousands of times (a mask token's), whose sum in the compute dtype
     # loses a quarter of its norm.  The same values forward.
     embed_grad_float32: bool = False
+    # What RoPE's frequencies are scaled by: the source's ``rope_scaling``
+    # record (ops/rope.py:scaled_frequencies; type "yarn"), kept as its
+    # sorted items.  YaRN's factor on the softmax is ``attention_scale``.
+    rope_scaling: Optional[tuple] = None
+    # Manifold-constrained hyper-connections (models/hyper_connections.py,
+    # arXiv:2512.24880): the residual stream is hc_mult copies, stored
+    # [batch, seq, hc_mult * emb_dim]; each half of a block reads them
+    # through learned weights and writes back through a doubly stochastic
+    # hc_mult x hc_mult map that hc_sinkhorn_iters rounds of Sinkhorn-Knopp
+    # (hc_eps in both denominators) make of the exponential of logits
+    # clamped to hc_res_clamp.  1: one stream and a plain residual add.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple = (-30.0, 30.0)
 
     def __post_init__(self):
+        if self.rope_scaling is not None:
+            object.__setattr__(self, "rope_scaling", tuple(sorted(
+                dict(self.rope_scaling).items())))
+            if self.pos_embedding != "rope":
+                raise ValueError(
+                    f"rope_scaling scales RoPE's frequencies: "
+                    f"pos_embedding must be 'rope', got "
+                    f"{self.pos_embedding!r}")
+        object.__setattr__(self, "hc_res_clamp", tuple(self.hc_res_clamp))
+        if (self.hc_mult < 1 or self.hc_sinkhorn_iters < 1
+                or self.hc_eps < 0 or len(self.hc_res_clamp) != 2
+                or not self.hc_res_clamp[0] < self.hc_res_clamp[1]):
+            raise ValueError(
+                f"hyper-connections need hc_mult={self.hc_mult} >= 1 "
+                f"streams, hc_sinkhorn_iters={self.hc_sinkhorn_iters} >= 1, "
+                f"hc_eps={self.hc_eps} >= 0 and hc_res_clamp="
+                f"{self.hc_res_clamp!r} a (low, high) pair")
+        if self.hc_mult > 1 and self.routed_router_input == "layer_input":
+            raise ValueError(
+                f"hc_mult={self.hc_mult}: a router on the layer's input "
+                f"would read hc_mult streams; routed_router_input must be "
+                f"'ffn_input'")
         if self.num_kv_heads is not None:
             if self.num_kv_heads <= 0 or self.num_heads % self.num_kv_heads:
                 raise ValueError(
@@ -1060,12 +1098,25 @@ MIXER_SCOPES = {"mamba": scopes.SSM, "selective_scan": scopes.SSM,
 def block_math(cfg: TransformerConfig, x, *, ln1, mixer, ln2, mlp,
                layer_type: Optional[str] = None,
                post_attn_norm=None, post_mlp_norm=None,
-               hand_on: Optional[str] = None, route=None):
+               hand_on: Optional[str] = None, route=None,
+               connections=None):
     """THE pre-norm block wiring — the single source of truth.
 
-    ``norm → mixer → (+res) → norm → feed-forward → (+res)``, each
-    residual added through ``cfg.residual_multiplier``.  ``mixer`` is a
-    callable of the normed stream that returns the residual DELTA:
+    One stream (``connections=None``): ``norm → mixer → (+res) → norm →
+    feed-forward → (+res)``, each branch's output added to the stream
+    times ``cfg.residual_multiplier``.  ``cfg.hc_mult`` streams
+    (``x`` is ``[b, s, hc_mult * emb]``; ``connections`` a pair of
+    callables, the mixer half's and the feed-forward half's, each ``x →
+    (pre, post, res)``: ``models/hyper_connections.py:coefficients`` with
+    the caller's parameters closed over): each half is ``coefficients →
+    read-out → norm → branch → write-back``, the streams' weighted sum
+    in, the streams mixed by ``res`` plus ``post`` times the branch's
+    output back.  The branch, its norm and its scope are the same in
+    both; the hyper-connection's three stages trace under ``hc_coeff``,
+    ``hc_read`` and ``hc_write``, outside ``attn`` and ``mlp``.
+
+    ``mixer`` is a callable of the normed stream (``[b, s, emb]``
+    whatever ``hc_mult`` is) that returns the residual DELTA:
     :func:`attention_mixer`, :func:`mla_mixer`, :func:`mamba_mixer`,
     :func:`selective_scan_mixer`, :func:`gmu_mixer`,
     :func:`short_conv_mixer` or :func:`kda_mixer` with the caller's
@@ -1076,12 +1127,12 @@ def block_math(cfg: TransformerConfig, x, *, ln1, mixer, ln2, mlp,
     pipeline-parallel and decode block (:func:`raw_block_forward`), and
     the Megatron tensor-parallel block (``parallel/tensor_parallel.py``),
     so a change to the block (a norm variant, the residual's scale, a
-    post-norm) is made exactly once, and a new architecture is a new
-    mixer function, not an edit here.
+    post-norm, the residual path itself) is made exactly once, and a new
+    architecture is a new mixer function, not an edit here.
 
     ``ln1``, ``ln2``, ``mlp`` and the optional ``post_attn_norm`` and
-    ``post_mlp_norm`` (on a branch's output before its residual add) are
-    callables too; ``mlp`` returns its DELTA.
+    ``post_mlp_norm`` (on a branch's output before it joins the stream)
+    are callables too; ``mlp`` returns its DELTA.
 
     Values that cross layers: with ``hand_on`` (``"kv"`` or
     ``"memory"``) the mixer returns ``(delta, value)`` and the function
@@ -1096,27 +1147,46 @@ def block_math(cfg: TransformerConfig, x, *, ln1, mixer, ln2, mlp,
     block the decision crosses the mixer half inside one
     ``jax.checkpoint``, and its recompute is the sort again.
     """
-    def add(x, delta, post=None):
+    from . import hyper_connections as hc  # noqa: PLC0415
+
+    def joining(delta, post, dtype):
+        """A branch's output as it joins the stream."""
+        delta = act_store(delta, cfg)
         if post is not None:
-            delta = post(delta).astype(x.dtype)
-        if cfg.residual_multiplier == 1.0:
-            return x + delta
-        return x + cfg.residual_multiplier * delta
+            delta = post(delta).astype(dtype)
+        if cfg.residual_multiplier != 1.0:
+            delta = cfg.residual_multiplier * delta
+        return delta
+
+    def half(x, scope, norm, branch, post, connection, hands=False):
+        """One half on the stream ``x``: the next stream and what the
+        branch handed on beside its delta."""
+        handed = None
+        if connection is not None:
+            pre, post_weights, res = connection(x)
+            read = hc.read_out(x, pre)
+        with jax.named_scope(scope):
+            delta = branch(norm(x if connection is None else read))
+            if hands:
+                delta, handed = delta
+            delta = joining(delta, post, x.dtype)
+            if connection is None:
+                x = x + delta
+        if connection is not None:
+            x = hc.write_back(x, delta, post_weights, res)
+        return x, handed
 
     # The two halves trace under scopes (``jax.named_scope``) of their own, so
     # a device trace tells the mixer from the MLP whatever XLA names the
     # fusions.  A scope is metadata: it names no parameter, so the flax
     # tree stays ``block<i>/{ln1,qkv,proj,ln2,fc1,fc2}``.
-    handed = None
-    scope = MIXER_SCOPES.get(layer_type, scopes.ATTN)
+    for_mixer, for_mlp = connections or (None, None)
     decided = () if route is None else (route(x),)
-    with jax.named_scope(scope):
-        delta = mixer(ln1(x))
-        if hand_on is not None:
-            delta, handed = delta
-        x = add(x, act_store(delta, cfg), post_attn_norm)
-    with jax.named_scope(scopes.MLP):
-        x = add(x, act_store(mlp(ln2(x), *decided), cfg), post_mlp_norm)
+    x, handed = half(x, MIXER_SCOPES.get(layer_type, scopes.ATTN), ln1,
+                     mixer, post_attn_norm, for_mixer,
+                     hands=hand_on is not None)
+    x, _ = half(x, scopes.MLP, ln2, lambda h: mlp(h, *decided),
+                post_mlp_norm, for_mlp)
     return x if hand_on is None else (x, handed)
 
 
@@ -1196,7 +1266,8 @@ class Block(nn.Module):
     the flax parameters (the attention mixer's, a state-space mixer's, a
     gated memory unit's, a gated short convolution's, Kimi Delta
     Attention's or latent attention's, by ``layer_type``; a dense feed-forward's or the routed
-    experts', by ``ffn``) and hands
+    experts', by ``ffn``; with ``cfg.hc_mult`` streams the two
+    hyper-connections') and hands
     their applications in as callables: one ``mixer`` closure over the
     layer type's mixer function, the norms and ``mlp``.  ``hand_on`` says what the block
     returns beside ``x`` for later layers (``cfg.hands_on``);
@@ -1488,7 +1559,52 @@ class Block(nn.Module):
             mixer = lambda h: attention_mixer(
                 cfg, h, positions, tabs, layer_type=self.layer_type,
                 hand_on=self.hand_on, **attn)
+        def connection(name):
+            """One half's hyper-connection: the parameters of
+            ``hyper_connections.coefficients`` under ``<name>_{scale, phi,
+            b, alpha}`` and, where the caller asks for the collection
+            ``hc_stats``, how far its map was from doubly stochastic.
+            Initialised so that every term of the mathematics shows in
+            the first step (the paper's small gains and near-identity
+            map would let a program without Sinkhorn pass a check):
+            gains 1, ``phi`` lecun-normal, the biases normal(0, 1), the
+            map's with 2 on its diagonal."""
+            from .hyper_connections import (  # noqa: PLC0415
+                coefficients, stochastic_err,
+            )
+
+            n = cfg.hc_mult
+            wide, outs = n * cfg.emb_dim, n * n + 2 * n
+            ones = nn.initializers.ones
+
+            def b_init(key, shape, dtype=jnp.float32):
+                return jax.random.normal(key, shape, dtype).at[2 * n:].add(
+                    2.0 * jnp.eye(n, dtype=dtype).reshape(-1))
+
+            def apply(x):
+                pre, post, res = coefficients(
+                    x, n,
+                    scale=self.param(f"{name}_scale", ones, (wide,),
+                                     jnp.float32),
+                    phi=self.param(f"{name}_phi",
+                                   nn.initializers.lecun_normal(),
+                                   (wide, outs), jnp.float32),
+                    b=self.param(f"{name}_b", b_init, (outs,), jnp.float32),
+                    alpha=self.param(f"{name}_alpha", ones, (3,),
+                                     jnp.float32),
+                    norm_eps=cfg.norm_eps, iters=cfg.hc_sinkhorn_iters,
+                    eps=cfg.hc_eps, clamp=cfg.hc_res_clamp)
+                if self.is_mutable_collection("hc_stats"):
+                    self.variable("hc_stats", name, lambda: None).value = \
+                        stochastic_err(res)
+                return pre, post, res
+
+            return apply
+
         block = {}
+        if cfg.hc_mult > 1:
+            block["connections"] = (connection("hc_attn"),
+                                    connection("hc_mlp"))
         if cfg.post_norms:
             block["post_attn_norm"] = _norm(cfg, "post_attn_norm")
             block["post_mlp_norm"] = _norm(cfg, "post_mlp_norm")
@@ -1546,6 +1662,11 @@ class GPT(nn.Module):
     (``models/block_diffusion.py:paired``), the default positions repeat
     (``0 .. seq // 2 - 1`` twice) and the logits are those of the noised
     half alone, ``[batch, seq // 2, vocab]``.
+
+    With ``cfg.hc_mult`` > 1 the residual stream between the blocks is
+    that many copies, ``[batch, seq, hc_mult * emb]``: each starts as the
+    embedding, the blocks' hyper-connections mix them
+    (:func:`block_math`), and their sum is what the final norm reads.
 
     Returns logits ``[batch, seq, vocab]`` in fp32.  With a
     multi-token-prediction module (``cfg.mtp_modules``) and
@@ -1612,7 +1733,26 @@ class GPT(nn.Module):
 
             # once for ALL blocks: under remat a per-block recompute would
             # re-run the transcendentals in the backward pass too
-            rope_tabs = rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
+            rope_tabs = rope_tables(
+                positions, cfg.rope_dim, cfg.rope_theta,
+                cfg.rope_scaling and dict(cfg.rope_scaling))
+        if cfg.hc_mult > 1:
+            from ..obs.registry import get_registry  # noqa: PLC0415
+
+            if cfg.mtp_modules:
+                raise ValueError(
+                    f"hc_mult={cfg.hc_mult} with mtp_modules="
+                    f"{cfg.mtp_modules}: how a prediction module joins a "
+                    f"stream of several copies is not implemented; build "
+                    f"the model with mtp_modules=0")
+            # every stream starts as the embedding; counted while the
+            # step is traced
+            with jax.named_scope(scopes.EMBED):
+                x = jnp.tile(x, (1, 1, cfg.hc_mult))
+            for gauge, value in (("streams", cfg.hc_mult),
+                                 ("sinkhorn_iters", cfg.hc_sinkhorn_iters),
+                                 ("sublayers", 2 * cfg.num_layers)):
+                get_registry().gauge(f"hc.{gauge}").set(value)
         block_cls = Block
         if cfg.remat:
             block_cls = nn.remat(Block, policy=block_remat_policy(cfg))
@@ -1697,6 +1837,10 @@ class GPT(nn.Module):
             # and values fed the noised rows in every layer)
             x = x[:, :s // 2]
         with jax.named_scope(scopes.HEAD):
+            if cfg.hc_mult > 1:
+                # the streams' plain sum is what the final norm reads
+                x = x.reshape(*x.shape[:2], cfg.hc_mult, cfg.emb_dim).astype(
+                    jnp.float32).sum(axis=2).astype(cfg.dtype)
             logits = head(_norm(cfg, "lnf")(x))
         if cfg.mtp_modules == 0 or (next_tokens is None
                                     and not self.is_initializing()):
@@ -1980,6 +2124,44 @@ GPT_CONFIGS = {
         # 16384 x 5120 queries, keys and values a block: keep each
         # block's input and, as every policy does, what its kernels made
         # (o 128 MiB and lse 2 MiB a block)
+        remat_policy="nothing_saveable",
+    ),
+    # https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B config.json
+    # (model_type xing4_0): DeepSeek-V3's keys (latent attention with a
+    # query rank of 768 and a latent of 512, keys of 128 + 64 over values
+    # of 128, the 64 rotary channels turned at YaRN's blended frequencies
+    # and the softmax scaled by its mscale squared; layers 0 and 1 a
+    # silu-gated feed-forward of 9216, the other 38 hold 64 routed experts
+    # of 1024, sigmoid scores, 4 a token, a selection bias, weights
+    # normalised and scaled 2, beside one shared expert; one prediction
+    # module; an untied head) plus manifold-constrained hyper-connections
+    # (arXiv:2512.24880): a residual stream of four copies, each half of a
+    # block reading and writing it through maps made per token, the 4 x 4
+    # one by twenty rounds of Sinkhorn-Knopp.  How the prediction module
+    # joins four streams is published nowhere: the model refuses to be
+    # traced until ``mtp_modules=0`` overrides it.
+    # Training path only (require_gpt2_block says who refuses it).
+    "xing4.0-29b-a4b": TransformerConfig(
+        vocab_size=131072, num_layers=40, emb_dim=3584, max_len=262144,
+        layer_types=("mla",) * 40, num_heads=32, num_kv_heads=32,
+        q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+        pos_embedding="rope", rope_theta=10000.0,
+        rope_scaling=dict(
+            type="yarn", factor=64, original_max_position_embeddings=4096,
+            beta_fast=32, beta_slow=1, mscale=1, mscale_all_dim=1),
+        # 192 ** -0.5 times YaRN's (0.1 mscale_all_dim ln 64 + 1) ** 2
+        attention_scale=192 ** -0.5 * yarn_mscale(64, 1) ** 2,
+        hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+        hc_res_clamp=(-30.0, 30.0),
+        mlp_width=9216, mlp="silu_gated", norm="rmsnorm", norm_eps=1e-6,
+        use_bias=False, tie_embeddings=False,
+        routed_experts=64, routed_top_k=4, routed_width=1024,
+        routed_scaling=2.0, shared_experts=1, dense_layers_first=2,
+        mtp_modules=1,
+        # 8192 x 12288 queries, keys and values a block and a stream of
+        # 8192 x 14336: keep each block's input and, as every policy
+        # does, what its kernels made (o 64 MiB and lse 1 MiB a block)
         remat_policy="nothing_saveable",
     ),
 }

@@ -69,6 +69,18 @@ DIFFUSION_NOISE = "diffusion_noise"    # the step's noising: the levels,
                                        # the masked positions, the noised
                                        # copy laid beside the clean one
                                        # (models/block_diffusion.py)
+HC_COEFF = "hc_coeff"                  # hyper-connections, ahead of each
+                                       # half and outside its scope: the
+                                       # norm over all the streams'
+                                       # channels, the float32 matmul, the
+                                       # sigmoids, the clamped exponential
+                                       # and Sinkhorn's iteration
+HC_READ = "hc_read"                    # the read-out: the streams' weighted
+                                       # sum a half's norm and branch read
+HC_WRITE = "hc_write"                  # the write-back: the streams mixed
+                                       # by the doubly stochastic map plus
+                                       # the branch's output a stream, the
+                                       # float32 sum and the store
 MLP = "mlp"                            # a block's MLP half
 MOE_ROUTE = "moe_route"                # router matmul, scores, top-k, the
                                        # sort by expert: inside mlp, or at
@@ -136,4 +148,4 @@ SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ,
           MTP, EMBED, HEAD, STEM, KV_GATHER, KV_SCATTER, SAMPLE,
           MOE_LOGITS, MOE_TOPK, MOE_SORT, MOE_UNSORT, MOE_ROWS_IN,
           MOE_ROWS_OUT, MOE_CAST, MOE_GATE, ATTN_BLOCK_DIFFUSION,
-          DIFFUSION_NOISE)
+          DIFFUSION_NOISE, HC_COEFF, HC_READ, HC_WRITE)

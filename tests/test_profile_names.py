@@ -401,10 +401,11 @@ def test_every_named_scope_of_the_package_is_a_constant_of_scopes():
                 assert arg.startswith("scopes."), (name, arg)
                 used.add(getattr(scopes, arg[len("scopes."):]))
     used.add(scopes.OPTIMIZER_UPDATE)          # passed to optim._scoped
-    # block_math's ``scope``: the mixer's, by layer type, else ``attn``
+    # block_math's ``scope``: the mixer's, by layer type, else ``attn``,
+    # and the feed-forward half's ``mlp``
     from horovod_tpu.models.transformer import MIXER_SCOPES
 
-    used |= set(MIXER_SCOPES.values()) | {scopes.ATTN}
+    used |= set(MIXER_SCOPES.values()) | {scopes.ATTN, scopes.MLP}
     assert used == constants
 
 

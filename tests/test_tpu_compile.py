@@ -403,6 +403,43 @@ def test_flash_block_diffusion_mask_compiles_for_v5e(one_chip):
         19 * 2 ** 20, 37 * 2 ** 20)
 
 
+def test_xing4_latent_flash_call_compiles_for_v5e(one_chip):
+    """``xing4_train_s8192``'s attention call as ``mla_mixer`` makes it:
+    32 heads with keys of 192 (128 latent-made channels beside 64 rotary
+    ones turned by YaRN's tables, the rotary key one vector for all
+    heads) over values of 128 at 8192 keys, the softmax scaled by
+    ``192 ** -0.5`` times YaRN's ``mscale`` squared: Kimi-Linear's head
+    geometry at GLM's length.  Forward and backward compile as the
+    kernels' own calls, the rotation around them as XLA's."""
+    from horovod_tpu.models.transformer import GPT_CONFIGS
+    from horovod_tpu.ops.rope import apply_rope_tables, rope_tables
+
+    cfg = GPT_CONFIGS["xing4.0-29b-a4b"]
+    assert (cfg.num_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+            cfg.v_head_dim) == (32, 192, 128)
+    shape = lambda heads, width: jax.ShapeDtypeStruct(
+        (1, 8192, heads, width), jnp.bfloat16, sharding=one_chip)
+
+    def attend(q, k_nope, k_rope, v):
+        tabs = rope_tables(jnp.arange(8192), 64, cfg.rope_theta,
+                           dict(cfg.rope_scaling))
+        q = jnp.concatenate(
+            [q[..., :128], apply_rope_tables(q[..., 128:], *tabs)], axis=-1)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            apply_rope_tables(k_rope, *tabs), (1, 8192, 32, 64))], axis=-1)
+        return flash_attention(q, k, v, causal=True,
+                               scale=cfg.attention_scale, interpret=False)
+
+    def backward(*args):
+        return jax.grad(lambda *a: attend(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2, 3))(*args)
+
+    text = jax.jit(backward).lower(
+        shape(32, 192), shape(32, 128), shape(1, 64),
+        shape(32, 128)).compile().as_text()
+    assert "flash_fwd" in text and "flash_bwd" in text
+
+
 # (id, rows, hidden, held experts, expert width): the whole slot buffer of
 # the GLM, Trinity and SmallThinker cells, the LFM2 cell's row bound
 _EXPERT_SHAPES = [
