@@ -43,8 +43,15 @@ one tensor.  In a device trace three scopes cover it (``moe_route``,
 ``moe_dispatch``, ``moe_experts``) and each names its own operations one
 level down (``horovod_tpu/scopes.py``: ``moe_logits``, ``moe_topk``,
 ``moe_sort``, ``moe_unsort``; ``moe_rows_in``, ``moe_rows_out``;
-``moe_cast``, ``moe_gate``).  The GShard path above is as it was and
-carries no scope; ROADMAP C6 has the folding of the two.
+``moe_cast``, ``moe_gate``).  Nothing of the decision follows the slots
+one after another: the counts are comparisons summed over the slots
+(:func:`_counts`), the sigmoid rule's chosen scores a select over the
+experts (:func:`_chosen`), the sort's inverse a second sort, so that
+``moe_route`` scatters and gathers nothing, forward or backward, but in
+the derivative of ``top_k``'s own values (the ``softmax_chosen`` rule's,
+which the chip's compiler fuses).  The GShard
+path above is as it was and carries no scope; ROADMAP C6 has the folding
+of the two.
 """
 
 from __future__ import annotations
@@ -332,6 +339,8 @@ class Routing(NamedTuple):
                             # first and by expert, the rest after them
     group_sizes: jax.Array  # [held + 1] int32: rows of each held expert,
                             # then the rows whose expert lives elsewhere
+                            # (the slots' keys compared with every group
+                            # and summed: ``_counts``, as ``load`` is)
     dropped: jax.Array      # int32 scalar: slots of a held expert that
                             # the sort left no row for (there is no
                             # capacity, so 0: the counter is the proof)
@@ -342,7 +351,8 @@ class Routing(NamedTuple):
                             # the whole slot buffer (``apply_routing``
                             # sets it; ``route`` knows no bound)
     inverse: Optional[jax.Array] = None  # [n*k] int32: slot -> sorted row
-                            # (``routing_decision`` sets it)
+                            # (``routing_decision`` sets it: ``order``
+                            # sorted once more)
     balance: Optional[jax.Array] = None  # float32 scalar, where asked for:
                             # E * sum_e f_e P_e, 1.0 at an even load
 
@@ -366,6 +376,17 @@ def route(x2, router, bias, *, top_k: int, scaling: float,
     the load even.  Experts ``first_held`` to ``first_held + held - 1``
     are this chip's.
 
+    No operation here scatters to or gathers by a slot, which the chip
+    does one key after another (8.7 ns a key: PERF.md section 6, PR 58).
+    Under the sigmoid rule ``top_k`` gives the choice alone and the
+    chosen scores are selected from ``scores`` over the expert axis
+    (:func:`_chosen`: the gathered values to the bit, and a dense masked
+    sum on the way back); ``softmax_chosen`` reads ``top_k``'s own values,
+    whose derivative the chip's compiler fuses (0.03 ms a layer where the
+    select costs 0.5: same section); ``group_sizes`` and ``load`` are
+    comparisons summed over the slots (:func:`_counts`: the scatter-adds'
+    integers); the sort is one stable ``argsort`` of the keys.
+
     ``balance`` asks for the load-balance loss beside the decision (scope
     ``moe_balance``): ``E * sum_e f_e P_e``, ``f_e`` the share of the
     ``n * top_k`` slots that chose expert ``e`` (a count: no gradient),
@@ -387,8 +408,10 @@ def route(x2, router, bias, *, top_k: int, scaling: float,
         scores = jax.nn.sigmoid(logits) if score_rule == "sigmoid" else logits
     with jax.named_scope(scopes.MOE_TOPK):
         if score_rule == "sigmoid":
-            _, experts = lax.top_k(scores + lax.stop_gradient(bias), top_k)
-            chosen = jnp.take_along_axis(scores, experts, axis=-1)
+            # the choice alone: its values are the biased ones and are
+            # not read, so nothing of it is differentiated
+            _, experts = lax.top_k(lax.stop_gradient(scores + bias), top_k)
+            chosen = _chosen(scores, experts)
             weights = (chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
                        * scaling)
         else:
@@ -399,10 +422,9 @@ def route(x2, router, bias, *, top_k: int, scaling: float,
         is_held = (local >= 0) & (local < held)
         key = jnp.where(is_held, local, held)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        group_sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
+        group_sizes = _counts(key, held + 1)
         dropped = is_held.sum(dtype=jnp.int32) - group_sizes[:held].sum()
-        load = jnp.zeros((router.shape[1],), jnp.int32).at[
-            experts.reshape(n * top_k)].add(1)
+        load = _counts(experts.reshape(n * top_k), router.shape[1])
     routing = Routing(weights, experts.astype(jnp.int32), order, group_sizes,
                       dropped, load)
     if balance:
@@ -412,6 +434,29 @@ def route(x2, router, bias, *, top_k: int, scaling: float,
             routing = routing._replace(
                 balance=router.shape[1] * jnp.sum(share * mean))
     return routing
+
+
+def _chosen(scores, experts):
+    """``scores[t, experts[t, j]]`` as ``[n, k]``, by a select over the
+    expert axis and not a gather by the slots: ``sum over e of where(
+    experts[t, j] == e, scores[t, e], 0)``, one fused pass over ``[n, k,
+    E]``.  A token's experts are distinct and ``x + 0 = x``, so the value
+    is the gathered one to the bit, and so is the cotangent, which
+    ``jax`` transposes to a dense masked sum over ``k`` where a gather's
+    is a scatter-add."""
+    mask = experts[..., None] == jnp.arange(scores.shape[-1],
+                                            dtype=experts.dtype)
+    return jnp.where(mask, scores[:, None, :], 0).sum(-1)
+
+
+def _counts(values, bins: int):
+    """How many of ``values`` (integers, one a slot) are each of ``0`` to
+    ``bins - 1``, as int32: every slot compared with every bin and the
+    matches summed over the slots, one fused compare-and-reduce that
+    writes ``bins`` integers, where a scatter-add follows the slots one
+    after another."""
+    return (values[:, None] == jnp.arange(bins, dtype=values.dtype)).sum(
+        0, dtype=jnp.int32)
 
 
 def rebalanced(moe_state, moe_stats, rate: float, axis_name=None):
@@ -794,14 +839,13 @@ def routing_decision(x2, router, bias, *, top_k: int, scaling: float,
     :func:`apply_routing` needs about ``n`` tokens, so the tensor that is
     dispatched may be another one of as many rows (the stream after
     attention, for a router that reads the layer's input)."""
-    n = x2.shape[0]
     with jax.named_scope(scopes.MOE_ROUTE):
         routing = route(x2, router, bias, top_k=top_k, scaling=scaling,
                         first_held=first_held, held=held,
                         score_rule=score_rule, balance=balance)
         with jax.named_scope(scopes.MOE_UNSORT):
-            inverse = jnp.zeros_like(routing.order).at[routing.order].set(
-                jnp.arange(n * top_k, dtype=jnp.int32))
+            # ``order`` is a permutation: its inverse is its own sort
+            inverse = jnp.argsort(routing.order).astype(jnp.int32)
     return routing._replace(inverse=inverse)
 
 

@@ -90,14 +90,17 @@ MOE_LOGITS = "moe_logits"              # inside moe_route: the float32
                                        # router matmul and the score rule's
                                        # sigmoid
 MOE_TOPK = "moe_topk"                  # inside moe_route: top-k, the chosen
-                                       # scores, the weights' normalisation
-                                       # (or softmax over the chosen), the
+                                       # scores (the sigmoid rule's a select
+                                       # over the experts),
+                                       # the weights' normalisation (or
+                                       # softmax over the chosen), the
                                        # scaling
 MOE_SORT = "moe_sort"                  # inside moe_route: the keys, the
                                        # stable argsort by expert, the two
-                                       # counts (group sizes, load)
-MOE_UNSORT = "moe_unsort"              # inside moe_route: the scatter that
-                                       # inverts the sort
+                                       # counts (group sizes, load) by
+                                       # comparison
+MOE_UNSORT = "moe_unsort"              # inside moe_route: the second sort
+                                       # that inverts the first
 MOE_BALANCE = "moe_balance"            # inside moe_route: the load-balance
                                        # loss (full softmax, its mean)
 MOE_DISPATCH = "moe_dispatch"          # inside mlp: rows gathered into

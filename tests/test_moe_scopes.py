@@ -41,7 +41,7 @@ PARTS = {
     scopes.MOE_LOGITS: (scopes.MOE_ROUTE, "dot_general", "transpose("),
     scopes.MOE_TOPK: (scopes.MOE_ROUTE, "top_k", "transpose("),
     scopes.MOE_SORT: (scopes.MOE_ROUTE, "jit(argsort)", None),
-    scopes.MOE_UNSORT: (scopes.MOE_ROUTE, "scatter", None),
+    scopes.MOE_UNSORT: (scopes.MOE_ROUTE, "jit(argsort)", None),
     scopes.MOE_ROWS_IN: (scopes.MOE_DISPATCH, "jit(_take)", "/mul"),
     scopes.MOE_ROWS_OUT: (scopes.MOE_DISPATCH, "dot_general", "/reduce_sum"),
     scopes.MOE_CAST: (scopes.MOE_EXPERTS, "convert_element_type",
@@ -123,6 +123,27 @@ def test_both_score_rules_name_the_logits_and_the_choice(score_rule):
     assert any(loc.endswith("/logistic")
                for loc in under(scopes.MOE_LOGITS, score_rule)) == (
                    score_rule == "sigmoid")
+
+
+@pytest.mark.parametrize("score_rule", moe.SCORE_RULES)
+def test_nothing_under_the_route_scatters_or_gathers(score_rule):
+    """The counts are comparisons summed over the slots, the sigmoid
+    rule's chosen scores a select over the experts (its transpose a dense
+    masked sum), the inverse a second sort: forward, and in the backward
+    pass that ``jax`` derives, no operation under ``moe_route`` follows
+    the slots one after another.  ``softmax_chosen`` reads ``top_k``'s
+    own values, and the transpose of that primitive's rule is the one
+    scatter-add that is lowered (the chip's compiler fuses it away:
+    ``test_tpu_compile.py`` reads the compiled program, PERF.md section 6
+    the trace)."""
+    found = [loc for loc in locations(score_rule)
+             if f"/{scopes.MOE_ROUTE}/" in loc]
+    assert any("transpose(" in loc for loc in found)
+    assert [loc for loc in found if loc.endswith(
+        ("/scatter", "/scatter-add", "/scatter_add", "/gather"))] == (
+            [] if score_rule == "sigmoid" else [
+                f"jit(loss)/transpose(jvp({scopes.MLP}))/{scopes.MOE_ROUTE}/"
+                f"{scopes.MOE_TOPK}/scatter-add"])
 
 
 def test_the_weights_gradient_goes_to_its_slots_by_the_rows():

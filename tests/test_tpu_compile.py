@@ -559,7 +559,11 @@ def test_bounded_expert_layer_compiles_for_v5e(one_chip, experts, held,
     tokens' gradient follow the routed rows (``ops/moe_combine.py``), so
     no gather brings rows back to slot order, and every gate, cast,
     select and grouped matmul there is on ``[bound, .]`` buffers.  The
-    other side is the whole-buffer computation with the same kernels.
+    one exception is no array: the counts compare every slot with every
+    group (``moe._counts``) inside a fusion that writes ``held + 1``
+    integers, and the ``[n * top_k, held + 1]`` matches are values of its
+    loop.  The other side is the whole-buffer computation with the same
+    kernels.
     The bounded side calls the Pallas grouped matmul as the layer without
     a bound does: twice forward, twice in the recomputed forward, four
     times backward (``gmm`` by the rows, ``tgmm`` by the matrices; the
@@ -597,7 +601,12 @@ def test_bounded_expert_layer_compiles_for_v5e(one_chip, experts, held,
     text = jax.jit(step).lower(*args).compile().as_text()
     assert "ragged-dot" not in text and "ragged_dot" not in text
     outside, sides = _wide_rows(text, n * top_k)
-    assert outside == {}
+    matches = {f"{kind}[{n * top_k},{held + 1}]" for kind in ("pred", "s32")}
+    assert set(outside) == matches
+    # made and used up inside fusions: none is a fusion's operand or result
+    assert set(re.findall(
+        r"= (?:pred|s32)\[%d,%d\]\S* ([\w\-]+)\(" % (n * top_k, held + 1),
+        text)) <= {"compare", "broadcast", "convert", "iota"}
     for under, over in sides:
         assert under == {}, under
     assert any(f"bf16[{n * top_k},{2 * ff}]" in over for _, over in sides)
@@ -631,6 +640,56 @@ def test_bounded_expert_layer_compiles_for_v5e(one_chip, experts, held,
     read = sorted(rows for side in combines.values() for rows in side)
     assert read.count(bound) in (2, 3) and read.count(n * top_k) in (2, 3)
     assert len(read) == read.count(bound) + read.count(n * top_k)
+
+
+@pytest.mark.parametrize("n,top_k,experts,held,score_rule", [
+    (16384, 8, 256, 8, "sigmoid"),           # kimilin_train_s16384
+    (16384, 8, 128, 16, "softmax_chosen"),   # sdar_train_s8192_bd4
+])
+def test_the_router_compiles_without_a_pass_by_the_slots(
+        one_chip, n, top_k, experts, held, score_rule):
+    """The decision and its gradient at the widest sigmoid cell and the
+    widest ``softmax_chosen`` one, 131 072 slots a layer both: the
+    compiled program scatters and gathers nothing but, under
+    ``softmax_chosen``, the derivative of ``top_k``'s own values (one
+    scatter to indices that are unique, which the chip does not take one
+    after another: 0.03 ms a layer in the cell's trace), and what the
+    dense forms compare (``[slots, bins]`` for the counts, ``[n, k, E]``
+    for the sigmoid rule's chosen scores and their gradient) is made and
+    used up inside fusions, never an array in HBM (Kimi-Linear's one-hot
+    would be 128 MiB a pass in int32)."""
+    import re
+
+    from horovod_tpu.parallel import moe
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def step(x2, router, bias):
+        def loss(x2, router):
+            routing = moe.routing_decision(
+                x2, router, bias if score_rule == "sigmoid" else None,
+                top_k=top_k, scaling=1.5, first_held=0, held=held,
+                score_rule=score_rule, balance=True)
+            return routing.weights.sum() + routing.balance, routing
+
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+            x2, router)
+
+    text = jax.jit(step).lower(
+        shape((n, 2048), jnp.bfloat16), shape((2048, experts), jnp.float32),
+        shape((experts,), jnp.float32)).compile().as_text()
+    assert re.findall(r" (scatter|gather)\(", text) == (
+        [] if score_rule == "sigmoid" else ["scatter"])
+    slots = n * top_k
+    dense = "|".join((f"{slots},{held + 1}", f"{slots},{experts}",
+                      f"{n},{top_k},{experts}"))
+    ops = set(re.findall(
+        r"= (?:pred|s32|f32)\[(?:%s)\]\S* ([\w\-]+)\(" % dense, text))
+    assert ops >= ({"compare", "select"} if score_rule == "sigmoid"
+                   else {"compare"})
+    assert ops <= {"compare", "select", "broadcast", "convert", "iota",
+                   "bitcast", "reshape"}, ops
 
 
 # tokens, choices, hidden, held, experts, rows: the widest layers of the
