@@ -65,7 +65,8 @@ def build_gpt_step(size: str, dtype: str, batch_size: int, seq_len: int,
     tokens = np.random.RandomState(0).randint(
         0, vocab, size=(global_batch, seq_len + 1)
     ).astype(np.int32)
-    params = model.init(jax.random.PRNGKey(0), jnp.asarray(tokens[:2, :-1]))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.asarray(tokens[:2, :-1]))
     params = hvd.broadcast_parameters(params, root_rank=0)
 
     def make_loss_fn(toks):
@@ -176,7 +177,8 @@ def build_step(model_name: str, dtype: str, batch_size: int,
         0, 1000, size=(global_batch,)
     ).astype(np.int32)
 
-    variables = model.init(rng, jnp.asarray(images[:2]), train=True)
+    variables = jax.jit(lambda key, x: model.init(key, x, train=True))(
+        rng, jnp.asarray(images[:2]))
     # VGG has no BN; {} keeps the step signature uniform across models
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})

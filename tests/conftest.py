@@ -72,6 +72,42 @@ def _world():
     hvd.shutdown()
 
 
+# ``--dist loadfile`` gives a file to one worker, in collection order, and
+# the first six files are the six workers' first.  The launcher cases go
+# first: ``test_multiprocess.py`` is the longest file, and its 2-process
+# worlds all start from one worker.  ``test_analysis.py`` is second because
+# two of its cases fork a pool from the test process (``analysis/cli.py``,
+# ``jobs > 1``), and a fork from a worker that has already loaded the TPU
+# compiler or run a launcher leaves a child waiting on a lock for ever: it
+# runs on a fresh worker, as it did while files went out most cases first.
+# Then the long files whose names sort late, so that none of them starts
+# last and is the run's tail; the rest keep the collection's order.
+# (``scripts/tier1_times.py`` on a run's junit file shows the tail.)
+LONGEST_FIRST = [
+    "test_multiprocess", "test_analysis", "test_smallthinker",
+    "test_moe_tpu_compile", "test_step_tpu_compile", "test_models_gpt",
+    "test_testing_steps", "test_pipeline", "test_xing4_moe_mla",
+    "test_sdar_moe",
+]
+
+
+def pytest_configure(config):
+    # pytest-xdist hands the files out by their count of cases, most first
+    # (``--loadscope-reorder``, its default), and the few long cases (a
+    # step compiled for the chip, the graft entry) would start last.  Under
+    # ``loadfile`` the order is the collection's, which the hook below sets.
+    if getattr(config.option, "dist", "no") == "loadfile":
+        assert hasattr(config.option, "loadscopereorder"), (
+            "pytest-xdist no longer has --loadscope-reorder: see how it "
+            "orders the files now, or tier-1's tail comes back")
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(items):
+    rank = {name: i for i, name in enumerate(LONGEST_FIRST)}
+    items.sort(key=lambda item: rank.get(item.path.stem, len(rank)))
+
+
 def assert_trees_equal(got, want):
     """Exact-equality pytree comparison shared by the param-layout
     round-trip tests (pipeline/tensor-parallel unstackers)."""

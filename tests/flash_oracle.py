@@ -2,12 +2,16 @@
 backward as a plain scan over K tiles (the oracle the Pallas backward is
 pinned to; it lived in ``horovod_tpu/ops/flash_attention.py`` until
 PR 47 and no program called it), and a call's ``FlashPlan`` from folded
-operands or from loose sizes, and the tile pairs a mask keeps."""
+operands or from loose sizes, and the tile pairs a mask keeps; and what
+the files of flash tests share (``tests/test_flash_*.py``): seeded
+operands, the limits that force a backward's form, and the
+``pallas_call``s a program holds."""
 
 import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from horovod_tpu.ops import flash_attention as fa
@@ -123,3 +127,98 @@ def traced_calls(monkeypatch):
 
     monkeypatch.setattr(fa, "flash_plan", spy)
     return calls
+
+
+def qkv(b=2, s=64, h=4, d=16, seed=0, dtype=jnp.float32):
+    rng = np.random.RandomState(seed)
+    mk = lambda: jnp.asarray(rng.randn(b, s, h, d), dtype) * 0.3
+    return mk(), mk(), mk()
+
+
+def out_and_grads(f, weight, *operands):
+    """``f``'s output and the gradients of its ``weight``ed float32 sum in
+    its three operands, from one trace."""
+    return jax.jit(lambda *a: (f(*a), jax.grad(
+        lambda *b: (f(*b).astype(jnp.float32) * weight).sum(),
+        argnums=(0, 1, 2))(*a)))(*operands)
+
+
+def grouped_blockwise(q, k, v, o, lse, do, causal, scale, bk, window, h,
+                      hkv):
+    """`flash_bwd_blockwise` knows no grouped heads: give every query
+    head its own copy of its kv row and fold dk and dv back (each at its
+    own width: the values' need not be the keys')."""
+    z, s, _ = q.shape
+    b, group = z // h, h // hkv
+    f32 = jnp.float32
+    rep = lambda t: jnp.repeat(
+        t.astype(f32).reshape(b, hkv, 1, s, t.shape[-1]), group, 2
+    ).reshape(z, s, t.shape[-1])
+    dq, dk, dv = flash_bwd_blockwise(q.astype(f32), rep(k), rep(v), o, lse,
+                                     do, causal, scale, bk, window=window)
+    fold = lambda t: t.reshape(b, hkv, group, s, t.shape[-1]).sum(2).reshape(
+        -1, s, t.shape[-1])
+    return dq, fold(dk), fold(dv)
+
+
+def vmem_limits(monkeypatch, limit, ceiling=None):
+    """The VMEM a one-kernel backward may state, as the shape gate reads
+    it: ``limit`` for the forms in their order and ``ceiling`` for the
+    smaller count above it (``None``: no room above the limit, so 0
+    leaves the two passes alone)."""
+    monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_LIMIT", limit)
+    monkeypatch.setattr(fa, "_FUSED_BWD_VMEM_CEILING",
+                        limit if ceiling is None else ceiling)
+
+
+def pallas_calls(jaxpr, what=lambda params: params["name"]):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield what(eqn.params)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from pallas_calls(sub, what)
+
+
+# the backward's ``pallas_call``s by name, as ``pallas_calls`` lists them
+ONE_KERNEL = ["flash_bwd_dkdv"]
+TWO_PASSES = ["flash_bwd_dkdv", "flash_bwd_dq"]
+
+
+def force(monkeypatch, backward):
+    """Take the named backward whatever the shape says; its kernels' names."""
+    if backward == "two_passes":
+        vmem_limits(monkeypatch, 0)
+    elif backward == "dq_resident":
+        force_form(monkeypatch, backward)
+    return TWO_PASSES if backward == "two_passes" else ONE_KERNEL
+
+
+def stated_vmem(eqn_params):
+    """The ``vmem_limit_bytes`` a ``pallas_call`` states (``None``: the
+    compiler's default)."""
+    return eqn_params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+
+
+def forward_call(eqn_params):
+    """What a ``flash_fwd`` ``pallas_call`` holds of K and V: the rows of
+    their blocks (the whole kv row where it is resident, ``block_k``
+    where tiles stream) and the VMEM the call states."""
+    k_block, v_block = eqn_params["grid_mapping"].block_mappings[1:3]
+    rows = {int(m.block_shape[1].block_size) for m in (k_block, v_block)}
+    assert len(rows) == 1 and k_block.pipeline_mode is None
+    return rows.pop(), stated_vmem(eqn_params)
+
+
+# --------------------------- the grids walk the live tiles alone (PR 49)
+# A head's live (Q tile, K tile) pairs come from a table the kernels
+# prefetch to SMEM, not from two grid axes and a predicate.  Tiles 32 x 16
+# over 96 keys (3 x 6 a head): no mask, the causal half, and windows under
+# both tiles, of a K tile exactly, and a multiple of neither.
+WALK_SEQ, WALK_BQ, WALK_BK, WALK_D = 96, 32, 16, 16
+WALK_MASKS = [
+    ("noncausal", False, None),
+    ("causal", True, None),
+    ("window_8_under_the_tiles", True, 8),
+    ("window_16_a_k_tile", True, 16),
+    ("window_20_no_multiple", True, 20),
+]

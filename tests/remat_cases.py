@@ -1,5 +1,5 @@
 """One small model per mixer, for the tests of what a rematerialised
-block keeps (tests/test_models.py, tests/test_remat_kernels.py): GPT
+block keeps (tests/test_models_gpt.py, tests/test_remat_kernels.py): GPT
 nano with the reference attention and with the flash kernels, latent
 attention with routed experts and a prediction module (the block it
 builds is rematerialised too), and Mamba-2 layers.  Two blocks each, the
@@ -52,7 +52,7 @@ def build(mixer, remat=False, policy=POLICIES[0]):
     model = _model(mixer, remat=remat, remat_policy=policy)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, SEQ + 2), 0,
                                 plain.cfg.vocab_size)
-    variables = plain.init(jax.random.PRNGKey(1), tokens[:, :SEQ])
+    variables = jax.jit(plain.init)(jax.random.PRNGKey(1), tokens[:, :SEQ])
     rest = {k: v for k, v in variables.items() if k == "moe_state"}
 
     def cross_entropy(logits, labels):

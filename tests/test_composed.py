@@ -60,7 +60,7 @@ def _unsharded_step(model, params, tx, tokens, targets):
     def loss_fn(p):
         return _nll(model.apply(p, tokens), targets)
 
-    loss, grads = jax.value_and_grad(loss_fn)(params)
+    loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params)
     updates, _ = tx.update(grads, tx.init(params), params)
     return optax.apply_updates(params, updates), loss
 
@@ -71,7 +71,7 @@ def test_dp_tp_step_matches_unsharded():
     tp = 2
     model = _model()
     tokens, targets = _data(model)
-    params = model.init(jax.random.PRNGKey(0), tokens[:1])
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens[:1])
     # SGD, not adam: adam's first-step update is +-lr * sign(g), which
     # amplifies fp-reordering sign flips of near-zero grads (unused qkv
     # bias columns) into full 2*lr mismatches; sgd is linear in g so the
@@ -142,7 +142,7 @@ def test_dp_pp_step_matches_unsharded():
     pp = 2
     model = _model(num_layers=2)
     tokens, targets = _data(model)
-    params = model.init(jax.random.PRNGKey(0), tokens[:1])
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens[:1])
     # SGD, not adam: adam's first-step update is +-lr * sign(g), which
     # amplifies fp-reordering sign flips of near-zero grads (unused qkv
     # bias columns) into full 2*lr mismatches; sgd is linear in g so the
@@ -212,14 +212,14 @@ def test_dp_pp_tp_step_matches_unsharded():
     pp, tp = 2, 2
     model = _model(num_layers=4)
     tokens, targets = _data(model)
-    params = model.init(jax.random.PRNGKey(0), tokens[:1])
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens[:1])
     tx = optax.sgd(0.05, momentum=0.9)
 
     def loss_ref(p):
         return _nll(model.apply(p, tokens), targets)
 
     want_loss = loss_ref(params)
-    g_ref = jax.grad(loss_ref)(params)
+    g_ref = jax.jit(jax.grad(loss_ref))(params)
     updates, _ = tx.update(g_ref, tx.init(params), params)
     want_params = optax.apply_updates(params, updates)
 
@@ -283,7 +283,7 @@ def test_pp_tp_rejects_mismatched_pp_stack():
 
     model = _model(num_layers=4)
     tokens, targets = _data(model)
-    params = model.init(jax.random.PRNGKey(0), tokens[:1])
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens[:1])
     st_sh, st_rep, rep = stack_tp_pp_params(params, model.cfg, 4, 2)
     mesh = Mesh(
         np.asarray(jax.devices()[:4]).reshape(2, 2), ("pp", "tp")

@@ -52,8 +52,8 @@ def _prompt(model, b=2, s=12, seed=0):
 def test_prefill_matches_full_forward(overrides):
     model = _model(**overrides)
     prompt = _prompt(model)
-    params = model.init(jax.random.PRNGKey(0), prompt)
-    want = model.apply(params, prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), prompt)
+    want = jax.jit(model.apply)(params, prompt)
     got, cache = jax.jit(
         lambda p, t: prefill(model.cfg, p, t)
     )(params, prompt)
@@ -78,8 +78,8 @@ def test_prefill_single_forward_matches_scanned(overrides):
     rounding (``SCHEDULE_ATOL``); shapes, dtypes and positions exactly."""
     model = _model(**overrides)
     prompt = _prompt(model, s=12, seed=9)
-    params = model.init(jax.random.PRNGKey(9), prompt)
-    want = np.asarray(model.apply(params, prompt))
+    params = jax.jit(model.init)(jax.random.PRNGKey(9), prompt)
+    want = np.asarray(jax.jit(model.apply)(params, prompt))
     single, c1 = jax.jit(
         lambda p, t: prefill(model.cfg, p, t)
     )(params, prompt)
@@ -111,7 +111,7 @@ def test_prefill_supports_zigzag_models():
 
     model = _model(pos_embedding="rope")
     prompt = _prompt(model, s=10, seed=17)
-    params = model.init(jax.random.PRNGKey(17), prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(17), prompt)
     zig = replace(model.cfg, attention_impl="zigzag")
     single, c1 = jax.jit(
         lambda p, t: prefill(zig, p, t)
@@ -119,7 +119,7 @@ def test_prefill_supports_zigzag_models():
     scanned, c2 = jax.jit(
         lambda p, t: prefill_scan(zig, p, t)
     )(params, prompt)
-    want = np.asarray(model.apply(params, prompt))
+    want = np.asarray(jax.jit(model.apply)(params, prompt))
     for got in (single, scanned):
         np.testing.assert_allclose(np.asarray(got), want, rtol=0,
                                    atol=SCHEDULE_ATOL)
@@ -140,10 +140,10 @@ def test_decode_step_extends_prefill():
     model = _model()
     prompt = _prompt(model, s=10, seed=1)
     nxt = _prompt(model, s=1, seed=2)[:, 0]
-    params = model.init(jax.random.PRNGKey(1), prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), prompt)
     _, cache = prefill(model.cfg, params, prompt)
     got, cache = decode_step(model.cfg, params, cache, nxt)
-    full = model.apply(
+    full = jax.jit(model.apply)(
         params, jnp.concatenate([prompt, nxt[:, None]], axis=1)
     )
     np.testing.assert_allclose(
@@ -159,7 +159,7 @@ def test_generate_matches_full_forward_greedy():
     full forward at every step (the O(S^2)-per-token oracle)."""
     model = _model()
     prompt = _prompt(model, s=8, seed=3)
-    params = model.init(jax.random.PRNGKey(2), prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(2), prompt)
     steps = 6
     got = jax.jit(
         lambda p, t: generate(model.cfg, p, t, steps)
@@ -190,8 +190,8 @@ def test_prefill_matches_windowed_forward():
     model = _model(attention_impl="flash", attention_window=4,
                    flash_block_q=8, flash_block_k=8)
     prompt = _prompt(model, s=16, seed=4)
-    params = model.init(jax.random.PRNGKey(3), prompt)
-    want = model.apply(params, prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(3), prompt)
+    want = jax.jit(model.apply)(params, prompt)
     got, _ = jax.jit(
         lambda p, t: prefill(model.cfg, p, t)
     )(params, prompt)
@@ -205,7 +205,7 @@ def test_decode_past_cache_end_poisons():
     logits instead of silently overwriting the last slot."""
     model = _model()
     prompt = _prompt(model, s=4, seed=5)
-    params = model.init(jax.random.PRNGKey(4), prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(4), prompt)
     _, cache = prefill(model.cfg, params, prompt, max_len=4)  # full
     logits, _ = decode_step(model.cfg, params, cache,
                             prompt[:, 0])  # pos == cache size
@@ -217,7 +217,7 @@ def test_sampled_generation():
     greedy, temperature>0 without a key raises."""
     model = _model()
     prompt = _prompt(model, s=6, seed=6)
-    params = model.init(jax.random.PRNGKey(5), prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(5), prompt)
 
     key = jax.random.PRNGKey(7)
     a = generate(model.cfg, params, prompt, 5, temperature=1.0, key=key)
@@ -240,7 +240,7 @@ def test_generate_eos_freezes_finished_rows():
     a frozen row must never perturb its batch peers."""
     model = _model(pos_embedding="rope")
     prompt = _prompt(model, b=3, s=6, seed=8)
-    params = model.init(jax.random.PRNGKey(8), prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(8), prompt)
     steps = 6
     full = np.asarray(generate(model.cfg, params, prompt, steps))
     # Pick a token some row actually emits mid-stream so the freeze has
@@ -262,7 +262,7 @@ def test_generate_eos_unused_matches_plain():
     (the early-exit path is the same math, only gated)."""
     model = _model()
     prompt = _prompt(model, s=6, seed=10)
-    params = model.init(jax.random.PRNGKey(10), prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(10), prompt)
     plain = np.asarray(generate(model.cfg, params, prompt, 5))
     eos = int(model.cfg.vocab_size - 1)
     if eos in plain:  # pragma: no cover - vanishingly unlikely
@@ -281,7 +281,7 @@ def test_assign_slot_isolated_and_matches_single_stream():
     model = _model(pos_embedding="rope", num_kv_heads=2)
     cfg = model.cfg
     prompt = _prompt(model, b=1, s=7, seed=11)
-    params = model.init(jax.random.PRNGKey(11), prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(11), prompt)
     steps = 5
     want = np.asarray(generate(cfg, params, prompt, steps))[0]
 
@@ -311,7 +311,7 @@ def test_assign_slot_isolated_and_matches_single_stream():
 def test_reset_slot_clears_one_slot_only():
     model = _model()
     cfg = model.cfg
-    params = model.init(jax.random.PRNGKey(13), _prompt(model))
+    params = jax.jit(model.init)(jax.random.PRNGKey(13), _prompt(model))
     cache = init_cache(cfg, 3)
     cache, _ = assign_slot(cfg, params, cache, 0,
                            _prompt(model, b=1, s=4, seed=14)[0])
@@ -329,7 +329,7 @@ def test_legacy_scalar_pos_cache_still_decodes():
     old checkpoint) broadcast into the per-slot layout on first use."""
     model = _model()
     prompt = _prompt(model, s=4, seed=16)
-    params = model.init(jax.random.PRNGKey(16), prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(16), prompt)
     _, cache = prefill(model.cfg, params, prompt)
     legacy = {"k": cache["k"], "v": cache["v"],
               "pos": jnp.asarray(4, jnp.int32)}

@@ -11,6 +11,7 @@ are held from expert 4 on, 3 a token, one dense layer before two expert
 layers.
 """
 
+import functools
 import os
 import sys
 from dataclasses import replace
@@ -54,7 +55,8 @@ def init(model, key=1):
     away from 1 (at 1 the final norm before the prediction module's own
     norm changes nothing, and a reference that takes the stream after it
     could not be told from one that takes it before)."""
-    variables = model.init(jax.random.PRNGKey(key), TOKENS[:, :SEQ])
+    variables = jax.jit(model.init)(jax.random.PRNGKey(key),
+                                    TOKENS[:, :SEQ])
 
     def moved(path, leaf):
         name = jax.tree_util.keystr(path)
@@ -82,28 +84,49 @@ def program_loss(model, variables, tokens):
     return main + CONFIG["mtp_loss_weight"] * mtp
 
 
+def _traced(forward, losses, loss, variables):
+    """Both heads' logits, both losses and the gradient of the weighted
+    loss in ``params``, from one trace."""
+    def run(v):
+        grads = jax.grad(lambda p: loss({**v, "params": p}))(v["params"])
+        return forward(v), losses(v), loss(v), grads
+
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(run)(variables)
+
+
+@functools.cache
+def sound():
+    """The seeded variables and what the plain reference gives for them,
+    computed once a module (the attention's form changes no variable)."""
+    variables = init(small_model())
+    return variables, _traced(
+        lambda v: ref.forward(CONFIG, v, TOKENS[:, :-2], TOKENS[:, 1:-1]),
+        lambda v: ref.losses(CONFIG, v, TOKENS),
+        lambda v: ref.loss(CONFIG, v, TOKENS), variables)
+
+
+@functools.cache
+def program(attention):
+    model = small_model(attention_impl=attention)
+    return _traced(
+        lambda v: model.apply(
+            {k: v[k] for k in ("params", "moe_state")},
+            TOKENS[:, :-2], next_tokens=TOKENS[:, 1:-1]),
+        lambda v: program_losses(model, v, TOKENS),
+        lambda v: program_loss(model, v, TOKENS), sound()[0])
+
+
 @pytest.mark.parametrize("attention", ["reference", "flash"])
 def test_model_matches_plain_reference(attention):
     """Logits of both heads, both losses and every leaf of the gradient,
     with the reference attention and through the flash kernels (the
     Pallas interpreter): query and key channels 16 + 8 = the values' 24."""
-    model = small_model(attention_impl=attention)
-    variables = init(model)
-    with jax.default_matmul_precision("highest"):
-        got = model.apply(
-            {k: variables[k] for k in ("params", "moe_state")},
-            TOKENS[:, :-2], next_tokens=TOKENS[:, 1:-1])
-        want = ref.forward(CONFIG, variables, TOKENS[:, :-2],
-                           TOKENS[:, 1:-1])
-        for name, a, b in zip(("logits", "mtp_logits"), got, want):
-            np.testing.assert_allclose(a, b, atol=2e-4, err_msg=name)
-        np.testing.assert_allclose(
-            program_losses(model, variables, TOKENS),
-            ref.losses(CONFIG, variables, TOKENS), atol=1e-5)
-        got_grads = jax.grad(lambda p: program_loss(
-            model, {**variables, "params": p}, TOKENS))(variables["params"])
-        want_grads = jax.grad(lambda p: ref.loss(
-            CONFIG, {**variables, "params": p}, TOKENS))(variables["params"])
+    got, got_losses, _, got_grads = program(attention)
+    _, (want, want_losses, _, want_grads) = sound()
+    for name, a, b in zip(("logits", "mtp_logits"), got, want):
+        np.testing.assert_allclose(a, b, atol=2e-4, err_msg=name)
+    np.testing.assert_allclose(got_losses, want_losses, atol=1e-5)
     flat_got = dict(jax.tree_util.tree_leaves_with_path(got_grads))
     flat_want = dict(jax.tree_util.tree_leaves_with_path(want_grads))
     assert flat_got.keys() == flat_want.keys()
@@ -119,13 +142,12 @@ def test_model_matches_plain_reference(attention):
     "bias_in_weights", "rope_per_head_key", "mtp_after_norm",
     "concat_swapped"])
 def test_comparison_fails_on_a_seeded_departure(depart):
-    model = small_model()
-    variables = init(model)
+    variables, (_, _, sound_loss, _) = sound()
+    got = program("reference")[2]
     with jax.default_matmul_precision("highest"):
-        got = program_loss(model, variables, TOKENS)
-        sound = ref.loss(CONFIG, variables, TOKENS)
-        departed = ref.loss(CONFIG, variables, TOKENS, depart)
-    assert abs(got - sound) < 1e-5
+        departed = jax.jit(lambda v: ref.loss(CONFIG, v, TOKENS, depart))(
+            variables)
+    assert abs(got - sound_loss) < 1e-5
     assert abs(got - departed) > 1e-4
 
 
@@ -146,7 +168,8 @@ def test_the_shares_add_up_to_the_uncut_layer():
     positions = jnp.arange(SEQ)
     tabs = rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
     whole = _block(cfg, 0, 16)
-    variables = whole.init(jax.random.PRNGKey(4), x, positions, tabs)
+    variables = jax.jit(whole.init)(jax.random.PRNGKey(4), x, positions,
+                                    tabs)
     p = dict(variables["params"])
     p["router"] = p["router"] * 10.0
     state = {"moe_state": variables["moe_state"]}
@@ -274,8 +297,9 @@ def test_the_rows_of_the_last_step_are_state_and_not_parameters():
     assert set(variables) == {"params", "moe_state", "moe_stats"}
     assert set(variables["moe_state"]) == {"block1", "block2", "mtp"}
     assert float(jnp.abs(variables["moe_state"]["block1"]["bias"]).min()) > 0
-    _, new = model.apply(variables, TOKENS[:, :-2],
-                         next_tokens=TOKENS[:, 1:-1], mutable=["moe_stats"])
+    _, new = jax.jit(lambda v: model.apply(
+        v, TOKENS[:, :-2], next_tokens=TOKENS[:, 1:-1],
+        mutable=["moe_stats"]))(variables)
     registry = MetricsRegistry()
     stats = moe.publish_stats(new["moe_stats"], registry)
     assert set(stats) == {"block1", "block2", "mtp/block"}
@@ -293,7 +317,8 @@ def test_the_rows_of_the_last_step_are_state_and_not_parameters():
         assert registry.gauge("moe.rows_held", layer=layer).value == \
             entry["rows_held"]
     # an apply that does not ask for the counters leaves them alone
-    assert model.apply(variables, TOKENS[:, :SEQ]).shape == (2, SEQ, 256)
+    assert jax.eval_shape(model.apply, variables,
+                          TOKENS[:, :SEQ]).shape == (2, SEQ, 256)
 
 
 # ------------------------------------------------------------ the row bound
@@ -450,10 +475,11 @@ def test_the_overflow_counter_counts_steps_and_is_published(monkeypatch):
         if "block1" in jax.tree_util.keystr(path) else leaf,
         variables["moe_state"])
     variables = {**variables, "moe_state": skewed}
+    apply = jax.jit(lambda v: model.apply(
+        v, TOKENS[:, :-2], next_tokens=TOKENS[:, 1:-1],
+        mutable=["moe_stats"]))
     for step in (1, 2):
-        _, new = model.apply(variables, TOKENS[:, :-2],
-                             next_tokens=TOKENS[:, 1:-1],
-                             mutable=["moe_stats"])
+        _, new = apply(variables)
         variables = {**variables, "moe_stats": new["moe_stats"]}
         registry = MetricsRegistry()
         stats = moe.publish_stats(new["moe_stats"], registry)
@@ -481,15 +507,16 @@ def test_a_rematerialised_block_keeps_both_sides_of_the_bound_exact(
     model = small_model(remat=True, remat_policy=policy)
     variables = init(model)
 
-    def outcome():
-        return jax.value_and_grad(
+    def outcome():  # a function of its own a call: traced anew each time
+        return jax.jit(jax.value_and_grad(
             lambda p: program_loss(model, {**variables, "params": p},
-                                   TOKENS))(variables["params"])
+                                   TOKENS)))(variables["params"])
 
     unbounded = outcome()
     _small_tiles(monkeypatch, shares=1)
-    _, new = model.apply(variables, TOKENS[:, :-2],
-                         next_tokens=TOKENS[:, 1:-1], mutable=["moe_stats"])
+    _, new = jax.jit(lambda v: model.apply(
+        v, TOKENS[:, :-2], next_tokens=TOKENS[:, 1:-1],
+        mutable=["moe_stats"]))(variables)
     over = [entry["overflow_steps"]
             for entry in moe.publish_stats(new["moe_stats"]).values()]
     assert sorted(set(over)) == [0, 1]
@@ -644,9 +671,11 @@ def test_flash_takes_unequal_head_sizes():
     """Values narrower than the keys: the flash kernels have taken them
     since PR 42 and ``mla_mixer``'s refusal, older than that, went in
     PR 51; the flash path and the reference path agree."""
-    variables = small_model(v_head_dim=16).init(
+    variables = jax.jit(small_model(v_head_dim=16).init)(
         jax.random.PRNGKey(0), TOKENS[:, :SEQ])
     with jax.default_matmul_precision("highest"):
-        flash, plain = (small_model(attention_impl=impl, v_head_dim=16).apply(
-            variables, TOKENS[:, :SEQ])[0] for impl in ("flash", "reference"))
+        flash, plain = (
+            jax.jit(small_model(attention_impl=impl, v_head_dim=16).apply)(
+                variables, TOKENS[:, :SEQ])[0]
+            for impl in ("flash", "reference"))
     np.testing.assert_allclose(flash, plain, atol=2e-4)

@@ -336,13 +336,14 @@ def test_a_model_of_several_streams_and_the_gauges_it_sets():
     model = gpt("nano", hc_mult=2, hc_sinkhorn_iters=7,
                 attention_impl="reference", dtype=jnp.float32)
     tokens = jnp.arange(16).reshape(1, 16) % 7
-    variables = model.init(jax.random.PRNGKey(0), tokens)
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     assert set(variables) == {"params", "hc_stats"}
     block = variables["params"]["block0"]
     assert block["hc_attn_phi"].shape == (2 * 128, 2 * 2 + 2 * 2)
     assert block["hc_mlp_scale"].shape == (256,)
     assert block["hc_attn_alpha"].tolist() == [1.0, 1.0, 1.0]
-    logits, new = model.apply(variables, tokens, mutable=["hc_stats"])
+    logits, new = jax.jit(lambda v: model.apply(
+        v, tokens, mutable=["hc_stats"]))(variables)
     assert logits.shape == (1, 16, 1024)
     assert get_registry().gauge("hc.streams").value == 2
     assert get_registry().gauge("hc.sinkhorn_iters").value == 7
@@ -355,8 +356,8 @@ def test_a_model_of_several_streams_and_the_gauges_it_sets():
     assert registry.gauge("hc.stochastic_err").value < 0.05
     assert hc.publish_stats({}, registry) == {}
     # the gradient reaches every leaf of both connections
-    grads = jax.grad(lambda p: model.apply(
-        {"params": p}, tokens).astype(jnp.float32).var())(
+    grads = jax.jit(jax.grad(lambda p: model.apply(
+        {"params": p}, tokens).astype(jnp.float32).var()))(
             variables["params"])
     for name, leaf in grads["block2"].items():
         if name.startswith("hc_"):

@@ -75,7 +75,8 @@ def init(model, key=1):
     """Seeded variables; the router ten times its initial size so that
     the scores spread at this width, and the norms' weights away from
     1."""
-    variables = model.init(jax.random.PRNGKey(key), TOKENS[:, :SEQ])
+    variables = jax.jit(model.init)(jax.random.PRNGKey(key),
+                                    TOKENS[:, :SEQ])
 
     def moved(path, leaf):
         name = jax.tree_util.keystr(path)
@@ -113,9 +114,17 @@ def sound():
     plain reference gives for them, computed once."""
     variables = init(small_model())
     with jax.default_matmul_precision("highest"):
-        return (variables, ref.logprob(CONFIG, variables, BATCH),
-                grads_of(jax.jit(lambda v: ref.loss(CONFIG, v, BATCH)),
-                         variables))
+        return (variables, *jax.jit(lambda v: (
+            ref.logprob(CONFIG, v, BATCH),
+            grads_of(lambda t: ref.loss(CONFIG, t, BATCH), v)))(variables))
+
+
+def program(model, variables):
+    """The labels' log-probabilities and the gradient of their mean, from
+    one trace."""
+    return jax.jit(lambda v: (
+        program_logprob(model, v, TOKENS),
+        grads_of(lambda t: program_loss(model, t, TOKENS), v)))(variables)
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["kept", "remat"])
@@ -128,9 +137,7 @@ def test_model_matches_plain_reference(attention, remat):
     model = small_model(attention_impl=attention, remat=remat)
     variables, want_logp, want_grads = sound()
     with jax.default_matmul_precision("highest"):
-        got_logp = program_logprob(model, variables, TOKENS)
-        got_grads = grads_of(lambda v: program_loss(model, v, TOKENS),
-                             variables)
+        got_logp, got_grads = program(model, variables)
     np.testing.assert_allclose(got_logp, want_logp, atol=2e-4)
     np.testing.assert_allclose(got_logp.mean(), want_logp.mean(), atol=1e-5)
     flat_got = dict(jax.tree_util.tree_leaves_with_path(got_grads))
@@ -161,8 +168,7 @@ BF16_LIMITS = dict(loss_abs=0.02, logprob_abs=0.4, grad_rel=0.15)
 def test_bfloat16_stays_within_stated_limits_of_the_reference():
     model = small_model(dtype=jnp.bfloat16)
     variables, want_logp, want_grads = sound()
-    got_logp = program_logprob(model, variables, TOKENS)
-    got_grads = grads_of(lambda v: program_loss(model, v, TOKENS), variables)
+    got_logp, got_grads = program(model, variables)
     norm = lambda tree: float(jnp.sqrt(sum(
         jnp.sum(jnp.square(g.astype(jnp.float32)))
         for g in jax.tree.leaves(tree))))
@@ -175,17 +181,25 @@ def test_bfloat16_stays_within_stated_limits_of_the_reference():
     assert apart(want_grads) <= BF16_LIMITS["grad_rel"]
     # and the limits are no formality: they tell a departure
     with jax.default_matmul_precision("highest"):
-        departed = grads_of(jax.jit(lambda v: ref.loss(
-            CONFIG, v, BATCH, "weights_unnormalised")), variables)
+        departed = jax.jit(lambda v: grads_of(lambda t: ref.loss(
+            CONFIG, t, BATCH, "weights_unnormalised"), v))(variables)
     assert apart(departed) > 2 * BF16_LIMITS["grad_rel"]
+
+
+@functools.cache
+def sound_program_loss():
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(lambda v: program_loss(small_model(), v, TOKENS))(
+            sound()[0])
 
 
 @pytest.mark.parametrize("depart", ref.DEPARTURES)
 def test_comparison_fails_on_a_seeded_departure(depart):
     variables, want_logp, _ = sound()
+    got = sound_program_loss()
     with jax.default_matmul_precision("highest"):
-        got = program_loss(small_model(), variables, TOKENS)
-        departed = ref.loss(CONFIG, variables, BATCH, depart)
+        departed = jax.jit(lambda v: ref.loss(CONFIG, v, BATCH, depart))(
+            variables)
     assert abs(got + want_logp.mean()) < 1e-5
     assert abs(got - departed) > 1e-4
 
@@ -259,7 +273,8 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
         return Block(replace(cfg, routed_first_held=first,
                              routed_held=held), "conv", "routed")
 
-    variables = block(0, 16).init(jax.random.PRNGKey(4), x, positions)
+    variables = jax.jit(block(0, 16).init)(jax.random.PRNGKey(4), x,
+                                           positions)
     p = dict(variables["params"])
     p["router"] = p["router"] * 10.0
     bias = variables["moe_state"]["bias"]

@@ -218,7 +218,7 @@ def test_ep_gradient_recipe_matches_dense():
             loss_shard(p, x[r * per:(r + 1) * per]) for r in range(EP)
         ) / EP
 
-    g_dense = jax.grad(loss_dense)(p)
+    g_dense = jax.jit(jax.grad(loss_dense))(p)
 
     def local_grads(router, w1, b1, w2, b2, x_l):
         lp = MoEParams(router, w1, b1, w2, b2)

@@ -1190,8 +1190,8 @@ def _python_autotune_fn(log_path):
         hvd.allreduce(np.ones(2048, np.float32), op=hvd.Sum,
                       name=f"t{i % 4}")
         i += 1
-        if i % 50 == 0 and rank == 0:
-            try:
+        if i % 50 == 0:  # both ranks read rank 0's log: neither waits
+            try:             # out the deadline once both states are there
                 with open(log_path) as f:
                     cache_col = {
                         line.split(",")[4] for line in f.readlines()[1:]

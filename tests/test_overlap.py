@@ -346,10 +346,10 @@ def test_sync_gradients_matches_reduced_value_and_grad():
 
     outs = []
     for fn in (synced, reference):
-        outs.append(shard_map_compat(
+        outs.append(jax.jit(shard_map_compat(
             fn, mesh=mesh, in_specs=(P(), P(AX), P(AX)),
             out_specs=(P(), P()),
-        )(params, x, y))
+        ))(params, x, y))
     (loss_s, grads_s), (loss_r, grads_r) = outs
     assert float(loss_s) == float(loss_r)
     _assert_bitwise(jax.tree_util.tree_leaves(grads_s),
@@ -370,10 +370,10 @@ def test_sync_gradients_has_aux():
         )
         return loss, aux["n"], grads
 
-    loss, n, grads = shard_map_compat(
+    loss, n, grads = jax.jit(shard_map_compat(
         run, mesh=mesh, in_specs=(P(), P(AX), P(AX)),
         out_specs=(P(), P(), P()),
-    )(params, x, y)
+    ))(params, x, y)
     assert float(n) == 1.0
     assert np.isfinite(float(loss))
     assert jax.tree_util.tree_structure(grads) \

@@ -73,7 +73,8 @@ def init(model, key=1):
     """Seeded variables, every leaf moved off its initial value (biases
     and norm weights start at 0 and 1, and the four lambda vectors at a
     tenth, where a lost term would not show)."""
-    variables = model.init(jax.random.PRNGKey(key), TOKENS[:, :SEQ])
+    variables = jax.jit(model.init)(jax.random.PRNGKey(key),
+                                    TOKENS[:, :SEQ])
 
     def moved(path, leaf):
         name = jax.tree_util.keystr(path)

@@ -67,8 +67,8 @@ def _pp_fwd(model, params, tokens, microbatches):
 def test_pp_matches_single_device(microbatches, pos_embedding):
     model = _model(pos_embedding=pos_embedding)
     tokens = _tokens()
-    params = model.init(jax.random.PRNGKey(0), tokens)
-    ref = model.apply(params, tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
+    ref = jax.jit(model.apply)(params, tokens)
     out = _pp_fwd(model, params, tokens, microbatches)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-4, rtol=2e-4
@@ -80,7 +80,7 @@ def test_pp_gradients_match():
     model (check_vma=True for the collective transposes, as with TP)."""
     model = _model()
     tokens = _tokens(1)
-    params = model.init(jax.random.PRNGKey(1), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), tokens)
     targets = jnp.roll(tokens, -1, axis=1)
 
     def loss_ref(p):
@@ -89,7 +89,7 @@ def test_pp_gradients_match():
             jax.nn.log_softmax(logits), targets[..., None], -1
         ).mean()
 
-    g_ref = jax.grad(loss_ref)(params)["params"]
+    g_ref = jax.jit(jax.grad(loss_ref))(params)["params"]
     staged, replicated = stack_pp_params(params, model.cfg, PP)
 
     def local_loss(staged, replicated, tok, tgt):
@@ -122,7 +122,7 @@ def test_pp_gradients_match():
 
 
 def _ref_token_loss(model, params, tokens, targets):
-    logits = model.apply(params, tokens)
+    logits = jax.jit(model.apply)(params, tokens)
     return -jnp.take_along_axis(
         jax.nn.log_softmax(logits), targets[..., None], -1
     ).mean()
@@ -135,7 +135,7 @@ def test_pp_loss_matches_single_device(remat):
     model = _model()
     tokens = _tokens(2)
     targets = jnp.roll(tokens, -1, axis=1)
-    params = model.init(jax.random.PRNGKey(2), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(2), tokens)
     ref = _ref_token_loss(model, params, tokens, targets)
     staged, replicated = stack_pp_params(params, model.cfg, PP)
 
@@ -163,10 +163,10 @@ def test_pp_loss_gradients_match():
     model = _model()
     tokens = _tokens(3)
     targets = jnp.roll(tokens, -1, axis=1)
-    params = model.init(jax.random.PRNGKey(3), tokens)
-    g_ref = jax.grad(
+    params = jax.jit(model.init)(jax.random.PRNGKey(3), tokens)
+    g_ref = jax.jit(jax.grad(
         lambda p: _ref_token_loss(model, p, tokens, targets)
-    )(params)["params"]
+    ))(params)["params"]
     staged, replicated = stack_pp_params(params, model.cfg, PP)
 
     def local_loss(staged, replicated, tok, tgt):
@@ -208,7 +208,7 @@ def test_pp_apply_remat_matches():
     """remat=True is numerically a no-op for the logits path."""
     model = _model()
     tokens = _tokens(4)
-    params = model.init(jax.random.PRNGKey(4), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(4), tokens)
     staged, replicated = stack_pp_params(params, model.cfg, PP)
 
     def run(remat):
@@ -238,7 +238,7 @@ def test_pp_circular_loss_matches_single_device(pp, circles, layers, mbs):
     model = _model(num_layers=layers)
     tokens = _tokens(5)
     targets = jnp.roll(tokens, -1, axis=1)
-    params = model.init(jax.random.PRNGKey(5), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(5), tokens)
     ref = _ref_token_loss(model, params, tokens, targets)
     staged, replicated = stack_pp_params_circular(
         params, model.cfg, pp, circles
@@ -271,10 +271,10 @@ def test_pp_circular_gradients_match():
     model = _model(num_layers=4)
     tokens = _tokens(6)
     targets = jnp.roll(tokens, -1, axis=1)
-    params = model.init(jax.random.PRNGKey(6), tokens)
-    g_ref = jax.grad(
+    params = jax.jit(model.init)(jax.random.PRNGKey(6), tokens)
+    g_ref = jax.jit(jax.grad(
         lambda p: _ref_token_loss(model, p, tokens, targets)
-    )(params)["params"]
+    ))(params)["params"]
     staged, replicated = stack_pp_params_circular(
         params, model.cfg, pp, circles
     )
@@ -319,7 +319,7 @@ def test_pp_circular_gradients_match():
 
 def test_pp_circular_validation_errors():
     model = _model()  # 4 layers
-    params = model.init(jax.random.PRNGKey(0), _tokens())
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), _tokens())
     with pytest.raises(ValueError, match="must divide"):
         stack_pp_params_circular(params, model.cfg, 4, 2)  # 8 !| 4
     staged, replicated = stack_pp_params_circular(params, model.cfg, 2, 2)
@@ -354,12 +354,12 @@ def test_pp_circular_validation_errors():
 
 def test_pp_validation_errors():
     model = _model(num_layers=3)  # 3 % 4 != 0
-    params = model.init(jax.random.PRNGKey(0), _tokens())
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), _tokens())
     with pytest.raises(ValueError, match="must divide num_layers"):
         stack_pp_params(params, model.cfg, PP)
 
     model = _model()
-    params = model.init(jax.random.PRNGKey(0), _tokens())
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), _tokens())
     with pytest.raises(Exception, match="microbatches"):
         _pp_fwd(model, params, _tokens(b=3), microbatches=2)
 
@@ -377,7 +377,7 @@ def test_unstack_round_trips():
     )
 
     model = _model()  # 4 layers
-    params = model.init(jax.random.PRNGKey(7), _tokens())["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(7), _tokens())["params"]
 
     staged, rep = stack_pp_params({"params": params}, model.cfg, PP)
     assert_trees_equal(

@@ -142,10 +142,12 @@ def test_differential_attention_matches_the_dense_formula(attention, kind,
         return jnp.stack(want, axis=2)
 
     args = (q, k, v, lambdas, scale)
-    np.testing.assert_allclose(program(*args), dense(*args), atol=2e-5)
-    got, want = (jax.grad(lambda *a: (f(*a) * weight).sum(),
-                          argnums=(0, 1, 2, 3, 4))(*args)
-                 for f in (program, dense))
+    (out, got), (dense_out, want) = (  # one trace a side
+        jax.jit(lambda *a: (f(*a), jax.grad(
+            lambda *b: (f(*b) * weight).sum(),
+            argnums=(0, 1, 2, 3, 4))(*a)))(*args)
+        for f in (program, dense))
+    np.testing.assert_allclose(out, dense_out, atol=2e-5)
     for name, a, r in zip(("q", "k", "v", "lambdas", "scale"), got, want):
         for leaf, want_leaf in zip(jax.tree.leaves(a), jax.tree.leaves(r)):
             np.testing.assert_allclose(leaf, want_leaf, atol=5e-5,

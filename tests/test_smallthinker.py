@@ -17,6 +17,7 @@ head of 16, 8 experts of width 32, 3 a token, a window of 8 in 32 tokens,
 one full layer and three window layers.
 """
 
+import functools
 import hashlib
 import importlib.util
 import os
@@ -74,7 +75,8 @@ def init(model, key=1):
     """Seeded parameters; the router ten times its initial size so that
     the logits spread at this width, and the norms' weights away from
     1."""
-    variables = model.init(jax.random.PRNGKey(key), TOKENS[:, :SEQ])
+    variables = jax.jit(model.init)(jax.random.PRNGKey(key),
+                                    TOKENS[:, :SEQ])
 
     def moved(path, leaf):
         name = jax.tree_util.keystr(path)
@@ -106,6 +108,41 @@ def program_loss(model, variables, tokens):
         jax.tree.leaves(sown.get("losses", {})))
 
 
+def _outcome(logprob, loss, variables):
+    """The labels' log-probabilities, the loss and the gradient of the
+    loss in ``params``, from one trace."""
+    def run(v):
+        grads = jax.grad(lambda p: loss({"params": p}))(v["params"])
+        return logprob(v), loss(v), grads
+
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(run)(variables)
+
+
+@functools.cache
+def seeded():
+    """The seeded variables: the same whatever the attention's form, the
+    balance loss's coefficient and the router's placement."""
+    return init(small_model())
+
+
+@functools.cache
+def sound(coef=COEF):
+    """What the plain reference gives for the seeded variables, computed
+    once a coefficient."""
+    config = {**CONFIG, "balance_loss_coef": coef}
+    batch = {"tokens": TOKENS}
+    return _outcome(lambda v: ref.logprob(config, v, batch),
+                    lambda v: ref.loss(config, v, batch), seeded())
+
+
+@functools.cache
+def program(attention="reference", coef=COEF):
+    model = small_model(attention_impl=attention, routed_balance_coef=coef)
+    return _outcome(lambda v: program_logprob(model, v, TOKENS),
+                    lambda v: program_loss(model, v, TOKENS), seeded())
+
+
 @pytest.mark.parametrize("coef", [0.0, COEF], ids=["plain", "balanced"])
 @pytest.mark.parametrize("attention", ["reference", "flash"])
 def test_model_matches_plain_reference(attention, coef):
@@ -113,21 +150,10 @@ def test_model_matches_plain_reference(attention, coef):
     gradient, with the reference attention and through the flash kernels
     (the Pallas interpreter, seven query heads on one key/value head),
     without the balance loss and with it."""
-    model = small_model(attention_impl=attention, routed_balance_coef=coef)
-    variables = init(model)
-    config = {**CONFIG, "balance_loss_coef": coef}
-    batch = {"tokens": TOKENS}
-    with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(
-            program_logprob(model, variables, TOKENS),
-            ref.logprob(config, variables, batch), atol=2e-4)
-        np.testing.assert_allclose(
-            program_loss(model, variables, TOKENS),
-            ref.loss(config, variables, batch), atol=1e-5)
-        got_grads = jax.grad(lambda p: program_loss(
-            model, {"params": p}, TOKENS))(variables["params"])
-        want_grads = jax.grad(lambda p: ref.loss(
-            config, {"params": p}, batch))(variables["params"])
+    got_logp, got_loss, got_grads = program(attention, coef)
+    want_logp, want_loss, want_grads = sound(coef)
+    np.testing.assert_allclose(got_logp, want_logp, atol=2e-4)
+    np.testing.assert_allclose(got_loss, want_loss, atol=1e-5)
     flat_got = dict(jax.tree_util.tree_leaves_with_path(got_grads))
     flat_want = dict(jax.tree_util.tree_leaves_with_path(want_grads))
     assert flat_got.keys() == flat_want.keys()
@@ -139,17 +165,17 @@ def test_model_matches_plain_reference(attention, coef):
             err_msg=jax.tree_util.keystr(path))
 
 
+def _departed(depart):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(lambda v: ref.loss(
+            CONFIG, v, {"tokens": TOKENS}, depart))(seeded())
+
+
 @pytest.mark.parametrize("depart", ref.DEPARTURES)
 def test_comparison_fails_on_a_seeded_departure(depart):
-    model = small_model()
-    variables = init(model)
-    batch = {"tokens": TOKENS}
-    with jax.default_matmul_precision("highest"):
-        got = program_loss(model, variables, TOKENS)
-        sound = ref.loss(CONFIG, variables, batch)
-        departed = ref.loss(CONFIG, variables, batch, depart)
-    assert abs(got - sound) < 1e-5
-    assert abs(got - departed) > 1e-4
+    got = program()[1]
+    assert abs(got - sound()[1]) < 1e-5
+    assert abs(got - _departed(depart)) > 1e-4
 
 
 def test_the_late_router_chooses_other_experts_and_is_the_other_setting():
@@ -158,14 +184,12 @@ def test_the_late_router_chooses_other_experts_and_is_the_other_setting():
     after attention the choices differ, and the program with the usual
     placement (``routed_router_input="ffn_input"``) is the reference's
     ``router_after_attention`` departure, not the sound reference."""
-    early, late = small_model(), small_model(routed_router_input="ffn_input")
-    variables = init(early)
-    batch = {"tokens": TOKENS}
+    late = small_model(routed_router_input="ffn_input")
+    variables = seeded()
     with jax.default_matmul_precision("highest"):
-        got = program_loss(late, variables, TOKENS)
-        assert abs(got - ref.loss(CONFIG, variables, batch,
-                                  "router_after_attention")) < 1e-5
-        assert abs(got - ref.loss(CONFIG, variables, batch)) > 1e-4
+        got = jax.jit(lambda v: program_loss(late, v, TOKENS))(variables)
+        assert abs(got - _departed("router_after_attention")) < 1e-5
+        assert abs(got - sound()[1]) > 1e-4
         blk = variables["params"]["block1"]
         x = jax.random.normal(jax.random.PRNGKey(5), (2, SEQ, 64))
         after = x + ref._attention(CONFIG, blk, ref._rms_norm(
@@ -196,7 +220,8 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
         return Block(replace(cfg, routed_first_held=first,
                              routed_held=held), kind, "routed")
 
-    variables = block(0, 8).init(jax.random.PRNGKey(4), x, positions, tabs)
+    variables = jax.jit(block(0, 8).init)(jax.random.PRNGKey(4), x,
+                                          positions, tabs)
     assert "moe_state" not in variables          # no selection bias
     p = dict(variables["params"])
     p["router"] = p["router"] * 10.0
@@ -276,8 +301,9 @@ def test_the_gates_handwritten_backward_is_jax_grads(activation):
         return (ys * probe).sum()
 
     with jax.default_matmul_precision("highest"):
-        want = jax.value_and_grad(plain, argnums=(0, 1, 2))(xs, gate_up, down)
-        got = jax.value_and_grad(ours, argnums=(0, 1, 2))(xs, gate_up, down)
+        want, got = (
+            jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))(xs, gate_up, down)
+            for f in (plain, ours))
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         a, b, atol=1e-4 * float(jnp.abs(b).max()) + 1e-7), got, want)
     assert float(jnp.abs(got[1][0][jnp.asarray([1, 6])]).max()) == 0.0
@@ -463,7 +489,7 @@ def test_the_router_traces_at_the_blocks_top_with_the_balance_inside():
     ``moe_balance`` inside it; the counters hold the balance loss and
     there is no selection bias to keep."""
     model = small_model()
-    variables = init(model)
+    variables = seeded()
     text = jax.jit(lambda v, t: program_loss(model, v, t)).lower(
         variables, TOKENS).as_text(debug_info=True)
     assert "block0/moe_route/" in text
@@ -474,11 +500,11 @@ def test_the_router_traces_at_the_blocks_top_with_the_balance_inside():
     text = jax.jit(lambda v, t: program_loss(late, v, t)).lower(
         variables, TOKENS).as_text(debug_info=True)
     assert "block0/mlp/moe_route/" in text
-    made = model.init(jax.random.PRNGKey(0), TOKENS[:, :SEQ])
+    made = jax.jit(model.init)(jax.random.PRNGKey(0), TOKENS[:, :SEQ])
     assert set(made) == {"params", "moe_stats", "losses"}
-    _, new = model.apply({"params": made["params"],
-                          "moe_stats": made["moe_stats"]}, TOKENS[:, :SEQ],
-                         mutable=["moe_stats", "losses"])
+    _, new = jax.jit(lambda v: model.apply(
+        v, TOKENS[:, :SEQ], mutable=["moe_stats", "losses"]))(
+            {"params": made["params"], "moe_stats": made["moe_stats"]})
     assert set(new["moe_stats"]["block0"]) == {
         "rows", "dropped", "load", "overflow_steps", "balance_loss"}
     stats = moe.publish_stats(new["moe_stats"])
@@ -591,10 +617,8 @@ def test_two_passes_agree_with_the_one_kernel_at_seven_to_a_kv_head(
     Q tile outermost, which the 16 384-key cell runs since PR 44, against
     the two passes it ran before, every bit, and both against the
     blockwise scan."""
-    from flash_oracle import folded_plan
-    from test_flash_attention import (
-        _grouped_blockwise, _pallas_calls, _vmem_limits,
-    )
+    from flash_oracle import (folded_plan, grouped_blockwise, pallas_calls,
+                              vmem_limits)
 
     from horovod_tpu.ops import flash_attention as fa
 
@@ -610,14 +634,14 @@ def test_two_passes_agree_with_the_one_kernel_at_seven_to_a_kv_head(
     def backward(plan):
         run = lambda: fa._flash_bwd_pallas(q, k, v, o, lse, do, plan, scale,
                                            True)
-        return run(), list(_pallas_calls(jax.make_jaxpr(run)().jaxpr))
+        return run(), list(pallas_calls(jax.make_jaxpr(run)().jaxpr))
 
     one, kernels = backward(plan())
     assert kernels == ["flash_bwd_dkdv"]
-    _vmem_limits(monkeypatch, 0)
+    vmem_limits(monkeypatch, 0)
     two, kernels = backward(plan())
     assert kernels == ["flash_bwd_dkdv", "flash_bwd_dq"]
-    oracle = _grouped_blockwise(q, k, v, o, lse, do, True, scale, bk, window,
+    oracle = grouped_blockwise(q, k, v, o, lse, do, True, scale, bk, window,
                                 h, hkv)
     for name, a, t, r in zip(("dq", "dk", "dv"), one, two, oracle):
         np.testing.assert_array_equal(a, t, err_msg=name)

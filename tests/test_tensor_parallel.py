@@ -64,8 +64,8 @@ def _tp_fwd(model, params, tokens):
 def test_tp_matches_single_device(pos_embedding):
     model = _model(pos_embedding=pos_embedding)
     tokens = _tokens()
-    params = model.init(jax.random.PRNGKey(0), tokens)
-    ref = model.apply(params, tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
+    ref = jax.jit(model.apply)(params, tokens)
     out = _tp_fwd(model, params, tokens)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-4, rtol=2e-4
@@ -76,8 +76,8 @@ def test_tp_gqa_matches_single_device():
     # TRUE GQA: kv_heads (4) < num_heads (8), both divisible by tp
     model = _model(num_heads=8, num_kv_heads=4)
     tokens = _tokens(1)
-    params = model.init(jax.random.PRNGKey(1), tokens)
-    ref = model.apply(params, tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), tokens)
+    ref = jax.jit(model.apply)(params, tokens)
     out = _tp_fwd(model, params, tokens)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-4, rtol=2e-4
@@ -91,7 +91,7 @@ def test_tp_gradients_match():
     transpose correct — see the tp-scaling pin below."""
     model = _model()
     tokens = _tokens(2)
-    params = model.init(jax.random.PRNGKey(2), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(2), tokens)
     targets = jnp.roll(tokens, -1, axis=1)
 
     def loss_ref(p):
@@ -100,7 +100,7 @@ def test_tp_gradients_match():
             jax.nn.log_softmax(logits), targets[..., None], -1
         ).mean()
 
-    g_ref = jax.grad(loss_ref)(params)["params"]
+    g_ref = jax.jit(jax.grad(loss_ref))(params)["params"]
     sharded, replicated = stack_tp_params(params, model.cfg, TP)
 
     def local_loss(sharded, replicated, tok, tgt):
@@ -185,7 +185,7 @@ def test_tp_replicated_stacking_scales_grads():
 
 def test_tp_divisibility_errors():
     model = _model()
-    params = model.init(jax.random.PRNGKey(0), _tokens())
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), _tokens())
     with pytest.raises(ValueError, match="must divide num_heads"):
         stack_tp_params(params, model.cfg, 3)
 
@@ -199,7 +199,7 @@ def test_unstack_tp_round_trips():
     from horovod_tpu.parallel.tensor_parallel import unstack_tp_params
 
     model = _model()
-    params = model.init(jax.random.PRNGKey(8), _tokens())["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(8), _tokens())["params"]
     sharded, replicated = stack_tp_params({"params": params},
                                           model.cfg, 2)
     assert_trees_equal(

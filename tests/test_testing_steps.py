@@ -70,6 +70,7 @@ def _shard_losses_gpt(state):
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, toks[:, 1:]).mean()
 
+    loss = jax.jit(loss)  # one trace for the eight shards
     return [float(loss(shard)) for shard in np.split(np.asarray(tokens), N)]
 
 
@@ -87,6 +88,7 @@ def _shard_losses_resnet(state):
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, y).mean()
 
+    loss = jax.jit(loss)  # one trace for the eight shards
     return [float(loss(x, y)) for x, y in zip(
         np.split(np.asarray(images), N), np.split(np.asarray(labels), N))]
 

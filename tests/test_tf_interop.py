@@ -3,12 +3,27 @@ test/test_tensorflow.py — op correctness, gradients, DistributedOptimizer,
 DistributedGradientTape, IndexedSlices sparse path; single-process
 identities here, real 2-process semantics in test_multiprocess.py)."""
 
+import importlib.util
+
 import numpy as np
 import pytest
 
-tf = pytest.importorskip("tensorflow")
+if importlib.util.find_spec("tensorflow") is None:
+    pytest.importorskip("tensorflow")  # one skip for the module, as ever
 
-import horovod_tpu.interop.tf as hvd  # noqa: E402
+tf = hvd = None  # ``_tensorflow`` binds them: see there
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _tensorflow():
+    # TensorFlow is imported here and not at the top: every xdist worker
+    # imports every test file, and the twenty seconds its import takes
+    # belong to the one worker that runs this file.  Nothing outside a
+    # test's body (or a helper a test calls) may name ``tf`` or ``hvd``.
+    global tf, hvd
+    import tensorflow as tf
+
+    import horovod_tpu.interop.tf as hvd
 
 
 @pytest.fixture(autouse=True)

@@ -16,6 +16,7 @@ layers and a full one, one dense layer before two expert layers of 16
 experts of width 32 of which 4 are held from expert 4 on, 3 a token.
 """
 
+import functools
 import hashlib
 import importlib.util
 import os
@@ -69,7 +70,8 @@ def init(model, key=1):
     """Seeded variables; the router ten times its initial size so that
     the scores spread over (0, 1) at this width, and the norms' weights
     away from 1."""
-    variables = model.init(jax.random.PRNGKey(key), TOKENS[:, :SEQ])
+    variables = jax.jit(model.init)(jax.random.PRNGKey(key),
+                                    TOKENS[:, :SEQ])
 
     def moved(path, leaf):
         name = jax.tree_util.keystr(path)
@@ -95,25 +97,44 @@ def program_loss(model, variables, tokens):
     return -program_logprob(model, variables, tokens).mean()
 
 
+def _outcome(logprob, loss, variables):
+    """The labels' log-probabilities, the loss and the gradient of the
+    loss in ``params``, from one trace."""
+    def run(v):
+        grads = jax.grad(lambda p: loss({**v, "params": p}))(v["params"])
+        return logprob(v), loss(v), grads
+
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(run)(variables)
+
+
+@functools.cache
+def sound():
+    """The seeded variables and what the plain reference gives for them,
+    computed once a module (the attention's form changes no variable)."""
+    variables = init(small_model())
+    batch = {"tokens": TOKENS}
+    return variables, _outcome(
+        lambda v: ref.logprob(CONFIG, v, batch),
+        lambda v: ref.loss(CONFIG, v, batch), variables)
+
+
+@functools.cache
+def program(attention):
+    model = small_model(attention_impl=attention)
+    return _outcome(lambda v: program_logprob(model, v, TOKENS),
+                    lambda v: program_loss(model, v, TOKENS), sound()[0])
+
+
 @pytest.mark.parametrize("attention", ["reference", "flash"])
 def test_model_matches_plain_reference(attention):
     """The loss, every label's log-probability and every leaf of the
     gradient, with the reference attention (``local_attention`` with its
     window) and through the flash kernels (the Pallas interpreter)."""
-    model = small_model(attention_impl=attention)
-    variables = init(model)
-    batch = {"tokens": TOKENS}
-    with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(
-            program_logprob(model, variables, TOKENS),
-            ref.logprob(CONFIG, variables, batch), atol=2e-4)
-        np.testing.assert_allclose(
-            program_loss(model, variables, TOKENS),
-            ref.loss(CONFIG, variables, batch), atol=1e-5)
-        got_grads = jax.grad(lambda p: program_loss(
-            model, {**variables, "params": p}, TOKENS))(variables["params"])
-        want_grads = jax.grad(lambda p: ref.loss(
-            CONFIG, {**variables, "params": p}, batch))(variables["params"])
+    _, (want_logp, want_loss, want_grads) = sound()
+    got_logp, got_loss, got_grads = program(attention)
+    np.testing.assert_allclose(got_logp, want_logp, atol=2e-4)
+    np.testing.assert_allclose(got_loss, want_loss, atol=1e-5)
     flat_got = dict(jax.tree_util.tree_leaves_with_path(got_grads))
     flat_want = dict(jax.tree_util.tree_leaves_with_path(want_grads))
     assert flat_got.keys() == flat_want.keys()
@@ -129,14 +150,12 @@ def test_model_matches_plain_reference(attention):
     "window_ignored", "window_off_by_one", "gate_dropped",
     "rope_in_full_layer", "post_norm_dropped", "multiplier_dropped"])
 def test_comparison_fails_on_a_seeded_departure(depart):
-    model = small_model()
-    variables = init(model)
-    batch = {"tokens": TOKENS}
+    variables, (_, sound_loss, _) = sound()
+    got = program("reference")[1]
     with jax.default_matmul_precision("highest"):
-        got = program_loss(model, variables, TOKENS)
-        sound = ref.loss(CONFIG, variables, batch)
-        departed = ref.loss(CONFIG, variables, batch, depart)
-    assert abs(got - sound) < 1e-5
+        departed = jax.jit(lambda v: ref.loss(
+            CONFIG, v, {"tokens": TOKENS}, depart))(variables)
+    assert abs(got - sound_loss) < 1e-5
     assert abs(got - departed) > 1e-4
 
 
@@ -164,8 +183,8 @@ def test_the_shares_add_up_to_the_uncut_layer():
         return Block(replace(cfg, routed_first_held=first,
                              routed_held=held), kind, "routed")
 
-    variables = block(0, 128).init(jax.random.PRNGKey(4), x, positions,
-                                   tabs)
+    variables = jax.jit(block(0, 128).init)(jax.random.PRNGKey(4), x,
+                                            positions, tabs)
     p = dict(variables["params"])
     p["router"] = p["router"] * 10.0
     bias = variables["moe_state"]["bias"]
