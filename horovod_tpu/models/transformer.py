@@ -100,7 +100,12 @@ class TransformerConfig:
     # attention's o and lse, the scan's y and chunk states:
     # block_remat_policy): a kernel's cost is quadratic in the sequence
     # (the scan's, a chunk's square a chunk) and its output one
-    # activation of the stream, so it is never run a second time.
+    # activation of the stream, so it is never run a second time.  While
+    # the chip the step is traced on has room (remat_room: its limit and
+    # what is resident, from the device; the working set, from the
+    # shapes) a block keeps its matmuls' outputs too, in trace order,
+    # and the recompute is left the norms, gates and elementwise chains;
+    # a backend that reports no memory (the CPU) keeps none.
     remat: bool = False
     # Mixture-of-experts MLP (parallel/moe.py): >0 replaces every block's
     # dense MLP with moe_experts experts (GShard one-hot dispatch, static
@@ -137,9 +142,11 @@ class TransformerConfig:
     logits_scaling: float = 1.0        # logits = head(x) / scaling
     tie_embeddings: bool = False       # the head is wte, transposed
     # Which of a rematerialized block's values are kept for the backward
-    # pass beside its kernels' outputs (a name in
-    # jax.checkpoint_policies; the default keeps matmul outputs with no
-    # batch dims, the standard TPU transformer policy).
+    # pass whatever the chip's memory, beside its kernels' outputs (a
+    # name in jax.checkpoint_policies; the default keeps matmul outputs
+    # with no batch dims, the standard TPU transformer policy;
+    # "nothing_saveable" leaves them to the room the chip has:
+    # block_remat_policy).
     remat_policy: str = "dots_with_no_batch_dims_saveable"
     # The Mamba-2 mixer: ssm_heads heads of ssm_head_dim (their product is
     # the inner width), a state of ssm_state per head channel, B and C
@@ -1755,7 +1762,15 @@ class GPT(nn.Module):
                 get_registry().gauge(f"hc.{gauge}").set(value)
         block_cls = Block
         if cfg.remat:
-            block_cls = nn.remat(Block, policy=block_remat_policy(cfg))
+            # one policy a block, so that the room the chip has left
+            # is spent knowing how many blocks are still to come
+            # (block_remat_policy)
+            policy_of_block = block_remat_policy(cfg, remat_room(
+                cfg, (tokens.shape[0], s), self.variables.get("params")))
+
+            def block_cls(*args, **kwargs):
+                return nn.remat(Block, policy=policy_of_block())(
+                    *args, **kwargs)
         # what a layer made for later layers, beside the stream
         handed = {"kv": None, "memory": None}
         if cfg.layer_types:
@@ -1865,39 +1880,192 @@ class GPT(nn.Module):
         return logits, mtp_logits
 
 
-def block_remat_policy(cfg: TransformerConfig):
-    """What a rematerialised block keeps: whatever ``cfg.remat_policy``
-    keeps, and always what a Pallas kernel's forward made
-    (``scopes.KERNEL_OUTPUTS``, named in the kernels' ``custom_vjp``
-    forward rules), so the recompute never runs a kernel a second time.
+# The projected peak a rematerialised model may fill its chip to, as a
+# share of the limit the device reports: 14.5 of a v5e's 15.75 GiB, what
+# the fullest training cell of the benchmark runs at (PERF.md section 3).
+REMAT_CEILING = 14.5 / 15.75
+# The working set beside what is kept, in the terms of ``remat_room``;
+# the three counts are fitted to the peaks of the benchmark's nine
+# rematerialised cells (PERF.md section 6 has the table).
+REMAT_HEAD_COPIES = 3.0    # of the float32 logits: they, their gradient,
+#                            a compute-dtype copy of each
+REMAT_BLOCK_COPIES = 2.5   # of the widest block's matmul inputs and
+#                            outputs: the recompute's and the cotangents'
+REMAT_SLOT_COPIES = 6.0    # of an expert layer's tokens x top_k rows of
+#                            the stream: sorted, gathered, weighted, back
+
+
+# The name the gauges ``remat.kept_values`` / ``remat.kept_mib`` count
+# kept matmul outputs under, beside the kernels' ``scopes.KERNEL_OUTPUTS``.
+MATMUL_OUT = "matmul"
+
+
+def device_memory():
+    """``(bytes_limit, bytes_in_use)`` of this process's first device as
+    its allocator reports them, or None where the backend reports no
+    limit (the CPU).  The one reading ``remat_room`` rests on."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    if not stats.get("bytes_limit"):
+        return None
+    return stats["bytes_limit"], stats.get("bytes_in_use", 0)
+
+
+def remat_room(cfg: TransformerConfig, tokens_shape, params):
+    """Bytes that the rematerialised blocks of a model applied to
+    ``params`` on ``tokens_shape = (batch, seq)`` may keep beside their
+    inputs before the step's projected peak passes ``REMAT_CEILING`` of
+    the device's limit; None where the device reports none.  Read when
+    the model is traced: the train state is on the chip by then.
+
+        room = ceiling - resident - stream - transient
+        ceiling   = REMAT_CEILING x bytes_limit
+        resident  = bytes_in_use (parameters, optimizer state, batch)
+        stream    = blocks x tokens x stream width: each block's input
+        transient = max(head, block) + slots
+          head  = REMAT_HEAD_COPIES x tokens x vocabulary x 4
+          block = REMAT_BLOCK_COPIES x tokens x the largest sum, over a
+                  block's Dense kernels [K, N], of K + N
+          slots = REMAT_SLOT_COPIES x tokens x top_k x width, where a
+                  layer routes
+
+    The gradients are as large as the parameters, but they grow while
+    the kept values are given back, block by block: they count only
+    where ``resident + gradients + transient`` alone passes the ceiling
+    (no room then).  What the kernels' outputs take is not in the
+    formula: ``block_remat_policy`` charges them to the room as they
+    come, before any matmul's."""
+    memory = device_memory()
+    if memory is None or not params:
+        return None
+    limit, resident = memory
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    tokens = tokens_shape[0] * tokens_shape[1]
+    blocks = cfg.num_layers + cfg.mtp_modules
+    stream = blocks * tokens * cfg.emb_dim * cfg.hc_mult * itemsize
+    head_tokens = tokens // 2 if cfg.block_diffusion is not None else tokens
+    head = REMAT_HEAD_COPIES * head_tokens * cfg.vocab_size * 4
+    widest = max((
+        sum(sum(leaf.shape)
+            for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+            if leaf.ndim == 2 and path[-1].key == "kernel")
+        for name, tree in params.items()
+        if name.startswith("block") or name == "mtp"), default=0)
+    block = REMAT_BLOCK_COPIES * tokens * widest * itemsize
+    slots = (REMAT_SLOT_COPIES * tokens * cfg.routed_top_k * cfg.emb_dim
+             * itemsize)
+    transient = max(head, block) + slots
+    gradients = sum(leaf.size * leaf.dtype.itemsize
+                    for leaf in jax.tree.leaves(params))
+    ceiling = REMAT_CEILING * limit
+    if resident + gradients + transient > ceiling:
+        return 0
+    return max(0, int(ceiling - resident - stream - transient))
+
+
+def block_remat_policy(cfg: TransformerConfig, room=None):
+    """What a model's rematerialised blocks keep, as a function that
+    makes one block's policy (call it once a block).
+
+    Whatever ``cfg.remat_policy`` keeps; always what a Pallas kernel's
+    forward made (``scopes.KERNEL_OUTPUTS``, named in the kernels'
+    ``custom_vjp`` forward rules), so the recompute never runs a kernel
+    a second time; and, while ``room`` bytes (``remat_room``) last, the
+    outputs of the block's matmuls (a ``dot_general`` with no batch
+    dimensions: every Dense projection; not the expert layers' grouped
+    matmuls, not a kernel's inner products), as the operation wrote
+    them, so the recompute does not run those a second time either.
+    ``room=None`` (no device limit: the CPU) keeps no matmul output.
+
+    The room is spent in trace order.  A kernel's outputs are charged to
+    it as they come (they are kept whatever is left); a matmul's output
+    is kept if it fits in what is left after holding back, for every
+    block still to come, what the fullest block so far gave its
+    kernels' outputs, so that the first blocks do not spend what the
+    last ones' kernels need.  A policy is shown one operation at a time
+    and cannot weigh a deep contraction against a later one: trace
+    order is the order there is.
 
     The decision is taken when the step is differentiated, so it is
     counted there: gauges ``remat.kept_values{name}`` and
-    ``remat.kept_mib{name}`` of the metrics registry hold, by name, how
-    many kernel outputs the blocks of the last such trace kept and their
-    size, from the shapes the policy was shown.  One policy a model
-    trace: its tally starts at nothing."""
+    ``remat.kept_mib{name}`` of the metrics registry hold, by name
+    (a kernel output's, or ``matmul``), how many values the blocks of
+    the last such trace kept and their size, from the shapes the policy
+    was shown.  With a room, ``remat.room_mib{trace}``,
+    ``remat.eligible_mib{trace}`` and ``remat.matmul_kept_mib{trace}``
+    hold what the formula found, the matmul outputs that could be kept
+    and those that were, of the process's ``trace``-th such trace (1 is
+    the training step's: the first thing a runner differentiates).  One
+    policy maker a model trace: its tally starts at nothing."""
     from ..obs.registry import get_registry  # noqa: PLC0415
 
     policies = jax.checkpoint_policies
+    configured = getattr(policies, cfg.remat_policy)
     is_kernel_output = policies.save_only_these_names(*scopes.KERNEL_OUTPUTS)
+    blocks = cfg.num_layers + cfg.mtp_modules
     kept = {}
+    plan = {"left": room, "started": 0, "eligible": 0, "kernels": 0,
+            "trace": None}
 
-    def kernel_outputs(prim, *avals, **params):
-        if not is_kernel_output(prim, *avals, **params):
-            return False
-        name = params["name"]
-        values, nbytes = kept.get(name, (0, 0))
-        values += 1
-        nbytes += sum(a.size * a.dtype.itemsize for a in avals)
-        kept[name] = values, nbytes
+    def count(name, nbytes):
+        values, total = kept.get(name, (0, 0))
+        kept[name] = values, total = values + 1, total + nbytes
         registry = get_registry()
         registry.gauge("remat.kept_values", name=name).set(values)
-        registry.gauge("remat.kept_mib", name=name).set(nbytes / 2 ** 20)
-        return True
+        registry.gauge("remat.kept_mib", name=name).set(total / 2 ** 20)
 
-    return policies.save_from_both_policies(
-        getattr(policies, cfg.remat_policy), kernel_outputs)
+    def publish():
+        registry = get_registry()
+        if plan["trace"] is None:
+            traces = registry.counter("remat.traces")
+            traces.inc()
+            plan["trace"] = str(traces.value)
+            registry.gauge("remat.room_mib", trace=plan["trace"]).set(
+                room / 2 ** 20)
+        for gauge, nbytes in (
+                ("eligible_mib", plan["eligible"]),
+                ("matmul_kept_mib", kept.get(MATMUL_OUT, (0, 0))[1])):
+            registry.gauge(f"remat.{gauge}", trace=plan["trace"]).set(
+                nbytes / 2 ** 20)
+
+    def block_policy():
+        started, kernels = False, 0  # this block's outputs of kernels
+
+        def policy(prim, *avals, **params):
+            nonlocal started, kernels
+            if not started:
+                started = True
+                plan["started"] += 1
+            if is_kernel_output(prim, *avals, **params):
+                nbytes = sum(a.size * a.dtype.itemsize for a in avals)
+                count(params["name"], nbytes)
+                if room is not None:
+                    plan["left"] -= nbytes
+                    kernels += nbytes
+                    plan["kernels"] = max(plan["kernels"], kernels)
+                return True
+            keep = configured(prim, *avals, **params)
+            if (room is None or prim is not jax.lax.dot_general_p
+                    or any(params["dimension_numbers"][1])):
+                return keep
+            out, _ = prim.abstract_eval(*avals, **params)
+            nbytes = out.size * out.dtype.itemsize
+            plan["eligible"] += nbytes
+            held_back = (blocks - plan["started"]) * plan["kernels"]
+            if keep or nbytes <= plan["left"] - held_back:
+                keep = True
+                plan["left"] -= nbytes
+                count(MATMUL_OUT, nbytes)
+            publish()
+            return keep
+
+        return policy
+
+    if not room:
+        # nothing to apportion: one policy for every block, as before
+        # the rule, so that what the blocks share lowers once
+        shared = block_policy()
+        return lambda: shared
+    return block_policy
 
 
 # Named sizes (GPT-2 family geometry; head_dim 64, MXU-friendly widths).
@@ -1923,10 +2091,13 @@ GPT_CONFIGS = {
         logits_scaling=8.0, tie_embeddings=True,
         ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=1,
         ssm_conv=4, ssm_chunk=256,
-        # a Mamba block's matmul outputs are 0.47 GB at 8192 tokens:
-        # keep each block's input and, as every policy does, what its
-        # kernels made (the scan's y and states 128 MiB a block, the
-        # attention layer's o and lse 33 MiB)
+        # a Mamba block's matmul outputs are 0.47 GB at 8192 tokens, ten
+        # blocks' more than a chip holds beside the train state: keep
+        # each block's input, as every policy does what its kernels
+        # made (the scan's y and states 128 MiB a block, the attention
+        # layer's o and lse 33 MiB), and of the matmul outputs as many
+        # blocks' as the chip has room for (block_remat_policy: six of
+        # ten at 8192 tokens on a v5e)
         remat_policy="nothing_saveable",
     ),
     # https://huggingface.co/zai-org/GLM-4.7-Flash config.json

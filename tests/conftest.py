@@ -86,8 +86,8 @@ def _world():
 LONGEST_FIRST = [
     "test_multiprocess", "test_analysis", "test_smallthinker",
     "test_moe_tpu_compile", "test_step_tpu_compile", "test_models_gpt",
-    "test_testing_steps", "test_pipeline", "test_xing4_moe_mla",
-    "test_sdar_moe",
+    "test_remat_kernels", "test_testing_steps", "test_pipeline",
+    "test_xing4_moe_mla", "test_sdar_moe",
 ]
 
 

@@ -71,3 +71,31 @@ def build(mixer, remat=False, policy=POLICIES[0]):
                 + 0.3 * cross_entropy(mtp_logits, tokens[:, 2:]))
 
     return loss, variables["params"]
+
+
+def matmul_outputs(mixer):
+    """Bytes of the matmul outputs (a ``dot_general`` with no batch
+    dimensions) that the mixer's rematerialised blocks make, one entry a
+    block in trace order, each the sizes in the block's own order: what
+    ``block_remat_policy`` may keep while the chip has room.  Read off a
+    block's forward, one ``remat2`` equation each."""
+    loss, params = build(mixer, remat=True, policy="nothing_saveable")
+    found = []
+
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "remat2":
+                found.append([])
+                walk(eqn.params["jaxpr"], True)
+            elif (inside and eqn.primitive.name == "dot_general"
+                  and not any(eqn.params["dimension_numbers"][1])):
+                out = eqn.outvars[0].aval
+                found[-1].append(out.size * out.dtype.itemsize)
+            elif not eqn.primitive.name.startswith("custom_vjp"):
+                # (a kernel's own products: the policy is shown its
+                # forward rule, where they are inside the ``pallas_call``)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub, inside)
+
+    walk(jax.make_jaxpr(loss)(params).jaxpr, False)
+    return found
