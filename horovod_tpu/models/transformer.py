@@ -56,11 +56,15 @@ from ..parallel.moe import DEFAULT_GROUP_SIZE as MOE_DEFAULT_GROUP_SIZE
 # ``memory_layer`` names, ``conv`` a gated short convolution: a
 # causal depthwise filter of ``conv_taps`` taps between two gates, and
 # ``kda`` a gated delta rule with a decay per channel of the key
-# (ops/kda.py).
+# (ops/kda.py).  ``feed_forward`` is no mixer: a model that names it is
+# made of layers of ONE half each (``TransformerConfig.one_half``), one
+# norm and a mixer in a layer of a mixer type, one norm and the
+# feed-forward (dense or routed: ``ffn_type``) in a ``feed_forward`` one.
 ATTENTION_LAYER_TYPES = ("attention", "mla", "sliding_attention",
                          "full_attention", "cross_attention")
+FEED_FORWARD = "feed_forward"
 LAYER_TYPES = ATTENTION_LAYER_TYPES + ("mamba", "selective_scan", "gmu",
-                                       "conv", "kda")
+                                       "conv", "kda", FEED_FORWARD)
 
 
 @dataclass(frozen=True)
@@ -134,7 +138,9 @@ class TransformerConfig:
     norm: str = "layernorm"            # layernorm | rmsnorm
     norm_eps: float = 1e-6
     use_bias: bool = True              # biases of the dense projections
-    mlp: str = "gelu"                  # gelu | silu_gated: W_out(silu(g)*u)
+    # gelu | silu_gated: W_out(silu(g) * u) | relu2: W_out(relu(W_in x)^2),
+    # no gate matrix
+    mlp: str = "gelu"
     # softmax(attention_scale * q k^T); None = head_dim ** -0.5
     attention_scale: Optional[float] = None
     embedding_multiplier: float = 1.0  # x = multiplier * wte[tokens]
@@ -207,8 +213,14 @@ class TransformerConfig:
     # holds the load even.
     routed_scores: str = "sigmoid"
     # The gate's activation in a routed expert: act(x W_gate) * (x W_up)
-    # (parallel/moe.py:ACTIVATIONS).
+    # (parallel/moe.py:ACTIVATIONS).  routed_gated=False: an expert has
+    # no gate matrix and no product, W_down(act(x W_up)), and
+    # ``experts_fc1`` is [held, emb, width]; the shared expert and the
+    # dense layers (``mlp``) are then ungated too.
     routed_activation: str = "silu"
+    routed_gated: bool = True
+    # The shared expert's width (None = shared_experts * routed_width).
+    shared_width: Optional[int] = None
     # > 0: every expert layer sows its load-balance loss (E * sum_e f_e
     # P_e over the layer's own tokens, 1.0 at an even load; scope
     # "moe_balance") into the "losses" collection as "moe_balance" and
@@ -355,9 +367,10 @@ class TransformerConfig:
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(
                 f"norm must be 'layernorm' or 'rmsnorm', got {self.norm!r}")
-        if self.mlp not in ("gelu", "silu_gated"):
+        if self.mlp not in ("gelu", "silu_gated", "relu2"):
             raise ValueError(
-                f"mlp must be 'gelu' or 'silu_gated', got {self.mlp!r}")
+                f"mlp must be 'gelu', 'silu_gated' or 'relu2', got "
+                f"{self.mlp!r}")
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             unknown = set(self.layer_types) - set(LAYER_TYPES)
@@ -411,15 +424,32 @@ class TransformerConfig:
                         "an 'mla' layer rotates its rotary channels or "
                         "sees no positions: pos_embedding must be 'rope' "
                         "or 'none'")
+        # (a layer hands on from its mixer, so the makers and readers of
+        # a value stay mixer layers: _check_handed_on above; and
+        # dense_layers_first counts layers of any type)
+        if self.one_half and (self.mtp_modules or self.hc_mult > 1):
+            raise ValueError(
+                f"a model of one-half layers (layer_types names "
+                f"{FEED_FORWARD!r}) has no prediction module and one "
+                f"residual stream: a block of the last layer's kind "
+                f"would be a feed-forward alone (mtp_modules="
+                f"{self.mtp_modules}), and a half's hyper-connection "
+                f"alone is not implemented (hc_mult={self.hc_mult})")
+        if self.shared_width is not None and self.shared_width <= 0:
+            raise ValueError(
+                f"shared_width={self.shared_width} must be positive (None "
+                f"= shared_experts * routed_width)")
         if self.routed_experts > 0:
             if self.moe_experts > 0:
                 raise ValueError(
                     "routed_experts (dropless) and moe_experts (GShard "
                     "capacity) are two expert layers: set one")
-            if self.mlp != "silu_gated":
+            # the shared expert and the dense layers take the experts' form
+            form, says = (("silu_gated", "silu-gated") if self.routed_gated
+                          else ("relu2", "ungated"))
+            if self.mlp != form:
                 raise ValueError(
-                    "the routed experts are silu-gated: mlp must be "
-                    "'silu_gated'")
+                    f"the routed experts are {says}: mlp must be {form!r}")
             held = self.held_experts
             if not (0 < self.routed_top_k <= self.routed_experts
                     and self.routed_width > 0 and held > 0
@@ -566,9 +596,23 @@ class TransformerConfig:
 
     def ffn_type(self, i: int) -> str:
         """``"routed"`` where layer ``i``'s feed-forward is the dropless
-        expert layer, else ``"dense"``."""
+        expert layer, else ``"dense"``; ``"none"`` for a mixer layer of a
+        model of one-half layers."""
+        if self.one_half and self.layer_types[i] != FEED_FORWARD:
+            return "none"
         return ("routed" if self.routed_experts > 0
                 and i >= self.dense_layers_first else "dense")
+
+    @property
+    def one_half(self) -> bool:
+        """Is every layer one half (a mixer or a feed-forward, with one
+        norm) and not a mixer and a feed-forward?"""
+        return FEED_FORWARD in (self.layer_types or ())
+
+    @property
+    def shared_ffn_width(self) -> int:
+        return (self.shared_width if self.shared_width is not None
+                else self.shared_experts * self.routed_width)
 
     @property
     def held_experts(self) -> int:
@@ -814,11 +858,14 @@ def mamba_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
     """The Mamba-2 mixer on the normed stream ``h`` [b, s, emb]: one
     projection to the gate ``z``, the conv's input ``xBC`` and ``dt``;
     a causal depthwise conv and silu over ``xBC``; the state-space scan
-    (``ops/ssd.py``); the gate, THEN the RMS norm over all inner
-    channels (one group); the output projection.  ``in_proj`` and
-    ``out_proj`` are callables like ``block_math``'s, the rest raw
-    arrays.  ``dt``, ``A`` and everything the scan carries are float32.
-    Returns the residual delta."""
+    (``ops/ssd.py``); the gate, THEN the RMS norm over each of the
+    ``ssm_groups`` groups' ``ssm_inner / ssm_groups`` channels, every
+    group with its own mean square and all under the one learned scale
+    (one group: over all inner channels), both float32 under the scope
+    ``ssm_norm``; the output projection.  ``in_proj`` and ``out_proj``
+    are callables like ``block_math``'s, the rest raw arrays.  ``dt``,
+    ``A`` and everything the scan carries are float32.  Returns the
+    residual delta."""
     from ..ops.ssd import ssd_scan  # noqa: PLC0415
 
     b, s, _ = h.shape
@@ -837,11 +884,16 @@ def mamba_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
     dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
     y = ssd_scan(x, dt, -jnp.exp(a_log.astype(jnp.float32)), B, C, d_skip,
                  cfg.ssm_chunk)
-    gated = y.reshape(b, s, inner).astype(jnp.float32) \
-        * jax.nn.silu(z.astype(jnp.float32))
-    normed = gated * jax.lax.rsqrt(
-        jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + cfg.norm_eps)
-    return out_proj(normed * norm_scale)
+    with jax.named_scope(scopes.SSM_NORM):
+        gated = y.reshape(b, s, inner).astype(jnp.float32) \
+            * jax.nn.silu(z.astype(jnp.float32))
+        if cfg.ssm_groups > 1:  # one group: the array as it stands
+            gated = gated.reshape(b, s, cfg.ssm_groups, -1)
+        normed = gated * jax.lax.rsqrt(
+            jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
+            + cfg.norm_eps)
+        normed = normed.reshape(b, s, inner) * norm_scale
+    return out_proj(normed)
 
 
 def selective_scan_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
@@ -1102,8 +1154,8 @@ MIXER_SCOPES = {"mamba": scopes.SSM, "selective_scan": scopes.SSM,
                 "kda": scopes.KDA}
 
 
-def block_math(cfg: TransformerConfig, x, *, ln1, mixer, ln2, mlp,
-               layer_type: Optional[str] = None,
+def block_math(cfg: TransformerConfig, x, *, ln1, mixer=None, ln2=None,
+               mlp=None, layer_type: Optional[str] = None,
                post_attn_norm=None, post_mlp_norm=None,
                hand_on: Optional[str] = None, route=None,
                connections=None):
@@ -1121,6 +1173,12 @@ def block_math(cfg: TransformerConfig, x, *, ln1, mixer, ln2, mlp,
     output back.  The branch, its norm and its scope are the same in
     both; the hyper-connection's three stages trace under ``hc_coeff``,
     ``hc_read`` and ``hc_write``, outside ``attn`` and ``mlp``.
+
+    A layer of one half (``cfg.one_half``) hands in the half it has:
+    ``mixer`` without ``ln2`` and ``mlp``, or ``mlp`` without ``mixer``
+    and ``ln2``; ``ln1`` is then the one norm, and nothing of the absent
+    half is called, normed or traced.  Everything below holds for the
+    half that is there.
 
     ``mixer`` is a callable of the normed stream (``[b, s, emb]``
     whatever ``hc_mult`` is) that returns the residual DELTA:
@@ -1189,11 +1247,14 @@ def block_math(cfg: TransformerConfig, x, *, ln1, mixer, ln2, mlp,
     # tree stays ``block<i>/{ln1,qkv,proj,ln2,fc1,fc2}``.
     for_mixer, for_mlp = connections or (None, None)
     decided = () if route is None else (route(x),)
-    x, handed = half(x, MIXER_SCOPES.get(layer_type, scopes.ATTN), ln1,
-                     mixer, post_attn_norm, for_mixer,
-                     hands=hand_on is not None)
-    x, _ = half(x, scopes.MLP, ln2, lambda h: mlp(h, *decided),
-                post_mlp_norm, for_mlp)
+    handed = None
+    if mixer is not None:
+        x, handed = half(x, MIXER_SCOPES.get(layer_type, scopes.ATTN), ln1,
+                         mixer, post_attn_norm, for_mixer,
+                         hands=hand_on is not None)
+    if mlp is not None:
+        x, _ = half(x, scopes.MLP, ln1 if mixer is None else ln2,
+                    lambda h: mlp(h, *decided), post_mlp_norm, for_mlp)
     return x if hand_on is None else (x, handed)
 
 
@@ -1280,7 +1341,9 @@ class Block(nn.Module):
     returns beside ``x`` for later layers (``cfg.hands_on``);
     ``layer_index`` is the layer's index in the whole model.  A
     ``cross_attention`` block is called with ``shared_kv``, a ``gmu``
-    block with ``memory``.
+    block with ``memory``.  In a model of one-half layers a block holds
+    ``ln1`` and one half's parameters: the mixer's (``ffn="none"``) or,
+    for ``layer_type="feed_forward"``, the feed-forward's.
     """
 
     cfg: TransformerConfig
@@ -1310,6 +1373,8 @@ class Block(nn.Module):
             if cfg.mlp == "silu_gated":
                 gate_up = layer(2 * wide, fc1)(h)
                 m = jax.nn.silu(gate_up[..., :wide]) * gate_up[..., wide:]
+            elif cfg.mlp == "relu2":
+                m = jnp.square(jax.nn.relu(layer(wide, fc1)(h)))
             else:
                 m = nn.gelu(layer(wide, fc1)(h))
             return layer(cfg.emb_dim, fc2)(act_store(m, cfg))
@@ -1356,7 +1421,8 @@ class Block(nn.Module):
             # rows every [row_bound, .] buffer carries, and the slots the
             # layer runs on in a step that passes the bound
             for name, value in (
-                    ("gmm_tile_fill", ffn_tile_fill(d, ff, cfg.dtype)),
+                    ("gmm_tile_fill", ffn_tile_fill(d, ff, cfg.dtype,
+                                                    cfg.routed_gated)),
                     ("row_bound", bound),
                     ("slots", b * s * cfg.routed_top_k)):
                 get_registry().gauge(
@@ -1374,7 +1440,8 @@ class Block(nn.Module):
                 routing = decide(x2)
             y, routing = apply_routing(
                 routing, x2,
-                self.param("experts_fc1", stacked, (held, d, 2 * ff),
+                self.param("experts_fc1", stacked,
+                           (held, d, 2 * ff if cfg.routed_gated else ff),
                            jnp.float32),
                 self.param("experts_fc2", stacked, (held, ff, d),
                            jnp.float32),
@@ -1407,7 +1474,7 @@ class Block(nn.Module):
             y = y.reshape(b, s, d)
             if cfg.shared_experts > 0:
                 with jax.named_scope(scopes.MOE_SHARED):
-                    y = y + feed_forward(h, cfg.shared_experts * ff,
+                    y = y + feed_forward(h, cfg.shared_ffn_width,
                                          "shared_fc1", "shared_fc2")
             return y
 
@@ -1434,7 +1501,9 @@ class Block(nn.Module):
                 return y.astype(cfg.dtype)
             return feed_forward(h, width, "fc1", "fc2")
 
-        if self.layer_type == "mamba":
+        if self.layer_type == FEED_FORWARD:
+            mixer = None
+        elif self.layer_type == "mamba":
             inner, heads = cfg.ssm_inner, cfg.ssm_heads
             conv_dim = inner + 2 * cfg.ssm_groups * cfg.ssm_state
             zeros, ones = nn.initializers.zeros, nn.initializers.ones
@@ -1617,10 +1686,13 @@ class Block(nn.Module):
             block["post_mlp_norm"] = _norm(cfg, "post_mlp_norm")
         if self.ffn == "routed" and cfg.routed_router_input == "layer_input":
             block["route"] = lambda x: decide(x.reshape(-1, x.shape[-1]))
+        if self.ffn != "none":
+            block["mlp"] = mlp
+            if mixer is not None:
+                block["ln2"] = _norm(cfg, "ln2")
         return block_math(
             cfg, x, ln1=_norm(cfg, "ln1"), mixer=mixer,
-            ln2=_norm(cfg, "ln2"), mlp=mlp, layer_type=self.layer_type,
-            hand_on=self.hand_on, **block,
+            layer_type=self.layer_type, hand_on=self.hand_on, **block,
         )
 
 
@@ -1791,6 +1863,17 @@ class GPT(nn.Module):
                     convs * short_conv_filter_bytes(
                         tokens.shape[0], s, cfg.emb_dim,
                         jnp.dtype(cfg.dtype).itemsize))
+            if "mamba" in cfg.layer_types:
+                from ..ops.ssd import kept_mib as ssd_kept_mib  # noqa: PLC0415
+
+                # the scan's groups and chunk, and what one layer's scan
+                # keeps for its backward
+                get_registry().gauge("ssd.groups").set(cfg.ssm_groups)
+                get_registry().gauge("ssd.chunk").set(cfg.ssm_chunk)
+                get_registry().gauge("ssd.kept_mib").set(ssd_kept_mib(
+                    tokens.shape[0], s, cfg.ssm_heads, cfg.ssm_head_dim,
+                    cfg.ssm_state, cfg.ssm_chunk,
+                    jnp.dtype(cfg.dtype).itemsize))
             kdas = cfg.layer_types.count("kda")
             if kdas:
                 from ..ops import kda, kda_prep  # noqa: PLC0415
@@ -2333,6 +2416,38 @@ GPT_CONFIGS = {
         # 8192 x 12288 queries, keys and values a block and a stream of
         # 8192 x 14336: keep each block's input and, as every policy
         # does, what its kernels made (o 64 MiB and lse 1 MiB a block)
+        remat_policy="nothing_saveable",
+    ),
+    # https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16
+    # config.json (model_type nemotron_h; arXiv:2512.20848): 52 layers of
+    # ONE half each, ``hybrid_override_pattern`` naming them: ``M`` a
+    # Mamba-2 mixer (64 heads of 64, state 128, B and C in 8 groups, the
+    # gated norm a group, chunk 128), ``*`` grouped-query attention (32
+    # query heads over 2 key/value heads of 128, nothing rotated), ``E``
+    # 128 routed experts of 1856 WITHOUT a gate matrix, W_down
+    # relu(W_up x)^2 (sigmoid scores, 6 a token, a selection bias,
+    # weights normalised and scaled 2.5, nothing dropped), beside a
+    # shared expert of the same form, 3712 wide; one RMSNorm a layer, an
+    # untied head.
+    # Training path only (require_gpt2_block says who refuses it).
+    "nvidia-nemotron-3-nano-30b-a3b-bf16": TransformerConfig(
+        vocab_size=131072, num_layers=52, emb_dim=2688, max_len=262144,
+        layer_types=tuple(
+            {"M": "mamba", "*": "full_attention", "E": FEED_FORWARD}[kind]
+            for kind in ("MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*"
+                         "EMEMEMEME")),
+        num_heads=32, num_kv_heads=2, head_size=128, pos_embedding="none",
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=8,
+        ssm_conv=4, ssm_chunk=128,
+        mlp_width=1856, mlp="relu2", norm="rmsnorm", norm_eps=1e-5,
+        use_bias=False, tie_embeddings=False,
+        routed_experts=128, routed_top_k=6, routed_width=1856,
+        routed_scaling=2.5, routed_activation="relu2", routed_gated=False,
+        shared_experts=1, shared_width=3712,
+        # 16384 x 10304 of in_proj a Mamba layer: keep each layer's
+        # input and, as every policy does, what its kernels made (the
+        # scan's y 128 MiB and states 256 MiB, the attention layer's o
+        # 128 MiB and lse 2 MiB)
         remat_policy="nothing_saveable",
     ),
 }

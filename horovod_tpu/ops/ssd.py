@@ -142,6 +142,14 @@ def _check_tiles(heads, p, n, chunk, hb, itemsize):
             f"({_VMEM_LIMIT // 2 ** 20} MiB) the call states")
 
 
+def kept_mib(batch: int, seq: int, heads: int, p: int, n: int, chunk: int,
+             itemsize: int) -> float:
+    """MiB one call keeps for its backward: ``y`` in ``x``'s dtype and a
+    float32 state of ``heads x p x n`` at every chunk's start."""
+    return batch * heads * p * (seq * itemsize
+                                + seq // chunk * n * 4) / 2 ** 20
+
+
 def ssd_scan(x, dt, A, B, C, D, chunk: int):
     """``x`` [batch, seq, heads, head_dim]; ``dt`` [batch, seq, heads]
     (positive: after its softplus); ``A`` [heads] (negative); ``B``, ``C``

@@ -589,13 +589,15 @@ def gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int,
             + 4 * (tk if weights_out else tm) * tn)
 
 
-def ffn_calls(d: int, ff: int):
-    """The six grouped matmuls of one gated expert feed-forward and its
-    gradients as ``(k, n, weights_out)``: ``hidden -> [gate | up]`` and
-    ``ff -> hidden`` forward, the same two transposed for the rows'
-    gradients, and ``tgmm`` for each matrix's."""
-    return ((d, 2 * ff, False), (ff, d, False), (d, ff, False),
-            (2 * ff, d, False), (ff, d, True), (d, 2 * ff, True))
+def ffn_calls(d: int, ff: int, gated: bool = True):
+    """The six grouped matmuls of one expert feed-forward and its
+    gradients as ``(k, n, weights_out)``: ``hidden -> [gate | up]`` (an
+    ungated expert's: ``hidden -> up``, ``ff`` wide) and ``ff -> hidden``
+    forward, the same two transposed for the rows' gradients, and
+    ``tgmm`` for each matrix's."""
+    first = 2 * ff if gated else ff
+    return ((d, first, False), (ff, d, False), (d, ff, False),
+            (first, d, False), (ff, d, True), (d, first, True))
 
 
 def tile_fill(calls, tiles) -> float:
@@ -609,10 +611,10 @@ def tile_fill(calls, tiles) -> float:
         up(k, tk) * up(n, tn) for (k, n, _), (_, tk, tn) in zip(calls, tiles))
 
 
-def ffn_tile_fill(d: int, ff: int, dtype) -> float:
+def ffn_tile_fill(d: int, ff: int, dtype, gated: bool = True) -> float:
     """``tile_fill`` of an expert layer of hidden ``d`` and width ``ff``
     under the tiles its calls get (gauge ``moe.gmm_tile_fill``)."""
-    calls = ffn_calls(d, ff)
+    calls = ffn_calls(d, ff, gated)
     size = jnp.dtype(dtype).itemsize
     return tile_fill(calls, [gmm_tiles(GMM_ROW_TILE, k, n, size, out)
                              for k, n, out in calls])
@@ -639,16 +641,20 @@ def _gmm_bwd(lhs, rhs, group_sizes, interpret: bool, grad):
                  num_actual_groups=rhs.shape[0]))
 
 
-# The gate's activation in a gated expert: act(x W_gate) * (x W_up).
-ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# The activation of an expert: act(x W_gate) * (x W_up) in a gated one,
+# act(x W_up) in an ungated one (``_gate``).
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+               "relu2": lambda h: jnp.square(jax.nn.relu(h))}
 
 
 def grouped_ffn(xs, gate_up, down, group_sizes, *, dtype=jnp.bfloat16,
                 interpret: bool = False, activation: str = "silu"):
     """The gated feed-forward ``W_down(act(x W_gate) * (x W_up))`` of
     every held expert on its own rows (``activation``, a name in
-    ``ACTIVATIONS``: silu, or relu): ``xs [rows, d]`` in expert order,
-    ``gate_up [held, d, 2 ff]`` (gate then up), ``down [held, ff, d]``,
+    ``ACTIVATIONS``: silu, relu, or relu2, its square): ``xs [rows, d]``
+    in expert order, ``gate_up [held, d, 2 ff]`` (gate then up), or
+    ``[held, d, ff]`` for experts without a gate matrix, ``W_down(act(x
+    W_up))``: the first matrix's width says which; ``down [held, ff, d]``,
     ``group_sizes [held + 1]`` that add up to ``rows``: the held experts'
     rows, then what is left of ``rows``, which no expert here computes
     and which comes out zero (``_gmm``).  ``rows`` is what the caller
@@ -677,9 +683,12 @@ def _grouped_ffn_bwd(interpret, activation, kept, d_ys):
 _grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)
 
 
-def _gate(activation: str, h):
-    """``act(gate) * up`` of ``h = [gate | up]``."""
-    ff = h.shape[1] // 2
+def _gate(activation: str, ff: int, h):
+    """``act(gate) * up`` of ``h = [gate | up]``, each ``ff`` wide; of an
+    ``h`` that is ``ff`` wide (an ungated expert's ``x W_up``) ``act(h)``
+    alone, in float32."""
+    if h.shape[1] == ff:
+        return ACTIVATIONS[activation](h.astype(jnp.float32)).astype(h.dtype)
     return (ACTIVATIONS[activation](h[:, :ff]) * h[:, ff:]).astype(h.dtype)
 
 
@@ -689,7 +698,7 @@ def _ffn(xs, gate_up, down, sizes, interpret: bool, activation: str):
     with jax.named_scope(scopes.MOE_EXPERTS):
         h = _gmm(xs, gate_up, sizes, interpret)
         with jax.named_scope(scopes.MOE_GATE):
-            act = _gate(activation, h)
+            act = _gate(activation, down.shape[1], h)
         return _gmm(act, down, sizes, interpret), h
 
 
@@ -699,7 +708,8 @@ def _ffn_bwd(xs, h, gate_up, down, sizes, interpret: bool, activation: str,
     ``h``: no grouped matmul of the forward pass runs again."""
     with jax.named_scope(scopes.MOE_EXPERTS):
         with jax.named_scope(scopes.MOE_GATE):
-            act, gate_bwd = jax.vjp(partial(_gate, activation), h)
+            act, gate_bwd = jax.vjp(
+                partial(_gate, activation, down.shape[1]), h)
         d_act, d_down = _gmm_bwd(act, down, sizes, interpret, d_ys)
         with jax.named_scope(scopes.MOE_GATE):
             d_h, = gate_bwd(d_act)
