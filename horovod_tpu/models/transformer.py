@@ -853,46 +853,78 @@ def causal_depthwise_conv(x, kernel, bias=None):
     return out if bias is None else out + bias
 
 
+def ssm_prep_chain(fused, conv_kernel, conv_bias, *, inner, heads, groups):
+    """The float32 chain in front of the Mamba-2 scan as XLA compiles
+    it: what ``ops/ssm_chain.py:ssm_prep`` computes (same arguments,
+    same results), for the shapes the kernels do not take
+    (``ssm_chain.plan``), and what the tests hold them against."""
+    b, s, _ = fused.shape
+    bc = (conv_kernel.shape[1] - inner) // 2
+    xbc = fused[..., inner:2 * inner + 2 * bc]
+    xbc = jax.nn.silu(causal_depthwise_conv(
+        xbc, conv_kernel, conv_bias)).astype(fused.dtype)
+    x = xbc[..., :inner].reshape(b, s, heads, inner // heads)
+    B = xbc[..., inner:inner + bc].reshape(b, s, groups, bc // groups)
+    C = xbc[..., inner + bc:].reshape(b, s, groups, bc // groups)
+    return x, B, C
+
+
+def ssm_norm_chain(y, fused, norm_scale, *, groups, eps):
+    """The gate and the grouped RMS norm behind the Mamba-2 scan as XLA
+    compiles them: what ``ops/ssm_chain.py:ssm_norm`` computes (same
+    arguments, same results), for the shapes the kernels do not take,
+    and what the tests hold them against."""
+    b, s, _ = fused.shape
+    inner = norm_scale.shape[0]
+    gated = y.reshape(b, s, inner).astype(jnp.float32) \
+        * jax.nn.silu(fused[..., :inner].astype(jnp.float32))
+    if groups > 1:  # one group: the array as it stands
+        gated = gated.reshape(b, s, groups, -1)
+    normed = gated * jax.lax.rsqrt(
+        jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + eps)
+    normed = normed.reshape(b, s, inner) * norm_scale
+    # the rounding out_proj's Dense does to a float32 input
+    return normed.astype(fused.dtype)
+
+
 def mamba_mixer(cfg: TransformerConfig, h, *, in_proj, conv_kernel,
                 conv_bias, dt_bias, a_log, d_skip, norm_scale, out_proj):
     """The Mamba-2 mixer on the normed stream ``h`` [b, s, emb]: one
     projection to the gate ``z``, the conv's input ``xBC`` and ``dt``;
-    a causal depthwise conv and silu over ``xBC``; the state-space scan
-    (``ops/ssd.py``); the gate, THEN the RMS norm over each of the
-    ``ssm_groups`` groups' ``ssm_inner / ssm_groups`` channels, every
-    group with its own mean square and all under the one learned scale
-    (one group: over all inner channels), both float32 under the scope
-    ``ssm_norm``; the output projection.  ``in_proj`` and ``out_proj``
-    are callables like ``block_math``'s, the rest raw arrays.  ``dt``,
-    ``A`` and everything the scan carries are float32.  Returns the
-    residual delta."""
+    a causal depthwise conv and silu over ``xBC`` under the scope
+    ``ssm_prep``; the state-space scan (``ops/ssd.py``); the gate, THEN
+    the RMS norm over each of the ``ssm_groups`` groups' ``ssm_inner /
+    ssm_groups`` channels, every group with its own mean square and all
+    under the one learned scale (one group: over all inner channels),
+    under the scope ``ssm_norm``; the output projection.  Both chains
+    are float32 and rounded once to the compute dtype: the kernel pairs
+    of ``ops/ssm_chain.py`` where its ``plan`` takes the shape, else
+    :func:`ssm_prep_chain` and :func:`ssm_norm_chain`.  ``in_proj`` and
+    ``out_proj`` are callables like ``block_math``'s, the rest raw
+    arrays.  ``dt``, ``A`` and everything the scan carries are float32.
+    Returns the residual delta."""
+    from ..ops import ssm_chain  # noqa: PLC0415
     from ..ops.ssd import ssd_scan  # noqa: PLC0415
 
-    b, s, _ = h.shape
-    inner, heads = cfg.ssm_inner, cfg.ssm_heads
-    bc = cfg.ssm_groups * cfg.ssm_state
+    s = h.shape[1]
+    inner, groups = cfg.ssm_inner, cfg.ssm_groups
     fused = in_proj(h)
-    z = fused[..., :inner]
-    xbc = fused[..., inner:2 * inner + 2 * bc]
-    dt = fused[..., 2 * inner + 2 * bc:]
-    xbc = jax.nn.silu(causal_depthwise_conv(
-        xbc, conv_kernel, conv_bias)).astype(fused.dtype)
-    x = xbc[..., :inner].reshape(b, s, heads, cfg.ssm_head_dim)
-    B = xbc[..., inner:inner + bc].reshape(b, s, cfg.ssm_groups,
-                                           cfg.ssm_state)
-    C = xbc[..., inner + bc:].reshape(b, s, cfg.ssm_groups, cfg.ssm_state)
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+    tiles = ssm_chain.plan(s, inner, groups, cfg.ssm_state,
+                           conv_kernel.shape[0])
+    prep, norm = (ssm_prep_chain, ssm_norm_chain) if tiles is None else (
+        functools.partial(ssm_chain.ssm_prep, tiles=tiles),
+        functools.partial(ssm_chain.ssm_norm, tiles=tiles))
+    with jax.named_scope(scopes.SSM_PREP):
+        x, B, C = prep(fused, conv_kernel, conv_bias, inner=inner,
+                       heads=cfg.ssm_heads, groups=groups)
+    # a head's one number a token, a few MiB a layer: XLA's
+    dt = jax.nn.softplus(
+        fused[..., 2 * inner + 2 * groups * cfg.ssm_state:].astype(
+            jnp.float32) + dt_bias)
     y = ssd_scan(x, dt, -jnp.exp(a_log.astype(jnp.float32)), B, C, d_skip,
                  cfg.ssm_chunk)
     with jax.named_scope(scopes.SSM_NORM):
-        gated = y.reshape(b, s, inner).astype(jnp.float32) \
-            * jax.nn.silu(z.astype(jnp.float32))
-        if cfg.ssm_groups > 1:  # one group: the array as it stands
-            gated = gated.reshape(b, s, cfg.ssm_groups, -1)
-        normed = gated * jax.lax.rsqrt(
-            jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
-            + cfg.norm_eps)
-        normed = normed.reshape(b, s, inner) * norm_scale
+        normed = norm(y, fused, norm_scale, groups=groups, eps=cfg.norm_eps)
     return out_proj(normed)
 
 
@@ -1864,8 +1896,18 @@ class GPT(nn.Module):
                         tokens.shape[0], s, cfg.emb_dim,
                         jnp.dtype(cfg.dtype).itemsize))
             if "mamba" in cfg.layer_types:
+                from ..ops import ssm_chain  # noqa: PLC0415
                 from ..ops.ssd import kept_mib as ssd_kept_mib  # noqa: PLC0415
 
+                # the Mamba-2 layers of the program and those whose two
+                # float32 chains take the kernels (all or none: they
+                # share a shape)
+                mambas = cfg.layer_types.count("mamba")
+                get_registry().gauge("ssm_chain.layers").set(mambas)
+                get_registry().gauge("ssm_chain.kernel_layers").set(
+                    0 if ssm_chain.plan(s, cfg.ssm_inner, cfg.ssm_groups,
+                                        cfg.ssm_state, cfg.ssm_conv) is None
+                    else mambas)
                 # the scan's groups and chunk, and what one layer's scan
                 # keeps for its backward
                 get_registry().gauge("ssd.groups").set(cfg.ssm_groups)

@@ -22,10 +22,21 @@ SSM = "ssm"                            # a block's state-space mixer half,
                                        # Mamba-2's or Mamba-1's: in_proj,
                                        # conv, scan, gate, out_proj
 SSD_SCAN = "ssd_scan"                  # Mamba-2's scan alone, inside ssm
+SSM_PREP = "ssm_prep"                  # inside ssm, Mamba-2's: the filter,
+                                       # its bias and silu over xBC and the
+                                       # split into x, B and C, a float32
+                                       # elementwise chain between in_proj
+                                       # and the scan (dt's softplus stays
+                                       # outside it)
 SSM_NORM = "ssm_norm"                  # inside ssm, Mamba-2's: the gate
                                        # y * silu(z) and the RMS norm by
                                        # group, a float32 elementwise chain
-                                       # between the scan and out_proj
+                                       # between the scan and out_proj.
+                                       # Both chains are the kernel pairs of
+                                       # ops/ssm_chain.py where the shape
+                                       # allows (gauge
+                                       # ssm_chain.kernel_layers), else
+                                       # XLA's fusions
 SELECTIVE_SCAN = "selective_scan"      # Mamba-1's scan alone, inside ssm
 GMU = "gmu"                            # a block's gated-memory-unit half:
                                        # in_proj, the product with the scan
@@ -155,4 +166,5 @@ SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ,
           MTP, EMBED, HEAD, STEM, KV_GATHER, KV_SCATTER, SAMPLE,
           MOE_LOGITS, MOE_TOPK, MOE_SORT, MOE_UNSORT, MOE_ROWS_IN,
           MOE_ROWS_OUT, MOE_CAST, MOE_GATE, ATTN_BLOCK_DIFFUSION,
-          DIFFUSION_NOISE, HC_COEFF, HC_READ, HC_WRITE, SSM_NORM)
+          DIFFUSION_NOISE, HC_COEFF, HC_READ, HC_WRITE, SSM_NORM,
+          SSM_PREP)

@@ -600,3 +600,47 @@ def test_differential_flash_call_compiles_for_v5e(one_chip, window):
     text = jax.jit(backward).lower(q, k, v).compile().as_text()
     assert "flash_fwd" in text and "flash_bwd_dkdv" in text
     assert "flash_bwd_dq" not in text
+
+
+@pytest.mark.parametrize("seq,groups", [(8192, 1), (16384, 8)])
+def test_ssm_chain_kernels_compile_for_v5e(one_chip, compiled_kernels, seq,
+                                           groups):
+    """The Mamba-2 chains' two kernel pairs (``ops/ssm_chain.py``) at
+    ``granite4hm_train_s8192``'s and ``nemotron3n_train_s16384``'s
+    shapes (inner 4096, a state of 128, one group and eight), forward
+    and backward through the ``custom_vjp``s, inside the VMEM their
+    calls state: all four calls in the compiled program and no
+    ``while``."""
+    from horovod_tpu.ops import ssm_chain
+
+    inner, heads, state = 4096, 64, 128
+    width = inner + 2 * groups * state
+    tiles = ssm_chain.plan(seq, inner, groups, state, 4)
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def backward(fused, conv_kernel, conv_bias, y, scale):
+        def loss(*a):
+            x, B, C = ssm_chain.ssm_prep(*a[:3], inner=inner, heads=heads,
+                                         groups=groups, tiles=tiles)
+            normed = ssm_chain.ssm_norm(a[3], a[0], a[4], groups=groups,
+                                        eps=1e-5, tiles=tiles)
+            return sum(t.astype(jnp.float32).sum()
+                       for t in (x, B, C, normed))
+
+        # the value too: the backward reads the inputs alone, and a
+        # gradient by itself would leave the forward calls dead
+        return jax.value_and_grad(loss, argnums=tuple(range(5)))(
+            fused, conv_kernel, conv_bias, y, scale)
+
+    compiled = jax.jit(backward).lower(
+        shaped(1, seq, inner + width + heads),
+        shaped(4, width, dtype=jnp.float32),
+        shaped(width, dtype=jnp.float32), shaped(1, seq, heads, 64),
+        shaped(inner, dtype=jnp.float32)).compile()
+    text = compiled.as_text()
+    assert " while(" not in text
+    for name in ("ssm_prep_fwd", "ssm_prep_bwd", "ssm_norm_fwd",
+                 "ssm_norm_bwd"):
+        assert f"/{name}/" in text, name
