@@ -691,7 +691,10 @@ def _attend(cfg: TransformerConfig, q, k, v, positions,
     under the scope ``attn_window``, so a device trace tells the banded
     kernels from the full ones, the call of a layer that reads another
     layer's keys and values under ``attn_cross``, and a call under the
-    block-diffusion mask under ``attn_block_diffusion``."""
+    block-diffusion mask under ``attn_block_diffusion``.  ``q``, ``k``
+    and ``v`` are ``[b, s, heads, hd]``, or for the flash schedule alone
+    head-major ``[b heads, s, hd]`` as ``ops/attn_prep.py`` writes
+    them; the result is ``[b, s, heads, value dim]`` either way."""
     window = cfg.window_of(layer_type)
     with (jax.named_scope(scopes.ATTN_BLOCK_DIFFUSION)
           if cfg.block_diffusion is not None
@@ -746,7 +749,7 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
     if cfg.attention_impl == "flash":
         from ..obs.registry import get_registry  # noqa: PLC0415
         from ..ops.flash_attention import (  # noqa: PLC0415
-            flash_attention, flash_plan,
+            flash_attention, flash_attention_folded, flash_plan, unfolded,
         )
 
         # counted while the step is traced, like remat.kept_values: what
@@ -754,6 +757,16 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
         call = dict(causal=cfg.block_diffusion is None,
                     block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
                     window=window, block_diffusion=cfg.block_diffusion)
+        attended = functools.partial(flash_attention, q, k, v)
+        if q.ndim == 3:
+            # head-major already ([b heads, s, hd]: ops/attn_prep.py
+            # wrote them); the plan and the gauges read the shapes they
+            # stand for
+            attended = functools.partial(
+                flash_attention_folded, q, k, v, heads=cfg.num_heads,
+                kv_heads=cfg.kv_heads)
+            q, k, v = (unfolded(q, cfg.num_heads), unfolded(k, cfg.kv_heads),
+                       unfolded(v, cfg.kv_heads))
         plan = flash_plan(q, k, v, **call)
         label = layer_type or "attention"
         gauge = lambda name: get_registry().gauge(name, layer_type=label)
@@ -774,7 +787,7 @@ def _attend_schedule(cfg: TransformerConfig, q, k, v, positions, layer_type,
                 q.shape[1] // 2, cfg.block_diffusion))
             gauge("bd.live_tile_pairs").set(
                 plan.tiles_live * plan.block_q * plan.block_k)
-        return flash_attention(q, k, v, scale=cfg.attention_scale, **call)
+        return attended(scale=cfg.attention_scale, **call)
     if window is not None and cfg.attention_impl != "reference":
         raise ValueError(
             "attention_window is flash-only on a chip (the reference "
@@ -1102,6 +1115,60 @@ def mla_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *, q_b,
     return proj(act_store(att.reshape(b, s, nh * vd), cfg))
 
 
+def attn_prep_chain(fused, q_norm, k_norm, rope_tabs, *, heads, kv_heads,
+                    head_dim, shared_kv=None):
+    """What stands between the fused q/k/v matmul and the attention call
+    as XLA compiles it: ``fused`` [b, s, (heads + 2 kv_heads) head_dim]
+    split into ``q`` [b, s, heads, head_dim] and ``k``, ``v`` [b, s,
+    kv_heads, head_dim] (``shared_kv=(k, v)``: ``fused`` holds the
+    queries alone), ``q_norm`` and ``k_norm`` over each head's channels
+    where there are any, rounded to ``fused``'s dtype, then the rotation
+    by ``rope_tabs`` where the layer sees positions.  What
+    ``ops/attn_prep.py``'s kernels compute (head-major there), for the
+    calls they do not take (``attn_prep.plan``), and what the tests hold
+    them against."""
+    b, s, _ = fused.shape
+    q_dim, kv_dim = heads * head_dim, kv_heads * head_dim
+    q = fused[..., :q_dim].reshape(b, s, heads, head_dim)
+    if shared_kv is not None:
+        k, v = shared_kv
+    else:
+        k = fused[..., q_dim:q_dim + kv_dim].reshape(b, s, kv_heads, head_dim)
+        v = fused[..., q_dim + kv_dim:].reshape(b, s, kv_heads, head_dim)
+    if q_norm is not None:
+        q = q_norm(q).astype(fused.dtype)
+    if k_norm is not None:
+        k = k_norm(k).astype(fused.dtype)
+    if rope_tabs is not None:
+        from ..ops.rope import apply_rope_tables  # noqa: PLC0415
+
+        q = apply_rope_tables(q, *rope_tabs)
+        k = apply_rope_tables(k, *rope_tabs)
+    return q, k, v
+
+
+def _attn_prep_plan(cfg: TransformerConfig, seq: int, heads: int,
+                    kv_heads: int, *, norm, rotates: bool, plain: bool):
+    """What ``ops/attn_prep.py:plan`` says of an attention layer of
+    ``cfg`` at ``seq`` rows: the kernels' tiles, or ``None`` for the
+    chain.  One place for :func:`attention_mixer`, which decides by it,
+    and for the gauges ``GPT.__call__`` sets."""
+    from ..ops import attn_prep  # noqa: PLC0415
+
+    return attn_prep.plan(seq, heads, kv_heads, cfg.head_dim, norm=norm,
+                          rotates=rotates,
+                          flash=cfg.attention_impl == "flash", plain=plain)
+
+
+def _scale_of(norm, width: int):
+    """The learned scale [width] of a flax norm over ``width`` channels
+    that may not have run yet: a call on one row of zeros makes the
+    parameter where the module is being initialised (the compiler drops
+    the row)."""
+    norm(jnp.zeros((width,), jnp.float32))
+    return norm.variables["params"]["scale"]
+
+
 def attention_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *,
                     qkv, proj, num_heads: Optional[int] = None,
                     num_kv_heads: Optional[int] = None, attend=None,
@@ -1114,6 +1181,14 @@ def attention_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *,
     or psum-rejoined tensor-parallel closures).  Returns the residual
     delta, or ``(delta, (k, v))`` with ``hand_on="kv"``: the keys and
     values as this layer attends them, for the layers that read them.
+
+    What stands between ``qkv`` and the attention call (the split into
+    heads, the head norms, the rotation) traces under the scope
+    ``attn_prep``: the kernel pair of ``ops/attn_prep.py``, which hands
+    the flash kernels ``q``, ``k`` and ``v`` head-major, where its
+    ``plan`` takes the call (the flash schedule, the layer's own keys
+    and values, RMS norms or none, heads of whole 128-lane tiles), else
+    :func:`attn_prep_chain`.
 
     ``num_heads`` / ``num_kv_heads`` override the config's head counts
     for callers operating on a per-rank head shard (TP).  ``attend``
@@ -1141,23 +1216,30 @@ def attention_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *,
     nkv = num_kv_heads if num_kv_heads is not None else cfg.kv_heads
     hd = cfg.head_dim
     q_dim = nh * hd
-    kv_dim = nkv * hd
     fused = qkv(h)
-    q = fused[..., :q_dim].reshape(b, s, nh, hd)
-    if shared_kv is not None:
-        k, v = shared_kv
-    else:
-        k = fused[..., q_dim:q_dim + kv_dim].reshape(b, s, nkv, hd)
-        v = fused[..., q_dim + kv_dim:].reshape(b, s, nkv, hd)
-    if q_norm is not None:
-        q = q_norm(q).astype(fused.dtype)
-    if k_norm is not None:
-        k = k_norm(k).astype(fused.dtype)
-    if rope_tabs is not None:
-        from ..ops.rope import apply_rope_tables  # noqa: PLC0415
+    norms = q_norm is not None or k_norm is not None
+    rms = isinstance(q_norm, nn.RMSNorm) and isinstance(k_norm, nn.RMSNorm)
+    tiles = _attn_prep_plan(
+        cfg, s, nh, nkv,
+        norm="rmsnorm" if rms else "other" if norms else None,
+        rotates=rope_tabs is not None,
+        plain=(attend is None and shared_kv is None and hand_on is None
+               and differential is None))
+    with jax.named_scope(scopes.ATTN_PREP):
+        if tiles is None:
+            q, k, v = attn_prep_chain(
+                fused, q_norm, k_norm, rope_tabs, heads=nh, kv_heads=nkv,
+                head_dim=hd, shared_kv=shared_kv)
+        else:
+            from ..ops import attn_prep  # noqa: PLC0415
 
-        q = apply_rope_tables(q, *rope_tabs)
-        k = apply_rope_tables(k, *rope_tabs)
+            # head-major, as the flash kernels read them
+            q, k, v = attn_prep.attn_prep(
+                fused,
+                (_scale_of(q_norm, hd), _scale_of(k_norm, hd)) if rms
+                else None,
+                rope_tabs, heads=nh, kv_heads=nkv,
+                eps=q_norm.epsilon if rms else 0.0, tiles=tiles)
     if differential is not None:
         att_4d = _attend_differential(cfg, q, k, v, positions, layer_type,
                                       **differential)
@@ -1184,6 +1266,8 @@ def attention_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *,
 MIXER_SCOPES = {"mamba": scopes.SSM, "selective_scan": scopes.SSM,
                 "gmu": scopes.GMU, "conv": scopes.SHORT_CONV,
                 "kda": scopes.KDA}
+# The layer types that do not run :func:`attention_mixer`.
+NOT_ATTENTION_MIXER = frozenset({*MIXER_SCOPES, "mla", FEED_FORWARD})
 
 
 def block_math(cfg: TransformerConfig, x, *, ln1, mixer=None, ln2=None,
@@ -1877,6 +1961,26 @@ class GPT(nn.Module):
                     *args, **kwargs)
         # what a layer made for later layers, beside the stream
         handed = {"kv": None, "memory": None}
+        # counted while the step is traced: the attention layers that
+        # have a norm over each head or a rotation between the fused
+        # matmul and the attention call, and those of them whose chain
+        # takes the kernels of ops/attn_prep.py
+        preps = [_attn_prep_plan(
+            cfg, s, cfg.num_heads, cfg.kv_heads,
+            norm=cfg.norm if cfg.qk_norm else None,
+            rotates=cfg.rotates(cfg.layer_type(i)),
+            plain=(cfg.layer_type(i) != "cross_attention"
+                   and cfg.hands_on(i) is None
+                   and not cfg.differential_attention))
+            for i in range(cfg.num_layers)
+            if cfg.layer_type(i) not in NOT_ATTENTION_MIXER
+            and (cfg.qk_norm or cfg.rotates(cfg.layer_type(i)))]
+        if preps:
+            from ..obs.registry import get_registry  # noqa: PLC0415
+
+            get_registry().gauge("attn_prep.layers").set(len(preps))
+            get_registry().gauge("attn_prep.kernel_layers").set(
+                sum(tiles is not None for tiles in preps))
         if cfg.layer_types:
             from ..obs.registry import get_registry  # noqa: PLC0415
 

@@ -1093,3 +1093,28 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, plan: FlashPlan, scale,
         scratch_shapes=[pltpu.VMEM((d, bq), f32)],
         semantics=("parallel", "arbitrary"))
     return dq, dk, dvalues
+
+
+# Below the kernels, so that no line of theirs moves (file and line of a
+# Pallas kernel sit in its Mosaic payload: every flash call lowers to the
+# text it did).
+def unfolded(t, heads: int):
+    """The ``[batch, seq, heads, head_dim]`` shape of a head-major ``t``
+    ``[batch heads, seq, head_dim]``, for :func:`flash_plan`."""
+    z, s, d = t.shape
+    return jax.ShapeDtypeStruct((z // heads, s, heads, d), t.dtype)
+
+
+def flash_attention_folded(q, k, v, *, heads: int, kv_heads: int,
+                           scale: Optional[float] = None, **call):
+    """:func:`flash_attention` for ``q`` ``[batch heads, seq, head_dim]``
+    and ``k``, ``v`` ``[batch kv_heads, seq, .]`` that are head-major
+    already (``ops/attn_prep.py`` writes them so); ``call`` the other
+    keyword arguments of :func:`flash_plan`.  Returns ``[batch, seq,
+    heads, value_dim]``."""
+    plan = flash_plan(unfolded(q, heads), unfolded(k, kv_heads),
+                      unfolded(v, kv_heads), **call)
+    z, s, d = q.shape
+    out = _flash(q, k, v, plan, scale if scale is not None else d ** -0.5,
+                 bool(_interpret_for_backend(jax.default_backend())))
+    return out.reshape(z // heads, heads, s, v.shape[2]).transpose(0, 2, 1, 3)

@@ -64,6 +64,18 @@ KDA_SCAN = "kda_scan"                  # inside kda: the chunk rule alone
 MLA_PROJ = "mla_proj"                  # latent attention, inside attn: the
                                        # two low-rank paths, their norms,
                                        # RoPE, the shared rotary key
+ATTN_PREP = "attn_prep"                # inside attn: what stands between
+                                       # the fused q/k/v matmul and the
+                                       # attention call: the split into
+                                       # heads, the norm over each head of
+                                       # q and k, the rotation.  The
+                                       # kernels attn_prep_fwd and
+                                       # attn_prep_bwd of ops/attn_prep.py
+                                       # (which write head-major, as the
+                                       # flash kernels read) where its plan
+                                       # takes the call (gauge
+                                       # attn_prep.kernel_layers), else
+                                       # XLA's fusions
 ATTN_WINDOW = "attn_window"            # inside attn: the attention call of
                                        # a layer that has a window, so its
                                        # flash kernels carry the name
@@ -167,4 +179,4 @@ SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ,
           MOE_LOGITS, MOE_TOPK, MOE_SORT, MOE_UNSORT, MOE_ROWS_IN,
           MOE_ROWS_OUT, MOE_CAST, MOE_GATE, ATTN_BLOCK_DIFFUSION,
           DIFFUSION_NOISE, HC_COEFF, HC_READ, HC_WRITE, SSM_NORM,
-          SSM_PREP)
+          SSM_PREP, ATTN_PREP)

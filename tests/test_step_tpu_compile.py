@@ -11,8 +11,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from test_ssm_chain import _names
 from test_tpu_compile import (compiled_kernels, four_chips,  # noqa: F401
-                              no_compile_cache, topo)
+                              no_compile_cache, one_chip, topo)
 
 
 # The gradient plane's "proof of overlap" (optim/overlap.py), read from
@@ -52,6 +53,10 @@ def test_lfm2_cell_step_compiles_for_v5e(topo, compiled_kernels):
     for kernel in ("flash_fwd", "flash_bwd_dkdv", "gmm", "tgmm"):
         assert kernel in text, kernel
     assert "flash_bwd_dq" not in text
+    # heads of 64, half a lane tile: the norms and the rotation stay
+    # XLA's chain, under the scope (ops/attn_prep.py:plan)
+    assert "attn_prep_fwd" not in text and "attn_prep_bwd" not in text
+    assert "/block1/attn/attn_prep/" in text
     assert "jvp(GPT)/block0/short_conv/short_conv_filter" in text
     assert "/block4/short_conv/short_conv_filter" in text
     mem = compiled.memory_analysis()
@@ -60,6 +65,72 @@ def test_lfm2_cell_step_compiles_for_v5e(topo, compiled_kernels):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert total < 15 * 2 ** 30, json.dumps(total / 2 ** 30)
+
+
+@pytest.mark.parametrize("cell,layers", [
+    ("gpt2m_train_s1024", 0),       # no norms, learned positions
+    ("granite4hm_train_s8192", 0),  # no norms, no positions
+    ("trinitym_train_s8192", 5),    # four with the rotation, one without
+    ("sdar_train_s8192_bd4", 6),
+])
+def test_which_cells_steps_hold_the_attn_prep_kernels(topo, cell, layers):
+    """The steps as the benchmark builds them, traced for one described
+    chip: a cell whose attention layers have neither a norm over each
+    head nor a rotation holds no ``attn_prep`` call; Trinity-Mini's and
+    SDAR's hold one forward kernel a layer in the forward pass, a second
+    in the rematerialised block's recompute, and one backward.  (LFM2's
+    heads of 64 keep the chain: the compiled step above.)"""
+    import os
+    import sys
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    import horovod_tpu as hvd
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.harness import registry
+
+    loaded = registry.load_cell(cell, root)
+    config = loaded["config_values"]
+    mesh = Mesh(np.asarray(topo.devices[:1], dtype=object), (hvd.DP_AXIS,))
+    built = registry.load_model_builder(config["family"], root).build(
+        config, loaded["params"], 0, described_mesh=mesh)
+    names = _names(jax.make_jaxpr(built.step)(*built.state).jaxpr)
+    assert names.count("attn_prep_fwd") == 2 * layers
+    assert names.count("attn_prep_bwd") == layers
+    assert names.count("flash_fwd") > 0
+
+
+@pytest.mark.parametrize("rows,heads,norms,rotates", [
+    (16384, 32, True, True),    # SDAR's layers, Trinity-Mini's sliding
+    (8192, 32, True, False),    # Trinity-Mini's full_attention layer
+    (16384, 28, False, True),   # SmallThinker's sliding layers
+])
+def test_attn_prep_kernels_compile_for_v5e(one_chip, rows, heads, norms,
+                                           rotates):
+    """Both kernels at the cells' shapes (heads over 4 key/value heads
+    of 128, bfloat16), inside the VMEM their calls state."""
+    from horovod_tpu.ops import attn_prep
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tiles = attn_prep.plan(rows, heads, 4, 128,
+                           norm="rmsnorm" if norms else None,
+                           rotates=rotates, flash=True, plain=True)
+    call = dict(shape=(heads, 4, 1e-6, *tiles), interpret=False)
+    args = (shaped(1, rows, (heads + 8) * 128),
+            shaped(2, 128, dtype=jnp.float32) if norms else None,
+            (shaped(rows, 128, dtype=jnp.float32),) * 2 if rotates else None)
+    major = lambda n: shaped(1, n, rows, 128)
+    for lowered in (
+            attn_prep._forward.lower(*args, **call),
+            attn_prep._backward.lower(*args, major(heads), major(4),
+                                      major(4), **call)):
+        assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 @pytest.mark.parametrize("width,bucket_bytes", [
