@@ -34,7 +34,7 @@ import contextlib
 import functools
 import math
 from dataclasses import dataclass, fields, replace
-from typing import Any, Optional
+from typing import Any, Optional, Union
 
 import flax.linen as nn
 import jax
@@ -56,7 +56,9 @@ from ..parallel.moe import DEFAULT_GROUP_SIZE as MOE_DEFAULT_GROUP_SIZE
 # ``memory_layer`` names, ``conv`` a gated short convolution: a
 # causal depthwise filter of ``conv_taps`` taps between two gates, and
 # ``kda`` a gated delta rule with a decay per channel of the key
-# (ops/kda.py).  ``feed_forward`` is no mixer: a model that names it is
+# (ops/kda.py), ``gdn`` the gated delta rule whose decay is one number a
+# head, with fewer key heads than value heads (Gated DeltaNet; the same
+# kernels).  ``feed_forward`` is no mixer: a model that names it is
 # made of layers of ONE half each (``TransformerConfig.one_half``), one
 # norm and a mixer in a layer of a mixer type, one norm and the
 # feed-forward (dense or routed: ``ffn_type``) in a ``feed_forward`` one.
@@ -64,7 +66,7 @@ ATTENTION_LAYER_TYPES = ("attention", "mla", "sliding_attention",
                          "full_attention", "cross_attention")
 FEED_FORWARD = "feed_forward"
 LAYER_TYPES = ATTENTION_LAYER_TYPES + ("mamba", "selective_scan", "gmu",
-                                       "conv", "kda", FEED_FORWARD)
+                                       "conv", "kda", "gdn", FEED_FORWARD)
 
 
 @dataclass(frozen=True)
@@ -221,6 +223,9 @@ class TransformerConfig:
     routed_gated: bool = True
     # The shared expert's width (None = shared_experts * routed_width).
     shared_width: Optional[int] = None
+    # The shared expert behind a learned gate of its own: sigmoid(x w_s)
+    # * shared(x), w_s [emb, 1] (``shared_gate``), a number a token.
+    shared_expert_gate: bool = False
     # > 0: every expert layer sows its load-balance loss (E * sum_e f_e
     # P_e over the layer's own tokens, 1.0 at an even load; scope
     # "moe_balance") into the "losses" collection as "moe_balance" and
@@ -247,8 +252,20 @@ class TransformerConfig:
     # (one scale of head_dim each, shared by the heads), before RoPE.
     qk_norm: bool = False
     # att * sigmoid(gate(h)) before proj: a gate as wide as q, from the
-    # normed stream the queries are made of.
-    attention_gate: bool = False
+    # normed stream the queries are made of.  True: its values come from
+    # a projection of its own (``gate``); "query": from the query
+    # projection itself, made twice as wide (``qkv`` is then [gate ; q ;
+    # k ; v]: the same parameters as a q_proj whose every head is
+    # [query ; gate]).
+    attention_gate: Union[bool, str] = False
+    # The share of a head's channels RoPE turns: the first head_dim *
+    # partial_rotary_factor of them, the others see no positions.
+    partial_rotary_factor: float = 1.0
+    # RMS norms scale by 1 + w with w from zeros, not by w from ones:
+    # the blocks' norms, the final norm and the head norms of q and k
+    # (``norm`` must be "rmsnorm").  The same function at the start;
+    # weight decay then pulls the scale towards 1 and not towards 0.
+    norm_unit_offset: bool = False
     # A norm on each branch's OUTPUT before the residual add, beside the
     # two on its input: four norms a block.
     post_norms: bool = False
@@ -299,6 +316,19 @@ class TransformerConfig:
     kda_conv: int = 4
     kda_chunk: int = 64
     kda_states_every: int = 4
+    # Gated DeltaNet (layer type "gdn", arXiv:2412.06464; ops/kda.py:
+    # gated_delta_rule): gdn_value_heads heads of values gdn_value_head_dim
+    # wide over gdn_key_heads heads of queries and keys gdn_key_head_dim
+    # wide (a key head serves value_heads // key_heads value heads in
+    # order), ONE causal depthwise filter of gdn_conv taps over q, k and
+    # v together, a log-decay and a beta a value head.  One rule and one
+    # kernel pair with "kda": its chunks are kda_chunk tokens and a state
+    # is kept every kda_states_every of them.
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_head_dim: int = 128
+    gdn_value_head_dim: int = 128
+    gdn_conv: int = 4
     # The attention mask.  None: causal (with the layer type's window).
     # B, a power of two: the block-diffusion training mask
     # (arXiv:2503.09573; models/block_diffusion.py makes the step's
@@ -401,14 +431,30 @@ class TransformerConfig:
                 ("attention", "sliding_attention", "full_attention"))
             self._check_handed_on("gmu", "memory_layer",
                                   ("selective_scan",))
+            chunks_ok = (self.kda_states_every > 0 and self.kda_chunk > 0
+                         and not self.kda_chunk & (self.kda_chunk - 1))
             if "kda" in self.layer_types and (
-                    min(self.kda_heads, self.kda_head_dim, self.kda_conv,
-                        self.kda_states_every) <= 0 or self.kda_chunk <= 0
-                    or self.kda_chunk & (self.kda_chunk - 1)):
+                    min(self.kda_heads, self.kda_head_dim,
+                        self.kda_conv) <= 0 or not chunks_ok):
                 raise ValueError(
                     f"a 'kda' layer needs positive kda_heads="
                     f"{self.kda_heads}, kda_head_dim={self.kda_head_dim}, "
                     f"kda_conv={self.kda_conv} and kda_states_every="
+                    f"{self.kda_states_every}, and kda_chunk="
+                    f"{self.kda_chunk} a power of two")
+            if "gdn" in self.layer_types and (
+                    min(self.gdn_key_heads, self.gdn_value_heads,
+                        self.gdn_key_head_dim, self.gdn_value_head_dim,
+                        self.gdn_conv) <= 0
+                    or self.gdn_value_heads % self.gdn_key_heads
+                    or not chunks_ok):
+                raise ValueError(
+                    f"a 'gdn' layer needs gdn_value_heads="
+                    f"{self.gdn_value_heads} a positive multiple of "
+                    f"gdn_key_heads={self.gdn_key_heads}, positive "
+                    f"gdn_key_head_dim={self.gdn_key_head_dim}, "
+                    f"gdn_value_head_dim={self.gdn_value_head_dim}, "
+                    f"gdn_conv={self.gdn_conv} and kda_states_every="
                     f"{self.kda_states_every}, and kda_chunk="
                     f"{self.kda_chunk} a power of two")
             if "mla" in self.layer_types:
@@ -461,7 +507,20 @@ class TransformerConfig:
                     f"0 < routed_top_k <= routed_experts, a routed_width "
                     f"and held experts {self.routed_first_held}.."
                     f"{self.routed_first_held + held - 1} among them")
+        if self.norm_unit_offset and self.norm != "rmsnorm":
+            raise ValueError(
+                f"norm_unit_offset is the RMS norms' scale 1 + w: norm "
+                f"must be 'rmsnorm', got {self.norm!r}")
+        rotary = self.head_dim * self.partial_rotary_factor
+        if self.partial_rotary_factor != 1.0 and (
+                not 0 < self.partial_rotary_factor < 1
+                or rotary != int(rotary) or int(rotary) % 2):
+            raise ValueError(
+                f"partial_rotary_factor={self.partial_rotary_factor} of a "
+                f"head of {self.head_dim} must be an even number of "
+                f"channels, at most the head")
         for setting, allowed in (
+                ("attention_gate", (False, True, "query")),
                 ("routed_router_input", ("ffn_input", "layer_input")),
                 ("routed_scores", SCORE_RULES),
                 ("routed_activation", tuple(ACTIVATIONS))):
@@ -479,6 +538,14 @@ class TransformerConfig:
             raise ValueError(
                 "shared_kv_layer and memory_layer name a layer of "
                 "layer_types: layer_types must be set")
+        if self.query_gate and (
+                self.differential_attention
+                or "cross_attention" in (self.layer_types or ())):
+            raise ValueError(
+                "attention_gate='query' widens the projection that "
+                "makes q, k and v: it is implemented for the plain "
+                "attention layers, not for differential or "
+                "'cross_attention' ones")
         if self.differential_attention:
             if self.num_heads % 2 or self.kv_heads % 2:
                 raise ValueError(
@@ -621,9 +688,24 @@ class TransformerConfig:
 
     @property
     def rope_dim(self) -> int:
-        """The channels RoPE rotates: a whole head, or an MLA head's
-        rotary part."""
-        return self.qk_rope_head_dim or self.head_dim
+        """The channels RoPE rotates: a head's ``partial_rotary_factor``
+        (all of it by default), or an MLA head's rotary part."""
+        return self.qk_rope_head_dim or int(
+            self.head_dim * self.partial_rotary_factor)
+
+    @property
+    def query_gate(self) -> bool:
+        """Does the attention layers' output gate come out of the query
+        projection?"""
+        return self.attention_gate == "query"
+
+    @property
+    def gdn_key_inner(self) -> int:
+        return self.gdn_key_heads * self.gdn_key_head_dim
+
+    @property
+    def gdn_value_inner(self) -> int:
+        return self.gdn_value_heads * self.gdn_value_head_dim
 
     @property
     def kda_inner(self) -> int:
@@ -1067,6 +1149,72 @@ def kda_mixer(cfg: TransformerConfig, h, *, qkv, conv_kernel, f_a, f_b,
     return o_proj(gated.reshape(b, s, inner).astype(fused.dtype))
 
 
+def gdn_prep_chain(fused, decay, beta, conv_kernel, dt_bias, a_log, *,
+                   key_heads, value_heads, d_k, d_v):
+    """The float32 chain in front of the scalar-decay delta rule:
+    ``fused`` [b, s, >= 2 key_heads d_k + value_heads d_v] holds ``[q ;
+    k ; v]`` first, through ONE causal depthwise filter ``conv_kernel``
+    [taps, that width] and silu; ``q`` and ``k`` of unit length over a
+    head's channels (``+ 1e-6`` under the root), ``q`` then scaled by
+    ``d_k ** -0.5``, all three rounded to ``fused``'s dtype; ``g =
+    -exp(a_log) * softplus(decay + dt_bias)`` and ``sigmoid(beta)`` a
+    value head, float32."""
+    b, s, _ = fused.shape
+    key_inner, value_inner = key_heads * d_k, value_heads * d_v
+    mixed = jax.nn.silu(causal_depthwise_conv(
+        fused[..., :2 * key_inner + value_inner], conv_kernel))
+    q = mixed[..., :key_inner].reshape(b, s, key_heads, d_k)
+    k = mixed[..., key_inner:2 * key_inner].reshape(b, s, key_heads, d_k)
+    v = mixed[..., 2 * key_inner:].reshape(b, s, value_heads, d_v)
+    unit = lambda t: t * jax.lax.rsqrt(
+        jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+    q, k = unit(q) * d_k ** -0.5, unit(k)
+    g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
+        decay.astype(jnp.float32) + dt_bias)
+    return (*(t.astype(fused.dtype) for t in (q, k, v)), g,
+            jax.nn.sigmoid(beta.astype(jnp.float32)))
+
+
+def gdn_mixer(cfg: TransformerConfig, h, *, in_proj, ba_proj, conv_kernel,
+              dt_bias, a_log, norm_scale, out_proj):
+    """Gated DeltaNet (arXiv:2412.06464, as the ``qwen3_next`` family
+    builds it) on the normed stream ``h`` [b, s, emb]: ``in_proj`` gives
+    ``[q ; k ; v ; z]``, ``q`` and ``k`` ``gdn_key_heads x
+    gdn_key_head_dim`` wide, ``v`` and the gate ``z`` ``gdn_value_heads
+    x gdn_value_head_dim``; ``ba_proj`` gives ``[b ; a]``, a number a
+    value head each.  Under the scope ``gdn_prep``
+    (:func:`gdn_prep_chain`): one filter of ``conv_kernel`` [taps, q + k
+    + v] and silu, the two L2 norms, ``g = -exp(a_log) * softplus(a +
+    dt_bias)`` and ``beta = sigmoid(b)``.  The rule
+    (``ops/kda.py:gated_delta_rule``, scope ``gdn_scan``) in chunks of
+    ``kda_chunk`` tokens, which refuses a sequence the chunk does not
+    divide; an RMS norm over each value head's channels with the one
+    scale ``norm_scale`` (plain, whatever ``norm_unit_offset`` says)
+    times ``silu(z)``; the output projection.  The projections are
+    callables like ``block_math``'s, the rest raw arrays; ``q``, ``k``
+    and ``v`` enter the rule in the compute dtype, ``g`` and ``beta`` in
+    float32.  Returns the residual delta."""
+    from ..ops.kda import gated_delta_rule  # noqa: PLC0415
+
+    b, s, _ = h.shape
+    heads, d_v = cfg.gdn_value_heads, cfg.gdn_value_head_dim
+    fused, ba = in_proj(h), ba_proj(h)
+    with jax.named_scope(scopes.GDN_PREP):
+        q, k, v, g, beta = gdn_prep_chain(
+            fused, ba[..., heads:], ba[..., :heads], conv_kernel, dt_bias,
+            a_log, key_heads=cfg.gdn_key_heads, value_heads=heads,
+            d_k=cfg.gdn_key_head_dim, d_v=d_v)
+    o = gated_delta_rule(q, k, v, g, beta, chunk=cfg.kda_chunk,
+                         states_every=cfg.kda_states_every).astype(
+                             jnp.float32)
+    z = fused[..., 2 * cfg.gdn_key_inner + cfg.gdn_value_inner:]
+    normed = o * jax.lax.rsqrt(
+        jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps)
+    gated = normed * norm_scale * jax.nn.silu(
+        z.astype(jnp.float32).reshape(b, s, heads, d_v))
+    return out_proj(gated.reshape(b, s, heads * d_v).astype(fused.dtype))
+
+
 def gmu_mixer(h, memory, *, in_proj, out_proj):
     """A gated memory unit (arXiv:2507.06607) on the normed stream ``h``:
     ``out_proj(silu(in_proj(h)) * memory)``, ``memory`` [b, s, width]
@@ -1152,9 +1300,16 @@ def _attn_prep_plan(cfg: TransformerConfig, seq: int, heads: int,
     """What ``ops/attn_prep.py:plan`` says of an attention layer of
     ``cfg`` at ``seq`` rows: the kernels' tiles, or ``None`` for the
     chain.  One place for :func:`attention_mixer`, which decides by it,
-    and for the gauges ``GPT.__call__`` sets."""
+    and for the gauges ``GPT.__call__`` sets.  A layer whose gate comes
+    out of the query projection, whose head norms scale by ``1 + w`` or
+    whose heads are turned in part gets the chain: the kernels take none
+    of the three."""
     from ..ops import attn_prep  # noqa: PLC0415
 
+    if (cfg.query_gate or (norm and cfg.norm_unit_offset)
+            or (rotates and cfg.rope_dim != cfg.head_dim)):
+        # the kernels read [q ; k ; v], scale by w and turn whole heads
+        return None
     return attn_prep.plan(seq, heads, kv_heads, cfg.head_dim, norm=norm,
                           rotates=rotates,
                           flash=cfg.attention_impl == "flash", plain=plain)
@@ -1174,7 +1329,8 @@ def attention_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *,
                     num_kv_heads: Optional[int] = None, attend=None,
                     layer_type: Optional[str] = None, q_norm=None,
                     k_norm=None, gate=None, differential=None,
-                    shared_kv=None, hand_on: Optional[str] = None):
+                    shared_kv=None, hand_on: Optional[str] = None,
+                    query_gate: bool = False):
     """Attention on the normed stream ``h`` [b, s, emb]: ``qkv →
     split-heads → rope → attend → proj``.  ``qkv`` and ``proj`` are
     callables like ``block_math``'s (flax modules, raw-weight closures,
@@ -1203,7 +1359,10 @@ def attention_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *,
     head's channels after the head split and before RoPE; ``gate``, from
     the same normed stream as the queries and as wide, whose sigmoid
     multiplies the attended values before ``proj`` (scope
-    ``attn_gate``).  ``layer_type`` gives the attention call its window
+    ``attn_gate``); ``query_gate`` instead of ``gate``: ``qkv``'s output
+    is ``[gate ; q ; k ; v]`` and its leading ``q``-wide part is the
+    gate's values (the same scope around sigmoid and product).
+    ``layer_type`` gives the attention call its window
     (``cfg.window_of``); the caller hands in ``rope_tabs=None`` for a
     layer that sees no positions.  ``shared_kv=(k, v)``, what another
     layer handed on, makes ``qkv`` a projection to the queries alone
@@ -1217,6 +1376,8 @@ def attention_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *,
     hd = cfg.head_dim
     q_dim = nh * hd
     fused = qkv(h)
+    if query_gate:
+        gate_values, fused = fused[..., :q_dim], fused[..., q_dim:]
     norms = q_norm is not None or k_norm is not None
     rms = isinstance(q_norm, nn.RMSNorm) and isinstance(k_norm, nn.RMSNorm)
     tiles = _attn_prep_plan(
@@ -1253,10 +1414,12 @@ def attention_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *,
     else:
         att_4d = attend(q, k, v)
     att = att_4d.reshape(b, s, q_dim)
-    if gate is not None:
+    if gate is not None or query_gate:
         with jax.named_scope(scopes.ATTN_GATE):
+            if not query_gate:
+                gate_values = gate(h)
             att = (att * jax.nn.sigmoid(
-                gate(h).astype(jnp.float32))).astype(att.dtype)
+                gate_values.astype(jnp.float32))).astype(att.dtype)
     delta = proj(act_store(att, cfg))
     return (delta, (k, v)) if hand_on == "kv" else delta
 
@@ -1265,7 +1428,7 @@ def attention_mixer(cfg: TransformerConfig, h, positions, rope_tabs, *,
 # is an attention layer and traces under ``attn``.
 MIXER_SCOPES = {"mamba": scopes.SSM, "selective_scan": scopes.SSM,
                 "gmu": scopes.GMU, "conv": scopes.SHORT_CONV,
-                "kda": scopes.KDA}
+                "kda": scopes.KDA, "gdn": scopes.GDN}
 # The layer types that do not run :func:`attention_mixer`.
 NOT_ATTENTION_MIXER = frozenset({*MIXER_SCOPES, "mla", FEED_FORWARD})
 
@@ -1300,12 +1463,11 @@ def block_math(cfg: TransformerConfig, x, *, ln1, mixer=None, ln2=None,
     whatever ``hc_mult`` is) that returns the residual DELTA:
     :func:`attention_mixer`, :func:`mla_mixer`, :func:`mamba_mixer`,
     :func:`selective_scan_mixer`, :func:`gmu_mixer`,
-    :func:`short_conv_mixer` or :func:`kda_mixer` with the caller's
-    parameterized layer
-    applications closed over; it traces under the scope of its
-    ``layer_type`` (``MIXER_SCOPES``: ``ssm``, ``gmu``, ``short_conv``,
-    ``kda`` or ``attn``).  Shared by the flax :class:`Block`, the raw-weights
-    pipeline-parallel and decode block (:func:`raw_block_forward`), and
+    :func:`short_conv_mixer`, :func:`kda_mixer` or :func:`gdn_mixer` with
+    the caller's parameterized layer applications closed over; it traces
+    under the scope of its ``layer_type`` (``MIXER_SCOPES``: ``ssm``,
+    ``gmu``, ``short_conv``, ``kda``, ``gdn`` or ``attn``).  Shared by
+    the flax :class:`Block`, the raw-weights pipeline-parallel and decode block (:func:`raw_block_forward`), and
     the Megatron tensor-parallel block (``parallel/tensor_parallel.py``),
     so a change to the block (a norm variant, the residual's scale, a
     post-norm, the residual path itself) is made exactly once, and a new
@@ -1419,8 +1581,27 @@ def raw_block_forward(cfg: TransformerConfig, p, x, positions, rope_tabs,
     )
 
 
+class UnitOffsetRMSNorm(nn.Module):
+    """``x * rsqrt(mean x^2 + epsilon) * (1 + scale)`` over the last
+    axis, ``scale`` from zeros, float32 math: the RMS norm of the
+    families that store the scale's distance from 1."""
+
+    epsilon: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        x = x.astype(jnp.float32)
+        scale = self.param("scale", nn.initializers.zeros, (x.shape[-1],),
+                           jnp.float32)
+        return x * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+            + self.epsilon) * (1.0 + scale)
+
+
 def _norm(cfg: TransformerConfig, name: str):
     """The configuration's norm as a flax module, float32 math."""
+    if cfg.norm_unit_offset:
+        return UnitOffsetRMSNorm(epsilon=cfg.norm_eps, name=name)
     if cfg.norm == "rmsnorm":
         return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
     return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
@@ -1449,7 +1630,8 @@ class Block(nn.Module):
     The wiring lives in :func:`block_math`; this module only declares
     the flax parameters (the attention mixer's, a state-space mixer's, a
     gated memory unit's, a gated short convolution's, Kimi Delta
-    Attention's or latent attention's, by ``layer_type``; a dense feed-forward's or the routed
+    Attention's, Gated DeltaNet's or latent attention's, by
+    ``layer_type``; a dense feed-forward's or the routed
     experts', by ``ffn``; with ``cfg.hc_mult`` streams the two
     hyper-connections') and hands
     their applications in as callables: one ``mixer`` closure over the
@@ -1590,8 +1772,13 @@ class Block(nn.Module):
             y = y.reshape(b, s, d)
             if cfg.shared_experts > 0:
                 with jax.named_scope(scopes.MOE_SHARED):
-                    y = y + feed_forward(h, cfg.shared_ffn_width,
-                                         "shared_fc1", "shared_fc2")
+                    shared = feed_forward(h, cfg.shared_ffn_width,
+                                          "shared_fc1", "shared_fc2")
+                    if cfg.shared_expert_gate:
+                        shared = (shared * jax.nn.sigmoid(
+                            unbiased(1, "shared_gate")(h).astype(
+                                jnp.float32))).astype(shared.dtype)
+                    y = y + shared
             return y
 
         def mlp(h, routing=None):
@@ -1703,6 +1890,26 @@ class Block(nn.Module):
                 norm_scale=self.param("o_norm", nn.initializers.ones,
                                       (hd,), jnp.float32),
                 o_proj=unbiased(cfg.emb_dim, "o_proj"))
+        elif self.layer_type == "gdn":
+            heads = cfg.gdn_value_heads
+            streams = 2 * cfg.gdn_key_inner + cfg.gdn_value_inner
+            mixer = lambda h: gdn_mixer(
+                cfg, h,
+                in_proj=unbiased(streams + cfg.gdn_value_inner, "in_proj"),
+                ba_proj=unbiased(2 * heads, "ba_proj"),
+                conv_kernel=self.param(
+                    "conv_kernel", _conv_init, (cfg.gdn_conv, streams),
+                    jnp.float32),
+                dt_bias=self.param("dt_bias", nn.initializers.ones,
+                                   (heads,), jnp.float32),
+                # A = U(0, 16) as the family draws it, kept off 0
+                a_log=self.param(
+                    "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                        key, shape, jnp.float32, 1e-3, 16.0)), (heads,)),
+                norm_scale=self.param("o_norm", nn.initializers.ones,
+                                      (cfg.gdn_value_head_dim,),
+                                      jnp.float32),
+                out_proj=unbiased(cfg.emb_dim, "out_proj"))
         elif self.layer_type == "mla":
             heads = cfg.num_heads
             query = {}
@@ -1730,7 +1937,8 @@ class Block(nn.Module):
                 attn["qkv"] = dense(q_dim, "q")
                 attn["shared_kv"] = shared_kv
             else:
-                attn["qkv"] = dense(q_dim + 2 * kv_dim, "qkv")
+                attn["qkv"] = dense(
+                    (2 if cfg.query_gate else 1) * q_dim + 2 * kv_dim, "qkv")
             attn["proj"] = dense(cfg.emb_dim, "proj")
             if cfg.differential_attention:
                 vector = lambda name: self.param(
@@ -1745,7 +1953,9 @@ class Block(nn.Module):
             if cfg.qk_norm:
                 attn["q_norm"] = _norm(cfg, "q_norm")
                 attn["k_norm"] = _norm(cfg, "k_norm")
-            if cfg.attention_gate:
+            if cfg.query_gate:
+                attn["query_gate"] = True
+            elif cfg.attention_gate:
                 attn["gate"] = dense(q_dim, "gate")
             tabs = rope_tabs if cfg.rotates(self.layer_type) else None
             mixer = lambda h: attention_mixer(
@@ -2044,6 +2254,24 @@ class GPT(nn.Module):
                     tokens.shape[0], s, cfg.kda_heads, cfg.kda_head_dim,
                     cfg.kda_head_dim, cfg.kda_chunk, cfg.kda_states_every,
                     jnp.dtype(cfg.dtype).itemsize))
+            gdns = cfg.layer_types.count("gdn")
+            if gdns:
+                from ..ops import kda  # noqa: PLC0415
+
+                # the scalar-decay delta-rule layers of the program, those
+                # whose rule takes the kernels (all or none: they share a
+                # shape; the rule enters them at the value heads' count),
+                # the chunk it runs at and what one layer keeps for its
+                # backward
+                rule = (cfg.gdn_value_heads, cfg.gdn_key_head_dim,
+                        cfg.gdn_value_head_dim, cfg.kda_chunk,
+                        cfg.kda_states_every, jnp.dtype(cfg.dtype).itemsize)
+                get_registry().gauge("gdn.layers").set(gdns)
+                get_registry().gauge("gdn.kernel_layers").set(
+                    0 if kda.plan(s, *rule) is None else gdns)
+                get_registry().gauge("gdn.chunk").set(cfg.kda_chunk)
+                get_registry().gauge("gdn.kept_mib").set(
+                    kda.kept_mib(tokens.shape[0], s, *rule))
         for i in range(cfg.num_layers):
             kind, hand_on = cfg.layer_type(i), cfg.hands_on(i)
             block = block_cls(cfg, kind, cfg.ffn_type(i), hand_on,
@@ -2594,6 +2822,44 @@ GPT_CONFIGS = {
         # input and, as every policy does, what its kernels made (the
         # scan's y 128 MiB and states 256 MiB, the attention layer's o
         # 128 MiB and lse 2 MiB)
+        remat_policy="nothing_saveable",
+    ),
+    # https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct config.json
+    # (model_type qwen3_next; the rule: arXiv:2412.06464): Gated DeltaNet
+    # layers (16 key heads under 32 value heads of 128, one four-tap
+    # filter over q, k and v, a decay that is one number a head, the
+    # head norm gated by silu(z)) three to one with gated attention
+    # layers where (i + 1) % 4 == 0 (16 query heads over 2 key/value
+    # heads of 256, the query projection twice as wide: a head's query
+    # and its sigmoid output gate; 1 + w norms over each head of q and
+    # k; the first 64 of a head's 256 channels rotated, theta 1e7);
+    # every norm of the stream scales by 1 + w; in every layer 512
+    # routed experts of 512, 10 a token, weights the full softmax
+    # renormalised over the ten (norm_topk_prob), beside a shared expert
+    # of 512 behind a sigmoid gate of its own; a load-balance loss at
+    # the family's router_aux_loss_coef 0.001 (not in config.json); an
+    # untied head.  The release's prediction module has no key in
+    # config.json and is not built.
+    # Training path only (require_gpt2_block says who refuses it).
+    "qwen3-next-80b-a3b-instruct": TransformerConfig(
+        vocab_size=151936, num_layers=48, emb_dim=2048, max_len=262144,
+        layer_types=tuple("full_attention" if (i + 1) % 4 == 0 else "gdn"
+                          for i in range(48)),
+        num_heads=16, num_kv_heads=2, head_size=256, qk_norm=True,
+        attention_gate="query",
+        pos_embedding="rope", rope_theta=1e7, partial_rotary_factor=0.25,
+        gdn_key_heads=16, gdn_value_heads=32, gdn_key_head_dim=128,
+        gdn_value_head_dim=128, gdn_conv=4,
+        mlp_width=5120, mlp="silu_gated", norm="rmsnorm", norm_eps=1e-6,
+        norm_unit_offset=True, use_bias=False, tie_embeddings=False,
+        routed_experts=512, routed_top_k=10, routed_width=512,
+        routed_scores="softmax_chosen", routed_balance_coef=0.001,
+        shared_experts=1, shared_expert_gate=True,
+        # 16384 x 12288 of in_proj a DeltaNet block and 16384 x 9216 of
+        # the attention block's projection: keep each block's input and,
+        # as every policy does, what its kernels made (the rule's o 128
+        # MiB and states 128 MiB, the attention layer's o 128 MiB and
+        # lse 1 MiB)
         remat_policy="nothing_saveable",
     ),
 }

@@ -784,3 +784,47 @@ def _kernel_backward(q, k, v, g, beta, states, do, chunk, n, hb, interpret):
     d_beta = d_beta.transpose(0, 2, 4, 1, 3).reshape(b, s, h)
     return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
             dg.reshape(g.shape), d_beta)
+
+
+def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
+                     states_every: int = 4):
+    """The gated delta rule whose decay is ONE number a head and token
+    (Gated DeltaNet, arXiv:2412.06464), with fewer key heads than value
+    heads: ``q``, ``k`` [batch, seq, key heads, d_k] (``k`` of unit norm
+    a head, ``q`` scaled); ``v`` [batch, seq, heads, d_v], ``heads`` a
+    multiple of the key heads, value head ``i`` reading key head ``i //
+    (heads // key heads)``; ``g`` [batch, seq, heads], the log-decay
+    (``<= 0``); ``beta`` [batch, seq, heads] in [0, 1].  Per value head
+    ``S_t = (I - beta_t k_t k_t^T) exp(g_t) S_{t-1} + beta_t k_t v_t^T``,
+    ``o_t = S_t^T q_t``.  Returns ``o`` like ``v``.
+
+    Computed by :func:`kda`'s rule, exactly: equal decays in all of a
+    head's channels are the scalar rule.  ``g`` is spread over the
+    ``d_k`` channels (float32 ``[batch, seq, heads, d_k]``: 256 MiB a
+    layer at 16 384 tokens and 32 heads of 128) and ``q`` and ``k`` over
+    the value heads (128 MiB each there), under the scope ``gdn_spread``
+    inside ``gdn_scan``, which holds the rule itself; their gradients are
+    the sums over what was spread.  Stands below the kernels so that no
+    line of theirs moves: both rules lower to the same ``kda_fwd`` and
+    ``kda_bwd``."""
+    b, s, hk, dk = q.shape
+    h = v.shape[2]
+    if s % chunk or chunk & (chunk - 1):
+        raise ValueError(
+            f"gated_delta_rule: seq={s} must be a multiple of chunk="
+            f"{chunk}, a power of two")
+    if k.shape != q.shape or h % hk or v.shape[:2] != (b, s) \
+            or g.shape != (b, s, h) or beta.shape != (b, s, h):
+        raise ValueError(
+            f"gated_delta_rule: q {q.shape}, k {k.shape}, v {v.shape}, "
+            f"g {g.shape}, beta {beta.shape} do not agree")
+    n = group_chunks(s // chunk, states_every)
+    tiles = plan(s, h, dk, v.shape[-1], chunk, states_every,
+                 v.dtype.itemsize)
+    with jax.named_scope(scopes.GDN_SCAN):
+        with jax.named_scope(scopes.GDN_SPREAD):
+            if h != hk:
+                q, k = (jnp.repeat(t, h // hk, axis=2) for t in (q, k))
+            channels = jnp.broadcast_to(g.astype(_F32)[..., None],
+                                        (b, s, h, dk))
+        return _kda(q, k, v, channels, beta.astype(_F32), chunk, n, tiles)

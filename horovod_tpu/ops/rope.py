@@ -99,7 +99,15 @@ def rope_tables(positions: jax.Array, head_dim: int,
 def apply_rope_tables(x: jax.Array, cos: jax.Array,
                       sin: jax.Array) -> jax.Array:
     """Rotate ``x`` ``[batch, seq, heads, head_dim]`` by precomputed
-    tables from :func:`rope_tables`."""
+    tables from :func:`rope_tables`.  Tables made for fewer channels
+    than a head has (a configuration's ``partial_rotary_factor``) turn
+    the head's FIRST ``2 * cos.shape[-1]`` channels, split halves among
+    themselves, and leave the others as they are."""
+    rotary = 2 * cos.shape[-1]
+    if rotary < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope_tables(x[..., :rotary], cos, sin), x[..., rotary:]],
+            axis=-1)
     half = x.shape[-1] // 2
     c = cos[None, :, None, :]
     s = sin[None, :, None, :]

@@ -61,6 +61,25 @@ KDA_PREP = "kda_prep"                  # inside kda: the float32 elementwise
                                        # kda.prep_kernel_layers), else XLA's
                                        # fusions; beta's sigmoid either way
 KDA_SCAN = "kda_scan"                  # inside kda: the chunk rule alone
+GDN = "gdn"                            # a block's Gated-DeltaNet half: the
+                                       # two projections, the filter, the
+                                       # L2 norms, the decay, the chunk
+                                       # rule, the gated norm, out_proj
+GDN_PREP = "gdn_prep"                  # inside gdn: the float32 elementwise
+                                       # chain between the projections and
+                                       # the rule (one filter over q, k and
+                                       # v with silu, two L2 norms, the
+                                       # decay a head, beta's sigmoid):
+                                       # XLA's fusions
+GDN_SCAN = "gdn_scan"                  # inside gdn: the rule alone, with
+                                       # what spreads a head's decay over
+                                       # its channels and a key head over
+                                       # its value heads for ops/kda.py's
+                                       # kernels
+GDN_SPREAD = "gdn_spread"              # inside gdn_scan: that spreading
+                                       # alone (and the sums that take its
+                                       # gradients back), so gdn_scan less
+                                       # gdn_spread is the rule's kernels
 MLA_PROJ = "mla_proj"                  # latent attention, inside attn: the
                                        # two low-rank paths, their norms,
                                        # RoPE, the shared rotary key
@@ -80,7 +99,10 @@ ATTN_WINDOW = "attn_window"            # inside attn: the attention call of
                                        # a layer that has a window, so its
                                        # flash kernels carry the name
 ATTN_GATE = "attn_gate"                # inside attn: the output gate's
-                                       # matmul, sigmoid and product
+                                       # matmul (where the gate has a
+                                       # matrix of its own and is not part
+                                       # of the query projection), sigmoid
+                                       # and product
 ATTN_CROSS = "attn_cross"              # inside attn: the attention call of
                                        # a layer that reads the keys and
                                        # values another layer made
@@ -144,7 +166,8 @@ MOE_CAST = "moe_cast"                  # inside moe_experts: the float32
                                        # (backward: the gradients' cast back)
 MOE_GATE = "moe_gate"                  # inside moe_experts: act(gate) * up
                                        # between the two grouped matmuls
-MOE_SHARED = "moe_shared"              # inside mlp: the shared expert
+MOE_SHARED = "moe_shared"              # inside mlp: the shared expert and,
+                                       # where it has one, its sigmoid gate
 MTP = "mtp"                            # the multi-token-prediction module
                                        # and its pass through the head
 EMBED = "embed"                        # token and position embedding
@@ -179,4 +202,4 @@ SCOPES = (GRAD_ALLREDUCE, ALLREDUCE, OPTIMIZER_UPDATE, ATTN, MLA_PROJ,
           MOE_LOGITS, MOE_TOPK, MOE_SORT, MOE_UNSORT, MOE_ROWS_IN,
           MOE_ROWS_OUT, MOE_CAST, MOE_GATE, ATTN_BLOCK_DIFFUSION,
           DIFFUSION_NOISE, HC_COEFF, HC_READ, HC_WRITE, SSM_NORM,
-          SSM_PREP, ATTN_PREP)
+          SSM_PREP, ATTN_PREP, GDN, GDN_PREP, GDN_SCAN, GDN_SPREAD)

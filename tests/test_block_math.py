@@ -106,4 +106,4 @@ def test_raw_block_paths_refuse_every_setting_they_do_not_honour(setting):
 
 def test_the_settings_the_raw_block_paths_honour_are_fields():
     assert RAW_BLOCK_SETTINGS <= {f.name for f in fields(TransformerConfig)}
-    assert len(fields(TransformerConfig)) == 84
+    assert len(fields(TransformerConfig)) == 92

@@ -494,8 +494,9 @@ def test_configuration_refuses_what_it_cannot_mean(override, message):
 def test_the_defaults_are_the_parents():
     """The dense width defaults to ``mlp_ratio * emb_dim`` and no named
     size states another but this one, since PR 57 Xing4.0's (9216 on a
-    stream of 3584) and since PR 61 Nemotron-3-Nano's (1856 on 2688); no
-    other named size has a conv layer."""
+    stream of 3584), since PR 61 Nemotron-3-Nano's (1856 on 2688) and
+    since PR 64 Qwen3-Next's (5120 on 2048); no other named size has a
+    conv layer."""
     cfg = TransformerConfig()
     assert (cfg.mlp_width, cfg.conv_taps) == (None, 3)
     assert cfg.ffn_width == cfg.mlp_ratio * cfg.emb_dim
@@ -508,6 +509,10 @@ def test_the_defaults_are_the_parents():
             continue
         if size == "nvidia-nemotron-3-nano-30b-a3b-bf16":
             assert named.ffn_width == named.mlp_width == 1856
+            continue
+        if size == "qwen3-next-80b-a3b-instruct":
+            # published, and used by no layer: every layer routes
+            assert named.ffn_width == named.mlp_width == 5120
             continue
         assert named.mlp_width is None, size
         assert named.ffn_width == named.mlp_ratio * named.emb_dim, size

@@ -4,7 +4,9 @@ and compute the loss they did before PR 61 edited ``block_math``,
 ``mamba_mixer`` under all of them (a layer of one half, experts without
 a gate matrix, a norm by group): each size at a small shape on the CPU,
 its variables from a fixed seed, against the values the parent commit
-(3570c60) gave.  ``RECORDED`` is what this file prints when it is run
+(3570c60) gave; Nemotron-3-Nano's, PR 61's own size, against PR 63's
+tree (37d1e38) since PR 64 edited ``attention_mixer``, ``_norm`` and the
+shared expert under it.  ``RECORDED`` is what this file prints when it is run
 as a script with the parent's tree on ``PYTHONPATH``: the SHA-1 of the
 sorted ``path:shape:dtype`` lines of every collection ``init`` makes,
 and the mean next-token loss on a fixed batch.
@@ -68,6 +70,14 @@ SIZES = {
         num_heads=4, num_kv_heads=4, q_lora_rank=24, kv_lora_rank=16,
         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
         mlp_width=96, mtp_modules=0, attention_scale=24 ** -0.5, **ROUTED),
+    # since PR 64, against PR 63's tree (37d1e38): one half a layer, a
+    # Mamba-2 mixer in groups, an ungated expert layer, attention
+    "nvidia-nemotron-3-nano-30b-a3b-bf16": dict(
+        num_layers=4, layer_types=("mamba", "feed_forward", "mamba",
+                                   "full_attention"),
+        num_heads=4, num_kv_heads=2, head_size=16, ssm_heads=4,
+        ssm_head_dim=16, ssm_state=16, ssm_groups=2, ssm_chunk=8,
+        mlp_width=32, shared_width=64, **ROUTED),
 }
 RECORDED = {
     "glm-4.7-flash": ("e364b2e28a5a2ad5c4a1342ede039ae2232f9f4e",
@@ -79,6 +89,8 @@ RECORDED = {
     "lfm2-24b-a2b": ("1747d5ac9459773bcac56281dfe61e8b076521ab",
                      5.988839149475098),
     "medium": ("6474d4de9bd533ae5fbdf2f814f1cee68aac9050", 6.06050443649292),
+    "nvidia-nemotron-3-nano-30b-a3b-bf16": (
+        "87097d85fa9ad76b41db4a2feef7ef647a0e45dc", 6.008303165435791),
     "phi-4-mini-flash-reasoning": (
         "53991843cde173f1b0982470254182844177c029", 6.0701904296875),
     "sdar-30b-a3b-chat": ("29ab2b0838e8a9b141e28a0d11ab8023522ec37e",
@@ -135,7 +147,7 @@ def test_every_transformer_configuration_of_the_benchmark_is_a_case():
         if program.get("factory", "").endswith("transformer.gpt"):
             sizes.add(program["size"])
     # this PR's own size has no parent to be held to
-    assert sizes - {"nvidia-nemotron-3-nano-30b-a3b-bf16"} == set(SIZES)
+    assert sizes - {"qwen3-next-80b-a3b-instruct"} == set(SIZES)
 
 
 if __name__ == "__main__":

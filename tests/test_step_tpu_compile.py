@@ -72,14 +72,19 @@ def test_lfm2_cell_step_compiles_for_v5e(topo, compiled_kernels):
     ("granite4hm_train_s8192", 0),  # no norms, no positions
     ("trinitym_train_s8192", 5),    # four with the rotation, one without
     ("sdar_train_s8192_bd4", 6),
+    # one layer, its gate out of the query projection, 1 + w head norms
+    # and a quarter of a head rotated: the chain
+    ("qwen3next_train_s16384", 0),
 ])
 def test_which_cells_steps_hold_the_attn_prep_kernels(topo, cell, layers):
     """The steps as the benchmark builds them, traced for one described
     chip: a cell whose attention layers have neither a norm over each
     head nor a rotation holds no ``attn_prep`` call; Trinity-Mini's and
     SDAR's hold one forward kernel a layer in the forward pass, a second
-    in the rematerialised block's recompute, and one backward.  (LFM2's
-    heads of 64 keep the chain: the compiled step above.)"""
+    in the rematerialised block's recompute, and one backward, and
+    ``attn_prep_kernel_share`` reads 1.0 from the gauges the trace set;
+    Qwen3-Next's one attention layer takes the chain and reads 0.0.
+    (LFM2's heads of 64 keep the chain: the compiled step above.)"""
     import os
     import sys
 
@@ -102,6 +107,12 @@ def test_which_cells_steps_hold_the_attn_prep_kernels(topo, cell, layers):
     assert names.count("attn_prep_fwd") == 2 * layers
     assert names.count("attn_prep_bwd") == layers
     assert names.count("flash_fwd") > 0
+    if config["family"] in ("afmoe", "sdar_moe", "qwen3_next"):
+        # (the cells above these set no attn_prep gauge)
+        share = registry.load_module(os.path.join(
+            root, "benchmark", "metrics",
+            "attn_prep_kernel_share.py")).read({})
+        assert share == (1.0 if layers else 0.0)
 
 
 @pytest.mark.parametrize("rows,heads,norms,rotates", [
